@@ -71,7 +71,7 @@ def _psum_grads(mesh):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from draco_tpu.runtime import shard_map
+    from jax import shard_map
 
     return shard_map(lambda x: lax.psum(x, "w"), mesh=mesh,
                      in_specs=P("w", None), out_specs=P(),
@@ -154,7 +154,7 @@ def _build_extra_all_gather() -> BuiltProgram:
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from draco_tpu.runtime import shard_map
+    from jax import shard_map
 
     mesh = _mini_mesh()
 
@@ -374,7 +374,7 @@ def _build_wrong_axis_psum() -> BuiltProgram:
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from draco_tpu.runtime import shard_map
+    from jax import shard_map
 
     devs = np.asarray(jax.devices())
     mesh = Mesh(devs.reshape(len(devs) // 2, 2), ("w", "tp"))

@@ -80,7 +80,7 @@ class Manifest:
 
     ``host_transfer_budget`` is 0 for every registered program: a single
     infeed/outfeed/host-callback inside a scanned body serializes the chunk
-    on the host link and defeats the whole scan-chunk design (PERF.md §0).
+    on the host link and defeats the whole scan-chunk design (PERF_HISTORY.md §0).
 
     ``max_peak_bytes``: cap on the program's peak-memory estimate from
     XLA's ``compiled.memory_analysis()`` (argument + output + temp +

@@ -10,7 +10,7 @@ memory/cost analysis — against the program's
                    the serialized module ≤ max_module_bytes (generalizes the
                    round-5 d-sized-constant guard, tests/test_program_size.py
                    lineage: a (d,) f32 closure serialized 638 MB at the
-                   d≈159M flagship and wedged a 27-min chip window, PERF.md
+                   d≈159M flagship and compiled for 27 min, PERF_HISTORY.md
                    §4 / rng.random_projection_factors_in_graph)
   donation         the state carry is actually marked for buffer reuse in
                    the exported module (``jax.buffer_donor`` /
@@ -32,7 +32,7 @@ memory/cost analysis — against the program's
   host_traffic     zero infeed/outfeed/send/recv ops and zero host-callback
                    custom calls or callback primitives — one host hop inside
                    a scanned body re-serializes the chunk on the ~70 ms
-                   dispatch link the scan exists to hide (PERF.md §0)
+                   dispatch link the scan exists to hide (PERF_HISTORY.md §0)
   memory_budget    the compiled executable's peak-memory estimate
                    (``compiled.memory_analysis()``: argument + output +
                    temp + generated-code bytes, minus donated-alias bytes)
@@ -41,7 +41,7 @@ memory/cost analysis — against the program's
                    carries the raw byte columns and the program's analytic
                    flops (``cost_analysis``), so the committed artifact is
                    the round-over-round record tools/perf_watch.py diffs
-                   (PERF.md §8). Measured on the CPU-host compile of the
+                   (PERF_HISTORY.md §8). Measured on the CPU-host compile of the
                    same program the CI mesh executes — an estimate of
                    shape, not a chip HBM number.
 
@@ -150,8 +150,6 @@ def _cost_flops(compiled) -> Optional[float]:
     """Analytic FLOPs of the optimized program (same source bench.py's MFU
     uses; a scan body is counted once regardless of trip count)."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0)) if cost else 0.0
     return flops if flops > 0 else None
 
@@ -254,7 +252,7 @@ def rule_constant_bloat(art: Artifacts) -> dict:
         return {"ok": False, **res,
                 "error": f"serialized module is {art.serialized_bytes} bytes "
                          f"(limit {m.max_module_bytes}) — a large array is "
-                         f"being baked into the program (PERF.md §4)"}
+                         f"being baked into the program (PERF_HISTORY.md §4)"}
     return {"ok": True, **res}
 
 
@@ -407,7 +405,7 @@ def rule_collectives(art: Artifacts) -> dict:
                 "error": f"explicit collective counts drifted from the "
                          f"manifest (kind: expected, observed) {diff} — if "
                          f"the change is a deliberate algorithm change, "
-                         f"update the manifest (PERF.md §6)"}
+                         f"update the manifest (PERF_HISTORY.md §6)"}
     return {"ok": True, "observed": observed}
 
 
@@ -430,7 +428,7 @@ def rule_host_traffic(art: Artifacts) -> dict:
                 "error": f"{len(hits)} host-transfer sites (budget "
                          f"{m.host_transfer_budget}) — a host hop inside "
                          f"the program serializes every scanned chunk on "
-                         f"the dispatch link (PERF.md §0): {hits[:4]}"}
+                         f"the dispatch link (PERF_HISTORY.md §0): {hits[:4]}"}
     return {"ok": True, **res}
 
 
@@ -451,7 +449,7 @@ def rule_memory_budget(art: Artifacts) -> dict:
                          f"budget (dropped donation? lost remat? an "
                          f"accidental materialized temp?); raise the "
                          f"manifest only for a deliberate change "
-                         f"(PERF.md §8)"}
+                         f"(PERF_HISTORY.md §8)"}
     return {"ok": True, **res}
 
 

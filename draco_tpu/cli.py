@@ -103,10 +103,12 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     p.add_argument("--decode-impl", type=str, default="auto",
                    choices=["auto", "xla", "pallas"],
                    help="coded-decode lowering (ops/decode_kernels.py): "
-                        "auto = fused Pallas kernels on TPU backends / "
-                        "historical XLA path elsewhere; xla pins the "
-                        "historical path; pallas selects the fused kernels "
-                        "(their reference XLA lowering off-TPU)")
+                        "auto = fused Pallas kernels on a one-device TPU "
+                        "mesh / historical XLA path on a mesh that spans "
+                        "devices and off-TPU; xla pins the historical "
+                        "path; pallas demands the fused kernels (an error "
+                        "on a multi-device TPU mesh; off-TPU their "
+                        "reference XLA lowering, announced on stderr)")
     p.add_argument("--eval-freq", type=int, default=50)
     p.add_argument("--train-dir", type=str, default="./train_out/")
     p.add_argument("--job-name", type=str, default="",
@@ -132,7 +134,9 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    choices=["dense", "flash"],
                    help="single-shard attention: dense (T,T) scores or the "
                         "Pallas blockwise flash kernel (long context on one "
-                        "chip; ops/flash_attention.py)")
+                        "chip; ops/flash_attention.py — on a TPU a T that "
+                        "does not tile is an error, off-TPU the dense path "
+                        "is the lowering)")
     p.add_argument("--tensor-shards", type=int, default=1,
                    help="tp mesh-axis size (Megatron GSPMD path, tp_step.py)")
     p.add_argument("--moe-experts", type=int, default=0,
@@ -156,7 +160,7 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "TransformerLM route (sp/tp/ep/pp); hides per-step "
                         "host dispatch/RTT. Eval/checkpoint snap to chunk "
                         "boundaries. Keep 1 for conv nets on CPU (XLA:CPU "
-                        "serializes conv thunks in scan bodies, PERF.md §4); "
+                        "serializes conv thunks in scan bodies, PERF_HISTORY.md §4); "
                         "raise on accelerators and for matmul-dominated "
                         "models (TransformerLM/FC) everywhere")
     p.add_argument("--token-gen", type=str, default="host",
@@ -195,7 +199,7 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "compile lane; after --compile-warmup builds per "
                         "program a further build warns (default) or raises "
                         "— a mid-run retrace re-pays the compile the "
-                        "scan-chunked loops exist to amortize (PERF.md §8)")
+                        "scan-chunked loops exist to amortize (PERF_HISTORY.md §8)")
     p.add_argument("--numerics-watch", type=str, default="off",
                    choices=["off", "on"],
                    help="numerics observatory (obs/numerics.py, ISSUE 10): "
@@ -215,7 +219,7 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "elements; --shadow-round stochastic = shared-draw "
                         "stochastic rounding) that cross the sharding "
                         "boundary narrow and widen to f32 only inside the "
-                        "decode — 2–4× wire bytes/HBM (PERF.md §17). The "
+                        "decode — 2–4× wire bytes/HBM (PERF_HISTORY.md §17). The "
                         "cyclic decode runs the quantization-aware flag "
                         "threshold + Tikhonov-regularized locator; coded "
                         "approaches only, exclusive with --shadow-wire")
@@ -286,7 +290,7 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    help="per-detector threshold overrides, comma-"
                         "separated '<detector>.<key>=<float>' (e.g. "
                         "'trust.floor=0.4'); keys validated against the "
-                        "declarative registry (PERF.md §15 table)")
+                        "declarative registry (PERF_HISTORY.md §15 table)")
     p.add_argument("--autopilot", type=str, default="off",
                    choices=["off", "on"],
                    help="adaptive coding autopilot (draco_tpu/control): "
@@ -304,7 +308,7 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    help="autopilot policy overrides, comma-separated "
                         "'<key>=<float>' (e.g. 'r_low=1.2,"
                         "clean_boundaries=3'); keys validated against "
-                        "control.autopilot.DEFAULT_POLICY (PERF.md §16)")
+                        "control.autopilot.DEFAULT_POLICY (PERF_HISTORY.md §16)")
     p.add_argument("--compile-warmup", type=int, default=1,
                    help="XLA builds allowed per registered program (per "
                         "chunk shape) before the compile guard treats a "
@@ -348,27 +352,14 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
 
 
 def maybe_force_cpu_mesh(args: argparse.Namespace) -> None:
-    """Tool bootstrap: enable the persistent XLA compile cache, then apply
+    """Tool bootstrap: enable the persistent XLA compile cache
+    (runtime.enable_compile_cache — one policy, every backend), then apply
     --cpu-mesh N (an N-device virtual CPU mesh instead of accelerators).
     Must run before any jax computation; safe to call twice. Every tool and
-    bench.py routes through here so cache policy lives in one place.
+    bench.py routes through here so cache policy lives in one place."""
+    from draco_tpu.runtime import enable_compile_cache
 
-    The cache is skipped when an explicit CPU mode is requested
-    (--cpu-mesh / --cpu-interpret: CI smokes, where cache churn is waste)
-    or when JAX_PLATFORMS=cpu is set (enable_compile_cache refuses there:
-    cache-built XLA:CPU executables corrupt donated carries, PERF.md §9).
-    It is NOT gated on the resolved backend — probing that here would
-    initialize jax in-process, the exact ~25-minute wedge bench.py's
-    subprocess probes exist to avoid — so a flagless run that silently
-    FALLS BACK to CPU still caches XLA:CPU results and is exposed to the
-    §9 donated-carry corruption; prefer an explicit --cpu-mesh (or
-    JAX_PLATFORMS=cpu) whenever CPU execution is the intent. The
-    microarch-fingerprint cache scoping separately guards against foreign
-    feature-pinned CPU AOT reloads (the SIGILL hazard)."""
-    if not (getattr(args, "cpu_mesh", 0) or getattr(args, "cpu_interpret", False)):
-        from draco_tpu.runtime import enable_compile_cache
-
-        enable_compile_cache()
+    enable_compile_cache()
     if getattr(args, "cpu_mesh", 0):
         import os
 
@@ -494,6 +485,9 @@ def main(argv=None):
     if cfg.network == "TransformerLM":
         # model-parallel paths compose with coded DP on 2-D (w × axis)
         # meshes; config.validate() guarantees at most one axis is active.
+        # One mesh rule for all of them (parallel/mesh._make_mesh_w2): the
+        # model axis takes its shards, the n logical workers fold onto the
+        # devices that are left — 1 chip, 4 chips or a full n × shards slice.
         # --profile-dir routes to every one of them (run_token_loop;
         # chunk-snapped under steps_per_call > 1)
         if cfg.tensor_shards > 1:

@@ -206,7 +206,7 @@ def decode(code: ApproxCode, rows: jnp.ndarray,
 
     ``impl`` (ISSUE 12): ``"xla"`` is the historical lowering, bit-for-bit
     unchanged. ``"fused"`` restructures the O(n·d) health passes (the
-    decode_impl="pallas" CPU fallback: the true-mean reduction becomes a
+    kernels' reference lowering: the true-mean reduction becomes a
     matvec and the residual algebra fuses into the same sweep — bounded-err
     vs xla from accumulation order only) on the identical weight solve.
     ``"pallas"`` runs the hand-tiled kernel
@@ -247,7 +247,7 @@ def _decode_fused(code: ApproxCode, rows, present, with_health, batch_grads,
     """The fused decode (``decode`` docstring, impl != "xla"): the SAME
     weight solve as the xla path (decode_weights — a bitwise-shared
     prologue op), then the O(n·d) work either as the restructured XLA
-    sweep ("fused" — the CPU fallback) or the Pallas kernel
+    sweep ("fused" — the kernels' reference lowering) or the Pallas kernel
     ("pallas"/"pallas_interpret"). Health semantics identical to the xla
     path; only accumulation order differs. ``wire`` (ISSUE 15): the REAL
     narrow wire buffers ``(mode, buf, block)`` — on the kernel path they

@@ -91,7 +91,7 @@ def _spread_rank(n: int) -> np.ndarray:
 # the fitted-codeword deviations above say nothing (any n−2s rows define an
 # exact codeword), but the in-scope attack payloads are magnitude outliers
 # (O(100×) amplitude ⇒ O(1e4×) energy) while honest encoded rows sit within
-# ~6× of their median energy (measured, PERF.md §10) — 30× energy splits the
+# ~6× of their median energy (measured, PERF_HISTORY.md §10) — 30× energy splits the
 # two with more than an order of margin either side. The median (not the
 # mean) keeps the baseline honest with up to s+1 corrupt rows present, and
 # absent rows are excluded from both sides (a zero-filled erasure is
@@ -456,166 +456,203 @@ def locator_core(e_re, e_im, c2h_re, c2h_im, c1_re, c1_im, est_re, est_im,
     """Steps 2–5 of the decode + health, batched over projected columns —
     the fused counterpart of :func:`_locate_v` (ISSUE 12 tentpole).
 
-    Identical math and identical health semantics, restructured for the
-    fused decode kernels: a leading batch axis (the per-layer projected
-    columns ``decode_layers`` vmaps over) and only the op set Mosaic
-    lowers inside a Pallas kernel body — the three separate
-    ``_complex_solve`` calls become one-sided Jacobi (the truncated
-    locator least squares, ``linalg.jacobi_lstsq``) plus ONE Gauss–Jordan
-    inverse of the honest-row submatrix that serves both the
-    recombination vector (row 0 of ``rec⁻¹``) and the health fit
-    (``rec⁻¹ e_sel``); ``top_k``/gather/median become pairwise-rank masks
-    and matmul compaction (coding/linalg.py). The Pallas kernel
-    (``ops/decode_kernels.cyclic_locator``) calls THIS function on its
-    VMEM blocks and the ``decode_impl="pallas"`` CPU fallback jits it on
-    the full (L, n) stack, so the two lowerings cannot drift
-    algorithmically. Against the XLA path the results are bounded-err
-    with identical flag/honest sets (the selection and flag margins are
-    orders of magnitude above the solver differences; the equivalence
-    suite pins both).
+    Identical selection and health semantics, restructured so that ONE
+    function serves both lowerings: the Pallas kernel
+    (``ops/decode_kernels.cyclic_locator``) calls it on its VMEM blocks and
+    ``impl="fused"`` jits it on the full stack, so the two cannot drift
+    algorithmically. It is **batch-last** — the projected columns ride the
+    trailing (lane) axis and every value is an (n, B) block, a (1, B) row
+    or an (n, 1) column — and uses only what the TPU's Pallas compiler
+    lowers on such values (coding/linalg.py, fused tier):
 
-    e_re, e_im: (bb, n) projected columns. pres_f: (1 or bb, n) f32
+      * the locator least squares is one-sided Jacobi
+        (``linalg.jacobi_lstsq``) on the 2s×2s embedded Hankel system;
+      * honest-row ``top_k`` and the loud-row median are pairwise ranks
+        (``linalg.topk_mask`` / ``masked_median``);
+      * the two honest-row solves of ``_locate_v`` have closed forms,
+        because the honest-row submatrix of C1 is a Vandermonde matrix in
+        the DFT nodes z_t = e^{−2πi t/n}: with H the honest set and
+        Q_i = Π_{j∈H, j≠i} (z_i − z_j), the recombination vector is the
+        Lagrange basis at 0, v_i = √n · Π_{j∈H, j≠i} z_j / (z_j − z_i)
+        = (−1)^{m+1} √n (Π_{j∈H} z_j) z̄_i / Q_i, and the codeword the
+        honest rows imply is their degree-<m interpolant,
+        fit_t = Q_t · Σ_{i∈H} (e_i / Q_i) / (z_t − z_i) for t ∉ H
+        (barycentric form; fit_t = e_t on H). One masked running product
+        over the n nodes yields every Q at once — no gather of the honest
+        rows and no (m, m) solve.
+
+    Against the XLA path the results are bounded-err with identical
+    flag/honest sets (the selection and flag margins are orders of
+    magnitude above the solver differences; the equivalence suite pins
+    both). Honest rows deviate from their own interpolant by exactly 0
+    here where ``_locate_v`` reports f32 solve noise.
+
+    e_re, e_im: (n, B) projected columns, one per lane. pres_f: (n, 1) f32
     presence (all-ones when every row arrived). Returns
     ``(v_re, v_im, honest, flagged, loud, residual)`` — the first five
-    (bb, n) with the v pair already carrying the 1/1 scale of
-    ``_locate_v`` (callers fold /n into it), ``residual`` (bb,).
+    (n, B) with the v pair already carrying the 1/1 scale of
+    ``_locate_v`` (callers fold /n into it), ``residual`` (1, B).
     """
-    bb, n = e_re.shape
+    n = e_re.shape[0]
     m = n - 2 * s
-    pres_f = jnp.broadcast_to(pres_f, (bb, n))
     # presence-weighted received energy (the λ path's signal scale and the
     # health normalisation below)
     energy = e_re ** 2 + e_im ** 2
-    msq = (jnp.sum(energy * pres_f, axis=1)
-           / jnp.maximum(jnp.sum(pres_f, axis=1), 1.0))[:, None]
+    # widened as f32 and compared at full shape: the kernel compiler has no
+    # broadcast of a boolean column
+    present = jnp.broadcast_to(pres_f, energy.shape) > 0
+    msq = (jnp.sum(energy * pres_f, axis=0, keepdims=True)
+           / jnp.maximum(jnp.sum(pres_f, axis=0, keepdims=True), 1.0))
 
     if s > 0:
-        # 2. syndrome (bb, 2s): one complex matmul pair
-        e2_re = (jnp.matmul(e_re, c2h_re.T, precision=PREC)
-                 - jnp.matmul(e_im, c2h_im.T, precision=PREC))
-        e2_im = (jnp.matmul(e_re, c2h_im.T, precision=PREC)
-                 + jnp.matmul(e_im, c2h_re.T, precision=PREC))
-        # 3. Hankel system rows via STATIC slices (A[i, j] = E2[s-i-1+j],
-        #    b[i] = E2[2s-i-1]) — no gather, Mosaic constraint
-        a_re = jnp.stack(
-            [e2_re[:, s - 1 - i:2 * s - 1 - i] for i in range(s)], axis=1)
-        a_im = jnp.stack(
-            [e2_im[:, s - 1 - i:2 * s - 1 - i] for i in range(s)], axis=1)
-        b_re = jnp.concatenate(
-            [e2_re[:, 2 * s - 1 - i:2 * s - i] for i in range(s)], axis=1)
-        b_im = jnp.concatenate(
-            [e2_im[:, 2 * s - 1 - i:2 * s - i] for i in range(s)], axis=1)
+        # 2. syndrome (2s, B): one complex matmul pair, then one (1, B)
+        #    row per syndrome entry
+        e2_re = (jnp.matmul(c2h_re, e_re, precision=PREC)
+                 - jnp.matmul(c2h_im, e_im, precision=PREC))
+        e2_im = (jnp.matmul(c2h_re, e_im, precision=PREC)
+                 + jnp.matmul(c2h_im, e_re, precision=PREC))
         # same scale-free normalisation as _locate_v; the λ path divides
         # by the SIGNAL scale instead and gates on syndrome significance
         # (_locate_v's λ-branch comments — identical semantics here)
         syn = jnp.sqrt(jnp.maximum(
-            jnp.max(e2_re ** 2 + e2_im ** 2, axis=1), 1e-60))[:, None]
+            jnp.max(e2_re ** 2 + e2_im ** 2, axis=0, keepdims=True), 1e-60))
         if lam == 0.0:
             scale = syn
         else:
             scale = jnp.maximum(jnp.sqrt(msq), 1e-30)
-        big = jnp.concatenate([
-            jnp.concatenate([a_re, -a_im], axis=2),
-            jnp.concatenate([a_im, a_re], axis=2),
-        ], axis=1) / scale[:, :, None]
-        rhs = jnp.concatenate([b_re, b_im], axis=1) / scale
-        al = linalg_mod.jacobi_lstsq(big, rhs, LOCATOR_RCOND,
-                                     lam=lam)  # (bb, 2s)
-        alpha_re, alpha_im = al[:, :s], al[:, s:]
-        # 4. locator polynomial evaluated on the DFT grid
-        poly_re = jnp.concatenate(
-            [-alpha_re, jnp.ones((bb, 1), e_re.dtype)], axis=1)
-        poly_im = jnp.concatenate(
-            [-alpha_im, jnp.zeros((bb, 1), e_re.dtype)], axis=1)
-        val_re = (jnp.matmul(poly_re, est_re.T, precision=PREC)
-                  - jnp.matmul(poly_im, est_im.T, precision=PREC))
-        val_im = (jnp.matmul(poly_re, est_im.T, precision=PREC)
-                  + jnp.matmul(poly_im, est_re.T, precision=PREC))
+        sr = [e2_re[k:k + 1] / scale for k in range(2 * s)]
+        si = [e2_im[k:k + 1] / scale for k in range(2 * s)]
+        # 3. Hankel system (A[i, j] = E2[s-1-i+j], b[i] = E2[2s-1-i]) in
+        #    its real 2s×2s embedding [[Ar, −Ai], [Ai, Ar]] — plain python
+        #    indexing of the per-entry rows
+        big = ([[sr[s - 1 - i + j] for j in range(s)]
+                + [-si[s - 1 - i + j] for j in range(s)] for i in range(s)]
+               + [[si[s - 1 - i + j] for j in range(s)]
+                  + [sr[s - 1 - i + j] for j in range(s)] for i in range(s)])
+        rhs = ([sr[2 * s - 1 - i] for i in range(s)]
+               + [si[2 * s - 1 - i] for i in range(s)])
+        al = linalg_mod.jacobi_lstsq(big, rhs, LOCATOR_RCOND, lam=lam)
+        # 4. locator polynomial p(z) = z^s − Σ α_j z^j on the DFT grid:
+        #    (n, 1) grid columns × (1, B) coefficient rows
+        poly_re = [-x for x in al[:s]] + [jnp.ones_like(syn)]
+        poly_im = [-x for x in al[s:]] + [jnp.zeros_like(syn)]
+        val_re = sum(est_re[:, j:j + 1] * poly_re[j]
+                     - est_im[:, j:j + 1] * poly_im[j] for j in range(s + 1))
+        val_im = sum(est_re[:, j:j + 1] * poly_im[j]
+                     + est_im[:, j:j + 1] * poly_re[j] for j in range(s + 1))
         mag = val_re ** 2 + val_im ** 2
         if lam > 0.0:
             # syndrome significance gate at 2λ (_locate_v λ-branch comment)
-            live = (syn / scale) > 2.0 * lam  # (bb, 1)
-            mag = jnp.where(live, mag, jnp.ones_like(mag))
+            mag = jnp.where((syn / scale) > 2.0 * lam, mag, 1.0)
     else:
-        mag = jnp.ones((bb, n), jnp.float32)
+        mag = jnp.ones_like(energy)
 
     # deterministic tie-break (see _locate_v) + absent rows never eligible;
     # the λ path biases by SPREAD rank (SPREAD_PHI) — computed from iota
-    # pairwise comparisons, no host constant (Mosaic kernel body)
+    # pairwise comparisons, no host constant (kernel body)
+    iota = linalg_mod.iota
     if lam == 0.0:
-        bias = jax.lax.broadcasted_iota(jnp.float32, (bb, n), 1)
+        bias = iota(mag.shape, 0).astype(jnp.float32)
     else:
-        ki = jax.lax.broadcasted_iota(jnp.float32, (n, n), 0) * SPREAD_PHI
-        kj = jax.lax.broadcasted_iota(jnp.float32, (n, n), 1) * SPREAD_PHI
+        ki = iota((n, n), 0).astype(jnp.float32) * SPREAD_PHI
+        kj = iota((n, n), 1).astype(jnp.float32) * SPREAD_PHI
         ki = ki - jnp.floor(ki)
         kj = kj - jnp.floor(kj)
-        rank = jnp.sum((kj < ki).astype(jnp.float32), axis=1)  # (n,)
-        bias = jnp.broadcast_to(rank[None, :], (bb, n))
-    mag = mag + bias * ((1e-3 / n) * jnp.mean(mag, axis=1, keepdims=True))
-    mag = jnp.where(pres_f > 0, mag, -1.0)
+        bias = jnp.sum(jnp.where(kj < ki, 1.0, 0.0), axis=1,
+                       keepdims=True)  # (n, 1)
+    mag = mag + bias * ((1e-3 / n) * jnp.mean(mag, axis=0, keepdims=True))
+    mag = jnp.where(present, mag, -1.0)
 
-    # 5. honest set + recombination vector + health fit, through ONE
-    #    Gauss–Jordan inverse of the (m, m) honest-row submatrix
-    honest = linalg_mod.topk_mask(mag, m)  # (bb, n) bool
-    sel = linalg_mod.select_matrix(honest, m)  # (bb, m, n) f32
-    rec_re = jnp.matmul(sel.reshape(bb * m, n), c1_re,
-                        precision=PREC).reshape(bb, m, m)
-    rec_im = jnp.matmul(sel.reshape(bb * m, n), c1_im,
-                        precision=PREC).reshape(bb, m, m)
-    e_sel_re = jnp.sum(sel * e_re[:, None, :], axis=2)  # (bb, m)
-    e_sel_im = jnp.sum(sel * e_im[:, None, :], axis=2)
-    inv_re, inv_im = linalg_mod.gauss_inv_c(rec_re, rec_im)
-    # vᵀ rec = e1ᵀ  ⇒  v = row 0 of rec⁻¹, scattered back through sel
-    # (sliced, not integer-indexed: integer indexing lowers to a gather,
-    # which Mosaic cannot lower in the kernel body)
-    row0_re = inv_re[:, 0:1, :].reshape(bb, m, 1)
-    row0_im = inv_im[:, 0:1, :].reshape(bb, m, 1)
-    v_re = jnp.sum(row0_re * sel, axis=1)  # (bb, n)
-    v_im = jnp.sum(row0_im * sel, axis=1)
-    # health fit: q̂ = rec⁻¹ e_sel (the same inverse), codeword = C1 q̂
-    q_re = (jnp.sum(inv_re * e_sel_re[:, None, :], axis=2)
-            - jnp.sum(inv_im * e_sel_im[:, None, :], axis=2))
-    q_im = (jnp.sum(inv_re * e_sel_im[:, None, :], axis=2)
-            + jnp.sum(inv_im * e_sel_re[:, None, :], axis=2))
-    fit_re = (jnp.matmul(q_re, c1_re.T, precision=PREC)
-              - jnp.matmul(q_im, c1_im.T, precision=PREC))
-    fit_im = (jnp.matmul(q_re, c1_im.T, precision=PREC)
-              + jnp.matmul(q_im, c1_re.T, precision=PREC))
+    # 5. honest set, then recombination vector and codeword fit in closed
+    #    form over the DFT nodes (docstring). z_t = √n · C1[t, 1].
+    honest = linalg_mod.topk_mask(mag, m)  # (n, B) bool
+    h = jnp.where(honest, 1.0, 0.0)
+    root_n = float(np.sqrt(n))
+    z_re, z_im = c1_re[:, 1:2] * root_n, c1_im[:, 1:2] * root_n  # (n, 1)
+    node = iota((n, 1), 0)
+    nodes = np.exp(-2j * np.pi * np.arange(n) / n)
+
+    # diffs[j]: the (n, 1) column z_t − z_j, its t == j entry set to 1 + 0i
+    diffs = [(jnp.where(node == j, 1.0, z_re - float(nodes[j].real)),
+              jnp.where(node == j, 0.0, z_im - float(nodes[j].imag)))
+             for j in range(n)]
+
+    # Q_t = Π_{j∈H, j≠t} (z_t − z_j) for every t at once, and the honest
+    # node product Π_{j∈H} z_j per column — masked running products: a
+    # dishonest j contributes the factor 1
+    q_re, q_im = jnp.ones_like(energy), jnp.zeros_like(energy)
+    zp_re, zp_im = jnp.ones_like(msq), jnp.zeros_like(msq)
+    for j in range(n):
+        hj = h[j:j + 1]  # (1, B)
+        d_re, d_im = diffs[j]
+        f_re, f_im = 1.0 + hj * (d_re - 1.0), hj * d_im
+        q_re, q_im = q_re * f_re - q_im * f_im, q_re * f_im + q_im * f_re
+        g_re = 1.0 + hj * (float(nodes[j].real) - 1.0)
+        g_im = hj * float(nodes[j].imag)
+        zp_re, zp_im = zp_re * g_re - zp_im * g_im, zp_re * g_im + zp_im * g_re
+    q_abs2 = jnp.maximum(q_re ** 2 + q_im ** 2, 1e-30)
+    qi_re, qi_im = q_re / q_abs2, -q_im / q_abs2  # 1 / Q
+    # v_i = (−1)^{m+1} √n (Π_H z_j) z̄_i / Q_i on H, 0 elsewhere
+    sign = root_n if (m + 1) % 2 == 0 else -root_n
+    w_re = qi_re * z_re + qi_im * z_im  # z̄ / Q
+    w_im = qi_im * z_re - qi_re * z_im
+    v_re = h * sign * (zp_re * w_re - zp_im * w_im)
+    v_im = h * sign * (zp_re * w_im + zp_im * w_re)
+    # health fit: fit_t = Q_t Σ_{i∈H} (e_i / Q_i) / (z_t − z_i) off H, e_t
+    # on H. A dishonest i has u_i = 0; where(…) and not h·(…) so a
+    # non-finite dishonest row cannot leak in through 0·NaN
+    u_re = jnp.where(honest, e_re * qi_re - e_im * qi_im, 0.0)
+    u_im = jnp.where(honest, e_re * qi_im + e_im * qi_re, 0.0)
+    acc_re, acc_im = jnp.zeros_like(energy), jnp.zeros_like(energy)
+    for i in range(n):
+        d_re, d_im = diffs[i]
+        d_abs2 = d_re ** 2 + d_im ** 2
+        r_re, r_im = d_re / d_abs2, -d_im / d_abs2  # 1 / (z_t − z_i)
+        ui_re, ui_im = u_re[i:i + 1], u_im[i:i + 1]
+        acc_re = acc_re + ui_re * r_re - ui_im * r_im
+        acc_im = acc_im + ui_re * r_im + ui_im * r_re
+    fit_re = jnp.where(honest, e_re, q_re * acc_re - q_im * acc_im)
+    fit_im = jnp.where(honest, e_im, q_re * acc_im + q_im * acc_re)
     dev = (e_re - fit_re) ** 2 + (e_im - fit_im) ** 2
     # energy / msq computed at the top (the λ path's signal scale)
-    flagged = (dev > (rel_tol ** 2) * msq) & (pres_f > 0)
-    resid_sq = (jnp.sum(jnp.where(flagged, 0.0, dev) * pres_f, axis=1)
-                / jnp.maximum(jnp.sum(energy * pres_f, axis=1), 1e-30))
+    flagged = (dev > (rel_tol ** 2) * msq) & present
+    resid_sq = (jnp.sum(jnp.where(flagged, 0.0, dev) * pres_f, axis=0,
+                        keepdims=True)
+                / jnp.maximum(jnp.sum(energy * pres_f, axis=0,
+                                      keepdims=True), 1e-30))
     # loud-row forensics (LOUD_REL_TOL docstring): rank-selection median
     # over present∧non-NaN rows matches _locate_v's nanmedian exactly
-    med = linalg_mod.masked_median(
-        energy, (pres_f > 0) & ~jnp.isnan(energy))[:, None]
-    loud = (energy > LOUD_REL_TOL * med) & (pres_f > 0)
+    med = linalg_mod.masked_median(energy, present & (energy == energy))
+    loud = (energy > LOUD_REL_TOL * med) & present
     return v_re, v_im, honest, flagged, loud, jnp.sqrt(resid_sq)
 
 
 def _run_locator(code: CyclicCode, e_re_l, e_im_l, present, rel_tol,
                  impl: str, lam: float = 0.0):
-    """Dispatch the batched locator: ``fused`` = :func:`locator_core`
-    lowered through XLA (the decode_impl="pallas" CPU fallback),
-    ``pallas``/``pallas_interpret`` = the hand-tiled kernel
+    """Dispatch the batched locator on an (L, n) projected-column stack:
+    ``fused`` = :func:`locator_core` lowered through XLA (the kernels'
+    reference), ``pallas``/``pallas_interpret`` = the hand-tiled kernel
     (ops/decode_kernels.cyclic_locator) running the same function on VMEM
-    blocks."""
+    blocks. Both take the stack batch-last, (n, L); the transposes of a few
+    kilobytes live here so the callers keep their (L, n) rows."""
     n = code.n
-    pres_f = (jnp.ones((1, n), jnp.float32) if present is None
-              else jnp.asarray(present).astype(jnp.float32)[None, :])
+    pres_f = (jnp.ones((n, 1), jnp.float32) if present is None
+              else jnp.asarray(present).astype(jnp.float32)[:, None])
     if impl in ("pallas", "pallas_interpret"):
         from draco_tpu.ops import decode_kernels
 
-        return decode_kernels.cyclic_locator(
-            code, e_re_l, e_im_l, pres_f, rel_tol,
+        out = decode_kernels.cyclic_locator(
+            code, e_re_l.T, e_im_l.T, pres_f, rel_tol,
             interpret=(impl == "pallas_interpret"), lam=lam)
-    return locator_core(
-        e_re_l, e_im_l,
-        jnp.asarray(code.c2h_re), jnp.asarray(code.c2h_im),
-        jnp.asarray(code.c1_re), jnp.asarray(code.c1_im),
-        jnp.asarray(code.est_re), jnp.asarray(code.est_im),
-        pres_f, code.s, rel_tol, lam=lam)
+    else:
+        out = locator_core(
+            e_re_l.T, e_im_l.T,
+            jnp.asarray(code.c2h_re), jnp.asarray(code.c2h_im),
+            jnp.asarray(code.c1_re), jnp.asarray(code.c1_im),
+            jnp.asarray(code.est_re), jnp.asarray(code.est_im),
+            pres_f, code.s, rel_tol, lam=lam)
+    *masks, resid = out
+    return (*(x.T for x in masks), resid[0])
 
 
 def decode(code: CyclicCode, r_re: jnp.ndarray, r_im: jnp.ndarray, rand_factor: jnp.ndarray,
@@ -649,7 +686,7 @@ def decode(code: CyclicCode, r_re: jnp.ndarray, r_im: jnp.ndarray, rand_factor: 
     ``impl`` selects the locator implementation (ISSUE 12): ``"xla"`` is
     the historical lowering, bit-for-bit unchanged (the K∈{1,4} bitwise
     suites run it); ``"fused"`` runs the batched :func:`locator_core`
-    through XLA (the decode_impl="pallas" CPU fallback — bounded-err vs
+    through XLA (the kernels' reference lowering — bounded-err vs
     xla, identical honest/flag sets); ``"pallas"`` runs the hand-tiled
     kernel (ops/decode_kernels, TPU backends). Both non-xla paths fold
     the 1/n into the recombination vector.
@@ -691,7 +728,7 @@ def decode(code: CyclicCode, r_re: jnp.ndarray, r_im: jnp.ndarray, rand_factor: 
 
 
 def _recombine_layers_fused(n: int, v_re_l, v_im_l, bounds, r_re, r_im):
-    """Per-layer recombination of the fused decode path (PERF.md §14):
+    """Per-layer recombination of the fused decode path (PERF_HISTORY.md §14):
     same per-segment complex matvecs as the XLA path, but assembled by
     dynamic_update_slice writes into one preallocated (d,) output instead
     of a concatenate, and with the 1/n already folded into the v pair —
@@ -747,7 +784,7 @@ def decode_layers(code: CyclicCode, r_re: jnp.ndarray, r_im: jnp.ndarray,
     :func:`decode` but the layer-granularity recombination keeps the
     widened f32 rows: the per-layer segment boundaries do not align with
     the narrow wire's per-block scale tiling, so the in-tile dequant
-    kernel applies to the GLOBAL decode only (PERF.md §17).
+    kernel applies to the GLOBAL decode only (PERF_HISTORY.md §17).
     """
     del wire
     n = code.n

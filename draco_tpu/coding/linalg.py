@@ -10,43 +10,35 @@ rcond-truncated SVD least squares both that embedding and the approx
 family's where-masked optimal-decoding solve call). Bit-for-bit the ops the
 callers inlined before; the K∈{1,4} bitwise equivalence suites pin that.
 
-**Fused tier** — the same math re-derived for the fused decode kernels
-(``ops/decode_kernels.py``): batched over a leading axis and restricted to
-the op set Mosaic (the Pallas TPU compiler) lowers inside a kernel body —
-no ``lax.linalg`` custom calls, no ``sort``/``top_k``/``gather``/``scatter``,
-no traced-index slicing (Mosaic has no ``dynamic_slice``); everything is
-matmuls, elementwise algebra, ``broadcasted_iota`` masks and
-``fori_loop``-carried tensors. Each fused primitive is used twice: the
-Pallas kernels call it on VMEM blocks, and the kernels' REFERENCE path
-(the ``decode_impl="pallas"`` CPU fallback, coding/cyclic.py §fused) jits
-the identical function on full arrays — so the interpret-mode kernel tests
-and the reference path cannot drift algorithmically.
+**Fused tier** — the same math re-derived for the fused cyclic locator
+(``coding/cyclic.locator_core``, which ``ops/decode_kernels.cyclic_locator``
+runs on VMEM blocks and the ``impl="fused"`` reference path jits on full
+arrays — one function, two lowerings). Everything here is **batch-last**:
+the batch of independent problems (the per-layer projected columns) rides
+the LAST axis — the TPU lane axis — and every value is a 2-D array, an
+``(n, B)`` block with the code's worker axis on sublanes or a ``(1, B)``
+row holding one scalar per problem. That restriction is what the TPU's
+Pallas compiler (Mosaic) lowers without relayouts: elementwise algebra,
+row/column broadcasts, static row slices, axis-0 reductions, int32
+``broadcasted_iota`` masks and ``fori_loop``-carried 2-D values — no
+``lax.linalg`` custom calls, no ``sort``/``top_k``/``gather``/``scatter``,
+no rank-3 values, no reshapes. (The batch-FIRST rank-3 formulation this
+replaces passed interpret mode and was refused by the chip's compiler —
+PERF.md, chip bring-up.)
 
   truncated least squares   :func:`jacobi_lstsq` — one-sided Jacobi SVD,
                             fixed sweep count (quadratic convergence; the
-                            systems are ≤ 2s×2s ≤ 10×10). Works on A
-                            directly, NOT its gram: the gram squares the
-                            condition number and f32 gram eigenvalues below
-                            ~1e-7·λmax are noise, which would put the
-                            rcond=1e-5 locator cutoff (λ cutoff 1e-10)
-                            under the noise floor — the exact failure the
-                            XLA tier's docstring warns about.
-  square complex solve      :func:`gauss_inv_c` — Gauss–Jordan inverse
-                            with partial pivoting on the complex modulus,
-                            carried as (re, im) pairs. One inversion serves
-                            both decode solves: the recombination vector is
-                            ROW 0 of ``rec⁻¹`` (vᵀrec = e1ᵀ ⇒ v = first row)
-                            and the health fit is ``rec⁻¹ e_sel`` — the XLA
-                            tier pays two separate LU solves for these.
+                            systems are ≤ 2s×2s ≤ 10×10), on matrices
+                            held as nested lists of ``(1, B)`` entries.
+                            Works on A directly, NOT its gram: the gram
+                            squares the condition number and f32 gram
+                            eigenvalues below ~1e-7·λmax are noise, which
+                            would put the rcond=1e-5 locator cutoff (λ
+                            cutoff 1e-10) under the noise floor — the exact
+                            failure the XLA tier's docstring warns about.
   honest-row top-k          :func:`topk_mask` — pairwise-comparison ranks
-                            (n ≤ 64, the (n, n) bool block is nothing);
-                            ties break toward the lower index, matching
-                            ``lax.top_k``.
-  masked compaction         :func:`select_matrix` — the top-k rows as an
-                            (m, n) 0/1 selection matrix (cumsum via a
-                            triangular matmul), so "gather the honest rows
-                            of C1" becomes an MXU matmul instead of a
-                            gather.
+                            accumulated row by row; ties break toward the
+                            lower index, matching ``lax.top_k``.
   masked median             :func:`masked_median` — rank-selection median
                             over a masked axis, matching ``jnp.nanmedian``
                             over present∧finite entries (the cyclic loud-row
@@ -54,6 +46,8 @@ and the reference path cannot drift algorithmically.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +79,7 @@ def truncated_lstsq(a: jnp.ndarray, b: jnp.ndarray, rcond: float,
     the Tikhonov family — ridge-DAMPING the kept directions
     (σ/(σ²+λ²)) was measured to distort the locator polynomial enough
     to mislocate live adversaries at int8's noise floor (the σ ≈ λ
-    boundary pays up to 50% coefficient shrinkage; PERF.md §17), so
+    boundary pays up to 50% coefficient shrinkage; PERF_HISTORY.md §17), so
     kept directions solve exactly. ``lam == 0.0`` takes the historical
     path bit-for-bit (a static python branch — the compiled program is
     unchanged)."""
@@ -135,7 +129,7 @@ def complex_solve(a_re, a_im, b_re, b_im, rcond: float = 0.0,
 
 
 # ---------------------------------------------------------------------------
-# Fused tier — Mosaic-lowerable batched primitives (leading axis = batch)
+# Fused tier — Mosaic-lowerable batch-LAST primitives (trailing axis = batch)
 # ---------------------------------------------------------------------------
 
 # One-sided Jacobi sweep count. Convergence is quadratic in sweeps; the
@@ -150,54 +144,52 @@ JACOBI_SWEEPS = 12
 _TINY = 1e-30
 
 
-def _i2(shape, dim):
+def iota(shape, dim):
+    """int32 iota — the only iota dtype the TPU's Pallas compiler has."""
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
 
-def _col(a, j):
-    """Static column j of a (b, m, k) batch as (b, m) — static-strided
-    slice, no dynamic_slice (Mosaic constraint)."""
-    return a[:, :, j]
+def _row_at(x: jnp.ndarray, j):
+    """Row j of an (n, B) block as (1, B), for a TRACED j: a masked axis-0
+    reduction (there is no dynamic slice in a kernel body). A static j
+    slices instead (``x[j:j + 1]``)."""
+    return jnp.sum(jnp.where(iota(x.shape, 0) == j, x, 0.0), axis=0,
+                   keepdims=True)
 
 
-def _set_col(a, j, new):
-    """Mask-based static column write: a[:, :, j] = new, Mosaic-safe."""
-    return jnp.where(_i2(a.shape, 2) == j, new[:, :, None], a)
+def jacobi_lstsq(a, b, rcond: float, sweeps: int = JACOBI_SWEEPS,
+                 lam: float = 0.0):
+    """Truncated least squares ``min ‖A x − b‖`` via one-sided Jacobi SVD,
+    batched over the lanes.
 
-
-def jacobi_lstsq(a: jnp.ndarray, b: jnp.ndarray, rcond: float,
-                 sweeps: int = JACOBI_SWEEPS, lam: float = 0.0):
-    """Truncated least squares ``min ‖A x − b‖`` via one-sided Jacobi SVD.
-
-    a: (bb, m, m) real, b: (bb, m) — returns x (bb, m) with singular
-    directions below ``rcond·σmax`` dropped, the fused-tier counterpart of
-    :func:`truncated_lstsq` (same cutoff semantics; σ come out of the
-    rotations at high relative accuracy because the gram is never formed).
-    ``lam`` > 0 drops directions with σ ≤ λ outright, exactly like the
-    XLA tier (truncated_lstsq's noise-floor cutoff — keep
-    σ² > max((rcond·σmax)², λ²)); kept directions solve exactly. λ=0
-    keeps the historical expression bit-for-bit via a static python
-    branch.
+    ``a``: m×m nested sequence, ``a[r][c]`` a (1, B) row (entry (r, c) of
+    each of the B systems); ``b``: m-sequence of (1, B) rows. Returns x as
+    a list of m (1, B) rows with singular directions below ``rcond·σmax``
+    dropped — the fused-tier counterpart of :func:`truncated_lstsq` (same
+    cutoff semantics; σ come out of the rotations at high relative
+    accuracy because the gram is never formed). ``lam`` > 0 drops
+    directions with σ ≤ λ outright, exactly like the XLA tier
+    (truncated_lstsq's noise-floor cutoff — keep
+    σ² > max((rcond·σmax)², λ²)); kept directions solve exactly.
 
     One-sided Jacobi: rotate column pairs of A (accumulating the rotations
     in V) until columns are mutually orthogonal — then A·V = W with
     ``WᵀW = diag(σ²)``, and x = V Σ⁻² Wᵀ b restricted to kept σ. The pair
-    loop is a static python loop (m ≤ 10), every update a masked
-    elementwise op — no traced indexing anywhere.
+    loop is a static python loop (m ≤ 10) over per-entry (1, B) rows:
+    every update is elementwise, nothing is indexed.
     """
-    bb, m, _ = a.shape
-    v0 = jnp.broadcast_to(
-        (_i2((bb, m, m), 1) == _i2((bb, m, m), 2)).astype(a.dtype),
-        (bb, m, m))
+    m = len(b)
+    one, zero = jnp.ones_like(b[0]), jnp.zeros_like(b[0])
+    w0 = [[a[r][c] for c in range(m)] for r in range(m)]
+    v0 = [[one if r == c else zero for c in range(m)] for r in range(m)]
 
     def sweep(_, carry):
-        w, v = carry
+        w, v = ([list(row) for row in t] for t in carry)
         for p in range(m - 1):
             for q in range(p + 1, m):
-                wp, wq = _col(w, p), _col(w, q)
-                alpha = jnp.sum(wp * wp, axis=1)
-                beta = jnp.sum(wq * wq, axis=1)
-                gamma = jnp.sum(wp * wq, axis=1)
+                alpha = sum(w[r][p] * w[r][p] for r in range(m))
+                beta = sum(w[r][q] * w[r][q] for r in range(m))
+                gamma = sum(w[r][p] * w[r][q] for r in range(m))
                 # rotation annihilating the (p, q) off-diagonal of WᵀW:
                 # branchless — |γ| ≈ 0 degrades to the identity rotation
                 live = jnp.abs(gamma) > _TINY
@@ -210,171 +202,75 @@ def jacobi_lstsq(a: jnp.ndarray, b: jnp.ndarray, rcond: float,
                 t = jnp.where(live, t, 0.0)
                 c = 1.0 / jnp.sqrt(1.0 + t * t)
                 s = c * t
-                c_ = c[:, None]
-                s_ = s[:, None]
-                new_wp = c_ * wp - s_ * wq
-                new_wq = s_ * wp + c_ * wq
-                w = _set_col(_set_col(w, p, new_wp), q, new_wq)
-                vp, vq = _col(v, p), _col(v, q)
-                new_vp = c_ * vp - s_ * vq
-                new_vq = s_ * vp + c_ * vq
-                v = _set_col(_set_col(v, p, new_vp), q, new_vq)
+                for mat in (w, v):
+                    for r in range(m):
+                        mp, mq = mat[r][p], mat[r][q]
+                        mat[r][p] = c * mp - s * mq
+                        mat[r][q] = s * mp + c * mq
         return w, v
 
     # sweeps under ONE fori_loop: the pair loop must stay unrolled (static
-    # column slicing) but the sweep body is identical each pass — carrying
-    # it keeps the op graph sweeps× smaller, which is the difference
-    # between a seconds and a minutes XLA:CPU compile at n=32
-    w, v = jax.lax.fori_loop(0, sweeps, sweep, (a, v0))
-    sig2 = jnp.sum(w * w, axis=1)  # (bb, m) = σ²
-    sig2max = jnp.max(sig2, axis=1, keepdims=True)
-    keep = sig2 > (rcond * rcond) * sig2max
-    wtb = jnp.sum(w * b[:, :, None], axis=1)  # (bb, m) = Wᵀ b
-    if lam > 0.0:
-        keep = keep & (sig2 > lam * lam)
-    coef = jnp.where(keep, wtb / jnp.maximum(sig2, _TINY), 0.0)
-    return jnp.sum(v * coef[:, None, :], axis=2)  # V @ coef
+    # entries) but the sweep body is identical each pass — carrying it
+    # keeps the op graph sweeps× smaller, which is the difference between a
+    # seconds and a minutes XLA:CPU compile at n=32
+    w, v = jax.lax.fori_loop(0, sweeps, sweep, (w0, v0))
+    sig2 = [sum(w[r][c] * w[r][c] for r in range(m)) for c in range(m)]
+    sig2max = functools.reduce(jnp.maximum, sig2)
+    coef = []
+    for c in range(m):
+        keep = sig2[c] > (rcond * rcond) * sig2max
+        if lam > 0.0:
+            keep = keep & (sig2[c] > lam * lam)
+        wtb = sum(w[r][c] * b[r] for r in range(m))  # (Wᵀ b)[c]
+        coef.append(jnp.where(keep, wtb / jnp.maximum(sig2[c], _TINY), 0.0))
+    return [sum(v[r][c] * coef[c] for c in range(m)) for r in range(m)]
 
 
-def gauss_inv_c(a_re: jnp.ndarray, a_im: jnp.ndarray):
-    """Batched complex matrix inverse via Gauss–Jordan with partial
-    pivoting on the complex modulus, carried as (re, im) pairs.
+def _count_ahead(x: jnp.ndarray, eligible=None):
+    """(n, B) f32 count, per entry i, of the entries j of its column that
+    sort AHEAD of it ascending (``x[j] < x[i]``, equal values by lower
+    index) — restricted to ``eligible`` j's when given ((n, B) f32 0/1).
+    One row per ``fori_loop`` step: the (n, n) pairwise block never exists,
+    and the op graph does not grow with n. f32 counts are exact (n ≤ 64)."""
+    n = x.shape[0]
+    idx = iota(x.shape, 0)
 
-    a_re, a_im: (bb, m, m). Returns (inv_re, inv_im). Every step is
-    mask-based (iota one-hots select/ swap/ update rows), the pivot row is
-    the max-|a|² row at or below the diagonal with lowest-index tie-break,
-    and the m-step elimination runs under one ``fori_loop`` — the whole
-    inverse is elementwise algebra Mosaic lowers in-kernel. The decode
-    callers invert the honest-row DFT submatrix, full-rank by construction
-    (any n−2s distinct rows of the C1 Vandermonde are independent).
-    """
-    bb, m, _ = a_re.shape
-    shape = (bb, m, m)
-    eye = (_i2(shape, 1) == _i2(shape, 2)).astype(a_re.dtype)
-    eye = jnp.broadcast_to(eye, shape)
-    inv_re = eye
-    inv_im = jnp.zeros(shape, a_re.dtype)
+    def step(j, count):
+        xj = _row_at(x, j)
+        ahead = (xj < x) | ((xj == x) & (idx > j))
+        if eligible is not None:
+            ahead = ahead & (_row_at(eligible, j) > 0.5)
+        return count + jnp.where(ahead, 1.0, 0.0)
 
-    def rows_get(t, rowsel):
-        return jnp.sum(t * rowsel, axis=1, keepdims=True)  # (bb, 1, m)
-
-    def body(k, carry):
-        a_re, a_im, inv_re, inv_im = carry
-        csel = (_i2(shape, 2) == k).astype(a_re.dtype)
-        col_re = jnp.sum(a_re * csel, axis=2)  # (bb, m)
-        col_im = jnp.sum(a_im * csel, axis=2)
-        mod = col_re * col_re + col_im * col_im
-        # f32 row indices (exact: m ≤ 64) — Mosaic has no integer reductions
-        rowix = _i2((bb, m), 1).astype(a_re.dtype)
-        kf = jnp.float32(1.0) * k
-        mod = jnp.where(rowix >= kf, mod, -1.0)  # eliminated rows ineligible
-        mx = jnp.max(mod, axis=1, keepdims=True)
-        is_max = mod == mx
-        # lowest-index argmax, branchless
-        r = jnp.min(jnp.where(is_max, rowix, float(m)), axis=1)  # (bb,)
-        rsel_k = (_i2(shape, 1) == k).astype(a_re.dtype)
-        rsel_r = (_i2(shape, 1).astype(a_re.dtype)
-                  == r[:, None, None]).astype(a_re.dtype)
-
-        def swap(t):
-            row_k = rows_get(t, rsel_k)
-            row_r = rows_get(t, rsel_r)
-            return t + rsel_k * (row_r - row_k) + rsel_r * (row_k - row_r)
-
-        a_re, a_im = swap(a_re), swap(a_im)
-        inv_re, inv_im = swap(inv_re), swap(inv_im)
-
-        # pivot = a[k, k]; scale row k by 1/pivot (complex reciprocal)
-        p_re = jnp.sum(a_re * rsel_k * csel[:, :m, :], axis=(1, 2))
-        p_im = jnp.sum(a_im * rsel_k * csel[:, :m, :], axis=(1, 2))
-        pm = jnp.maximum(p_re * p_re + p_im * p_im, _TINY)
-        ip_re = (p_re / pm)[:, None, None]
-        ip_im = (-p_im / pm)[:, None, None]
-        rk_re = rows_get(a_re, rsel_k)
-        rk_im = rows_get(a_im, rsel_k)
-        ik_re = rows_get(inv_re, rsel_k)
-        ik_im = rows_get(inv_im, rsel_k)
-        srk_re = rk_re * ip_re - rk_im * ip_im
-        srk_im = rk_re * ip_im + rk_im * ip_re
-        sik_re = ik_re * ip_re - ik_im * ip_im
-        sik_im = ik_re * ip_im + ik_im * ip_re
-
-        # eliminate column k from every other row
-        f_re = jnp.where(rowix == k, 0.0, jnp.sum(a_re * csel, axis=2))
-        f_im = jnp.where(rowix == k, 0.0, jnp.sum(a_im * csel, axis=2))
-        f_re = f_re[:, :, None]
-        f_im = f_im[:, :, None]
-        a_re2 = a_re - (f_re * srk_re - f_im * srk_im)
-        a_im2 = a_im - (f_re * srk_im + f_im * srk_re)
-        inv_re2 = inv_re - (f_re * sik_re - f_im * sik_im)
-        inv_im2 = inv_im - (f_re * sik_im + f_im * sik_re)
-        isrow = _i2(shape, 1) == k
-        a_re2 = jnp.where(isrow, srk_re, a_re2)
-        a_im2 = jnp.where(isrow, srk_im, a_im2)
-        inv_re2 = jnp.where(isrow, sik_re, inv_re2)
-        inv_im2 = jnp.where(isrow, sik_im, inv_im2)
-        return a_re2, a_im2, inv_re2, inv_im2
-
-    _, _, inv_re, inv_im = jax.lax.fori_loop(
-        0, m, body, (a_re, a_im, inv_re, inv_im))
-    return inv_re, inv_im
+    return jax.lax.fori_loop(0, n, step, jnp.zeros(x.shape, jnp.float32))
 
 
 def topk_mask(mag: jnp.ndarray, m: int):
-    """Bool mask of the top-m entries per batch row of mag (bb, n), by
+    """Bool mask of the top-m entries per COLUMN of mag (n, B), by
     pairwise-comparison rank — no sort, no top_k (Mosaic constraint). Ties
     break toward the lower index (``lax.top_k``'s preference), though the
     cyclic locator's index-monotone bias makes exact ties unreachable."""
-    gt = (mag[:, None, :] > mag[:, :, None]) | (
-        (mag[:, None, :] == mag[:, :, None])
-        & (_i2((mag.shape[0],) + mag.shape[1:] * 2, 2)
-           < _i2((mag.shape[0],) + mag.shape[1:] * 2, 1)))
-    # f32 count (exact: n ≤ 64) — Mosaic has no integer reductions
-    rank = jnp.sum(gt.astype(jnp.float32), axis=2)  # entries ahead of i
-    return rank < float(m)
-
-
-def select_matrix(mask: jnp.ndarray, m: int):
-    """The (bb, m, n) 0/1 compaction matrix of a (bb, n) bool mask with
-    exactly m set lanes per row: S[r, i] = 1 iff i is the r-th set lane.
-    ``S @ X`` then gathers the selected rows of X as a matmul — the MXU
-    replacement for a gather Mosaic cannot lower. Cumsum comes from a
-    triangular-matrix matmul (built from iota, so no host constant)."""
-    bb, n = mask.shape
-    mf = mask.astype(jnp.float32)
-    tri = (_i2((n, n), 0) <= _i2((n, n), 1)).astype(jnp.float32)
-    pos = jnp.dot(mf, tri,
-                  preferred_element_type=jnp.float32) - 1.0  # (bb, n)
-    shape = (bb, m, n)
-    sel = (jnp.broadcast_to(pos[:, None, :], shape)
-           == _i2(shape, 1).astype(jnp.float32))
-    return jnp.where(jnp.broadcast_to(mask[:, None, :], shape), sel,
-                     False).astype(jnp.float32)
+    return _count_ahead(-mag) < float(m)
 
 
 def masked_median(x: jnp.ndarray, mask: jnp.ndarray):
-    """Median of x (bb, n) over the lanes where mask (bb, n) is True —
-    rank-selection (average of the two middle order statistics for even
-    counts), matching ``jnp.nanmedian`` over the masked entries. All-False
-    rows return NaN, like nanmedian of an all-NaN slice. Non-finite x
-    lanes must be excluded by the caller's mask; masked-out lanes are
-    value-sanitized so a NaN there cannot leak through the 0·NaN trap."""
-    bb, n = x.shape
-    mf = mask.astype(x.dtype)
+    """Median of each column of x (n, B) over the rows where mask (n, B)
+    is True, as a (1, B) row — rank-selection (average of the two middle
+    order statistics for even counts), matching ``jnp.nanmedian`` over the
+    masked entries. All-False columns return NaN, like nanmedian of an
+    all-NaN slice. Non-finite x entries must be excluded by the caller's
+    mask; masked-out entries are value-sanitized so a NaN there cannot
+    leak through the 0·NaN trap."""
+    mf = jnp.where(mask, 1.0, 0.0)
     xs = jnp.where(mask, x, 0.0)
-    shape = (bb, n, n)
-    lt = (xs[:, None, :] < xs[:, :, None]) | (
-        (xs[:, None, :] == xs[:, :, None]) & (_i2(shape, 2) < _i2(shape, 1)))
-    lt = lt & jnp.broadcast_to(mask[:, None, :], shape)
-    # f32 counts (exact: n ≤ 64) — Mosaic has no integer reductions
-    rank = jnp.sum(lt.astype(jnp.float32), axis=2)  # (bb, n) masked rank
-    p = jnp.sum(mf, axis=1, keepdims=True)  # (bb, 1)
+    rank = _count_ahead(xs, mf)  # masked rank of every entry
+    p = jnp.sum(mf, axis=0, keepdims=True)  # (1, B)
     k1 = jnp.floor((p - 1.0) * 0.5)
     k2 = jnp.floor(p * 0.5)
 
     def at_rank(k):
-        hit = (rank == k) & mask
-        return jnp.sum(jnp.where(hit, xs, 0.0), axis=1)
+        return jnp.sum(jnp.where((rank == k) & mask, xs, 0.0), axis=0,
+                       keepdims=True)
 
     med = 0.5 * (at_rank(k1) + at_rank(k2))
-    return jnp.where(p[:, 0] > 0, med, jnp.nan)
+    return jnp.where(p > 0, med, jnp.nan)

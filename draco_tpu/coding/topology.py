@@ -441,7 +441,7 @@ def make_tree_decode_shmap(tcode: TreeCode, mesh, impl: str = "xla",
     from jax.sharding import PartitionSpec as P
 
     from draco_tpu.coding import cyclic as cyclic_mod
-    from draco_tpu.runtime import shard_map
+    from jax import shard_map
 
     from draco_tpu.parallel.partition import tree_rows
 

@@ -116,12 +116,14 @@ class TrainConfig:
     # (cyclic_master.py:126-128).
     decode_granularity: str = "global"
     # Decode implementation (ISSUE 12; ops/decode_kernels.py). "auto":
-    # the fused Pallas decode kernels on TPU backends, the historical XLA
-    # lowering elsewhere — CI and CPU runs keep today's bitwise path.
-    # "xla": pin the historical lowering everywhere. "pallas": the fused
-    # kernels where a TPU can run them, their reference lowering (the
-    # same fused algorithm through XLA — bounded-err vs xla, identical
-    # honest/flag sets) on other backends. Applies to the cyclic locator
+    # the fused Pallas decode kernels on a one-device TPU mesh, the
+    # historical XLA lowering on a mesh that spans devices (GSPMD cannot
+    # partition a Mosaic kernel) and off-TPU — CI and CPU runs keep the
+    # bitwise path. "xla": pin the historical lowering everywhere.
+    # "pallas": demand the fused kernels — an error on a multi-device TPU
+    # mesh; off-TPU their reference lowering (the same fused algorithm
+    # through XLA — bounded-err vs xla, identical honest/flag sets), said
+    # on stderr. Resolved once per setup. Applies to the cyclic locator
     # chain and the approx partial-recovery decode on every route; the
     # shadow-quantized decode (obs/numerics.py) stays on the xla path its
     # thresholds were calibrated on.
@@ -138,7 +140,8 @@ class TrainConfig:
     # Single-shard attention implementation (seq_shards == 1): "dense"
     # materialises (T, T) scores per head; "flash" is the Pallas blockwise
     # kernel (ops/flash_attention.py) — O(T·Dh) memory, for long sequences
-    # on one chip. Off-TPU it falls back to dense automatically.
+    # on one chip. On a TPU a T that does not tile raises; off-TPU the
+    # dense path is the lowering.
     attn_impl: str = "dense"
     # tp mesh-axis size for the GSPMD tensor-parallel path (parallel/
     # tp_step.py); composes with the coded worker axis on a (w, tp) mesh
@@ -180,7 +183,7 @@ class TrainConfig:
     # Single-host only (utils/checkpoint.py).
     compress_ckpt: bool = False
 
-    # --- host-loop fusion (TPU-native addition; PERF.md §0/§4b) ---
+    # --- host-loop fusion (TPU-native addition; PERF_HISTORY.md §0/§4b) ---
     # K training steps fused into ONE jitted lax.scan per device program
     # (training/step.py train_many for the coded-DP CNN Trainer;
     # parallel/common.py make_token_train_many + parallel/token_loop.py for
@@ -191,9 +194,9 @@ class TrainConfig:
     # boundaries (explicit remainder chunks, so max_steps need not divide
     # by K). K=1 keeps today's eager per-step loop bit-for-bit. CPU caveat:
     # XLA:CPU runs conv thunks inside scan bodies single-threaded
-    # (PERF.md §4), so the default stays 1 for conv nets — raise it on
+    # (PERF_HISTORY.md §4), so the default stays 1 for conv nets — raise it on
     # accelerators (and freely for the matmul-dominated TransformerLM /
-    # FC, where the caveat does not apply — PERF.md §4b).
+    # FC, where the caveat does not apply — PERF_HISTORY.md §4b).
     steps_per_call: int = 1
     # Where the synthetic token stream is generated (TransformerLM routes):
     # "host" — numpy synthetic_text per step, uploaded per step/chunk (the
@@ -253,7 +256,7 @@ class TrainConfig:
     # shadow_round="stochastic" — shared-draw stochastic rounding) which
     # cross the worker-sharding boundary narrow and are widened to f32
     # only inside the decode (f32 accumulation throughout): the 2–4×
-    # wire-bytes/HBM win of PERF.md §13's ledger, landed on the actual
+    # wire-bytes/HBM win of PERF_HISTORY.md §13's ledger, landed on the actual
     # coded path. The cyclic decode then runs the quantization-aware flag
     # threshold (per-(n, s, dtype) table derived by tools/wire_study.py)
     # and the Tikhonov-regularized locator (λ scaled to the dtype's noise
@@ -332,7 +335,7 @@ class TrainConfig:
     # Per-detector threshold overrides, comma-separated
     # "<detector>.<key>=<float>" (e.g. "trust.floor=0.4,guard.off_count=2")
     # — keys validated against the declarative detector registry at config
-    # time. "" keeps every registered default (PERF.md §15 table).
+    # time. "" keeps every registered default (PERF_HISTORY.md §15 table).
     incident_thresholds: str = ""
 
     # --- adaptive coding autopilot (draco_tpu/control; ROADMAP item 5) ---

@@ -577,7 +577,7 @@ class Autopilot:
             "erasure_budget": self._quarantine_budget(),
             # the engine's next chunk was assembled before this boundary:
             # the schedule write lands at effective_step, the wire sees
-            # it one chunk later (PERF.md §16)
+            # it one chunk later (PERF_HISTORY.md §16)
             "wire_lag": "one assembled chunk",
         })
 

@@ -58,7 +58,7 @@ class ChunkedEngine:
     """Run the chunked regime over ``ranges`` with ``client`` supplying the
     loop-specific pieces. ``timed=True`` adds the CNN loop's t_fetch/t_comp
     wall accounting (a ``sync`` span + per-flush ``t_comp`` record field);
-    the LM loop runs untimed (its flush IS the sync, PERF.md §0).
+    the LM loop runs untimed (its flush IS the sync, PERF_HISTORY.md §0).
 
     ``autopilot`` (control/autopilot.py, or None) acts at every flush
     boundary — AFTER the heartbeat beat, so the incident engine has folded
@@ -154,7 +154,7 @@ class ChunkedEngine:
                         # clock so device execution lands in t_comp (a
                         # device→host fetch, NOT block_until_ready — the
                         # latter only awaits dispatch on remote backends,
-                        # PERF.md §0); this is the boundary's one true sync
+                        # PERF_HISTORY.md §0); this is the boundary's one true sync
                         with tracer.span("sync", at_step=end):
                             deferred.sync()
                         t_comp = max(time.perf_counter() - window_t0
@@ -214,7 +214,7 @@ class SegmentPipeline:
     drains ``j`` — so the transfer wall hides under the decode wall. The
     serial rail (``pipelined=False``) drains before the next transfer,
     forbidding overlap; the delta between the rails is the pipeline win
-    tools/segment_study.py commits behind perf_watch (PERF.md §18).
+    tools/segment_study.py commits behind perf_watch (PERF_HISTORY.md §18).
 
     Hooks (duck-typed, like the engine's client protocol):
 

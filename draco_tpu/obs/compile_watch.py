@@ -273,7 +273,7 @@ class CompileWatch:
                 f"windows, warmup={self.warmup}): the program "
                 f"re-paid trace+lower+compile mid-run — a shape/dtype/"
                 f"structure change in its arguments is defeating the "
-                f"compile-once contract (obs/compile_watch.py, PERF.md §8)")
+                f"compile-once contract (obs/compile_watch.py, PERF_HISTORY.md §8)")
 
     def _on_backend(self, compile_s: float) -> None:
         with self._lock:

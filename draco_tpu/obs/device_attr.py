@@ -16,7 +16,7 @@ Capture shapes handled
 jax.profiler writes ``profile_dir/plugins/profile/<ts>/*.trace.json.gz`` — a
 Chrome-trace-event dump. Two event shapes exist:
 
-* **XLA:CPU fallback (this container, PERF.md §8c):** each executed HLO op
+* **XLA:CPU fallback (this container, PERF_HISTORY.md §8c):** each executed HLO op
   is one complete event whose ``args`` carry only ``hlo_module`` (e.g.
   ``jit_many_body``) and ``hlo_op`` (the *optimized*-HLO instruction name,
   e.g. ``dot.2`` / ``fusion.17``). The named-scope path is NOT in the event —
@@ -431,7 +431,7 @@ def cross_check(ledger: dict, manifest_counts: Optional[dict],
             f"disagrees with the linted Manifest — {diff}. The static audit "
             f"and the runtime trace must agree: either the program changed "
             f"without relinting (run tools/program_lint.py) or the scope "
-            f"map drifted from the executed program (PERF.md §12)")
+            f"map drifted from the executed program (PERF_HISTORY.md §12)")
     return {"ok": True, "expected": expected, "observed": observed}
 
 
@@ -444,10 +444,10 @@ def roofline(total_device_us: float, steps_profiled: int, lint_row: dict,
              peak_bytes_per_s: Optional[float] = None) -> dict:
     """Join measured device time with the program's analytic cost columns
     (``rules.memory_budget``: cost_analysis flops + memory byte columns;
-    PERF.md §8). ``flops`` of a K-fused row counts the scan body ONCE
+    PERF_HISTORY.md §8). ``flops`` of a K-fused row counts the scan body ONCE
     (rules._cost_flops), so it is the per-step figure either way. Fractions
     are reported only when a peak is supplied (on the XLA:CPU fallback there
-    is no honest hardware peak — PERF.md §8c; chip runs pass the chip
+    is no honest hardware peak — PERF_HISTORY.md §8c; chip runs pass the chip
     numbers)."""
     mb = (lint_row.get("rules") or {}).get("memory_budget") or {}
     flops = mb.get("flops")
@@ -662,7 +662,7 @@ def device_status_block(fold: dict) -> Optional[dict]:
     phase fractions, decode share, attribution coverage, and — when the
     scope map carries the program's analytic flops (stamped by
     tools/device_profile.py) — the achieved-FLOPs rate. On the XLA:CPU
-    fallback there is no honest hardware peak (PERF.md §8c), so
+    fallback there is no honest hardware peak (PERF_HISTORY.md §8c), so
     ``achieved_flops_frac`` stays None unless a peak was supplied."""
     programs = (fold or {}).get("programs") or []
     if not programs:

@@ -399,7 +399,7 @@ def register_slo(name: str, thresholds: Dict[str, float]):
 
 
 def slo_table() -> List[dict]:
-    """The enumerable SLO set (PERF.md §21's table source)."""
+    """The enumerable SLO set (PERF_HISTORY.md §21's table source)."""
     return [{"name": s.name, "thresholds": dict(s.thresholds),
              "doc": s.doc} for s in SLOS.values()]
 

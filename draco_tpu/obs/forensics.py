@@ -70,7 +70,7 @@ def num_mask_words(num_workers: int) -> int:
         raise ValueError(
             f"forensics mask columns support num_workers <= {MAX_WORKERS} "
             f"(got {num_workers}); grow MAX_WORKERS and the column family "
-            f"together (PERF.md §10)"
+            f"together (PERF_HISTORY.md §10)"
         )
     return (num_workers + MASK_WORD_BITS - 1) // MASK_WORD_BITS
 

@@ -112,7 +112,7 @@ def register_detector(name: str, severity: str, source: str,
 
 
 def detector_table() -> List[dict]:
-    """The enumerable detector set (PERF.md §15's table source): name,
+    """The enumerable detector set (PERF_HISTORY.md §15's table source): name,
     severity, source, and the declared threshold defaults."""
     return [{"name": s.name, "severity": s.severity, "source": s.source,
              "thresholds": dict(s.thresholds), "doc": s.doc}

@@ -147,7 +147,7 @@ WIRE_REL_TOL_TABLE = {
     # through the locator fit deviate up to 0.79/7.5 — past these
     # thresholds — so detection recall holds but flag precision degrades
     # in the adversary regime (honest_dev_max_adv in the committed cells;
-    # PERF.md §17). The certificate these entries carry is the
+    # PERF_HISTORY.md §17). The certificate these entries carry is the
     # no-adversary one the PR 10 blocker was about.
     (32, 3, "bf16"): 2e-1, (32, 3, "int8"): 2.8e-1,
 }

@@ -41,18 +41,12 @@ TILE_D = 4096
 
 
 def use_pallas() -> bool:
-    """True when the attached backend can lower the Pallas kernels natively
-    (a TPU, including TPUs behind plugin backends that report a non-"tpu"
-    platform name). Recorded by tools/tpu_kernel_check.py in its report —
-    it does NOT drive production dispatch, which defaults to the XLA path
-    after hardware measurement (see module docstring)."""
-    if jax.default_backend() == "tpu":
-        return True
-    try:
-        kind = jax.devices()[0].device_kind or ""
-    except Exception:
-        return False
-    return "tpu" in kind.lower()
+    """True when the attached backend compiles the Pallas kernels: a TPU.
+    Drives the decode-kernel and flash-attention dispatch
+    (ops/decode_kernels.resolve_decode_impl, ops/flash_attention); the three
+    kernels in THIS module stay behind ``force=True`` after hardware
+    measurement (module docstring)."""
+    return jax.default_backend() == "tpu"
 
 
 def _pad_d(x: jnp.ndarray, tile: int) -> jnp.ndarray:

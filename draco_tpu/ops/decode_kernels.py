@@ -8,16 +8,16 @@ codewords. Two kernels attack it:
 
 ``cyclic_locator``
     Steps 2–5 of the cyclic decode — syndrome matmuls → Hankel locator
-    solve → honest-row top-k → recombination-vector solve → fitted-codeword
-    health residual — fused into one kernel, vmapped over per-layer
-    projected columns via the grid: each grid step loads an (8, n) block
-    of the (L, n) projected-column stack into VMEM and runs the whole
-    locator chain on it (``coding/cyclic.locator_core`` — the SAME
-    function the CPU reference path jits, so the two lowerings cannot
-    drift), instead of round-tripping ~6 solver ops per layer through HBM.
-    The in-graph health/forensics columns (residual, flagged, loud,
-    honest) are KERNEL OUTPUTS — observability is part of the contract,
-    not a casualty of fusion.
+    solve → honest-row top-k → recombination vector → fitted-codeword
+    health residual — fused into one kernel over the per-layer projected
+    columns, batch-last: each grid step loads an (n, 128) block of the
+    (n, L) projected-column stack into VMEM — 128 columns on the lanes —
+    and runs the whole locator chain on it (``coding/cyclic.locator_core``
+    — the SAME function the reference path jits, so the two lowerings
+    cannot drift), instead of round-tripping ~6 solver ops per layer
+    through HBM. The in-graph health/forensics columns (residual,
+    flagged, loud, honest) are KERNEL OUTPUTS — observability is part of
+    the contract, not a casualty of fusion.
 
 ``approx_decode``
     The approx family's partial-recovery decode tail: where-mask →
@@ -30,57 +30,82 @@ codewords. Two kernels attack it:
     sums accumulated across sequential grid steps (the
     ``ops/coded._project_kernel`` accumulator pattern).
 
-Dispatch (``resolve_decode_impl``): ``cfg.decode_impl = "auto"`` keeps
-today's XLA lowering off-TPU and selects the kernels on TPU backends;
-``"pallas"`` selects the kernels where they can run and otherwise falls
-back to their reference lowering (the same fused algorithm through XLA —
-coding/cyclic.locator_core / coding/approx._decode_fused), which is what
-the committed CPU-container artifacts measure (PERF.md §14); ``"xla"``
-pins the historical path bit-for-bit. Interpret mode covers the kernel
-bodies in CI without a TPU, and the registered lint rows export the
-pallas_call programs for the TPU platform, so the Python-side Mosaic
-lowering is exercised on every CI run (the tpu_attn_lowering_check
-methodology).
+Dispatch (``resolve_decode_impl``): ``cfg.decode_impl = "auto"`` selects
+the kernels on a TPU backend when the step's mesh is ONE device, and the XLA
+lowering on a mesh that spans devices (GSPMD cannot partition a Mosaic
+kernel, and the decode sits in the replicated part of the step) and off-TPU;
+``"pallas"`` selects the kernels on a one-device TPU mesh, raises on a TPU
+mesh that spans devices, and off-TPU says, on stderr, that it runs their
+reference lowering instead (the same functions through XLA —
+coding/cyclic.locator_core / coding/approx._decode_fused); ``"xla"`` pins
+the historical path bit-for-bit. The choice is made once per setup from the
+backend and the mesh — never per call, never by catching a failure.
+Interpret mode covers the kernel bodies in CI without a TPU, and
+tests/test_chip_compile.py compiles every kernel for a described v5e with
+the chip's own compiler (interpret mode and the cross-platform export both
+accept programs that compiler refuses — PERF.md, chip bring-up).
 """
 
 from __future__ import annotations
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (parity w/ ops)
 
 from draco_tpu.ops.coded import TILE_D, _pad_d, use_pallas
 
-# Layers per cyclic-locator grid step: the f32 sublane tile. The (L, n)
-# projected-column stack is padded up to a multiple of this; padded layers
-# run the locator on zero columns (harmlessly — the truncated solves are
-# zero-safe) and the wrapper slices them away.
-LAYER_BLOCK = 8
+# Projected columns (layers / wire segments) per cyclic-locator grid step:
+# the lane width. The (n, L) batch-last stack is padded up to a multiple of
+# this; padded columns run the locator on zeros (harmlessly — the truncated
+# solves are zero-safe) and the wrapper slices them away.
+LAYER_BLOCK = 128
 
 
-def resolve_decode_impl(value: str, backend_pallas=None) -> str:
-    """cfg.decode_impl -> the coding-layer ``impl`` tag (static per
-    process: dispatch depends only on the attached backend, so the jitted
-    step programs close over the result — no retraces).
+def resolve_decode_impl(value: str, mesh=None, backend_pallas=None) -> str:
+    """cfg.decode_impl -> the coding-layer ``impl`` tag. Static per setup:
+    it depends only on the attached backend and on ``mesh`` — the mesh the
+    step program is built for — so the jitted step closes over the result;
+    no retraces, and nothing gives way to anything else at run time.
 
-      auto    pallas on TPU backends, xla elsewhere (the default: CI and
-              CPU fallbacks keep today's bitwise path)
+    The kernels are a ONE-DEVICE lowering: inside a program that GSPMD
+    partitions over several devices a Mosaic kernel is refused at compile
+    time ("cannot be automatically partitioned"), and the decode runs in
+    exactly that replicated-after-the-gather part of the step. So:
+
+      auto    the kernels on a TPU backend when the mesh is one device;
+              xla on a mesh that spans devices, and off-TPU
       xla     the historical lowering, everywhere
-      pallas  the kernels on TPU; their fused reference lowering (same
-              algorithm through XLA) elsewhere — the CPU-container cells
-              of the committed artifacts run this fallback
+      pallas  the kernels on a one-device TPU mesh; on a TPU mesh that
+              spans devices a ValueError — the request cannot be met and
+              is not reinterpreted. Off-TPU the kernels cannot be built;
+              the request resolves — LOUDLY, on stderr — to ``fused``,
+              the kernels' reference lowering (the same functions through
+              XLA), which is what the CPU tests, the lint registry and
+              the committed CPU artifacts drive
     """
     if backend_pallas is None:
         backend_pallas = use_pallas()
+    n_dev = 1 if mesh is None else mesh.devices.size
     if value == "xla":
         return "xla"
     if value == "auto":
-        return "pallas" if backend_pallas else "xla"
+        return "pallas" if backend_pallas and n_dev == 1 else "xla"
     if value == "pallas":
-        return "pallas" if backend_pallas else "fused"
+        if backend_pallas and n_dev > 1:
+            raise ValueError(
+                f"decode_impl=pallas: the decode kernels are a one-device "
+                f"lowering and this mesh spans {n_dev} devices (a Mosaic "
+                f"kernel cannot be partitioned by GSPMD); use "
+                f"decode_impl=auto or xla")
+        if backend_pallas:
+            return "pallas"
+        print(f"decode_impl=pallas: backend is {jax.default_backend()!r}, "
+              f"not a TPU — the kernels cannot run here; using their XLA "
+              f"reference lowering (impl=fused)", file=sys.stderr, flush=True)
+        return "fused"
     raise ValueError(f"decode_impl must be auto|xla|pallas, got {value!r}")
 
 
@@ -101,62 +126,56 @@ def _cyclic_locator_kernel(s, rel_tol, lam, e_re_ref, e_im_ref, c2h_re_ref,
         pres_ref[...], s, rel_tol, lam=lam)
     v_re_ref[...] = v_re
     v_im_ref[...] = v_im
-    honest_ref[...] = honest.astype(jnp.float32)
-    flagged_ref[...] = flagged.astype(jnp.float32)
-    loud_ref[...] = loud.astype(jnp.float32)
-    # per-layer scalar, lane-broadcast to satisfy the block tiling (the
-    # wrapper keeps lane 0) — the flash kernel's lse layout
-    resid_ref[...] = jnp.broadcast_to(resid[:, None], resid_ref.shape)
+    honest_ref[...] = jnp.where(honest, 1.0, 0.0)
+    flagged_ref[...] = jnp.where(flagged, 1.0, 0.0)
+    loud_ref[...] = jnp.where(loud, 1.0, 0.0)
+    resid_ref[...] = resid
 
 
 @functools.partial(jax.jit,
                    static_argnames=("s", "rel_tol", "lam", "interpret"))
-def _cyclic_locator_pallas(e_re_l, e_im_l, c2h_re, c2h_im, c1_re, c1_im,
+def _cyclic_locator_pallas(e_re, e_im, c2h_re, c2h_im, c1_re, c1_im,
                            est_re, est_im, pres_f, s, rel_tol, lam,
                            interpret):
-    L, n = e_re_l.shape
+    n, L = e_re.shape
     lp = -(-L // LAYER_BLOCK) * LAYER_BLOCK
     if lp != L:
-        pad = [(0, lp - L), (0, 0)]
-        e_re_l = jnp.pad(e_re_l, pad)
-        e_im_l = jnp.pad(e_im_l, pad)
-    grid = (lp // LAYER_BLOCK,)
-    row = lambda i: (i, 0)  # noqa: E731
+        pad = [(0, 0), (0, lp - L)]
+        e_re = jnp.pad(e_re, pad)
+        e_im = jnp.pad(e_im, pad)
+    if s == 0:  # no syndrome: a placeholder row stands in for the (0, n) C2ᴴ
+        c2h_re = c2h_im = jnp.zeros((1, n), jnp.float32)
+    col = lambda i: (0, i)  # noqa: E731
     whole = lambda i: (0, 0)  # noqa: E731
-    blk = (LAYER_BLOCK, n)
+    blk = (n, LAYER_BLOCK)
+    consts = (c2h_re, c2h_im, c1_re, c1_im, est_re, est_im, pres_f)
     out = pl.pallas_call(
         functools.partial(_cyclic_locator_kernel, s, rel_tol, lam),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(blk, row),
-            pl.BlockSpec(blk, row),
-            pl.BlockSpec(c2h_re.shape, whole),
-            pl.BlockSpec(c2h_im.shape, whole),
-            pl.BlockSpec(c1_re.shape, whole),
-            pl.BlockSpec(c1_im.shape, whole),
-            pl.BlockSpec(est_re.shape, whole),
-            pl.BlockSpec(est_im.shape, whole),
-            pl.BlockSpec((1, n), whole),
-        ],
-        out_specs=[pl.BlockSpec(blk, row)] * 6,
-        out_shape=[jax.ShapeDtypeStruct((lp, n), jnp.float32)] * 6,
+        grid=(lp // LAYER_BLOCK,),
+        in_specs=[pl.BlockSpec(blk, col)] * 2
+        + [pl.BlockSpec(c.shape, whole) for c in consts],
+        out_specs=[pl.BlockSpec(blk, col)] * 5
+        + [pl.BlockSpec((1, LAYER_BLOCK), col)],
+        out_shape=[jax.ShapeDtypeStruct((n, lp), jnp.float32)] * 5
+        + [jax.ShapeDtypeStruct((1, lp), jnp.float32)],
         interpret=interpret,
-    )(e_re_l, e_im_l, c2h_re, c2h_im, c1_re, c1_im, est_re, est_im, pres_f)
+    )(e_re, e_im, *consts)
     v_re, v_im, honest, flagged, loud, resid = out
-    return (v_re[:L], v_im[:L], honest[:L] > 0.5, flagged[:L] > 0.5,
-            loud[:L] > 0.5, resid[:L, 0])
+    return (v_re[:, :L], v_im[:, :L], honest[:, :L] > 0.5,
+            flagged[:, :L] > 0.5, loud[:, :L] > 0.5, resid[:, :L])
 
 
-def cyclic_locator(code, e_re_l, e_im_l, pres_f, rel_tol,
+def cyclic_locator(code, e_re, e_im, pres_f, rel_tol,
                    interpret: bool = False, lam: float = 0.0):
-    """Kernel entry used by ``coding/cyclic._run_locator``: (L, n)
-    projected-column stack -> the locator outputs of
-    ``coding/cyclic.locator_core`` (v pair, honest/flagged/loud masks,
-    per-layer residual). ``pres_f``: (1, n) f32 presence row shared by
-    every layer. ``lam``: static Tikhonov λ of the locator solve
-    (narrow-wire regularization, ISSUE 15; 0.0 = exact path)."""
+    """Kernel entry used by ``coding/cyclic._run_locator``: batch-last
+    (n, L) projected-column stack -> the locator outputs of
+    ``coding/cyclic.locator_core`` in the same layout (v pair and
+    honest/flagged/loud masks (n, L), residual (1, L)). ``pres_f``: (n, 1)
+    f32 presence column shared by every layer. ``lam``: static Tikhonov λ
+    of the locator solve (narrow-wire regularization, ISSUE 15; 0.0 =
+    exact path)."""
     return _cyclic_locator_pallas(
-        e_re_l, e_im_l,
+        e_re, e_im,
         jnp.asarray(code.c2h_re), jnp.asarray(code.c2h_im),
         jnp.asarray(code.c1_re), jnp.asarray(code.c1_im),
         jnp.asarray(code.est_re), jnp.asarray(code.est_im),
@@ -166,7 +185,7 @@ def cyclic_locator(code, e_re_l, e_im_l, pres_f, rel_tol,
 # ---------------------------------------------------------------------------
 # narrow-ingest dequantization (ISSUE 15): widen bf16/int8 wire tiles to
 # f32 INSIDE the kernel body, so the widened (n, d) f32 matrix never
-# round-trips HBM — the dequant the XLA fallback pays as a separate
+# round-trips HBM — the dequant the XLA lowering pays as a separate
 # convert/multiply pass happens on the VMEM-resident tile instead
 # ---------------------------------------------------------------------------
 
@@ -186,6 +205,24 @@ def _dequant_tile(q, scale, block):
               & (col < (row + 1) * block)).astype(jnp.float32)
     wide = jnp.dot(scale, expand, preferred_element_type=jnp.float32)
     return q.astype(jnp.float32) * wide
+
+
+def _scale_tiles(scale, n_tiles: int, sb: int):
+    """(n, nb) per-block int8 scales -> (n_tiles, n, sb), one (n, sb) slab
+    per d tile, padded with 1.0 (padded q lanes are 0, so 0·1 stays 0).
+    The slab is a block whose last two dims EQUAL the array's — the only
+    way a block sb = TILE_D/block (16) lanes wide is legal on TPU once
+    there is more than one tile; an (n, sb) window into the (n, nb) array
+    is refused by the compiler at any real d."""
+    n, nb = scale.shape
+    if n_tiles * sb != nb:
+        scale = jnp.pad(scale, [(0, 0), (0, n_tiles * sb - nb)],
+                        constant_values=1.0)
+    return jnp.moveaxis(scale.reshape(n, n_tiles, sb), 1, 0)
+
+
+def _scale_spec(n: int, sb: int):
+    return pl.BlockSpec((None, n, sb), lambda j: (j, 0, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -256,18 +293,12 @@ def _approx_decode_pallas(rows, bg, v_over_n, pres_wide, scale=None,
     if scale is None:
         kernel = functools.partial(_approx_decode_kernel, d, n)
     else:
-        # per-block int8 scales ride their own (n, TILE_D/block) tiles,
-        # padded with 1.0 (padded q lanes are 0, so 0·1 stays 0)
+        # per-block int8 scales ride their own (n, TILE_D/block) slabs
         sb = TILE_D // block
-        nb = scale.shape[-1]
-        nb_p = (dp // TILE_D) * sb
-        if nb_p != nb:
-            scale = jnp.pad(scale, [(0, 0), (0, nb_p - nb)],
-                            constant_values=1.0)
         kernel = functools.partial(_approx_decode_kernel_narrow, d, n,
                                    block)
-        in_specs.insert(1, pl.BlockSpec((n, sb), lambda j: (0, j)))
-        operands.insert(1, scale)
+        in_specs.insert(1, _scale_spec(n, sb))
+        operands.insert(1, _scale_tiles(scale, dp // TILE_D, sb))
     decoded, sqd, sqg = pl.pallas_call(
         kernel,
         grid=grid,
@@ -367,14 +398,10 @@ def _cyclic_recombine_pallas(v_re, v_im, q_re, q_im, s_re=None, s_im=None,
         kernel = _cyclic_recombine_kernel_bf16
     else:
         sb = TILE_D // block
-        nb_p = (dp // TILE_D) * sb
-        pad = [(0, 0), (0, nb_p - s_re.shape[-1])]
-        if nb_p != s_re.shape[-1]:
-            s_re = jnp.pad(s_re, pad, constant_values=1.0)
-            s_im = jnp.pad(s_im, pad, constant_values=1.0)
         kernel = functools.partial(_cyclic_recombine_kernel_int8, block)
-        in_specs += [pl.BlockSpec((n, sb), lambda j: (0, j))] * 2
-        operands += [s_re, s_im]
+        in_specs += [_scale_spec(n, sb)] * 2
+        operands += [_scale_tiles(s_re, dp // TILE_D, sb),
+                     _scale_tiles(s_im, dp // TILE_D, sb)]
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -511,13 +538,13 @@ def lint_programs():
         code = cyclic_mod.build_cyclic_code(8, 1)
         L, n = 16, 8
 
-        def fn(e_re_l, e_im_l, pres_f):
-            return cyclic_locator(code, e_re_l, e_im_l, pres_f,
+        def fn(e_re, e_im, pres_f):
+            return cyclic_locator(code, e_re, e_im, pres_f,
                                   cyclic_mod.HEALTH_REL_TOL)
 
-        args = (jnp.zeros((L, n), jnp.float32),
-                jnp.zeros((L, n), jnp.float32),
-                jnp.ones((1, n), jnp.float32))
+        args = (jnp.zeros((n, L), jnp.float32),
+                jnp.zeros((n, L), jnp.float32),
+                jnp.ones((n, 1), jnp.float32))
         return BuiltProgram("kernel_cyclic_locator", jax.jit(fn), args,
                             None, kernel_manifest,
                             extra={"layers": L, "n": n, "s": code.s},

@@ -18,29 +18,25 @@ compute nothing (`pl.when`), so causal attention does ~half the block work.
 No reference counterpart (the reference is CNN-only, SURVEY.md §5.7); this
 is a hot-op kernel of the TPU build's long-context axis, complementing ring
 attention (which shards T across chips; this kernel serves each shard or the
-single-chip case). Dispatch mirrors ops/coded.py: Pallas on TPU backends,
-dense jnp fallback elsewhere; interpret mode in CI.
+single-chip case). Dispatch: the kernel on a TPU backend — a shape that
+does not tile RAISES there, it never becomes the O(T²) dense path behind the
+caller's back; off-TPU the dense jnp path is the lowering (the CPU tests'
+reference), and interpret mode covers the kernel body in CI.
 """
 
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.6 names the Mosaic params class TPUCompilerParams; same kwargs
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 from draco_tpu.ops.coded import use_pallas
 
 NEG_INF = -1e30
 _LANE = 128
-_FALLBACK_WARNED = set()  # one warning per distinct non-tiling shape
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -52,10 +48,10 @@ def _fit_block(limit: int, t: int, lane_rule: bool) -> int:
     be a multiple of the 8-row sublane tile, and (key blocks only,
     lane_rule=True) be a whole number of 128-wide lane tiles when wider
     than one. A plain min(limit, t) would demote every t not divisible by
-    the default (e.g. t=1536 with bk=1024) to the dense fallback — the
+    the default (e.g. t=1536 with bk=1024) out of the kernel — the
     shrink keeps every t%8==0 length kernel-eligible at the biggest block
     the shape allows (t=768 -> 256 under a 1024 limit). Returns 0 when no
-    legal block exists (t%8 != 0); _kernel_eligible then rejects."""
+    legal block exists (t%8 != 0); _kernel_eligible then raises."""
     b = min(limit, t)
     b -= b % 8
     while b >= 8:
@@ -192,7 +188,7 @@ def _flash_fwd(q, k, v, scale, bq, bk, causal, interpret):
             pltpu.VMEM((bq, _LANE), jnp.float32),
             pltpu.VMEM((bq, _LANE), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -338,7 +334,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
         out_specs=pl.BlockSpec((1, bq, dh), q_row),
         out_shape=jax.ShapeDtypeStruct((g, t, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -372,7 +368,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
             pltpu.VMEM((bk, dh), jnp.float32),
             pltpu.VMEM((bk, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -437,9 +433,9 @@ def flash_attention(q, k, v, *, block_q: int = 512, block_k: int = 1024,
     (attention math upstream is f32; the kernel accumulates f32 regardless).
 
     The causal mask is offset-invariant for self-attention (q and k share
-    positions), so no offset argument is needed. Falls back to the dense
-    streaming-softmax path off-TPU, when T doesn't tile, or when T is too
-    small to block.
+    positions), so no offset argument is needed. Off-TPU (and not
+    interpret/force) this is the dense streaming-softmax path; where the
+    kernel is selected, a T that does not tile raises (_kernel_eligible).
     """
     from draco_tpu.parallel.ring_attention import dense_attention
 
@@ -456,36 +452,23 @@ def _kernel_eligible(t, bq, bk, dh, force, interpret) -> bool:
     (including T itself when it becomes the single block) must honour the
     8-sublane f32 tile, and key blocks wider than a lane tile must be whole
     lane tiles so the lane-broadcast row stats can tile across them (_cols).
-    force=True on a non-tiling shape raises — a caller that explicitly
-    demanded the O(T·Dh)-memory kernel must not silently get the O(T²)
-    dense path (advisor r2); a TPU caller falling back warns once."""
+    Where the kernel is selected — a TPU backend, ``force=True`` or
+    interpret mode — a non-tiling shape raises: a caller who asked for the
+    O(T·Dh)-memory kernel must not silently get the O(T²) dense path.
+    False (the dense path) only when the kernel is not selected at all:
+    off-TPU, or ``force=False``."""
     use = force if force is not None else (use_pallas() or interpret)
-    tiling_fail = bool(
-        bq < 8 or bk < 8  # _fit_block found no legal block (t % 8 != 0)
-        or t % 8 or bq % 8 or bk % 8 or t % bq or t % bk
-        or dh > _LANE or (bk > _LANE and bk % _LANE))
-    if use and not tiling_fail:
-        return True
-    constraints = (
-        f"need t%8==0, t%bq==0, t%bk==0, blocks%8==0, dh<={_LANE}, "
-        f"and bk a multiple of {_LANE} when bk>{_LANE}"
-    )
-    if force and tiling_fail:
+    if not use:
+        return False
+    if (bq < 8 or bk < 8  # _fit_block found no legal block (t % 8 != 0)
+            or t % 8 or bq % 8 or bk % 8 or t % bq or t % bk
+            or dh > _LANE or (bk > _LANE and bk % _LANE)):
         raise ValueError(
-            f"flash_attention(force=True): shape does not tile "
-            f"(t={t}, bq={bq}, bk={bk}, dh={dh}; {constraints})"
-        )
-    if use and tiling_fail:
-        key = (t, bq, bk, dh)
-        if key not in _FALLBACK_WARNED:
-            _FALLBACK_WARNED.add(key)
-            warnings.warn(
-                f"flash_attention: falling back to dense O(T²) attention "
-                f"for non-tiling shape (t={t}, bq={bq}, bk={bk}, "
-                f"dh={dh}; {constraints})",
-                stacklevel=2,
-            )
-    return False
+            f"flash_attention: shape does not tile for the kernel "
+            f"(t={t}, bq={bq}, bk={bk}, dh={dh}; need t%8==0, t%bq==0, "
+            f"t%bk==0, blocks%8==0, dh<={_LANE}, and bk a multiple of "
+            f"{_LANE} when bk>{_LANE}) — use attn_impl=dense for this shape")
+    return True
 
 
 def _run_folded(q, k, v, bq, bk, causal, interpret, want_lse):
@@ -518,8 +501,9 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True,
     """(o, lse) pair for the ring composition (parallel/ring_attention.
     ring_flash_attention): lse is the per-row log-sum-exp in (B, T, H), and
     is differentiable (the kernels' VJP carries d lse/d s = softmax), which
-    is what lets normalized per-hop outputs merge under grad. Falls back to
-    the dense streaming path (with lse) off-TPU or for non-tiling shapes."""
+    is what lets normalized per-hop outputs merge under grad. The dense
+    streaming path (with lse) off-TPU; raises for a non-tiling shape where
+    the kernel is selected."""
     from draco_tpu.parallel.ring_attention import dense_attention_lse
 
     b, t, h, dh = q.shape

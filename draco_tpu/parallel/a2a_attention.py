@@ -29,7 +29,6 @@ from jax import lax
 
 from draco_tpu.parallel.ring_attention import dense_attention
 
-from draco_tpu.runtime import axis_size
 
 
 def a2a_attention(
@@ -56,7 +55,7 @@ def a2a_attention(
         return (inner(q, k, v) if inner is not None
                 else dense_attention(q, k, v, causal=causal))
 
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % sp:
         raise ValueError(f"a2a_attention: heads {h} not divisible by sp={sp}")

@@ -63,7 +63,7 @@ def segment_decode_bounds(cfg, dim: int, leaf_offsets=None):
 
 
 def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
-                     cfg=None, adv_mask=None, step=None):
+                     cfg=None, adv_mask=None, step=None, mesh=None):
     """The approx family's whole aggregation sequence — ingest forensics →
     weighted-partial-sum encode → present mask → optimal-decoding partial
     recovery → residual-vs-bound health — in ONE place, shared by the CNN
@@ -81,13 +81,15 @@ def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
     range columns for grads/wire/aggregate and the shadow-quantized decode
     — stashed under ``health["watch"]`` for ``decode_health_metrics`` to
     merge into the metric row. Identity (no added ops) when the watch is
-    off."""
+    off. ``mesh``: the mesh the calling step is built for — it decides the
+    decode lowering (ops/decode_kernels.resolve_decode_impl)."""
     from draco_tpu.obs import forensics as forensics_mod
     from draco_tpu.obs import numerics as numerics_mod
     from draco_tpu.ops.decode_kernels import resolve_decode_impl
 
     decode_impl = resolve_decode_impl(
-        getattr(cfg, "decode_impl", "xla") if cfg is not None else "xla")
+        getattr(cfg, "decode_impl", "xla") if cfg is not None else "xla",
+        mesh)
     tree = _is_tree(code)
     bad_rows = forensics_mod.nonfinite_rows(grads)
     with jax.named_scope("draco_encode"):
@@ -156,7 +158,8 @@ def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
 
 
 def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
-                         present=None, leaf_offsets=None, step=None):
+                         present=None, leaf_offsets=None, step=None,
+                         mesh=None):
     """(n, d) per-worker flat gradients → ``(aggregated (d,), health)``.
 
     ``step`` (optional traced scalar): the training step, threaded so the
@@ -184,6 +187,10 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
     the cyclic decode runs one locator per parameter tensor like the
     reference (cyclic_master.py:125-129), matching the CNN path.
 
+    ``mesh``: the mesh the calling route's step is built for — it decides
+    the decode lowering (ops/decode_kernels.resolve_decode_impl: the
+    kernels are a one-device lowering).
+
     The encode/decode phases run under ``jax.named_scope`` so XProf device
     traces group ops by Draco's reference phase names (the device-side
     counterpart of the host SpanTracer, draco_tpu/obs).
@@ -196,7 +203,7 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
         # approximate family (coding/approx.py; ISSUE 8): the shared
         # sequence above — health is the residual-vs-bound certificate
         return approx_aggregate(code, grads, present=present, cfg=cfg,
-                                adv_mask=adv_mask, step=step)
+                                adv_mask=adv_mask, step=step, mesh=mesh)
     if cfg.approach == "cyclic":
         # ingest-row health, BEFORE encode: a non-finite per-worker gradient
         # row attributes to its worker here, where row k still means worker
@@ -233,7 +240,7 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
         from draco_tpu.obs import numerics as numerics_mod
         from draco_tpu.ops.decode_kernels import resolve_decode_impl
 
-        decode_impl = resolve_decode_impl(cfg.decode_impl)
+        decode_impl = resolve_decode_impl(cfg.decode_impl, mesh)
         # the REAL narrow wire (ISSUE 15): the codeword pair is rounded
         # into narrow buffers that cross the sharding boundary; the decode
         # widens to f32 and runs the quantization-aware flag threshold +

@@ -1,4 +1,4 @@
-"""2-D device meshes: coded worker axis × sequence axis."""
+"""2-D device meshes: coded worker axis × one model-parallel axis."""
 
 from __future__ import annotations
 
@@ -8,7 +8,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from draco_tpu.runtime import WORKER_AXIS
+from draco_tpu.runtime import WORKER_AXIS, worker_axis_size
 
 SEQ_AXIS = "sp"
 TP_AXIS = "tp"
@@ -16,55 +16,55 @@ EP_AXIS = "ep"
 PP_AXIS = "pp"
 
 
+def _make_mesh_w2(axis2: str, num_workers: int, shards: int,
+                  devices: Optional[Sequence[jax.Device]]) -> Mesh:
+    """(w, axis2) mesh for ``num_workers`` LOGICAL workers × ``shards``
+    model-parallel shards, on whatever devices there are.
+
+    One rule for every route (the runtime.make_mesh discipline): the
+    model-parallel axis takes exactly ``shards`` devices — it shards the
+    model, so it cannot shrink — and the worker axis takes the largest
+    divisor of ``num_workers`` that fits the rest, ``len(devices) //
+    shards``. Fewer w devices than workers FOLDS the workers onto them in
+    equal lane blocks (every step builder vmaps its lanes), said loudly;
+    so n=8 runs on one chip (w=1, 8 lanes), on four (w=4, 2 lanes; or
+    w=2 × 2 shards) and on eight alike. The model-parallel axis is
+    innermost, riding the fastest ICI links (its collectives fire several
+    times per step; the worker-axis gather once)."""
+    devices = list(devices if devices is not None else jax.devices())
+    if len(devices) < shards:
+        raise ValueError(
+            f"(w, {axis2}={shards}) mesh needs at least {shards} devices, "
+            f"have {len(devices)}"
+        )
+    w = worker_axis_size(num_workers, len(devices) // shards)
+    if w < num_workers or w * shards < len(devices):
+        print(
+            f"mesh (w={w}, {axis2}={shards}): {num_workers} logical workers "
+            f"fold {num_workers // w} to a device on {w * shards}/"
+            f"{len(devices)} devices",
+            flush=True,
+        )
+    grid = np.asarray(devices[:w * shards]).reshape(w, shards)
+    return Mesh(grid, (WORKER_AXIS, axis2))
+
+
 def make_mesh_2d(
     num_workers: int,
     seq_shards: int,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Mesh of shape (num_workers, seq_shards) with axes (w, sp).
-
-    Lay the sequence axis innermost so its ring rides neighbouring ICI links;
-    the worker-axis gather crosses the slower dimension once per step.
-    """
-    devices = list(devices if devices is not None else jax.devices())
-    need = num_workers * seq_shards
-    if len(devices) < need:
-        raise ValueError(
-            f"make_mesh_2d({num_workers}, {seq_shards}) needs {need} devices, "
-            f"have {len(devices)}"
-        )
-    grid = np.asarray(devices[:need]).reshape(num_workers, seq_shards)
-    return Mesh(grid, (WORKER_AXIS, SEQ_AXIS))
+    """(w, sp) mesh (_make_mesh_w2): the sequence axis innermost so its ring
+    rides neighbouring ICI links; the worker-axis gather crosses the slower
+    dimension once per step."""
+    return _make_mesh_w2(SEQ_AXIS, num_workers, seq_shards, devices)
 
 
 def make_folded_wtp_mesh(num_workers: int) -> Mesh:
-    """(w, tp=1) mesh with the logical workers FOLDED onto the available
-    devices (runtime.make_mesh discipline: equal lane blocks per device, warns
-    when devices idle). The trivial tp axis makes the GSPMD LM builder
+    """(w, tp=1) mesh: the trivial tp axis makes the GSPMD LM builder
     (tp_step.build_tp_train_setup) applicable on any device count — the
-    single-chip n-lane vmapped regime the perf/convergence tools run in.
-    Distinct from make_mesh_wtp, which demands num_workers × shards physical
-    devices for real tensor sharding."""
-    from draco_tpu.runtime import make_mesh
-
-    fold = make_mesh(num_workers).devices.ravel()
-    return Mesh(np.asarray(fold).reshape(len(fold), 1), (WORKER_AXIS, TP_AXIS))
-
-
-def _make_mesh_w2(axis2: str, num_workers: int, shards: int,
-                  devices: Optional[Sequence[jax.Device]]) -> Mesh:
-    """(num_workers, shards) mesh with axes (w, axis2); the model-parallel
-    axis is innermost, riding the fastest ICI links (its collectives fire
-    several times per step; the worker-axis gather once)."""
-    devices = list(devices if devices is not None else jax.devices())
-    need = num_workers * shards
-    if len(devices) < need:
-        raise ValueError(
-            f"(w={num_workers}, {axis2}={shards}) mesh needs {need} devices, "
-            f"have {len(devices)}"
-        )
-    grid = np.asarray(devices[:need]).reshape(num_workers, shards)
-    return Mesh(grid, (WORKER_AXIS, axis2))
+    single-chip n-lane vmapped regime the perf/convergence tools run in."""
+    return make_mesh_wtp(num_workers, 1)
 
 
 def make_mesh_wtp(
@@ -72,7 +72,7 @@ def make_mesh_wtp(
     tensor_shards: int,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Mesh of shape (num_workers, tensor_shards) with axes (w, tp)."""
+    """(w, tp) mesh (_make_mesh_w2)."""
     return _make_mesh_w2(TP_AXIS, num_workers, tensor_shards, devices)
 
 
@@ -81,7 +81,7 @@ def make_mesh_wep(
     expert_shards: int,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Mesh of shape (num_workers, expert_shards) with axes (w, ep)."""
+    """(w, ep) mesh (_make_mesh_w2)."""
     return _make_mesh_w2(EP_AXIS, num_workers, expert_shards, devices)
 
 
@@ -90,5 +90,5 @@ def make_mesh_wpp(
     pipeline_shards: int,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Mesh of shape (num_workers, pipeline_shards) with axes (w, pp)."""
+    """(w, pp) mesh (_make_mesh_w2)."""
     return _make_mesh_w2(PP_AXIS, num_workers, pipeline_shards, devices)

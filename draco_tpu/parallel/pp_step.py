@@ -34,12 +34,11 @@ from typing import Any, NamedTuple, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from draco_tpu import optim, rng as drng
 from draco_tpu.coding import cyclic as cyclic_mod
-from draco_tpu.runtime import shard_map
 from draco_tpu.config import TrainConfig
 from draco_tpu.models.transformer import Block
 from draco_tpu.parallel.common import (
@@ -340,7 +339,7 @@ def build_pp_train_setup(cfg: TrainConfig, mesh) -> PPTrainSetup:
         agg, health = aggregate_flat_grads(grads, adv_mask, cfg, code,
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
-                                           step=state.step)
+                                           step=state.step, mesh=mesh)
         new_state, guard_cols = finish_flat_step(
             cfg, state, agg, health, opt, unravel, present=present,
             constrain=lambda p: _constrain_params(p, mesh, _leaf_spec),
@@ -390,7 +389,7 @@ def build_pp_train_setup(cfg: TrainConfig, mesh) -> PPTrainSetup:
 # contribute 4 all_reduce. Static op counts — layout-independent (same on
 # the 16-device chip audit and the folded 8-device CI mesh), shared with
 # tools/tpu_parallel_lowering_check.py; a legitimate schedule change
-# updates it HERE, once (PERF.md §6).
+# updates it HERE, once (PERF_HISTORY.md §6).
 LINT_COLLECTIVES = {"all_reduce": 4, "collective_permute": 2}
 
 

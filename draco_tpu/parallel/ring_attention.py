@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from draco_tpu.runtime import axis_size
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def ring_flash_attention(
         o, _ = attn_with_lse(q, k, v, causal=causal)
         return o
 
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -161,7 +160,7 @@ def ring_attention(
     if axis_name is None:
         return dense_attention(q, k, v, causal=causal)
 
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, t, h, dh = q.shape
     scale = 1.0 / (dh**0.5)

@@ -24,12 +24,11 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from draco_tpu import optim, rng as drng
 from draco_tpu.coding import cyclic as cyclic_mod
-from draco_tpu.runtime import shard_map
 from draco_tpu.config import TrainConfig
 from draco_tpu.models.transformer import TransformerLM
 from draco_tpu.parallel.a2a_attention import a2a_attention
@@ -295,7 +294,7 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
         agg, health = aggregate_flat_grads(grads, adv_mask, cfg, code,
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
-                                           step=state.step)
+                                           step=state.step, mesh=mesh)
         new_state, guard_cols = finish_flat_step(cfg, state, agg, health,
                                                  opt, unravel,
                                                  present=present)
@@ -340,7 +339,7 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
 # psums over sp. Static op counts — layout-independent (the 16-device
 # chip audit and the folded 8-device CI mesh observe the same counts), so
 # tools/tpu_parallel_lowering_check.py imports this same constant. A
-# legitimate schedule change updates it HERE, once (PERF.md §6).
+# legitimate schedule change updates it HERE, once (PERF_HISTORY.md §6).
 LINT_COLLECTIVES = {"all_reduce": 2, "collective_permute": 5}
 
 

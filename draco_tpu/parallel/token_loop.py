@@ -18,8 +18,8 @@ as the CNN ``Trainer`` (training/trainer.py):
   device runs the current one (``TokenChunkPrefetcher``). Per K steps the
   host pays ONE dispatch instead of K × (host token gen + device_put +
   dispatch) — this is what hides the ~70 ms/dispatch RTT of remote backends
-  (PERF.md §0/§4b) on the LM routes, where it was ~70 % of the flagship
-  step (PERF.md §1b).
+  (PERF_HISTORY.md §0/§4b) on the LM routes, where it was ~70 % of the flagship
+  step (PERF_HISTORY.md §1b).
 
 ``cfg.token_gen == "device"`` removes the host token path entirely: the
 scanned program regenerates each step's batch in-graph from the scalar

@@ -227,8 +227,8 @@ def _build_gspmd_train_setup(cfg: TrainConfig, mesh, *, mp_axis: str,
         attends keys <= i, so rows < T-1 cannot see token T-1). Feeding
         toks[:, :-1] instead would hand the attention a T-1-length
         sequence (1023 at T=1024), which fails the flash kernel's t%8
-        tiling and silently rode the dense fallback — the kernel never
-        actually ran on the LM path before this.
+        tiling (an error now; once a silent dense path — the kernel never
+        actually ran on the LM path before this).
 
         Deliberate deviation when moe_experts > 0: Switch capacity
         routing (models/moe.py) is cross-token over the flattened B*T
@@ -277,7 +277,7 @@ def _build_gspmd_train_setup(cfg: TrainConfig, mesh, *, mp_axis: str,
         agg, health = aggregate_flat_grads(grads, adv_mask, cfg, code,
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
-                                           step=state.step)
+                                           step=state.step, mesh=mesh)
         new_state, guard_cols = finish_flat_step(
             cfg, state, agg, health, opt, unravel, present=present,
             constrain=lambda p: _constrain_params(p, mesh, partition_fn),

@@ -9,7 +9,7 @@ segment names, checkpoint every eval_freq steps.
 Two execution regimes, selected by ``cfg.steps_per_call``:
 
 * K=1 (default): the eager per-step loop — one dispatch, one metrics fetch,
-  one ``block_until_ready`` per step. Honest on CPU (PERF.md §4: XLA:CPU
+  one ``block_until_ready`` per step. Honest on CPU (PERF_HISTORY.md §4: XLA:CPU
   serializes conv thunks inside scan bodies) and the bitwise reference for
   the chunked path.
 * K>1: the scan-chunked loop — ``train_many`` fuses K full coded steps into
@@ -19,7 +19,7 @@ Two execution regimes, selected by ``cfg.steps_per_call``:
   boundaries, and there is NO host sync in steady state. Eval/checkpoint
   cadence snaps to chunk boundaries via explicit remainder chunks, so
   ``max_steps`` need not divide by K. This is what hides the ~70 ms/dispatch
-  RTT of remote backends (PERF.md §0) behind useful device work.
+  RTT of remote backends (PERF_HISTORY.md §0) behind useful device work.
 """
 
 from __future__ import annotations

@@ -1,0 +1,134 @@
+"""Compile the main path's Pallas kernels for a TPU v5e that is described,
+not attached — with the chip's own compiler, at the real widths.
+
+Interpret mode and the cross-platform export (``jax.export`` with
+``platforms=["tpu"]``, tests/test_decode_kernels.py) both accept programs the
+chip's compiler refuses: the batch-first cyclic locator passed both for ten
+PRs and could not be built for the chip at all (PERF.md, chip bring-up). These
+cases run the real thing — Mosaic + the TPU backend of XLA, for the
+``v5e:2x2`` topology — in a second or two each and at no chip time. Nothing
+executes, so they say nothing about results; chip_smoke.py does that on the
+chip.
+
+Skipped where the topology cannot be described (no TPU compiler installed).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from draco_tpu.coding import approx as approx_mod
+from draco_tpu.coding import cyclic as cyclic_mod
+from draco_tpu.ops import decode_kernels as dk
+from draco_tpu.ops.flash_attention import flash_attention
+
+RESNET18_D = 11_173_962  # the cyclic-resnet18 preset's gradient length
+RESNET18_LEAVES = 62  # its parameter tensors: the per-layer locator batch
+FLASH_SHAPE = (2, 1024, 12, 64)
+BLOCK = 256  # cfg.shadow_block default: the int8 wire's scale granularity
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A ``SingleDeviceSharding`` on the first chip of a described v5e host,
+    with the persistent compilation cache off around the module: such a
+    compile is written to the cache but cannot be read back without a chip,
+    and the next one would warn."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this host
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _locator(n, s, lam=0.0):
+    code = cyclic_mod.build_cyclic_code(n, s)
+
+    def fn(e_re, e_im, pres):
+        return dk.cyclic_locator(code, e_re, e_im, pres,
+                                 cyclic_mod.HEALTH_REL_TOL, lam=lam)
+
+    col = ((n, RESNET18_LEAVES), jnp.float32)
+    return fn, [col, col, ((n, 1), jnp.float32)]
+
+
+def _approx(mode, n=9, d=RESNET18_D):
+    code = approx_mod.build_approx_code(n, 1.5)
+    nb = -(-d // BLOCK)
+
+    def fn(rows, bg, present, scale):
+        wire = {"f32": None, "bf16": ("bf16", {"q": rows}, BLOCK),
+                "int8": ("int8", {"q": rows, "scale": scale}, BLOCK)}[mode]
+        dec, _, health = approx_mod.decode(
+            code, rows.astype(jnp.float32), present=present,
+            with_health=True, batch_grads=bg, impl="pallas", wire=wire)
+        return dec, health["residual"]
+
+    wire_dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+                  "int8": jnp.int8}[mode]
+    return fn, [((n, d), wire_dtype), ((n, d), jnp.float32), ((n,), bool),
+                ((n, nb), jnp.float32)]
+
+
+def _recombine(mode, n=9, d=RESNET18_D):
+    nb = -(-d // BLOCK)
+
+    def fn(v_re, v_im, q_re, q_im, s_re, s_im):
+        bufs = (({"q": q_re}, {"q": q_im}) if mode == "bf16" else
+                ({"q": q_re, "scale": s_re}, {"q": q_im, "scale": s_im}))
+        return dk.cyclic_narrow_recombine(v_re, v_im, (mode, *bufs, BLOCK))
+
+    wire_dtype = jnp.bfloat16 if mode == "bf16" else jnp.int8
+    return fn, [((n,), jnp.float32)] * 2 + [((n, d), wire_dtype)] * 2 + [
+        ((n, nb), jnp.float32)] * 2
+
+
+def _flash(grad):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, force=True)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(fwd(q, k, v))),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (bwd if grad else fwd), [(FLASH_SHAPE, jnp.float32)] * 3
+
+
+CASES = {
+    # auto selects these on the chip: the three presets' codes + the narrow
+    # wire's regularized locator (n=9 s=2 is cyclic-vgg11, n=8 the LM runs)
+    "cyclic_locator_n9_s1": lambda: _locator(9, 1),
+    "cyclic_locator_n9_s2": lambda: _locator(9, 2),
+    "cyclic_locator_n8_s1": lambda: _locator(8, 1),
+    "cyclic_locator_n9_s1_lam": lambda: _locator(9, 1, lam=2.0 ** -7),
+    "approx_decode_f32": lambda: _approx("f32"),
+    "approx_decode_bf16": lambda: _approx("bf16"),
+    "approx_decode_int8": lambda: _approx("int8"),
+    "cyclic_recombine_bf16": lambda: _recombine("bf16"),
+    "cyclic_recombine_int8": lambda: _recombine("int8"),
+    "flash_fwd": lambda: _flash(grad=False),
+    "flash_grad": lambda: _flash(grad=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    fn, specs = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # a kernel that quietly became plain XLA would pass a compile
+    assert "tpu_custom_call" in compiled.as_text(), case

@@ -783,26 +783,20 @@ def test_perf_watch_passes_on_committed_artifacts():
 
 def test_lm_lowering_audit_matches_r5_rung():
     """Drift guard (r5 review): the offline lowering audit hardcodes the
-    lm_big rung shapes because the chain script cannot be edited while it
-    runs — so this test is the sync mechanism. If either side changes, it
-    fails and points at the other."""
+    lm_big shapes next to the tools/tpu_lm_perf.py command lines that
+    measure them on the chip (LM_BIG_RUNG) — this test is the sync
+    mechanism. If either side changes, it fails and points at the other."""
     import re
 
     from tools.tpu_lm_lowering_check import (
-        LM_BIG, LM_BIG_VARIANTS_B1, LM_BIG_VARIANTS_B2,
+        LM_BIG, LM_BIG_RUNG, LM_BIG_VARIANTS_B1, LM_BIG_VARIANTS_B2,
     )
-
-    sh = open(os.path.join(os.path.dirname(__file__), "..",
-                           "tools", "chip_jobs_r5.sh")).read()
-    m = re.search(r"rung lm_big .*?'(.*?)'", sh, re.S)
-    assert m, "lm_big rung not found in chip_jobs_r5.sh"
-    rung = m.group(1)
 
     def flag(name, text):
         fm = re.search(rf"--{name}\s+(\S+)", text)
         return fm and fm.group(1)
 
-    legs = rung.split("&&")
+    legs = LM_BIG_RUNG
     assert len(legs) == 2, "expected the b=2 leg and the b=1 simulate leg"
     for leg, bsz, variants in ((legs[0], "2", LM_BIG_VARIANTS_B2),
                                (legs[1], "1", LM_BIG_VARIANTS_B1)):

@@ -7,7 +7,7 @@ capture smoke on the CPU mesh.
 
 The committed fixture (tests/data/device_profile_fixture/) is a synthetic
 jax.profiler capture in the XLA:CPU fallback trace shape this container
-produces (PERF.md §12): hlo_module/hlo_op args on each complete event, the
+produces (PERF_HISTORY.md §12): hlo_module/hlo_op args on each complete event, the
 named-scope path only in the runner-dumped scope map, a nested ``call``
 wrapper on one thread, a GSPMD collective, and an op absent from the scope
 map entirely (the honest ``unattributed`` row).
@@ -195,7 +195,7 @@ def test_fold_capture_fixture_end_to_end():
     assert block["profiled_steps"] == 5
     # the fixture's scope map stamps flops_per_step, so the achieved rate
     # is computable; the CPU fallback has no honest peak so the fraction
-    # stays None (PERF.md §12)
+    # stays None (PERF_HISTORY.md §12)
     assert block["achieved_flops_per_s"] == pytest.approx(
         1.0e6 * 5 / (1250.0 / 1e6))
     assert block["achieved_flops_frac"] is None

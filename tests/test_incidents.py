@@ -35,7 +35,7 @@ def rec(step, accused=0, present=0b11111111, adv=None, **cols):
 def test_detector_registry_enumerable():
     """The detector set is declaratively registered: every spec names a
     severity, a source, and a thresholds dict carrying the hysteresis
-    pair — the enumerability the chaos matrix and PERF.md §15 rest on."""
+    pair — the enumerability the chaos matrix and PERF_HISTORY.md §15 rest on."""
     table = inc.detector_table()
     names = {t["name"] for t in table}
     assert {"throughput", "decode_residual", "trust", "guard", "nonfinite",

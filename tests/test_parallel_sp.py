@@ -7,9 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from draco_tpu.runtime import shard_map
 
 from draco_tpu.config import TrainConfig
 from draco_tpu.parallel import make_mesh_2d, ring_attention

@@ -49,7 +49,7 @@ def test_partition_rules():
     # the placement actually applied, not just computed — in the NORMALIZED
     # spelling (trailing Nones stripped, tp_step._norm_spec): the applied
     # shardings are pinned to the form XLA reports back, so the K-fused
-    # carry cannot retrace against its own output layout (PERF.md §9)
+    # carry cannot retrace against its own output layout (PERF_HISTORY.md §9)
     from draco_tpu.parallel.tp_step import _norm_spec
 
     for key, (want, got) in seen.items():

@@ -59,11 +59,14 @@ def test_lam_zero_paths_bitwise():
     x0 = linalg_mod.truncated_lstsq(a, b, 1e-5)
     x1 = linalg_mod.truncated_lstsq(a, b, 1e-5, lam=0.0)
     assert np.array_equal(np.asarray(x0), np.asarray(x1))
-    ab = jnp.asarray(rs.randn(4, 6, 6).astype(np.float32))
-    bb = jnp.asarray(rs.randn(4, 6).astype(np.float32))
+    # fused tier: batch-last nested (1, B) entry rows (coding/linalg.py)
+    ab = [[jnp.asarray(rs.randn(1, 4).astype(np.float32)) for _ in range(6)]
+          for _ in range(6)]
+    bb = [jnp.asarray(rs.randn(1, 4).astype(np.float32)) for _ in range(6)]
     j0 = linalg_mod.jacobi_lstsq(ab, bb, 1e-5)
     j1 = linalg_mod.jacobi_lstsq(ab, bb, 1e-5, lam=0.0)
-    assert np.array_equal(np.asarray(j0), np.asarray(j1))
+    assert all(np.array_equal(np.asarray(u), np.asarray(v))
+               for u, v in zip(j0, j1))
     ar, ai = (jnp.asarray(rs.randn(5, 5).astype(np.float32))
               for _ in range(2))
     br, bi = (jnp.asarray(rs.randn(5).astype(np.float32)) for _ in range(2))
@@ -133,7 +136,7 @@ def test_real_wire_matches_shadow_quantizer_bitwise():
     quantizer (obs/numerics.quantize_rows) under every mode — nearest and
     shared-draw stochastic, bf16 and int8, ragged block tail included.
     This is the 'calibration transfers' contract: the committed shadow
-    study (PERF.md §13) priced exactly the arithmetic the real wire ships,
+    study (PERF_HISTORY.md §13) priced exactly the arithmetic the real wire ships,
     so the two implementations may never drift apart."""
     x = np.random.RandomState(3).randn(5, 1000).astype(np.float32)
     x[0, 7] = np.inf

@@ -800,14 +800,14 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-mesh", type=int, default=0, metavar="N",
                     help="force an N-device virtual CPU mesh")
     args = ap.parse_args(argv)
-    # cpu-mesh bootstrap only, NEVER the persistent compile cache: the
-    # chaos matrix classifies outcomes by BITWISE final-state comparison,
-    # and cache-enabled XLA:CPU executables corrupt donated carries
-    # (mutating output state, NaNs in later checkpoints — caught by this
-    # very harness; runtime.enable_compile_cache docstring). Runs are tiny,
-    # so compiling uncached costs seconds.
+    # the shared bootstrap (compile cache + cpu mesh). The matrix classifies
+    # outcomes by BITWISE final-state comparison; the donated-carry
+    # corruption that once kept cached XLA:CPU executables out of it
+    # (jax 0.4.x) does not reproduce on jax 0.9.0 — cold- and warm-cache
+    # runs of the sigterm / ckpt_corrupt / nan_grad / prefetch_crash cells
+    # classify identically (PERF.md, chip bring-up)
     if args.cpu_mesh:
-        maybe_force_cpu_mesh(args)  # skips the cache in explicit CPU mode
+        maybe_force_cpu_mesh(args)
 
     loops = _loops()
     pick_loops = [s for s in args.loops.split(",") if s] or list(loops)

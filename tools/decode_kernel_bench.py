@@ -26,7 +26,7 @@ at the time tolerance, and rungs marked ``gate: true`` additionally pin
 ``kernel_not_slower`` (ratio ≤ 1) at tolerance 0 — the fused path
 regressing below the XLA path at a committed rung fails the round
 (flipped-row tests in tests/test_cli_tools.py prove the gate live). Two
-cyclic rung classes are deliberately ungated on CPU fallbacks (PERF.md
+cyclic rung classes are deliberately ungated on CPU fallbacks (PERF_HISTORY.md
 §14): the GLOBAL rungs — two near-memory-floor (n, d) matvec passes with
 the locator at ~3% of them, nothing for the CPU fallback to win — and the
 n=32 LAYER rung, where the per-segment matvec cost dominates both impls

@@ -35,7 +35,7 @@ Folded by ``tools/perf_watch.py``: phase-fraction metrics at the time-kind
 tolerance (a decode-share regression gates round-over-round), collective
 instruction/byte counts pinned at tolerance 0.
 
-CPU-fallback caveat (PERF.md §8c/§12): on this container the capture is the
+CPU-fallback caveat (PERF_HISTORY.md §8c/§12): on this container the capture is the
 XLA:CPU trace shape — attribution works through the runner-dumped scope map
 (optimized-HLO metadata), absolute times are not chip times, and there is
 no honest hardware peak, so roofline rows carry achieved rates without
@@ -67,7 +67,7 @@ NUM_DEVICES = 8
 # cost columns the fold joins, config overrides). The K=4 cells join the
 # closest registered row: collective counts are per-instruction (a K-fused
 # scan compiles its body once, so they are K-independent) and the linter's
-# flops column counts the scan body once (per-step figure) — PERF.md §8.
+# flops column counts the scan body once (per-step figure) — PERF_HISTORY.md §8.
 CELLS = {
     "cnn_cyclic_k1": ("cnn", 1, "cnn_cyclic_step", {}),
     "cnn_cyclic_k4": ("cnn", 4, "cnn_cyclic_many_k2", {}),
@@ -89,7 +89,7 @@ CELLS = {
     # shapes as an xla-path pair cell, so the "decode share dropped"
     # claim is a committed, diffed artifact. On this container the pallas
     # dispatch runs the kernels' fused reference lowering (CPU fallback,
-    # ops/decode_kernels.resolve_decode_impl; PERF.md §14).
+    # ops/decode_kernels.resolve_decode_impl; PERF_HISTORY.md §14).
     "cnn_approx_pallas_k1": ("cnn", 1, "cnn_approx_pallas_step",
                              dict(approach="approx", worker_fail=0,
                                   redundancy="shared", code_redundancy=1.5,
@@ -117,7 +117,7 @@ CELLS = {
     # the fused path running the production loop end-to-end; NO
     # share-drop claim on the CPU fallback (the layer decode there is at
     # the per-segment matvec floor, within noise of the xla path — the
-    # cyclic kernel's win is TPU-side HBM traffic, PERF.md §14), so this
+    # cyclic kernel's win is TPU-side HBM traffic, PERF_HISTORY.md §14), so this
     # pair is absent from PALLAS_CLAIMS.
     "cnn_cyclic_layer_k1": ("cnn", 1, "cnn_cyclic_layer_step",
                             dict(decode_granularity="layer")),
@@ -134,7 +134,7 @@ CELLS = {
 # move ±3% with XLA:CPU fusion-attribution noise (eager k1 even inverts —
 # the true-mean matvec cannot fuse into the grads producer the way the
 # xla path's axis-0 reduction does), so those pallas cells are committed
-# as same-shape evidence WITHOUT the claim (PERF.md §14; the robust CPU
+# as same-shape evidence WITHOUT the claim (PERF_HISTORY.md §14; the robust CPU
 # evidence for the decode itself is decode_kernel_bench.json).
 PALLAS_CLAIMS = {
     "lm_sp_approx_pallas_k4": "lm_sp_approx_k4",
@@ -392,7 +392,7 @@ def fold_all(work: str, cells: list, root: str) -> dict:
         ),
         "profile_steps": list(PROFILE_STEPS),
         "devices": NUM_DEVICES,
-        "cpu_fallback": True,  # this container has no TPU (PERF.md §8c)
+        "cpu_fallback": True,  # this container has no TPU (PERF_HISTORY.md §8c)
         "all_ok": all(r.get("ok") for r in rows),
         "cells": rows,
     }

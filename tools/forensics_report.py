@@ -3,7 +3,7 @@
 
 The coded training steps ship their per-worker accusation, presence, and
 seeded-adversary masks as packed bitmask columns riding the metric block
-(draco_tpu/obs/forensics.py, PERF.md §10). This tool replays the host
+(draco_tpu/obs/forensics.py, PERF_HISTORY.md §10). This tool replays the host
 ledger over a run's ``metrics.jsonl`` — per-worker accusation counters,
 detection precision/recall vs the seeded schedule, exponentially-weighted
 trust, and attack **episodes** ("worker 3 was adversarial for steps

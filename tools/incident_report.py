@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Replay a run's incident ledger offline and diff it against the live one.
 
-The incident engine (draco_tpu/obs/incidents.py, PERF.md §15) folds the
+The incident engine (draco_tpu/obs/incidents.py, PERF_HISTORY.md §15) folds the
 per-step metric column families into typed, attributed incident episodes
 live, streaming onset/offset events to ``train_dir/incidents.jsonl``. This
 tool is its offline twin — the same discipline as forensics_report.py:

@@ -18,6 +18,10 @@ Usage:
 Each child gets DRACO_COORDINATOR / DRACO_NUM_PROCESSES / DRACO_PROCESS_ID
 (read by draco_tpu.runtime.init_distributed) and an XLA host-device count of
 ``-d``. Exit code is the first non-zero child exit code.
+
+CPU only: a chip belongs to one process at a time, and this launcher starts
+several — so every child runs with ``JAX_PLATFORMS=cpu``, and an environment
+that asks for anything else is refused rather than overridden.
 """
 
 from __future__ import annotations
@@ -46,7 +50,14 @@ def launch(num_processes: int, devices_per_process: int, cmd: list[str],
         base.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={devices_per_process}"
     ).strip()
-    base.setdefault("JAX_PLATFORMS", "cpu")
+    asked = base.get("JAX_PLATFORMS", "cpu").strip().lower()
+    if asked != "cpu":
+        raise ValueError(
+            f"local_cluster simulates a cluster on virtual CPU devices and "
+            f"starts {num_processes} processes; a chip belongs to one "
+            f"process at a time. Refusing JAX_PLATFORMS={asked!r}: unset it "
+            f"or set it to 'cpu'.")
+    base["JAX_PLATFORMS"] = "cpu"
 
     # Each child writes to its own temp file, never a pipe: collectives keep
     # all children in lock-step, so a child blocked on a full pipe buffer

@@ -17,7 +17,7 @@ artifact.
 The memory_budget rows double as the per-program memory/cost LEDGER
 (argument/output/temp/generated-code bytes, peak estimate, analytic
 flops): the committed artifact is what tools/perf_watch.py diffs
-round-over-round (PERF.md §8).
+round-over-round (PERF_HISTORY.md §8).
 
   python tools/program_lint.py [--out baselines_out/program_lint.json]
       [--fast] [--programs name|regex,...] [--only rule,...]
@@ -26,7 +26,7 @@ round-over-round (PERF.md §8).
 ``--fast`` skips the non-fast programs (currently only the big-d
 constant-bloat guard, which builds ~3.3M params); the fast subset runs in
 roughly a minute on the CI host and is what the ``core``-tier test
-exercises (tests/test_program_lint.py, PERF.md §6).
+exercises (tests/test_program_lint.py, PERF_HISTORY.md §6).
 
 The report is rewritten after every row (incremental-artifact discipline);
 bench.py refuses to record a chip run while this artifact reports a

@@ -2,7 +2,7 @@
 """Time-to-accuracy measurement (BASELINE.md's second north-star axis).
 
 The bench image has no real MNIST/CIFAR files and no network egress
-(documented in PERF.md): the strongest available substitute is the
+(documented in PERF_HISTORY.md §5): the strongest available substitute is the
 deterministic class-conditional synthetic sets (draco_tpu/data/datasets.py
 ``_synthetic`` — learnable, with a held-out test split), standing in for the
 reference's convergence oracle (src/distributed_evaluator.py:92-110).
@@ -52,7 +52,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-steps", type=int, default=1500)
     ap.add_argument("--steps-per-call", type=int, default=1,
                     help="K steps fused per device program (the production "
-                         "scan-chunked loop); keep 1 on CPU (PERF.md §4)")
+                         "scan-chunked loop); keep 1 on CPU (PERF_HISTORY.md §4)")
     ap.add_argument("--cpu-mesh", type=int, default=0)
     args = ap.parse_args(argv)
 

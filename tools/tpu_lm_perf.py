@@ -19,11 +19,11 @@ the GSPMD LM path, parallel/tp_step.py):
 Timing: utils/timing.py protocol — steps folded into ONE jitted lax.scan
 over pre-staged token batches, device→host fetch sync, minus RTT. FLOPs
 from XLA cost analysis of the compiled scan (counts the body once). Run
-with the host otherwise idle (PERF.md §4).
+with the host otherwise idle (PERF_HISTORY.md §4).
 
 ``--production-loop`` re-times the same variants on the PRODUCTION chunked
 token loop (parallel/token_loop.run_token_loop driving train_token_many
-with --steps-per-call, PERF.md §4b) instead of this tool's private scan
+with --steps-per-call, PERF_HISTORY.md §4b) instead of this tool's private scan
 harness — since the production loop became scan-chunked the two measure the
 same fold, and the artifact records ``steps_per_call``/``loop`` so which one
 produced each number is explicit.
@@ -236,7 +236,7 @@ def main(argv=None) -> int:
     ap.add_argument("--scan-layers", action="store_true",
                     help="compile the layer stack as one nn.scan body — "
                          "~layers× smaller XLA program, for configs that "
-                         "hit compile-time/service ceilings (PERF.md §4)")
+                         "hit compile-time/service ceilings (PERF_HISTORY.md §4)")
     ap.add_argument("--variants", type=str, default="",
                     help="comma-separated subset of variants to run")
     ap.add_argument("--production-loop", action="store_true",
@@ -307,7 +307,10 @@ def main(argv=None) -> int:
                  else "private_scan_harness"),
         "token_gen": args.token_gen if args.production_loop else "host",
     }
-    peak = bench._peak_flops(report["device_kind"])
+    # no peak, hence no MFU, for the --cpu-mesh plumbing smoke; on a TPU an
+    # unknown device_kind is an error (bench._PEAK_BF16)
+    peak = (bench._peak_flops(report["device_kind"])
+            if report["platform"] == "tpu" else None)
     for name, kw in variants.items():
         print(f"[tpu_lm_perf] measuring {name} ...", file=sys.stderr, flush=True)
         t0 = time.time()

@@ -97,7 +97,7 @@ def main(argv=None) -> int:
 
     # explicit-collective budgets per axis (the hop structure is the row's
     # claim), imported from the owning route modules so a legitimate
-    # schedule change is a ONE-file manifest edit (PERF.md §6): the sp ring
+    # schedule change is a ONE-file manifest edit (PERF_HISTORY.md §6): the sp ring
     # budget covers both attention inners (dense and flash — the hop
     # structure is inner-independent), the pipeline brings its tick
     # schedule + loss/grad psums, and tp/ep are pure GSPMD (collectives

@@ -307,7 +307,7 @@ def locator_cell(n: int, s: int, dtype: str, lam: float) -> dict:
     extrapolated under a live adversary cross the flag line, so detection
     RECALL holds (adv_dev_min > threshold) while flag PRECISION degrades
     in the adversary regime at large (n, s). A measured limit, documented
-    in PERF.md §17 and the WIRE_REL_TOL_TABLE comment, not silently
+    in PERF_HISTORY.md §17 and the WIRE_REL_TOL_TABLE comment, not silently
     absorbed into the certificate."""
     import jax.numpy as jnp
     import numpy as np
