@@ -45,9 +45,11 @@ class TrainerChunkClient:
         return self.tr._device_chunk(
             ranges[i], ranges[i + 1] if i + 1 < len(ranges) else None)
 
-    def dispatch(self, state, chunk):
+    def program(self, state, chunk):
         xs, ys, masks, presents = chunk
-        return self.setup.train_many(state, xs, ys, masks, presents)
+        fn, args = self.setup.train_many, (state, xs, ys, masks, presents)
+        self.tr._note_dispatch(self.label, fn, args, key=len(masks))
+        return fn, args
 
     def defer_extras(self, chunk, fetch_s, k):
         extras = {"t_fetch": round(fetch_s / k, 6)}
@@ -165,9 +167,9 @@ class TokenChunkClient:
             )
         return toks, masks, presents
 
-    def dispatch(self, state, chunk):
+    def program(self, state, chunk):
         toks, masks, presents = chunk
-        return self.setup.train_token_many(state, toks, masks, presents)
+        return self.setup.train_token_many, (state, toks, masks, presents)
 
     def defer_extras(self, chunk, fetch_s, k):
         return None

@@ -27,7 +27,11 @@ loops):
   assemble(i, ranges)         build + upload chunk i's payload (client
                               does its own gather/upload tracer spans and
                               double-buffering)
-  dispatch(state, payload)    run the chunk program -> (state, block)
+  program(state, payload)     the chunk program and its call's arguments
+                              -> (jitted callable, args); the engine calls
+                              it -> (state, block), and hands the pair to
+                              the profiler window, which writes the scope
+                              map of what ran (obs/profiling.py)
   defer_extras(payload, fetch_s, k)  extra per-chunk record fields
                               (t_fetch, present counts) or None
   should_log(step)            the loop's metrics.jsonl cadence
@@ -136,9 +140,12 @@ class ChunkedEngine:
                 seg = int(getattr(client, "wire_segments", 1) or 1)
                 if seg > 1:
                     span_kw["segments"] = seg
+                fn, args = client.program(state, chunk)
+                win.note_program(client.label, fn, args, key=k)
                 with tracer.span("dispatch", **span_kw), \
                         watch.expect(client.label, key=k):
-                    state, block = client.dispatch(state, chunk)
+                    state, block = fn(*args)
+                del args  # the donated carry must not outlive its call
                 self.state, self.last_end = state, end
                 deferred.defer(range(start, end + 1), client.metric_names,
                                block, client.defer_extras(chunk, fetch_s, k))
@@ -155,8 +162,7 @@ class ChunkedEngine:
                         # device→host fetch, NOT block_until_ready — the
                         # latter only awaits dispatch on remote backends,
                         # PERF_HISTORY.md §0); this is the boundary's one true sync
-                        with tracer.span("sync", at_step=end):
-                            deferred.sync()
+                        self._sync(end)
                         t_comp = max(time.perf_counter() - window_t0
                                      - window_fetch, 0.0)
                         common = {"t_comp": round(t_comp / window_steps, 6)}
@@ -186,8 +192,7 @@ class ChunkedEngine:
                     # drain the pending metric blocks first, then snap the
                     # resumable checkpoint exactly here
                     if self.timed:
-                        with tracer.span("sync", at_step=end):
-                            deferred.sync()
+                        self._sync(end)
                     with tracer.span("flush", at_step=end):
                         deferred.flush(client.should_log)
                     client.snap_stop(end, state, bool(boundary))
@@ -198,6 +203,18 @@ class ChunkedEngine:
             finally:
                 client.cleanup()
         return state, deferred.last
+
+    def _sync(self, end: int) -> None:
+        """The boundary's one true sync, in the eager loop's two parts:
+        ``device_wait`` (the newest chunk's outputs ready) then ``drain``
+        (the device->host fetch that proves execution on a remote backend;
+        the flush span that follows materialises the blocks)."""
+        tracer, deferred = self.tracer, self.deferred
+        with tracer.span("sync", at_step=end):
+            with tracer.span("device_wait", at_step=end):
+                deferred.wait()
+            with tracer.span("drain", at_step=end, columns=deferred.depth):
+                deferred.sync()
 
 
 class SegmentPipeline:

@@ -8,11 +8,17 @@ production north star needs, built under the PR 1–2 invariant: **no new
 device fetches in steady state** and zero overhead when disabled.
 
   tracer.py     SpanTracer — Chrome-trace-event host spans
-                (gather/upload/dispatch/sync/flush/eval/ckpt + prefetcher
-                worker-thread lanes + queue-depth counters) written to
-                ``trace_dir/trace.json``, loadable in Perfetto / chrome://
-                tracing; ``NULL_TRACER`` is the allocation-free disabled
-                path every loop runs by default.
+                (gather/upload/dispatch/sync/flush/eval/ckpt, sync's two
+                parts device_wait and drain, book between two steps, +
+                prefetcher worker-thread lanes + queue-depth counters)
+                written to ``trace_dir/trace.json``, loadable in Perfetto /
+                chrome://tracing; an open span is also a
+                ``jax.profiler.TraceAnnotation``, so a profiler capture
+                holds the spans on its own clock; ``NULL_TRACER`` is the
+                allocation-free disabled path every loop runs by default.
+                The eager loop's per-step record carries the same ledger
+                as seconds (utils/metrics.Segments): ``t_fetch``,
+                ``t_comp`` = ``t_dispatch + t_wait + t_drain``, ``t_book``.
   heartbeat.py  RunHeartbeat — ``train_dir/status.json`` rewritten
                 atomically at every flush boundary (step, steps/s, ETA,
                 last loss, decode health, prefetch queue depth, compile
@@ -26,11 +32,18 @@ device fetches in steady state** and zero overhead when disabled.
                 registered program after its warmup build.
   profiling.py  profiler_window — the ONE jax.profiler start/stop window
                 both production loops run (drain-before-stop + the
-                wall-clock anchor the merged host+device timeline needs);
-                previously four copy-pasted blocks (ISSUE 9).
-  device_attr.py  The device-side half of the spine (ISSUE 9, jax-free):
-                parses a jax.profiler capture into the per-phase chip
-                ledger (draco_comp/encode/decode/update + explicit
+                wall-clock anchor the merged host+device timeline needs,
+                stamped inside a ``draco_anchor`` annotation); on stop it
+                writes ``device_scope_map.json`` — instruction -> scope —
+                from the programs the loop dispatched in the window
+                (train_step, train_many[k], the token loop's), lowered
+                from the call's own arguments.
+  device_attr.py  The device-side half of the spine (ISSUE 9; jax-free but
+                for reading an ``.xplane.pb``): parses a jax.profiler
+                capture — the chip's xplane, whose events are named by
+                their instruction's HLO text, or XLA:CPU's — into the
+                per-phase chip ledger (draco_comp / pack / input / attack
+                / health / encode / decode / update + explicit
                 residual, rows summing to the profiled window), the
                 collective comms ledger cross-checked against the PR 3
                 Manifest counts (mismatch = hard error), and the merged

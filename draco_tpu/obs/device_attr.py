@@ -2,36 +2,54 @@
 per-collective ledgers (ISSUE 9 — the device-side half of the telemetry
 spine).
 
-PR 4 planted ``jax.named_scope`` phases (``draco_comp`` / ``draco_encode`` /
-``draco_decode`` / ``draco_update``) in every step body and ``--profile-dir``
-captures jax.profiler traces, but nothing parsed them: all attribution was
-host-side spans around opaque jitted dispatches. This module closes the gap
-**without importing jax** — it is pure artifact folding, importable from the
-jax-free tools (tools/device_profile.py, tools/trace_report.py) and usable on
-a laptop against capture dirs scp'd from a chip job.
+Every op of a step program sits under one ``jax.named_scope`` phase
+(``draco_comp`` / ``draco_pack`` / ``draco_input`` / ``draco_attack`` /
+``draco_health`` / ``draco_encode`` / ``draco_decode`` / ``draco_update`` —
+training/step.py, parallel/common.py) and ``--profile-dir`` captures a
+jax.profiler trace of a window of steps. This module folds such a capture
+into the ledgers. Apart from reading an ``.xplane.pb`` (which takes
+``jax.profiler.ProfileData``, imported inside the reader) it **imports no
+jax** — it is pure artifact folding, importable from the jax-free tools
+(tools/device_profile.py, tools/trace_report.py).
 
 Capture shapes handled
 ----------------------
 
-jax.profiler writes ``profile_dir/plugins/profile/<ts>/*.trace.json.gz`` — a
-Chrome-trace-event dump. Two event shapes exist:
+jax.profiler writes ``profile_dir/plugins/profile/<ts>/<host>.xplane.pb``
+(and, on the CPU backend, a ``.trace.json.gz`` Chrome dump beside it).
+:func:`load_trace` turns either into one list of Chrome-style event dicts,
+``args.hlo_op`` = the *optimized*-HLO instruction name and
+``args.hlo_module`` = the program:
 
-* **XLA:CPU fallback (this container, PERF_HISTORY.md §8c):** each executed HLO op
-  is one complete event whose ``args`` carry only ``hlo_module`` (e.g.
-  ``jit_many_body``) and ``hlo_op`` (the *optimized*-HLO instruction name,
-  e.g. ``dot.2`` / ``fusion.17``). The named-scope path is NOT in the event —
-  it lives in the compiled executable's HLO metadata
-  (``metadata={op_name="jit(f)/.../draco_decode/dot_general"}``). Attribution
-  therefore needs a **scope map**: optimized-instruction name → draco phase,
-  parsed from ``compiled.as_text()`` by :func:`scope_map_from_hlo` and dumped
-  next to the capture (``device_scope_map.json``) by the profiled run
-  (tools/device_profile.py ``--run-cell``). Because XLA:CPU compilation is
-  deterministic for a fixed program, the re-compiled text names match the
-  executed trace's names — and a drift would be loud, not silent: unmatched
-  ops land in the ``unattributed`` row, never in a phase.
-* **TPU (XProf) traces** carry the full scope path in the event itself; ops
-  whose name/args embed a ``draco_*`` segment attribute directly, scope map
-  optional.
+* **TPU (.xplane.pb, looked at by hand in PERF.md §6):** each chip is a
+  plane ``/device:TPU:<i>``; its line ``XLA Ops`` holds one event per
+  executed op, whose NAME is the instruction's whole HLO text
+  (``%fusion.16 = (bf16[8,32,...``) — the instruction name is the part
+  before ``" = "``. The line ``XLA Modules`` holds one event per program
+  execution; an op belongs to the module event that contains it. A TPU
+  event carries **no** named-scope path.
+* **XLA:CPU (.xplane.pb or .trace.json.gz):** the executor threads are
+  lines of ``/host:CPU``; an op event carries ``hlo_module`` and ``hlo_op``
+  as stats / args (e.g. ``jit_many_body``, ``fusion.17``).
+* **Host annotations:** while a profiler window is open the span tracer's
+  spans are ``TraceAnnotation`` events on ``/host:CPU`` too (obs/tracer.py),
+  and the window's ``draco_anchor`` annotation marks the instant
+  ``host_anchor.json`` stamps (obs/profiling.py). They come back with
+  ``cat="host"``: the program's own spans on the profiler's clock.
+
+In every shape the named-scope path is NOT in the event — it lives in the
+compiled executable's HLO metadata
+(``metadata={op_name="jit(f)/.../draco_decode/dot_general"}``). Attribution
+therefore needs a **scope map**: optimized-instruction name → draco phase,
+parsed from ``compiled.as_text()`` by :func:`scope_map_from_hlo`. The
+profiler window writes it beside the capture (``device_scope_map.json``)
+for every program the loop dispatched in the window, from that call's own
+arguments (obs/profiling.ProfilerWindow). Compilation is deterministic for
+a fixed program, so the text's names match the executed trace's — and a
+drift would be loud, not silent: unmatched ops land in the ``unattributed``
+row, never in a phase. A fusion carries its root's scope; an instruction
+the compiler made itself (a concatenate rewritten into update-slices, an
+async copy) has no metadata at all and lands in ``other``.
 
 Accounting rule (the "provably sums" contract)
 ----------------------------------------------
@@ -42,7 +60,8 @@ naive duration sums double-count. Attribution uses per-thread **self time**:
 each event's duration minus the durations of events nested inside it on the
 same thread. Per program, the ledger rows
 
-  draco_comp + draco_encode + draco_decode + draco_update
+  draco_comp + draco_pack + draco_input + draco_attack + draco_health
+  + draco_encode + draco_decode + draco_update
   + other (mapped op, no draco scope) + unattributed (op not in the map)
 
 sum EXACTLY to the program's total device self-time in the profiled window —
@@ -77,11 +96,20 @@ import gzip
 import json
 import os
 import re
+import warnings
 from typing import Optional
 
-# the named-scope phases every step body carries (PR 4; training/step.py +
-# parallel/common.py) — ledger row order
-PHASES = ("draco_comp", "draco_encode", "draco_decode", "draco_update")
+# the named-scope phases every step body carries (training/step.py +
+# parallel/common.py) — ledger row order: the gradient, its packing into
+# the stack, the step's rng-derived inputs, the simulated adversary, the
+# health columns, then the code and the update
+PHASES = ("draco_comp", "draco_pack", "draco_input", "draco_attack",
+          "draco_health", "draco_encode", "draco_decode", "draco_update")
+SCOPE_MAP_FILE = "device_scope_map.json"
+# obs/profiling.ANCHOR_EVENT (not imported: this module pulls in no sibling)
+ANCHOR_EVENT = "draco_anchor"
+# the TPU planes' lines (PERF.md §6)
+_TPU_OPS_LINE, _TPU_MODULES_LINE = "XLA Ops", "XLA Modules"
 # residual rows: "other" = op mapped by the scope map but under no draco
 # scope (optimizer glue, schedule slicing, metric folds), "unattributed" =
 # op absent from the scope map entirely (post-scheduling copies, or a
@@ -199,20 +227,93 @@ def scope_map_from_hlo(hlo_text: str) -> dict:
 # --------------------------------------------------------------------------
 
 def find_capture(profile_dir: str) -> Optional[str]:
-    """Newest ``*.trace.json.gz`` (or ``.trace.json``) under the jax
-    profiler layout ``profile_dir/plugins/profile/<ts>/``; None when the
-    directory holds no capture (tolerated, like a missing metrics.jsonl)."""
-    pats = (os.path.join(profile_dir, "plugins", "profile", "*",
-                         "*.trace.json.gz"),
-            os.path.join(profile_dir, "plugins", "profile", "*",
-                         "*.trace.json"))
-    hits = [p for pat in pats for p in glob.glob(pat)]
-    return max(hits, key=os.path.getmtime) if hits else None
+    """Newest capture under the jax profiler layout
+    ``profile_dir/plugins/profile/<ts>/``: the ``*.xplane.pb`` (what a chip
+    run leaves) where there is one, else a ``*.trace.json(.gz)``; None when
+    the directory holds no capture (tolerated, like a missing
+    metrics.jsonl)."""
+    for ext in ("*.xplane.pb", "*.trace.json.gz", "*.trace.json"):
+        hits = glob.glob(os.path.join(profile_dir, "plugins", "profile",
+                                      "*", ext))
+        if hits:
+            return max(hits, key=os.path.getmtime)
+    return None
 
 
-def load_trace(path: str) -> "tuple[list, dict]":
-    """(events, top-level payload) from a Chrome-trace JSON (.gz or plain;
-    tolerates the bare event-array form)."""
+def instruction_of(event_name: str) -> str:
+    """``%fusion.16 = (bf16[...`` -> ``fusion.16``: a TPU op event is
+    named by its instruction's whole HLO text."""
+    return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def load_xplane(path: str, host: bool = True) -> list:
+    """An ``.xplane.pb`` as Chrome-style complete events
+    (:func:`events_from_planes`)."""
+    from jax.profiler import ProfileData  # the one jax import, kept lazy
+
+    with warnings.catch_warnings():
+        # jaxlib's stats iterator type warns as it is first made
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return events_from_planes(ProfileData.from_file(path).planes, host)
+
+
+def events_from_planes(planes, host: bool = True) -> list:
+    """Profiler planes (``.name``, ``.lines`` of ``.name`` / ``.events`` of
+    ``.name`` / ``.start_ns`` / ``.duration_ns`` / ``.stats``) as
+    Chrome-style complete events (µs): device ops with ``args.hlo_op`` /
+    ``args.hlo_module`` (module docstring, both shapes), and the host
+    plane's other events with ``cat="host"`` (left out with ``host=False``:
+    a chip capture's runtime threads hold hundreds of thousands, and the
+    ledgers read none). One ``pid`` per plane, one ``tid`` per line, so
+    self times stay per line."""
+    events: list = []
+    for pid, plane in enumerate(planes):
+        tpu = plane.name.startswith("/device:TPU:")
+        if not tpu and plane.name != "/host:CPU":
+            continue
+        lines = list(plane.lines)
+        events.append({"name": "process_name", "ph": "M", "pid": pid,
+                       "tid": 0, "args": {"name": plane.name}})
+        modules = sorted(
+            (ev.start_ns, ev.start_ns + ev.duration_ns,
+             re.match(r"[\w.\-]*", ev.name).group(0))
+            for ln in lines if tpu and ln.name == _TPU_MODULES_LINE
+            for ev in ln.events)
+        for tid, ln in enumerate(lines):
+            if tpu and ln.name != _TPU_OPS_LINE:
+                continue
+            at = 0  # ops and modules both come in time order
+            for ev in ln.events:
+                out = {"name": ev.name, "ph": "X", "pid": pid, "tid": tid,
+                       "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3}
+                if tpu:
+                    while at < len(modules) and modules[at][1] <= ev.start_ns:
+                        at += 1
+                    inside = (at < len(modules)
+                              and modules[at][0] <= ev.start_ns)
+                    out["name"] = instruction_of(ev.name)
+                    out["args"] = {
+                        "hlo_op": out["name"],
+                        "hlo_module": modules[at][2] if inside else None}
+                else:
+                    stats = dict(ev.stats)
+                    if "hlo_op" in stats:
+                        out["args"] = {"hlo_op": stats["hlo_op"],
+                                       "hlo_module": stats.get("hlo_module")}
+                    elif host:
+                        out["cat"] = "host"
+                    else:
+                        continue
+                events.append(out)
+    return events
+
+
+def load_trace(path: str, host: bool = True) -> "tuple[list, dict]":
+    """(events, top-level payload) of a capture: an ``.xplane.pb``
+    (:func:`load_xplane`) or a Chrome-trace JSON (.gz or plain; tolerates
+    the bare event-array form)."""
+    if path.endswith(".xplane.pb"):
+        return load_xplane(path, host), {}
     opener = gzip.open if path.endswith(".gz") else open
     with opener(path, "rt") as fh:
         payload = json.load(fh)
@@ -231,10 +332,10 @@ def load_json(path: str) -> Optional[dict]:
 
 
 def load_scope_map(profile_dir: str) -> Optional[dict]:
-    """The runner-dumped ``device_scope_map.json`` (None when absent — a
-    plain ``--profile-dir`` run never dumps one; attribution then degrades
-    to module totals with everything unattributed)."""
-    return load_json(os.path.join(profile_dir, "device_scope_map.json"))
+    """The window-written ``device_scope_map.json`` (None when absent — a
+    capture taken outside a profiler window has none; attribution then
+    degrades to module totals with everything unattributed)."""
+    return load_json(os.path.join(profile_dir, SCOPE_MAP_FILE))
 
 
 def load_anchor(profile_dir: str) -> Optional[dict]:
@@ -295,23 +396,9 @@ def self_times(events: list) -> "list[tuple[dict, float]]":
 
 def _module_events(events: list, module: str) -> list:
     """One selection rule for both ledgers: complete events tagged
-    ``args.hlo_module == module``, plus untagged events carrying a
-    ``draco_*`` segment in their name/op path (the TPU scope-in-name
-    shape)."""
-    out = []
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
-        evm = _module_of(ev)
-        if evm is not None:
-            if evm == module:
-                out.append(ev)
-        elif (_SCOPE_RE.search(_op_of(ev))
-              or _SCOPE_RE.search(ev.get("name", ""))):
-            # scope-in-name (TPU) shape — _op_of prefers args.hlo_op, so
-            # also search the event name the scope path actually rides in
-            out.append(ev)
-    return out
+    ``args.hlo_module == module``."""
+    return [ev for ev in events
+            if ev.get("ph") == "X" and _module_of(ev) == module]
 
 
 def _phase_rows(pairs: list, scope: dict) -> dict:
@@ -323,17 +410,10 @@ def _phase_rows(pairs: list, scope: dict) -> dict:
             for k in PHASES + RESIDUAL_ROWS}
     t_lo, t_hi = float("inf"), float("-inf")
     for ev, self_us in pairs:
-        op = _op_of(ev)
-        ph = ops.get(op)
-        if ph is None:
-            ph = phase_of(op)  # TPU shape: the path is the event name
-            key = ph if ph else "unattributed"
-        else:
-            key = ph if ph else "other"
+        ph = ops.get(_op_of(ev))
+        key = "unattributed" if ph is None else (ph or "other")
         if key not in rows:
-            # a draco_* token outside the ledger rows — e.g. "draco_tpu"
-            # matched from a repo file path in a python-tracer frame name,
-            # or a future named scope this ledger predates: residual, loud
+            # a draco_* scope this ledger predates: residual, loud
             key = "unattributed"
         rows[key]["time_us"] += self_us
         rows[key]["events"] += 1
@@ -358,9 +438,7 @@ def attribute_phases(events: list, scope: dict) -> dict:
     ``scope``: a :func:`scope_map_from_hlo` dict. Events are selected by
     :func:`_module_events`; each selected event's SELF time lands in
     exactly one row (phase / other / unattributed), so the rows sum to
-    ``total_device_us`` by construction. Ops with no module tag but a
-    ``draco_*`` segment in their name/op path (TPU trace shape) attribute
-    directly.
+    ``total_device_us`` by construction.
     """
     pairs = self_times(_module_events(events, scope.get("module", "")))
     return _phase_rows(pairs, scope)
@@ -530,6 +608,10 @@ def merge_timeline(host_events: list, device_events: list,
     The device timebase is shifted onto the host tracer clock through the
     best anchor pair available (obs/profiling.py stamps both ends):
 
+    * the capture's own ``draco_anchor`` host annotation paired with
+      ``anchor["tracer_ts_us"]``, stamped inside it — the same instant on
+      both clocks, no estimate (an ``.xplane.pb`` taken by a profiler
+      window);
     * the capture's ``start_trace`` frame END paired with
       ``anchor["tracer_ts_us"]`` (python-tracer captures — exact);
     * else the capture's LAST event END paired with
@@ -541,7 +623,10 @@ def merge_timeline(host_events: list, device_events: list,
       the device lanes EARLY by at most the start-to-first-dispatch
       lead-in.
 
-    Device events are
+    The capture's host-plane events (``cat="host"``: the tracer's spans as
+    annotations, and the runtime's own) duplicate ``host_events`` and are
+    left out unless ``host_events`` is empty — then they ARE the host lanes,
+    already on the device's clock. Device events are
     re-emitted under ``pid += DEVICE_PID_BASE`` with their draco phase (from
     the scope map) in ``args.phase`` and ``cat="device"`` — so one trace
     answers "is the gap host prefetch or chip decode". Without an anchor
@@ -555,9 +640,18 @@ def merge_timeline(host_events: list, device_events: list,
     never a silent cap. Metadata/counter events always survive."""
     tracer_ts = (anchor or {}).get("tracer_ts_us")
     drained_ts = (anchor or {}).get("drained_tracer_ts_us")
+    marks = [ev for ev in device_events if ev.get("cat") == "host"
+             and ev.get("name") == ANCHOR_EVENT]
+    if host_events:
+        device_events = [ev for ev in device_events
+                         if ev.get("cat") != "host"]
     start_end = _start_trace_end(device_events)
     span_lo, span_hi = _event_span(device_events)
-    if tracer_ts is not None and start_end is not None:
+    if tracer_ts is not None and marks:
+        anchor_kind = "annotation"
+        offset = tracer_ts - (float(marks[0]["ts"])
+                              + float(marks[0].get("dur", 0.0)))
+    elif tracer_ts is not None and start_end is not None:
         anchor_kind = "start_trace"
         offset = tracer_ts - start_end
     elif drained_ts is not None and span_hi is not None:
@@ -574,13 +668,15 @@ def merge_timeline(host_events: list, device_events: list,
     seen_pids = set()
     dropped = 0
     if max_device_events > 0:
-        xs = [ev for ev in device_events if ev.get("ph") == "X"]
+        xs = [ev for ev in device_events
+              if ev.get("ph") == "X" and ev.get("cat") != "host"]
         if len(xs) > max_device_events:
             xs.sort(key=lambda e: -float(e.get("dur", 0.0)))
             keep = set(map(id, xs[:max_device_events]))
             dropped = len(xs) - max_device_events
             device_events = [ev for ev in device_events
-                             if ev.get("ph") != "X" or id(ev) in keep]
+                             if ev.get("ph") != "X" or id(ev) in keep
+                             or ev.get("cat") == "host"]
     for ev in device_events:
         ph = ev.get("ph")
         if ph not in ("X", "M", "C", "i"):
@@ -590,8 +686,8 @@ def merge_timeline(host_events: list, device_events: list,
         out["pid"] = pid
         if ph != "M":
             out["ts"] = round(float(ev.get("ts", 0.0)) + offset, 3)
-            out["cat"] = "device"
-            phase = ops.get(_op_of(ev)) or phase_of(_op_of(ev))
+            out["cat"] = "host" if ev.get("cat") == "host" else "device"
+            phase = ops.get(_op_of(ev))
             if phase:
                 out.setdefault("args", {})
                 out["args"] = dict(out["args"], phase=phase)
@@ -615,18 +711,20 @@ def merge_timeline(host_events: list, device_events: list,
 # --------------------------------------------------------------------------
 
 def fold_capture(profile_dir: str, strict: bool = False) -> Optional[dict]:
-    """Fold a profile dir (capture + runner-dumped scope map) into the
+    """Fold a profile dir (capture + the window's scope map) into the
     device report: per-program phase ledger + collective ledger. None when
     no capture exists; a capture without a scope map folds with every op
-    unattributed (still honest — the residual carries it). A torn/corrupt
-    capture (a run killed mid-flush) returns None too unless ``strict`` —
-    the same partial-artifact tolerance metrics.jsonl consumers follow."""
+    unattributed (still honest — the residual carries it). A capture that
+    cannot be read (a run killed mid-flush) returns None too unless
+    ``strict`` — the same partial-artifact tolerance metrics.jsonl
+    consumers follow; heartbeat.observe_device folds strictly and records
+    the cause."""
     trace_path = find_capture(profile_dir)
     if trace_path is None:
         return None
     try:
-        events, payload = load_trace(trace_path)
-    except (OSError, ValueError, EOFError):
+        events, payload = load_trace(trace_path, host=False)
+    except Exception:
         if strict:
             raise
         return None
@@ -652,8 +750,11 @@ def fold_capture(profile_dir: str, strict: bool = False) -> Optional[dict]:
             if isinstance(scope, dict) and k in scope:
                 row[k] = scope[k]
         out_programs.append(row)
-    return {"trace": trace_path, "programs": out_programs,
-            "anchor": load_anchor(profile_dir), **meta}
+    out = {"trace": trace_path, "programs": out_programs,
+           "anchor": load_anchor(profile_dir), **meta}
+    if sm and sm.get("errors"):
+        out["scope_map_errors"] = sm["errors"]
+    return out
 
 
 def device_status_block(fold: dict) -> Optional[dict]:
@@ -685,9 +786,9 @@ def device_status_block(fold: dict) -> Optional[dict]:
                         for k, v in totals.items()},
         "decode_share": (round(totals["draco_decode"] / total_us, 4)
                          if total_us else 0.0),
-        # share of device time the scope map could attribute at all — a
-        # plain --profile-dir run has no scope map and reads 0.0 here
-        # (everything in the unattributed row), which is the honest state
+        # share of device time whose instruction the scope map knows: near
+        # 1 when the map is of the programs that ran (the window writes it,
+        # obs/profiling.py); 0.0 for a capture with no scope map
         "attributed_frac": (round(1.0 - totals["unattributed"] / total_us, 4)
                             if total_us else 0.0),
         "achieved_flops_per_s": None,

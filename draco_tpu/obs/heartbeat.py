@@ -320,21 +320,26 @@ class RunHeartbeat:
         block (phase fractions, decode share, attribution coverage — ISSUE
         9). Wired as ``obs.profiling.profiler_window``'s ``on_stop`` hook by
         both production loops, so the block lands on the first beat after
-        the capture window closes. Best-effort: a torn capture (or a run
-        with no capture at all) folds nothing, and observation must never
-        take the run down."""
+        the capture window closes. A fold that fails must never take the
+        run down — but it is not dropped either: the block then holds the
+        one-line cause (``error``), so a missing ledger explains itself."""
         if self.path is None:
             return
         try:
             from draco_tpu.obs import device_attr
 
-            fold = device_attr.fold_capture(profile_dir)
+            fold = device_attr.fold_capture(profile_dir, strict=True)
             block = device_attr.device_status_block(fold) if fold else None
-        except Exception:
-            return
-        if block is not None:
-            block["profile_dir"] = profile_dir
-            self._device = block
+            if block is None:
+                block = {"error": "no capture under the profile dir"
+                         if fold is None else "the capture holds no op of "
+                         "a mapped program"}
+            elif fold.get("scope_map_errors"):
+                block["error"] = "; ".join(fold["scope_map_errors"])[:300]
+        except Exception as e:
+            block = {"error": f"{type(e).__name__}: {e}"[:300]}
+        block["profile_dir"] = profile_dir
+        self._device = block
 
     def decode_health(self) -> Optional[dict]:
         """Cumulative detection precision/recall (1.0 denominators-empty:

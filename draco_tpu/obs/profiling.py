@@ -8,15 +8,22 @@ per site, incompletely (the CNN eager loop never drained). ISSUE 9
 deduplicates them into :func:`profiler_window`, which also stamps the
 **wall-clock anchor** (``profile_dir/host_anchor.json``) that the merged
 host+device timeline needs: the host tracer's relative timestamp at the
-moment ``start_trace`` returned, pairing with the capture's own start-time
-origin (obs/device_attr.device_time_origin) to put both event streams on
-one clock.
+moment ``start_trace`` returned, taken inside a ``draco_anchor``
+``TraceAnnotation`` so that the capture's host plane holds the same instant
+on the profiler's clock (obs/device_attr.merge_timeline) — and, on stop,
+writes the **scope map** (``profile_dir/device_scope_map.json``) of every
+program the loop dispatched in the window: instruction name -> ``draco_*``
+scope, parsed from ``lower(*the call's own arguments).compile().as_text()``.
+A device event names its instruction and nothing else, so this file is what
+turns a capture into the per-phase ledger (obs/device_attr.py).
 
 Window semantics (unchanged from the per-site logic):
 
 * ``maybe_start(step_end)`` before a work unit whose last step is
   ``step_end`` — starts the capture at the first unit reaching
   ``profile_steps[0]`` (chunk-snapped under K>1), at most once per run.
+* ``note_program(label, fn, args, key)`` right before a dispatch — kept
+  (as shapes) for the first call of each program inside the window.
 * ``maybe_stop(step_end, drain)`` after the unit — stops once
   ``step_end >= profile_steps[1] - 1``, draining ``drain`` (the state
   carry) through ``jax.block_until_ready`` first so the capture contains
@@ -41,52 +48,47 @@ from typing import Optional
 from draco_tpu.obs.tracer import NULL_TRACER
 
 ANCHOR_FILE = "host_anchor.json"
+# the annotation the window opens as it stamps ``tracer_ts_us``: the same
+# instant on the capture's host plane (device_attr.merge_timeline)
+ANCHOR_EVENT = "draco_anchor"
 
 
 def _quiet_start_trace(log_dir: str) -> None:
-    """``jax.profiler.start_trace`` with the python tracer DISABLED.
+    """``jax.profiler.start_trace`` with the python tracer OFF and the host
+    tracer at level 1.
 
     The default capture interleaves a python-callstack event per host frame
     — ~1M events for a CI-sized 8-step window, flooding the bounded trace
-    buffer and truncating the device stream this module exists to capture
-    (the host half is already covered by the span tracer, obs/tracer.py).
-    jax 0.4.x exposes no public knob, so this builds the ProfilerSession
-    with ``ProfileOptions.python_tracer_level = 0`` through the same
-    internal state ``start_trace`` uses; if the internals move with a
-    toolchain bump, it degrades to the public (noisy) ``start_trace``
-    rather than losing the capture."""
+    buffer and truncating the device stream this module exists to capture —
+    and at the default host level a traced step's host side ran 60 ms
+    behind an untraced one on the chip (PERF.md §6). Level 1 keeps
+    ``TraceAnnotation`` events, which is how the span tracer's spans
+    (obs/tracer.py) and the anchor below reach the capture's host plane."""
     import jax
 
-    already_active = False
-    try:
-        from jax._src import profiler as _prof
-        from jax._src import xla_bridge
-        from jax._src.lib import xla_client
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=options)
 
-        opts = xla_client.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        with _prof._profile_state.lock:
-            if _prof._profile_state.profile_session is not None:
-                already_active = True
-            else:
-                xla_bridge.get_backend()  # the session needs a live backend
-                _prof._profile_state.profile_session = \
-                    xla_client.profiler.ProfilerSession(opts)
-                _prof._profile_state.create_perfetto_link = False
-                _prof._profile_state.create_perfetto_trace = False
-                _prof._profile_state.log_dir = str(log_dir)
-    except Exception:
-        # internals moved (or a backend/XLA error — note XlaRuntimeError
-        # subclasses RuntimeError, so no bare RuntimeError re-raise here):
-        # keep capturing via the public path, accept the noise
-        jax.profiler.start_trace(log_dir)
-        return
-    if already_active:
-        # only OUR sentinel propagates — a second concurrent window is a
-        # caller bug, not a degradation case
-        raise RuntimeError(
-            "profiler session already active — only one "
-            "profiler_window may run at a time")
+
+def abstract_args(args):
+    """The call's arguments as shapes (with their shardings): what
+    ``lower`` needs, and safe to keep after a donating call."""
+    import jax
+
+    return jax.tree.map(
+        lambda a: (jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=a.sharding)
+                   if isinstance(a, jax.Array) else a), args)
+
+
+def program_text(fn, args) -> str:
+    """Optimized-HLO text of the executable ``fn(*args)`` dispatches: the
+    same lowering, so with the persistent compile cache on it is a cache
+    load, and its instruction names are the ones a capture's device events
+    carry (obs/device_attr.scope_map_from_hlo)."""
+    return fn.lower(*args).compile().as_text()
 
 
 class NullProfilerWindow:
@@ -98,6 +100,9 @@ class NullProfilerWindow:
     profiled = False
 
     def maybe_start(self, step_end: int, first_step=None) -> None:
+        pass
+
+    def note_program(self, label: str, fn, args, key=None) -> None:
         pass
 
     def maybe_stop(self, step_end: int, drain=None) -> None:
@@ -126,6 +131,9 @@ class ProfilerWindow:
         self._anchor: dict = {}
         self._first: Optional[int] = None
         self._last_end: Optional[int] = None
+        # (label, key) -> (jitted callable, the call's arguments as shapes):
+        # the programs dispatched while the window was open
+        self._programs: dict = {}
         # called with the profile dir after a successful stop — the loops
         # pass heartbeat.observe_device so status.json grows the ``device``
         # block from the capture that just landed
@@ -137,23 +145,37 @@ class ProfilerWindow:
         count is [first_step, last stop step], not [profile_steps)."""
         if self.active or self.profiled or step_end < self.steps[0]:
             return
+        import jax
+
         os.makedirs(self.dir, exist_ok=True)
         _quiet_start_trace(self.dir)
         self._first = int(first_step if first_step is not None else step_end)
-        # stamped AFTER start_trace returns; with the python tracer off the
-        # capture has no start event, so the merge anchors on the DRAIN
-        # stamp below instead (device_attr.merge_timeline)
-        self._anchor = {
-            "schema": 1,
-            "profile_steps": list(self.steps),
-            "first_step": self._first,
-            "started_unix": time.time(),
-            "started_perf": time.perf_counter(),
-            # host-tracer-relative µs of the same instant (None when the
-            # run has no tracer — the timeline then keeps separate origins)
-            "tracer_ts_us": getattr(self.tracer, "now_us", lambda: None)(),
-        }
+        # stamped AFTER start_trace returns, inside an annotation of its
+        # own: the capture's host plane then holds the very instant the
+        # stamps name, on the profiler's clock (device_attr.merge_timeline
+        # anchors there; the DRAIN stamp below is the fallback)
+        with jax.profiler.TraceAnnotation(ANCHOR_EVENT):
+            self._anchor = {
+                "schema": 1,
+                "profile_steps": list(self.steps),
+                "first_step": self._first,
+                "started_unix": time.time(),
+                "started_perf": time.perf_counter(),
+                # host-tracer-relative µs of the same instant (None when
+                # the run has no tracer — the timeline then keeps separate
+                # origins)
+                "tracer_ts_us": getattr(self.tracer, "now_us",
+                                        lambda: None)(),
+            }
         self.active = True
+
+    def note_program(self, label: str, fn, args, key=None) -> None:
+        """The loop is about to dispatch ``fn(*args)`` under compile-watch
+        label ``label`` (``key``: the chunk length of a K-fused program).
+        Kept, as shapes, for the first call of each program inside the
+        window; :meth:`stop` turns them into ``device_scope_map.json``."""
+        if self.active and (label, key) not in self._programs:
+            self._programs[(label, key)] = (fn, abstract_args(args))
 
     def maybe_stop(self, step_end: int, drain=None) -> None:
         if not self.active:
@@ -206,11 +228,62 @@ class ProfilerWindow:
             os.replace(tmp, os.path.join(self.dir, ANCHOR_FILE))
         except OSError:
             pass  # anchor is best-effort; the capture itself already landed
+        self._write_scope_map()
         if self._on_stop is not None:
             try:
                 self._on_stop(self.dir)
             except Exception:
                 pass  # observation must never take the run down
+
+    def _write_scope_map(self) -> None:
+        """``device_scope_map.json`` beside the capture: instruction ->
+        ``draco_*`` scope for every program dispatched in the window, from
+        the program's own text (a device event names the instruction and
+        nothing else). Keeps what a tool stamped there before the run
+        (``cell``, a program's ``lint_row`` / ``flops_per_step``). A program
+        whose text cannot be had leaves its one-line cause instead."""
+        if not self._programs:
+            return
+        from draco_tpu.obs import device_attr
+
+        path = os.path.join(self.dir, device_attr.SCOPE_MAP_FILE)
+        payload = device_attr.load_json(path) or {}
+        stamped = {p.get("module"): p for p in payload.get("programs", [])}
+        programs, errors = [], []
+        for (label, key), (fn, args) in self._programs.items():
+            name = label if key is None else f"{label}[{key}]"
+            try:
+                scope = device_attr.scope_map_from_hlo(program_text(fn, args))
+            except Exception as e:
+                errors.append(f"{name}: {type(e).__name__}: {e}"[:300])
+                continue
+            same = next((p for p in programs
+                         if p["module"] == scope["module"]), None)
+            if same is not None:
+                # two programs of one name (a main and a remainder chunk of
+                # one scan body): an event names its module, not which of
+                # the two ran, so they fold as one — the first's entry
+                # stands where an instruction name is in both
+                for k in ("ops", "collectives"):
+                    same[k] = {**scope[k], **same[k]}
+                same["label"] += "+" + name
+                continue
+            old = stamped.get(scope["module"], {})
+            scope.update({k: old[k] for k in ("lint_row", "flops_per_step")
+                          if k in old})
+            scope["label"] = name
+            programs.append(scope)
+        payload.update(schema=1, programs=programs,
+                       steps_profiled=self._anchor.get("steps_profiled"))
+        if errors:
+            payload["errors"] = errors
+        tmp = path + ".tmp"
+        try:
+            with open(tmp, "w") as fh:
+                json.dump(payload, fh)
+            os.replace(tmp, path)
+        except OSError:
+            pass
 
 
 def profiler_window(profile_dir: Optional[str], profile_steps: tuple = (3, 8),

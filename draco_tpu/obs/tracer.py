@@ -23,6 +23,13 @@ Design constraints (the PR 1–2 invariant):
 * **Thread-safe.** Prefetcher worker threads emit spans from their own
   threads; events append under a lock and carry the emitting thread's id,
   so each worker gets its own lane (``name_thread`` labels it).
+* **One clock with the profiler.** While a span is open on an enabled
+  tracer it also holds a ``jax.profiler.TraceAnnotation`` of the same name
+  (where jax is importable; outside a capture an annotation costs a flag
+  test), so a jax.profiler capture's ``/host:CPU`` plane carries the
+  program's own spans on the profiler's clock, next to the device's ops —
+  obs/device_attr.py reads them from there and estimates no offset.
+  ``NULL_TRACER`` takes no annotation.
 
 Event kinds used (Chrome trace event format spec):
 
@@ -98,7 +105,7 @@ class _Span:
     """One live span: records ts on __enter__, appends the complete event
     on __exit__ (so nesting falls out of wall-clock containment)."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_note")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict]):
         self._tracer = tracer
@@ -106,12 +113,18 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        annotate = self._tracer._annotate
+        self._note = None if annotate is None else annotate(self._name)
+        if self._note is not None:
+            self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         tr = self._tracer
         t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(*exc)
         ev = {
             "name": self._name,
             "ph": "X",
@@ -150,6 +163,13 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._max_events = max(int(max_events), 16)
         self._dropped = 0
+        # the profiler's own annotation type, so an open span also shows on
+        # a capture's host plane (module docstring); None without jax
+        try:
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
+        except ImportError:
+            self._annotate = None
         self._events: list = [
             {"name": "process_name", "ph": "M", "pid": self._pid, "tid": 0,
              "args": {"name": process_name}},

@@ -91,7 +91,8 @@ def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
         getattr(cfg, "decode_impl", "xla") if cfg is not None else "xla",
         mesh)
     tree = _is_tree(code)
-    bad_rows = forensics_mod.nonfinite_rows(grads)
+    with jax.named_scope("draco_health"):
+        bad_rows = forensics_mod.nonfinite_rows(grads)
     with jax.named_scope("draco_encode"):
         if tree:
             from draco_tpu.coding import topology as topology_mod
@@ -198,7 +199,8 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
     from draco_tpu.obs import forensics as forensics_mod
     from draco_tpu.resilience import faults as faults_mod
 
-    grads = faults_mod.corrupt_grads(grads, cfg, step)
+    with jax.named_scope("draco_attack"):
+        grads = faults_mod.corrupt_grads(grads, cfg, step)
     if cfg.approach == "approx":
         # approximate family (coding/approx.py; ISSUE 8): the shared
         # sequence above — health is the residual-vs-bound certificate
@@ -210,7 +212,8 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
         # k — the shared-redundancy encode below smears any NaN across every
         # codeword (0·NaN = NaN in the masked matmul), so the wire rows
         # cannot (obs/forensics.nonfinite_rows docstring)
-        bad_rows = forensics_mod.nonfinite_rows(grads)
+        with jax.named_scope("draco_health"):
+            bad_rows = forensics_mod.nonfinite_rows(grads)
         tree = _is_tree(code)
         with jax.named_scope("draco_encode"):
             if tree:
@@ -230,23 +233,27 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                 # (n, d): one-copy batch gradients, rows formed algebraically
                 # (cfg.redundancy == "shared", the TPU-native fast path)
                 enc_re, enc_im = cyclic_mod.encode_shared(code, grads)
+        with jax.named_scope("draco_attack"):
+            # simulation: what a deployment does not pay
             enc_re, enc_im = attacks.inject_cyclic(
                 enc_re, enc_im, adv_mask, cfg.err_mode, cfg.adversarial,
                 step=step, seed=cfg.seed
             )
-            if present is not None:
-                pw = present[:, None].astype(enc_re.dtype)
-                enc_re, enc_im = enc_re * pw, enc_im * pw
         from draco_tpu.obs import numerics as numerics_mod
         from draco_tpu.ops.decode_kernels import resolve_decode_impl
 
         decode_impl = resolve_decode_impl(cfg.decode_impl, mesh)
-        # the REAL narrow wire (ISSUE 15): the codeword pair is rounded
-        # into narrow buffers that cross the sharding boundary; the decode
-        # widens to f32 and runs the quantization-aware flag threshold +
-        # Tikhonov-regularized locator. Identity on the f32 wire.
-        enc_re, enc_im, wire = numerics_mod.narrow_wire_pair(
-            cfg, enc_re, enc_im, step=step)
+        with jax.named_scope("draco_encode"):
+            if present is not None:
+                pw = present[:, None].astype(enc_re.dtype)
+                enc_re, enc_im = enc_re * pw, enc_im * pw
+            # the REAL narrow wire (ISSUE 15): the codeword pair is
+            # rounded into narrow buffers that cross the sharding boundary;
+            # the decode widens to f32 and runs the quantization-aware flag
+            # threshold + Tikhonov-regularized locator. Identity on the f32
+            # wire.
+            enc_re, enc_im, wire = numerics_mod.narrow_wire_pair(
+                cfg, enc_re, enc_im, step=step)
         if tree:
             # the tree decodes each leaf group at the GROUP shape — its
             # narrow-wire thresholds come from the (fanout, s_g) table row
@@ -315,25 +322,27 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
         health["bad_rows"] = bad_rows
 
         if numerics_mod.watch_enabled(cfg):
-            # numerics observatory (obs/numerics.py, ISSUE 10): dynamic-
-            # range columns + the shadow-quantized decode, stashed under
-            # health["watch"] for decode_health_metrics to merge — the f32
-            # decode above alone feeds the update
-            watch = {}
-            if cfg.numerics_watch == "on":
-                watch.update(numerics_mod.numerics_columns(
-                    cfg, [grads], [enc_re, enc_im], agg))
-            if cfg.shadow_wire != "off":
-                watch.update(numerics_mod.cyclic_shadow(
-                    cfg, code, enc_re, enc_im, agg, health, rand_factor,
-                    leaf_offsets, present, adv_mask, step))
-            health["watch"] = watch
+            with jax.named_scope("draco_health"):
+                # numerics observatory (obs/numerics.py, ISSUE 10): dynamic-
+                # range columns + the shadow-quantized decode, stashed under
+                # health["watch"] for decode_health_metrics to merge — the f32
+                # decode above alone feeds the update
+                watch = {}
+                if cfg.numerics_watch == "on":
+                    watch.update(numerics_mod.numerics_columns(
+                        cfg, [grads], [enc_re, enc_im], agg))
+                if cfg.shadow_wire != "off":
+                    watch.update(numerics_mod.cyclic_shadow(
+                        cfg, code, enc_re, enc_im, agg, health, rand_factor,
+                        leaf_offsets, present, adv_mask, step))
+                health["watch"] = watch
         return agg, health
-    with jax.named_scope("draco_decode"):
+    with jax.named_scope("draco_attack"):
         grads = attacks.inject_plain(grads, adv_mask, cfg.err_mode,
                                      cfg.adversarial,
                                      n_mal=cfg.num_adversaries,
                                      step=step, seed=cfg.seed)
+    with jax.named_scope("draco_decode"):
         agg = aggregation.aggregate(
             grads, cfg.mode, s=cfg.worker_fail,
             geomedian_iters=cfg.geomedian_iters, present=present,
@@ -353,8 +362,9 @@ def masked_loss_metric(losses, present):
 def apply_flat_update(state, agg: jnp.ndarray, opt, unravel):
     """Aggregated flat gradient → (new_params, new_opt_state) via the
     grads-as-argument optimizer convention (reference sgd_modified.py:53)."""
-    with jax.named_scope("draco_update"):
+    with jax.named_scope("draco_pack"):
         grads_tree = unravel(agg)
+    with jax.named_scope("draco_update"):
         updates, new_opt = opt.update(grads_tree, state.opt_state,
                                       state.params)
         new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
@@ -385,13 +395,16 @@ def finish_flat_step(cfg, state, agg, health, opt, unravel, present=None,
         new_params = constrain(new_params)
     if constrain_opt is not None:
         new_opt = constrain_opt(new_opt)
-    new_state = state._replace(params=new_params, opt_state=new_opt,
-                               step=state.step + 1)
+    with jax.named_scope("draco_update"):
+        new_state = state._replace(params=new_params, opt_state=new_opt,
+                                   step=state.step + 1)
     if cfg.step_guard != "on":
         return new_state, {}
     from draco_tpu.resilience import guards
 
-    return guards.guard_update(cfg, state, new_state, agg, health, present)
+    with jax.named_scope("draco_health"):
+        return guards.guard_update(cfg, state, new_state, agg, health,
+                                   present)
 
 
 # column order of the (K, m) metric block train_token_many returns on the
@@ -571,11 +584,14 @@ def make_token_train_many(step_body, token_fn=None,
         def body(st, operand):
             toks, adv_mask, present = operand
             if token_fn is not None:
-                toks = token_fn(toks)
+                with jax.named_scope("draco_input"):
+                    toks = token_fn(toks)
             st, metrics = step_body(st, toks, adv_mask, present)
-            row = jnp.stack(
-                [jnp.asarray(metrics[k], jnp.float32) for k in metric_names]
-            )
+            with jax.named_scope("draco_health"):
+                row = jnp.stack(
+                    [jnp.asarray(metrics[k], jnp.float32)
+                     for k in metric_names]
+                )
             return st, row
 
         return jax.lax.scan(body, state, (tokens, masks, presents))

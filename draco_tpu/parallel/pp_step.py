@@ -334,8 +334,10 @@ def build_pp_train_setup(cfg: TrainConfig, mesh) -> PPTrainSetup:
         # in-graph decode projection — no d-length program constant
         # (rng.random_projection_factors_in_graph docstring); the approx
         # decode is projection-free
-        rand_factor = (drng.random_projection_factors_in_graph(cfg.seed, dim)
-                       if cfg.approach == "cyclic" else None)
+        with jax.named_scope("draco_input"):
+            rand_factor = (
+                drng.random_projection_factors_in_graph(cfg.seed, dim)
+                if cfg.approach == "cyclic" else None)
         agg, health = aggregate_flat_grads(grads, adv_mask, cfg, code,
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
@@ -344,8 +346,9 @@ def build_pp_train_setup(cfg: TrainConfig, mesh) -> PPTrainSetup:
             cfg, state, agg, health, opt, unravel, present=present,
             constrain=lambda p: _constrain_params(p, mesh, _leaf_spec),
         )
-        metrics = {"loss": masked_loss_metric(losses, present)}
-        metrics.update(decode_health_metrics(health, adv_mask, present))
+        with jax.named_scope("draco_health"):
+            metrics = {"loss": masked_loss_metric(losses, present)}
+            metrics.update(decode_health_metrics(health, adv_mask, present))
         metrics.update(guard_cols)
         return new_state, metrics
 

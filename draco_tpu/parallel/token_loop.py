@@ -326,15 +326,13 @@ def _run_eager(setup, cfg, state, start, last_step, adv, straggle, writer,
                 synthetic_text(cfg.seed, step, cfg.num_workers,
                                cfg.batch_size, cfg.seq_len, cfg.vocab)
             )
+        args = (state, toks, jnp.asarray(adv[step]))
+        if straggle is not None:
+            args += (jnp.asarray(~straggle[step]),)
+        win.note_program("train_step", setup.train_step, args)
         with tracer.span("dispatch"), watch.expect("train_step"):
-            if straggle is None:
-                state, metrics = setup.train_step(state, toks,
-                                                  jnp.asarray(adv[step]))
-            else:
-                state, metrics = setup.train_step(
-                    state, toks, jnp.asarray(adv[step]),
-                    jnp.asarray(~straggle[step]),
-                )
+            state, metrics = setup.train_step(*args)
+        del args  # the donated state must not outlive its call
         win.maybe_stop(step, state.params)
         if obs.latest is not None:  # escalated-stop checkpoint cursor
             obs.latest["state"], obs.latest["step"] = state, step
@@ -345,11 +343,14 @@ def _run_eager(setup, cfg, state, start, last_step, adv, straggle, writer,
         # (the chunked driver observes every step for free at its flush)
         if step % cfg.log_every == 0:
             with tracer.span("sync"):
-                # record_value: forensics bitmask columns materialize as
-                # exact integer words (obs/forensics docstring)
-                record = {"step": step}
-                record.update({k: record_value(k, v)
-                               for k, v in metrics.items()})
+                with tracer.span("device_wait"):
+                    jax.block_until_ready(metrics)
+                with tracer.span("drain", columns=len(metrics)):
+                    # record_value: forensics bitmask columns materialize
+                    # as exact integer words (obs/forensics docstring)
+                    record = {"step": step}
+                    record.update({k: record_value(k, v)
+                                   for k, v in metrics.items()})
             heartbeat.observe(record)
             writer.write(record)
         boundary = cfg.eval_freq and step % cfg.eval_freq == 0

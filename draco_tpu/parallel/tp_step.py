@@ -272,8 +272,10 @@ def _build_gspmd_train_setup(cfg: TrainConfig, mesh, *, mp_axis: str,
         # closed-over (d,) constant serializes into the program (638 MB at
         # d~159M: the remote-compile ceiling, rng.py docstring); the approx
         # decode is projection-free
-        rand_factor = (drng.random_projection_factors_in_graph(cfg.seed, dim)
-                       if cfg.approach == "cyclic" else None)
+        with jax.named_scope("draco_input"):
+            rand_factor = (
+                drng.random_projection_factors_in_graph(cfg.seed, dim)
+                if cfg.approach == "cyclic" else None)
         agg, health = aggregate_flat_grads(grads, adv_mask, cfg, code,
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
@@ -283,8 +285,9 @@ def _build_gspmd_train_setup(cfg: TrainConfig, mesh, *, mp_axis: str,
             constrain=lambda p: _constrain_params(p, mesh, partition_fn),
             constrain_opt=constrain_opt,
         )
-        metrics = {"loss": masked_loss_metric(losses, present)}
-        metrics.update(decode_health_metrics(health, adv_mask, present))
+        with jax.named_scope("draco_health"):
+            metrics = {"loss": masked_loss_metric(losses, present)}
+            metrics.update(decode_health_metrics(health, adv_mask, present))
         metrics.update(guard_cols)
         return new_state, metrics
 

@@ -40,6 +40,12 @@ def enable_compile_cache() -> str:
     goes through here, on every backend: a coded ResNet-18 step costs about
     a minute to compile cold and seconds warm. Safe to call repeatedly.
     """
+    # an entry's key covers its labels too (the ``draco_*`` named scopes live
+    # in the instructions' metadata, which the default key leaves out): an
+    # executable compiled from another version of the source would come back
+    # with that version's labels, and a device trace is attributed through
+    # them (obs/device_attr.scope_map_from_hlo)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # the default 1 s floor would skip mid-size kernels; cache everything
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

@@ -118,9 +118,12 @@ def _cross_entropy(logits, labels):
     return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
 
 
+def _ravel_leaves(tree) -> list:
+    return [jnp.reshape(x, (-1,)) for x in jax.tree.leaves(tree)]
+
+
 def _flatten_tree(tree) -> jnp.ndarray:
-    return jnp.concatenate(
-        [jnp.reshape(x, (-1,)) for x in jax.tree.leaves(tree)])
+    return jnp.concatenate(_ravel_leaves(tree))
 
 
 def _make_unravel(params):
@@ -222,39 +225,72 @@ def build_train_setup(cfg: TrainConfig, mesh,
             (loss, (new_stats, prec1)), g = jax.value_and_grad(
                 lane_loss, has_aux=True
             )(p, stats, x, y, dkey)
-        return _flatten_tree(g), new_stats, loss, prec1
+            # the per-leaf ravel stays with the gradient: where XLA fuses a
+            # weight-gradient convolution into its relayout the fusion
+            # carries this label, so convolutions count as compute in
+            # every cell (PERF.md §3 lists the movement this leaves here)
+            leaves = _ravel_leaves(g)
+        # leaves -> one row of the (n, [r,] d) stack: pure movement, its
+        # own ledger row
+        with jax.named_scope("draco_pack"):
+            flat = jnp.concatenate(leaves)
+        return flat, new_stats, loss, prec1
 
     def apply_update(state: TrainState, flat_grad, new_stats):
-        with jax.named_scope("draco_update"):
+        with jax.named_scope("draco_pack"):
             grads_tree = unravel(flat_grad)
+        with jax.named_scope("draco_update"):
             updates, new_opt = opt.update(grads_tree, state.opt_state,
                                           state.params)
             new_params = jax.tree.map(lambda p, u: p + u, state.params,
                                       updates)
-        return TrainState(
-            params=new_params,
-            opt_state=new_opt,
-            batch_stats=new_stats,
-            step=state.step + 1,
-        )
+            return TrainState(
+                params=new_params,
+                opt_state=new_opt,
+                batch_stats=new_stats,
+                step=state.step + 1,
+            )
 
     adv_mag = cfg.adversarial
 
-    def prep_rows(state, x, y):
+    def prep_rows(state, x, y, ids=None):
         """Augment + dropout keys per *global batch row* k — any worker
         computing batch k sees identical data and rng. The per-batch-row
         discipline both algebraic code families (cyclic, approx) share:
-        it is what makes the shared-redundancy encode exact."""
-        if use_aug:
-            keys = jax.vmap(
-                lambda k: drng.fold(jax.random.key(cfg.seed + 2),
+        it is what makes the shared-redundancy encode exact. ``ids``: the
+        (n,) key ids folded per row — the row index by default, the group
+        id where group members must stay bitwise identical (maj_vote)."""
+        with jax.named_scope("draco_input"):
+            if use_aug:
+                keys = jax.vmap(
+                    lambda k: drng.fold(jax.random.key(cfg.seed + 2),
+                                        state.step, k)
+                )(jnp.arange(n) if ids is None else ids)
+                x = jax.vmap(augment_mod.augment_batch)(x, keys)
+            dkeys = jax.vmap(
+                lambda k: drng.fold(jax.random.key(cfg.seed + 3),
                                     state.step, k)
-            )(jnp.arange(n))
-            x = jax.vmap(augment_mod.augment_batch)(x, keys)
-        dkeys = jax.vmap(
-            lambda k: drng.fold(jax.random.key(cfg.seed + 3), state.step, k)
-        )(jnp.arange(n))
+            )(jnp.arange(n) if ids is None else ids)
         return x, y, dkeys
+
+    def simulate_faults(grads, state, adv_mask=None):
+        """What a deployment does not pay: the fault plan's in-graph
+        corruption and — where ``adv_mask`` is given — the plain-row
+        adversary, both under ``draco_attack``."""
+        with jax.named_scope("draco_attack"):
+            grads = faults_mod.corrupt_grads(grads, cfg, state.step)
+            if adv_mask is not None:
+                grads = attacks.inject_plain(grads, adv_mask, cfg.err_mode,
+                                             adv_mag,
+                                             n_mal=cfg.num_adversaries,
+                                             step=state.step, seed=cfg.seed)
+        return grads
+
+    def stack_rows(grads, spec):
+        """Pin the gradient stack's worker sharding (the slab the gather
+        moves): layout, so it counts with the pack."""
+        with jax.named_scope("draco_pack"):
+            return jax.lax.with_sharding_constraint(grads, spec)
 
     # ---- approach-specific step bodies -----------------------------------
     if cfg.approach == "baseline":
@@ -263,26 +299,13 @@ def build_train_setup(cfg: TrainConfig, mesh,
 
         def step_body(state: TrainState, x, y, adv_mask, present=None):
             # x, y: (n, B, ...) sharded over w; aug key per (step, worker)
-            if use_aug:
-                keys = jax.vmap(
-                    lambda i: drng.fold(jax.random.key(cfg.seed + 2),
-                                        state.step, i)
-                )(jnp.arange(n))
-                x = jax.vmap(augment_mod.augment_batch)(x, keys)
-            dkeys = jax.vmap(
-                lambda i: drng.fold(jax.random.key(cfg.seed + 3),
-                                    state.step, i)
-            )(jnp.arange(n))
+            x, y, dkeys = prep_rows(state, x, y)
             grads, new_stats, losses, precs = jax.vmap(
                 lane, in_axes=(None, 0, 0, 0, 0))(
                 state.params, state.batch_stats, x, y, dkeys
             )
-            grads = jax.lax.with_sharding_constraint(grads, shard_w)
-            grads = faults_mod.corrupt_grads(grads, cfg, state.step)
-            grads = attacks.inject_plain(grads, adv_mask, cfg.err_mode,
-                                         adv_mag,
-                                         n_mal=cfg.num_adversaries,
-                                         step=state.step, seed=cfg.seed)
+            grads = stack_rows(grads, shard_w)
+            grads = simulate_faults(grads, state, adv_mask)
             with jax.named_scope("draco_decode"):
                 agg = aggregation.aggregate(grads, cfg.mode,
                                             s=cfg.worker_fail,
@@ -290,11 +313,12 @@ def build_train_setup(cfg: TrainConfig, mesh,
                                                 cfg.geomedian_iters),
                                             present=present)
             new_state = apply_update(state, agg, new_stats)
-            out = _metrics(losses, precs, present)
-            # no exactness certificate on approximate rules: the guard's
-            # only signal here is the global-finite check
-            new_state = _maybe_guard(cfg, state, new_state, agg, None,
-                                     present, out)
+            with jax.named_scope("draco_health"):
+                out = _metrics(losses, precs, present)
+                # no exactness certificate on approximate rules: the
+                # guard's only signal here is the global-finite check
+                new_state = _maybe_guard(cfg, state, new_state, agg, None,
+                                         present, out)
             return new_state, out
 
     elif cfg.approach == "maj_vote":
@@ -306,32 +330,20 @@ def build_train_setup(cfg: TrainConfig, mesh,
             # group members carry identical batches (batching layer guarantees
             # it); aug + dropout keys fold the *group* id so lanes stay
             # bitwise identical within a group — the vote's soundness condition
-            if use_aug:
-                keys = jax.vmap(
-                    lambda gid: drng.fold(jax.random.key(cfg.seed + 2),
-                                          state.step, gid)
-                )(group_ids)
-                x = jax.vmap(augment_mod.augment_batch)(x, keys)
-            dkeys = jax.vmap(
-                lambda gid: drng.fold(jax.random.key(cfg.seed + 3),
-                                      state.step, gid)
-            )(group_ids)
+            x, y, dkeys = prep_rows(state, x, y, group_ids)
             grads, new_stats, losses, precs = jax.vmap(
                 lane, in_axes=(None, 0, 0, 0, 0))(
                 state.params, state.batch_stats, x, y, dkeys
             )
-            grads = jax.lax.with_sharding_constraint(grads, shard_w)
-            grads = faults_mod.corrupt_grads(grads, cfg, state.step)
-            grads = attacks.inject_plain(grads, adv_mask, cfg.err_mode,
-                                         adv_mag,
-                                         n_mal=cfg.num_adversaries,
-                                         step=state.step, seed=cfg.seed)
+            grads = stack_rows(grads, shard_w)
+            grads = simulate_faults(grads, state, adv_mask)
             # per-step fingerprint salt, identical on every device (folded
             # from replicated state.step). Being seed-derived it is NOT
             # secret from a participant that knows the experiment seed —
             # cfg.vote_check="exact" is the collision-free option for that
             # threat model (repetition.py module docstring, tier 3).
-            vkey = drng.fold(jax.random.key(cfg.seed + 4), state.step)
+            with jax.named_scope("draco_input"):
+                vkey = drng.fold(jax.random.key(cfg.seed + 4), state.step)
             # the REAL narrow wire (ISSUE 15): this family's wire IS the
             # raw gradient rows — quantize them into narrow buffers (the
             # shared noise draw keeps within-group rows bitwise identical,
@@ -339,46 +351,49 @@ def build_train_setup(cfg: TrainConfig, mesh,
             # and vote over the widened rows. Identity on the f32 wire.
             vote_rows = grads
             if cfg.wire_dtype != "f32":
-                vote_rows, _wire = numerics_mod.narrow_wire_single(
-                    cfg, grads, step=state.step,
-                    constrain=lambda r: jax.lax.with_sharding_constraint(
-                        r, shard_w))
+                with jax.named_scope("draco_encode"):
+                    vote_rows, _wire = numerics_mod.narrow_wire_single(
+                        cfg, grads, step=state.step,
+                        constrain=lambda r: jax.lax.with_sharding_constraint(
+                            r, shard_w))
             with jax.named_scope("draco_decode"):
                 voted, vhealth = rep_mod.majority_vote(
                     rep_code, vote_rows, present=present, key=vkey,
                     method=cfg.vote_check, with_health=True)
             new_state = apply_update(state, voted, new_stats)
-            out = _metrics(losses, precs, present)
-            # vote health (telemetry columns; coding/repetition.py):
-            # agreement fraction + flagged groups, and the per-row flag set
-            # scored against the seeded schedules — all in-graph
-            out["vote_agree"] = vhealth["vote_agree"]
-            out["flagged_groups"] = vhealth["flagged_groups"]
-            out.update(_detection_metrics(vhealth["flagged"], adv_mask,
-                                          present))
-            # numerics observatory (obs/numerics.py, ISSUE 10): this
-            # family's wire IS the raw gradient rows; the shadow re-votes
-            # over the quantized rows (deterministic rounding preserves
-            # within-group bitwise equality, the vote's soundness condition)
-            if numerics_mod.watch_enabled(cfg):
-                if cfg.numerics_watch == "on":
-                    out.update(numerics_mod.numerics_columns(
-                        cfg, [grads], [vote_rows], voted))
-                if cfg.shadow_wire != "off":
-                    out.update(numerics_mod.majvote_shadow(
-                        cfg, rep_code, grads, voted, vhealth, vkey,
-                        present, adv_mask, state.step))
-            # per-worker forensics columns (obs/forensics): the vote's own
-            # out-voted set ∪ non-finite ingest rows, packed with the
-            # present + seeded-adversary masks to ride the metric block
-            out.update(forensics_mod.pack_mask_columns(
-                vhealth["flagged"] | forensics_mod.nonfinite_rows(grads),
-                present, adv_mask))
-            # guard signals: finite vote + out-voted rows (vote
-            # disagreement) within the s budget
-            new_state = _maybe_guard(cfg, state, new_state, voted,
-                                     {"flagged": vhealth["flagged"]},
-                                     present, out)
+            with jax.named_scope("draco_health"):
+                out = _metrics(losses, precs, present)
+                # vote health (telemetry columns; coding/repetition.py):
+                # agreement fraction + flagged groups, and the per-row flag set
+                # scored against the seeded schedules — all in-graph
+                out["vote_agree"] = vhealth["vote_agree"]
+                out["flagged_groups"] = vhealth["flagged_groups"]
+                out.update(_detection_metrics(vhealth["flagged"], adv_mask,
+                                              present))
+                # numerics observatory (obs/numerics.py, ISSUE 10): this
+                # family's wire IS the raw gradient rows; the shadow re-votes
+                # over the quantized rows (deterministic rounding preserves
+                # within-group bitwise equality, the vote's soundness
+                # condition)
+                if numerics_mod.watch_enabled(cfg):
+                    if cfg.numerics_watch == "on":
+                        out.update(numerics_mod.numerics_columns(
+                            cfg, [grads], [vote_rows], voted))
+                    if cfg.shadow_wire != "off":
+                        out.update(numerics_mod.majvote_shadow(
+                            cfg, rep_code, grads, voted, vhealth, vkey,
+                            present, adv_mask, state.step))
+                # per-worker forensics columns (obs/forensics): the vote's own
+                # out-voted set ∪ non-finite ingest rows, packed with the
+                # present + seeded-adversary masks to ride the metric block
+                out.update(forensics_mod.pack_mask_columns(
+                    vhealth["flagged"] | forensics_mod.nonfinite_rows(grads),
+                    present, adv_mask))
+                # guard signals: finite vote + out-voted rows (vote
+                # disagreement) within the s budget
+                new_state = _maybe_guard(cfg, state, new_state, voted,
+                                         {"flagged": vhealth["flagged"]},
+                                         present, out)
             return new_state, out
 
     elif cfg.approach == "approx":
@@ -399,8 +414,8 @@ def build_train_setup(cfg: TrainConfig, mesh,
             grads, new_stats, losses, precs = jax.vmap(
                 lane, in_axes=(None, 0, 0, 0, 0)
             )(state.params, state.batch_stats, x, y, dkeys)
-            grads = jax.lax.with_sharding_constraint(grads, shard_w)
-            grads = faults_mod.corrupt_grads(grads, cfg, state.step)
+            grads = stack_rows(grads, shard_w)
+            grads = simulate_faults(grads, state)
             # the ONE shared encode→mask→decode→forensics sequence
             # (parallel/common.approx_aggregate — identical semantics with
             # the LM routes by construction)
@@ -410,17 +425,18 @@ def build_train_setup(cfg: TrainConfig, mesh,
                     r, shard_w),
                 cfg=cfg, adv_mask=adv_mask, step=state.step, mesh=mesh)
             new_state = apply_update(state, decoded, new_stats)
-            out = _metrics(losses, precs, present)
             # residual-vs-bound health + packed forensics masks (accused =
             # non-finite ingest rows only — a scheduled straggler is never
             # accused); one schema with the LM routes
             from draco_tpu.parallel.common import decode_health_metrics
 
-            out.update(decode_health_metrics(health, adv_mask, present))
-            # guard signals: finite decode + residual within its analytic
-            # bound (guards.assess's approx branch)
-            new_state = _maybe_guard(cfg, state, new_state, decoded, health,
-                                     present, out)
+            with jax.named_scope("draco_health"):
+                out = _metrics(losses, precs, present)
+                out.update(decode_health_metrics(health, adv_mask, present))
+                # guard signals: finite decode + residual within its
+                # analytic bound (guards.assess's approx branch)
+                new_state = _maybe_guard(cfg, state, new_state, decoded,
+                                         health, present, out)
             return new_state, out
 
     elif cfg.approach == "cyclic":
@@ -444,6 +460,18 @@ def build_train_setup(cfg: TrainConfig, mesh,
 
         decode_impl = resolve_decode_impl(cfg.decode_impl, mesh)
 
+        def ingest_health(grads):
+            """Ingest-row forensics — attribute non-finite rows BEFORE the
+            algebraic encode smears them (forensics.nonfinite_rows) — and
+            the grad-stage numerics columns (obs/numerics.py), computed
+            where the pre-encode rows still exist."""
+            with jax.named_scope("draco_health"):
+                bad_rows = forensics_mod.nonfinite_rows(grads)
+                grad_watch = (numerics_mod.stage_columns(
+                    "grad", [grads], cfg.shadow_block)
+                    if cfg.numerics_watch == "on" else {})
+            return bad_rows, grad_watch
+
         if cfg.redundancy == "shared":
 
             def compute_encoded(state, x, y):
@@ -454,16 +482,9 @@ def build_train_setup(cfg: TrainConfig, mesh,
                 grads, new_stats, losses, precs = jax.vmap(
                     lane, in_axes=(None, 0, 0, 0, 0)
                 )(state.params, state.batch_stats, x, y, dkeys)
-                grads = jax.lax.with_sharding_constraint(grads, shard_w)
-                grads = faults_mod.corrupt_grads(grads, cfg, state.step)
-                # ingest-row forensics: attribute non-finite rows BEFORE the
-                # algebraic encode smears them (forensics.nonfinite_rows)
-                bad_rows = forensics_mod.nonfinite_rows(grads)
-                # grad-stage numerics (obs/numerics.py): computed here,
-                # where the pre-encode rows still exist
-                grad_watch = (numerics_mod.stage_columns(
-                    "grad", [grads], cfg.shadow_block)
-                    if cfg.numerics_watch == "on" else {})
+                grads = stack_rows(grads, shard_w)
+                grads = simulate_faults(grads, state)
+                bad_rows, grad_watch = ingest_health(grads)
                 with jax.named_scope("draco_encode"):
                     if tree:
                         # each leaf group encodes with the shared small
@@ -480,20 +501,22 @@ def build_train_setup(cfg: TrainConfig, mesh,
 
             def compute_encoded(state, x, y):
                 x, y, dkeys = prep_rows(state, x, y)
-                # worker i gathers its hat_s batch rows: (n, hat_s, B, ...)
-                xw = x[batch_ids]
-                yw = y[batch_ids]
-                kw = dkeys[batch_ids]
-                # worker's BN stats replicated over its hat_s lanes
-                stats_w = (
-                    jax.tree.map(
-                        lambda t: jnp.broadcast_to(
-                            t[:, None], (n, hat_s) + t.shape[1:]),
-                        state.batch_stats,
+                with jax.named_scope("draco_input"):
+                    # worker i gathers its hat_s batch rows:
+                    # (n, hat_s, B, ...)
+                    xw = x[batch_ids]
+                    yw = y[batch_ids]
+                    kw = dkeys[batch_ids]
+                    # worker's BN stats replicated over its hat_s lanes
+                    stats_w = (
+                        jax.tree.map(
+                            lambda t: jnp.broadcast_to(
+                                t[:, None], (n, hat_s) + t.shape[1:]),
+                            state.batch_stats,
+                        )
+                        if has_bn
+                        else None
                     )
-                    if has_bn
-                    else None
-                )
                 def worker_lane(stats_i, x_i, y_i, k_i):
                     return jax.vmap(lane, in_axes=(None, 0, 0, 0, 0))(
                         state.params, stats_i, x_i, y_i, k_i
@@ -501,36 +524,35 @@ def build_train_setup(cfg: TrainConfig, mesh,
                 grads, new_stats, losses, precs = jax.vmap(worker_lane)(
                     stats_w, xw, yw, kw
                 )  # grads: (n, hat_s, d)
-                grads = jax.lax.with_sharding_constraint(
-                    grads, sharding(mesh, WORKER_ROWS3)
-                )
-                grads = faults_mod.corrupt_grads(grads, cfg, state.step)
-                # ingest-row forensics: any non-finite value in worker i's
-                # hat_s redundant lanes attributes to worker i
-                bad_rows = forensics_mod.nonfinite_rows(grads)
-                grad_watch = (numerics_mod.stage_columns(
-                    "grad", [grads], cfg.shadow_block)
-                    if cfg.numerics_watch == "on" else {})
+                grads = stack_rows(grads, sharding(mesh, WORKER_ROWS3))
+                grads = simulate_faults(grads, state)
+                # any non-finite value in worker i's hat_s redundant lanes
+                # attributes to worker i
+                bad_rows, grad_watch = ingest_health(grads)
                 with jax.named_scope("draco_encode"):
                     enc_re, enc_im = cyclic_mod.encode(code, grads)
-                # fold the per-sub-batch stats back to one per worker
-                new_stats = (
-                    jax.tree.map(lambda t: jnp.mean(t, axis=1), new_stats)
-                    if has_bn
-                    else None
-                )
-                return (enc_re, enc_im, new_stats, jnp.mean(losses, 1),
-                        jnp.mean(precs, 1), bad_rows, grad_watch)
+                # fold the per-sub-batch stats back to one per worker (the
+                # BN state's own update)
+                with jax.named_scope("draco_update"):
+                    new_stats = (
+                        jax.tree.map(lambda t: jnp.mean(t, axis=1),
+                                     new_stats)
+                        if has_bn
+                        else None
+                    )
+                with jax.named_scope("draco_health"):
+                    losses, precs = jnp.mean(losses, 1), jnp.mean(precs, 1)
+                return (enc_re, enc_im, new_stats, losses, precs, bad_rows,
+                        grad_watch)
 
         def step_body(state: TrainState, x, y, adv_mask, present=None):
             (enc_re, enc_im, new_stats, losses, precs, bad_rows,
              grad_watch) = compute_encoded(state, x, y)
-            with jax.named_scope("draco_encode"):
+            with jax.named_scope("draco_attack"):
                 enc_re, enc_im = attacks.inject_cyclic(
-                    enc_re, enc_im, adv_mask,
-                                                       cfg.err_mode, adv_mag,
-                                                       step=state.step,
-                                                       seed=cfg.seed)
+                    enc_re, enc_im, adv_mask, cfg.err_mode, adv_mag,
+                    step=state.step, seed=cfg.seed)
+            with jax.named_scope("draco_encode"):
                 if present is not None:
                     # straggler rows never arrive: zero-fill (erasures at known
                     # positions; decode recovers exactly within the budget —
@@ -554,8 +576,9 @@ def build_train_setup(cfg: TrainConfig, mesh,
                     enc_im = jax.lax.with_sharding_constraint(enc_im, shard_w)
             # in-graph decode projection — no d-length program constant
             # (rng.random_projection_factors_in_graph docstring)
-            rand_factor = drng.random_projection_factors_in_graph(cfg.seed,
-                                                                  dim)
+            with jax.named_scope("draco_input"):
+                rand_factor = drng.random_projection_factors_in_graph(
+                    cfg.seed, dim)
             # quantization-aware flag threshold + locator λ for the narrow
             # wire (obs/numerics.wire_decode_params; f32 keeps the exact
             # HEALTH_REL_TOL / λ=0 path bitwise)
@@ -626,40 +649,41 @@ def build_train_setup(cfg: TrainConfig, mesh,
                         with_health=True, impl=decode_impl,
                         rel_tol=rel_tol, lam=wire_lam, wire=wire)
             new_state = apply_update(state, decoded, new_stats)
-            out = _metrics(losses, precs, present)
-            out["honest_located"] = jnp.sum(honest.astype(jnp.int32))
-            # decode health (telemetry columns; coding/cyclic._locate_v
-            # docstring): residual ≈ 0 is the paper's exactness guarantee
-            # made observable, the flag set scores against the seeded
-            # schedules — all in-graph, no host traffic. One schema with
-            # the LM routes (common.decode_health_metrics; imported lazily,
-            # parallel/__init__ imports this module). The packed forensics
-            # masks ride along (accused = flagged ∪ loud ∪ bad_rows)
-            from draco_tpu.parallel.common import decode_health_metrics
+            with jax.named_scope("draco_health"):
+                out = _metrics(losses, precs, present)
+                out["honest_located"] = jnp.sum(honest.astype(jnp.int32))
+                # decode health (telemetry columns; coding/cyclic._locate_v
+                # docstring): residual ≈ 0 is the paper's exactness guarantee
+                # made observable, the flag set scores against the seeded
+                # schedules — all in-graph, no host traffic. One schema with
+                # the LM routes (common.decode_health_metrics; imported lazily,
+                # parallel/__init__ imports this module). The packed forensics
+                # masks ride along (accused = flagged ∪ loud ∪ bad_rows)
+                from draco_tpu.parallel.common import decode_health_metrics
 
-            health["bad_rows"] = bad_rows
-            # numerics observatory (obs/numerics.py, ISSUE 10): wire/agg
-            # stages + the shadow-quantized decode join the grad-stage
-            # columns from compute_encoded; decode_health_metrics merges
-            # the stash — the f32 decode above alone feeds the update
-            if numerics_mod.watch_enabled(cfg):
-                watch = dict(grad_watch)
-                if cfg.numerics_watch == "on":
-                    watch.update(numerics_mod.stage_columns(
-                        "wire", [enc_re, enc_im], cfg.shadow_block))
-                    watch.update(numerics_mod.stage_columns(
-                        "agg", [decoded], cfg.shadow_block))
-                if cfg.shadow_wire != "off":
-                    watch.update(numerics_mod.cyclic_shadow(
-                        cfg, code, enc_re, enc_im, decoded, health,
-                        rand_factor, leaf_offsets, present, adv_mask,
-                        state.step))
-                health["watch"] = watch
-            out.update(decode_health_metrics(health, adv_mask, present))
-            # guard signals: finite decode + loud residual + located rows
-            # beyond the locator budget (the beyond-budget fault class)
-            new_state = _maybe_guard(cfg, state, new_state, decoded, health,
-                                     present, out)
+                health["bad_rows"] = bad_rows
+                # numerics observatory (obs/numerics.py, ISSUE 10): wire/agg
+                # stages + the shadow-quantized decode join the grad-stage
+                # columns from compute_encoded; decode_health_metrics merges
+                # the stash — the f32 decode above alone feeds the update
+                if numerics_mod.watch_enabled(cfg):
+                    watch = dict(grad_watch)
+                    if cfg.numerics_watch == "on":
+                        watch.update(numerics_mod.stage_columns(
+                            "wire", [enc_re, enc_im], cfg.shadow_block))
+                        watch.update(numerics_mod.stage_columns(
+                            "agg", [decoded], cfg.shadow_block))
+                    if cfg.shadow_wire != "off":
+                        watch.update(numerics_mod.cyclic_shadow(
+                            cfg, code, enc_re, enc_im, decoded, health,
+                            rand_factor, leaf_offsets, present, adv_mask,
+                            state.step))
+                    health["watch"] = watch
+                out.update(decode_health_metrics(health, adv_mask, present))
+                # guard signals: finite decode + loud residual + located rows
+                # beyond the locator budget (the beyond-budget fault class)
+                new_state = _maybe_guard(cfg, state, new_state, decoded,
+                                         health, present, out)
             return new_state, out
 
     else:  # pragma: no cover
@@ -714,9 +738,11 @@ def build_train_setup(cfg: TrainConfig, mesh,
         def body(st, operand):
             x, y, adv_mask, present = operand
             st, metrics = step_body(st, x, y, adv_mask, present)
-            row = jnp.stack(
-                [jnp.asarray(metrics[k], jnp.float32) for k in metric_names]
-            )
+            with jax.named_scope("draco_health"):
+                row = jnp.stack(
+                    [jnp.asarray(metrics[k], jnp.float32)
+                     for k in metric_names]
+                )
             return st, row
 
         # presents=None threads through as an empty pytree: the scan slices

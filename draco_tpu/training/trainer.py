@@ -139,6 +139,8 @@ class Trainer:
             self._straggle_schedule = np.zeros(
                 (cfg.max_steps + 1, cfg.num_workers), dtype=bool)
         self._engine = None  # live ChunkedEngine while _run_chunked runs
+        # (label, key, callable, argument shapes) of the newest dispatch
+        self._dispatched = None
         self._autopilot = None  # cached control/autopilot.Autopilot
         self._eager_step = None  # newest completed eager step (escalation)
         self._sched_steps = cfg.max_steps  # rows precomputed in the schedules
@@ -393,10 +395,15 @@ class Trainer:
         win = profiler_window(profile_dir, profile_steps, self._is_main,
                               self.tracer,
                               on_stop=self.heartbeat.observe_device)
+        comp_end = None  # clock read that closed the previous step's t_comp
+        win.maybe_start(self._start_step)
         for step in range(self._start_step, n_steps + 1):
-            win.maybe_start(step)
             seg = Segments()
-            seg.begin("fetch")
+            # t_book: everything since the previous step's t_comp closed
+            # (the ``book`` span below + the loop's own turn-around); with
+            # it the records tile the loop: sum(t_book + t_fetch + t_comp)
+            # is the wall time from the first fetch to the last sync
+            seg.begin("fetch", since=comp_end, gap="book")
             with self.tracer.span("gather+upload", step=step):
                 x, y = self._device_batch(step)
                 # numpy (uncommitted) so multi-host jit treats it as
@@ -409,55 +416,92 @@ class Trainer:
                 )
             seg.end()
 
-            seg.begin("comp")  # fwd+bwd+encode+gather+decode+update, one program
+            # fwd+bwd+encode+gather+decode+update, one program; t_comp's
+            # parts: t_dispatch (the call until it returns), t_wait (the
+            # host waiting for the device: the loop's own per-step device
+            # time, a lower bound — t_dispatch + t_wait is the upper),
+            # t_drain (the metric columns, one device->host fetch each)
+            seg.begin("comp")
+            args = (self.state, x, y, mask) if present is None \
+                else (self.state, x, y, mask, present)
+            self._note_dispatch("train_step", self.setup.train_step, args)
+            win.note_program("train_step", self.setup.train_step, args)
             with self.tracer.span("dispatch", step=step), \
                     self.compile_watch.expect("train_step"):
-                if present is None:
-                    self.state, metrics = self.setup.train_step(self.state, x,
-                                                                y, mask)
-                else:
-                    self.state, metrics = self.setup.train_step(self.state, x,
-                                                                y, mask,
-                                                                present)
+                self.state, metrics = self.setup.train_step(*args)
+            del args  # the donated state must not outlive its call
+            seg.lap("dispatch")
             with self.tracer.span("sync", step=step):
-                # record_value: forensics bitmask columns materialize as
-                # exact integer words, everything else as float
-                metrics = {k: record_value(k, v) for k, v in metrics.items()}
-                if present is not None:
-                    metrics["present"] = float(present.sum())
-                jax.block_until_ready(self.state.params)
-            seg.end()
+                # wait FIRST, on the step's outputs, so the wait is not
+                # hidden inside the first column's fetch
+                with self.tracer.span("device_wait", step=step):
+                    jax.block_until_ready((metrics, self.state.params))
+                seg.lap("wait")
+                with self.tracer.span("drain", step=step,
+                                      columns=len(metrics)):
+                    # record_value: forensics bitmask columns materialize
+                    # as exact integer words, everything else as float
+                    metrics = {k: record_value(k, v)
+                               for k, v in metrics.items()}
+                    if present is not None:
+                        metrics["present"] = float(present.sum())
+            comp_end = seg.end(lap="drain")
 
-            win.maybe_stop(step, self.state.params)
-            self._eager_step = step  # escalated-stop checkpoint cursor
-            record = {"step": step, **metrics, **seg.as_dict()}
-            last = record
-            self.heartbeat.observe(record)
-            if step % cfg.log_every == 0 or step == 1:
-                self.writer.write(record)
-            boundary = cfg.eval_freq and step % cfg.eval_freq == 0
-            if boundary or step == n_steps:
-                with self.tracer.span("flush", at_step=step):
-                    self.writer.flush()
-                    self.heartbeat.beat(step, n_steps,
-                                        extra={**self._prefetch_depth(),
-                                               **self.compile_watch
-                                               .snapshot()})
-                    self.tracer.flush()
-            if boundary:
-                self.evaluate(step)
-                if cfg.train_dir:
-                    with self.tracer.span("ckpt", at_step=step):
-                        ckpt.save(cfg.train_dir, step, self.state,
-                                  compress=cfg.compress_ckpt,
-                                  keep=cfg.keep_checkpoints)
-            if self._check_stop(step):
-                with self.tracer.span("flush", at_step=step):
-                    self.writer.flush()
-                self._snap_stop(step, already_saved=bool(boundary))
-                break
+            with self.tracer.span("book", step=step):
+                win.maybe_stop(step, self.state.params)
+                self._eager_step = step  # escalated-stop checkpoint cursor
+                record = {"step": step, **metrics, **seg.as_dict()}
+                last = record
+                self.heartbeat.observe(record)
+                if step % cfg.log_every == 0 or step == 1:
+                    self.writer.write(record)
+                boundary = cfg.eval_freq and step % cfg.eval_freq == 0
+                if boundary or step == n_steps:
+                    with self.tracer.span("flush", at_step=step):
+                        self.writer.flush()
+                        self.heartbeat.beat(step, n_steps,
+                                            extra={**self._prefetch_depth(),
+                                                   **self.compile_watch
+                                                   .snapshot()})
+                        self.tracer.flush()
+                if boundary:
+                    self.evaluate(step)
+                    if cfg.train_dir:
+                        with self.tracer.span("ckpt", at_step=step):
+                            ckpt.save(cfg.train_dir, step, self.state,
+                                      compress=cfg.compress_ckpt,
+                                      keep=cfg.keep_checkpoints)
+                if self._check_stop(step):
+                    with self.tracer.span("flush", at_step=step):
+                        self.writer.flush()
+                    self._snap_stop(step, already_saved=bool(boundary))
+                    break
+                if step < n_steps:
+                    win.maybe_start(step + 1)
         win.stop(self.state.params)  # loop ended inside the window
         return last
+
+    def _note_dispatch(self, label: str, fn, args, key=None) -> None:
+        """Remember what is about to be dispatched — the callable and, as
+        shapes, every argument after the state (which the call donates and
+        ``self.state`` stands for) — for :meth:`dispatched_hlo`."""
+        if self._dispatched is None or self._dispatched[:2] != (label, key):
+            from draco_tpu.obs.profiling import abstract_args
+
+            self._dispatched = (label, key, fn, abstract_args(args[1:]))
+
+    def dispatched_hlo(self) -> str:
+        """Optimized-HLO text of the step program the loop dispatched last
+        (``train_step``, or ``train_many`` at its last chunk length), lowered
+        from that call's own argument types — the program a device trace of
+        this run names its events after (obs/device_attr.scope_map_from_hlo).
+        '' before the first step."""
+        if self._dispatched is None:
+            return ""
+        from draco_tpu.obs.profiling import abstract_args, program_text
+
+        _, _, fn, rest = self._dispatched
+        return program_text(fn, abstract_args((self.state,)) + rest)
 
     def _run_chunked(self, n_steps: int, profile_dir, profile_steps) -> dict:
         """The scan-fused loop, driven by the shared ``ChunkedEngine``

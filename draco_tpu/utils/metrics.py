@@ -105,6 +105,15 @@ class DeferredMetricWriter:
         chunk) or per-step sequence; values must already be host data."""
         self._pending.append((list(steps), tuple(names), block, extras or {}))
 
+    def wait(self) -> None:
+        """Block until the NEWEST pending block is ready (chunks run in
+        program order). On an attached device that is the whole wait; on a
+        remote-dispatch backend only :meth:`sync` proves execution."""
+        if self._pending:
+            ready = getattr(self._pending[-1][2], "block_until_ready", None)
+            if ready is not None:
+                ready()
+
     def sync(self) -> None:
         """Execution barrier: device→host fetch of one element of the
         NEWEST pending block. ``jax.block_until_ready`` only awaits dispatch
@@ -155,21 +164,46 @@ class Segments:
     t_fetch/t_comp values the way the old ``time.time()`` deltas could.
     The record-level ``time`` field (MetricWriter.write) deliberately stays
     wall-clock: it timestamps the record for humans; only durations need
-    monotonicity."""
+    monotonicity.
+
+    A segment has PARTS: ``lap(name)`` closes one inside the open segment
+    (from the previous lap, or the segment's begin, to now) and ``end(lap)``
+    closes the last with the segment's own final clock read, so the parts
+    tile the segment exactly (``t_dispatch + t_wait + t_drain == t_comp``).
+    ``begin(name, since=..., gap="book")`` books the time since ``since`` —
+    the previous step's ``end()`` — under ``gap``, so consecutive steps'
+    records tile the loop's wall time."""
 
     def __init__(self):
         self.t = {}
         self._start = None
+        self._lap = None
         self._name = None
 
-    def begin(self, name: str):
-        self._name, self._start = name, time.perf_counter()
+    def begin(self, name: str, since: Optional[float] = None,
+              gap: Optional[str] = None):
+        now = time.perf_counter()
+        if gap is not None:
+            # since=None: the run's first step, which follows nothing
+            self.t[gap] = 0.0 if since is None else max(now - since, 0.0)
+        self._name, self._start, self._lap = name, now, now
 
-    def end(self):
-        if self._name is not None:
-            self.t[self._name] = (self.t.get(self._name, 0.0)
-                                  + time.perf_counter() - self._start)
-            self._name = None
+    def lap(self, name: str, now=None):
+        now = time.perf_counter() if now is None else now
+        self.t[name] = self.t.get(name, 0.0) + now - self._lap
+        self._lap = now
+
+    def end(self, lap: Optional[str] = None) -> Optional[float]:
+        """Close the open segment (and its last part ``lap``); returns the
+        clock read it closed on, for the next step's ``begin(since=)``."""
+        if self._name is None:
+            return None
+        now = time.perf_counter()
+        if lap is not None:
+            self.lap(lap, now)
+        self.t[self._name] = self.t.get(self._name, 0.0) + now - self._start
+        self._name = None
+        return now
 
     def as_dict(self, prefix: str = "t_"):
         return {prefix + k: round(v, 6) for k, v in self.t.items()}

@@ -132,3 +132,61 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     # a kernel that quietly became plain XLA would pass a compile
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+def _resnet18_step_text(chip) -> str:
+    """The headline cell's step program (ResNet-18, cyclic s=1, n=8, r=3,
+    batch 32 a worker) compiled for the described chip: the mesh is built
+    from the described device and ``device_put`` hands back shapes, as
+    there is no device to hold an array (on-chip-measurement guide, 2)."""
+    import numpy as np
+    from unittest import mock
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from draco_tpu.config import TrainConfig
+    from draco_tpu.training.step import build_train_setup
+
+    def shapes_only(tree, sharding=None, **kw):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                           sharding=sharding), tree)
+
+    cfg = TrainConfig(network="ResNet18", dataset="synthetic-cifar10",
+                      approach="cyclic", num_workers=8, worker_fail=1,
+                      err_mode="rev_grad", redundancy="simulate",
+                      batch_size=32, lr=0.01, momentum=0.9, max_steps=8,
+                      eval_freq=0, train_dir="", decode_impl="auto")
+    mesh = Mesh(np.asarray([chip._device_assignment[0]]), ("w",))
+    with mock.patch.object(jax, "device_put", shapes_only), \
+            mock.patch.object(dk, "use_pallas", lambda: True):
+        setup = build_train_setup(cfg, mesh, dataset_name=cfg.dataset)
+    rows = NamedSharding(mesh, P("w"))
+    x = jax.ShapeDtypeStruct((8, 32, 32, 32, 3), jnp.float32, sharding=rows)
+    y = jax.ShapeDtypeStruct((8, 32), jnp.int32, sharding=rows)
+    return setup.train_step.lower(
+        setup.state, x, y, np.zeros((8,), bool)).compile().as_text()
+
+
+def test_resnet18_step_scopes_on_the_described_chip(one_chip, monkeypatch):
+    """The TPU program's own labels (ISSUE 24): at ResNet-18 width every
+    instruction the program wrote is under a ``draco_*`` scope, the Pallas
+    locator is there, and switching the four new scopes off changes no
+    instruction — only metadata (the kernel's serialized body holds the
+    caller's line number, which a scope does not move either)."""
+    from tests.test_step_scopes import (NEW_SCOPES, new_scopes_off,
+                                        scope_report, strip_metadata)
+
+    on = _resnet18_step_text(one_chip)
+    assert "tpu_custom_call" in on
+    report = scope_report(on)
+    assert report["share"] >= 0.95, report["unscoped"][:10]
+    # (no op of its own under draco_attack: the one reversed row fuses into
+    # the encode's fusions, which carry their root's label)
+    for scope in ("draco_comp", "draco_pack", "draco_input", "draco_health",
+                  "draco_encode", "draco_decode", "draco_update"):
+        assert report["bytes"].get(scope, 0) > 0, report["bytes"]
+    new_scopes_off(monkeypatch)
+    off = _resnet18_step_text(one_chip)
+    assert not any(s in off for s in NEW_SCOPES)
+    assert strip_metadata(on) == strip_metadata(off)

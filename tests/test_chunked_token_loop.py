@@ -277,9 +277,10 @@ def _assert_route_telemetry(route, kw, run_dir):
     assert not any(r["steady_recompile"] for r in ledger)
     assert any(e.get("cat") == "compile" for e in events)
     # the profiled window's device surface (ISSUE 9): capture + shared-clock
-    # anchor landed, and the heartbeat folded the capture into the
-    # ``device`` status block (no scope map on a plain --profile-dir run,
-    # so attribution honestly reads 0 — everything in the unattributed row)
+    # anchor landed, the window wrote the scope map of the two chunk
+    # programs it dispatched (k=3 and the k=1 remainder: one module name,
+    # folded as one) and the heartbeat folded the capture into the
+    # ``device`` status block
     from draco_tpu.obs import device_attr
 
     assert device_attr.find_capture(str(run_dir)) is not None
@@ -288,7 +289,8 @@ def _assert_route_telemetry(route, kw, run_dir):
     assert anchor["tracer_ts_us"] is not None
     dev = status["device"]
     assert dev["profiled_steps"] == 7 and dev["total_device_us"] > 0
-    assert dev["attributed_frac"] == 0.0 and dev["decode_share"] == 0.0
+    assert "error" not in dev, dev
+    assert dev["attributed_frac"] > 0.9 and dev["decode_share"] > 0.0
 
 
 def test_device_token_gen_bitwise_and_distinct():

@@ -5,6 +5,12 @@ live on a seeded extra-all-gather mismatch), the merged host+device
 timeline, the heartbeat ``device`` status block, and a core-marked live
 capture smoke on the CPU mesh.
 
+The chip's capture is an ``.xplane.pb`` whose device events are named by
+their instruction's whole HLO text (ISSUE 24): the xplane cases feed
+``events_from_planes`` planes rebuilt from the recorded TPU v5e trace the
+benchmark keeps (benchmark/testdata/tpu_v5e_two_steps.json.gz), and must
+read the same per-scope times the benchmark's own reducer reads from it.
+
 The committed fixture (tests/data/device_profile_fixture/) is a synthetic
 jax.profiler capture in the XLA:CPU fallback trace shape this container
 produces (PERF_HISTORY.md §12): hlo_module/hlo_op args on each complete event, the
@@ -29,9 +35,63 @@ FIXTURE = os.path.join(os.path.dirname(__file__), "data",
 #   draco_encode= fusion.7 250
 #   other       = call self (300-280=20) + all-gather.9 150 = 170
 #   unattributed= copy.5 50
-FIX_EXPECT = {"draco_comp": 400.0, "draco_encode": 250.0,
-              "draco_decode": 380.0, "draco_update": 0.0,
-              "other": 170.0, "unattributed": 50.0}
+FIX_EXPECT = {"draco_comp": 400.0, "draco_pack": 0.0, "draco_input": 0.0,
+              "draco_attack": 0.0, "draco_health": 0.0,
+              "draco_encode": 250.0, "draco_decode": 380.0,
+              "draco_update": 0.0, "other": 170.0, "unattributed": 50.0}
+
+RECORDED = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "testdata", "tpu_v5e_two_steps.json.gz")
+
+
+class _Ev:
+    def __init__(self, name, start_ns, duration_ns, stats=()):
+        self.name, self.start_ns = name, start_ns
+        self.duration_ns = duration_ns
+        self.stats = list(stats)
+
+
+class _Line:
+    def __init__(self, name, events):
+        self.name, self.events = name, events
+
+
+class _Plane:
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+def _recorded_planes():
+    """The recorded chip trace as the profiler's planes: the ``XLA Ops``
+    line as recorded (names are HLO instruction texts), an ``XLA Modules``
+    line with one event per step (the record holds two), and a host plane
+    with the window's anchor annotation."""
+    import gzip
+
+    with gzip.open(RECORDED, "rt") as fh:
+        rec = json.load(fh)
+    (plane, evs), = rec["devices"].items()
+    ops = [_Ev(n, s, d) for n, s, d in evs]
+    lo = min(e.start_ns for e in ops)
+    hi = max(e.start_ns + e.duration_ns for e in ops)
+    # the widest idle stretch of the record separates its two steps
+    edges = sorted((e.start_ns, e.start_ns + e.duration_ns) for e in ops)
+    reach, cut = edges[0][1], None
+    for a, b in edges[1:]:
+        if cut is None or a - reach > cut[1] - cut[0]:
+            cut = (reach, a) if a > reach else cut
+        reach = max(reach, b)
+    mods = [_Ev("jit_step_body(7)", lo, cut[0] - lo),
+            _Ev("jit_step_body(7)", cut[1], hi - cut[1])]
+    host = _Line("python", [_Ev("draco_anchor", rec["anchor_ns"], 2000.0),
+                            _Ev("dispatch", lo - 5000.0, 900.0)])
+    planes = [_Plane("/host:metadata", []),
+              _Plane(plane, [_Line("Steps", []), _Line("XLA Modules", mods),
+                             _Line("XLA Ops", ops),
+                             _Line("Async XLA Ops", [])]),
+              _Plane("/host:CPU", [host])]
+    return planes, {"module": "jit_step_body", "ops": rec["scope_map"],
+                    "collectives": {}}
 
 
 def _fixture_events():
@@ -119,13 +179,22 @@ def test_fixture_attribution_sums_to_window():
     fr = {k: v["frac"] for k, v in row["phases"].items()}
     assert sum(fr.values()) == pytest.approx(1.0)
     assert fr["draco_decode"] == pytest.approx(380.0 / 1250.0)
-    # a draco_* token OUTSIDE the ledger rows (a repo file path in a
-    # python-tracer frame name, or a future named scope) lands in the
-    # unattributed residual instead of crashing the fold
-    stray = [{"ph": "X", "name": "$/repo/draco_tpu/loop.py:28 _run",
-              "ts": 100.0, "dur": 10.0, "tid": 9}]
-    srow = da.attribute_phases(stray, _fixture_scope())
-    assert srow["phases"]["unattributed"]["time_us"] == pytest.approx(10.0)
+    # the chip's shape (xplane): an event is named by its instruction's
+    # whole HLO text and belongs to the module event that contains it; a
+    # scope the ledger predates lands in the unattributed residual, and an
+    # op outside every module event is left out
+    text = "%fusion.7 = (f32[8]{0:T(8)}, f32[8]) fusion(f32[8] %p), kind=kLoop"
+    planes = [_Plane("/device:TPU:0", [
+        _Line("XLA Modules", [_Ev("jit_many_body(3)", 0.0, 100e3)]),
+        _Line("XLA Ops", [_Ev(text, 10e3, 10e3),
+                          _Ev("%new.1 = f32[] add(%a, %b)", 30e3, 5e3),
+                          _Ev("%late.2 = f32[] add(%a, %b)", 200e3, 5e3)])])]
+    scope = dict(_fixture_scope())
+    scope["ops"] = dict(scope["ops"], **{"new.1": "draco_future"})
+    srow = da.attribute_phases(da.events_from_planes(planes), scope)
+    assert srow["matched_events"] == 2
+    assert srow["phases"]["draco_encode"]["time_us"] == pytest.approx(10.0)
+    assert srow["phases"]["unattributed"]["time_us"] == pytest.approx(5.0)
 
 
 @pytest.mark.core
@@ -138,13 +207,15 @@ def test_fixture_collective_ledger_and_cross_check():
     # reconciles against the linted manifest (missing kinds default 0)
     ok = da.cross_check(led, {"all_reduce": 1}, "fixture")
     assert ok["ok"] and ok["observed"]["all_reduce"] == 1
-    # TPU scope-in-name shape: an untagged event (no hlo_module) whose
-    # name carries the scope path uses the SAME selection as the phase
-    # ledger — the collective is counted, not dropped into an empty
-    # ledger that would then hard-fail the manifest cross-check
-    tpu = [{"ph": "X", "name": "jit(f)/draco_decode/psum",
-            "args": {"hlo_op": "all-reduce.3"},
-            "ts": 50.0, "dur": 20.0, "tid": 3}]
+    # the chip's shape (xplane): the collective's event is its HLO text;
+    # it uses the SAME selection as the phase ledger — counted, not
+    # dropped into an empty ledger that would then hard-fail the manifest
+    # cross-check
+    tpu = da.events_from_planes([_Plane("/device:TPU:0", [
+        _Line("XLA Modules", [_Ev("jit_many_body(3)", 0.0, 100e3)]),
+        _Line("XLA Ops", [_Ev("%all-reduce.3 = f32[256]{0} all-reduce("
+                              "f32[256]{0} %g), replica_groups={{0,1}}",
+                              50e3, 20e3)])])])
     tled = da.collective_ledger(tpu, _fixture_scope())
     assert tled["explicit"]["all_reduce"]["instructions"] == 1
     assert tled["explicit"]["all_reduce"]["time_us"] == pytest.approx(20.0)
@@ -287,9 +358,73 @@ def test_heartbeat_device_block(tmp_path):
     assert dev["profile_dir"] == FIXTURE
     on_disk = json.loads((tmp_path / "status.json").read_text())
     assert on_disk["device"]["profiled_steps"] == 5
-    # a dir with no capture folds nothing and never raises
+    # a fold that fails never raises, and is not dropped either: the
+    # block holds the one-line cause
     hb.observe_device(str(tmp_path))
-    assert hb.beat(3)["device"]["decode_share"] == dev["decode_share"]
+    assert "no capture" in hb.beat(3)["device"]["error"]
+    d = tmp_path / "plugins" / "profile" / "0001"
+    d.mkdir(parents=True)
+    (d / "torn.trace.json").write_text('{"traceEvents": [{"ph": "X"')
+    hb.observe_device(str(tmp_path))
+    assert "JSONDecodeError" in hb.beat(4)["device"]["error"]
+
+
+# --------------------------------------------------------------------------
+# the chip's capture: an xplane whose events are HLO instruction texts
+# --------------------------------------------------------------------------
+
+@pytest.mark.core
+def test_xplane_recorded_tpu_trace_reads_the_benchmarks_numbers():
+    """The recorded v5e trace through the program's own reducer: the same
+    per-scope seconds benchmark/harness/xplane.py reads from it
+    (tests/benchmark/test_benchmark_trace.py pins those)."""
+    planes, scope = _recorded_planes()
+    events = da.events_from_planes(planes)
+    ops = [e for e in events if e["ph"] == "X" and e.get("args")]
+    assert ops[0]["name"] == ops[0]["args"]["hlo_op"]
+    assert " = " not in ops[0]["name"] and not ops[0]["name"].startswith("%")
+    assert {e["args"]["hlo_module"] for e in ops} == {"jit_step_body"}
+    row = da.attribute_phases(events, scope)
+    got = {k: v["time_us"] * 1e-6 for k, v in row["phases"].items()}
+    assert got["draco_comp"] == pytest.approx(0.157849807, rel=1e-6)
+    assert got["draco_encode"] == pytest.approx(0.008155637, rel=1e-6)
+    assert got["draco_decode"] == pytest.approx(0.004225111, rel=1e-6)
+    assert got["draco_update"] == pytest.approx(0.000191346, rel=1e-6)
+    assert got["other"] == pytest.approx(0.028453528, rel=1e-6)
+    assert got["unattributed"] == 0.0
+    assert sum(got.values()) == pytest.approx(row["total_device_us"] * 1e-6)
+    block = da.device_status_block({"programs": [row], "anchor": {
+        "steps_profiled": 2}})
+    assert block["attributed_frac"] == 1.0
+    assert block["phase_fracs"]["draco_comp"] == pytest.approx(0.7937,
+                                                               abs=1e-4)
+
+
+@pytest.mark.core
+def test_xplane_anchor_annotation_puts_both_sides_on_one_clock():
+    """The window's ``draco_anchor`` annotation is the instant
+    ``tracer_ts_us`` stamps: the merge shifts by exactly their difference,
+    and the capture's host events stand in for a missing host trace."""
+    planes, scope = _recorded_planes()
+    events = da.events_from_planes(planes)
+    mark = next(e for e in events if e["name"] == da.ANCHOR_EVENT)
+    assert mark["cat"] == "host"
+    anchor = {"tracer_ts_us": 123456.0, "drained_tracer_ts_us": 9e9}
+    host = [{"name": "dispatch", "ph": "X", "ts": 1.0, "dur": 2.0,
+             "pid": 1, "tid": 1}]
+    merged = da.merge_timeline(host, events, scope, anchor,
+                               max_device_events=100)
+    mt = merged["mergedTimeline"]
+    assert mt["anchor_kind"] == "annotation"
+    assert mt["device_offset_us"] == pytest.approx(
+        123456.0 - (mark["ts"] + mark["dur"]))
+    # the host trace was given: the capture's own host events stay out
+    assert not [e for e in merged["traceEvents"] if e.get("cat") == "host"]
+    alone = da.merge_timeline([], events, scope, anchor,
+                              max_device_events=100)
+    names = {e["name"] for e in alone["traceEvents"]
+             if e.get("cat") == "host"}
+    assert names == {"draco_anchor", "dispatch"}
 
 
 # --------------------------------------------------------------------------

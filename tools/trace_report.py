@@ -88,6 +88,14 @@ def fold_counters(events: list) -> dict:
     return out
 
 
+# the loop's per-step host clocks (utils/metrics.Segments): t_fetch and
+# t_comp, t_comp's parts on the eager loop (the call until it returns, the
+# wait for the device, the metric columns' fetches), and the bookkeeping
+# between two steps — t_book + t_fetch + t_comp tile the loop's wall time
+SEGMENT_KEYS = ("t_fetch", "t_comp", "t_dispatch", "t_wait", "t_drain",
+                "t_book")
+
+
 def fold_metrics(path: str) -> dict:
     """Step count + summed per-step segment seconds from metrics.jsonl
     (t_fetch/t_comp are per-step amortized values, so their sums are the
@@ -106,7 +114,7 @@ def fold_metrics(path: str) -> dict:
         last = rec
         if first is None:
             first = rec
-        for key in ("t_fetch", "t_comp"):
+        for key in SEGMENT_KEYS:
             if key in rec:
                 sums[key] += float(rec[key])
         if "guard_trips" in rec:
@@ -123,7 +131,7 @@ def fold_metrics(path: str) -> dict:
                     break
     out = {"train_records": steps}
     out.update({f"{k}_total_s": round(v, 4) for k, v in sums.items()
-                if k in ("t_fetch", "t_comp")})
+                if k in SEGMENT_KEYS})
     if guard_seen:
         out["guard_trips"] = sums["guard_trips"]
         out["skipped_steps"] = sums["skipped_steps"]
