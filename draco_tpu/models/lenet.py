@@ -2,7 +2,12 @@
 
 conv(1→20, 5×5, VALID) → maxpool2 → relu → conv(20→50) → maxpool2 → relu →
 fc(800→500) → fc(500→10). Note the reference applies relu *after* the pool;
-kept as-is."""
+kept as-is.
+
+The pools are ``pooling.max_pool_2x2``, not ``nn.max_pool``, whose
+``reduce-window`` / ``select-and-scatter`` pair under the step builder's two
+``vmap``s ran 50–90 × off its roofline on the chip (46.4 of 145.8 device ms a
+step in ``vgg11.cyclic_s2``: ledger, PR 24; PERF.md §6, PR 25)."""
 
 from __future__ import annotations
 
@@ -10,6 +15,8 @@ from typing import Any
 
 import flax.linen as nn
 import jax.numpy as jnp
+
+from draco_tpu.models.pooling import max_pool_2x2
 
 
 class LeNet(nn.Module):
@@ -20,10 +27,10 @@ class LeNet(nn.Module):
     def __call__(self, x, train: bool = True):
         x = x.astype(self.dtype)
         x = nn.Conv(20, (5, 5), padding="VALID", dtype=self.dtype)(x)
-        x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        x = max_pool_2x2(x)
         x = nn.relu(x)
         x = nn.Conv(50, (5, 5), padding="VALID", dtype=self.dtype)(x)
-        x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        x = max_pool_2x2(x)
         x = nn.relu(x)
         x = x.reshape((x.shape[0], -1))  # (B, 4*4*50)
         x = nn.Dense(500, dtype=self.dtype)(x)

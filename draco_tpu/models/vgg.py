@@ -10,6 +10,12 @@ worker-dependent (two workers computing the same batch draw different dropout
 masks — decode there was only approximate). Here the dropout rng key is folded
 from (step, batch-id) by the trainer, so any worker computing batch k draws
 the same mask and both codes stay exactly decodable.
+
+The pool is ``pooling.max_pool_2x2``, not ``nn.max_pool``: under the step
+builder's two ``vmap``s ``nn.max_pool`` is a 6-D ``reduce-window`` and its
+backward a ``select-and-scatter`` behind two relayout copies — 46.4 of the
+145.8 device ms a step of ``vgg11.cyclic_s2`` (ledger, PR 24; 157 → 113 ms a
+step with the fused pool: PERF.md §6, PR 25). Do not "simplify" it back.
 """
 
 from __future__ import annotations
@@ -18,6 +24,8 @@ from typing import Any, Sequence, Union
 
 import flax.linen as nn
 import jax.numpy as jnp
+
+from draco_tpu.models.pooling import max_pool_2x2
 
 _CFG = {
     "A": (64, "M", 128, "M", 256, 256, "M", 512, 512, "M", 512, 512, "M"),
@@ -40,7 +48,7 @@ class VGG(nn.Module):
         x = x.astype(self.dtype)
         for v in self.cfg:
             if v == "M":
-                x = nn.max_pool(x, (2, 2), strides=(2, 2))
+                x = max_pool_2x2(x)
             else:
                 x = nn.Conv(int(v), (3, 3), padding=((1, 1), (1, 1)),
                             dtype=self.dtype)(x)

@@ -190,3 +190,49 @@ def test_resnet18_step_scopes_on_the_described_chip(one_chip, monkeypatch):
     off = _resnet18_step_text(one_chip)
     assert not any(s in off for s in NEW_SCOPES)
     assert strip_metadata(on) == strip_metadata(off)
+
+
+def test_vgg11_lanes_pool_as_fusions(one_chip):
+    """ISSUE 25: VGG-11's forward + backward under the step builder's two
+    ``vmap``s (n = 9 workers × r = 5 rows, batch cut to 8), once the chip's
+    compiler is done with it: the three pools over planes of 8×8 and more
+    are elementwise fusions; only the last two (4×4 and 2×2 planes, kept on
+    ``nn.max_pool``: models/pooling.py) are still a 2×2 ``reduce-window``
+    and a ``select-and-scatter``. With ``nn.max_pool`` throughout it held
+    5 + 5, a third of ``vgg11.cyclic_s2``'s device time (PERF.md §6, PR 25)."""
+    import re
+
+    from draco_tpu.models import build_model
+
+    model = build_model("VGG11")
+    image = (32, 32, 3)
+    params = jax.eval_shape(
+        lambda k: model.init({"params": k, "dropout": k},
+                             jnp.zeros((1, *image)), train=False)["params"],
+        jax.random.key(0))
+
+    def loss(p, x, y, key):
+        logits = model.apply({"params": p}, x, train=True,
+                             rngs={"dropout": jax.random.wrap_key_data(key)})
+        logp = jax.nn.log_softmax(logits)
+        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+
+    def lanes(p, x, y, keys):
+        lane = jax.vmap(jax.grad(loss), in_axes=(None, 0, 0, 0))
+        return jax.vmap(lane, in_axes=(None, 0, 0, 0))(p, x, y, keys)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lanes).lower(
+        jax.tree.map(lambda v: arg(v.shape, v.dtype), params),
+        arg((9, 5, 8, *image), jnp.float32), arg((9, 5, 8), jnp.int32),
+        arg((9, 5, 2), jnp.uint32)).compile().as_text()
+    # (n, r, batch, rows, columns, channels) of each window operation left
+    pooled = re.findall(
+        r" = \w+\[9,5,8,(\d+),(\d+),\d+\]\S* reduce-window\(.*"
+        r"window=\{size=1x1x1x2x2x1", text)
+    scattered = re.findall(
+        r" = \w+\[9,5,8,(\d+),(\d+),\d+\]\S* select-and-scatter\(", text)
+    assert sorted(pooled) == [("1", "1"), ("2", "2")], pooled
+    assert sorted(scattered) == [("2", "2"), ("4", "4")], scattered
