@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import argparse
 
-from draco_tpu.config import AGG_MODES, SEED, TrainConfig
+from draco_tpu.config import AGG_MODES, SEED, TOKEN_NETWORKS, TrainConfig
 
 
 def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
@@ -152,6 +152,11 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     p.add_argument("--model-dim", type=int, default=128)
     p.add_argument("--model-heads", type=int, default=4)
     p.add_argument("--model-layers", type=int, default=2)
+    p.add_argument("--model-spec", type=str, default="",
+                   help="network=LatentMoeLM: a JSON file holding the one "
+                        "mapping that states the model (a published "
+                        "config.json's keys plus layers / experts_held / "
+                        "vocab_rows; models/latent_moe.py)")
     p.add_argument("--cpu-mesh", type=int, default=0, metavar="N",
                    help="force an N-device virtual CPU mesh (testing without TPUs)")
     p.add_argument("--steps-per-call", type=int, default=1,
@@ -372,6 +377,15 @@ def maybe_force_cpu_mesh(args: argparse.Namespace) -> None:
         jax.config.update("jax_platforms", "cpu")
 
 
+def _load_model_spec(path: str):
+    if not path:
+        return None
+    import json
+
+    with open(path) as fh:
+        return json.load(fh)
+
+
 def config_from_args(args: argparse.Namespace) -> TrainConfig:
     return TrainConfig(
         network=args.network,
@@ -454,6 +468,7 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
         model_dim=args.model_dim,
         model_heads=args.model_heads,
         model_layers=args.model_layers,
+        model_spec=_load_model_spec(args.model_spec),
     ).validate()
 
 
@@ -482,7 +497,7 @@ def main(argv=None):
     else:
         cfg = config_from_args(args)
     profile_dir = args.profile_dir or None
-    if cfg.network == "TransformerLM":
+    if cfg.network in TOKEN_NETWORKS:
         # model-parallel paths compose with coded DP on 2-D (w × axis)
         # meshes; config.validate() guarantees at most one axis is active.
         # One mesh rule for all of them (parallel/mesh._make_mesh_w2): the

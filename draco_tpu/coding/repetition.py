@@ -92,7 +92,8 @@ def _row_bits(rows: jnp.ndarray) -> jnp.ndarray:
 
 
 def _row_fingerprints(rows: jnp.ndarray, key=None):
-    """(G, r, d) -> two (G, r) uint32 mix-then-sum hashes of each row's bits.
+    """(G, r, d) — or (G, r, ...), a row laid out in several axes — -> two
+    (G, r) uint32 mix-then-sum hashes of each row's bits.
 
     Per position j: keyed avalanche of the element's bits, wrapping-ADD the
     avalanched position, avalanche again, then wrapping-sum over j. The
@@ -106,21 +107,60 @@ def _row_fingerprints(rows: jnp.ndarray, key=None):
     direct-call/test path). All elementwise uint32 ops: one O(d) pass per
     row either way.
     """
-    bits = _row_bits(rows).astype(jnp.uint32)
-    j = jax.lax.iota(jnp.uint32, bits.shape[-1])
     if key is None:
         s1 = jnp.uint32(0x9E3779B1)
         s2 = jnp.uint32(0xC2B2AE35)
     else:
         salts = jax.random.bits(key, (2,), jnp.uint32)
         s1, s2 = salts[0], salts[1]
-    posmix = _splitmix32(j * jnp.uint32(2654435761) + jnp.uint32(0x9E3779B9))
-    h1 = jnp.sum(_splitmix32(_splitmix32(bits ^ s1) + posmix),
-                 axis=-1, dtype=jnp.uint32)
-    h2 = jnp.sum(_splitmix32(_splitmix32(bits ^ s2 ^ jnp.uint32(0x7F4A7C15))
-                             + posmix),
-                 axis=-1, dtype=jnp.uint32)
+    s2 = s2 ^ jnp.uint32(0x7F4A7C15)
+
+    def terms(block, j):
+        """The two hashes' per-position terms of rows[..., j]."""
+        bits = _row_bits(block).astype(jnp.uint32)
+        posmix = _splitmix32(j * jnp.uint32(2654435761)
+                             + jnp.uint32(0x9E3779B9))
+        return (_splitmix32(_splitmix32(bits ^ s1) + posmix),
+                _splitmix32(_splitmix32(bits ^ s2) + posmix))
+
+    # a row — (d,), or laid out in several axes, positions counting
+    # row-major — a block of its first axis at a time (one block where the
+    # row is no longer than FINGERPRINT_BLOCK): as one pass over a long row
+    # the bit view of the whole stack and the d-length position mix were
+    # materialised (6.3 + 1.6 GB at three rows of d = 425 M, on a chip the
+    # stack itself fills). The wrapping sum does not care in which order it
+    # is taken: same bits. The last block is clamped back inside the row
+    # and the positions an earlier block already counted are left out.
+    trail = rows.shape[2:]
+    d = int(np.prod(trail))
+    m, inner = trail[0], d // trail[0]
+    mb = min(m, max(FINGERPRINT_BLOCK // inner, 1))
+    ones = (1,) * (len(trail) - 1)
+    within = jnp.arange(inner, dtype=jnp.int32).reshape(trail[1:])
+    axes = tuple(range(2, rows.ndim))
+
+    def body(acc, i):
+        start = jnp.minimum(i * mb, m - mb)
+        lead = (start + jax.lax.iota(jnp.int32, mb)).reshape((mb,) + ones)
+        t1, t2 = terms(jax.lax.dynamic_slice_in_dim(rows, start, mb, axis=2),
+                       (lead * inner + within).astype(jnp.uint32))
+        new = lead >= i * mb
+        return (acc[0] + jnp.sum(jnp.where(new, t1, 0), axis=axes,
+                                 dtype=jnp.uint32),
+                acc[1] + jnp.sum(jnp.where(new, t2, 0), axis=axes,
+                                 dtype=jnp.uint32)), None
+
+    zero = jnp.zeros(rows.shape[:2], jnp.uint32)
+    (h1, h2), _ = jax.lax.scan(body, (zero, zero),
+                               jnp.arange(-(-m // mb), dtype=jnp.int32))
     return h1, h2
+
+
+# positions of a row fingerprinted at a time. A shorter row is one block
+# (every CNN and LM the other tests vote on); several blocks with a clamped
+# last one: tests/test_lm_maj_vote.py's several-axes and lanes-in-turn
+# cases, and the d = 425 M cell
+FINGERPRINT_BLOCK = 2**22
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,7 +189,10 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
                   present=None, key=None,
                   method: str = "fingerprint",
                   with_health: bool = False):
-    """grads: (n, d) -> (d,) mean over groups of each group's majority row.
+    """grads: (n, d) -> (d,) mean over groups of each group's majority row
+    ((n, ...) -> (...): a stack whose rows are laid out in several axes, as
+    a large one is so that the chip's tiling neither pads n nor makes writing
+    one row cost the whole stack; positions count row-major).
 
     ``present``: optional (n,) bool — absent members (stragglers) neither
     vote nor can win; a group with no present member contributes nothing and
@@ -181,11 +224,12 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
         known-missing, never "detected").
     """
     g, r = code.num_groups, code.r
-    rows = grads.reshape(g, r, -1)
+    rows = grads.reshape((g, r) + grads.shape[1:])
+    trail = tuple(range(3, rows.ndim + 1))  # a row's axes, after (G, r, r)
     # pairwise-equality counts, (G, r): agree[g, i] = #{j : row_i == row_j}
     if method == "exact":
         bits = _row_bits(rows)
-        eq = jnp.all(bits[:, :, None, :] == bits[:, None, :, :], axis=-1)
+        eq = jnp.all(bits[:, :, None] == bits[:, None, :], axis=trail)
     elif method == "fingerprint":
         # 64-bit row fingerprints (O(r·d)) — see module docstring
         h1, h2 = _row_fingerprints(rows, key=key)
@@ -199,16 +243,19 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
         pres = jnp.ones((g, r), bool)
         agree = jnp.sum(eq, axis=-1)
         winner = jnp.argmax(agree, axis=-1)  # (G,)
-        picked = jnp.take_along_axis(rows, winner[:, None, None], axis=1)[:, 0, :]
+        picked = jnp.take_along_axis(
+            rows, winner.reshape((g,) + (1,) * (rows.ndim - 1)), axis=1)[:, 0]
         voted = jnp.mean(picked, axis=0)
     else:
         pres = present.reshape(g, r)
         agree = jnp.sum(eq & pres[:, None, :], axis=-1)  # only present members vote
         agree = jnp.where(pres, agree, -1)  # absent members cannot win
         winner = jnp.argmax(agree, axis=-1)
-        picked = jnp.take_along_axis(rows, winner[:, None, None], axis=1)[:, 0, :]
+        picked = jnp.take_along_axis(
+            rows, winner.reshape((g,) + (1,) * (rows.ndim - 1)), axis=1)[:, 0]
         group_alive = jnp.any(pres, axis=1).astype(grads.dtype)  # (G,)
-        voted = (group_alive @ picked) / jnp.maximum(jnp.sum(group_alive), 1.0)
+        voted = (jnp.tensordot(group_alive, picked, axes=1)
+                 / jnp.maximum(jnp.sum(group_alive), 1.0))
     if not with_health:
         return voted
     # member i agrees with its group's winner iff eq[g, i, winner_g]

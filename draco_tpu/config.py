@@ -23,11 +23,18 @@ SEED = 428
 AGG_MODES = ("normal", "geometric_median", "krum", "coord_median",
              "trimmed_mean", "multi_krum", "bulyan")
 
+# Networks that train on token sequences through the shared token loop
+# (parallel/token_loop.py) and come from models.build_lm; everything else is
+# an image model on the CNN Trainer.
+TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM")
+
 
 @dataclasses.dataclass
 class TrainConfig:
     # --- model / data (reference: distributed_nn.py:27-37) ---
-    network: str = "LeNet"  # LeNet | FC | ResNet18/34/50/101/152 | VGG11/13/16/19[_bn]
+    # LeNet | FC | ResNet18/34/50/101/152 | VGG11/13/16/19[_bn] | the token
+    # models TransformerLM | LatentMoeLM (TOKEN_NETWORKS)
+    network: str = "LeNet"
     dataset: str = "MNIST"  # MNIST | Cifar10 | synthetic variants
     data_dir: str = "./data"
     batch_size: int = 128  # per-worker batch size
@@ -162,6 +169,12 @@ class TrainConfig:
     model_dim: int = 128
     model_heads: int = 4
     model_layers: int = 2
+    # network=LatentMoeLM: the ONE mapping that states the model — a
+    # published config.json's keys verbatim plus ``layers``,
+    # ``experts_held`` ([first, count]) and ``vocab_rows``, the chip's share
+    # of a deployment (models/latent_moe.py). The model_* fields above are
+    # TransformerLM's and are not read for it. CLI: --model-spec <file.json>.
+    model_spec: Optional[dict] = None
 
     # --- precision ---
     compute_dtype: str = "float32"  # forward/backward dtype (bfloat16|float32)
@@ -599,7 +612,7 @@ class TrainConfig:
             raise ValueError(
                 f"token_gen must be host|device, got {self.token_gen}"
             )
-        if self.token_gen == "device" and self.network != "TransformerLM":
+        if self.token_gen == "device" and self.network not in TOKEN_NETWORKS:
             # the CNN Trainer trains on dataset rows, not a generated token
             # stream — there is nothing for the in-graph generator to replace
             raise ValueError(
@@ -884,14 +897,67 @@ class TrainConfig:
                         f"{self.mode} needs num_workers - straggle_count > "
                         f"2 * worker_fail ({n} - {e} <= {2 * s})"
                     )
-        if self.network == "TransformerLM":
-            if self.approach == "maj_vote":
+        if self.network in TOKEN_NETWORKS and self.approach == "maj_vote":
+            # the vote runs on the single-shard token route
+            # (parallel/sp_step.py at seq_shards == 1): the loop feeds a
+            # group's members the same rows and the lanes agree bitwise.
+            # Everything else about it on a token model is refused by name.
+            sharded = [name for name in ("seq_shards", "tensor_shards",
+                                         "expert_shards", "pipeline_shards")
+                       if getattr(self, name) > 1]
+            if self.pp_microbatches > 0:
+                sharded.append("pp_microbatches")
+            if sharded:
                 raise ValueError(
-                    "approach=maj_vote is not supported for TransformerLM: the "
-                    "vote's bitwise-equality contract is specified over "
-                    "replicated CNN lanes (use baseline or cyclic; "
-                    "draco_tpu/parallel/sp_step.py)"
+                    f"approach=maj_vote on {self.network} runs on the "
+                    f"single-shard token route only, got {sharded} > 1: "
+                    "under a model-parallel axis a group member is a whole "
+                    "mesh row, which the token loop does not replicate"
                 )
+            for name, off in (("wire_dtype", "f32"),
+                              ("numerics_watch", "off"),
+                              ("shadow_wire", "off")):
+                if getattr(self, name) != off:
+                    raise ValueError(
+                        f"approach=maj_vote on {self.network} with "
+                        f"{name}={getattr(self, name)!r} is not implemented "
+                        "(the narrow wire and the numerics observatory of "
+                        "the vote live on the CNN path, training/step.py)"
+                    )
+        if self.network == "LatentMoeLM":
+            from draco_tpu.models.latent_moe import check_spec
+
+            check_spec(self.model_spec)
+            for name in ("seq_shards", "tensor_shards", "expert_shards",
+                         "pipeline_shards"):
+                if getattr(self, name) > 1:
+                    raise ValueError(
+                        f"{name}={getattr(self, name)} with network="
+                        "LatentMoeLM is not implemented: the block runs on "
+                        "the single-shard token route (its experts are the "
+                        "chip's share of a deployment, stated in "
+                        "model_spec['experts_held'], not an ep mesh axis)"
+                    )
+            if self.pp_microbatches > 0 or self.moe_experts > 0 \
+                    or self.scan_layers:
+                raise ValueError(
+                    "pp_microbatches / moe_experts / scan_layers are "
+                    "TransformerLM's and do not apply to network=LatentMoeLM "
+                    "(its experts come from model_spec)"
+                )
+            if self.attn_impl not in ("dense", "flash"):
+                raise ValueError(
+                    f"attn_impl must be dense|flash, got {self.attn_impl}"
+                )
+            if self.vocab != self.model_spec["vocab_rows"]:
+                raise ValueError(
+                    f"vocab {self.vocab} must equal model_spec['vocab_rows'] "
+                    f"{self.model_spec['vocab_rows']}: token ids are drawn "
+                    "from the slice the head scores"
+                )
+            if self.seq_len < 2:
+                raise ValueError("LatentMoeLM needs seq_len >= 2")
+        elif self.network == "TransformerLM":
             if self.model_dim % self.model_heads != 0:
                 raise ValueError(
                     f"model_dim {self.model_dim} not divisible by "
@@ -1008,6 +1074,8 @@ class TrainConfig:
                     )
             if self.seq_len < 2 or self.vocab < 2:
                 raise ValueError("TransformerLM needs seq_len >= 2 and vocab >= 2")
+        elif self.model_spec is not None:
+            raise ValueError("model_spec requires network=LatentMoeLM")
         elif self.seq_shards > 1:
             raise ValueError("seq_shards > 1 requires network=TransformerLM")
         elif self.tensor_shards > 1:

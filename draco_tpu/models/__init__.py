@@ -5,6 +5,14 @@ The reference carries two copies of every model: a plain nn.Module and a
 over MPI as soon as it exists (reference: src/model_ops/resnet_split.py:431-623).
 Under XLA the overlap the Split models bought is the compiler's job (async
 collectives + latency hiding), so there is exactly one copy of each model here.
+
+Token models (``TOKEN_NETWORKS``, config.py) come from :func:`build_lm`, the
+one factory the LM step builders call: ``TransformerLM`` (the repo's own
+pre-LN / GELU / tied-head block, the only one the tp / ep / pp / sequence-
+sharded routes build) and ``LatentMoeLM`` (models/latent_moe.py: a block that
+states a published config — RMS norm, SwiGLU, latent key/value attention,
+sigmoid top-k routing without drops over the experts this chip holds, shared
+experts, untied head — on the single-shard route of parallel/sp_step.py).
 """
 
 from draco_tpu.models.fc import FC_NN
@@ -54,12 +62,12 @@ def build_model(name: str, num_classes: int = 10, dtype=None):
     baseline_master.py:30-47 / baseline_worker.py:37-50). ``dtype``: compute
     dtype for the conv/dense stacks ("bfloat16" rides the MXU at full rate;
     params, BN stats and logits stay float32)."""
-    if name == "TransformerLM":
+    if name in ("TransformerLM", "LatentMoeLM"):
         raise ValueError(
-            "TransformerLM is a token model and does not run on the image "
+            f"{name} is a token model and does not run on the image "
             "pipeline; the CLI routes it automatically, or construct it via "
             "draco_tpu.parallel.sp_step.build_sp_train_setup (all knobs) / "
-            "draco_tpu.models.TransformerLM directly"
+            "draco_tpu.models.build_lm directly"
         )
     if name not in _REGISTRY:
         raise ValueError(f"unknown network: {name} (have {sorted(_REGISTRY)})")
@@ -69,6 +77,64 @@ def build_model(name: str, num_classes: int = 10, dtype=None):
 
         kwargs["dtype"] = jnp.dtype(dtype)
     return _REGISTRY[name](**kwargs)
+
+
+class _FlaxTokenLM:
+    """TransformerLM behind the token-model surface of :func:`build_lm`."""
+
+    stat_names = ()  # no per-step counters of its own
+
+    def __init__(self, module, init_module, seq_len: int):
+        self.module, self._init_module = module, init_module
+        self._init_len = min(seq_len, 8)
+
+    def init(self, key):
+        import jax.numpy as jnp
+
+        toks = jnp.zeros((1, self._init_len), jnp.int32)
+        # single-shard (dense attention) init: the shapes are the same
+        return self._init_module.init({"params": key}, toks,
+                                      train=True)["params"]
+
+    def token_nll(self, params, tokens, targets, pos_offset=0,
+                  train: bool = True):
+        import jax
+        import jax.numpy as jnp
+
+        logits = self.module.apply({"params": params}, tokens,
+                                   pos_offset=pos_offset, train=train)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return nll, {}
+
+
+def build_lm(cfg, attn_fn=None, kernel_fn=None):
+    """The token model of ``cfg.network``: an object with ``init(key) ->
+    params``, ``token_nll(params, tokens (B, T), targets (B, T),
+    pos_offset, train) -> (per-position negative log-likelihood (B, T)
+    float32, per-step counters {name: scalar})`` and ``stat_names``, those
+    counters' names in the metric row's order (empty where a model has
+    none). The route offers two attentions ((q, k, v) -> o) and each model
+    takes the one it can use: ``attn_fn``, the route's own (sequence-
+    parallel wrappers included; equal head sizes), and ``kernel_fn``, the
+    bare single-device kernel, which ``LatentMoeLM`` takes because its q/k
+    and v differ in head size. None is each model's plain lowering."""
+    import jax.numpy as jnp
+
+    cdtype = jnp.dtype(cfg.compute_dtype)
+    if cfg.network == "LatentMoeLM":
+        from draco_tpu.models.latent_moe import LatentMoeLM
+
+        return LatentMoeLM(cfg.model_spec, attn_fn=kernel_fn, dtype=cdtype,
+                           remat=cfg.remat)
+    if cfg.network != "TransformerLM":
+        raise ValueError(f"{cfg.network!r} is not a token model")
+    kw = dict(vocab=cfg.vocab, dim=cfg.model_dim, heads=cfg.model_heads,
+              layers=cfg.model_layers, experts=cfg.moe_experts, dtype=cdtype,
+              scan_layers=cfg.scan_layers)
+    return _FlaxTokenLM(
+        TransformerLM(attn_fn=attn_fn, remat=cfg.remat, **kw),
+        TransformerLM(attn_fn=None, **kw), cfg.seq_len)
 
 
 def input_shape(dataset: str):

@@ -1,0 +1,451 @@
+"""Decoder LM built from a published config mapping: latent key/value
+attention (MLA), sigmoid-routed experts with shared experts, RMS norm,
+SwiGLU, untied head — the ``deepseek_v3`` family's block (kakaocorp
+kanana-2-30b-a3b is the configuration the benchmark runs).
+
+One mapping states the model (``TrainConfig.model_spec``): the published
+``config.json`` keys verbatim, plus three that say what THIS chip holds of a
+deployment that shares each layer among several chips:
+
+  ``layers``        depth kept (further layers lie on further pipeline stages)
+  ``experts_held``  [first, count]: the routed experts this chip holds
+  ``vocab_rows``    rows of the vocabulary slice (ids, logits and loss are
+                    over the slice)
+
+Per layer, x (T, hidden):
+
+  h = RMSNorm(x). q = h·Wq → (T, H, nope+rope). h·Wkva → [c | k_rope];
+  c = RMSNorm(c); c·Wkvb → (T, H, nope+v) = [k_nope | v]. RoPE (interleaved
+  pairs) on q_rope and on the ONE k_rope all heads share. k = [k_nope |
+  k_rope]; x += causal softmax(q·kᵀ/√(nope+rope))·v · Wo.
+  h = RMSNorm(x). Layers < first_k_dense_replace: x += SwiGLU(h). Expert
+  layers: s = sigmoid(h·Wg) (float32, ``highest``); chosen = top-k of s + b
+  (b = ``e_score_correction_bias``: a leaf of the tree so the seeded weights
+  reach it, but it takes no gradient — its update rule is not in the config,
+  it is held fixed); w = s[chosen]/(Σ s[chosen] + 1e-20) · routed_scaling;
+  x += Σ_{e ∈ chosen ∩ held} w_e·SwiGLU_e(h) + SwiGLU_shared(h).
+
+The expert layer is TOLD which experts it holds, scores all of them, takes
+the top-k of all of them and computes its own experts' part: what the
+experts held elsewhere would add is left out (no code stands in for the
+absent chips or their exchange). No token is ever dropped: the dispatch
+buffer holds every (token, choice) pair, T·k rows, sorted by expert with
+this chip's experts first, and the held groups go through one grouped
+product each (on a TPU jax's own megablox kernel, which skips the tiles of
+experts not held — chosen over ``lax.ragged_dot`` after measuring both on
+the chip, PERF.md section 6; elsewhere a dense masked product).
+
+Device scopes (nested in the step's ``draco_comp``): ``draco_attn`` (MLA
+whole), ``draco_route`` (scores, top-k, sort, gather, combine),
+``draco_experts`` (every feed-forward: layer 0's dense one, the shared and
+the routed experts), ``draco_head`` (final norm, logits, loss).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from draco_tpu.ops.coded import use_pallas
+
+# the published config keys the block reads (model_spec must carry them)
+SPEC_KEYS = (
+    "hidden_size", "intermediate_size", "moe_intermediate_size",
+    "num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
+    "qk_rope_head_dim", "v_head_dim", "q_lora_rank", "rope_theta",
+    "rope_interleave", "rope_scaling", "rms_norm_eps",
+    "first_k_dense_replace", "n_routed_experts", "n_shared_experts",
+    "num_experts_per_tok", "norm_topk_prob", "routed_scaling_factor",
+    "scoring_func", "topk_method", "n_group", "topk_group",
+    "tie_word_embeddings", "hidden_act",
+    # the chip's share
+    "layers", "experts_held", "vocab_rows",
+)
+INIT_STD = 0.02  # initializer_range is not in the published config
+# The embedding alone is seeded at unit scale. Every block reads its input
+# through an RMS norm, so what a block adds does not shrink with its input:
+# beside rows of std 0.02 the stream after layer 0 is the attention's running
+# mean of v — the same vector at every position — and every token of a
+# sequence takes the same six experts (measured at the published widths:
+# single experts of the eight held got 0 to 2 982 of 4 096 tokens, the chip's
+# share 3 096 to 7 891 pairs by seed, and the step time followed it). A
+# trained model's stream is the token's own, as it is here at unit scale.
+EMBED_STD = 1.0
+BIAS_STD = 0.02  # e_score_correction_bias: moves the top-6, not the load
+# per-step counters of the expert layers (token-expert pairs that landed on
+# the experts held, over all expert layers; the fullest held expert over the
+# mean one; pairs that got no row), averaged over lanes by the caller
+STAT_NAMES = ("moe_assignments_held", "moe_load_max_over_mean",
+              "moe_dropped")
+
+
+def check_spec(spec) -> None:
+    """Raise ValueError, naming the key, for a mapping this block cannot
+    state. What the block does not implement is refused by name."""
+    if not isinstance(spec, dict):
+        raise ValueError("model_spec must be a mapping of the published "
+                         "config keys plus layers/experts_held/vocab_rows")
+    missing = [k for k in SPEC_KEYS if k not in spec]
+    if missing:
+        raise ValueError(f"model_spec lacks {missing}")
+    want = {"q_lora_rank": None, "rope_scaling": None,
+            "rope_interleave": True, "scoring_func": "sigmoid",
+            "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+            "tie_word_embeddings": False, "hidden_act": "silu"}
+    for key, value in want.items():
+        if spec[key] != value:
+            raise ValueError(
+                f"model_spec[{key!r}] = {spec[key]!r}: this block implements "
+                f"{value!r} only")
+    first, count = spec["experts_held"]
+    if not (0 <= first and count >= 1
+            and first + count <= spec["n_routed_experts"]):
+        raise ValueError(
+            f"model_spec['experts_held'] = {spec['experts_held']}: a "
+            f"[first, count] range inside the {spec['n_routed_experts']} "
+            f"routed experts")
+    if spec["num_experts_per_tok"] > spec["n_routed_experts"]:
+        raise ValueError("num_experts_per_tok exceeds n_routed_experts")
+    if spec["qk_rope_head_dim"] % 2:
+        raise ValueError("qk_rope_head_dim must be even for the rotary pairs")
+    if not 1 <= spec["first_k_dense_replace"] <= spec["layers"]:
+        raise ValueError("first_k_dense_replace must lie in [1, layers]")
+    if spec["vocab_rows"] < 2:
+        raise ValueError("vocab_rows must be >= 2")
+
+
+def _operand(x):
+    """What a kernel's product is handed. On the TPU a float32 product at
+    default precision rounds its operands to bfloat16 and accumulates in
+    float32; the Pallas kernels take the type they are given, so they are
+    given what XLA's own products get. Elsewhere products are float32."""
+    return x.astype(jnp.bfloat16) if use_pallas() else x
+
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                        + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def rope_interleaved(x, positions, theta):
+    """Rotate the pairs (x[2i], x[2i+1]) of the last axis by
+    positions·theta^(-2i/dim). x: (B, T, ..., dim), positions: (T,)."""
+    dim = x.shape[-1]
+    freqs = theta ** (-np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ang = positions.astype(jnp.float32)[:, None] * freqs
+    ang = ang.reshape((1, x.shape[1]) + (1,) * (x.ndim - 3) + (dim // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., 0::2], x[..., 1::2]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape)
+
+
+def dense_causal_attention(q, k, v):
+    """(B, T, H, Dh) q, k and (B, T, H, Dv) v -> (B, T, H, Dv): the plain
+    lowering where no kernel is selected."""
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    mask = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+def _dot(x, kernel):
+    return x @ kernel.astype(x.dtype)
+
+
+def swiglu(h, p):
+    return _dot(jax.nn.silu(_dot(h, p["gate"]["kernel"]))
+                * _dot(h, p["up"]["kernel"]), p["down"]["kernel"])
+
+
+@jax.custom_vjp
+def permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation and its inverse. The backward pass is
+    the inverse gather, ``g[inverse]``: autodiff's transpose of a gather is
+    a scatter-add, which the chip runs far slower than a gather of whole
+    rows, and for a permutation the two are the same thing."""
+    return x[perm]
+
+
+permute_rows.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
+                    lambda res, g: (g[res[1]], None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_rows(h, order, inverse, k: int):
+    """Row ``i`` of the dispatch buffer: the token of the ``order[i]``-th
+    (token, choice) pair, ``h[order // k]``. Backward: each token's k rows
+    gathered back (``g[inverse]``) and summed — no scatter-add."""
+    return h[order // k]
+
+
+dispatch_rows.defvjp(
+    lambda h, order, inverse, k: (h[order // k], (inverse, h.shape)),
+    lambda k, res, g: (g[res[0]].reshape(res[1][0], k, res[1][1]).sum(axis=1),
+                       None, None))
+
+
+def grouped_dot(xs, kernels, sizes, held: int):
+    """Rows of ``xs`` (M, K), sorted by group with the ``held`` groups this
+    chip holds first, times their group's matrix in ``kernels`` (held, K,
+    N). ``sizes`` counts the rows of every group, the absent ones too; rows
+    of absent groups come out zero."""
+    if use_pallas():
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        m, k = xs.shape
+        n = kernels.shape[-1]
+        tiling = (min(m, 256), min(k, 1024), min(n, 1024))
+        return megablox.gmm(_operand(xs), _operand(kernels), sizes,
+                            jnp.float32, tiling,
+                            jnp.zeros((), jnp.int32))
+    # off the TPU (the CPU tests, under vmapped lanes, where lax.ragged_dot
+    # has no batching rule): every held group's product over every row,
+    # kept where the row is of that group
+    ends = jnp.cumsum(sizes[:held])
+    row = jnp.arange(xs.shape[0])
+    mine = (row[:, None] < ends) & (row[:, None] >= ends - sizes[:held])
+    full = jnp.einsum("mk,gkn->mgn", xs, kernels.astype(xs.dtype))
+    return jnp.einsum("mg,mgn->mn", mine.astype(xs.dtype), full)
+
+
+class LatentMoeLM:
+    """``init(key) -> params``; ``token_nll(params, tokens, targets,
+    pos_offset, train) -> (nll (B, T) float32, stats)``. ``attn_fn``: (q,
+    k, v) -> o with v's own head size (ops/flash_attention.flash_attention
+    on the TPU); None is the plain lowering. ``remat``: rematerialise each
+    layer in the backward pass. ``stat_names``: the counters' names, in the
+    order a step's metric row carries them."""
+
+    stat_names = STAT_NAMES
+
+    def __init__(self, spec: dict, attn_fn=None, dtype=jnp.float32,
+                 remat: bool = False):
+        check_spec(spec)
+        self.spec = dict(spec)
+        self.attn_fn = attn_fn or dense_causal_attention
+        self.dtype = jnp.dtype(dtype)
+        self.remat = remat
+
+    # ---- parameters ---------------------------------------------------
+    def param_shapes(self) -> dict:
+        s = self.spec
+        d, h = s["hidden_size"], s["num_attention_heads"]
+        nope, rp, vd = (s["qk_nope_head_dim"], s["qk_rope_head_dim"],
+                        s["v_head_dim"])
+        rank, held = s["kv_lora_rank"], s["experts_held"][1]
+
+        def mlp(width, lead=()):
+            return {"gate": {"kernel": lead + (d, width)},
+                    "up": {"kernel": lead + (d, width)},
+                    "down": {"kernel": lead + (width, d)}}
+
+        tree = {"embed": {"embedding": (s["vocab_rows"], d)},
+                "final_norm": {"scale": (d,)},
+                "head": {"kernel": (d, s["vocab_rows"])}}
+        for i in range(s["layers"]):
+            layer = {
+                "attn_norm": {"scale": (d,)},
+                "q": {"kernel": (d, h * (nope + rp))},
+                "kv_a": {"kernel": (d, rank + rp)},
+                "kv_norm": {"scale": (rank,)},
+                "kv_b": {"kernel": (rank, h * (nope + vd))},
+                "o": {"kernel": (h * vd, d)},
+                "mlp_norm": {"scale": (d,)},
+            }
+            if i < s["first_k_dense_replace"]:
+                layer["mlp"] = mlp(s["intermediate_size"])
+            else:
+                width = s["moe_intermediate_size"]
+                layer["router"] = {
+                    "kernel": (d, s["n_routed_experts"]),
+                    "e_score_correction_bias": (s["n_routed_experts"],)}
+                layer["shared"] = mlp(width * s["n_shared_experts"])
+                layer["experts"] = mlp(width, (held,))
+            tree[f"layer{i}"] = layer
+        return tree
+
+    def init(self, key):
+        shapes = self.param_shapes()
+        paths, treedef = jax.tree_util.tree_flatten_with_path(
+            shapes, is_leaf=lambda x: isinstance(x, tuple))
+        leaves = []
+        for i, (path, shape) in enumerate(paths):
+            name = path[-1].key
+            k = jax.random.fold_in(key, i)
+            if name == "scale":
+                leaves.append(jnp.ones(shape, jnp.float32))
+            else:
+                std = {"e_score_correction_bias": BIAS_STD,
+                       "embedding": EMBED_STD}.get(name, INIT_STD)
+                leaves.append(std * jax.random.normal(k, shape, jnp.float32))
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    # ---- the block ----------------------------------------------------
+    def _attention(self, h, p, positions):
+        s = self.spec
+        b, t, _ = h.shape
+        heads = s["num_attention_heads"]
+        nope, rp, vd = (s["qk_nope_head_dim"], s["qk_rope_head_dim"],
+                        s["v_head_dim"])
+        rank = s["kv_lora_rank"]
+        q = _dot(h, p["q"]["kernel"]).reshape(b, t, heads, nope + rp)
+        kva = _dot(h, p["kv_a"]["kernel"])
+        c = rms_norm(kva[..., :rank], p["kv_norm"]["scale"],
+                     s["rms_norm_eps"])
+        k_rope = rope_interleaved(kva[..., rank:].astype(jnp.float32),
+                                  positions, s["rope_theta"])
+        kvb = _dot(c, p["kv_b"]["kernel"]).reshape(b, t, heads, nope + vd)
+        q = jnp.concatenate([
+            q[..., :nope].astype(jnp.float32),
+            rope_interleaved(q[..., nope:].astype(jnp.float32), positions,
+                             s["rope_theta"])], axis=-1)
+        k = jnp.concatenate([
+            kvb[..., :nope].astype(jnp.float32),
+            jnp.broadcast_to(k_rope[:, :, None, :], (b, t, heads, rp))],
+            axis=-1)
+        v = kvb[..., nope:]
+        o = self.attn_fn(_operand(q), _operand(k), _operand(v))
+        return _dot(o.astype(h.dtype).reshape(b, t, heads * vd),
+                    p["o"]["kernel"])
+
+    def _route(self, h, p):
+        """h (N, hidden) -> the dispatch: the (token, choice) pairs' sorted
+        order, the groups' sizes, the inverse permutation, (N, k) combine
+        weights (zero where the chosen expert is not held), the count of
+        rows that landed here, and the counters."""
+        s = self.spec
+        k = s["num_experts_per_tok"]
+        first, held = s["experts_held"]
+        n_exp = s["n_routed_experts"]
+        scores = jax.nn.sigmoid(jnp.matmul(
+            h.astype(jnp.float32), p["kernel"],
+            precision=lax.Precision.HIGHEST))
+        bias = lax.stop_gradient(p["e_score_correction_bias"])
+        _, chosen = lax.top_k(scores + bias, k)
+        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        if s["norm_topk_prob"]:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * s["routed_scaling_factor"]
+        # this chip's experts become groups 0..held-1, the others follow
+        group = (chosen.reshape(-1) - first) % n_exp
+        order = jnp.argsort(group)  # stable: arrival order within a group
+        rows = group.shape[0]
+        inverse = jnp.zeros((rows,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32))
+        sizes = jnp.zeros((n_exp,), jnp.int32).at[group].add(1)
+        here = (group < held).reshape(chosen.shape)
+        landed = jnp.sum(sizes[:held])
+        stats = {"load": sizes[:held].astype(jnp.float32),
+                 # pairs that chose an expert held here and got no row:
+                 # none, the buffer holds every pair
+                 "dropped": (jnp.sum(here) - landed).astype(jnp.float32)}
+        return (order, sizes, inverse, jnp.where(here, w, 0.0), landed,
+                stats)
+
+    def _experts(self, x, p):
+        """x (N, hidden) -> x + the routed (held) and shared experts of its
+        normalised rows; the norm counts as the experts' (it feeds them)."""
+        held = self.spec["experts_held"][1]
+        k = self.spec["num_experts_per_tok"]
+        with jax.named_scope("draco_experts"):
+            h = rms_norm(x, p["mlp_norm"]["scale"],
+                         self.spec["rms_norm_eps"])
+        with jax.named_scope("draco_route"):
+            order, sizes, inverse, w, landed, stats = self._route(
+                h, p["router"])
+            # (N·k, hidden), held experts' rows first
+            xs = dispatch_rows(h, order, inverse, k)
+        with jax.named_scope("draco_experts"):
+            e = p["experts"]
+            mid = (jax.nn.silu(grouped_dot(xs, e["gate"]["kernel"], sizes,
+                                           held))
+                   * grouped_dot(xs, e["up"]["kernel"], sizes, held))
+            ys = grouped_dot(mid.astype(xs.dtype), e["down"]["kernel"],
+                             sizes, held)
+            # rows of experts not held: exactly zero, whatever the
+            # grouped product left there
+            ys = jnp.where(jnp.arange(ys.shape[0])[:, None] < landed, ys,
+                           0.0).astype(h.dtype)
+            shared = swiglu(h, p["shared"])
+        with jax.named_scope("draco_route"):
+            routed = jnp.einsum(
+                "nk,nkd->nd", w.astype(h.dtype),
+                permute_rows(ys, inverse, order).reshape(
+                    h.shape[0], k, h.shape[1]))
+        with jax.named_scope("draco_experts"):
+            return x + (routed + shared), stats
+
+    def _layer(self, x, p, positions, dense: bool):
+        eps = self.spec["rms_norm_eps"]
+        with jax.named_scope("draco_attn"):
+            x = x + self._attention(
+                rms_norm(x, p["attn_norm"]["scale"], eps), p, positions)
+        b, t, d = x.shape
+        if dense:
+            with jax.named_scope("draco_experts"):
+                h = rms_norm(x, p["mlp_norm"]["scale"], eps)
+                return x + swiglu(h, p["mlp"]), None
+        y, stats = self._experts(x.reshape(b * t, d), p)
+        return y.reshape(b, t, d), stats
+
+    def hidden(self, params, tokens, pos_offset=0):
+        """tokens (B, T) -> (the last layer's output (B, T, hidden), the
+        STAT_NAMES counters)."""
+        s = self.spec
+        x = params["embed"]["embedding"][tokens].astype(self.dtype)
+        positions = pos_offset + jnp.arange(tokens.shape[1])
+        per_layer = []
+        for i in range(s["layers"]):
+            fn = functools.partial(self._layer, positions=positions,
+                                   dense=i < s["first_k_dense_replace"])
+            if self.remat:
+                fn = jax.checkpoint(fn)
+            x, stats = fn(x, params[f"layer{i}"])
+            if stats is not None:
+                per_layer.append(stats)
+        return x, fold_stats(per_layer)
+
+    def _head(self, params, x):
+        x = rms_norm(x, params["final_norm"]["scale"],
+                     self.spec["rms_norm_eps"])
+        return _dot(x, params["head"]["kernel"]).astype(jnp.float32)
+
+    def logits(self, params, tokens, pos_offset=0):
+        """tokens (B, T) -> (B, T, vocab_rows) float32."""
+        x, _ = self.hidden(params, tokens, pos_offset)
+        with jax.named_scope("draco_head"):
+            return self._head(params, x)
+
+    def token_nll(self, params, tokens, targets, pos_offset=0,
+                  train: bool = True):
+        """tokens, targets (B, T) -> (per-position negative log-likelihood
+        (B, T) float32 over the vocabulary slice, STAT_NAMES counters)."""
+        del train  # no dropout in this block
+        x, stats = self.hidden(params, tokens, pos_offset)
+        with jax.named_scope("draco_head"):
+            logp = jax.nn.log_softmax(self._head(params, x))
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
+        return nll, stats
+
+
+def fold_stats(per_layer: list) -> dict:
+    """The expert layers' counters as the record's columns."""
+    if not per_layer:
+        return {}
+    load = jnp.stack([s["load"] for s in per_layer])  # (layers, held)
+    return {
+        "moe_assignments_held": jnp.sum(load),
+        "moe_load_max_over_mean": jnp.max(load) / jnp.maximum(
+            jnp.mean(load), 1e-9),
+        "moe_dropped": sum(s["dropped"] for s in per_layer),
+    }

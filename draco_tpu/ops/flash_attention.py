@@ -157,12 +157,15 @@ def _fwd_kernel(scale, nk, bq, bk, causal, q_ref, k_ref, v_ref, o_ref,
                    static_argnames=("scale", "bq", "bk", "causal",
                                     "interpret"))
 def _flash_fwd(q, k, v, scale, bq, bk, causal, interpret):
-    """q, k, v: (G, T, Dh_padded) f32 (G = B·H folded). ``scale`` comes from
-    the TRUE head dim (the lane padding must not change the softmax
-    temperature). Returns (o, lse); lse is (G, T) — the kernel emits it
+    """q, k: (G, T, Dh_padded), v: (G, T, Dv_padded) (G = B·H folded; v's
+    head size may differ from q/k's — latent attention scores at 192 and
+    mixes values of 128). ``scale`` comes from the TRUE q/k head dim (the
+    lane padding must not change the softmax temperature). Returns
+    (o (G, T, Dv_padded), lse); lse is (G, T) — the kernel emits it
     lane-broadcast (G, T, _LANE) to satisfy Mosaic block tiling and the
     wrapper keeps lane 0."""
     g, t, dh = q.shape
+    dv = v.shape[-1]
     nq, nk = t // bq, t // bk
     grid = (g, nq, nk)
     kern = functools.partial(_fwd_kernel, scale, nk, bq, bk, causal)
@@ -173,18 +176,18 @@ def _flash_fwd(q, k, v, scale, bq, bk, causal, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((1, bk, dh), kv_row),
-            pl.BlockSpec((1, bk, dh), kv_row),
+            pl.BlockSpec((1, bk, dv), kv_row),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, dh), lambda g, i, j: (g, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((1, bq, _LANE), lambda g, i, j: (g, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((g, t, dh), q.dtype),
+            jax.ShapeDtypeStruct((g, t, dv), q.dtype),
             jax.ShapeDtypeStruct((g, t, _LANE), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, dh), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANE), jnp.float32),
             pltpu.VMEM((bq, _LANE), jnp.float32),
         ],
@@ -304,6 +307,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
     """dlse=None is the hot path (lse output unused): the kernels take one
     fewer input stream and skip the dead add."""
     g, t, dh = q.shape
+    dv = v.shape[-1]
     nq, nk = t // bq, t // bk
     dcap = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     # lane-broadcast the per-row stats so their blocks tile (bq, _LANE)
@@ -327,8 +331,8 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, dh), q_row),
             pl.BlockSpec((1, bk, dh), k_row),
-            pl.BlockSpec((1, bk, dh), k_row),
-            pl.BlockSpec((1, bq, dh), q_row),
+            pl.BlockSpec((1, bk, dv), k_row),
+            pl.BlockSpec((1, bq, dv), q_row),
             *stat_specs,
         ],
         out_specs=pl.BlockSpec((1, bq, dh), q_row),
@@ -352,21 +356,21 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, dh), q_row2),
             pl.BlockSpec((1, bk, dh), k_row2),
-            pl.BlockSpec((1, bk, dh), k_row2),
-            pl.BlockSpec((1, bq, dh), q_row2),
+            pl.BlockSpec((1, bk, dv), k_row2),
+            pl.BlockSpec((1, bq, dv), q_row2),
             *stat_specs2,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dh), k_row2),
-            pl.BlockSpec((1, bk, dh), k_row2),
+            pl.BlockSpec((1, bk, dv), k_row2),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, t, dh), k.dtype),
-            jax.ShapeDtypeStruct((g, t, dh), v.dtype),
+            jax.ShapeDtypeStruct((g, t, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -429,8 +433,10 @@ _flash_core_lse.defvjp(_flash_core_lse_fwd, _flash_core_lse_bwd)
 
 def flash_attention(q, k, v, *, block_q: int = 512, block_k: int = 1024,
                     force=None, interpret: bool = False):
-    """Causal self-attention. q, k, v: (B, T, H, Dh) — the Block contract
-    (attention math upstream is f32; the kernel accumulates f32 regardless).
+    """Causal self-attention. q, k: (B, T, H, Dh), v: (B, T, H, Dv) — the
+    Block contract with Dv == Dh, latent attention with Dh 192 against Dv
+    128 (attention math upstream is f32; the kernel accumulates f32
+    regardless). Returns (B, T, H, Dv).
 
     The causal mask is offset-invariant for self-attention (q and k share
     positions), so no offset argument is needed. Off-TPU (and not
@@ -462,32 +468,34 @@ def _kernel_eligible(t, bq, bk, dh, force, interpret) -> bool:
         return False
     if (bq < 8 or bk < 8  # _fit_block found no legal block (t % 8 != 0)
             or t % 8 or bq % 8 or bk % 8 or t % bq or t % bk
-            or dh > _LANE or (bk > _LANE and bk % _LANE)):
+            or dh > 2 * _LANE or (bk > _LANE and bk % _LANE)):
         raise ValueError(
             f"flash_attention: shape does not tile for the kernel "
             f"(t={t}, bq={bq}, bk={bk}, dh={dh}; need t%8==0, t%bq==0, "
-            f"t%bk==0, blocks%8==0, dh<={_LANE}, and bk a multiple of "
+            f"t%bk==0, blocks%8==0, dh<={2 * _LANE}, and bk a multiple of "
             f"{_LANE} when bk>{_LANE}) — use attn_impl=dense for this shape")
     return True
 
 
 def _run_folded(q, k, v, bq, bk, causal, interpret, want_lse):
-    """(B,T,H,Dh) qkv -> folded kernel call -> o (B,T,H,Dh), or
-    (o, lse (B,T,H)) with a differentiable lse when want_lse."""
+    """(B,T,H,Dh) q, k and (B,T,H,Dv) v -> folded kernel call -> o
+    (B,T,H,Dv), or (o, lse (B,T,H)) with a differentiable lse when
+    want_lse. Each head size is padded to whole lane tiles on its own."""
     b, t, h, dh = q.shape
-    dh_p = _ceil_to(dh, _LANE)
+    dv = v.shape[-1]
 
     def fold(x):
-        x = jnp.moveaxis(x, 2, 1).reshape(b * h, t, dh)  # (B,T,H,D)->(BH,T,D)
-        if dh_p != dh:
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, dh_p - dh)))
+        d = x.shape[-1]
+        x = jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)  # (B,T,H,D)->(BH,T,D)
+        if d % _LANE:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, _ceil_to(d, _LANE) - d)))
         return x
 
     args = (fold(q), fold(k), fold(v), 1.0 / (dh ** 0.5),
             bq, bk, causal, interpret)
 
     def unfold(o):
-        return jnp.moveaxis(o[..., :dh].reshape(b, h, t, dh), 1, 2)
+        return jnp.moveaxis(o[..., :dv].reshape(b, h, t, dv), 1, 2)
 
     if not want_lse:
         return unfold(_flash_core(*args))

@@ -158,10 +158,43 @@ def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
     return agg, health
 
 
+def _inject_rows(grads, adv_mask, cfg, step):
+    """``attacks.inject_plain`` on the raw rows of the (n, ...) stack. A
+    large stack (its rows laid out in tiles, sp_step.STACK_TILE) is
+    attacked one row at a time through a dynamic-update-slice, which XLA
+    performs in place: as ONE elementwise pass the injection fused with its
+    readers into an op of several results that could not reuse the stack's
+    buffer, and a second stack does not fit beside the first. Row-local
+    attacks only (rev_grad / constant / random). Same values either way:
+    the layout decides, and only the vote's large stack has it
+    (tests/test_lm_maj_vote.py holds both sides to the same bits)."""
+    kw = dict(n_mal=cfg.num_adversaries, step=step, seed=cfg.seed)
+    if grads.ndim == 2:
+        return attacks.inject_plain(grads, adv_mask, cfg.err_mode,
+                                    cfg.adversarial, **kw)
+    if cfg.err_mode in ("alie", "ipm"):
+        raise ValueError(
+            f"err_mode={cfg.err_mode} reads every row at once and is not "
+            "implemented for a stack attacked a row at a time")
+
+    def body(i, g):
+        # one row and its one mask bit: the (1, 1) mask broadcasts over
+        # whatever axes the row is laid out in
+        row = jax.lax.dynamic_slice_in_dim(g, i, 1, axis=0)
+        bad = attacks.inject_plain(
+            row, jax.lax.dynamic_slice_in_dim(adv_mask, i, 1),
+            cfg.err_mode, cfg.adversarial, **kw)
+        return jax.lax.dynamic_update_slice_in_dim(g, bad, i, axis=0)
+
+    return jax.lax.fori_loop(0, grads.shape[0], body, grads)
+
+
 def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                          present=None, leaf_offsets=None, step=None,
                          mesh=None):
     """(n, d) per-worker flat gradients → ``(aggregated (d,), health)``.
+    (maj_vote also takes a large stack with its rows laid out in tiles,
+    (n, d / 1024, 8, 128): sp_step.STACK_TILE.)
 
     ``step`` (optional traced scalar): the training step, threaded so the
     deterministic fault plan (``cfg.fault_spec``,
@@ -337,11 +370,36 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                         leaf_offsets, present, adv_mask, step))
                 health["watch"] = watch
         return agg, health
+    if cfg.approach == "maj_vote":
+        # ingest-row health on the rows as computed, BEFORE the simulated
+        # attack rewrites the stack (as the cyclic branch reads it before
+        # the encode)
+        with jax.named_scope("draco_health"):
+            bad_rows = ~jnp.all(jnp.isfinite(grads),
+                                axis=tuple(range(1, grads.ndim)))
     with jax.named_scope("draco_attack"):
-        grads = attacks.inject_plain(grads, adv_mask, cfg.err_mode,
-                                     cfg.adversarial,
-                                     n_mal=cfg.num_adversaries,
-                                     step=step, seed=cfg.seed)
+        grads = _inject_rows(grads, adv_mask, cfg, step)
+    if cfg.approach == "maj_vote":
+        # repetition code: the members of a group were fed the same rows
+        # (token_loop.step_tokens) and ran the same program on them, so
+        # honest rows agree bitwise and the vote over the raw rows is exact
+        # (coding/repetition.py; the CNN path's tail is training/step.py).
+        # The fingerprint salt is folded from the replicated step, as there.
+        from draco_tpu import rng as drng
+        from draco_tpu.coding import repetition as rep_mod
+
+        with jax.named_scope("draco_input"):
+            vkey = drng.fold(jax.random.key(cfg.seed + 4), step)
+        with jax.named_scope("draco_decode"):
+            voted, health = rep_mod.majority_vote(
+                rep_mod.build_repetition_code(cfg.num_workers,
+                                              cfg.group_size),
+                grads, present=present, key=vkey, method=cfg.vote_check,
+                with_health=True)
+        health["bad_rows"] = bad_rows
+        with jax.named_scope("draco_pack"):
+            voted = voted.reshape(-1)
+        return voted, health
     with jax.named_scope("draco_decode"):
         agg = aggregation.aggregate(
             grads, cfg.mode, s=cfg.worker_fail,
@@ -474,14 +532,20 @@ def metric_family_names(cfg) -> tuple:
     return names
 
 
-def token_metric_names(cfg) -> tuple:
+def token_metric_names(cfg, stat_names=()) -> tuple:
     """Column order of the (K, m) metric block for an LM route at ``cfg``
     — every route builder stores this on its setup so the shared token
     loop flushes the right schema. The optional families (health masks /
     forensics / numerics / guard) come from the one shared assembly
     (:func:`metric_family_names`); baseline routes emit only the base
-    columns."""
-    return TOKEN_METRIC_NAMES + metric_family_names(cfg)
+    columns. ``stat_names``: the token model's own per-step counters
+    (``models.build_lm``'s surface), which close the row."""
+    names = TOKEN_METRIC_NAMES + tuple(
+        # the token routes ship the vote's flag count under the name the
+        # cyclic decode's has (decode_health_metrics)
+        "located_errors" if cfg.approach == "maj_vote" and n == "det_flagged"
+        else n for n in metric_family_names(cfg))
+    return names + tuple(stat_names)
 
 
 def accusation_mask(health, present=None):
@@ -544,12 +608,18 @@ def decode_health_metrics(health, adv_mask, present) -> dict:
         out.update(watch)
         return out
     det = _detection_metrics(health["flagged"], adv_mask, present)
-    out = {
-        "decode_residual": health["residual"],
+    if "vote_agree" in health:
+        # repetition code: the vote's agreement record where the cyclic
+        # decode has its residual; the flag count keeps the one name
+        out = {"vote_agree": health["vote_agree"],
+               "flagged_groups": health["flagged_groups"]}
+    else:
+        out = {"decode_residual": health["residual"]}
+    out.update({
         "located_errors": det["det_flagged"],
         "det_tp": det["det_tp"],
         "det_adv": det["det_adv"],
-    }
+    })
     out.update(forensics_mod.pack_mask_columns(
         accusation_mask(health, present), present, adv_mask))
     out.update(watch)

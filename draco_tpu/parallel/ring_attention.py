@@ -67,7 +67,8 @@ def dense_attention_lse(q, k, v, q_offset=0, k_offset=0, causal: bool = True):
     scale = 1.0 / (dh**0.5)
     q_pos = q_offset + jnp.arange(tq)
     k_pos = k_offset + jnp.arange(tk)
-    o = jnp.zeros((b, tq, h, dh), jnp.float32)
+    # v's head size may differ from q/k's (latent attention: 192 vs 128)
+    o = jnp.zeros((b, tq, h, v.shape[-1]), jnp.float32)
     m = jnp.full((b, tq, h), NEG_INF, jnp.float32)
     l = jnp.zeros((b, tq, h), jnp.float32)
     o, m, l = _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l)

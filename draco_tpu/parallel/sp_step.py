@@ -7,19 +7,32 @@ exact whole per-worker gradients; Draco's coding/aggregation then acts on the
 (draco_tpu/training/step.py) — Byzantine resilience is oblivious to how each
 worker's compute was sharded.
 
-Supported approaches here: ``baseline`` (mean / geo-median / krum) and
+Supported approaches here: ``baseline`` (mean / geo-median / krum),
 ``cyclic`` with either redundancy mode — ``simulate`` (reference-parity
 2s+1-lane redundant compute per worker, cyclic_worker.py:122-146) or
-``shared`` (each batch gradient computed once, rows formed algebraically).
-(maj_vote's bitwise-equality vote is specified over identical lanes; under
-SP a group member is a whole mesh row, which the batching layer does not
-replicate — use the CNN path for it.)
+``shared`` (each batch gradient computed once, rows formed algebraically) —
+``approx``, and ``maj_vote`` at ``seq_shards == 1``: the token loop feeds
+every member of a repetition group the same rows (token_loop.step_tokens),
+the lanes run the identical program on them and so agree bitwise, and the
+vote (coding/repetition.py) acts on the raw (n, d) rows. (Under a sharded
+sequence a group member is a whole mesh row; config.validate refuses that
+by name.)
+
+The token model comes from ``models.build_lm``: ``TransformerLM`` on every
+mesh this route builds, ``LatentMoeLM`` (a published config's block: latent
+attention, routed and shared experts) at ``seq_shards == 1``. Where the
+(lanes, d) stack of per-lane gradients computed side by side would not fit
+beside the rest of the step (``LANES_IN_TURN_BYTES``), the lanes are
+evaluated in turn (``lax.map``), each layer is rematerialised in the
+backward pass and the vote's stack is kept in tiles (``STACK_TILE``): ONE
+size test, and the shape decides, no option does.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +43,7 @@ from jax.sharding import PartitionSpec as P
 from draco_tpu import optim, rng as drng
 from draco_tpu.coding import cyclic as cyclic_mod
 from draco_tpu.config import TrainConfig
-from draco_tpu.models.transformer import TransformerLM
+from draco_tpu.models import build_lm
 from draco_tpu.parallel.a2a_attention import a2a_attention
 from draco_tpu.parallel.common import (
     TOKEN_METRIC_NAMES,
@@ -51,19 +64,44 @@ from draco_tpu.runtime import WORKER_AXIS
 from draco_tpu.training.step import TrainState, _flatten_tree, _make_unravel
 
 
+# A (lanes, d) float32 stack of per-lane gradients above this, built by
+# vmapped lanes (each with its own gradient tree and activations alive at
+# once), does not fit one 16 GB chip beside weights, momentum and the decoded
+# gradient: such a step evaluates its lanes in turn and rematerialises per
+# layer. d = 425 M x 3 lanes is 5.1 GB: the large side is the cell
+# kanana2.maj_vote_r3 and, with this constant set to 0,
+# tests/test_lm_maj_vote.py::test_lanes_in_turn_train_the_same_as_side_by_side;
+# every other LM the tests build is on the small side.
+LANES_IN_TURN_BYTES = 2**30
+# Such a stack is kept (lanes, ceil(d / 1024), 8, 128) where the vote reads
+# it (coding/repetition.py takes rows of several axes; the tail then attacks
+# it a row at a time, common._inject_rows): one lane's row as the chip's own
+# (8, 128) tiles in order, which is the flat row's bytes as they lie, so the
+# reshape moves nothing, no lane is padded and a lane's row is one
+# contiguous block (a d that does not fill its last tile is closed with
+# zeros). Measured at d = 425 M on the chip (PERF.md section
+# 6): as (lanes, d) the tiling is (4, 128) — three lanes padded to four,
+# 6.3 GB for 4.75, and writing ONE lane's row rewrites every tile of the
+# stack, 36 ms a lane and again for each row the attack touches; as
+# (lanes, 8, d / 8) building a lane's row is eight such rewrites, 49 ms; as
+# (lanes, 1, d) the stack is linear but every pass over it uses one
+# sublane of eight (the fingerprints 80 ms for 10).
+STACK_TILE = (8, 128)
+
+
 class SPTrainSetup(NamedTuple):
-    model: TransformerLM
+    model: Any  # models.build_lm's token model
     state: TrainState
     # (state, tokens (n,B,T), adv_mask (n,)) -> (state, metrics)
-    train_step: any
-    eval_step: any  # (params, tokens) -> loss (no donation, no update)
+    train_step: Any
+    eval_step: Any  # (params, tokens) -> loss (no donation, no update)
     code: Optional[cyclic_mod.CyclicCode]
-    unravel: any
+    unravel: Any
     dim: int
     # K fused LM steps in ONE device program (parallel/common.py):
     # (state, toks (K,n,B,T) | steps (K,), masks (K,n), presents (K,n)|None)
     #   -> (state, metrics (K, len(metric_names)) float32)
-    train_token_many: any = None
+    train_token_many: Any = None
     metric_names: tuple = TOKEN_METRIC_NAMES
 
 
@@ -105,18 +143,28 @@ def token_fn_from_cfg(cfg: TrainConfig):
     builder so the scanned drivers can't disagree on the stream."""
     if cfg.token_gen != "device":
         return None
-    return lambda step: synthetic_text_in_graph(
-        cfg.seed, step, cfg.num_workers, cfg.batch_size, cfg.seq_len,
-        cfg.vocab,
-    )
+    rows, repeat = token_rows(cfg)
+    return lambda step: jnp.repeat(synthetic_text_in_graph(
+        cfg.seed, step, rows, cfg.batch_size, cfg.seq_len, cfg.vocab,
+    ), repeat, axis=0)
+
+
+def token_rows(cfg: TrainConfig):
+    """(distinct rows a step draws, copies of each): n rows once, or under
+    ``maj_vote`` one row a repetition group, held by all its members — the
+    vote's soundness condition (coding/repetition.py)."""
+    if cfg.approach == "maj_vote":
+        return cfg.num_workers // cfg.group_size, cfg.group_size
+    return cfg.num_workers, 1
 
 
 def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
     """mesh must have axes (w, sp) — see make_mesh_2d."""
     cfg.validate()
-    if cfg.approach not in ("baseline", "cyclic", "approx"):
+    if cfg.approach not in ("baseline", "cyclic", "approx", "maj_vote"):
         raise ValueError(
-            f"SP path supports baseline|cyclic|approx, got {cfg.approach}")
+            f"SP path supports baseline|cyclic|approx|maj_vote, got "
+            f"{cfg.approach}")
     n = cfg.num_workers
     sp = mesh.shape[SEQ_AXIS]
     # logical workers fold onto the available w-axis devices in equal
@@ -155,24 +203,22 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
         attn = functools.partial(
             attn_impl, axis_name=SEQ_AXIS if sp > 1 else None
         )
-    cdtype = jnp.dtype(cfg.compute_dtype)
-    model = TransformerLM(
-        vocab=cfg.vocab, dim=cfg.model_dim, heads=cfg.model_heads,
-        layers=cfg.model_layers, attn_fn=attn, experts=cfg.moe_experts,
-        dtype=cdtype, remat=cfg.remat, scan_layers=cfg.scan_layers,
-    )
-    # init single-shard (dense attention) — parameter shapes are identical
-    init_model = TransformerLM(
-        vocab=cfg.vocab, dim=cfg.model_dim, heads=cfg.model_heads,
-        layers=cfg.model_layers, attn_fn=None, experts=cfg.moe_experts,
-        dtype=cdtype, scan_layers=cfg.scan_layers,
-    )
-    root = jax.random.key(cfg.seed)
-    init_toks = jnp.zeros((1, min(cfg.seq_len, 8)), jnp.int32)
-    params = init_model.init({"params": root}, init_toks, train=True)["params"]
+    # the route's attention and the bare kernel: each model takes the one
+    # it can use (models.build_lm)
+    model = build_lm(cfg, attn, kernel_fn=flash)
+    params = model.init(jax.random.key(cfg.seed))
 
     opt = optim.build_optimizer_from_cfg(cfg)
     unravel, dim, leaf_offsets = _make_unravel(params)
+    lanes = n // mesh.shape[WORKER_AXIS]
+    lanes_in_turn = 4 * lanes * dim > LANES_IN_TURN_BYTES
+    tiled_stack = lanes_in_turn and cfg.approach == "maj_vote"
+    # zeros that close a row's last tile (none at the cell's d = 415 001
+    # tiles); every lane writes the same, so the vote is unmoved
+    tile_pad = -dim % int(np.prod(STACK_TILE)) if tiled_stack else 0
+    if lanes_in_turn and not cfg.remat:
+        model = build_lm(dataclasses.replace(cfg, remat=True), attn,
+                         kernel_fn=flash)
 
     repl = sharding(mesh, REPLICATED)
     shard_w = sharding(mesh, WORKER_ROWS)
@@ -206,43 +252,54 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
             jnp.ones((t_local,), jnp.float32),
         )
         denom = toks.shape[0] * (cfg.seq_len - 1)
-        logits = model.apply({"params": params}, toks, pos_offset=off,
-                             train=train)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.sum(nll * pos_valid[None, :]) / denom
+        nll, stats = model.token_nll(params, toks, targets, pos_offset=off,
+                                     train=train)
+        return jnp.sum(nll * pos_valid[None, :]) / denom, stats
+
+    def over_lanes(fn, tokens):
+        """``fn`` on every lane's tokens: side by side, or in turn where
+        the lanes' gradients side by side would not fit
+        (LANES_IN_TURN_BYTES)."""
+        return (lax.map(fn, tokens) if lanes_in_turn
+                else jax.vmap(fn)(tokens))
 
     def device_grads(params, tokens):
         """tokens: (lanes, B, t_local) — this device's shard of its workers'
         batches (lanes = num_workers / mesh w-axis; 1 on a full mesh).
-        Returns (flat_grads (lanes, d), losses (lanes,)) — each worker's FULL
-        gradient, psum-assembled over sp and replicated along it."""
+        Returns (flat_grads (lanes, d), losses (lanes,), the model's
+        counters (lanes,) each) — each worker's FULL gradient,
+        psum-assembled over sp and replicated along it."""
         def one_lane(toks):
-            loss, g = jax.value_and_grad(
-                lambda p: _shard_objective(p, toks, train=True)
-            )(params)
-            return _flatten_tree(g), loss
+            (loss, stats), g = jax.value_and_grad(
+                lambda p: _shard_objective(p, toks, train=True),
+                has_aux=True)(params)
+            g = _flatten_tree(g)
+            if tiled_stack:
+                if tile_pad:
+                    g = jnp.pad(g, (0, tile_pad))
+                g = g.reshape((-1,) + STACK_TILE)
+            return g, loss, stats
 
-        g, loss = jax.vmap(one_lane)(tokens)
+        g, loss, stats = over_lanes(one_lane, tokens)
         # exact per-worker grad: cotangents already routed through the ring's
         # transpose; psum folds the shard contributions
         g = lax.psum(g, SEQ_AXIS)
         loss = lax.psum(loss, SEQ_AXIS)
-        return g, loss
+        return g, loss, stats
 
     def device_loss(params, tokens):
         """Forward-only held-out loss (no backward, no gradient ICI
         traffic)."""
-        loss = jax.vmap(
-            lambda toks: _shard_objective(params, toks, train=False)
-        )(tokens)
+        loss = over_lanes(
+            lambda toks: _shard_objective(params, toks, train=False)[0],
+            tokens)
         return lax.psum(loss, SEQ_AXIS)
 
     grads_fn = shard_map(
         device_grads,
         mesh=mesh,
         in_specs=(P(), P(WORKER_AXIS, None, SEQ_AXIS)),
-        out_specs=(P(WORKER_AXIS, None), P(WORKER_AXIS)),
+        out_specs=(P(WORKER_AXIS, None), P(WORKER_AXIS), P(WORKER_AXIS)),
         check_vma=False,
     )
 
@@ -252,9 +309,9 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
         hat_s = 2s+1 assigned batch rows (cyclic_worker.py:122-146).
         Returns ((lanes, hat_s, d), (lanes, hat_s))."""
         def one_row(toks):
-            loss, g = jax.value_and_grad(
-                lambda p: _shard_objective(p, toks, train=True)
-            )(params)
+            (loss, _stats), g = jax.value_and_grad(
+                lambda p: _shard_objective(p, toks, train=True),
+                has_aux=True)(params)
             return _flatten_tree(g), loss
 
         g, loss = jax.vmap(jax.vmap(one_row))(tokens)
@@ -283,9 +340,12 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
                 grads, losses = grads_fn_sim(state.params, toks_w)
                 grads = lax.with_sharding_constraint(grads, shard_w3)
                 losses = jnp.mean(losses, axis=1)
+                stats = {}
             else:
-                grads, losses = grads_fn(state.params, tokens)
-                grads = lax.with_sharding_constraint(grads, shard_w)
+                grads, losses, stats = grads_fn(state.params, tokens)
+                grads = lax.with_sharding_constraint(
+                    grads, sharding(mesh, P(WORKER_AXIS))
+                    if tiled_stack else shard_w)
         # in-graph decode projection — no d-length program constant
         # (rng.random_projection_factors_in_graph docstring); the approx
         # decode is projection-free (real least squares, no syndrome)
@@ -297,12 +357,17 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
                                            step=state.step, mesh=mesh)
+        if tile_pad:
+            with jax.named_scope("draco_pack"):
+                agg = agg[:dim]
         new_state, guard_cols = finish_flat_step(cfg, state, agg, health,
                                                  opt, unravel,
                                                  present=present)
         with jax.named_scope("draco_health"):
             metrics = {"loss": masked_loss_metric(losses, present)}
             metrics.update(decode_health_metrics(health, adv_mask, present))
+            # the token model's own counters (a mean over the lanes)
+            metrics.update({k: jnp.mean(v) for k, v in stats.items()})
         metrics.update(guard_cols)
         return new_state, metrics
 
@@ -317,7 +382,7 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
     def eval_body(params, tokens):
         return jnp.mean(loss_fn(params, tokens))
 
-    metric_names = token_metric_names(cfg)
+    metric_names = token_metric_names(cfg, model.stat_names)
     with mesh:
         train_step = jax.jit(step_body, donate_argnums=(0,))
         eval_step = jax.jit(eval_body)

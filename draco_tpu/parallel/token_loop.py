@@ -115,11 +115,38 @@ def _snap_stop(cfg, state, step: int, obs: _LoopTelemetry,
         obs.stop.stopped_step = step
 
 
+def step_tokens(cfg: TrainConfig, step: int, tokens=None) -> np.ndarray:
+    """The (n, B, T) int32 rows of training ``step``, on the host: one row a
+    worker — or, under ``maj_vote``, one row a repetition group, held by
+    all its members (the vote's soundness condition; sp_step.token_rows).
+    ``tokens``: the run's own source ``(step, rows) -> (rows, B, T)`` in
+    place of the synthetic stream (run_token_loop)."""
+    from draco_tpu.parallel.sp_step import synthetic_text, token_rows
+
+    rows, repeat = token_rows(cfg)
+    if tokens is None:
+        toks = synthetic_text(cfg.seed, step, rows, cfg.batch_size,
+                              cfg.seq_len, cfg.vocab)
+    else:
+        toks = np.asarray(tokens(step, rows), np.int32)
+    return np.repeat(toks, repeat, axis=0) if repeat > 1 else toks
+
+
 def run_token_loop(setup, cfg: TrainConfig, steps: Optional[int] = None,
                    quiet: bool = False, tag: str = "mp",
                    profile_dir: Optional[str] = None,
-                   profile_steps: tuple = (3, 8), rebuild=None):
+                   profile_steps: tuple = (3, 8), rebuild=None, *,
+                   tokens=None, start_step: Optional[int] = None,
+                   writer=None, tracer=None):
     """Train ``steps or cfg.max_steps`` steps on the synthetic token stream.
+
+    A caller that drives the loop in pieces over its own data (the
+    benchmark's token route, as block-wise callers drive ``Trainer.run``)
+    passes ``tokens`` (:func:`step_tokens`' source, in place of the
+    synthetic stream), ``start_step`` (the first step's number; the
+    schedules and the rows follow it), and may stand its own ``writer``
+    (``write`` / ``flush`` / ``close``) and ``tracer`` where the loop's
+    would be; a tracer handed in is flushed, not closed.
 
     Same operational contract as the CNN Trainer: step-indexed Orbax
     checkpoints + held-out eval every ``eval_freq`` steps (reference:
@@ -140,7 +167,7 @@ def run_token_loop(setup, cfg: TrainConfig, steps: Optional[int] = None,
     from draco_tpu.utils.metrics import MetricWriter
 
     state = setup.state
-    start = 1
+    start = 1 if start_step is None else start_step
     if cfg.checkpoint_step > 0 or cfg.checkpoint_step == -1:
         # walk-back restore (resilience/supervisor.py): a corrupt
         # checkpoint is skipped, not fatal; -1 means "newest loadable" —
@@ -184,8 +211,11 @@ def run_token_loop(setup, cfg: TrainConfig, steps: Optional[int] = None,
         # write, never a program-signature change (same rule as Trainer)
         straggle = np.zeros((start + total + 1, cfg.num_workers), dtype=bool)
     is_main = jax.process_index() == 0
-    writer = MetricWriter(cfg.train_dir or None, quiet=quiet)
-    tracer = make_tracer(cfg.trace_dir, is_main)
+    if writer is None:
+        writer = MetricWriter(cfg.train_dir or None, quiet=quiet)
+    own_tracer = tracer is None
+    if own_tracer:
+        tracer = make_tracer(cfg.trace_dir, is_main)
     # num_workers keys the heartbeat's per-worker accusation ledger
     # (obs/forensics.AccusationLedger), fed by the same observer hook; the
     # incident engine (obs/incidents.py, ISSUE 13) rides the same hook +
@@ -252,11 +282,13 @@ def run_token_loop(setup, cfg: TrainConfig, steps: Optional[int] = None,
                                               last_step, adv, straggle,
                                               writer, boundary_eval_ckpt,
                                               tag, obs, rebuild=rebuild,
-                                              engine_ref=engine_ref)
+                                              engine_ref=engine_ref,
+                                              tokens=tokens)
             else:
                 state, metrics = _run_eager(setup, cfg, state, start,
                                             last_step, adv, straggle,
-                                            writer, boundary_eval_ckpt, obs)
+                                            writer, boundary_eval_ckpt, obs,
+                                            tokens=tokens)
             if (cfg.train_dir and not cfg.eval_freq
                     and stop.stopped_step is None):
                 # checkpointing without eval: no cadence boundaries exist,
@@ -301,14 +333,21 @@ def run_token_loop(setup, cfg: TrainConfig, steps: Optional[int] = None,
     finally:
         writer.close()
         compile_watch.stop()
-        tracer.close()
+        if own_tracer:
+            tracer.close()
+        else:
+            tracer.flush()
     return state, metrics
 
 
 def _run_eager(setup, cfg, state, start, last_step, adv, straggle, writer,
-               boundary_eval_ckpt, obs=_LoopTelemetry()):
-    """One dispatch per step — the K=1 bitwise reference."""
-    from draco_tpu.parallel.sp_step import synthetic_text
+               boundary_eval_ckpt, obs=_LoopTelemetry(), tokens=None):
+    """One dispatch per step — the K=1 bitwise reference. A logged step's
+    record carries the Trainer's ledger in seconds (utils/metrics.Segments):
+    ``t_fetch`` (the rows, gathered and uploaded), ``t_comp`` =
+    ``t_dispatch + t_wait + t_drain``, ``t_book`` (everything since the
+    previous step's ``t_comp`` closed)."""
+    from draco_tpu.utils.metrics import Segments
 
     tracer, heartbeat, watch = obs.tracer, obs.heartbeat, obs.compile_watch
     total_end = obs.total_end
@@ -319,20 +358,23 @@ def _run_eager(setup, cfg, state, start, last_step, adv, straggle, writer,
     win = profiler_window(obs.profile_dir, obs.profile_steps, tracer=tracer,
                           on_stop=heartbeat.observe_device)
     metrics = {}
+    comp_end = None  # clock read that closed the previous step's t_comp
     for step in range(start, last_step + 1):
         win.maybe_start(step)
+        seg = Segments()
+        seg.begin("fetch", since=comp_end, gap="book")
         with tracer.span("gather"):
-            toks = jnp.asarray(
-                synthetic_text(cfg.seed, step, cfg.num_workers,
-                               cfg.batch_size, cfg.seq_len, cfg.vocab)
-            )
+            toks = jnp.asarray(step_tokens(cfg, step, tokens))
         args = (state, toks, jnp.asarray(adv[step]))
         if straggle is not None:
             args += (jnp.asarray(~straggle[step]),)
+        seg.end()
+        seg.begin("comp")
         win.note_program("train_step", setup.train_step, args)
         with tracer.span("dispatch"), watch.expect("train_step"):
             state, metrics = setup.train_step(*args)
         del args  # the donated state must not outlive its call
+        seg.lap("dispatch")
         win.maybe_stop(step, state.params)
         if obs.latest is not None:  # escalated-stop checkpoint cursor
             obs.latest["state"], obs.latest["step"] = state, step
@@ -345,14 +387,23 @@ def _run_eager(setup, cfg, state, start, last_step, adv, straggle, writer,
             with tracer.span("sync"):
                 with tracer.span("device_wait"):
                     jax.block_until_ready(metrics)
+                seg.lap("wait")
                 with tracer.span("drain", columns=len(metrics)):
-                    # record_value: forensics bitmask columns materialize
-                    # as exact integer words (obs/forensics docstring)
+                    # the columns in ONE device-to-host fetch (a blocking
+                    # fetch a column is 0.7 ms each on the chip, 12 ms for
+                    # the LM vote's 17: host time the device idles through,
+                    # and the step's run-to-run noise). record_value:
+                    # forensics bitmask columns materialize as exact
+                    # integer words (obs/forensics docstring)
                     record = {"step": step}
-                    record.update({k: record_value(k, v)
-                                   for k, v in metrics.items()})
+                    record.update({k: record_value(k, v) for k, v
+                                   in jax.device_get(metrics).items()})
+            comp_end = seg.end(lap="drain")
+            record.update(seg.as_dict())
             heartbeat.observe(record)
             writer.write(record)
+        else:
+            comp_end = seg.end()  # not synced: the dispatch alone
         boundary = cfg.eval_freq and step % cfg.eval_freq == 0
         if boundary or step == last_step:
             with tracer.span("flush"):
@@ -372,7 +423,7 @@ def _run_eager(setup, cfg, state, start, last_step, adv, straggle, writer,
 
 def _run_chunked(setup, cfg, state, start, last_step, adv, straggle, writer,
                  boundary_eval_ckpt, tag="mp", obs=_LoopTelemetry(),
-                 rebuild=None, engine_ref=None):
+                 rebuild=None, engine_ref=None, tokens=None):
     """One dispatch per chunk of up to K steps, driven by the shared
     ``ChunkedEngine`` (control/engine.py — one implementation with the CNN
     Trainer loop): metrics deferred to flush boundaries, next chunk
@@ -380,7 +431,6 @@ def _run_chunked(setup, cfg, state, start, last_step, adv, straggle, writer,
     from draco_tpu.control.clients import TokenChunkClient
     from draco_tpu.control.engine import ChunkedEngine
     from draco_tpu.data.prefetch import TokenChunkPrefetcher
-    from draco_tpu.parallel.sp_step import synthetic_text
 
     if setup.train_token_many is None:
         raise ValueError(
@@ -397,9 +447,7 @@ def _run_chunked(setup, cfg, state, start, last_step, adv, straggle, writer,
         # wait — a dead/hung worker thread is retried with backoff, then
         # surfaces as the named PrefetchStallError, never a silent hang
         gen_fn = obs.injector.wrap_step_fn(
-            lambda step: synthetic_text(cfg.seed, step, cfg.num_workers,
-                                        cfg.batch_size, cfg.seq_len,
-                                        cfg.vocab))
+            lambda step: step_tokens(cfg, step, tokens))
         factory = lambda: TokenChunkPrefetcher(  # noqa: E731
             gen_fn, tracer=obs.tracer, timeout_s=cfg.prefetch_timeout_s)
         prefetch = (SupervisedPrefetcher(factory,

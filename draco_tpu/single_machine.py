@@ -10,6 +10,7 @@ from __future__ import annotations
 import argparse
 
 from draco_tpu.cli import add_fit_args, config_from_args, maybe_force_cpu_mesh
+from draco_tpu.config import TOKEN_NETWORKS
 
 
 def main(argv=None):
@@ -23,7 +24,7 @@ def main(argv=None):
     maybe_force_cpu_mesh(args)
 
     cfg = config_from_args(args)
-    if cfg.network == "TransformerLM":
+    if cfg.network in TOKEN_NETWORKS:
         # LM single-machine path: the (w=1, sp=1) token loop — same
         # dispatch the distributed CLI uses, minus the coded axes. The
         # model-parallel knobs span devices this entry point doesn't have:

@@ -107,6 +107,42 @@ def _flash(grad):
     return (bwd if grad else fwd), [(FLASH_SHAPE, jnp.float32)] * 3
 
 
+# kanana2.maj_vote_r3: 32 heads of q/k 192 against v 128 over 4 096 tokens
+MLA_QK, MLA_V = (1, 4096, 32, 192), (1, 4096, 32, 128)
+# its routed experts: a T*6-row dispatch buffer, 128 groups, 8 held here
+GMM_ROWS, GMM_K, GMM_N, GMM_GROUPS, GMM_HELD = 24576, 2048, 768, 128, 8
+
+
+def _flash_latent():
+    """Forward and backward with q/k and v of different head sizes, handed
+    bfloat16 as the token model hands them."""
+    def fn(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, force=True).astype(jnp.float32))), argnums=(0, 1, 2))(
+                q, k, v)
+
+    return fn, [(MLA_QK, jnp.bfloat16)] * 2 + [(MLA_V, jnp.bfloat16)]
+
+
+def _grouped_dot():
+    """models/latent_moe.grouped_dot's kernel (jax's megablox) at the
+    cell's shapes, forward and backward, with only the held groups'
+    matrices."""
+    from unittest import mock
+
+    from draco_tpu.models import latent_moe
+
+    def fn(xs, kernels, sizes):
+        with mock.patch.object(latent_moe, "use_pallas", lambda: True):
+            return jax.grad(lambda xs, kernels: jnp.sum(
+                latent_moe.grouped_dot(xs, kernels, sizes, GMM_HELD) ** 2),
+                argnums=(0, 1))(xs, kernels)
+
+    return fn, [((GMM_ROWS, GMM_K), jnp.float32),
+                ((GMM_HELD, GMM_K, GMM_N), jnp.float32),
+                ((GMM_GROUPS,), jnp.int32)]
+
+
 CASES = {
     # auto selects these on the chip: the three presets' codes + the narrow
     # wire's regularized locator (n=9 s=2 is cyclic-vgg11, n=8 the LM runs)
@@ -121,6 +157,8 @@ CASES = {
     "cyclic_recombine_int8": lambda: _recombine("int8"),
     "flash_fwd": lambda: _flash(grad=False),
     "flash_grad": lambda: _flash(grad=True),
+    "flash_grad_qk192_v128": _flash_latent,
+    "grouped_dot_8_of_128": _grouped_dot,
 }
 
 
