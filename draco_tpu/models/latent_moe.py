@@ -28,12 +28,21 @@ Per layer, x (T, hidden):
 The expert layer is TOLD which experts it holds, scores all of them, takes
 the top-k of all of them and computes its own experts' part: what the
 experts held elsewhere would add is left out (no code stands in for the
-absent chips or their exchange). No token is ever dropped: the dispatch
-buffer holds every (token, choice) pair, T·k rows, sorted by expert with
-this chip's experts first, and the held groups go through one grouped
-product each (on a TPU jax's own megablox kernel, which skips the tiles of
+absent chips or their exchange). The T·k (token, choice) pairs are sorted by
+expert with this chip's experts first — T·k *scalars*; the rows that move
+are the pairs that landed here. The dispatch buffer holds C rows
+(``LatentMoeLM.dispatch_rows``: ``DISPATCH_SHARE`` times what uniform
+routing sends this chip, T·k when it holds every expert): the first C
+sorted pairs' tokens are gathered, go through one grouped product per held
+expert (on a TPU jax's own megablox kernel, which skips the tiles of
 experts not held — chosen over ``lax.ragged_dot`` after measuring both on
-the chip, PERF.md section 6; elsewhere a dense masked product).
+the chip, PERF.md section 6; elsewhere a dense masked product) and are
+summed back into their tokens under their combine weights. No token is ever
+dropped, at any routing: when more than C pairs land here the same code
+runs over the next C sorted pairs, and the next, until it has passed
+``landed`` (``routed_experts``: a loop whose trip count is the data's, one
+in the likely case); the counter ``moe_full_dispatch`` says how many such
+further buffers the step's expert layers ran.
 
 Device scopes (nested in the step's ``draco_comp``): ``draco_attn`` (MLA
 whole), ``draco_route`` (scores, top-k, sort, gather, combine),
@@ -76,11 +85,23 @@ INIT_STD = 0.02  # initializer_range is not in the published config
 # trained model's stream is the token's own, as it is here at unit scale.
 EMBED_STD = 1.0
 BIAS_STD = 0.02  # e_score_correction_bias: moves the top-6, not the load
+# The dispatch buffer's rows, in units of what uniform routing sends this
+# chip (T·k·held/n_routed_experts pairs). A deployment's balancing keeps a
+# chip's share near uniform; seeded weights do not: the benchmark's cell
+# counted up to 1.55 × uniform as the mean of its four expert layers, with
+# single experts at 17 × the held experts' mean — nearly every token of a
+# layer (PERF.md section 6). At 4 × the likely step needs no second buffer,
+# and the buffer is still a quarter of T·k where a chip holds a sixteenth
+# of the experts; a routing that overflows it costs one more pass over C
+# rows, not a wrong result.
+DISPATCH_SHARE = 4
+ROW_TILE = 256  # the grouped product's row tile; C is a multiple of it
 # per-step counters of the expert layers (token-expert pairs that landed on
 # the experts held, over all expert layers; the fullest held expert over the
-# mean one; pairs that got no row), averaged over lanes by the caller
+# mean one; pairs that got no row; dispatch buffers run beyond each layer's
+# first), averaged over lanes by the caller
 STAT_NAMES = ("moe_assignments_held", "moe_load_max_over_mean",
-              "moe_dropped")
+              "moe_dropped", "moe_full_dispatch")
 
 
 def check_spec(spec) -> None:
@@ -166,31 +187,39 @@ def swiglu(h, p):
                 * _dot(h, p["up"]["kernel"]), p["down"]["kernel"])
 
 
-@jax.custom_vjp
-def permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation and its inverse. The backward pass is
-    the inverse gather, ``g[inverse]``: autodiff's transpose of a gather is
-    a scatter-add, which the chip runs far slower than a gather of whole
-    rows, and for a permutation the two are the same thing."""
-    return x[perm]
+def _all_buffers(buffer, h, w, e, dispatch, needed):
+    """Σ_j ``buffer(j, h, w, e, dispatch)`` over the ``needed`` dispatch
+    buffers that hold a landed pair: one in the likely case, more while
+    pairs lie past them — a loop whose trip count is the data's, which
+    autodiff cannot reverse. So the backward pass is stated here: the same
+    loop, each buffer's vjp recomputed from the layer's inputs, which are
+    all that is kept. (Under the per-layer rematerialisation that costs
+    nothing: the recomputed layer no longer runs this forward, nothing
+    reads it. A ``lax.cond`` between a small and a full path hands back the
+    untaken side's residuals as zeros, full-size, on every step; a first
+    buffer outside the loop, with its own residuals kept, runs as fast and
+    compiles every product twice: +22 s on a cold start, PERF.md section
+    6.)"""
+    return lax.fori_loop(
+        0, needed, lambda j, acc: acc + buffer(j, h, w, e, dispatch),
+        jnp.zeros_like(h))
 
 
-permute_rows.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
-                    lambda res, g: (g[res[1]], None, None))
+def _routed_bwd(buffer, res, g):
+    h, w, e, dispatch, needed = res
+
+    def add_buffer(j, grads):
+        _, pull = jax.vjp(lambda h, w, e: buffer(j, h, w, e, dispatch),
+                          h, w, e)
+        return jax.tree.map(jnp.add, grads, pull(g))
+
+    zeros = jax.tree.map(jnp.zeros_like, (h, w, e))
+    return (*lax.fori_loop(0, needed, add_buffer, zeros), None, None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(h, order, inverse, k: int):
-    """Row ``i`` of the dispatch buffer: the token of the ``order[i]``-th
-    (token, choice) pair, ``h[order // k]``. Backward: each token's k rows
-    gathered back (``g[inverse]``) and summed — no scatter-add."""
-    return h[order // k]
-
-
-dispatch_rows.defvjp(
-    lambda h, order, inverse, k: (h[order // k], (inverse, h.shape)),
-    lambda k, res, g: (g[res[0]].reshape(res[1][0], k, res[1][1]).sum(axis=1),
-                       None, None))
+routed_experts = jax.custom_vjp(_all_buffers, nondiff_argnums=(0,))
+routed_experts.defvjp(
+    lambda buffer, *args: (_all_buffers(buffer, *args), args), _routed_bwd)
 
 
 def grouped_dot(xs, kernels, sizes, held: int):
@@ -203,7 +232,7 @@ def grouped_dot(xs, kernels, sizes, held: int):
 
         m, k = xs.shape
         n = kernels.shape[-1]
-        tiling = (min(m, 256), min(k, 1024), min(n, 1024))
+        tiling = (min(m, ROW_TILE), min(k, 1024), min(n, 1024))
         return megablox.gmm(_operand(xs), _operand(kernels), sizes,
                             jnp.float32, tiling,
                             jnp.zeros((), jnp.int32))
@@ -317,11 +346,22 @@ class LatentMoeLM:
         return _dot(o.astype(h.dtype).reshape(b, t, heads * vd),
                     p["o"]["kernel"])
 
+    def dispatch_rows(self, tokens: int) -> int:
+        """C, the dispatch buffer's rows for ``tokens`` rows of input: from
+        the shapes alone (module constant ``DISPATCH_SHARE``), T·k where
+        the chip holds every expert."""
+        s = self.spec
+        pairs = tokens * s["num_experts_per_tok"]
+        share = -(-DISPATCH_SHARE * pairs * s["experts_held"][1]
+                  // s["n_routed_experts"])
+        return min(pairs, -(-share // ROW_TILE) * ROW_TILE)
+
     def _route(self, h, p):
-        """h (N, hidden) -> the dispatch: the (token, choice) pairs' sorted
-        order, the groups' sizes, the inverse permutation, (N, k) combine
-        weights (zero where the chosen expert is not held), the count of
-        rows that landed here, and the counters."""
+        """h (N, hidden) -> (N, k) combine weights (zero where the chosen
+        expert is not held); the dispatch, all of it scalars: the (token,
+        choice) pairs' sorted order (this chip's experts first), the
+        groups' sizes, the count of pairs that landed here; the count of
+        dispatch buffers that hold a landed pair; and the counters."""
         s = self.spec
         k = s["num_experts_per_tok"]
         first, held = s["experts_held"]
@@ -331,57 +371,84 @@ class LatentMoeLM:
             precision=lax.Precision.HIGHEST))
         bias = lax.stop_gradient(p["e_score_correction_bias"])
         _, chosen = lax.top_k(scores + bias, k)
-        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        # scores[chosen] under a dense mask: the chip runs a gather of T·k
+        # scalars, and the scatter-add its transpose is, far slower
+        experts = jnp.arange(n_exp, dtype=chosen.dtype)
+        w = jnp.sum(jnp.where(chosen[..., None] == experts,
+                              scores[:, None, :], 0.0), axis=-1)
         if s["norm_topk_prob"]:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         w = w * s["routed_scaling_factor"]
         # this chip's experts become groups 0..held-1, the others follow
         group = (chosen.reshape(-1) - first) % n_exp
         order = jnp.argsort(group)  # stable: arrival order within a group
-        rows = group.shape[0]
-        inverse = jnp.zeros((rows,), jnp.int32).at[order].set(
-            jnp.arange(rows, dtype=jnp.int32))
-        sizes = jnp.zeros((n_exp,), jnp.int32).at[group].add(1)
+        sizes = jnp.sum(group[:, None] == experts, axis=0, dtype=jnp.int32)
         here = (group < held).reshape(chosen.shape)
         landed = jnp.sum(sizes[:held])
+        rows = self.dispatch_rows(h.shape[0])
+        needed = -(-landed // rows)
         stats = {"load": sizes[:held].astype(jnp.float32),
-                 # pairs that chose an expert held here and got no row:
-                 # none, the buffer holds every pair
-                 "dropped": (jnp.sum(here) - landed).astype(jnp.float32)}
-        return (order, sizes, inverse, jnp.where(here, w, 0.0), landed,
+                 # pairs that chose an expert held here and lie in no
+                 # buffer that is run: none, the buffers run past `landed`
+                 "dropped": (jnp.sum(here) - jnp.minimum(
+                     landed, needed * rows)).astype(jnp.float32),
+                 "further": jnp.maximum(needed - 1, 0).astype(jnp.float32)}
+        return (jnp.where(here, w, 0.0), (order, sizes, landed), needed,
                 stats)
+
+    def _buffer(self, j, h, w, e, dispatch):
+        """What the sorted pairs [j·C, (j+1)·C) add to the routed experts'
+        output (N, hidden): their tokens' rows gathered, one grouped product
+        per held expert, the rows summed back into their tokens under
+        their combine weights. C rows throughout, forward and backward."""
+        order, sizes, landed = dispatch
+        k = self.spec["num_experts_per_tok"]
+        held = self.spec["experts_held"][1]
+        rows = self.dispatch_rows(h.shape[0])
+        with jax.named_scope("draco_route"):
+            slot = j * rows + jnp.arange(rows, dtype=jnp.int32)
+            live = slot < landed
+            # (a last buffer that reaches past T·k reads padding, not live)
+            pair = lax.dynamic_slice(
+                jnp.pad(order, (0, -order.shape[0] % rows)), (j * rows,),
+                (rows,))
+            token = pair // k
+            weight = jnp.where(live, w.reshape(-1)[pair], 0.0)
+            # this buffer's part of every group
+            ends = jnp.cumsum(sizes)
+            lo, hi = j * rows, (j + 1) * rows
+            part = jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes, lo, hi)
+            xs = h[token]
+        with jax.named_scope("draco_experts"):
+            mid = (jax.nn.silu(grouped_dot(xs, e["gate"]["kernel"], part,
+                                           held))
+                   * grouped_dot(xs, e["up"]["kernel"], part, held))
+            ys = grouped_dot(mid.astype(xs.dtype), e["down"]["kernel"],
+                             part, held)
+            # rows of experts not held: exactly zero, whatever the
+            # grouped product left there
+            ys = jnp.where(live[:, None], ys, 0.0).astype(h.dtype)
+        with jax.named_scope("draco_route"):
+            return jax.ops.segment_sum(
+                weight[:, None].astype(h.dtype) * ys, token,
+                num_segments=h.shape[0])
 
     def _experts(self, x, p):
         """x (N, hidden) -> x + the routed (held) and shared experts of its
         normalised rows; the norm counts as the experts' (it feeds them)."""
-        held = self.spec["experts_held"][1]
-        k = self.spec["num_experts_per_tok"]
         with jax.named_scope("draco_experts"):
             h = rms_norm(x, p["mlp_norm"]["scale"],
                          self.spec["rms_norm_eps"])
+            # the products' operands, once a layer: every buffer reads the
+            # same copies (made inside the loop over further buffers, the
+            # compiler hoists a second set out of it and keeps it alive
+            # through the step: +0.2 GB of peak memory)
+            e = jax.tree.map(_operand, p["experts"])
         with jax.named_scope("draco_route"):
-            order, sizes, inverse, w, landed, stats = self._route(
-                h, p["router"])
-            # (N·k, hidden), held experts' rows first
-            xs = dispatch_rows(h, order, inverse, k)
+            w, dispatch, needed, stats = self._route(h, p["router"])
+        routed = routed_experts(self._buffer, h, w, e, dispatch, needed)
         with jax.named_scope("draco_experts"):
-            e = p["experts"]
-            mid = (jax.nn.silu(grouped_dot(xs, e["gate"]["kernel"], sizes,
-                                           held))
-                   * grouped_dot(xs, e["up"]["kernel"], sizes, held))
-            ys = grouped_dot(mid.astype(xs.dtype), e["down"]["kernel"],
-                             sizes, held)
-            # rows of experts not held: exactly zero, whatever the
-            # grouped product left there
-            ys = jnp.where(jnp.arange(ys.shape[0])[:, None] < landed, ys,
-                           0.0).astype(h.dtype)
             shared = swiglu(h, p["shared"])
-        with jax.named_scope("draco_route"):
-            routed = jnp.einsum(
-                "nk,nkd->nd", w.astype(h.dtype),
-                permute_rows(ys, inverse, order).reshape(
-                    h.shape[0], k, h.shape[1]))
-        with jax.named_scope("draco_experts"):
             return x + (routed + shared), stats
 
     def _layer(self, x, p, positions, dense: bool):
@@ -448,4 +515,5 @@ def fold_stats(per_layer: list) -> dict:
         "moe_load_max_over_mean": jnp.max(load) / jnp.maximum(
             jnp.mean(load), 1e-9),
         "moe_dropped": sum(s["dropped"] for s in per_layer),
+        "moe_full_dispatch": sum(s["further"] for s in per_layer),
     }

@@ -109,8 +109,9 @@ def _flash(grad):
 
 # kanana2.maj_vote_r3: 32 heads of q/k 192 against v 128 over 4 096 tokens
 MLA_QK, MLA_V = (1, 4096, 32, 192), (1, 4096, 32, 128)
-# its routed experts: a T*6-row dispatch buffer, 128 groups, 8 held here
-GMM_ROWS, GMM_K, GMM_N, GMM_GROUPS, GMM_HELD = 24576, 2048, 768, 128, 8
+# its routed experts: a dispatch buffer of C = 6 144 of the T*6 = 24 576
+# (token, choice) pairs (LatentMoeLM.dispatch_rows), 128 groups, 8 held here
+GMM_ROWS, GMM_K, GMM_N, GMM_GROUPS, GMM_HELD = 6144, 2048, 768, 128, 8
 
 
 def _flash_latent():

@@ -9,6 +9,10 @@ imports nothing of draco_tpu):
   reference layer;
 * the top-k never drops a token, at any imbalance (all tokens to one held
   expert);
+* the dispatch buffer holds C rows, fewer than T·k where the chip holds a
+  small share of the experts: a routing that lands 0, 1, C − 1, C, C + 1 or
+  T·min(k, held) pairs here gives the reference's output and gradients, with
+  the further buffers it took counted;
 * the flash kernel at q/k and v of different head sizes (interpret mode);
 * a mapping the block cannot state is refused by the key's name, and so is
   every TrainConfig the new network or the LM vote do not support.
@@ -158,6 +162,135 @@ def test_no_token_is_dropped_when_all_choose_one_held_expert(model):
     assert float(stats["moe_load_max_over_mean"]) == pytest.approx(1.0)
     assert float(loss) == pytest.approx(float(ref.loss(skew, toks, SPEC)),
                                         rel=2e-6)
+
+
+# ---- the dispatch buffer: C rows, and every routing still exact ---------
+# 2 of 64 experts held, k = 3, 1 024 tokens: C = 512 of T·k = 3 072 rows,
+# and up to T·min(k, held) = 2 048 pairs can land here (four buffers)
+SMALL_SHARE = dict(SPEC, n_routed_experts=64, experts_held=[4, 2])
+N_TOK = 1024
+C_ROWS = 512
+
+
+def _routing_that_lands(m: int):
+    """(x (N_TOK, hidden), an expert layer's parameters) under which
+    exactly ``m`` (token, choice) pairs choose an expert held here: the
+    router reads coordinate 0 for the first held expert and coordinate 1
+    for the second, the selection bias puts both between a token's default
+    three (held elsewhere) and the rest."""
+    spec = SMALL_SHARE
+    first, held = spec["experts_held"]
+    k, n_exp = spec["num_experts_per_tok"], spec["n_routed_experts"]
+    assert held == 2 and 0 <= m <= 2 * N_TOK
+    rng = np.random.default_rng(m)
+    p = LatentMoeLM(spec).init(jax.random.key(11))["layer1"]
+    p = jax.tree.map(np.array, p)
+    kernel = p["router"]["kernel"]
+    kernel[:2, first:first + held] = np.eye(2)
+    bias = np.full(n_exp, -10.0, np.float32)
+    bias[first:first + held] = 0.3
+    bias[first + held:first + held + k] = 0.5
+    p["router"]["e_score_correction_bias"] = bias
+    x = rng.standard_normal((N_TOK, spec["hidden_size"])).astype(np.float32)
+    x[:, :2] = -8.0
+    shuffled = rng.permutation(N_TOK)
+    x[shuffled[:min(m, N_TOK)], 0] = 8.0
+    x[shuffled[:max(m - N_TOK, 0)], 1] = 8.0
+    return jnp.asarray(x), jax.tree.map(jnp.asarray, p)
+
+
+def _assert_experts_match_the_reference(spec, x, p, landed, further):
+    """``_experts`` of (x, p): output and every leaf's gradient against the
+    dense reference, and the counters."""
+    lm = LatentMoeLM(spec)
+    cot = jax.random.normal(jax.random.key(landed), x.shape)
+
+    def reference(x, p):
+        h = ref.rms(x, p["mlp_norm"]["scale"], spec["rms_norm_eps"])
+        return x + ref.experts(h, p, spec, lambda t: t)
+
+    def ours(x, p):
+        y, stats = lm._experts(x, p)
+        return jnp.sum(y * cot), (y, latent_moe.fold_stats([stats]))
+
+    (_, (y, stats)), got = jax.value_and_grad(ours, argnums=(0, 1),
+                                              has_aux=True)(x, p)
+    assert float(stats["moe_assignments_held"]) == landed
+    assert float(stats["moe_dropped"]) == 0.0
+    assert float(stats["moe_full_dispatch"]) == further
+    np.testing.assert_allclose(y, reference(x, p), atol=2e-5)
+    want = jax.grad(lambda x, p: jnp.sum(reference(x, p) * cot),
+                    argnums=(0, 1))(x, p)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = jax.tree.leaves(want)
+    assert len(flat_got) == len(flat_want)
+    for (path, g), w in zip(flat_got, flat_want):
+        name = jax.tree_util.keystr(path)
+        scale = float(jnp.max(jnp.abs(w))) + 1e-12
+        assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * scale + 1e-9, name
+
+
+def test_dispatch_buffer_is_sized_by_the_chips_share():
+    assert LatentMoeLM(SMALL_SHARE).dispatch_rows(N_TOK) == C_ROWS
+    # the published widths: 8 of 128 held, top-6, 4 096 tokens a lane
+    assert LatentMoeLM(dict(SPEC, n_routed_experts=128, experts_held=[0, 8],
+                            num_experts_per_tok=6)).dispatch_rows(4096) \
+        == 6144
+    # every expert held: the buffer is every pair, one path
+    k, n_exp = SPEC["num_experts_per_tok"], SPEC["n_routed_experts"]
+    assert LatentMoeLM(dict(SPEC, experts_held=[0, n_exp])).dispatch_rows(
+        T) == T * k
+    # a quarter held at the tiny size: 4 × the share is every pair too
+    assert LatentMoeLM(SPEC).dispatch_rows(2 * T) == 2 * T * k
+
+
+@pytest.mark.parametrize("landed,further", [
+    (0, 0), (1, 0), (C_ROWS - 1, 0), (C_ROWS, 0), (C_ROWS + 1, 1),
+    (2 * N_TOK, 3)])
+def test_any_routing_is_computed_exactly(landed, further):
+    """Output and every leaf's gradient against the dense reference, for
+    routings that fill the buffer to its last row, pass it by one, and
+    send every token to both held experts."""
+    x, p = _routing_that_lands(landed)
+    _assert_experts_match_the_reference(SMALL_SHARE, x, p, landed, further)
+
+
+def test_a_last_buffer_that_reaches_past_the_pairs_is_exact():
+    """8 of 64 held, 1 000 tokens: C = 1 536 of 3 000 pairs, so the second
+    buffer reaches past the last pair — and is needed when every token
+    takes three held experts."""
+    spec = dict(SPEC, n_routed_experts=64, experts_held=[8, 8])
+    tokens, k = 1000, spec["num_experts_per_tok"]
+    assert LatentMoeLM(spec).dispatch_rows(tokens) == 1536
+    p = LatentMoeLM(spec).init(jax.random.key(13))["layer1"]
+    bias = np.zeros(spec["n_routed_experts"], np.float32)
+    bias[[9, 12, 15]] = 50.0
+    p["router"]["e_score_correction_bias"] = jnp.asarray(bias)
+    x = jax.random.normal(jax.random.key(14), (tokens, spec["hidden_size"]))
+    _assert_experts_match_the_reference(spec, x, p, tokens * k, 1)
+
+
+def test_lanes_side_by_side_each_take_the_buffers_they_need():
+    """Under ``vmap`` (the tests' lanes) the loop over further buffers runs
+    as long as any lane needs it, and each lane still gets its own sum."""
+    lm = LatentMoeLM(SMALL_SHARE)
+    x0, p = _routing_that_lands(7)
+    x1 = x0.at[:, 0].set(8.0)  # every token to the first held expert
+
+    def loss(x, p):
+        y, stats = lm._experts(x, p)
+        return jnp.sum(y ** 2), stats["further"]
+
+    each = [jax.value_and_grad(loss, argnums=1, has_aux=True)(x, p)
+            for x in (x0, x1)]
+    (_, further), grads = jax.vmap(
+        jax.value_and_grad(loss, argnums=1, has_aux=True),
+        in_axes=(0, None))(jnp.stack([x0, x1]), p)
+    assert further.tolist() == [0.0, 1.0]
+    for lane, ((_, f), g) in enumerate(each):
+        assert float(f) == float(further[lane])
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(grads)):
+            np.testing.assert_allclose(a, b[lane], rtol=1e-5, atol=1e-6)
 
 
 def test_flash_kernel_takes_q_k_and_v_of_different_head_sizes():

@@ -121,6 +121,7 @@ def test_the_new_network_reports_its_experts_counters():
     _, rows = _run(_cfg("LatentMoeLM", num_workers=3, max_steps=2))
     for r in rows:
         assert r["moe_dropped"] == 0.0
+        assert r["moe_full_dispatch"] == 0.0
         assert 0 < r["moe_assignments_held"] <= 2 * 32 * 3 * 2
         assert r["moe_load_max_over_mean"] >= 1.0
 
@@ -135,7 +136,7 @@ def test_the_chunked_loop_runs_the_same_steps():
     for a, c in zip(jax.tree.leaves(eager), jax.tree.leaves(chunked)):
         np.testing.assert_allclose(a, c, rtol=1e-5, atol=1e-7)
     names = {"loss", "vote_agree", "located_errors", "det_tp", "det_adv",
-             "moe_assignments_held", "moe_dropped"}
+             "moe_assignments_held", "moe_dropped", "moe_full_dispatch"}
     assert names <= set(rows2[-1]) and names <= set(rows1[-1])
     assert rows2[-1]["det_tp"] == 1.0
 
