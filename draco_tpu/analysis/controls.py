@@ -125,10 +125,9 @@ def _build_undonated_carry() -> BuiltProgram:
 
 def _build_f64_upcast() -> BuiltProgram:
     """Defect: an f64 accumulation inside the step (traced under
-    jax.experimental.enable_x64, the only way f64 can sneak in)."""
+    jax.enable_x64, the only way f64 can sneak in)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     mesh = _mini_mesh()
 
@@ -143,7 +142,7 @@ def _build_f64_upcast() -> BuiltProgram:
     return BuiltProgram("control_f64_upcast", fn,
                         (_mini_state(mesh), _mini_batch(mesh)), mesh,
                         Manifest(collectives=_MINI_COLLECTIVES),
-                        trace_ctx=enable_x64)
+                        trace_ctx=lambda: jax.enable_x64(True))
 
 
 def _build_extra_all_gather() -> BuiltProgram:
@@ -179,11 +178,14 @@ def _build_extra_all_gather() -> BuiltProgram:
 
 
 def _build_host_outfeed_in_scan() -> BuiltProgram:
-    """Defect: an outfeed inside the scanned body — the host round-trip
-    that re-serializes every chunk on the dispatch link."""
+    """Defect: a host hop inside the scanned body — the round-trip that
+    re-serializes every chunk on the dispatch link. (An ordered
+    ``io_callback``: jax 0.9 has no ``lax.outfeed`` left to write one
+    with; the control keeps the name the committed artifacts know.)"""
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from jax.experimental import io_callback
 
     mesh = _mini_mesh()
 
@@ -191,8 +193,8 @@ def _build_host_outfeed_in_scan() -> BuiltProgram:
         def body(st, x):
             w, step = st
             g = _psum_grads(mesh)(x).sum(0)
-            token = lax.create_token()
-            lax.outfeed(token, jnp.sum(g))  # <- host hop per scanned step
+            # the host hop per scanned step
+            io_callback(lambda v: None, None, jnp.sum(g), ordered=True)
             return (w - 0.01 * g, step + 1), jnp.sum(w)
 
         return lax.scan(body, state, xs)

@@ -40,9 +40,11 @@ from typing import Any, Callable, Optional, Tuple
 # Element types an honest draco_tpu program may contain (MLIR spelling).
 # f64/complex<f64> are NEVER allowed — rules.rule_dtype hard-fails on them
 # regardless of the manifest. i64 shows up as index arithmetic on the
-# shard_map/GSPMD routes (iota/gather bookkeeping), not as compute.
+# shard_map/GSPMD routes (iota/gather bookkeeping), not as compute; ui64
+# is jax.random's own block counter (since jax 0.9 threefry's random_bits
+# counts in a ui64 iota and splits it into its two ui32 words).
 DEFAULT_DTYPES = frozenset(
-    {"f32", "i1", "i8", "i16", "i32", "i64", "ui8", "ui16", "ui32"}
+    {"f32", "i1", "i8", "i16", "i32", "i64", "ui8", "ui16", "ui32", "ui64"}
 )
 BF16_DTYPES = DEFAULT_DTYPES | {"bf16"}
 
@@ -118,7 +120,7 @@ class BuiltProgram:
     args, the mesh to trace under, and the manifest to lint against.
 
     ``trace_ctx`` wraps trace+export (negative controls use
-    ``jax.experimental.enable_x64``); ``donate_argnums`` names which args
+    ``jax.enable_x64``); ``donate_argnums`` names which args
     the ``"state"`` donation sentinel resolves over (arg 0 by convention).
 
     ``capture_memory``: compile for the host backend to record the

@@ -720,8 +720,8 @@ def shadow_columns(agg, shadow_agg, shadow_residual, flags, shadow_flags,
                    adv_mask, present) -> dict:
     """The SHADOW_NAMES columns from one step's f32 + shadow decode pair
     (module docstring). The detection counts reimplement the present-gated
-    scoring of training/step._detection_metrics on the SHADOW flag set (a
-    straggling adversary is neither detectable nor ground truth)."""
+    scoring of parallel/common.decode_health_metrics on the SHADOW flag set
+    (a straggling adversary is neither detectable nor ground truth)."""
     import jax.numpy as jnp
 
     agg = jnp.asarray(agg, jnp.float32)

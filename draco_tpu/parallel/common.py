@@ -1,10 +1,15 @@
-"""Shared tail of the flat-gradient training paths (sp_step / tp_step):
-attack injection → coded decode or robust aggregation → optimizer update.
+"""The one tail of every training step — the CNN path (training/step.py) and
+the LM routes (sp / tp / ep / pp): fault and attack injection → encode →
+wire → coded decode, vote or robust aggregation → health → optimizer
+update → guard.
 
-One implementation so a fix to injection, decode, or the update convention
-cannot silently diverge between the parallelism paths. (The CNN path in
-training/step.py keeps its own tail: it additionally handles straggler
-presence masks, layer-granularity decode, and per-worker batch stats.)
+One implementation so a fix to injection, decode, the wire, or the update
+convention cannot diverge between the paths: a step builder computes its
+rows (which lanes run is the only per-approach, per-route part), hands
+the (n, [r,] d) stack to ``aggregate_flat_grads`` and the result to
+``finish_flat_step``. What a caller needs that another does not is an
+argument (``constrain``, ``carry``) or a ``health`` key, never a branch on
+who is calling.
 """
 
 from __future__ import annotations
@@ -12,9 +17,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from draco_tpu import aggregation, attacks
+from draco_tpu import aggregation, attacks, rng as drng
 from draco_tpu.coding import approx as approx_mod
 from draco_tpu.coding import cyclic as cyclic_mod
+from draco_tpu.coding import repetition as rep_mod
+from draco_tpu.coding import topology as topology_mod
+from draco_tpu.obs import forensics as forensics_mod
+from draco_tpu.obs import numerics as numerics_mod
+from draco_tpu.ops.decode_kernels import resolve_decode_impl
+from draco_tpu.resilience import faults as faults_mod
+from draco_tpu.resilience.guards import GUARD_METRIC_NAMES, guard_update
 
 
 def build_code_from_cfg(cfg):
@@ -25,10 +37,7 @@ def build_code_from_cfg(cfg):
     wrapping ONE small group code at the (fanout, s_g) shape — the
     aggregation tails below dispatch on the code type, so every route gets
     the hierarchical path through the same seam."""
-    if (cfg.approach in ("cyclic", "approx")
-            and getattr(cfg, "topology", "flat") == "tree"):
-        from draco_tpu.coding import topology as topology_mod
-
+    if cfg.approach in ("cyclic", "approx") and cfg.topology == "tree":
         return topology_mod.build_tree_code(cfg)
     if cfg.approach == "cyclic":
         return cyclic_mod.build_cyclic_code(cfg.num_workers, cfg.worker_fail)
@@ -39,10 +48,7 @@ def build_code_from_cfg(cfg):
 
 
 def _is_tree(code) -> bool:
-    """Code-type dispatch for the aggregation tails (lazy import so the
-    flat path's import graph is untouched)."""
-    from draco_tpu.coding import topology as topology_mod
-
+    """Code-type dispatch for the aggregation tails."""
     return isinstance(code, topology_mod.TreeCode)
 
 
@@ -52,8 +58,6 @@ def segment_decode_bounds(cfg, dim: int, leaf_offsets=None):
     — THE bounds source the ledger and tools share), refined by the static
     leaf boundaries when the decode runs at layer granularity so every
     parameter tensor keeps its own locator."""
-    from draco_tpu.obs import numerics as numerics_mod
-
     bounds = list(numerics_mod.cfg_segment_bounds(cfg, dim))
     if leaf_offsets is not None:
         cuts = sorted({int(o) for o in leaf_offsets}
@@ -62,41 +66,40 @@ def segment_decode_bounds(cfg, dim: int, leaf_offsets=None):
     return bounds
 
 
-def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
-                     cfg=None, adv_mask=None, step=None, mesh=None):
-    """The approx family's whole aggregation sequence — ingest forensics →
-    weighted-partial-sum encode → present mask → optimal-decoding partial
-    recovery → residual-vs-bound health — in ONE place, shared by the CNN
-    step body (training/step.py) and the LM routes' flat-gradient tail
-    below, so the accusation/masking semantics cannot drift between loops.
+def _stash_watch(cfg, health, grads, wire_parts, agg, shadow):
+    """The numerics observatory (obs/numerics.py, ISSUE 10) of one step:
+    dynamic-range columns of the three stages and — ``shadow()`` — the
+    shadow-quantized decode's, stashed under ``health["watch"]`` for
+    ``decode_health_metrics`` to merge into the metric row. The f32 decode
+    alone feeds the update; no added ops when the watch is off."""
+    if not numerics_mod.watch_enabled(cfg):
+        return
+    with jax.named_scope("draco_health"):
+        watch = {}
+        if cfg.numerics_watch == "on":
+            watch.update(numerics_mod.numerics_columns(
+                cfg, [grads], wire_parts, agg))
+        if cfg.shadow_wire != "off":
+            watch.update(shadow())
+        health["watch"] = watch
+
+
+def approx_aggregate(code, grads: jnp.ndarray, cfg, adv_mask, present=None,
+                     constrain=None, step=None, mesh=None):
+    """The approx family's branch of ``aggregate_flat_grads`` — ingest
+    forensics → weighted-partial-sum encode → present mask → wire →
+    optimal-decoding partial recovery → residual-vs-bound health.
 
     No adversary injection: config.validate rejects live adversaries under
     this family (no Byzantine certificate); stragglers are the fault model
     and the only per-worker accusation signal is the non-finite ingest
-    check. ``constrain``: optional sharding-constraint hook applied to the
-    encoded (n, d) rows (the CNN path pins them to the worker axis).
-
-    ``cfg``/``adv_mask``/``step`` (optional, passed by both call sites):
-    enable the numerics observatory (obs/numerics.py, ISSUE 10) — dynamic-
-    range columns for grads/wire/aggregate and the shadow-quantized decode
-    — stashed under ``health["watch"]`` for ``decode_health_metrics`` to
-    merge into the metric row. Identity (no added ops) when the watch is
-    off. ``mesh``: the mesh the calling step is built for — it decides the
-    decode lowering (ops/decode_kernels.resolve_decode_impl)."""
-    from draco_tpu.obs import forensics as forensics_mod
-    from draco_tpu.obs import numerics as numerics_mod
-    from draco_tpu.ops.decode_kernels import resolve_decode_impl
-
-    decode_impl = resolve_decode_impl(
-        getattr(cfg, "decode_impl", "xla") if cfg is not None else "xla",
-        mesh)
+    check."""
+    decode_impl = resolve_decode_impl(cfg.decode_impl, mesh)
     tree = _is_tree(code)
     with jax.named_scope("draco_health"):
         bad_rows = forensics_mod.nonfinite_rows(grads)
     with jax.named_scope("draco_encode"):
         if tree:
-            from draco_tpu.coding import topology as topology_mod
-
             rows = topology_mod.encode_tree(code, grads)
         else:
             rows = approx_mod.encode_shared(code, grads)
@@ -107,54 +110,37 @@ def approx_aggregate(code, grads: jnp.ndarray, present=None, constrain=None,
         # into narrow buffers — THE arrays that cross the sharding
         # boundary — and widen to f32 only for the decode; identity (no
         # ops) on the f32 wire
-        wire = None
-        if cfg is not None and getattr(cfg, "wire_dtype", "f32") != "f32":
-            rows, wire = numerics_mod.narrow_wire_single(
-                cfg, rows, step=step, constrain=constrain)
-        elif constrain is not None:
+        rows, wire = numerics_mod.narrow_wire_single(
+            cfg, rows, step=step, constrain=constrain)
+        if wire is None and constrain is not None:
             rows = constrain(rows)
-    segments = (int(getattr(cfg, "wire_segments", 1))
-                if cfg is not None else 1)
+    dim = int(rows.shape[-1])
     with jax.named_scope("draco_decode"):
         if tree:
             # hierarchical tree aggregation (ISSUE 17): per-group optimal
             # decoding at the (g, d) block, level-structured combine, root
             # residual + Cauchy-Schwarz-folded bound (decode_tree_approx)
-            from draco_tpu.coding import topology as topology_mod
-
-            bounds = (numerics_mod.cfg_segment_bounds(
-                cfg, int(rows.shape[-1])) if segments > 1 else None)
+            bounds = (numerics_mod.cfg_segment_bounds(cfg, dim)
+                      if cfg.wire_segments > 1 else None)
             agg, _v, health = topology_mod.decode_tree_approx(
                 code, rows, present=present, batch_grads=grads,
                 impl=decode_impl, wire=wire, bounds=bounds)
-        elif segments > 1:
+        elif cfg.wire_segments > 1:
             # streaming segmented wire (ISSUE 16): the presence-only
             # weight solve runs once; each segment combines on arrival and
             # the residual accumulators fold to one per-step verdict
-            bounds = numerics_mod.cfg_segment_bounds(
-                cfg, int(rows.shape[-1]))
             agg, _v, health = approx_mod.decode_segments(
-                code, rows, bounds, present=present, with_health=True,
-                batch_grads=grads, impl=decode_impl, wire=wire)
+                code, rows, numerics_mod.cfg_segment_bounds(cfg, dim),
+                present=present, with_health=True, batch_grads=grads,
+                impl=decode_impl, wire=wire)
         else:
             agg, _v, health = approx_mod.decode(
                 code, rows, present=present, with_health=True,
                 batch_grads=grads, impl=decode_impl, wire=wire)
     health["bad_rows"] = bad_rows
-    if cfg is not None:
-        from draco_tpu.obs import numerics as numerics_mod
-
-        if numerics_mod.watch_enabled(cfg):
-            watch = {}
-            if cfg.numerics_watch == "on":
-                watch.update(numerics_mod.numerics_columns(
-                    cfg, [grads], [rows], agg))
-            if cfg.shadow_wire != "off":
-                amask = (jnp.zeros((code.n,), bool) if adv_mask is None
-                         else adv_mask)
-                watch.update(numerics_mod.approx_shadow(
-                    cfg, code, rows, grads, agg, present, amask, step))
-            health["watch"] = watch
+    _stash_watch(cfg, health, grads, [rows], agg,
+                 lambda: numerics_mod.approx_shadow(
+                     cfg, code, rows, grads, agg, present, adv_mask, step))
     return agg, health
 
 
@@ -191,10 +177,12 @@ def _inject_rows(grads, adv_mask, cfg, step):
 
 def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                          present=None, leaf_offsets=None, step=None,
-                         mesh=None):
-    """(n, d) per-worker flat gradients → ``(aggregated (d,), health)``.
-    (maj_vote also takes a large stack with its rows laid out in tiles,
-    (n, d / 1024, 8, 128): sp_step.STACK_TILE.)
+                         mesh=None, constrain=None):
+    """The (n, d) stack of per-worker flat gradients → ``(aggregated (d,),
+    health)``: the one coded tail of every step builder. Two further
+    layouts: (n, hat_s, d), each worker's own redundant lanes
+    (``redundancy="simulate"``), and under maj_vote a large stack with its
+    rows laid out in tiles, (n, d / 1024, 8, 128): sp_step.STACK_TILE.
 
     ``step`` (optional traced scalar): the training step, threaded so the
     deterministic fault plan (``cfg.fault_spec``,
@@ -202,43 +190,46 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
     worker-gradient faults — identity (no added ops) when no plan is
     configured.
 
-    cyclic: shared-redundancy encode, adversarial injection on the encoded
-    rows, exact decode — ``health`` is the in-graph decode-health dict
+    cyclic: encode, adversarial injection on the encoded rows, the wire,
+    exact decode — ``health`` is the in-graph decode-health dict
     (coding/cyclic.decode ``with_health``: scalar ``residual`` ≈ 0 iff the
     decode is self-consistent, (n,) bool ``flagged`` of located-error
-    rows). Otherwise: injection on the raw rows, then the configured robust
-    aggregation (mean / geo-median / krum) — approximate rules carry no
-    exactness certificate, so ``health`` is None and the telemetry layer
-    emits no decode-health columns for them.
+    rows), plus ``honest`` (the decode's honest mask, (n,)) and
+    ``bad_rows`` (non-finite ingest rows). maj_vote: injection on the raw
+    rows, the wire, the vote — ``health`` is the vote's (``vote_agree``,
+    ``flagged_groups``, ``flagged``) and ``bad_rows``. approx:
+    :func:`approx_aggregate`. Otherwise: injection on the raw rows, then
+    the configured robust aggregation (mean / geo-median / krum) —
+    approximate rules carry no exactness certificate, so ``health`` is
+    None and the telemetry layer emits no decode-health columns for them.
 
     ``present`` ((n,) bool, optional): straggler rows marked False never
     arrive — cyclic decodes around them as erasures (known-missing, one
     redundancy unit each), the robust rules aggregate over present rows
-    only. Same semantics as the CNN path (training/step.py).
+    only.
 
     ``leaf_offsets``: static per-tensor segment boundaries from
     _make_unravel — required when ``cfg.decode_granularity == "layer"`` so
     the cyclic decode runs one locator per parameter tensor like the
-    reference (cyclic_master.py:125-129), matching the CNN path.
+    reference (cyclic_master.py:125-129).
 
     ``mesh``: the mesh the calling route's step is built for — it decides
     the decode lowering (ops/decode_kernels.resolve_decode_impl: the
     kernels are a one-device lowering).
 
-    The encode/decode phases run under ``jax.named_scope`` so XProf device
-    traces group ops by Draco's reference phase names (the device-side
-    counterpart of the host SpanTracer, draco_tpu/obs).
-    """
-    from draco_tpu.obs import forensics as forensics_mod
-    from draco_tpu.resilience import faults as faults_mod
+    ``constrain`` (optional): pins the arrays that cross the wire — the
+    encoded rows, or the narrow wire's buffers — to the caller's worker
+    sharding; it is what places the gather on a mesh of several devices.
 
+    The phases run under ``jax.named_scope`` so XProf device traces group
+    ops by Draco's reference phase names (the device-side counterpart of
+    the host SpanTracer, draco_tpu/obs).
+    """
     with jax.named_scope("draco_attack"):
         grads = faults_mod.corrupt_grads(grads, cfg, step)
     if cfg.approach == "approx":
-        # approximate family (coding/approx.py; ISSUE 8): the shared
-        # sequence above — health is the residual-vs-bound certificate
-        return approx_aggregate(code, grads, present=present, cfg=cfg,
-                                adv_mask=adv_mask, step=step, mesh=mesh)
+        return approx_aggregate(code, grads, cfg, adv_mask, present=present,
+                                constrain=constrain, step=step, mesh=mesh)
     if cfg.approach == "cyclic":
         # ingest-row health, BEFORE encode: a non-finite per-worker gradient
         # row attributes to its worker here, where row k still means worker
@@ -254,8 +245,6 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                 # encodes with the ONE shared small code — rows stay
                 # worker-indexed (n, d), so injection/presence/wire below
                 # are byte-identical to flat
-                from draco_tpu.coding import topology as topology_mod
-
                 enc_re, enc_im = topology_mod.encode_tree(code, grads)
             elif grads.ndim == 3:
                 # (n, hat_s, d): true per-worker redundant lanes
@@ -272,21 +261,24 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                 enc_re, enc_im, adv_mask, cfg.err_mode, cfg.adversarial,
                 step=step, seed=cfg.seed
             )
-        from draco_tpu.obs import numerics as numerics_mod
-        from draco_tpu.ops.decode_kernels import resolve_decode_impl
-
         decode_impl = resolve_decode_impl(cfg.decode_impl, mesh)
         with jax.named_scope("draco_encode"):
             if present is not None:
+                # straggler rows never arrive: zero-fill (erasures at known
+                # positions; decode recovers exactly within the budget —
+                # config.validate)
                 pw = present[:, None].astype(enc_re.dtype)
                 enc_re, enc_im = enc_re * pw, enc_im * pw
             # the REAL narrow wire (ISSUE 15): the codeword pair is
-            # rounded into narrow buffers that cross the sharding boundary;
-            # the decode widens to f32 and runs the quantization-aware flag
-            # threshold + Tikhonov-regularized locator. Identity on the f32
-            # wire.
+            # rounded into narrow buffers — THE arrays that cross the
+            # sharding boundary (the constraint pins them, not a widened
+            # copy); the decode widens to f32 and runs the quantization-
+            # aware flag threshold + Tikhonov-regularized locator.
+            # Identity on the f32 wire, where the pair itself is pinned.
             enc_re, enc_im, wire = numerics_mod.narrow_wire_pair(
-                cfg, enc_re, enc_im, step=step)
+                cfg, enc_re, enc_im, step=step, constrain=constrain)
+            if wire is None and constrain is not None:
+                enc_re, enc_im = constrain(enc_re), constrain(enc_im)
         if tree:
             # the tree decodes each leaf group at the GROUP shape — its
             # narrow-wire thresholds come from the (fanout, s_g) table row
@@ -294,81 +286,57 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                 cfg, n=code.plan.fanout, s=code.group_code.s)
         else:
             wire_tol, wire_lam = numerics_mod.wire_decode_params(cfg)
-        rel_tol = (cyclic_mod.HEALTH_REL_TOL if wire_tol is None
-                   else wire_tol)
-        segments = int(getattr(cfg, "wire_segments", 1))
+        kw = dict(present=present, impl=decode_impl, lam=wire_lam,
+                  rel_tol=(cyclic_mod.HEALTH_REL_TOL if wire_tol is None
+                           else wire_tol))
+        dim = int(grads.shape[-1])
         with jax.named_scope("draco_decode"):
             if tree:
                 # hierarchical decode (ISSUE 17): per-group small-n decode
                 # (segmented when the streaming wire is on), level-
                 # structured combine, PR 16-style health fold — same
-                # health keys as flat, so every consumer below is shared
-                from draco_tpu.coding import topology as topology_mod
-
-                bounds = (numerics_mod.cfg_segment_bounds(
-                    cfg, int(grads.shape[-1])) if segments > 1 else None)
-                agg, _honest, health = topology_mod.decode_tree_cyclic(
-                    code, enc_re, enc_im, rand_factor, present=present,
-                    rel_tol=rel_tol, impl=decode_impl, lam=wire_lam,
-                    wire=wire, bounds=bounds)
-            elif cfg.decode_granularity == "layer":
-                if leaf_offsets is None:
-                    raise ValueError(
-                        "decode_granularity='layer' needs leaf_offsets from "
-                        "_make_unravel"
-                    )
-                if segments > 1:
-                    # streaming segmented wire (ISSUE 16) at layer
-                    # granularity: the decode partition is the REFINEMENT
-                    # of the leaf boundaries by the quantum-aligned segment
-                    # cuts — every layer still gets (at least) its own
-                    # locator, and the health fold is unchanged (max /
-                    # union over a finer partition)
-                    bounds = segment_decode_bounds(cfg, int(grads.shape[-1]),
-                                                   leaf_offsets)
-                    agg, _honest, health = cyclic_mod.decode_segments(
-                        code, enc_re, enc_im, rand_factor, bounds,
-                        present=present, with_health=True, impl=decode_impl,
-                        rel_tol=rel_tol, lam=wire_lam, wire=wire)
-                else:
-                    agg, _honest, health = cyclic_mod.decode_layers(
-                        code, enc_re, enc_im, rand_factor, leaf_offsets,
-                        present=present, with_health=True, impl=decode_impl,
-                        rel_tol=rel_tol, lam=wire_lam,
-                    )
-            elif segments > 1:
+                # health keys as flat, and honest already folded to (n,)
+                bounds = (numerics_mod.cfg_segment_bounds(cfg, dim)
+                          if cfg.wire_segments > 1 else None)
+                agg, honest, health = topology_mod.decode_tree_cyclic(
+                    code, enc_re, enc_im, rand_factor, wire=wire,
+                    bounds=bounds, **kw)
+            elif cfg.decode_granularity == "layer" and leaf_offsets is None:
+                raise ValueError("decode_granularity='layer' needs "
+                                 "leaf_offsets from _make_unravel")
+            elif cfg.wire_segments > 1:
                 # streaming segmented wire (ISSUE 16): per-segment
-                # syndromes/locators, one folded verdict per step
-                from draco_tpu.obs import numerics as numerics_mod
-
-                bounds = numerics_mod.cfg_segment_bounds(
-                    cfg, int(grads.shape[-1]))
-                agg, _honest, health = cyclic_mod.decode_segments(
+                # syndromes/locators, one folded verdict per step. At layer
+                # granularity the partition is the REFINEMENT of the leaf
+                # boundaries by the quantum-aligned segment cuts — every
+                # layer still gets (at least) its own locator, and the
+                # health fold is unchanged (max / union over a finer
+                # partition)
+                bounds = segment_decode_bounds(
+                    cfg, dim, leaf_offsets
+                    if cfg.decode_granularity == "layer" else None)
+                agg, honest, health = cyclic_mod.decode_segments(
                     code, enc_re, enc_im, rand_factor, bounds,
-                    present=present, with_health=True, impl=decode_impl,
-                    rel_tol=rel_tol, lam=wire_lam, wire=wire)
+                    with_health=True, wire=wire, **kw)
+                honest = jnp.all(honest, axis=0)
+            elif cfg.decode_granularity == "layer":
+                # per-parameter-tensor locator + projection, like the
+                # reference's per-layer decode loop
+                # (cyclic_master.py:125-129)
+                agg, honest, health = cyclic_mod.decode_layers(
+                    code, enc_re, enc_im, rand_factor, leaf_offsets,
+                    with_health=True, **kw)
+                honest = jnp.all(honest, axis=0)
             else:
-                agg, _honest, health = cyclic_mod.decode(
-                    code, enc_re, enc_im, rand_factor, present=present,
-                    with_health=True, impl=decode_impl, rel_tol=rel_tol,
-                    lam=wire_lam, wire=wire)
+                agg, honest, health = cyclic_mod.decode(
+                    code, enc_re, enc_im, rand_factor, with_health=True,
+                    wire=wire, **kw)
+        health["honest"] = honest
         health["bad_rows"] = bad_rows
-
-        if numerics_mod.watch_enabled(cfg):
-            with jax.named_scope("draco_health"):
-                # numerics observatory (obs/numerics.py, ISSUE 10): dynamic-
-                # range columns + the shadow-quantized decode, stashed under
-                # health["watch"] for decode_health_metrics to merge — the f32
-                # decode above alone feeds the update
-                watch = {}
-                if cfg.numerics_watch == "on":
-                    watch.update(numerics_mod.numerics_columns(
-                        cfg, [grads], [enc_re, enc_im], agg))
-                if cfg.shadow_wire != "off":
-                    watch.update(numerics_mod.cyclic_shadow(
-                        cfg, code, enc_re, enc_im, agg, health, rand_factor,
-                        leaf_offsets, present, adv_mask, step))
-                health["watch"] = watch
+        _stash_watch(cfg, health, grads, [enc_re, enc_im], agg,
+                     lambda: numerics_mod.cyclic_shadow(
+                         cfg, code, enc_re, enc_im, agg, health, rand_factor,
+                         leaf_offsets, present, adv_mask, step))
         return agg, health
     if cfg.approach == "maj_vote":
         # ingest-row health on the rows as computed, BEFORE the simulated
@@ -381,21 +349,36 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
         grads = _inject_rows(grads, adv_mask, cfg, step)
     if cfg.approach == "maj_vote":
         # repetition code: the members of a group were fed the same rows
-        # (token_loop.step_tokens) and ran the same program on them, so
-        # honest rows agree bitwise and the vote over the raw rows is exact
-        # (coding/repetition.py; the CNN path's tail is training/step.py).
-        # The fingerprint salt is folded from the replicated step, as there.
-        from draco_tpu import rng as drng
-        from draco_tpu.coding import repetition as rep_mod
-
+        # (the batching layer; token_loop.step_tokens) and ran the same
+        # program on them, so honest rows agree bitwise and the vote over
+        # the raw rows is exact (coding/repetition.py). The per-step
+        # fingerprint salt is folded from the replicated step: identical on
+        # every device and, being seed-derived, NOT secret from a
+        # participant that knows the experiment seed —
+        # cfg.vote_check="exact" is the collision-free option for that
+        # threat model (repetition.py module docstring, tier 3).
+        rep_code = rep_mod.build_repetition_code(cfg.num_workers,
+                                                 cfg.group_size)
         with jax.named_scope("draco_input"):
             vkey = drng.fold(jax.random.key(cfg.seed + 4), step)
+        # the REAL narrow wire (ISSUE 15): this family's wire IS the raw
+        # gradient rows — quantized into narrow buffers (the shared noise
+        # draw keeps within-group rows bitwise identical, the vote's
+        # soundness condition; pinned in tests/test_wire.py), the vote runs
+        # over the widened rows. Identity on the f32 wire.
+        with jax.named_scope("draco_encode"):
+            vote_rows, _wire = numerics_mod.narrow_wire_single(
+                cfg, grads, step=step, constrain=constrain)
         with jax.named_scope("draco_decode"):
             voted, health = rep_mod.majority_vote(
-                rep_mod.build_repetition_code(cfg.num_workers,
-                                              cfg.group_size),
-                grads, present=present, key=vkey, method=cfg.vote_check,
-                with_health=True)
+                rep_code, vote_rows, present=present, key=vkey,
+                method=cfg.vote_check, with_health=True)
+        # (the shadow re-votes over the quantized rows: deterministic
+        # rounding preserves within-group bitwise equality)
+        _stash_watch(cfg, health, grads, [vote_rows], voted,
+                     lambda: numerics_mod.majvote_shadow(
+                         cfg, rep_code, grads, voted, health, vkey, present,
+                         adv_mask, step))
         health["bad_rows"] = bad_rows
         with jax.named_scope("draco_pack"):
             voted = voted.reshape(-1)
@@ -430,17 +413,21 @@ def apply_flat_update(state, agg: jnp.ndarray, opt, unravel):
 
 
 def finish_flat_step(cfg, state, agg, health, opt, unravel, present=None,
-                     constrain=None, constrain_opt=None):
+                     constrain=None, constrain_opt=None, carry=None):
     """The shared flat-gradient step tail: optimizer update → optional
     param/opt-state sharding constraints → advance the carry, with the
     in-graph step guard folded in when ``cfg.step_guard == "on"``
     (resilience/guards.guard_update: untrusted steps keep the previous
-    params/opt_state via branch-free carry passthrough, the step counter
-    still advances). One implementation for every LM route (sp / tp / ep /
-    pp) so the guard semantics cannot diverge between them. Returns
-    ``(new_state, guard_metric_columns)`` — the columns dict is empty when
-    the guard is off, so the metric schema only grows for guarded configs
-    (token_metric_names).
+    state via branch-free carry passthrough, the step counter still
+    advances). One implementation for the CNN path and every LM route (sp /
+    tp / ep / pp) so the guard semantics cannot diverge between them.
+    Returns ``(new_state, guard_metric_columns)`` — the columns dict is
+    empty when the guard is off, so the metric schema only grows for
+    guarded configs (metric_family_names).
+
+    ``carry``: further fields of the state the route advances itself (the
+    CNN's per-worker BatchNorm statistics), replaced before the guard so
+    that its passthrough covers them.
 
     ``constrain_opt``: routes whose carry must hold a GSPMD-stable layout
     (the real tp/ep meshes) pin the new opt state to the input layout here
@@ -455,14 +442,9 @@ def finish_flat_step(cfg, state, agg, health, opt, unravel, present=None,
         new_opt = constrain_opt(new_opt)
     with jax.named_scope("draco_update"):
         new_state = state._replace(params=new_params, opt_state=new_opt,
-                                   step=state.step + 1)
-    if cfg.step_guard != "on":
-        return new_state, {}
-    from draco_tpu.resilience import guards
-
+                                   step=state.step + 1, **(carry or {}))
     with jax.named_scope("draco_health"):
-        return guards.guard_update(cfg, state, new_state, agg, health,
-                                   present)
+        return guard_update(cfg, state, new_state, agg, health, present)
 
 
 # column order of the (K, m) metric block train_token_many returns on the
@@ -472,9 +454,8 @@ def finish_flat_step(cfg, state, agg, health, opt, unravel, present=None,
 # and the host flush can't disagree on the column order
 TOKEN_METRIC_NAMES = ("loss",)
 
-# per-step guard columns (resilience/guards.py): guard_trips = health
-# signals fired, skipped_steps = 1 iff the update was passthrough-skipped
-from draco_tpu.resilience.guards import GUARD_METRIC_NAMES  # noqa: E402
+# (GUARD_METRIC_NAMES, resilience/guards.py: guard_trips = health signals
+# fired, skipped_steps = 1 iff the update was passthrough-skipped)
 
 # per-step decode-health columns (in-graph scalars; coding/cyclic.py):
 #   decode_residual  self-consistency residual, ≈ 0 iff decode exact
@@ -515,17 +496,16 @@ def metric_family_names(cfg) -> tuple:
     cfg.shadow_wire, obs/numerics.py) → guard columns. The baseline
     approach contributes nothing before the guard block — no exactness
     certificate, no accusation set, no coded wire (the PR 4 invariant)."""
-    from draco_tpu.obs import numerics as numerics_mod
-    from draco_tpu.obs.forensics import mask_metric_names
-
+    masks = forensics_mod.mask_metric_names(cfg.num_workers)
     names = ()
     if cfg.approach == "cyclic":
-        names += DECODE_HEALTH_NAMES + mask_metric_names(cfg.num_workers)
+        names += DECODE_HEALTH_NAMES + masks
     elif cfg.approach == "approx":
-        names += APPROX_HEALTH_NAMES + mask_metric_names(cfg.num_workers)
+        names += APPROX_HEALTH_NAMES + masks
     elif cfg.approach == "maj_vote":
-        names += ("vote_agree", "flagged_groups", "det_flagged", "det_tp",
-                  "det_adv") + mask_metric_names(cfg.num_workers)
+        # the vote's flag count ships under the name the cyclic decode's has
+        names += ("vote_agree", "flagged_groups", "located_errors", "det_tp",
+                  "det_adv") + masks
     names += numerics_mod.watch_metric_names(cfg)
     if cfg.step_guard == "on":
         names += GUARD_METRIC_NAMES
@@ -540,12 +520,7 @@ def token_metric_names(cfg, stat_names=()) -> tuple:
     (:func:`metric_family_names`); baseline routes emit only the base
     columns. ``stat_names``: the token model's own per-step counters
     (``models.build_lm``'s surface), which close the row."""
-    names = TOKEN_METRIC_NAMES + tuple(
-        # the token routes ship the vote's flag count under the name the
-        # cyclic decode's has (decode_health_metrics)
-        "located_errors" if cfg.approach == "maj_vote" and n == "det_flagged"
-        else n for n in metric_family_names(cfg))
-    return names + tuple(stat_names)
+    return TOKEN_METRIC_NAMES + metric_family_names(cfg) + tuple(stat_names)
 
 
 def accusation_mask(health, present=None):
@@ -558,8 +533,6 @@ def accusation_mask(health, present=None):
     empty there; a *scheduled* straggler is in particular never accused.
     Present-gated at pack time too (forensics.pack_mask_columns): an absent
     worker is never an accused worker."""
-    import jax.numpy as jnp
-
     accused = None
     for key in ("flagged", "loud", "bad_rows"):
         if key in health:
@@ -576,17 +549,18 @@ def accusation_mask(health, present=None):
 def decode_health_metrics(health, adv_mask, present) -> dict:
     """The DECODE_HEALTH_NAMES columns + the packed per-worker forensics
     masks from a decode-health dict + the step's seeded schedules ({} when
-    the route has no exactness certificate, i.e. health is None). The
-    present-gated counting is the one shared implementation
-    (training/step._detection_metrics — a straggling adversary's row never
-    arrives, so it is neither detectable nor ground truth); only the column
-    name differs: the cyclic flag count ships as ``located_errors``. The
-    scalar detection counts keep their historical meaning (the decode's own
-    flag set, feeding the guard and the P/R fold); the packed ``accused``
-    mask is the wider forensic union (accusation_mask)."""
-    from draco_tpu.obs import forensics as forensics_mod
-    from draco_tpu.training.step import _detection_metrics
+    the route has no exactness certificate, i.e. health is None).
 
+    Detection counts vs the seeded schedules (both of which are step
+    INPUTS, so the comparison runs in-graph — no host traffic):
+    located_errors = flagged ∧ present, det_tp = flagged ∧ adversarial ∧
+    present, det_adv = adversarial ∧ present. A straggling adversary's row
+    never arrives — neither detectable nor ground truth, hence the
+    ``present`` gate on both sides. Flush boundaries fold these into
+    precision/recall (obs/heartbeat.py). The scalar counts keep their
+    historical meaning (the code's own flag set, feeding the guard and the
+    P/R fold); the packed ``accused`` mask is the wider forensic union
+    (accusation_mask)."""
     if health is None:
         return {}
     # numerics-observatory columns (obs/numerics.py, ISSUE 10) stashed by
@@ -607,7 +581,10 @@ def decode_health_metrics(health, adv_mask, present) -> dict:
             accusation_mask(health, present), present, adv_mask))
         out.update(watch)
         return out
-    det = _detection_metrics(health["flagged"], adv_mask, present)
+    pres = (jnp.ones_like(adv_mask, dtype=bool) if present is None
+            else present)
+    adv_live = adv_mask & pres
+    flagged = health["flagged"] & pres
     if "vote_agree" in health:
         # repetition code: the vote's agreement record where the cyclic
         # decode has its residual; the flag count keeps the one name
@@ -616,9 +593,9 @@ def decode_health_metrics(health, adv_mask, present) -> dict:
     else:
         out = {"decode_residual": health["residual"]}
     out.update({
-        "located_errors": det["det_flagged"],
-        "det_tp": det["det_tp"],
-        "det_adv": det["det_adv"],
+        "located_errors": jnp.sum(flagged.astype(jnp.int32)),
+        "det_tp": jnp.sum((flagged & adv_live).astype(jnp.int32)),
+        "det_adv": jnp.sum(adv_live.astype(jnp.int32)),
     })
     out.update(forensics_mod.pack_mask_columns(
         accusation_mask(health, present), present, adv_mask))

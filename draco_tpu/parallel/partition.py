@@ -254,6 +254,14 @@ def override(rules: Sequence[Tuple[str, P]],
     return tuple(overrides) + tuple(r for r in rules if r[0] not in pats)
 
 
+def approx_rules(rules: Sequence[Tuple[str, P]]
+                 ) -> Tuple[Tuple[str, P], ...]:
+    """A route's table under the approx family: config.validate admits no
+    live adversary there, so the mask reaches nothing but the packed
+    forensics columns and the compiler keeps its n bits replicated."""
+    return override(rules, (r"^adv_mask$", REPLICATED))
+
+
 def tree_rows(level_axes: Sequence[str]) -> P:
     """Worker-row spec on a tree-combine mesh: dim 0 folded over the
     REVERSED level axes, so C-order places leaf group j at grid

@@ -57,7 +57,8 @@ from draco_tpu.parallel.common import (
 )
 from draco_tpu.parallel.mesh import SEQ_AXIS
 from draco_tpu.parallel.partition import (
-    REPLICATED, SP_STEP_RULES, WORKER_ROWS, WORKER_ROWS3, sharding,
+    REPLICATED, SP_STEP_RULES, WORKER_ROWS, WORKER_ROWS3, approx_rules,
+    sharding,
 )
 from draco_tpu.parallel.ring_attention import ring_attention
 from draco_tpu.runtime import WORKER_AXIS
@@ -439,8 +440,10 @@ def lint_programs():
         cfg = ci_lm_config(seq_shards=2, **overrides)
         mesh = make_mesh_2d(4, 2)  # 8 CI devices; n=8 folds 2 lanes/device
         setup = build_sp_train_setup(cfg, mesh)
-        return built_token_program(name, cfg, mesh, setup, mf or manifest,
-                                   many=many, partition_rules=SP_STEP_RULES)
+        return built_token_program(
+            name, cfg, mesh, setup, mf or manifest, many=many,
+            partition_rules=(approx_rules(SP_STEP_RULES)
+                             if cfg.approach == "approx" else SP_STEP_RULES))
 
     return [
         LintProgram("lm_sp_ring_step", route="sp",

@@ -27,37 +27,16 @@ XLA inserts the collectives. No tags, no buffers, no races by construction
 
 from __future__ import annotations
 
-import functools
 from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from draco_tpu import aggregation, attacks, optim, rng as drng
+from draco_tpu import optim, rng as drng
 from draco_tpu.config import TrainConfig
-from draco_tpu.coding import cyclic as cyclic_mod
 from draco_tpu.coding import repetition as rep_mod
 from draco_tpu.data import augment as augment_mod
 from draco_tpu.models import build_model, input_shape
-from draco_tpu.obs import forensics as forensics_mod
-from draco_tpu.obs import numerics as numerics_mod
-from draco_tpu.resilience import faults as faults_mod
-from draco_tpu.runtime import WORKER_AXIS
-
-
-def _maybe_guard(cfg, prev_state, new_state, agg, health, present, out):
-    """Fold the in-graph step guard (resilience/guards.py) into a CNN step
-    body's tail: untrusted updates become branch-free carry passthrough and
-    the guard columns land in the metrics dict. Identity when
-    cfg.step_guard is off — the unguarded program is unchanged."""
-    if cfg.step_guard != "on":
-        return new_state
-    from draco_tpu.resilience import guards
-
-    new_state, cols = guards.guard_update(cfg, prev_state, new_state, agg,
-                                          health, present)
-    out.update(cols)
-    return new_state
 
 
 def _metrics(losses, precs, present=None):
@@ -68,24 +47,6 @@ def _metrics(losses, precs, present=None):
     denom = jnp.maximum(jnp.sum(w), 1.0)
     return {"loss": jnp.sum(losses * w) / denom,
             "prec1": jnp.sum(precs * w) / denom}
-
-
-def _detection_metrics(flagged, adv_mask, present):
-    """Per-step detection counts vs the seeded schedules (both of which are
-    step INPUTS, so the comparison runs in-graph — no host traffic): tp =
-    flagged ∧ adversarial ∧ present, adv = adversarial ∧ present. Flush
-    boundaries fold these into precision/recall (obs/heartbeat.py). A
-    straggling adversary's row never arrives — neither detectable nor
-    ground truth, hence the ``present`` gate on both sides."""
-    pres = (jnp.ones_like(adv_mask, dtype=bool) if present is None
-            else present)
-    adv_live = adv_mask & pres
-    flagged = flagged & pres
-    return {
-        "det_flagged": jnp.sum(flagged.astype(jnp.int32)),
-        "det_tp": jnp.sum((flagged & adv_live).astype(jnp.int32)),
-        "det_adv": jnp.sum(adv_live.astype(jnp.int32)),
-    }
 
 
 class TrainState(NamedTuple):
@@ -176,7 +137,11 @@ def build_train_setup(cfg: TrainConfig, mesh,
     opt_state = opt.init(params)
     unravel, dim, leaf_offsets = _make_unravel(params)
 
-    # lazy: parallel/__init__ imports this module
+    # lazy, once: parallel/__init__ imports this module
+    from draco_tpu.parallel.common import (
+        aggregate_flat_grads, build_code_from_cfg, decode_health_metrics,
+        finish_flat_step, metric_family_names,
+    )
     from draco_tpu.parallel.partition import (
         REPLICATED, WORKER_ROWS, WORKER_ROWS3, sharding,
     )
@@ -236,23 +201,6 @@ def build_train_setup(cfg: TrainConfig, mesh,
             flat = jnp.concatenate(leaves)
         return flat, new_stats, loss, prec1
 
-    def apply_update(state: TrainState, flat_grad, new_stats):
-        with jax.named_scope("draco_pack"):
-            grads_tree = unravel(flat_grad)
-        with jax.named_scope("draco_update"):
-            updates, new_opt = opt.update(grads_tree, state.opt_state,
-                                          state.params)
-            new_params = jax.tree.map(lambda p, u: p + u, state.params,
-                                      updates)
-            return TrainState(
-                params=new_params,
-                opt_state=new_opt,
-                batch_stats=new_stats,
-                step=state.step + 1,
-            )
-
-    adv_mag = cfg.adversarial
-
     def prep_rows(state, x, y, ids=None):
         """Augment + dropout keys per *global batch row* k — any worker
         computing batch k sees identical data and rng. The per-batch-row
@@ -273,421 +221,114 @@ def build_train_setup(cfg: TrainConfig, mesh,
             )(jnp.arange(n) if ids is None else ids)
         return x, y, dkeys
 
-    def simulate_faults(grads, state, adv_mask=None):
-        """What a deployment does not pay: the fault plan's in-graph
-        corruption and — where ``adv_mask`` is given — the plain-row
-        adversary, both under ``draco_attack``."""
-        with jax.named_scope("draco_attack"):
-            grads = faults_mod.corrupt_grads(grads, cfg, state.step)
-            if adv_mask is not None:
-                grads = attacks.inject_plain(grads, adv_mask, cfg.err_mode,
-                                             adv_mag,
-                                             n_mal=cfg.num_adversaries,
-                                             step=state.step, seed=cfg.seed)
-        return grads
-
     def stack_rows(grads, spec):
         """Pin the gradient stack's worker sharding (the slab the gather
         moves): layout, so it counts with the pack."""
         with jax.named_scope("draco_pack"):
             return jax.lax.with_sharding_constraint(grads, spec)
 
-    # ---- approach-specific step bodies -----------------------------------
-    if cfg.approach == "baseline":
-        code = None
-        rep_code = None
+    # ---- which lanes run: the only per-approach part of the step ----------
+    # cyclic: CyclicCode flat, or — under topology="tree" (ISSUE 17) — a
+    # TreeCode wrapping the ONE small group code; approx: ApproxCode; one
+    # shared constructor with the LM routes. maj_vote: group members carry
+    # identical batches (the batching layer guarantees it); aug + dropout
+    # keys fold the *group* id so lanes stay bitwise identical within a
+    # group — the vote's soundness condition.
+    code = build_code_from_cfg(cfg)
+    row_ids = None
+    if cfg.approach == "maj_vote":
+        code = rep_mod.build_repetition_code(n, cfg.group_size)
+        row_ids = jnp.asarray(np.arange(n) // cfg.group_size, jnp.int32)
 
-        def step_body(state: TrainState, x, y, adv_mask, present=None):
-            # x, y: (n, B, ...) sharded over w; aug key per (step, worker)
-            x, y, dkeys = prep_rows(state, x, y)
+    if not (cfg.approach == "cyclic" and cfg.redundancy == "simulate"):
+
+        def compute_rows(state, x, y):
+            """Each batch row computed once, one lane a worker: the (n, d)
+            stack (under cyclic ``redundancy="shared"`` the rows are then
+            combined with the masked W — identical semantics, r× less
+            compute, the TPU-native fast path; see config.redundancy)."""
+            x, y, dkeys = prep_rows(state, x, y, row_ids)
             grads, new_stats, losses, precs = jax.vmap(
                 lane, in_axes=(None, 0, 0, 0, 0))(
-                state.params, state.batch_stats, x, y, dkeys
-            )
-            grads = stack_rows(grads, shard_w)
-            grads = simulate_faults(grads, state, adv_mask)
-            with jax.named_scope("draco_decode"):
-                agg = aggregation.aggregate(grads, cfg.mode,
-                                            s=cfg.worker_fail,
-                                            geomedian_iters=(
-                                                cfg.geomedian_iters),
-                                            present=present)
-            new_state = apply_update(state, agg, new_stats)
-            with jax.named_scope("draco_health"):
-                out = _metrics(losses, precs, present)
-                # no exactness certificate on approximate rules: the
-                # guard's only signal here is the global-finite check
-                new_state = _maybe_guard(cfg, state, new_state, agg, None,
-                                         present, out)
-            return new_state, out
+                state.params, state.batch_stats, x, y, dkeys)
+            return stack_rows(grads, shard_w), new_stats, losses, precs
 
-    elif cfg.approach == "maj_vote":
-        code = None
-        rep_code = rep_mod.build_repetition_code(n, cfg.group_size)
-        group_ids = jnp.asarray(np.arange(n) // cfg.group_size, jnp.int32)
+    else:
+        batch_ids = jnp.asarray(code.batch_ids)  # (n, hat_s)
+        hat_s = code.hat_s
 
-        def step_body(state: TrainState, x, y, adv_mask, present=None):
-            # group members carry identical batches (batching layer guarantees
-            # it); aug + dropout keys fold the *group* id so lanes stay
-            # bitwise identical within a group — the vote's soundness condition
-            x, y, dkeys = prep_rows(state, x, y, group_ids)
-            grads, new_stats, losses, precs = jax.vmap(
-                lane, in_axes=(None, 0, 0, 0, 0))(
-                state.params, state.batch_stats, x, y, dkeys
-            )
-            grads = stack_rows(grads, shard_w)
-            grads = simulate_faults(grads, state, adv_mask)
-            # per-step fingerprint salt, identical on every device (folded
-            # from replicated state.step). Being seed-derived it is NOT
-            # secret from a participant that knows the experiment seed —
-            # cfg.vote_check="exact" is the collision-free option for that
-            # threat model (repetition.py module docstring, tier 3).
-            with jax.named_scope("draco_input"):
-                vkey = drng.fold(jax.random.key(cfg.seed + 4), state.step)
-            # the REAL narrow wire (ISSUE 15): this family's wire IS the
-            # raw gradient rows — quantize them into narrow buffers (the
-            # shared noise draw keeps within-group rows bitwise identical,
-            # the vote's soundness condition; pinned in tests/test_wire.py)
-            # and vote over the widened rows. Identity on the f32 wire.
-            vote_rows = grads
-            if cfg.wire_dtype != "f32":
-                with jax.named_scope("draco_encode"):
-                    vote_rows, _wire = numerics_mod.narrow_wire_single(
-                        cfg, grads, step=state.step,
-                        constrain=lambda r: jax.lax.with_sharding_constraint(
-                            r, shard_w))
-            with jax.named_scope("draco_decode"):
-                voted, vhealth = rep_mod.majority_vote(
-                    rep_code, vote_rows, present=present, key=vkey,
-                    method=cfg.vote_check, with_health=True)
-            new_state = apply_update(state, voted, new_stats)
-            with jax.named_scope("draco_health"):
-                out = _metrics(losses, precs, present)
-                # vote health (telemetry columns; coding/repetition.py):
-                # agreement fraction + flagged groups, and the per-row flag set
-                # scored against the seeded schedules — all in-graph
-                out["vote_agree"] = vhealth["vote_agree"]
-                out["flagged_groups"] = vhealth["flagged_groups"]
-                out.update(_detection_metrics(vhealth["flagged"], adv_mask,
-                                              present))
-                # numerics observatory (obs/numerics.py, ISSUE 10): this
-                # family's wire IS the raw gradient rows; the shadow re-votes
-                # over the quantized rows (deterministic rounding preserves
-                # within-group bitwise equality, the vote's soundness
-                # condition)
-                if numerics_mod.watch_enabled(cfg):
-                    if cfg.numerics_watch == "on":
-                        out.update(numerics_mod.numerics_columns(
-                            cfg, [grads], [vote_rows], voted))
-                    if cfg.shadow_wire != "off":
-                        out.update(numerics_mod.majvote_shadow(
-                            cfg, rep_code, grads, voted, vhealth, vkey,
-                            present, adv_mask, state.step))
-                # per-worker forensics columns (obs/forensics): the vote's own
-                # out-voted set ∪ non-finite ingest rows, packed with the
-                # present + seeded-adversary masks to ride the metric block
-                out.update(forensics_mod.pack_mask_columns(
-                    vhealth["flagged"] | forensics_mod.nonfinite_rows(grads),
-                    present, adv_mask))
-                # guard signals: finite vote + out-voted rows (vote
-                # disagreement) within the s budget
-                new_state = _maybe_guard(cfg, state, new_state, voted,
-                                         {"flagged": vhealth["flagged"]},
-                                         present, out)
-            return new_state, out
-
-    elif cfg.approach == "approx":
-        # approximate gradient code (coding/approx.py; ISSUE 8): per-batch
-        # rows computed once (shared redundancy — validate() pins it),
-        # replication-weighted partial sums, optimal-decoding partial
-        # recovery. No adversary injection: validate() rejects live
-        # adversaries (no Byzantine certificate) — the straggler `present`
-        # mask is this family's whole fault surface.
-        from draco_tpu.parallel.common import (approx_aggregate,
-                                               build_code_from_cfg)
-
-        code = build_code_from_cfg(cfg)
-        rep_code = None
-
-        def step_body(state: TrainState, x, y, adv_mask, present=None):
+        def compute_rows(state, x, y):
+            """The reference's true r× redundant compute: worker i really
+            evaluates its hat_s batch rows — the (n, hat_s, d) stack."""
             x, y, dkeys = prep_rows(state, x, y)
-            grads, new_stats, losses, precs = jax.vmap(
-                lane, in_axes=(None, 0, 0, 0, 0)
-            )(state.params, state.batch_stats, x, y, dkeys)
-            grads = stack_rows(grads, shard_w)
-            grads = simulate_faults(grads, state)
-            # the ONE shared encode→mask→decode→forensics sequence
-            # (parallel/common.approx_aggregate — identical semantics with
-            # the LM routes by construction)
-            decoded, health = approx_aggregate(
-                code, grads, present=present,
-                constrain=lambda r: jax.lax.with_sharding_constraint(
-                    r, shard_w),
-                cfg=cfg, adv_mask=adv_mask, step=state.step, mesh=mesh)
-            new_state = apply_update(state, decoded, new_stats)
-            # residual-vs-bound health + packed forensics masks (accused =
-            # non-finite ingest rows only — a scheduled straggler is never
-            # accused); one schema with the LM routes
-            from draco_tpu.parallel.common import decode_health_metrics
-
-            with jax.named_scope("draco_health"):
-                out = _metrics(losses, precs, present)
-                out.update(decode_health_metrics(health, adv_mask, present))
-                # guard signals: finite decode + residual within its
-                # analytic bound (guards.assess's approx branch)
-                new_state = _maybe_guard(cfg, state, new_state, decoded,
-                                         health, present, out)
-            return new_state, out
-
-    elif cfg.approach == "cyclic":
-        # one shared constructor with the LM routes: CyclicCode flat, or —
-        # under topology="tree" (ISSUE 17) — a TreeCode wrapping the ONE
-        # small group code at the (fanout, s_g) shape
-        from draco_tpu.parallel.common import build_code_from_cfg
-
-        code = build_code_from_cfg(cfg)
-        tree = getattr(cfg, "topology", "flat") == "tree"
-        if tree:
-            from draco_tpu.coding import topology as topology_mod
-        rep_code = None
-        if not tree:
-            batch_ids = jnp.asarray(code.batch_ids)  # (n, hat_s)
-            hat_s = code.hat_s
-        # decode lowering (ISSUE 12): resolved ONCE per setup — dispatch
-        # depends only on cfg + the attached backend, so the jitted step
-        # bodies close over a static tag (no retraces)
-        from draco_tpu.ops.decode_kernels import resolve_decode_impl
-
-        decode_impl = resolve_decode_impl(cfg.decode_impl, mesh)
-
-        def ingest_health(grads):
-            """Ingest-row forensics — attribute non-finite rows BEFORE the
-            algebraic encode smears them (forensics.nonfinite_rows) — and
-            the grad-stage numerics columns (obs/numerics.py), computed
-            where the pre-encode rows still exist."""
-            with jax.named_scope("draco_health"):
-                bad_rows = forensics_mod.nonfinite_rows(grads)
-                grad_watch = (numerics_mod.stage_columns(
-                    "grad", [grads], cfg.shadow_block)
-                    if cfg.numerics_watch == "on" else {})
-            return bad_rows, grad_watch
-
-        if cfg.redundancy == "shared":
-
-            def compute_encoded(state, x, y):
-                # each batch row computed once; rows then combined with the
-                # masked W — identical semantics, r× less compute (TPU-native
-                # fast path; see config.redundancy)
-                x, y, dkeys = prep_rows(state, x, y)
-                grads, new_stats, losses, precs = jax.vmap(
-                    lane, in_axes=(None, 0, 0, 0, 0)
-                )(state.params, state.batch_stats, x, y, dkeys)
-                grads = stack_rows(grads, shard_w)
-                grads = simulate_faults(grads, state)
-                bad_rows, grad_watch = ingest_health(grads)
-                with jax.named_scope("draco_encode"):
-                    if tree:
-                        # each leaf group encodes with the shared small
-                        # code; rows stay worker-indexed (n, d)
-                        enc_re, enc_im = topology_mod.encode_tree(code,
-                                                                  grads)
-                    else:
-                        enc_re, enc_im = cyclic_mod.encode_shared(code,
-                                                                  grads)
-                return (enc_re, enc_im, new_stats, losses, precs, bad_rows,
-                        grad_watch)
-
-        else:  # "simulate": the reference's true r× redundant compute
-
-            def compute_encoded(state, x, y):
-                x, y, dkeys = prep_rows(state, x, y)
-                with jax.named_scope("draco_input"):
-                    # worker i gathers its hat_s batch rows:
-                    # (n, hat_s, B, ...)
-                    xw = x[batch_ids]
-                    yw = y[batch_ids]
-                    kw = dkeys[batch_ids]
-                    # worker's BN stats replicated over its hat_s lanes
-                    stats_w = (
-                        jax.tree.map(
-                            lambda t: jnp.broadcast_to(
-                                t[:, None], (n, hat_s) + t.shape[1:]),
-                            state.batch_stats,
-                        )
-                        if has_bn
-                        else None
-                    )
-                def worker_lane(stats_i, x_i, y_i, k_i):
-                    return jax.vmap(lane, in_axes=(None, 0, 0, 0, 0))(
-                        state.params, stats_i, x_i, y_i, k_i
-                    )
-                grads, new_stats, losses, precs = jax.vmap(worker_lane)(
-                    stats_w, xw, yw, kw
-                )  # grads: (n, hat_s, d)
-                grads = stack_rows(grads, sharding(mesh, WORKER_ROWS3))
-                grads = simulate_faults(grads, state)
-                # any non-finite value in worker i's hat_s redundant lanes
-                # attributes to worker i
-                bad_rows, grad_watch = ingest_health(grads)
-                with jax.named_scope("draco_encode"):
-                    enc_re, enc_im = cyclic_mod.encode(code, grads)
-                # fold the per-sub-batch stats back to one per worker (the
-                # BN state's own update)
-                with jax.named_scope("draco_update"):
-                    new_stats = (
-                        jax.tree.map(lambda t: jnp.mean(t, axis=1),
-                                     new_stats)
-                        if has_bn
-                        else None
-                    )
-                with jax.named_scope("draco_health"):
-                    losses, precs = jnp.mean(losses, 1), jnp.mean(precs, 1)
-                return (enc_re, enc_im, new_stats, losses, precs, bad_rows,
-                        grad_watch)
-
-        def step_body(state: TrainState, x, y, adv_mask, present=None):
-            (enc_re, enc_im, new_stats, losses, precs, bad_rows,
-             grad_watch) = compute_encoded(state, x, y)
-            with jax.named_scope("draco_attack"):
-                enc_re, enc_im = attacks.inject_cyclic(
-                    enc_re, enc_im, adv_mask, cfg.err_mode, adv_mag,
-                    step=state.step, seed=cfg.seed)
-            with jax.named_scope("draco_encode"):
-                if present is not None:
-                    # straggler rows never arrive: zero-fill (erasures at known
-                    # positions; decode recovers exactly within the budget —
-                    # config.validate)
-                    pw = present[:, None].astype(enc_re.dtype)
-                    enc_re = enc_re * pw
-                    enc_im = enc_im * pw
-                # the REAL narrow wire (ISSUE 15): the codeword pair is
-                # rounded into narrow bf16/int8 buffers — THE arrays that
-                # cross the worker-sharding boundary (the constraint pins
-                # them, not a widened copy) — and widened to f32 only for
-                # the decode. Identity (no added ops) on the f32 wire.
-                if cfg.wire_dtype != "f32":
-                    enc_re, enc_im, wire = numerics_mod.narrow_wire_pair(
-                        cfg, enc_re, enc_im, step=state.step,
-                        constrain=lambda r: jax.lax.with_sharding_constraint(
-                            r, shard_w))
-                else:
-                    wire = None
-                    enc_re = jax.lax.with_sharding_constraint(enc_re, shard_w)
-                    enc_im = jax.lax.with_sharding_constraint(enc_im, shard_w)
-            # in-graph decode projection — no d-length program constant
-            # (rng.random_projection_factors_in_graph docstring)
             with jax.named_scope("draco_input"):
-                rand_factor = drng.random_projection_factors_in_graph(
-                    cfg.seed, dim)
-            # quantization-aware flag threshold + locator λ for the narrow
-            # wire (obs/numerics.wire_decode_params; f32 keeps the exact
-            # HEALTH_REL_TOL / λ=0 path bitwise)
-            if tree:
-                # per-group decode runs at the GROUP shape: thresholds
-                # come from the (fanout, s_g) table row, not the flat one
-                wire_tol, wire_lam = numerics_mod.wire_decode_params(
-                    cfg, n=code.plan.fanout, s=code.group_code.s)
-            else:
-                wire_tol, wire_lam = numerics_mod.wire_decode_params(cfg)
-            rel_tol = (cyclic_mod.HEALTH_REL_TOL if wire_tol is None
-                       else wire_tol)
-            segments = int(getattr(cfg, "wire_segments", 1))
-            with jax.named_scope("draco_decode"):
-                if tree:
-                    # hierarchical decode (ISSUE 17): per-group small-n
-                    # decode (segmented under the streaming wire), level-
-                    # structured combine, PR 16-style fold — honest comes
-                    # back already folded to (n,)
-                    bounds = (numerics_mod.cfg_segment_bounds(cfg, dim)
-                              if segments > 1 else None)
-                    decoded, honest, health = (
-                        topology_mod.decode_tree_cyclic(
-                            code, enc_re, enc_im, rand_factor,
-                            present=present, rel_tol=rel_tol,
-                            impl=decode_impl, lam=wire_lam, wire=wire,
-                            bounds=bounds))
-                elif cfg.decode_granularity == "layer":
-                    if segments > 1:
-                        # streaming segmented wire (ISSUE 16): the decode
-                        # partition refines the leaf boundaries by the
-                        # quantum-aligned segment cuts; honest/health fold
-                        # across the finer partition exactly as per-layer
-                        from draco_tpu.parallel.common import (
-                            segment_decode_bounds)
+                # worker i gathers its hat_s batch rows:
+                # (n, hat_s, B, ...)
+                xw = x[batch_ids]
+                yw = y[batch_ids]
+                kw = dkeys[batch_ids]
+                # worker's BN stats replicated over its hat_s lanes
+                stats_w = (
+                    jax.tree.map(
+                        lambda t: jnp.broadcast_to(
+                            t[:, None], (n, hat_s) + t.shape[1:]),
+                        state.batch_stats,
+                    )
+                    if has_bn
+                    else None
+                )
 
-                        bounds = segment_decode_bounds(cfg, dim,
-                                                       leaf_offsets)
-                        decoded, honest_l, health = (
-                            cyclic_mod.decode_segments(
-                                code, enc_re, enc_im, rand_factor, bounds,
-                                present=present, with_health=True,
-                                impl=decode_impl, rel_tol=rel_tol,
-                                lam=wire_lam, wire=wire))
-                    else:
-                        # per-parameter-tensor locator + projection, like
-                        # the reference's per-layer decode loop
-                        # (cyclic_master.py:125-129)
-                        decoded, honest_l, health = cyclic_mod.decode_layers(
-                            code, enc_re, enc_im, rand_factor, leaf_offsets,
-                            present=present, with_health=True,
-                            impl=decode_impl, rel_tol=rel_tol, lam=wire_lam,
-                        )
-                    honest = jnp.all(honest_l, axis=0)
-                elif segments > 1:
-                    # streaming segmented wire (ISSUE 16): per-segment
-                    # syndromes + locators, folded to one per-step verdict
-                    # (coding/cyclic.decode_segments docstring)
-                    bounds = numerics_mod.cfg_segment_bounds(cfg, dim)
-                    decoded, honest_l, health = cyclic_mod.decode_segments(
-                        code, enc_re, enc_im, rand_factor, bounds,
-                        present=present, with_health=True, impl=decode_impl,
-                        rel_tol=rel_tol, lam=wire_lam, wire=wire)
-                    honest = jnp.all(honest_l, axis=0)
-                else:
-                    decoded, honest, health = cyclic_mod.decode(
-                        code, enc_re, enc_im, rand_factor, present=present,
-                        with_health=True, impl=decode_impl,
-                        rel_tol=rel_tol, lam=wire_lam, wire=wire)
-            new_state = apply_update(state, decoded, new_stats)
+            def worker_lane(stats_i, x_i, y_i, k_i):
+                return jax.vmap(lane, in_axes=(None, 0, 0, 0, 0))(
+                    state.params, stats_i, x_i, y_i, k_i
+                )
+            grads, new_stats, losses, precs = jax.vmap(worker_lane)(
+                stats_w, xw, yw, kw
+            )  # grads: (n, hat_s, d)
+            grads = stack_rows(grads, sharding(mesh, WORKER_ROWS3))
+            # fold the per-sub-batch stats back to one per worker (the
+            # BN state's own update)
+            with jax.named_scope("draco_update"):
+                new_stats = (
+                    jax.tree.map(lambda t: jnp.mean(t, axis=1), new_stats)
+                    if has_bn
+                    else None
+                )
             with jax.named_scope("draco_health"):
-                out = _metrics(losses, precs, present)
-                out["honest_located"] = jnp.sum(honest.astype(jnp.int32))
-                # decode health (telemetry columns; coding/cyclic._locate_v
-                # docstring): residual ≈ 0 is the paper's exactness guarantee
-                # made observable, the flag set scores against the seeded
-                # schedules — all in-graph, no host traffic. One schema with
-                # the LM routes (common.decode_health_metrics; imported lazily,
-                # parallel/__init__ imports this module). The packed forensics
-                # masks ride along (accused = flagged ∪ loud ∪ bad_rows)
-                from draco_tpu.parallel.common import decode_health_metrics
+                losses, precs = jnp.mean(losses, 1), jnp.mean(precs, 1)
+            return grads, new_stats, losses, precs
 
-                health["bad_rows"] = bad_rows
-                # numerics observatory (obs/numerics.py, ISSUE 10): wire/agg
-                # stages + the shadow-quantized decode join the grad-stage
-                # columns from compute_encoded; decode_health_metrics merges
-                # the stash — the f32 decode above alone feeds the update
-                if numerics_mod.watch_enabled(cfg):
-                    watch = dict(grad_watch)
-                    if cfg.numerics_watch == "on":
-                        watch.update(numerics_mod.stage_columns(
-                            "wire", [enc_re, enc_im], cfg.shadow_block))
-                        watch.update(numerics_mod.stage_columns(
-                            "agg", [decoded], cfg.shadow_block))
-                    if cfg.shadow_wire != "off":
-                        watch.update(numerics_mod.cyclic_shadow(
-                            cfg, code, enc_re, enc_im, decoded, health,
-                            rand_factor, leaf_offsets, present, adv_mask,
-                            state.step))
-                    health["watch"] = watch
-                out.update(decode_health_metrics(health, adv_mask, present))
-                # guard signals: finite decode + loud residual + located rows
-                # beyond the locator budget (the beyond-budget fault class)
-                new_state = _maybe_guard(cfg, state, new_state, decoded,
-                                         health, present, out)
-            return new_state, out
+    def pin_w(rows):
+        """What crosses the wire is pinned to the worker sharding."""
+        return jax.lax.with_sharding_constraint(rows, shard_w)
 
-    else:  # pragma: no cover
-        raise ValueError(cfg.approach)
+    def step_body(state: TrainState, x, y, adv_mask, present=None):
+        # x, y: (n, B, ...) sharded over w
+        grads, new_stats, losses, precs = compute_rows(state, x, y)
+        # in-graph decode projection — no d-length program constant
+        # (rng.random_projection_factors_in_graph docstring)
+        with jax.named_scope("draco_input"):
+            rand_factor = (
+                drng.random_projection_factors_in_graph(cfg.seed, dim)
+                if cfg.approach == "cyclic" else None)
+        # the ONE coded tail, shared with every LM route
+        # (parallel/common.py): faults and attack → encode → wire →
+        # decode / vote / robust rule → health
+        agg, health = aggregate_flat_grads(
+            grads, adv_mask, cfg, code, rand_factor, present=present,
+            leaf_offsets=leaf_offsets, step=state.step, mesh=mesh,
+            constrain=pin_w)
+        new_state, guard_cols = finish_flat_step(
+            cfg, state, agg, health, opt, unravel, present=present,
+            carry={"batch_stats": new_stats})
+        with jax.named_scope("draco_health"):
+            out = _metrics(losses, precs, present)
+            if cfg.approach == "cyclic":
+                out["honest_located"] = jnp.sum(
+                    health["honest"].astype(jnp.int32))
+            out.update(decode_health_metrics(health, adv_mask, present))
+        out.update(guard_cols)
+        return new_state, out
 
     # ---- eval ------------------------------------------------------------
     def eval_body(state: TrainState, x, y, valid):
@@ -727,8 +368,6 @@ def build_train_setup(cfg: TrainConfig, mesh,
     # shared assembly (parallel/common.metric_family_names) so this path
     # and every LM route declare each family exactly once; only the
     # CNN-specific base columns (prec1, cyclic honest_located) live here.
-    from draco_tpu.parallel.common import metric_family_names
-
     metric_names = ("loss", "prec1")
     if cfg.approach == "cyclic":
         metric_names += ("honest_located",)
@@ -759,7 +398,7 @@ def build_train_setup(cfg: TrainConfig, mesh,
         state=state,
         train_step=train_step,
         eval_step=eval_step,
-        code=code if cfg.approach in ("cyclic", "approx") else rep_code,
+        code=code,
         unravel=unravel,
         dim=dim,
         train_many=train_many,
@@ -783,7 +422,7 @@ def lint_programs():
     from draco_tpu.analysis.registry import (
         BF16_DTYPES, DEFAULT_DTYPES, BuiltProgram, LintProgram, Manifest,
     )
-    from draco_tpu.parallel.partition import CNN_STEP_RULES
+    from draco_tpu.parallel.partition import CNN_STEP_RULES, approx_rules
 
     def _cfg(**overrides):
         kw = dict(
@@ -813,20 +452,21 @@ def lint_programs():
                                             else DEFAULT_DTYPES),
                             required_dtypes=frozenset(require))
         extra = {"dim": setup.dim, "devices_in_mesh": int(mesh.devices.size)}
+        rules = (approx_rules(CNN_STEP_RULES) if cfg.approach == "approx"
+                 else CNN_STEP_RULES)
         if many:
             args = (setup.state,
                     jnp.zeros((k, n, b) + shape, jnp.float32),
                     jnp.zeros((k, n, b), jnp.int32),
                     jnp.asarray(np.asarray(adv[1:k + 1])), None)
             return BuiltProgram(name, setup.train_many, args, mesh, manifest,
-                                extra=extra,
-                                partition_rules=CNN_STEP_RULES,
+                                extra=extra, partition_rules=rules,
                                 arg_names=("state", "x", "y", "adv_mask",
                                            "present"))
         args = (setup.state, jnp.zeros((n, b) + shape, jnp.float32),
                 jnp.zeros((n, b), jnp.int32), jnp.asarray(np.asarray(adv[1])))
         return BuiltProgram(name, setup.train_step, args, mesh, manifest,
-                            extra=extra, partition_rules=CNN_STEP_RULES,
+                            extra=extra, partition_rules=rules,
                             arg_names=("state", "x", "y", "adv_mask"))
 
     mk = lambda name, fast=True, **kw: LintProgram(  # noqa: E731

@@ -197,14 +197,16 @@ def _resnet18_step_text(chip) -> str:
                       batch_size=32, lr=0.01, momentum=0.9, max_steps=8,
                       eval_freq=0, train_dir="", decode_impl="auto")
     mesh = Mesh(np.asarray([chip._device_assignment[0]]), ("w",))
-    with mock.patch.object(jax, "device_put", shapes_only), \
-            mock.patch.object(dk, "use_pallas", lambda: True):
-        setup = build_train_setup(cfg, mesh, dataset_name=cfg.dataset)
     rows = NamedSharding(mesh, P("w"))
     x = jax.ShapeDtypeStruct((8, 32, 32, 32, 3), jnp.float32, sharding=rows)
     y = jax.ShapeDtypeStruct((8, 32), jnp.int32, sharding=rows)
-    return setup.train_step.lower(
-        setup.state, x, y, np.zeros((8,), bool)).compile().as_text()
+    # the decode lowering is chosen where the step is traced (the one coded
+    # tail, parallel/common.py): the patch stays on through the lowering
+    with mock.patch.object(jax, "device_put", shapes_only), \
+            mock.patch.object(dk, "use_pallas", lambda: True):
+        setup = build_train_setup(cfg, mesh, dataset_name=cfg.dataset)
+        return setup.train_step.lower(
+            setup.state, x, y, np.zeros((8,), bool)).compile().as_text()
 
 
 def test_resnet18_step_scopes_on_the_described_chip(one_chip, monkeypatch):

@@ -207,7 +207,6 @@ def _assert_decode_health(approach, stream, kw):
     adv = drng.adversary_schedule(428, 6, n, kw.get("adversary_count",
                                                     kw["worker_fail"]))
     strag = drng.straggler_schedule(428, 6, n, kw["straggle_count"])
-    flag_col = {"cyclic": "located_errors", "maj_vote": "det_flagged"}
     for step, vals in stream:
         # guards enabled suite-wide: a clean run (adversary + stragglers
         # inside budget) never trips and never skips an update
@@ -256,7 +255,7 @@ def _assert_decode_health(approach, stream, kw):
         want = int((adv[step] & ~strag[step]).sum())  # detectable truth
         assert vals["det_adv"] == want, (step, vals)
         assert vals["det_tp"] == want  # recall = 1.0
-        assert vals[flag_col[approach]] == want  # precision = 1.0
+        assert vals["located_errors"] == want  # precision = 1.0
         # detection P/R == 1.0 PRESERVED under the bf16 shadow (the ISSUE
         # 10 acceptance pin): the shadow flag set scores identically
         assert vals["shadow_det_flagged"] == want, (step, vals)

@@ -585,9 +585,10 @@ def test_chaos_mini_matrix_cnn_k4(tmp_path):
     # code just re-tests the over_budget locator failure), the adversary
     # episode runs on the dedicated random-attack loops (cnn_rand_*,
     # ISSUE 14), and the drift episode on the autopilot wire-dial loop
-    # (ap_wire_*, ISSUE 15) — every other fault class runs here
+    # (ap_wire_*, ISSUE 15), and a whole leaf group's drop on the tree
+    # loops (tree_*, ISSUE 17) — every other fault class runs here
     assert {r["fault"] for r in data["rows"]} \
-        == set(chaos_run.FAULTS) - {"straggle"} \
+        == set(chaos_run.FAULTS) - {"straggle", "subtree_straggle"} \
         - set(chaos_run.RAND_FAULTS) - set(chaos_run.WIRE_FAULTS)
     outcomes = {r["fault"]: r["outcome"] for r in data["rows"]}
     assert outcomes["nan_grad"] == "guarded"

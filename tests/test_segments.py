@@ -355,7 +355,8 @@ def test_cnn_segmented_equivalence(tmp_path):
 def test_lm_sp_segmented_equivalence(tmp_path):
     """The same fold discipline through the LM single-shard route
     (parallel/common.aggregate_flat_grads — the seam all five LM routes
-    share): S=2 vs S=1 under a live adversary, K=4 scan, strict compile
+    share and, since ISSUE 28, the CNN step too: the CNN cases above and
+    this one run the same tail): S=2 vs S=1 under a live adversary, K=4 scan, strict compile
     sentinel — bounded-err params, identical detection columns and
     forensics masks per record."""
     from draco_tpu.parallel import make_mesh_2d

@@ -324,3 +324,77 @@ def test_same_seed_training_is_bitwise_deterministic(ds, mesh):
         tr.close()
     for a, b in zip(*leaves, strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# one aggregation seam (ISSUE 28): the CNN step has no coded tail of its
+# own — cases of one test, so each still counts
+SEAM_CASES = {
+    "cyclic-simulate": dict(approach="cyclic", worker_fail=1,
+                            err_mode="rev_grad", redundancy="simulate"),
+    "cyclic-shared": dict(approach="cyclic", worker_fail=1,
+                          err_mode="rev_grad", redundancy="shared"),
+    "maj_vote": dict(approach="maj_vote", group_size=4, worker_fail=1,
+                     err_mode="rev_grad"),
+    "baseline-geomedian": dict(approach="baseline", mode="geometric_median",
+                               worker_fail=2, err_mode="rev_grad"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEAM_CASES))
+def test_step_update_is_the_seams_aggregate(case, ds, mesh):
+    """The gradient the CNN step hands its optimizer IS
+    ``parallel/common.aggregate_flat_grads`` of the per-worker stack: the
+    rows are computed here, plainly (LeNet: no BatchNorm, no dropout, no
+    augmentation), the seam is called directly on them, and the step's own
+    aggregate is read back from the momentum buffer, which after a first
+    step from zero holds exactly the gradient the optimizer was given.
+    Trivially true while the step calls the seam; false the day someone
+    gives ``training/step.py`` a tail of its own again."""
+    from draco_tpu import rng as drng
+    from draco_tpu.parallel.common import aggregate_flat_grads
+
+    cfg = make_cfg(**SEAM_CASES[case])
+    tr = Trainer(cfg, mesh=mesh, dataset=ds, quiet=True)
+    setup, params = tr.setup, tr.state.params
+    x, y = tr._device_batch(1)
+    mask = jnp.asarray(tr._adv_schedule[1])
+
+    def row_grad(xk, yk):
+        def loss(p):
+            logp = jax.nn.log_softmax(setup.model.apply({"params": p}, xk))
+            return -jnp.mean(jnp.take_along_axis(logp, yk[:, None], axis=1))
+        return jnp.concatenate(
+            [g.reshape(-1) for g in jax.tree.leaves(jax.grad(loss)(params))])
+
+    stack = jax.vmap(row_grad)(x, y)  # (n, d): one row a batch
+    if cfg.redundancy == "simulate" and cfg.approach == "cyclic":
+        stack = stack[np.asarray(setup.code.batch_ids)]  # (n, hat_s, d)
+    rand_factor = (drng.random_projection_factors_in_graph(cfg.seed,
+                                                           setup.dim)
+                   if cfg.approach == "cyclic" else None)
+    want, health = aggregate_flat_grads(
+        stack, mask, cfg, setup.code, rand_factor, step=tr.state.step,
+        mesh=mesh)
+
+    state, metrics = setup.train_step(tr.state, x, y, mask)
+    shapes = [p.shape for p in jax.tree.leaves(params)]
+    leaves = jax.tree.leaves(state.opt_state)
+    at = next(i for i in range(len(leaves))
+              if [l.shape for l in leaves[i:i + len(shapes)]] == shapes)
+    got = np.concatenate([np.asarray(l).reshape(-1)
+                          for l in leaves[at:at + len(shapes)]])
+    scale = float(np.max(np.abs(np.asarray(want))))
+    np.testing.assert_allclose(got, np.asarray(want), rtol=0,
+                               atol=1e-5 * scale)
+    # and the step's health columns are the seam's health
+    if cfg.approach == "cyclic":
+        assert int(metrics["located_errors"]) == int(
+            np.sum(np.asarray(health["flagged"]))) == cfg.num_adversaries
+        assert int(metrics["honest_located"]) == int(
+            np.sum(np.asarray(health["honest"])))
+    elif cfg.approach == "maj_vote":
+        assert int(metrics["located_errors"]) == int(
+            np.sum(np.asarray(health["flagged"]))) == cfg.num_adversaries
+        assert float(metrics["vote_agree"]) == float(health["vote_agree"])
+    else:
+        assert health is None and "located_errors" not in metrics

@@ -374,13 +374,14 @@ def test_cnn_tree_detection_parity_loop(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# LM route parity: the shared aggregate_flat_grads seam
+# LM route parity: the aggregate_flat_grads seam (the CNN step's too)
 # --------------------------------------------------------------------------
 
 def test_lm_sp_tree_parity(tmp_path):
     """The tree fold through the LM single-shard route
     (parallel/common.aggregate_flat_grads — the seam all five LM routes
-    share): g=4 vs flat at n=8, K=4 scan, strict compile sentinel —
+    share and, since ISSUE 28, the CNN step too: the CNN cases above and
+    this one run the same tail): g=4 vs flat at n=8, K=4 scan, strict compile sentinel —
     params within float noise, and the status wire ledger carries the
     tree block."""
     from draco_tpu.parallel import make_mesh_2d

@@ -141,10 +141,9 @@ def run_cell(family: str, dtype: str, k: int, args, mesh, ds) -> dict:
     shutil.rmtree(d, ignore_errors=True)
 
     exact = family in ("cyclic", "maj_vote")
-    flag_col = {"cyclic": "located_errors", "maj_vote": "det_flagged"}
     tp = sum(r.get("det_tp", 0.0) for r in recs)
     adv = sum(r.get("det_adv", 0.0) for r in recs)
-    flagged = sum(r.get(flag_col.get(family, ""), 0.0) for r in recs)
+    flagged = sum(r.get("located_errors", 0.0) for r in recs)
     stp = sum(r["shadow_det_tp"] for r in recs)
     sflagged = sum(r["shadow_det_flagged"] for r in recs)
     prec, rec = _fold_prec_recall(tp, flagged, adv)
@@ -250,10 +249,9 @@ def run_real_cell(family: str, dtype: str, k: int, args, mesh, ds,
     shutil.rmtree(cfg.train_dir, ignore_errors=True)
 
     exact = family in ("cyclic", "maj_vote")
-    flag_col = {"cyclic": "located_errors", "maj_vote": "det_flagged"}
     tp = sum(r.get("det_tp", 0.0) for r in recs)
     adv = sum(r.get("det_adv", 0.0) for r in recs)
-    flagged = sum(r.get(flag_col.get(family, ""), 0.0) for r in recs)
+    flagged = sum(r.get("located_errors", 0.0) for r in recs)
     prec, rec = _fold_prec_recall(tp, flagged, adv)
     err = float(np.linalg.norm(pv - pv0)
                 / max(np.linalg.norm(pv0), 1e-30))
