@@ -91,9 +91,14 @@ def _row_bits(rows: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.bitcast_convert_type(rows, uint)
 
 
-def _row_fingerprints(rows: jnp.ndarray, key=None):
+def _row_fingerprints(rows: jnp.ndarray, key=None, read=None):
     """(G, r, d) — or (G, r, ...), a row laid out in several axes — -> two
-    (G, r) uint32 mix-then-sum hashes of each row's bits.
+    (G, r) uint32 mix-then-sum hashes of each row's bits and, from the same
+    sweep, (G, r) bool: the row as stored holds a non-finite element.
+
+    ``read``: optional map of a block of the rows, (G, r, block...), as
+    stored to the block the hashes see (:func:`majority_vote`'s
+    ``row_map``): the mapped rows are hashed without ever being stored.
 
     Per position j: keyed avalanche of the element's bits, wrapping-ADD the
     avalanched position, avalanche again, then wrapping-sum over j. The
@@ -142,18 +147,22 @@ def _row_fingerprints(rows: jnp.ndarray, key=None):
     def body(acc, i):
         start = jnp.minimum(i * mb, m - mb)
         lead = (start + jax.lax.iota(jnp.int32, mb)).reshape((mb,) + ones)
-        t1, t2 = terms(jax.lax.dynamic_slice_in_dim(rows, start, mb, axis=2),
+        block = jax.lax.dynamic_slice_in_dim(rows, start, mb, axis=2)
+        # the non-finite elements of the block as stored, counted: a third
+        # wrapping sum of the hashes' own shape, so that the three are one
+        # pass over the block (a row has fewer than 2^32 positions)
+        t0 = (~jnp.isfinite(block)).astype(jnp.uint32)
+        t1, t2 = terms(block if read is None else read(block),
                        (lead * inner + within).astype(jnp.uint32))
         new = lead >= i * mb
-        return (acc[0] + jnp.sum(jnp.where(new, t1, 0), axis=axes,
-                                 dtype=jnp.uint32),
-                acc[1] + jnp.sum(jnp.where(new, t2, 0), axis=axes,
-                                 dtype=jnp.uint32)), None
+        return tuple(a + jnp.sum(jnp.where(new, t, 0), axis=axes,
+                                 dtype=jnp.uint32)
+                     for a, t in zip(acc, (t1, t2, t0))), None
 
     zero = jnp.zeros(rows.shape[:2], jnp.uint32)
-    (h1, h2), _ = jax.lax.scan(body, (zero, zero),
-                               jnp.arange(-(-m // mb), dtype=jnp.int32))
-    return h1, h2
+    (h1, h2, bad), _ = jax.lax.scan(body, (zero, zero, zero),
+                                    jnp.arange(-(-m // mb), dtype=jnp.int32))
+    return h1, h2, bad > 0
 
 
 # positions of a row fingerprinted at a time. A shorter row is one block
@@ -188,7 +197,7 @@ def build_repetition_code(n: int, r: int) -> RepetitionCode:
 def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
                   present=None, key=None,
                   method: str = "fingerprint",
-                  with_health: bool = False):
+                  with_health: bool = False, row_map=None):
     """grads: (n, d) -> (d,) mean over groups of each group's majority row
     ((n, ...) -> (...): a stack whose rows are laid out in several axes, as
     a large one is so that the chip's tiling neither pads n nor makes writing
@@ -209,9 +218,18 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
     experiment seed (module docstring tier 3; reference exact-recovery
     semantics, rep_master.py:162).
 
+    ``row_map``: optional ``(fn, mask)`` — the vote is over the stack in
+    which every row of ``mask`` ((n,) bool) is ``fn(row)``, ``fn``
+    elementwise, and that stack is never stored: ``fn`` is applied to each
+    block as the fingerprint sweep reads it and to the winner's row on its
+    way out. Same bits as voting over ``where(mask, fn(grads), grads)``;
+    the stack is read once (the step's simulated adversary,
+    parallel/common.aggregate_flat_grads).
+
     ``with_health=True`` returns ``(voted, health)`` — the vote's own
     detection record, computed from the agreement matrix the vote already
-    built (telemetry metric columns; no extra O(d) pass):
+    built and the sweep that fingerprinted the rows (telemetry metric
+    columns; no extra O(d) pass):
 
       * ``vote_agree``: fraction of present members whose row bitwise
         matches their group's winner — 1.0 is the all-honest state, each
@@ -221,18 +239,32 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
         groups' minority rows, rep_master.py:154-168);
       * ``flagged``: (n,) bool — present members out-voted by their group
         (the per-row located-adversary set; absent stragglers are
-        known-missing, never "detected").
+        known-missing, never "detected");
+      * ``bad_rows``: (n,) bool — rows of ``grads`` as handed in (before
+        ``row_map``) that hold a non-finite element.
     """
     g, r = code.num_groups, code.r
     rows = grads.reshape((g, r) + grads.shape[1:])
     trail = tuple(range(3, rows.ndim + 1))  # a row's axes, after (G, r, r)
+
+    def mapped(x, which=lambda mask: mask):
+        """``x`` as the vote sees it: ``fn`` on the rows that ``which`` of
+        the (G, r) mask marks (bool over x's leading axes)."""
+        if row_map is None:
+            return x
+        fn, mask = row_map
+        marks = which(mask.reshape(g, r))
+        return jnp.where(marks.reshape(marks.shape + (1,) * (
+            x.ndim - marks.ndim)), fn(x), x)
+
     # pairwise-equality counts, (G, r): agree[g, i] = #{j : row_i == row_j}
     if method == "exact":
-        bits = _row_bits(rows)
+        bits = _row_bits(mapped(rows))
         eq = jnp.all(bits[:, :, None] == bits[:, None, :], axis=trail)
+        bad = ~jnp.all(jnp.isfinite(rows), axis=tuple(range(2, rows.ndim)))
     elif method == "fingerprint":
         # 64-bit row fingerprints (O(r·d)) — see module docstring
-        h1, h2 = _row_fingerprints(rows, key=key)
+        h1, h2, bad = _row_fingerprints(rows, key=key, read=mapped)
         eq = ((h1[:, :, None] == h1[:, None, :])
               & (h2[:, :, None] == h2[:, None, :]))
     else:
@@ -242,20 +274,28 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
     if present is None:
         pres = jnp.ones((g, r), bool)
         agree = jnp.sum(eq, axis=-1)
-        winner = jnp.argmax(agree, axis=-1)  # (G,)
-        picked = jnp.take_along_axis(
-            rows, winner.reshape((g,) + (1,) * (rows.ndim - 1)), axis=1)[:, 0]
-        voted = jnp.mean(picked, axis=0)
     else:
         pres = present.reshape(g, r)
         agree = jnp.sum(eq & pres[:, None, :], axis=-1)  # only present members vote
         agree = jnp.where(pres, agree, -1)  # absent members cannot win
-        winner = jnp.argmax(agree, axis=-1)
-        picked = jnp.take_along_axis(
-            rows, winner.reshape((g,) + (1,) * (rows.ndim - 1)), axis=1)[:, 0]
-        group_alive = jnp.any(pres, axis=1).astype(grads.dtype)  # (G,)
-        voted = (jnp.tensordot(group_alive, picked, axes=1)
-                 / jnp.maximum(jnp.sum(group_alive), 1.0))
+    winner = jnp.argmax(agree, axis=-1)  # (G,)
+    if g == 1 and present is None:
+        # the one group's row where it lies: a slice, no gather's select,
+        # and the mean over one group is the row
+        voted = mapped(jax.lax.dynamic_index_in_dim(
+            rows[0], winner[0], axis=0, keepdims=False),
+            lambda mask: mask[0, winner[0]])
+    else:
+        picked = mapped(jnp.take_along_axis(
+            rows, winner.reshape((g,) + (1,) * (rows.ndim - 1)), axis=1)[:, 0],
+            lambda mask: jnp.take_along_axis(
+                mask, winner[:, None], axis=1)[:, 0])
+        if present is None:
+            voted = jnp.mean(picked, axis=0)
+        else:
+            group_alive = jnp.any(pres, axis=1).astype(grads.dtype)  # (G,)
+            voted = (jnp.tensordot(group_alive, picked, axes=1)
+                     / jnp.maximum(jnp.sum(group_alive), 1.0))
     if not with_health:
         return voted
     # member i agrees with its group's winner iff eq[g, i, winner_g]
@@ -269,5 +309,6 @@ def majority_vote(code: RepetitionCode, grads: jnp.ndarray,
         "flagged_groups": jnp.sum(jnp.any(flagged, axis=1)
                                   .astype(jnp.int32)),
         "flagged": flagged.reshape(code.n),
+        "bad_rows": bad.reshape(code.n),
     }
     return voted, health

@@ -145,14 +145,14 @@ def approx_aggregate(code, grads: jnp.ndarray, cfg, adv_mask, present=None,
 
 
 def _inject_rows(grads, adv_mask, cfg, step):
-    """``attacks.inject_plain`` on the raw rows of the (n, ...) stack. A
-    large stack (its rows laid out in tiles, sp_step.STACK_TILE) is
-    attacked one row at a time through a dynamic-update-slice, which XLA
-    performs in place: as ONE elementwise pass the injection fused with its
-    readers into an op of several results that could not reuse the stack's
-    buffer, and a second stack does not fit beside the first. Row-local
-    attacks only (rev_grad / constant / random). Same values either way:
-    the layout decides, and only the vote's large stack has it
+    """``attacks.inject_plain`` on the raw rows of the (n, ...) stack,
+    stored: what the robust rules aggregate, and what the vote reads where
+    it cannot apply the attack as it reads (:func:`_vote_row_map`). A large
+    stack (its rows laid out in tiles, sp_step.STACK_LANES) is attacked one
+    row at a time through a dynamic-update-slice, which XLA performs in
+    place: a second stack does not fit beside the first. Row-local attacks
+    only (rev_grad / constant / random). Same values either way: the layout
+    decides, and only the vote's large stack has it
     (tests/test_lm_maj_vote.py holds both sides to the same bits)."""
     kw = dict(n_mal=cfg.num_adversaries, step=step, seed=cfg.seed)
     if grads.ndim == 2:
@@ -175,6 +175,23 @@ def _inject_rows(grads, adv_mask, cfg, step):
     return jax.lax.fori_loop(0, grads.shape[0], body, grads)
 
 
+def _vote_row_map(cfg, adv_mask):
+    """The simulated adversary as ``majority_vote``'s ``row_map`` — applied
+    to each block of the stack as the vote's one sweep reads it and to the
+    winner's row, never stored — or None where the attacked stack has to
+    exist: an attack that is not an elementwise map of its own row (a keyed
+    ``random`` row; ``alie`` / ``ipm`` read every row), or a further reader
+    of the attacked rows (the narrow wire quantises them, the numerics
+    watch and the shadow vote measure them, ``vote_check="exact"`` compares
+    them whole). Same bits either way (tests/test_lm_maj_vote.py)."""
+    if (cfg.err_mode not in ("rev_grad", "constant")
+            or cfg.vote_check != "fingerprint" or cfg.wire_dtype != "f32"
+            or numerics_mod.watch_enabled(cfg)):
+        return None
+    return (lambda rows: attacks.attack_plain(rows, cfg.err_mode,
+                                              cfg.adversarial), adv_mask)
+
+
 def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                          present=None, leaf_offsets=None, step=None,
                          mesh=None, constrain=None):
@@ -182,7 +199,9 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
     health)``: the one coded tail of every step builder. Two further
     layouts: (n, hat_s, d), each worker's own redundant lanes
     (``redundancy="simulate"``), and under maj_vote a large stack with its
-    rows laid out in tiles, (n, d / 1024, 8, 128): sp_step.STACK_TILE.
+    rows laid out in tiles, (n, d / 128, 128): sp_step.STACK_LANES — the
+    winner then comes back as such a row, (d / 128, 128), which ``unravel``
+    (training/step._make_unravel) cuts into leaves where it lies.
 
     ``step`` (optional traced scalar): the training step, threaded so the
     deterministic fault plan (``cfg.fault_spec``,
@@ -196,8 +215,10 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
     decode is self-consistent, (n,) bool ``flagged`` of located-error
     rows), plus ``honest`` (the decode's honest mask, (n,)) and
     ``bad_rows`` (non-finite ingest rows). maj_vote: injection on the raw
-    rows, the wire, the vote — ``health`` is the vote's (``vote_agree``,
-    ``flagged_groups``, ``flagged``) and ``bad_rows``. approx:
+    rows, the wire, the vote — in ONE sweep of the stack where nothing else
+    reads the attacked rows (:func:`_vote_row_map`) — ``health`` is the
+    vote's (``vote_agree``, ``flagged_groups``, ``flagged``, and
+    ``bad_rows`` of the rows as computed). approx:
     :func:`approx_aggregate`. Otherwise: injection on the raw rows, then
     the configured robust aggregation (mean / geo-median / krum) —
     approximate rules carry no exactness certificate, so ``health`` is
@@ -339,15 +360,6 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                          leaf_offsets, present, adv_mask, step))
         return agg, health
     if cfg.approach == "maj_vote":
-        # ingest-row health on the rows as computed, BEFORE the simulated
-        # attack rewrites the stack (as the cyclic branch reads it before
-        # the encode)
-        with jax.named_scope("draco_health"):
-            bad_rows = ~jnp.all(jnp.isfinite(grads),
-                                axis=tuple(range(1, grads.ndim)))
-    with jax.named_scope("draco_attack"):
-        grads = _inject_rows(grads, adv_mask, cfg, step)
-    if cfg.approach == "maj_vote":
         # repetition code: the members of a group were fed the same rows
         # (the batching layer; token_loop.step_tokens) and ran the same
         # program on them, so honest rows agree bitwise and the vote over
@@ -361,6 +373,19 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
                                                  cfg.group_size)
         with jax.named_scope("draco_input"):
             vkey = drng.fold(jax.random.key(cfg.seed + 4), step)
+        # ONE sweep of the stack (under draco_decode): the vote's
+        # fingerprint scan also reads the ingest-row health off the rows
+        # as computed and applies the simulated attack to each block it
+        # hashes, so the attacked stack is never stored. Where it has to
+        # be (_vote_row_map), it is, and the rows as computed are checked
+        # before the attack rewrites them.
+        row_map = _vote_row_map(cfg, adv_mask)
+        if row_map is None:
+            with jax.named_scope("draco_health"):
+                bad_rows = ~jnp.all(jnp.isfinite(grads),
+                                    axis=tuple(range(1, grads.ndim)))
+            with jax.named_scope("draco_attack"):
+                grads = _inject_rows(grads, adv_mask, cfg, step)
         # the REAL narrow wire (ISSUE 15): this family's wire IS the raw
         # gradient rows — quantized into narrow buffers (the shared noise
         # draw keeps within-group rows bitwise identical, the vote's
@@ -369,20 +394,24 @@ def aggregate_flat_grads(grads: jnp.ndarray, adv_mask, cfg, code, rand_factor,
         with jax.named_scope("draco_encode"):
             vote_rows, _wire = numerics_mod.narrow_wire_single(
                 cfg, grads, step=step, constrain=constrain)
+        # the winner's row comes back as the stack's rows are laid out:
+        # the leaves are cut from it where it lies (training/step.py
+        # _make_unravel)
         with jax.named_scope("draco_decode"):
             voted, health = rep_mod.majority_vote(
                 rep_code, vote_rows, present=present, key=vkey,
-                method=cfg.vote_check, with_health=True)
+                method=cfg.vote_check, with_health=True, row_map=row_map)
+        if row_map is None:
+            health["bad_rows"] = bad_rows
         # (the shadow re-votes over the quantized rows: deterministic
         # rounding preserves within-group bitwise equality)
         _stash_watch(cfg, health, grads, [vote_rows], voted,
                      lambda: numerics_mod.majvote_shadow(
                          cfg, rep_code, grads, voted, health, vkey, present,
                          adv_mask, step))
-        health["bad_rows"] = bad_rows
-        with jax.named_scope("draco_pack"):
-            voted = voted.reshape(-1)
         return voted, health
+    with jax.named_scope("draco_attack"):
+        grads = _inject_rows(grads, adv_mask, cfg, step)
     with jax.named_scope("draco_decode"):
         agg = aggregation.aggregate(
             grads, cfg.mode, s=cfg.worker_fail,

@@ -24,8 +24,12 @@ attention, routed and shared experts) at ``seq_shards == 1``. Where the
 (lanes, d) stack of per-lane gradients computed side by side would not fit
 beside the rest of the step (``LANES_IN_TURN_BYTES``), the lanes are
 evaluated in turn (``lax.map``), each layer is rematerialised in the
-backward pass and the vote's stack is kept in tiles (``STACK_TILE``): ONE
-size test, and the shape decides, no option does.
+backward pass and the vote's stack is kept in the chip's tiles
+(``STACK_LANES``): ONE size test, and the shape decides, no option does.
+The vote then reads that stack once — finite check, simulated attack and
+fingerprints in one sweep, nothing stack-sized stored — and the winner's row
+is copied once, the leaves cut from it where it lies
+(parallel/common.aggregate_flat_grads, coding/repetition.majority_vote).
 """
 
 from __future__ import annotations
@@ -74,20 +78,23 @@ from draco_tpu.training.step import TrainState, _flatten_tree, _make_unravel
 # tests/test_lm_maj_vote.py::test_lanes_in_turn_train_the_same_as_side_by_side;
 # every other LM the tests build is on the small side.
 LANES_IN_TURN_BYTES = 2**30
-# Such a stack is kept (lanes, ceil(d / 1024), 8, 128) where the vote reads
-# it (coding/repetition.py takes rows of several axes; the tail then attacks
-# it a row at a time, common._inject_rows): one lane's row as the chip's own
-# (8, 128) tiles in order, which is the flat row's bytes as they lie, so the
-# reshape moves nothing, no lane is padded and a lane's row is one
-# contiguous block (a d that does not fill its last tile is closed with
-# zeros). Measured at d = 425 M on the chip (PERF.md section
-# 6): as (lanes, d) the tiling is (4, 128) — three lanes padded to four,
-# 6.3 GB for 4.75, and writing ONE lane's row rewrites every tile of the
-# stack, 36 ms a lane and again for each row the attack touches; as
+# Such a stack is kept (lanes, d / 128, 128), d closed to a whole (8, 128)
+# tile with zeros, where the vote reads it (coding/repetition.py takes rows
+# of several axes and hashes them a block at a time): one lane's row as the
+# chip's own (8, 128) tiles in order, which is the flat row's bytes as they
+# lie, so the reshape moves nothing, no lane is padded and a lane's row is
+# one contiguous block — and a leaf of the model is a range of the row's
+# lines (every offset and size of a published width is a multiple of 128),
+# so the winner's row is cut into leaves where it lies
+# (training/step._make_unravel). Measured at d = 425 M on the chip (PERF.md
+# section 6; there as (lanes, d / 1024, 8, 128), the same bytes): as
+# (lanes, d) the tiling is (4, 128) — three lanes padded to four, 6.3 GB
+# for 4.75, and writing ONE lane's row rewrites every tile of the stack,
+# 36 ms a lane and again for each row an attack touches; as
 # (lanes, 8, d / 8) building a lane's row is eight such rewrites, 49 ms; as
 # (lanes, 1, d) the stack is linear but every pass over it uses one
 # sublane of eight (the fingerprints 80 ms for 10).
-STACK_TILE = (8, 128)
+STACK_LANES = 128
 
 
 class SPTrainSetup(NamedTuple):
@@ -214,9 +221,9 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
     lanes = n // mesh.shape[WORKER_AXIS]
     lanes_in_turn = 4 * lanes * dim > LANES_IN_TURN_BYTES
     tiled_stack = lanes_in_turn and cfg.approach == "maj_vote"
-    # zeros that close a row's last tile (none at the cell's d = 415 001
-    # tiles); every lane writes the same, so the vote is unmoved
-    tile_pad = -dim % int(np.prod(STACK_TILE)) if tiled_stack else 0
+    # zeros that close a row's last (8, 128) tile (none at the cell's
+    # d = 415 001 tiles); every lane writes the same, so the vote is unmoved
+    tile_pad = -dim % (8 * STACK_LANES) if tiled_stack else 0
     if lanes_in_turn and not cfg.remat:
         model = build_lm(dataclasses.replace(cfg, remat=True), attn,
                          kernel_fn=flash)
@@ -278,7 +285,7 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
             if tiled_stack:
                 if tile_pad:
                     g = jnp.pad(g, (0, tile_pad))
-                g = g.reshape((-1,) + STACK_TILE)
+                g = g.reshape(-1, STACK_LANES)
             return g, loss, stats
 
         g, loss, stats = over_lanes(one_lane, tokens)
@@ -358,9 +365,6 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
                                            rand_factor, present=present,
                                            leaf_offsets=leaf_offsets,
                                            step=state.step, mesh=mesh)
-        if tile_pad:
-            with jax.named_scope("draco_pack"):
-                agg = agg[:dim]
         new_state, guard_cols = finish_flat_step(cfg, state, agg, health,
                                                  opt, unravel,
                                                  present=present)

@@ -91,17 +91,33 @@ def _make_unravel(params):
     """Returns (unravel, dim, offsets) — offsets are the per-leaf segment
     boundaries in the flat vector, the "layers" of layer-granularity decode
     (the reference decodes each parameter tensor separately,
-    cyclic_master.py:125-129)."""
+    cyclic_master.py:125-129).
+
+    ``unravel`` takes the flat (d,) vector, or the same positions counted
+    row-major in a row of several axes — the vote's winner as a large stack
+    lays it out, (d / 128, 128) with zeros closing the last tile
+    (parallel/sp_step.STACK_LANES). A leaf that starts and ends on a whole
+    (last-axis) line of such a row is cut as a range of lines, from the row
+    where it lies: flattening it first costs the chip a copy of the whole
+    row in a second layout."""
     leaves, treedef = jax.tree.flatten(params)
     shapes = [l.shape for l in leaves]
     sizes = [int(np.prod(s)) for s in shapes]
     offsets = np.cumsum([0] + sizes)
 
     def unravel(flat):
-        parts = [
-            jnp.reshape(flat[offsets[i] : offsets[i + 1]], shapes[i])
-            for i in range(len(shapes))
-        ]
+        line, lines = 1, flat
+        if flat.ndim > 1:
+            line = flat.shape[-1]
+            lines = flat.reshape((-1, line))
+        parts = []
+        for i, shape in enumerate(shapes):
+            lo, hi = int(offsets[i]), int(offsets[i + 1])
+            if lo % line or hi % line:
+                part = flat.reshape(-1)[lo:hi]
+            else:
+                part = lines[lo // line : hi // line]
+            parts.append(jnp.reshape(part, shape))
         return jax.tree.unflatten(treedef, parts)
 
     return unravel, int(offsets[-1]), offsets

@@ -277,3 +277,89 @@ def test_vgg11_lanes_pool_as_fusions(one_chip):
         r" = \w+\[9,5,8,(\d+),(\d+),\d+\]\S* select-and-scatter\(", text)
     assert sorted(pooled) == [("1", "1"), ("2", "2")], pooled
     assert sorted(scattered) == [("2", "2"), ("4", "4")], scattered
+
+
+def test_the_vote_reads_its_stack_once_and_copies_the_winner_once(one_chip):
+    """ISSUE 29: the vote's tail — stack in; one sweep (finite check,
+    simulated attack, fingerprints), the winner's row, an unravel of three
+    leaves (the middle one (128,), so the third starts off a tile boundary)
+    and an SGD-momentum update out — at a tiled stack of 11 009 tiles a
+    lane, once the chip's compiler is done with it: ONE loop carries the
+    stack and ONE fusion in it reads it (three ``u32[3]`` sums), nothing
+    runs under ``draco_attack``, one more fusion reads the stack (the
+    winner's copy),
+    and at most two results of a row's size stand between stack and update.
+    At the parent: three loops and an ``is-finite`` pass over the stack,
+    four row-sized results (PERF.md section 6, PR 29)."""
+    import math
+    import re
+
+    from draco_tpu import optim
+    from draco_tpu.obs import device_attr as da
+    from tests.test_step_scopes import _executed_lines
+
+    from draco_tpu.config import TrainConfig
+    from draco_tpu.parallel.common import (aggregate_flat_grads,
+                                           finish_flat_step)
+    from draco_tpu.training.step import TrainState, _make_unravel
+
+    cfg = TrainConfig(network="TransformerLM", dataset="synthetic-text",
+                      approach="maj_vote", num_workers=3, group_size=3,
+                      worker_fail=1, err_mode="rev_grad", lr=0.01,
+                      momentum=0.9, batch_size=1, seq_len=32, vocab=64,
+                      eval_freq=0, train_dir="").validate()
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"a": arg((4096, 2048)), "b": arg((128,)),
+              "c": arg((2048, 1408))}
+    opt = optim.build_optimizer_from_cfg(cfg)
+    unravel, dim, offsets = _make_unravel(params)
+    assert offsets[2] % 1024 == 128  # "c" starts inside a tile
+    lines = -(-dim // 1024) * 8
+    state = TrainState(
+        params=params, batch_stats=None, step=arg((), jnp.int32),
+        opt_state=jax.tree.map(lambda a: arg(a.shape, a.dtype),
+                               jax.eval_shape(opt.init, params)))
+
+    def tail(state, stack, adv_mask):
+        agg, health = aggregate_flat_grads(stack, adv_mask, cfg, None, None,
+                                           step=state.step)
+        new_state, _ = finish_flat_step(cfg, state, agg, health, opt,
+                                        unravel)
+        return new_state, health["flagged"], health["bad_rows"]
+
+    text = jax.jit(tail, donate_argnums=(0,)).lower(
+        state, arg((3, lines, 128)), arg((3,), jnp.bool_)).compile().as_text()
+    assert "draco_attack" not in text
+    row = lines * 128  # elements; the stack holds three, under any view
+
+    def f32_sizes(result):
+        return [math.prod(int(x) for x in dims.split(","))
+                for dims in re.findall(r"f32\[([\d,]+)\]", result)]
+
+    # (name, result type, op, operands) of what runs as an op of its own:
+    # the entry computation and the loops' bodies, not the fusions' insides
+    ins = []
+    for line in _executed_lines(text):
+        m = da._HLO_LINE_RE.match(line)
+        if m:
+            result, call = line.split("=", 1)[1].split(f" {m.group(2)}(", 1)
+            ins.append((m.group(1), result, m.group(2), re.findall(
+                r"%([\w.\-]+)", call.split("metadata=")[0])))
+    moves = ("parameter", "tuple", "get-tuple-element", "bitcast")
+    holds_stack = {name for name, result, _, _ in ins
+                   if 3 * row in f32_sizes(result)}
+    loops = [i for i in ins if i[2] == "while" and i[0] in holds_stack]
+    assert len(loops) == 1, loops
+    readers = [i for i in ins if i[2] not in moves + ("while",)
+               and holds_stack & set(i[3])]
+    # the sweep's three sums in the loop, the winner's copy after it
+    assert [i[2] for i in readers] == ["fusion", "fusion"], readers
+    assert sorted(f32_sizes(i[1]) for i in readers) == [[], [row]], readers
+    assert readers[0][1].count("u32[3]") + readers[1][1].count("u32[3]") == 3
+    row_sized = [i for i in ins if i[2] not in moves
+                 and not i[1].lstrip().startswith("(")
+                 and f32_sizes(i[1]) == [row]]
+    assert 1 <= len(row_sized) <= 2, row_sized

@@ -146,8 +146,9 @@ def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     """The large side of ``sp_step.LANES_IN_TURN_BYTES`` at the tiny size:
     lanes in turn (``lax.map``), each layer rematerialised, the stack in
     tiles (a d that is no multiple of 1024, so the last tile is closed with
-    zeros), the attack a row at a time, the fingerprints a block at a time
-    — what the d = 425 M cell runs. Same verdicts, same training."""
+    zeros), attack, finite check and fingerprints in one sweep a block at a
+    time, the leaves cut from the winner's row (here off whole lines: the
+    flat cut) — what the d = 425 M cell runs. Same verdicts, same training."""
     from draco_tpu.coding import repetition
     from draco_tpu.parallel import sp_step
 
@@ -166,11 +167,13 @@ def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
         assert r2["loss"] == pytest.approx(r1["loss"], rel=1e-5)
 
 
-@pytest.mark.parametrize("layout", [(1, -1), (8, -1), (-1, 8, 128)])
+@pytest.mark.parametrize("layout", [(1, -1), (8, -1), (-1, 8, 128),
+                                    (-1, 128)])
 def test_a_stack_with_rows_of_several_axes_votes_the_same(layout):
-    """A large stack is kept (n, d / 1024, 8, 128) (sp_step.STACK_TILE): the
-    tail attacks it a row at a time and the vote hashes it a block at a
-    time; same bits, same verdict as the (n, d) stack."""
+    """A large stack is kept (n, d / 128, 128) (sp_step.STACK_LANES): the
+    vote hashes it a block at a time, the attack applied to each block as
+    read; same bits, same verdict as the (n, d) stack, and the winner comes
+    back as the stack's rows are laid out."""
     import jax.numpy as jnp
 
     from draco_tpu.coding import repetition
@@ -190,8 +193,8 @@ def test_a_stack_with_rows_of_several_axes_votes_the_same(layout):
                                        cfg, None, None, step=step)
     finally:
         repetition.FINGERPRINT_BLOCK = old
-    assert got.shape == (d,)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert got.shape == flat[0].reshape(layout).shape
+    assert np.array_equal(np.asarray(got).reshape(-1), np.asarray(want))
     assert np.array_equal(np.asarray(want), np.asarray(one.mean(axis=0)))
     for key in ("flagged", "bad_rows"):
         assert np.array_equal(np.asarray(hg[key]), np.asarray(hw[key]))
@@ -212,3 +215,125 @@ def test_cli_trains_the_new_network_coded_and_attacked(tmp_path):
         "--max-steps", "8", "--eval-freq", "0", "--train-dir", "", "--lr",
         "0.05", "--log-every", "1"])
     assert float(last["det_tp"]) == 1.0 and float(last["loss"]) < 4.2
+
+
+def _vote_both_ways(raw, mask, err_mode, groups, present, key,
+                    method="fingerprint"):
+    """The vote over ``raw`` with the adversary's rows attacked — lazily
+    (``row_map``: the stack is read once, the attacked stack never stored)
+    and over today's materialised stack (``attacks.inject_plain``) — and
+    the fingerprint words and finite flags each side's sweep read."""
+    import jax.numpy as jnp
+
+    from draco_tpu import attacks
+    from draco_tpu.coding import repetition
+
+    code = repetition.build_repetition_code(raw.shape[0], raw.shape[0] // groups)
+    lead = (slice(None),) + (None,) * (raw.ndim - 1)
+    row_map = (lambda x: attacks.attack_plain(x, err_mode), mask)
+    stored = jnp.where(mask[lead], attacks.attack_plain(raw, err_mode), raw)
+    rows = (groups, code.r) + raw.shape[1:]
+    lazy = repetition.majority_vote(code, raw, present=present, key=key,
+                                    method=method, with_health=True,
+                                    row_map=row_map)
+    eager = repetition.majority_vote(code, stored, present=present, key=key,
+                                     method=method, with_health=True)
+    words_lazy = repetition._row_fingerprints(
+        raw.reshape(rows), key, read=lambda b: jnp.where(
+            mask.reshape(rows[:2] + (1,) * (raw.ndim - 1)),
+            attacks.attack_plain(b, err_mode), b))
+    words_eager = repetition._row_fingerprints(stored.reshape(rows), key)
+    return lazy, eager, words_lazy, words_eager
+
+
+@pytest.mark.parametrize("with_present", [False, True])
+@pytest.mark.parametrize("groups", [1, 3])
+@pytest.mark.parametrize("err_mode", ["rev_grad", "constant"])
+@pytest.mark.parametrize("layout", [(-1,), (-1, 8, 128), (-1, 128)])
+def test_the_lazy_sweep_votes_as_the_stored_attack_bit_for_bit(
+        layout, err_mode, groups, with_present, monkeypatch):
+    """ISSUE 29: attack, finite check and fingerprints in ONE sweep of the
+    stack against the attacked stack stored first — same ``voted``, same
+    health, same two fingerprint words — over the (n, d) stack and the
+    tiled ones (several blocks, the last clamped back), one group and
+    several, with and without stragglers."""
+    import jax.numpy as jnp
+
+    from draco_tpu.coding import repetition
+
+    monkeypatch.setattr(repetition, "FINGERPRINT_BLOCK", 3 * 1024)
+    r, d = 3, 10 * 1024  # 10 tiles a row: blocks of 3, 3, 3 and a clamped 1
+    n = r * groups
+    one = jax.random.normal(jax.random.key(29), (groups, d))
+    raw = jnp.repeat(one, r, axis=0).reshape((n,) + layout)
+    mask = jnp.zeros((n,), bool).at[jnp.arange(groups) * r + 1].set(True)
+    # a straggler in the last group, an honest one: its adversary ties the
+    # group's one present honest member, and the lower index wins
+    present = jnp.ones((n,), bool).at[n - 1].set(False) if with_present \
+        else None
+    (voted, hl), (want, he), wl, we = _vote_both_ways(
+        raw, mask, err_mode, groups, present, jax.random.key(7))
+    assert voted.shape == raw.shape[1:]
+    assert np.array_equal(np.asarray(voted), np.asarray(want))
+    for k in ("vote_agree", "flagged", "flagged_groups"):
+        assert np.array_equal(np.asarray(hl[k]), np.asarray(he[k])), k
+    # rows as computed on the lazy side; the stored attack wrote finite rows
+    assert not np.asarray(hl["bad_rows"]).any()
+    assert not np.asarray(he["bad_rows"]).any()
+    for a, b in zip(wl[:2], we[:2]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    if present is None:
+        assert np.array_equal(np.asarray(hl["flagged"]), np.asarray(mask))
+        assert np.array_equal(np.asarray(voted).reshape(-1),
+                              np.asarray(one.mean(axis=0)))
+
+
+@pytest.mark.parametrize("method", ["fingerprint", "exact"])
+@pytest.mark.parametrize("layout", [(-1,), (-1, 128)])
+def test_an_adversarial_majority_wins_with_its_attacked_row(layout, method):
+    """Two of three lanes attacked: they agree with each other, win the
+    vote, and what comes back is the ATTACKED row bit for bit — the
+    winner's row gets the map on its way out (the stack holds the raw
+    one)."""
+    import jax.numpy as jnp
+
+    one = jax.random.normal(jax.random.key(3), (1, 4 * 1024))
+    raw = jnp.repeat(one, 3, axis=0).reshape((3,) + layout)
+    mask = jnp.asarray([True, False, True])
+    (voted, hl), (want, he), _, _ = _vote_both_ways(
+        raw, mask, "rev_grad", 1, None, None, method)
+    assert np.array_equal(np.asarray(voted), np.asarray(want))
+    assert np.array_equal(np.asarray(voted).reshape(-1),
+                          np.asarray(-100.0 * one[0]))
+    assert np.array_equal(np.asarray(hl["flagged"]), [False, True, False])
+    assert np.array_equal(np.asarray(hl["flagged"]), np.asarray(he["flagged"]))
+
+
+@pytest.mark.parametrize("layout", [(-1,), (-1, 128)])
+def test_a_nan_under_a_constant_attack_is_still_named(layout):
+    """``bad_rows`` are the rows AS COMPUTED (PR 28's order): a NaN in the
+    adversary's raw row is named although the ``constant`` attack
+    overwrites it before the fingerprints see the row — through the seam,
+    on the lazy path and on the stored one (``vote_check="exact"`` keeps
+    it)."""
+    import jax.numpy as jnp
+
+    from draco_tpu.parallel.common import _vote_row_map, aggregate_flat_grads
+
+    one = jax.random.normal(jax.random.key(5), (1, 4 * 1024))
+    raw = jnp.repeat(one, 3, axis=0).at[1, 77].set(jnp.nan)
+    raw = raw.reshape((3,) + layout)
+    mask = jnp.asarray([False, True, False])
+    step = jnp.asarray(2, jnp.int32)
+    out = {}
+    for check in ("fingerprint", "exact"):
+        cfg = _cfg("TransformerLM", num_workers=3, err_mode="constant",
+                   vote_check=check)
+        assert (_vote_row_map(cfg, mask) is None) == (check == "exact")
+        out[check] = aggregate_flat_grads(raw, mask, cfg, None, None,
+                                          step=step)
+    for voted, health in out.values():
+        assert np.array_equal(np.asarray(health["bad_rows"]), np.asarray(mask))
+        assert np.array_equal(np.asarray(health["flagged"]), np.asarray(mask))
+        assert np.array_equal(np.asarray(voted).reshape(-1),
+                              np.asarray(one[0]))

@@ -64,7 +64,7 @@ class TestFingerprintCollisionResistance:
     advisor's constructed-collision finding)."""
 
     def _fps(self, rows, key=None):
-        h1, h2 = repetition._row_fingerprints(jnp.asarray(rows), key=key)
+        h1, h2, _ = repetition._row_fingerprints(jnp.asarray(rows), key=key)
         return np.asarray(h1), np.asarray(h2)
 
     def test_top_bit_pair_flip_does_not_collide(self, rng):

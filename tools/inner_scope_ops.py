@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Chip probe: one traced benchmark run of a cell, then the instructions of
-ONE nested scope (``draco_route``, ``draco_attn``, ...) by device self time.
+one scope, or of several (``draco_route``, ``draco_decode,draco_pack``, ...)
+by device self time.
 
   python3 tools/inner_scope_ops.py kanana2.maj_vote_r3 <seed> draco_route [tag]
 
@@ -30,8 +31,8 @@ sys.path.insert(0, ROOT)
 
 def main(argv) -> int:
     t_process = time.time()
-    cell_name, seed, scope = argv[0], int(argv[1]), argv[2]
-    tag = argv[3] if len(argv) > 3 else scope
+    cell_name, seed, scopes = argv[0], int(argv[1]), argv[2].split(",")
+    tag = argv[3] if len(argv) > 3 else scopes[0]
     from benchmark.harness import manifest, runner, xplane
     from benchmark.routes import token
     from draco_tpu.runtime import enable_compile_cache
@@ -67,15 +68,17 @@ def main(argv) -> int:
     trace, inner = kept["trace"], kept["inner"]
     per_step = 1e-6 / trace.steps  # ns over the capture -> ms a step
     totals: dict = {}
-    ops: dict = {}
+    ops: dict = {scope: {} for scope in scopes}
     for ev, ns in xplane.self_times(trace.first()):
         name = inner.get(ev[0]) or ev[3] or "(none)"
         totals[name] = totals.get(name, 0.0) + ns * per_step
-        if name == scope:
-            ops[ev[4]] = ops.get(ev[4], 0.0) + ns * per_step
-    out = {"cell": cell_name, "seed": seed, "scope": scope,
+        if name in ops:
+            ops[name][ev[4]] = ops[name].get(ev[4], 0.0) + ns * per_step
+    out = {"cell": cell_name, "seed": seed, "scopes": scopes,
            "traced_steps": trace.steps, "scope_ms_per_step": totals,
-           "ops_ms_per_step": sorted(ops.items(), key=lambda kv: -kv[1]),
+           "ops_ms_per_step": {
+               scope: sorted(of.items(), key=lambda kv: -kv[1])
+               for scope, of in ops.items()},
            "counters_max": {k: max(r[k] for r in kept["records"].rows)
                             for k in kept["counters"]},
            "steps": len(kept["records"].rows),
@@ -90,8 +93,10 @@ def main(argv) -> int:
         fh.write(kept["hlo"])
     print(json.dumps({k: out[k] for k in (
         "correct", "metrics", "scope_ms_per_step", "counters_max", "steps")}))
-    for label, ms in out["ops_ms_per_step"][:40]:
-        print(f"{ms:9.3f} ms  {label}")
+    for scope in scopes:
+        print(f"-- {scope}")
+        for label, ms in out["ops_ms_per_step"][scope][:40]:
+            print(f"{ms:9.3f} ms  {label}")
     return 0
 
 
