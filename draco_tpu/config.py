@@ -26,14 +26,18 @@ AGG_MODES = ("normal", "geometric_median", "krum", "coord_median",
 # Networks that train on token sequences through the shared token loop
 # (parallel/token_loop.py) and come from models.build_lm; everything else is
 # an image model on the CNN Trainer.
-TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM")
+TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM", "HybridMoeLM")
+# the token models stated by ONE mapping of a published config's keys
+# (TrainConfig.model_spec), each with the module that checks and builds it
+SPEC_NETWORKS = {"LatentMoeLM": "draco_tpu.models.latent_moe",
+                 "HybridMoeLM": "draco_tpu.models.hybrid_moe"}
 
 
 @dataclasses.dataclass
 class TrainConfig:
     # --- model / data (reference: distributed_nn.py:27-37) ---
     # LeNet | FC | ResNet18/34/50/101/152 | VGG11/13/16/19[_bn] | the token
-    # models TransformerLM | LatentMoeLM (TOKEN_NETWORKS)
+    # models TransformerLM | LatentMoeLM | HybridMoeLM (TOKEN_NETWORKS)
     network: str = "LeNet"
     dataset: str = "MNIST"  # MNIST | Cifar10 | synthetic variants
     data_dir: str = "./data"
@@ -169,11 +173,12 @@ class TrainConfig:
     model_dim: int = 128
     model_heads: int = 4
     model_layers: int = 2
-    # network=LatentMoeLM: the ONE mapping that states the model — a
-    # published config.json's keys verbatim plus ``layers``,
-    # ``experts_held`` ([first, count]) and ``vocab_rows``, the chip's share
-    # of a deployment (models/latent_moe.py). The model_* fields above are
-    # TransformerLM's and are not read for it. CLI: --model-spec <file.json>.
+    # network=LatentMoeLM | HybridMoeLM (SPEC_NETWORKS): the ONE mapping
+    # that states the model — a published config.json's keys verbatim plus
+    # ``layers``, ``experts_held`` ([first, count]) and ``vocab_rows``, the
+    # chip's share of a deployment (models/latent_moe.py, hybrid_moe.py).
+    # The model_* fields above are TransformerLM's and are not read for it.
+    # CLI: --model-spec <file.json>.
     model_spec: Optional[dict] = None
 
     # --- precision ---
@@ -924,16 +929,17 @@ class TrainConfig:
                         "(the narrow wire and the numerics observatory of "
                         "the vote live on the CNN path, training/step.py)"
                     )
-        if self.network == "LatentMoeLM":
-            from draco_tpu.models.latent_moe import check_spec
+        if self.network in SPEC_NETWORKS:
+            import importlib
 
-            check_spec(self.model_spec)
+            importlib.import_module(
+                SPEC_NETWORKS[self.network]).check_spec(self.model_spec)
             for name in ("seq_shards", "tensor_shards", "expert_shards",
                          "pipeline_shards"):
                 if getattr(self, name) > 1:
                     raise ValueError(
                         f"{name}={getattr(self, name)} with network="
-                        "LatentMoeLM is not implemented: the block runs on "
+                        f"{self.network} is not implemented: the block runs on "
                         "the single-shard token route (its experts are the "
                         "chip's share of a deployment, stated in "
                         "model_spec['experts_held'], not an ep mesh axis)"
@@ -942,7 +948,7 @@ class TrainConfig:
                     or self.scan_layers:
                 raise ValueError(
                     "pp_microbatches / moe_experts / scan_layers are "
-                    "TransformerLM's and do not apply to network=LatentMoeLM "
+                    f"TransformerLM's and do not apply to network={self.network} "
                     "(its experts come from model_spec)"
                 )
             if self.attn_impl not in ("dense", "flash"):
@@ -956,7 +962,7 @@ class TrainConfig:
                     "from the slice the head scores"
                 )
             if self.seq_len < 2:
-                raise ValueError("LatentMoeLM needs seq_len >= 2")
+                raise ValueError(f"{self.network} needs seq_len >= 2")
         elif self.network == "TransformerLM":
             if self.model_dim % self.model_heads != 0:
                 raise ValueError(
@@ -1075,7 +1081,8 @@ class TrainConfig:
             if self.seq_len < 2 or self.vocab < 2:
                 raise ValueError("TransformerLM needs seq_len >= 2 and vocab >= 2")
         elif self.model_spec is not None:
-            raise ValueError("model_spec requires network=LatentMoeLM")
+            raise ValueError("model_spec requires network=LatentMoeLM or "
+                             "HybridMoeLM")
         elif self.seq_shards > 1:
             raise ValueError("seq_shards > 1 requires network=TransformerLM")
         elif self.tensor_shards > 1:

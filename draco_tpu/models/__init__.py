@@ -9,12 +9,17 @@ collectives + latency hiding), so there is exactly one copy of each model here.
 Token models (``TOKEN_NETWORKS``, config.py) come from :func:`build_lm`, the
 one factory the LM step builders call: ``TransformerLM`` (the repo's own
 pre-LN / GELU / tied-head block, the only one the tp / ep / pp / sequence-
-sharded routes build) and ``LatentMoeLM`` (models/latent_moe.py: a block that
-states a published config — RMS norm, SwiGLU, latent key/value attention,
-sigmoid top-k routing without drops over the experts this chip holds, shared
-experts, untied head — on the single-shard route of parallel/sp_step.py).
+sharded routes build) and the two blocks that state a published config on
+the single-shard route of parallel/sp_step.py (``config.SPEC_NETWORKS``):
+``LatentMoeLM`` (models/latent_moe.py: RMS norm, SwiGLU, latent key/value
+attention, sigmoid top-k routing without drops over the experts this chip
+holds, shared experts, untied head) and ``HybridMoeLM`` (models/
+hybrid_moe.py: Gated DeltaNet linear-attention layers beside gated
+grouped-query softmax attention, softmax routing, a gated shared expert —
+over the same expert layer).
 """
 
+from draco_tpu.config import SPEC_NETWORKS, TOKEN_NETWORKS
 from draco_tpu.models.fc import FC_NN
 from draco_tpu.models.lenet import LeNet
 from draco_tpu.models.resnet import (
@@ -62,7 +67,7 @@ def build_model(name: str, num_classes: int = 10, dtype=None):
     baseline_master.py:30-47 / baseline_worker.py:37-50). ``dtype``: compute
     dtype for the conv/dense stacks ("bfloat16" rides the MXU at full rate;
     params, BN stats and logits stay float32)."""
-    if name in ("TransformerLM", "LatentMoeLM"):
+    if name in TOKEN_NETWORKS:
         raise ValueError(
             f"{name} is a token model and does not run on the image "
             "pipeline; the CLI routes it automatically, or construct it via "
@@ -117,16 +122,20 @@ def build_lm(cfg, attn_fn=None, kernel_fn=None):
     none). The route offers two attentions ((q, k, v) -> o) and each model
     takes the one it can use: ``attn_fn``, the route's own (sequence-
     parallel wrappers included; equal head sizes), and ``kernel_fn``, the
-    bare single-device kernel, which ``LatentMoeLM`` takes because its q/k
-    and v differ in head size. None is each model's plain lowering."""
+    bare single-device kernel, which the published-config blocks take
+    (``LatentMoeLM``'s q/k and v differ in head size, ``HybridMoeLM``'s
+    key/value heads are fewer than its query heads). None is each model's
+    plain lowering."""
+    import importlib
+
     import jax.numpy as jnp
 
     cdtype = jnp.dtype(cfg.compute_dtype)
-    if cfg.network == "LatentMoeLM":
-        from draco_tpu.models.latent_moe import LatentMoeLM
-
-        return LatentMoeLM(cfg.model_spec, attn_fn=kernel_fn, dtype=cdtype,
-                           remat=cfg.remat)
+    if cfg.network in SPEC_NETWORKS:
+        module = importlib.import_module(SPEC_NETWORKS[cfg.network])
+        return getattr(module, cfg.network)(
+            cfg.model_spec, attn_fn=kernel_fn, dtype=cdtype,
+            remat=cfg.remat)
     if cfg.network != "TransformerLM":
         raise ValueError(f"{cfg.network!r} is not a token model")
     kw = dict(vocab=cfg.vocab, dim=cfg.model_dim, heads=cfg.model_heads,

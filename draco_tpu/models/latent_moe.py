@@ -44,6 +44,13 @@ runs over the next C sorted pairs, and the next, until it has passed
 in the likely case); the counter ``moe_full_dispatch`` says how many such
 further buffers the step's expert layers ran.
 
+The expert layer, the head and the loss are ``RoutedExpertLM``'s, the base
+both published-config models build on (models/hybrid_moe.py is the other):
+ONE ``_route`` / ``_buffer`` / ``grouped_dot`` path, told by ``MoeSpec``
+which score function ranks the experts (``sigmoid`` with the selection bias
+and the routed scale, or ``softmax``), whether the shared expert is under a
+sigmoid gate, and which experts this chip holds.
+
 Device scopes (nested in the step's ``draco_comp``): ``draco_attn`` (MLA
 whole), ``draco_route`` (scores, top-k, sort, gather, combine),
 ``draco_experts`` (every feed-forward: layer 0's dense one, the shared and
@@ -53,6 +60,7 @@ the routed experts), ``draco_head`` (final norm, logits, loss).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +68,7 @@ import numpy as np
 from jax import lax
 
 from draco_tpu.ops.coded import use_pallas
+from draco_tpu.ops.flash_attention import spread_kv_heads
 
 # the published config keys the block reads (model_spec must carry them)
 SPEC_KEYS = (
@@ -169,8 +178,10 @@ def rope_interleaved(x, positions, theta):
 
 def dense_causal_attention(q, k, v):
     """(B, T, H, Dh) q, k and (B, T, H, Dv) v -> (B, T, H, Dv): the plain
-    lowering where no kernel is selected."""
+    lowering where no kernel is selected. k and v may have fewer heads
+    (grouped-query attention)."""
     t = q.shape[1]
+    k, v = spread_kv_heads(q.shape[2], k, v)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
     mask = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
@@ -246,8 +257,31 @@ def grouped_dot(xs, kernels, sizes, held: int):
     return jnp.einsum("mg,mgn->mn", mine.astype(xs.dtype), full)
 
 
-class LatentMoeLM:
-    """``init(key) -> params``; ``token_nll(params, tokens, targets,
+class MoeSpec(NamedTuple):
+    """What the shared expert layer is told about its model."""
+
+    experts: int  # routed experts of the deployment: the router's width
+    top_k: int  # experts a token takes, of all of them
+    first: int  # this chip holds experts [first, first + held)
+    held: int
+    # "sigmoid": chosen by sigmoid score + the selection bias (a leaf that
+    # takes no gradient); "softmax": chosen by the softmax over all
+    # experts, no bias
+    scoring: str
+    norm_topk: bool  # weights renormalised over the chosen top_k
+    scale: float  # the routed experts' weights times this
+    gated_shared: bool  # shared expert times sigmoid(h · w_sg)
+
+
+class RoutedExpertLM:
+    """The part every published-config model here shares: seeded ``init``
+    over ``param_shapes()``, the expert layer over the experts held, the
+    head, the loss. A model adds ``param_shapes``, ``norm(x, p)`` (its RMS
+    norm over a norm's leaves ``p``), ``hidden`` and ``init_rules`` (leaf
+    name -> ``"ones"`` | ``"zeros"`` | a normal's std; ``INIT_STD``
+    otherwise).
+
+    ``init(key) -> params``; ``token_nll(params, tokens, targets,
     pos_offset, train) -> (nll (B, T) float32, stats)``. ``attn_fn``: (q,
     k, v) -> o with v's own head size (ops/flash_attention.flash_attention
     on the TPU); None is the plain lowering. ``remat``: rematerialise each
@@ -255,52 +289,22 @@ class LatentMoeLM:
     order a step's metric row carries them."""
 
     stat_names = STAT_NAMES
+    init_rules: dict = {}
 
-    def __init__(self, spec: dict, attn_fn=None, dtype=jnp.float32,
-                 remat: bool = False):
-        check_spec(spec)
+    def __init__(self, spec: dict, moe: MoeSpec, attn_fn=None,
+                 dtype=jnp.float32, remat: bool = False):
         self.spec = dict(spec)
+        self.moe = moe
         self.attn_fn = attn_fn or dense_causal_attention
         self.dtype = jnp.dtype(dtype)
         self.remat = remat
 
     # ---- parameters ---------------------------------------------------
-    def param_shapes(self) -> dict:
-        s = self.spec
-        d, h = s["hidden_size"], s["num_attention_heads"]
-        nope, rp, vd = (s["qk_nope_head_dim"], s["qk_rope_head_dim"],
-                        s["v_head_dim"])
-        rank, held = s["kv_lora_rank"], s["experts_held"][1]
-
-        def mlp(width, lead=()):
-            return {"gate": {"kernel": lead + (d, width)},
-                    "up": {"kernel": lead + (d, width)},
-                    "down": {"kernel": lead + (width, d)}}
-
-        tree = {"embed": {"embedding": (s["vocab_rows"], d)},
-                "final_norm": {"scale": (d,)},
-                "head": {"kernel": (d, s["vocab_rows"])}}
-        for i in range(s["layers"]):
-            layer = {
-                "attn_norm": {"scale": (d,)},
-                "q": {"kernel": (d, h * (nope + rp))},
-                "kv_a": {"kernel": (d, rank + rp)},
-                "kv_norm": {"scale": (rank,)},
-                "kv_b": {"kernel": (rank, h * (nope + vd))},
-                "o": {"kernel": (h * vd, d)},
-                "mlp_norm": {"scale": (d,)},
-            }
-            if i < s["first_k_dense_replace"]:
-                layer["mlp"] = mlp(s["intermediate_size"])
-            else:
-                width = s["moe_intermediate_size"]
-                layer["router"] = {
-                    "kernel": (d, s["n_routed_experts"]),
-                    "e_score_correction_bias": (s["n_routed_experts"],)}
-                layer["shared"] = mlp(width * s["n_shared_experts"])
-                layer["experts"] = mlp(width, (held,))
-            tree[f"layer{i}"] = layer
-        return tree
+    def mlp_shapes(self, width: int, lead=()) -> dict:
+        d = self.spec["hidden_size"]
+        return {"gate": {"kernel": lead + (d, width)},
+                "up": {"kernel": lead + (d, width)},
+                "down": {"kernel": lead + (width, d)}}
 
     def init(self, key):
         shapes = self.param_shapes()
@@ -308,52 +312,22 @@ class LatentMoeLM:
             shapes, is_leaf=lambda x: isinstance(x, tuple))
         leaves = []
         for i, (path, shape) in enumerate(paths):
-            name = path[-1].key
+            rule = self.init_rules.get(path[-1].key, INIT_STD)
             k = jax.random.fold_in(key, i)
-            if name == "scale":
-                leaves.append(jnp.ones(shape, jnp.float32))
+            if rule in ("ones", "zeros"):
+                leaves.append(getattr(jnp, rule)(shape, jnp.float32))
             else:
-                std = {"e_score_correction_bias": BIAS_STD,
-                       "embedding": EMBED_STD}.get(name, INIT_STD)
-                leaves.append(std * jax.random.normal(k, shape, jnp.float32))
+                leaves.append(rule * jax.random.normal(k, shape, jnp.float32))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    # ---- the block ----------------------------------------------------
-    def _attention(self, h, p, positions):
-        s = self.spec
-        b, t, _ = h.shape
-        heads = s["num_attention_heads"]
-        nope, rp, vd = (s["qk_nope_head_dim"], s["qk_rope_head_dim"],
-                        s["v_head_dim"])
-        rank = s["kv_lora_rank"]
-        q = _dot(h, p["q"]["kernel"]).reshape(b, t, heads, nope + rp)
-        kva = _dot(h, p["kv_a"]["kernel"])
-        c = rms_norm(kva[..., :rank], p["kv_norm"]["scale"],
-                     s["rms_norm_eps"])
-        k_rope = rope_interleaved(kva[..., rank:].astype(jnp.float32),
-                                  positions, s["rope_theta"])
-        kvb = _dot(c, p["kv_b"]["kernel"]).reshape(b, t, heads, nope + vd)
-        q = jnp.concatenate([
-            q[..., :nope].astype(jnp.float32),
-            rope_interleaved(q[..., nope:].astype(jnp.float32), positions,
-                             s["rope_theta"])], axis=-1)
-        k = jnp.concatenate([
-            kvb[..., :nope].astype(jnp.float32),
-            jnp.broadcast_to(k_rope[:, :, None, :], (b, t, heads, rp))],
-            axis=-1)
-        v = kvb[..., nope:]
-        o = self.attn_fn(_operand(q), _operand(k), _operand(v))
-        return _dot(o.astype(h.dtype).reshape(b, t, heads * vd),
-                    p["o"]["kernel"])
-
+    # ---- the expert layer ---------------------------------------------
     def dispatch_rows(self, tokens: int) -> int:
         """C, the dispatch buffer's rows for ``tokens`` rows of input: from
         the shapes alone (module constant ``DISPATCH_SHARE``), T·k where
         the chip holds every expert."""
-        s = self.spec
-        pairs = tokens * s["num_experts_per_tok"]
-        share = -(-DISPATCH_SHARE * pairs * s["experts_held"][1]
-                  // s["n_routed_experts"])
+        m = self.moe
+        pairs = tokens * m.top_k
+        share = -(-DISPATCH_SHARE * pairs * m.held // m.experts)
         return min(pairs, -(-share // ROW_TILE) * ROW_TILE)
 
     def _route(self, h, p):
@@ -362,23 +336,25 @@ class LatentMoeLM:
         choice) pairs' sorted order (this chip's experts first), the
         groups' sizes, the count of pairs that landed here; the count of
         dispatch buffers that hold a landed pair; and the counters."""
-        s = self.spec
-        k = s["num_experts_per_tok"]
-        first, held = s["experts_held"]
-        n_exp = s["n_routed_experts"]
-        scores = jax.nn.sigmoid(jnp.matmul(
-            h.astype(jnp.float32), p["kernel"],
-            precision=lax.Precision.HIGHEST))
-        bias = lax.stop_gradient(p["e_score_correction_bias"])
-        _, chosen = lax.top_k(scores + bias, k)
+        m = self.moe
+        k, first, held, n_exp = m.top_k, m.first, m.held, m.experts
+        logits = jnp.matmul(h.astype(jnp.float32), p["kernel"],
+                            precision=lax.Precision.HIGHEST)
+        if m.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            bias = lax.stop_gradient(p["e_score_correction_bias"])
+            _, chosen = lax.top_k(scores + bias, k)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = lax.top_k(scores, k)
         # scores[chosen] under a dense mask: the chip runs a gather of T·k
         # scalars, and the scatter-add its transpose is, far slower
         experts = jnp.arange(n_exp, dtype=chosen.dtype)
         w = jnp.sum(jnp.where(chosen[..., None] == experts,
                               scores[:, None, :], 0.0), axis=-1)
-        if s["norm_topk_prob"]:
+        if m.norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        w = w * s["routed_scaling_factor"]
+        w = w * m.scale
         # this chip's experts become groups 0..held-1, the others follow
         group = (chosen.reshape(-1) - first) % n_exp
         order = jnp.argsort(group)  # stable: arrival order within a group
@@ -402,8 +378,7 @@ class LatentMoeLM:
         per held expert, the rows summed back into their tokens under
         their combine weights. C rows throughout, forward and backward."""
         order, sizes, landed = dispatch
-        k = self.spec["num_experts_per_tok"]
-        held = self.spec["experts_held"][1]
+        k, held = self.moe.top_k, self.moe.held
         rows = self.dispatch_rows(h.shape[0])
         with jax.named_scope("draco_route"):
             slot = j * rows + jnp.arange(rows, dtype=jnp.int32)
@@ -437,8 +412,7 @@ class LatentMoeLM:
         """x (N, hidden) -> x + the routed (held) and shared experts of its
         normalised rows; the norm counts as the experts' (it feeds them)."""
         with jax.named_scope("draco_experts"):
-            h = rms_norm(x, p["mlp_norm"]["scale"],
-                         self.spec["rms_norm_eps"])
+            h = self.norm(x, p["mlp_norm"])
             # the products' operands, once a layer: every buffer reads the
             # same copies (made inside the loop over further buffers, the
             # compiler hoists a second set out of it and keeps it alive
@@ -449,17 +423,124 @@ class LatentMoeLM:
         routed = routed_experts(self._buffer, h, w, e, dispatch, needed)
         with jax.named_scope("draco_experts"):
             shared = swiglu(h, p["shared"])
+            if self.moe.gated_shared:
+                shared = jax.nn.sigmoid(
+                    _dot(h, p["shared_gate"]["kernel"])) * shared
             return x + (routed + shared), stats
 
+    # ---- head and loss ------------------------------------------------
+    def _head(self, params, x):
+        x = self.norm(x, params["final_norm"])
+        return _dot(x, params["head"]["kernel"]).astype(jnp.float32)
+
+    def logits(self, params, tokens, pos_offset=0):
+        """tokens (B, T) -> (B, T, vocab_rows) float32."""
+        x, _ = self.hidden(params, tokens, pos_offset)
+        with jax.named_scope("draco_head"):
+            return self._head(params, x)
+
+    def token_nll(self, params, tokens, targets, pos_offset=0,
+                  train: bool = True):
+        """tokens, targets (B, T) -> (per-position negative log-likelihood
+        (B, T) float32 over the vocabulary slice, the ``stat_names``
+        counters)."""
+        del train  # no dropout in these blocks
+        x, stats = self.hidden(params, tokens, pos_offset)
+        with jax.named_scope("draco_head"):
+            logp = jax.nn.log_softmax(self._head(params, x))
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
+        return nll, stats
+
+
+class LatentMoeLM(RoutedExpertLM):
+    """The ``deepseek_v3`` family's block (module docstring)."""
+
+    init_rules = {"scale": "ones", "embedding": EMBED_STD,
+                  "e_score_correction_bias": BIAS_STD}
+
+    def __init__(self, spec: dict, attn_fn=None, dtype=jnp.float32,
+                 remat: bool = False):
+        check_spec(spec)
+        super().__init__(spec, MoeSpec(
+            experts=spec["n_routed_experts"],
+            top_k=spec["num_experts_per_tok"],
+            first=spec["experts_held"][0], held=spec["experts_held"][1],
+            scoring="sigmoid", norm_topk=spec["norm_topk_prob"],
+            scale=spec["routed_scaling_factor"], gated_shared=False),
+            attn_fn, dtype, remat)
+
+    def norm(self, x, p):
+        return rms_norm(x, p["scale"], self.spec["rms_norm_eps"])
+
+    # ---- parameters ---------------------------------------------------
+    def param_shapes(self) -> dict:
+        s = self.spec
+        d, h = s["hidden_size"], s["num_attention_heads"]
+        nope, rp, vd = (s["qk_nope_head_dim"], s["qk_rope_head_dim"],
+                        s["v_head_dim"])
+        rank, held = s["kv_lora_rank"], s["experts_held"][1]
+        tree = {"embed": {"embedding": (s["vocab_rows"], d)},
+                "final_norm": {"scale": (d,)},
+                "head": {"kernel": (d, s["vocab_rows"])}}
+        for i in range(s["layers"]):
+            layer = {
+                "attn_norm": {"scale": (d,)},
+                "q": {"kernel": (d, h * (nope + rp))},
+                "kv_a": {"kernel": (d, rank + rp)},
+                "kv_norm": {"scale": (rank,)},
+                "kv_b": {"kernel": (rank, h * (nope + vd))},
+                "o": {"kernel": (h * vd, d)},
+                "mlp_norm": {"scale": (d,)},
+            }
+            if i < s["first_k_dense_replace"]:
+                layer["mlp"] = self.mlp_shapes(s["intermediate_size"])
+            else:
+                width = s["moe_intermediate_size"]
+                layer["router"] = {
+                    "kernel": (d, s["n_routed_experts"]),
+                    "e_score_correction_bias": (s["n_routed_experts"],)}
+                layer["shared"] = self.mlp_shapes(
+                    width * s["n_shared_experts"])
+                layer["experts"] = self.mlp_shapes(width, (held,))
+            tree[f"layer{i}"] = layer
+        return tree
+
+    # ---- the block ----------------------------------------------------
+    def _attention(self, h, p, positions):
+        s = self.spec
+        b, t, _ = h.shape
+        heads = s["num_attention_heads"]
+        nope, rp, vd = (s["qk_nope_head_dim"], s["qk_rope_head_dim"],
+                        s["v_head_dim"])
+        rank = s["kv_lora_rank"]
+        q = _dot(h, p["q"]["kernel"]).reshape(b, t, heads, nope + rp)
+        kva = _dot(h, p["kv_a"]["kernel"])
+        c = self.norm(kva[..., :rank], p["kv_norm"])
+        k_rope = rope_interleaved(kva[..., rank:].astype(jnp.float32),
+                                  positions, s["rope_theta"])
+        kvb = _dot(c, p["kv_b"]["kernel"]).reshape(b, t, heads, nope + vd)
+        q = jnp.concatenate([
+            q[..., :nope].astype(jnp.float32),
+            rope_interleaved(q[..., nope:].astype(jnp.float32), positions,
+                             s["rope_theta"])], axis=-1)
+        k = jnp.concatenate([
+            kvb[..., :nope].astype(jnp.float32),
+            jnp.broadcast_to(k_rope[:, :, None, :], (b, t, heads, rp))],
+            axis=-1)
+        v = kvb[..., nope:]
+        o = self.attn_fn(_operand(q), _operand(k), _operand(v))
+        return _dot(o.astype(h.dtype).reshape(b, t, heads * vd),
+                    p["o"]["kernel"])
+
     def _layer(self, x, p, positions, dense: bool):
-        eps = self.spec["rms_norm_eps"]
         with jax.named_scope("draco_attn"):
-            x = x + self._attention(
-                rms_norm(x, p["attn_norm"]["scale"], eps), p, positions)
+            x = x + self._attention(self.norm(x, p["attn_norm"]), p,
+                                    positions)
         b, t, d = x.shape
         if dense:
             with jax.named_scope("draco_experts"):
-                h = rms_norm(x, p["mlp_norm"]["scale"], eps)
+                h = self.norm(x, p["mlp_norm"])
                 return x + swiglu(h, p["mlp"]), None
         y, stats = self._experts(x.reshape(b * t, d), p)
         return y.reshape(b, t, d), stats
@@ -480,29 +561,6 @@ class LatentMoeLM:
             if stats is not None:
                 per_layer.append(stats)
         return x, fold_stats(per_layer)
-
-    def _head(self, params, x):
-        x = rms_norm(x, params["final_norm"]["scale"],
-                     self.spec["rms_norm_eps"])
-        return _dot(x, params["head"]["kernel"]).astype(jnp.float32)
-
-    def logits(self, params, tokens, pos_offset=0):
-        """tokens (B, T) -> (B, T, vocab_rows) float32."""
-        x, _ = self.hidden(params, tokens, pos_offset)
-        with jax.named_scope("draco_head"):
-            return self._head(params, x)
-
-    def token_nll(self, params, tokens, targets, pos_offset=0,
-                  train: bool = True):
-        """tokens, targets (B, T) -> (per-position negative log-likelihood
-        (B, T) float32 over the vocabulary slice, STAT_NAMES counters)."""
-        del train  # no dropout in this block
-        x, stats = self.hidden(params, tokens, pos_offset)
-        with jax.named_scope("draco_head"):
-            logp = jax.nn.log_softmax(self._head(params, x))
-            nll = -jnp.take_along_axis(logp, targets[..., None],
-                                       axis=-1)[..., 0]
-        return nll, stats
 
 
 def fold_stats(per_layer: list) -> dict:

@@ -436,7 +436,8 @@ def flash_attention(q, k, v, *, block_q: int = 512, block_k: int = 1024,
     """Causal self-attention. q, k: (B, T, H, Dh), v: (B, T, H, Dv) — the
     Block contract with Dv == Dh, latent attention with Dh 192 against Dv
     128 (attention math upstream is f32; the kernel accumulates f32
-    regardless). Returns (B, T, H, Dv).
+    regardless); k and v may have fewer heads than q (grouped-query
+    attention, ``spread_kv_heads``). Returns (B, T, H, Dv).
 
     The causal mask is offset-invariant for self-attention (q and k share
     positions), so no offset argument is needed. Off-TPU (and not
@@ -446,11 +447,24 @@ def flash_attention(q, k, v, *, block_q: int = 512, block_k: int = 1024,
     from draco_tpu.parallel.ring_attention import dense_attention
 
     b, t, h, dh = q.shape
+    k, v = spread_kv_heads(h, k, v)
     bq = _fit_block(block_q, t, lane_rule=False)
     bk = _fit_block(block_k, t, lane_rule=True)
     if not _kernel_eligible(t, bq, bk, dh, force, interpret):
         return dense_attention(q, k, v, causal=True)
     return _run_folded(q, k, v, bq, bk, True, interpret, want_lse=False)
+
+
+def spread_kv_heads(heads: int, k, v):
+    """Grouped-query heads: k, v (B, T, Hkv, D) with Hkv dividing ``heads``
+    -> (B, T, heads, D), key/value head j serving query heads j·r ..
+    j·r + r − 1. The kernels take one key/value head a query head; the
+    copies are made here and autodiff sums their gradients back. Equal
+    head counts pass through untouched."""
+    r = heads // k.shape[2]
+    if r == 1:
+        return k, v
+    return jnp.repeat(k, r, axis=2), jnp.repeat(v, r, axis=2)
 
 
 def _kernel_eligible(t, bq, bk, dh, force, interpret) -> bool:

@@ -19,8 +19,9 @@ sequence a group member is a whole mesh row; config.validate refuses that
 by name.)
 
 The token model comes from ``models.build_lm``: ``TransformerLM`` on every
-mesh this route builds, ``LatentMoeLM`` (a published config's block: latent
-attention, routed and shared experts) at ``seq_shards == 1``. Where the
+mesh this route builds; the published-config blocks ``LatentMoeLM`` (latent
+attention, routed and shared experts) and ``HybridMoeLM`` (Gated DeltaNet
+beside gated attention, over the same expert layer) at ``seq_shards == 1``. Where the
 (lanes, d) stack of per-lane gradients computed side by side would not fit
 beside the rest of the step (``LANES_IN_TURN_BYTES``), the lanes are
 evaluated in turn (``lax.map``), each layer is rematerialised in the
@@ -73,8 +74,8 @@ from draco_tpu.training.step import TrainState, _flatten_tree, _make_unravel
 # vmapped lanes (each with its own gradient tree and activations alive at
 # once), does not fit one 16 GB chip beside weights, momentum and the decoded
 # gradient: such a step evaluates its lanes in turn and rematerialises per
-# layer. d = 425 M x 3 lanes is 5.1 GB: the large side is the cell
-# kanana2.maj_vote_r3 and, with this constant set to 0,
+# layer. d = 425 M x 3 lanes is 5.1 GB: the large side is the cells
+# kanana2.maj_vote_r3, qwen3next.maj_vote_r3 and, with this constant set to 0,
 # tests/test_lm_maj_vote.py::test_lanes_in_turn_train_the_same_as_side_by_side;
 # every other LM the tests build is on the small side.
 LANES_IN_TURN_BYTES = 2**30
@@ -84,8 +85,9 @@ LANES_IN_TURN_BYTES = 2**30
 # chip's own (8, 128) tiles in order, which is the flat row's bytes as they
 # lie, so the reshape moves nothing, no lane is padded and a lane's row is
 # one contiguous block — and a leaf of the model is a range of the row's
-# lines (every offset and size of a published width is a multiple of 128),
-# so the winner's row is cut into leaves where it lies
+# lines (every offset and size of a published width is a multiple of 128;
+# a model keeps what is not, per-head vectors, last in ravel order), so the
+# winner's row is cut into leaves where it lies
 # (training/step._make_unravel). Measured at d = 425 M on the chip (PERF.md
 # section 6; there as (lanes, d / 1024, 8, 128), the same bytes): as
 # (lanes, d) the tiling is (4, 128) — three lanes padded to four, 6.3 GB
@@ -221,8 +223,9 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
     lanes = n // mesh.shape[WORKER_AXIS]
     lanes_in_turn = 4 * lanes * dim > LANES_IN_TURN_BYTES
     tiled_stack = lanes_in_turn and cfg.approach == "maj_vote"
-    # zeros that close a row's last (8, 128) tile (none at the cell's
-    # d = 415 001 tiles); every lane writes the same, so the vote is unmoved
+    # zeros that close a row's last (8, 128) tile (none at kanana2's
+    # d = 415 001 tiles, 960 at qwen3next's d = 424 340 544); every lane
+    # writes the same, so the vote is unmoved
     tile_pad = -dim % (8 * STACK_LANES) if tiled_stack else 0
     if lanes_in_turn and not cfg.remat:
         model = build_lm(dataclasses.replace(cfg, remat=True), attn,

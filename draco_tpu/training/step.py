@@ -98,8 +98,15 @@ def _make_unravel(params):
     lays it out, (d / 128, 128) with zeros closing the last tile
     (parallel/sp_step.STACK_LANES). A leaf that starts and ends on a whole
     (last-axis) line of such a row is cut as a range of lines, from the row
-    where it lies: flattening it first costs the chip a copy of the whole
-    row in a second layout."""
+    where it lies. A leaf off the lines is cut from the lines that hold it:
+    those lines are copied once more, flat, and the leaf sliced out of the
+    copy — for a leaf of under a line (a per-head vector of 32) two lines;
+    for a large one its own bytes again, in a layout shifted against the
+    row's. What such a leaf costs the OTHERS is the point: every leaf after
+    it in ravel order starts off a line too, so a model keeps its sub-line
+    leaves last in ravel order (models/hybrid_moe.py: ``linear_heads``).
+    Never is the whole row flattened: that is a copy of it in a second
+    layout, 1.7 GB at d = 425 M, and the compiler makes it once per view."""
     leaves, treedef = jax.tree.flatten(params)
     shapes = [l.shape for l in leaves]
     sizes = [int(np.prod(s)) for s in shapes]
@@ -113,10 +120,9 @@ def _make_unravel(params):
         parts = []
         for i, shape in enumerate(shapes):
             lo, hi = int(offsets[i]), int(offsets[i + 1])
+            part = lines[lo // line : -(-hi // line)]
             if lo % line or hi % line:
-                part = flat.reshape(-1)[lo:hi]
-            else:
-                part = lines[lo // line : hi // line]
+                part = part.reshape(-1)[lo % line : lo % line + hi - lo]
             parts.append(jnp.reshape(part, shape))
         return jax.tree.unflatten(treedef, parts)
 

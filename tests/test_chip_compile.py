@@ -125,6 +125,21 @@ def _flash_latent():
     return fn, [(MLA_QK, jnp.bfloat16)] * 2 + [(MLA_V, jnp.bfloat16)]
 
 
+# qwen3next.maj_vote_r3: 16 query heads of 256 on 2 key/value heads
+GQA_Q, GQA_KV = (1, 4096, 16, 256), (1, 4096, 2, 256)
+
+
+def _flash_grouped_query():
+    """Forward and backward at the widest head the kernel takes (256), the
+    key/value heads spread over the query heads they serve."""
+    def fn(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, force=True).astype(jnp.float32))), argnums=(0, 1, 2))(
+                q, k, v)
+
+    return fn, [(GQA_Q, jnp.bfloat16)] + [(GQA_KV, jnp.bfloat16)] * 2
+
+
 def _grouped_dot():
     """models/latent_moe.grouped_dot's kernel (jax's megablox) at the
     cell's shapes, forward and backward, with only the held groups'
@@ -159,6 +174,7 @@ CASES = {
     "flash_fwd": lambda: _flash(grad=False),
     "flash_grad": lambda: _flash(grad=True),
     "flash_grad_qk192_v128": _flash_latent,
+    "flash_grad_16_heads_on_2_d256": _flash_grouped_query,
     "grouped_dot_8_of_128": _grouped_dot,
 }
 

@@ -1,0 +1,142 @@
+"""ops/delta_rule.chunked_gated_delta_rule against the recurrence itself,
+token by token (benchmark/reference/nets/qwen3_next.delta_rule, which
+imports nothing of draco_tpu): outputs, the state a row leaves behind, and
+the gradient of every input — at T a multiple of the chunk and not, at one
+chunk and many, at key heads serving one value head and two, and at log
+decays so negative that exp(−Σg) over a chunk overflows float32: the
+chunked form takes exponentials of differences G_c − G_e <= 0 only, so it
+must stay finite and right there.
+
+Tolerance: both sides are float32 sums of the same terms in another order
+(a chunk's writes solved at once against one token at a time): 2e-5 of the
+largest entry forward, 1e-4 for a gradient (at strongly negative g the
+gradient of g is what is left of terms that cancel: its largest entry is
+1e-3 of the other gradients' and carries their rounding)."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference.nets import qwen3_next as ref  # noqa: E402
+from draco_tpu.ops.delta_rule import (  # noqa: E402
+    SOLVE_NAME, _solve_by_squaring, _unit_lower_inverse,
+    chunked_gated_delta_rule,
+)
+
+pytestmark = pytest.mark.core
+DK, DV = 16, 8
+
+
+def _inputs(t, hk, hv, g_scale, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 5)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(jax.random.normal(keys[0], (t, hk, DK))) * DK ** -0.5
+    k = unit(jax.random.normal(keys[1], (t, hk, DK)))
+    v = jax.random.normal(keys[2], (t, hv, DV))
+    g = -g_scale * jax.nn.softplus(jax.random.normal(keys[3], (t, hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (t, hv)))
+    return q, k, v, g, beta
+
+
+def _token_by_token(q, k, v, g, beta):
+    r = v.shape[1] // q.shape[1]
+    return ref.delta_rule(jnp.repeat(q, r, axis=1), jnp.repeat(k, r, axis=1),
+                          v, g, beta, lambda x: x)
+
+
+def _chunked(q, k, v, g, beta, chunk):
+    o, state = chunked_gated_delta_rule(q[None], k[None], v[None], g[None],
+                                        beta[None], chunk)
+    return o[0], state[0]
+
+
+def _close(got, want, what, rel=2e-5):
+    scale = float(jnp.max(jnp.abs(want))) + 1e-12
+    assert np.all(np.isfinite(np.asarray(got))), what
+    assert float(jnp.max(jnp.abs(got - want))) <= rel * scale + 1e-9, what
+
+
+@pytest.mark.parametrize("t,chunk,hk,hv,g_scale", [
+    (64, 16, 2, 2, 0.1),   # whole chunks, one value head a key head
+    (50, 16, 2, 4, 0.1),   # a last chunk of 2 tokens, two value heads a key
+    (7, 16, 1, 2, 1.0),    # under one chunk
+    (130, 64, 2, 4, 0.05),  # the family's chunk, slow decay: a long memory
+    (50, 16, 2, 4, 40.0),  # strongly negative g: exp(640) would overflow
+])
+def test_chunked_rule_is_the_recurrence(t, chunk, hk, hv, g_scale):
+    args = _inputs(t, hk, hv, g_scale)
+    want = _token_by_token(*args)
+    got, _ = _chunked(*args, chunk)
+    _close(got, want, "outputs")
+
+    probe = jax.random.normal(jax.random.key(9), want.shape)
+    g_want = jax.grad(lambda *a: jnp.sum(_token_by_token(*a) * probe),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+    g_got = jax.grad(lambda *a: jnp.sum(_chunked(*a, chunk)[0] * probe),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("q k v g beta".split(), g_got, g_want):
+        _close(a, b, f"gradient of {name}", rel=1e-4)
+
+
+def test_the_state_handed_back_is_the_rows_last():
+    """The state after the last REAL token: the closing tokens of a padded
+    chunk neither decay it nor write to it."""
+    q, k, v, g, beta = _inputs(21, 1, 1, 0.3, seed=3)
+    _, state = _chunked(q, k, v, g, beta, 8)
+    s = jnp.zeros((DK, DV))
+    for t in range(21):
+        s = jnp.exp(g[t, 0]) * s
+        s = s + jnp.outer(k[t, 0], beta[t, 0] * (v[t, 0] - s.T @ k[t, 0]))
+    _close(state[0], s, "state")
+
+
+def test_the_solves_stated_cotangent_is_autodiffs():
+    """dL = −Tᵀ dT Tᵀ against the transpose of the squarings themselves."""
+    low = jnp.tril(0.2 * jax.random.normal(jax.random.key(1), (3, 16, 16)),
+                   -1)
+    probe = jax.random.normal(jax.random.key(2), low.shape)
+    got = jax.grad(lambda x: jnp.sum(_unit_lower_inverse(x) * probe))(low)
+    want = jax.grad(lambda x: jnp.sum(
+        _solve_by_squaring(x) * probe))(low)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def test_a_checkpoint_that_keeps_the_solve_does_not_solve_again():
+    """Under ``jax.checkpoint`` the gradient's program holds the solve's
+    ten products at ``highest`` twice (forward, rematerialised forward)
+    plus the cotangent's two; with ``SOLVE_NAME`` saved, once plus two — as
+    without any checkpoint."""
+    args = [x[None] for x in _inputs(128, 2, 4, 0.3)]
+
+    def products(fn):
+        grad = jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2, 3, 4))
+        return str(jax.make_jaxpr(grad)(*args)).count("HIGHEST") // 2
+
+    def rule(*a):
+        return chunked_gated_delta_rule(*a, 64)[0]
+
+    keep = jax.checkpoint_policies.save_only_these_names(SOLVE_NAME)
+    assert products(rule) == 12
+    assert products(jax.checkpoint(rule)) == 22
+    assert products(jax.checkpoint(rule, policy=keep)) == 12
+
+
+@pytest.mark.parametrize("c", [1, 2, 5, 16, 64])
+def test_unit_lower_inverse_is_the_inverse(c):
+    # entries as the rule has them: β·(k_c·k_e)·decay of unit keys, under
+    # one in size (a matrix of unit normals here has an inverse of 2^c)
+    low = jnp.tril(0.2 * jax.random.normal(jax.random.key(c), (3, c, c)), -1)
+    got = _unit_lower_inverse(low)
+    want = jnp.linalg.inv(jnp.eye(c) + low)
+    np.testing.assert_allclose(got, want, rtol=2e-4,
+                               atol=2e-5 * float(jnp.max(jnp.abs(want))))

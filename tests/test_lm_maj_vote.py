@@ -26,6 +26,10 @@ from draco_tpu.parallel.token_loop import (  # noqa: E402
 TINY = os.path.join(ROOT, "benchmark", "testdata", "latent-moe-tiny.json")
 with open(TINY) as fh:
     SPEC = json.load(fh)["train_config"]["model_spec"]
+with open(os.path.join(ROOT, "benchmark", "testdata",
+                       "hybrid-moe-tiny.json")) as fh:
+    SPECS = {"LatentMoeLM": SPEC,
+             "HybridMoeLM": json.load(fh)["train_config"]["model_spec"]}
 STEPS = 4
 
 
@@ -49,8 +53,8 @@ def _cfg(network, **kw):
                 worker_fail=1, err_mode="rev_grad", seq_len=32, vocab=64,
                 lr=0.05, eval_freq=0, train_dir="", log_every=1,
                 max_steps=STEPS)
-    if network == "LatentMoeLM":
-        base.update(model_spec=SPEC)
+    if network in SPECS:
+        base.update(model_spec=SPECS[network])
     else:
         base.update(model_dim=32, model_heads=2, model_layers=1)
     base.update(kw)
@@ -65,7 +69,8 @@ def _run(cfg):
     return jax.tree.map(np.asarray, state.params), rows.rows
 
 
-@pytest.fixture(scope="module", params=["LatentMoeLM", "TransformerLM"])
+@pytest.fixture(scope="module", params=["LatentMoeLM", "TransformerLM",
+                                        "HybridMoeLM"])
 def runs(request):
     attacked = _run(_cfg(request.param))
     clean = _run(_cfg(request.param, adversary_count=0))
@@ -141,14 +146,16 @@ def test_the_chunked_loop_runs_the_same_steps():
     assert rows2[-1]["det_tp"] == 1.0
 
 
-@pytest.mark.parametrize("network", ["LatentMoeLM", "TransformerLM"])
+@pytest.mark.parametrize("network", ["LatentMoeLM", "TransformerLM",
+                                     "HybridMoeLM"])
 def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     """The large side of ``sp_step.LANES_IN_TURN_BYTES`` at the tiny size:
     lanes in turn (``lax.map``), each layer rematerialised, the stack in
     tiles (a d that is no multiple of 1024, so the last tile is closed with
     zeros), attack, finite check and fingerprints in one sweep a block at a
-    time, the leaves cut from the winner's row (here off whole lines: the
-    flat cut) — what the d = 425 M cell runs. Same verdicts, same training."""
+    time, the leaves cut from the winner's row (here off whole lines: each
+    from the lines that hold it) — what the d = 425 M cells run. Same
+    verdicts, same training."""
     from draco_tpu.coding import repetition
     from draco_tpu.parallel import sp_step
 
