@@ -62,7 +62,7 @@ from draco_tpu.models.latent_moe import (
     fold_stats, rms_norm,
 )
 from draco_tpu.ops.delta_rule import (
-    CHUNK, SOLVE_NAME, chunked_gated_delta_rule,
+    CHUNK, SOLVE_NAME, chunked_gated_delta_rule, rule_runs_in_kernels,
 )
 
 # the published config keys the block reads (model_spec must carry them)
@@ -159,7 +159,8 @@ def causal_depthwise_conv(x, taps):
 class HybridMoeLM(RoutedExpertLM):
     """The ``qwen3_next`` family's block (module docstring)."""
 
-    stat_names = STAT_NAMES + ("linattn_state_absmax",)
+    stat_names = STAT_NAMES + ("linattn_state_absmax",
+                               "linattn_kernel_layers")
     # conv taps: variance 1 / taps (fan-in); A_log: the family draws A from
     # uniform(0, 16) and stores its log — here log A ~ normal(0, 1), heads
     # of different memory, median A = 1
@@ -248,7 +249,8 @@ class HybridMoeLM(RoutedExpertLM):
 
     def _linear_attention(self, h, p, a_log, dt_bias):
         """-> (the layer's output (B, T, hidden), max |S| over heads of the
-        state the row leaves behind)."""
+        state the row leaves behind, 1.0 where the rule ran in the Pallas
+        kernels and 0.0 where it took the ``jax.numpy`` path)."""
         s = self.spec
         b, t, _ = h.shape
         hk, hv = s["linear_num_key_heads"], s["linear_num_value_heads"]
@@ -274,30 +276,32 @@ class HybridMoeLM(RoutedExpertLM):
         with jax.named_scope("draco_deltarule"):
             o, state = chunked_gated_delta_rule(q, k, v, g, beta, CHUNK)
             absmax = jnp.max(jnp.abs(lax.stop_gradient(state)))
+        kernels = jnp.float32(rule_runs_in_kernels(q.shape, v.shape, CHUNK))
         o = rms_norm(o, p["out_norm"]["scale"], s["rms_norm_eps"])
         o = o * jax.nn.silu(z)
-        return _dot(o.reshape(b, t, hv * dv), p["out"]["kernel"]), absmax
+        return (_dot(o.reshape(b, t, hv * dv), p["out"]["kernel"]),
+                (absmax, kernels))
 
     def _layer(self, x, p, heads, positions, kind: str):
         h = self.norm(x, p["attn_norm"])
         if kind == "full_attention":
             with jax.named_scope("draco_attn"):
                 x = x + self._gated_attention(h, p, positions)
-            absmax = None
+            rule = None
         else:
             with jax.named_scope("draco_linattn"):
-                mixed, absmax = self._linear_attention(h, p, *heads)
+                mixed, rule = self._linear_attention(h, p, *heads)
                 x = x + mixed
         b, t, d = x.shape
         y, stats = self._experts(x.reshape(b * t, d), p)
-        return y.reshape(b, t, d), (stats, absmax)
+        return y.reshape(b, t, d), (stats, rule)
 
     def hidden(self, params, tokens, pos_offset=0):
         """tokens (B, T) -> (the last layer's output (B, T, hidden), the
         ``stat_names`` counters)."""
         x = params["embed"]["embedding"][tokens].astype(self.dtype)
         positions = pos_offset + jnp.arange(tokens.shape[1])
-        per_layer, states, linear = [], [], 0
+        per_layer, rules, linear = [], [], 0
         for i, kind in enumerate(self.layer_types):
             heads = None
             if kind == "linear_attention":
@@ -310,12 +314,14 @@ class HybridMoeLM(RoutedExpertLM):
                 # the rule's triangular solve is kept, not solved again
                 # (34 MB a layer; solving again was a fifth of the rule)
                 fn = jax.checkpoint(fn, policy=KEEP_SOLVE)
-            x, (stats, absmax) = fn(x, params[f"layer{i}"], heads)
+            x, (stats, rule) = fn(x, params[f"layer{i}"], heads)
             per_layer.append(stats)
-            if absmax is not None:
-                states.append(absmax)
+            if rule is not None:
+                rules.append(rule)
         out = fold_stats(per_layer)
-        out["linattn_state_absmax"] = (
-            jnp.max(jnp.stack(states)) if states
-            else jnp.zeros((), jnp.float32))
+        absmax, kernels = (map(jnp.stack, zip(*rules)) if rules
+                           else (jnp.zeros((1,), jnp.float32),) * 2)
+        out["linattn_state_absmax"] = jnp.max(absmax)
+        # the DeltaNet layers whose rule ran in the Pallas kernels
+        out["linattn_kernel_layers"] = jnp.sum(kernels)
         return x, out

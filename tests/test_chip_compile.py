@@ -159,6 +159,28 @@ def _grouped_dot():
                 ((GMM_GROUPS,), jnp.int32)]
 
 
+# qwen3next.maj_vote_r3: one lane's row through a Gated DeltaNet layer's rule
+RULE_QK, RULE_V, RULE_G = (1, 4096, 16, 128), (1, 4096, 32, 128), (1, 4096, 32)
+
+
+def _delta_rule(grad):
+    """ops/delta_rule.py's kernels under the model's scope: the solve and
+    the pass forward; with ``grad`` also the backward."""
+    from draco_tpu.ops.delta_rule import chunked_gated_delta_rule
+
+    def fwd(q, k, v, g, beta):
+        with jax.named_scope("draco_deltarule"):
+            return chunked_gated_delta_rule(q, k, v, g, beta, force=True)
+
+    def bwd(*args):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(fwd(*a)[0])),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+
+    return (bwd if grad else fwd), (
+        [(RULE_QK, jnp.float32)] * 2 + [(RULE_V, jnp.float32)]
+        + [(RULE_G, jnp.float32)] * 2)
+
+
 CASES = {
     # auto selects these on the chip: the three presets' codes + the narrow
     # wire's regularized locator (n=9 s=2 is cyclic-vgg11, n=8 the LM runs)
@@ -176,6 +198,8 @@ CASES = {
     "flash_grad_qk192_v128": _flash_latent,
     "flash_grad_16_heads_on_2_d256": _flash_grouped_query,
     "grouped_dot_8_of_128": _grouped_dot,
+    "delta_rule_fwd": lambda: _delta_rule(grad=False),
+    "delta_rule_grad": lambda: _delta_rule(grad=True),
 }
 
 
@@ -187,6 +211,30 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     # a kernel that quietly became plain XLA would pass a compile
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+def test_every_kernel_of_the_rule_carries_the_rules_scope(one_chip):
+    """``deltarule_ms`` reads each instruction's innermost ``draco_*``
+    segment: in the compiled gradient the solve, the pass and the backward
+    kernel (its op is named ``transpose(jvp(draco_deltarule))``) all carry
+    ``draco_deltarule``. A kernel that lost the label would leave
+    ``deltarule_roofline`` nothing to divide by."""
+    import re
+
+    fn, specs = _delta_rule(grad=True)
+    text = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in specs]).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in calls]
+    assert len(names) == 3, names
+    assert all("draco_deltarule" in name for name in names), names
+    assert sum("transpose(jvp(draco_deltarule))" in name
+               for name in names) == 1, names
+    for kernel in ("solve_kernel", "pass_kernel", "backward_kernel"):
+        assert sum(kernel in name for name in names) == 1, names
 
 
 def _resnet18_step_text(chip) -> str:

@@ -27,22 +27,22 @@ sys.path.insert(0, ROOT)
 from benchmark.reference.nets import qwen3_next as ref  # noqa: E402
 from draco_tpu.ops.delta_rule import (  # noqa: E402
     SOLVE_NAME, _solve_by_squaring, _unit_lower_inverse,
-    chunked_gated_delta_rule,
+    chunked_gated_delta_rule, rule_runs_in_kernels,
 )
 
 pytestmark = pytest.mark.core
 DK, DV = 16, 8
 
 
-def _inputs(t, hk, hv, g_scale, seed=0):
+def _inputs(t, hk, hv, g_scale, seed=0, dk=DK, dv=DV):
     keys = jax.random.split(jax.random.key(seed), 5)
 
     def unit(x):
         return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    q = unit(jax.random.normal(keys[0], (t, hk, DK))) * DK ** -0.5
-    k = unit(jax.random.normal(keys[1], (t, hk, DK)))
-    v = jax.random.normal(keys[2], (t, hv, DV))
+    q = unit(jax.random.normal(keys[0], (t, hk, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(keys[1], (t, hk, dk)))
+    v = jax.random.normal(keys[2], (t, hv, dv))
     g = -g_scale * jax.nn.softplus(jax.random.normal(keys[3], (t, hv)))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (t, hv)))
     return q, k, v, g, beta
@@ -140,3 +140,107 @@ def test_unit_lower_inverse_is_the_inverse(c):
     want = jnp.linalg.inv(jnp.eye(c) + low)
     np.testing.assert_allclose(got, want, rtol=2e-4,
                                atol=2e-5 * float(jnp.max(jnp.abs(want))))
+
+
+# ---- the kernels (interpret mode) against the jax.numpy path ---------------
+
+def _lane_inputs(t, hk, hv, g_scale, d=128):
+    """``_inputs`` at head sizes of ``d`` (whole lane tiles at 128), a
+    batch of one."""
+    return [x[None] for x in _inputs(t, hk, hv, g_scale, dk=d, dv=d)]
+
+
+def _both_paths(args, **kernel_kw):
+    """(o, state, five gradients) of the ``jax.numpy`` path and of the call
+    with ``kernel_kw``; both outputs take a cotangent."""
+    o, state = chunked_gated_delta_rule(*args)
+    probes = (jax.random.normal(jax.random.key(9), o.shape),
+              jax.random.normal(jax.random.key(10), state.shape))
+
+    def run(**kw):
+        def loss(*a):
+            o, state = chunked_gated_delta_rule(*a, **kw)
+            return jnp.sum(o * probes[0]) + jnp.sum(state * probes[1])
+
+        return (chunked_gated_delta_rule(*args, **kw)
+                + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args))
+
+    return run(), run(**kernel_kw)
+
+
+@pytest.mark.parametrize("t,hk,hv,g_scale", [
+    (64, 1, 1, 0.3),    # one chunk, one value head a key head
+    (128, 1, 2, 0.1),   # two chunks in one grid step, Hv = 2 Hk
+    (192, 2, 2, 1.0),   # three chunks, one a grid step: the state crosses
+    (256, 2, 4, 0.05),  # two grid steps of two chunks, slow decay
+    (128, 2, 4, 40.0),  # strongly negative g: exp(+2 560) would overflow
+    (64, 8, 8, 0.5),    # a whole sublane tile of key heads a grid step
+    (64, 16, 16, 0.5),  # two grid steps of eight key heads
+])
+def test_kernels_are_the_jnp_path(t, hk, hv, g_scale):
+    args = _lane_inputs(t, hk, hv, g_scale)
+    assert rule_runs_in_kernels(args[0].shape, args[2].shape, interpret=True)
+    want, got = _both_paths(args, interpret=True)
+    names = "o state dq dk dv dg dbeta".split()
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        _close(a, b, name, rel=2e-5)
+
+
+@pytest.mark.parametrize("t,d,chunk", [
+    (100, 128, 64),  # T not whole chunks
+    (128, 64, 64),   # head size under a lane tile
+    (128, 128, 32),  # not the family's chunk
+])
+def test_shapes_the_kernels_do_not_take_are_the_jnp_path(t, d, chunk,
+                                                         monkeypatch):
+    """The kernel is not chosen — a call to it would raise here — and the
+    result is today's, bit for bit, whatever ``interpret`` says."""
+    from draco_tpu.ops import delta_rule
+
+    args = _lane_inputs(t, 1, 2, 0.2, d=d)
+    assert not rule_runs_in_kernels(args[0].shape, args[2].shape, chunk,
+                                    force=True)
+
+    def refuse(*a, **kw):
+        raise AssertionError("the kernels were chosen")
+
+    monkeypatch.setattr(delta_rule, "_rule", refuse)
+    want = chunked_gated_delta_rule(*args, chunk)
+    got = chunked_gated_delta_rule(*args, chunk, interpret=True)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_off_the_chip_the_kernels_are_not_chosen():
+    args = _lane_inputs(128, 1, 2, 0.2)
+    assert not rule_runs_in_kernels(args[0].shape, args[2].shape)
+    assert not rule_runs_in_kernels(args[0].shape, args[2].shape,
+                                    interpret=True, force=False)
+
+
+def test_a_checkpoint_that_keeps_the_solve_runs_the_pass_again_only():
+    """On the kernel path the solve is its own kernel and T carries
+    ``SOLVE_NAME``: the gradient's program is three kernels (solve, pass,
+    backward); under a checkpoint five (solve and pass again); with the
+    name saved four — the rematerialised forward is the pass alone."""
+    args = _lane_inputs(128, 1, 2, 0.2)
+
+    def rule(*a):
+        return chunked_gated_delta_rule(*a, interpret=True)[0]
+
+    def kernels(fn):
+        grad = jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2, 3, 4))
+        return str(jax.make_jaxpr(grad)(*args)).count("pallas_call[")
+
+    keep = jax.checkpoint_policies.save_only_these_names(SOLVE_NAME)
+    assert kernels(rule) == 3
+    assert kernels(jax.checkpoint(rule)) == 5
+    assert kernels(jax.checkpoint(rule, policy=keep)) == 4
+    plain = jax.grad(lambda *a: jnp.sum(jnp.sin(rule(*a))),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    kept = jax.grad(jax.checkpoint(
+        lambda *a: jnp.sum(jnp.sin(rule(*a))), policy=keep),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(plain, kept):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-9)

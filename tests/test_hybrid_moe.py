@@ -114,6 +114,8 @@ def test_loss_and_logits_match_the_reference(model):
     assert lm.stat_names[:4] == latent_moe.STAT_NAMES
     assert float(stats["moe_dropped"]) == 0.0
     assert float(stats["linattn_state_absmax"]) > 0.0
+    # off the chip the rule takes the jax.numpy path in every layer
+    assert float(stats["linattn_kernel_layers"]) == 0.0
 
 
 def test_every_leafs_gradient_matches_the_reference(model):
@@ -155,6 +157,77 @@ def test_the_state_counter_grows_when_the_heads_forget_less(model):
     _, held = _loss(lm, slow, toks)
     assert float(held["linattn_state_absmax"]) > \
         float(stats["linattn_state_absmax"])
+
+
+# the tiny block with DeltaNet heads of a whole lane tile and a row of two
+# chunks: the shapes the rule's kernels take
+LANE_SPEC = dict(SPEC, linear_key_head_dim=128, linear_value_head_dim=128)
+LANE_T = 128
+
+
+@pytest.fixture
+def rule_in_kernels(monkeypatch):
+    """The model's two names for the rule — the call and the question which
+    path it takes — told ``interpret=True``: the kernels, off the chip."""
+    import functools
+
+    for name in ("chunked_gated_delta_rule", "rule_runs_in_kernels"):
+        monkeypatch.setattr(hybrid_moe, name, functools.partial(
+            getattr(hybrid_moe, name), interpret=True))
+
+
+def test_deltanet_layer_in_the_kernels_matches_the_reference(
+        rule_in_kernels):
+    """The layer's output and the gradient of its input, of every leaf and
+    of the per-head vectors, the rule in the Pallas kernels (interpret
+    mode), against ``qwen3_next.gated_deltanet`` token by token."""
+    lm = HybridMoeLM(LANE_SPEC)
+    params = _moved(lm.init(jax.random.key(11)), jax.random.key(12))
+    p = params["layer1"]
+    heads = (params["linear_heads"]["A_log"][1],
+             params["linear_heads"]["dt_bias"][1])
+    h = jax.random.normal(jax.random.key(13),
+                          (LANE_T, SPEC["hidden_size"]))
+    probe = jax.random.normal(jax.random.key(14), h.shape)
+
+    def program(h, p, heads):
+        out, (absmax, kernels) = lm._linear_attention(h[None], p, *heads)
+        return jnp.sum(out[0] * probe), (out[0], absmax, kernels)
+
+    def reference(h, p, heads):
+        out = ref.gated_deltanet(h, p, heads, LANE_SPEC, lambda t: t)
+        return jnp.sum(out * probe), out
+
+    (_, (got, absmax, kernels)), g_got = jax.value_and_grad(
+        program, argnums=(0, 1, 2), has_aux=True)(h, p, heads)
+    (_, want), g_want = jax.value_and_grad(
+        reference, argnums=(0, 1, 2), has_aux=True)(h, p, heads)
+    assert float(kernels) == 1.0 and float(absmax) > 0.0
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(
+        jnp.max(jnp.abs(want))))
+    flat_want = jax.tree.leaves(g_want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(g_got),
+                            flat_want):
+        # (the layer's leaves the mixer does not read take none, in both)
+        scale = float(jnp.max(jnp.abs(w)))
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-3 * scale + 1e-9, path
+    mixer = ("qkvz", "ba", "conv", "out_norm", "out")
+    assert all(float(jnp.max(jnp.abs(x))) > 0.0
+               for key in mixer for x in jax.tree.leaves(g_got[1][key]))
+
+
+def test_the_counter_counts_the_layers_whose_rule_ran_in_the_kernels(
+        rule_in_kernels):
+    lm = HybridMoeLM(LANE_SPEC)
+    params = lm.init(jax.random.key(15))
+    toks = _tokens(5, batch=1)[:, :64]
+    _, stats = lm.hidden(params, toks)
+    assert float(stats["linattn_kernel_layers"]) == \
+        lm.layer_types.count("linear_attention") == 3
+    # the tiny block's own heads (16 wide) stay on the jax.numpy path
+    small = HybridMoeLM(SPEC)
+    _, stats = small.hidden(small.init(jax.random.key(15)), toks)
+    assert float(stats["linattn_kernel_layers"]) == 0.0
 
 
 def test_the_32_shares_add_up_to_the_uncut_layer():
@@ -345,7 +418,8 @@ def test_the_network_is_built_on_the_normal_path():
     cfg = _cfg().validate()
     lm = build_lm(cfg)
     assert isinstance(lm, HybridMoeLM) and lm.remat == cfg.remat
-    assert lm.stat_names[-1] == "linattn_state_absmax"
+    assert lm.stat_names[-2:] == ("linattn_state_absmax",
+                                  "linattn_kernel_layers")
 
 
 @pytest.mark.parametrize("kw,names", [

@@ -10,9 +10,9 @@ instructions inside it do. It drives ``benchmark.harness.runner.run_cell``
 as ``benchmark/run.py --trace 1`` does, keeps the capture and the route's
 innermost-scope map, and writes ``chiprun_out/inner_scope_ops_<tag>.json``
 (every scope's total, the scope's instructions in ms per traced step with
-their result shapes, the run's metrics, and the largest value any step's
-record held of each of the model's counters — the ``ledger:`` lines print
-medians) and the compiled step program's text beside it
+their result shapes, the run's metrics, and the largest and the smallest
+value any step's record held of each of the model's counters — the
+``ledger:`` lines print medians) and the compiled step program's text beside it
 (``step_hlo_<tag>.txt.gz``: what shapes the program holds).
 Edits nothing of the benchmark; a TPU or exit 1, as the benchmark.
 """
@@ -81,6 +81,8 @@ def main(argv) -> int:
                for scope, of in ops.items()},
            "counters_max": {k: max(r[k] for r in kept["records"].rows)
                             for k in kept["counters"]},
+           "counters_min": {k: min(r[k] for r in kept["records"].rows)
+                            for k in kept["counters"]},
            "steps": len(kept["records"].rows),
            "correct": result["correct"], "metrics": result["metrics"],
            "device": result["device"]}
@@ -92,7 +94,8 @@ def main(argv) -> int:
                                 f"step_hlo_{tag}.txt.gz"), "wt") as fh:
         fh.write(kept["hlo"])
     print(json.dumps({k: out[k] for k in (
-        "correct", "metrics", "scope_ms_per_step", "counters_max", "steps")}))
+        "correct", "metrics", "scope_ms_per_step", "counters_max",
+        "counters_min", "steps")}))
     for scope in scopes:
         print(f"-- {scope}")
         for label, ms in out["ops_ms_per_step"][scope][:40]:
