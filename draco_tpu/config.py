@@ -26,18 +26,21 @@ AGG_MODES = ("normal", "geometric_median", "krum", "coord_median",
 # Networks that train on token sequences through the shared token loop
 # (parallel/token_loop.py) and come from models.build_lm; everything else is
 # an image model on the CNN Trainer.
-TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM", "HybridMoeLM")
+TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM", "HybridMoeLM",
+                  "WindowedMoeLM")
 # the token models stated by ONE mapping of a published config's keys
 # (TrainConfig.model_spec), each with the module that checks and builds it
 SPEC_NETWORKS = {"LatentMoeLM": "draco_tpu.models.latent_moe",
-                 "HybridMoeLM": "draco_tpu.models.hybrid_moe"}
+                 "HybridMoeLM": "draco_tpu.models.hybrid_moe",
+                 "WindowedMoeLM": "draco_tpu.models.windowed_moe"}
 
 
 @dataclasses.dataclass
 class TrainConfig:
     # --- model / data (reference: distributed_nn.py:27-37) ---
     # LeNet | FC | ResNet18/34/50/101/152 | VGG11/13/16/19[_bn] | the token
-    # models TransformerLM | LatentMoeLM | HybridMoeLM (TOKEN_NETWORKS)
+    # models TransformerLM | LatentMoeLM | HybridMoeLM | WindowedMoeLM
+    # (TOKEN_NETWORKS)
     network: str = "LeNet"
     dataset: str = "MNIST"  # MNIST | Cifar10 | synthetic variants
     data_dir: str = "./data"
@@ -173,10 +176,11 @@ class TrainConfig:
     model_dim: int = 128
     model_heads: int = 4
     model_layers: int = 2
-    # network=LatentMoeLM | HybridMoeLM (SPEC_NETWORKS): the ONE mapping
-    # that states the model — a published config.json's keys verbatim plus
-    # ``layers``, ``experts_held`` ([first, count]) and ``vocab_rows``, the
-    # chip's share of a deployment (models/latent_moe.py, hybrid_moe.py).
+    # network=LatentMoeLM | HybridMoeLM | WindowedMoeLM (SPEC_NETWORKS): the
+    # ONE mapping that states the model — a published config.json's keys
+    # verbatim plus ``layers``, ``experts_held`` ([first, count]) and
+    # ``vocab_rows``, the chip's share of a deployment (models/latent_moe.py,
+    # hybrid_moe.py, windowed_moe.py).
     # The model_* fields above are TransformerLM's and are not read for it.
     # CLI: --model-spec <file.json>.
     model_spec: Optional[dict] = None
@@ -1081,8 +1085,8 @@ class TrainConfig:
             if self.seq_len < 2 or self.vocab < 2:
                 raise ValueError("TransformerLM needs seq_len >= 2 and vocab >= 2")
         elif self.model_spec is not None:
-            raise ValueError("model_spec requires network=LatentMoeLM or "
-                             "HybridMoeLM")
+            raise ValueError("model_spec requires network="
+                             + " | ".join(SPEC_NETWORKS))
         elif self.seq_shards > 1:
             raise ValueError("seq_shards > 1 requires network=TransformerLM")
         elif self.tensor_shards > 1:

@@ -9,14 +9,17 @@ collectives + latency hiding), so there is exactly one copy of each model here.
 Token models (``TOKEN_NETWORKS``, config.py) come from :func:`build_lm`, the
 one factory the LM step builders call: ``TransformerLM`` (the repo's own
 pre-LN / GELU / tied-head block, the only one the tp / ep / pp / sequence-
-sharded routes build) and the two blocks that state a published config on
+sharded routes build) and the three blocks that state a published config on
 the single-shard route of parallel/sp_step.py (``config.SPEC_NETWORKS``):
 ``LatentMoeLM`` (models/latent_moe.py: RMS norm, SwiGLU, latent key/value
 attention, sigmoid top-k routing without drops over the experts this chip
 holds, shared experts, untied head) and ``HybridMoeLM`` (models/
 hybrid_moe.py: Gated DeltaNet linear-attention layers beside gated
 grouped-query softmax attention, softmax routing, a gated shared expert —
-over the same expert layer).
+over the same expert layer) and ``WindowedMoeLM`` (models/windowed_moe.py:
+grouped-query attention under a sliding window three layers in four and
+over the whole row every fourth, rotary parameters by the layer's kind,
+softmax routing and no shared expert — over the same expert layer).
 """
 
 from draco_tpu.config import SPEC_NETWORKS, TOKEN_NETWORKS
@@ -124,8 +127,9 @@ def build_lm(cfg, attn_fn=None, kernel_fn=None):
     parallel wrappers included; equal head sizes), and ``kernel_fn``, the
     bare single-device kernel, which the published-config blocks take
     (``LatentMoeLM``'s q/k and v differ in head size, ``HybridMoeLM``'s
-    key/value heads are fewer than its query heads). None is each model's
-    plain lowering."""
+    key/value heads are fewer than its query heads, ``WindowedMoeLM`` hands
+    each layer's call its own ``window=``). None is each model's plain
+    lowering."""
     import importlib
 
     import jax.numpy as jnp

@@ -59,7 +59,7 @@ from jax import lax
 
 from draco_tpu.models.latent_moe import (
     EMBED_STD, STAT_NAMES, MoeSpec, RoutedExpertLM, _dot, _operand,
-    fold_stats, rms_norm,
+    fold_stats, rms_norm, rope_half,
 )
 from draco_tpu.ops.delta_rule import (
     CHUNK, SOLVE_NAME, chunked_gated_delta_rule, rule_runs_in_kernels,
@@ -133,19 +133,6 @@ def layer_types(spec: dict) -> list:
             for i in range(spec["layers"])]
 
 
-def rope_half(x, positions, theta, rotary: int):
-    """Rotate the pairs (x[i], x[i + rotary/2]) of the first ``rotary``
-    dims of the last axis by positions·theta^(-2i/rotary); the rest pass.
-    x: (B, T, H, dim), positions: (T,)."""
-    half = rotary // 2
-    freqs = theta ** (-np.arange(0, rotary, 2, dtype=np.float32) / rotary)
-    ang = positions.astype(jnp.float32)[:, None] * freqs  # (T, half)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    a, b = x[..., :half], x[..., half:rotary]
-    return jnp.concatenate(
-        [a * cos - b * sin, b * cos + a * sin, x[..., rotary:]], axis=-1)
-
-
 def causal_depthwise_conv(x, taps):
     """y_t = Σ_j taps[j] ⊙ x_{t − (K − 1) + j}, zeros before the row's
     start. x (B, T, channels), taps (K, channels)."""
@@ -175,7 +162,7 @@ class HybridMoeLM(RoutedExpertLM):
             experts=spec["num_experts"], top_k=spec["num_experts_per_tok"],
             first=spec["experts_held"][0], held=spec["experts_held"][1],
             scoring="softmax", norm_topk=spec["norm_topk_prob"], scale=1.0,
-            gated_shared=True), attn_fn, dtype, remat)
+            shared="gated"), attn_fn, dtype, remat)
         self.layer_types = layer_types(spec)
 
     def norm(self, x, p):
@@ -239,10 +226,18 @@ class HybridMoeLM(RoutedExpertLM):
         q, gate = qg[..., :dh], qg[..., dh:]
         k = _dot(h, p["k"]["kernel"]).reshape(b, t, kv, dh)
         v = _dot(h, p["v"]["kernel"]).reshape(b, t, kv, dh)
-        q = rope_half(self.norm(q, p["q_norm"]).astype(jnp.float32),
-                      positions, s["rope_theta"], rotary)
-        k = rope_half(self.norm(k, p["k_norm"]).astype(jnp.float32),
-                      positions, s["rope_theta"], rotary)
+
+        def rotate(x):
+            # the first ``rotary`` dims at the default frequencies; the
+            # table is made anew a call, so that q's and k's stay two
+            # constants of the step (one shared table compiles to another
+            # program than PR 34's: PERF.md section 6, PR 35)
+            freqs = s["rope_theta"] ** (
+                -np.arange(0, rotary, 2, dtype=np.float32) / rotary)
+            return rope_half(x.astype(jnp.float32), positions, freqs)
+
+        q = rotate(self.norm(q, p["q_norm"]))
+        k = rotate(self.norm(k, p["k_norm"]))
         o = self.attn_fn(_operand(q), _operand(k), _operand(v))
         o = o.astype(h.dtype) * jax.nn.sigmoid(gate)
         return _dot(o.reshape(b, t, heads * dh), p["o"]["kernel"])

@@ -45,11 +45,23 @@ in the likely case); the counter ``moe_full_dispatch`` says how many such
 further buffers the step's expert layers ran.
 
 The expert layer, the head and the loss are ``RoutedExpertLM``'s, the base
-both published-config models build on (models/hybrid_moe.py is the other):
-ONE ``_route`` / ``_buffer`` / ``grouped_dot`` path, told by ``MoeSpec``
-which score function ranks the experts (``sigmoid`` with the selection bias
-and the routed scale, or ``softmax``), whether the shared expert is under a
-sigmoid gate, and which experts this chip holds.
+every published-config model builds on (models/hybrid_moe.py and
+models/windowed_moe.py are the others): ONE ``_choose`` (scores, top-k,
+combine weights) and ONE ``_route`` / ``_buffer`` / ``grouped_dot`` path,
+told by ``MoeSpec`` which score function ranks the experts (``sigmoid``
+with the selection bias and the routed scale, or ``softmax``), whether the
+model has a shared expert at all — one that has none carries no ``shared``
+leaf and adds the routed part alone — and whether it is under a sigmoid
+gate, and which experts this chip holds.
+
+A model whose held experts each expect a large share of the tokens
+(``DENSE_SHARE``: top-8 of 64 is an eighth) says ``MoeSpec.dense``: its
+held experts run over EVERY row as three plain products
+(``_every_token``), a row's combine weight zero where it did not choose
+the expert — the same sum, no sort, no buffer, no gather, no scatter-add,
+and work that no routing moves: the sorted path's C-row gathers and
+scatter-adds cost more there than the 8 x products do, and run faster or
+slower by the index pattern (PERF.md section 6, PR 35).
 
 Device scopes (nested in the step's ``draco_comp``): ``draco_attn`` (MLA
 whole), ``draco_route`` (scores, top-k, sort, gather, combine),
@@ -66,6 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from draco_tpu.ops.coded import use_pallas
 from draco_tpu.ops.flash_attention import spread_kv_heads
@@ -105,6 +118,22 @@ BIAS_STD = 0.02  # e_score_correction_bias: moves the top-6, not the load
 # rows, not a wrong result.
 DISPATCH_SHARE = 4
 ROW_TILE = 256  # the grouped product's row tile; C is a multiple of it
+# A model whose held experts each expect at least this share of the tokens
+# (top_k / experts) may run them over EVERY token (``MoeSpec.dense``;
+# models/windowed_moe.py does), the rows that did not choose an expert under
+# weight zero (``RoutedExpertLM._every_token``): no sort, no buffer, no
+# gather, no scatter-add, three plain products a layer whatever the routing.
+# At an eighth the plain products do 8 x the chosen pairs' work and the step
+# is 20-30 ms FASTER than on the sorted path, whose C-row gathers and
+# scatter-adds cost more than its grouped products and follow the index
+# pattern (PERF.md section 6, PR 35: measured at this one point); at
+# kanana2's 1/21 and qwen3next's 1/51 they would do 21 x and 51 x.
+DENSE_SHARE = 1 / 8
+# the plain products' results a rematerialised layer keeps (the backward
+# pass reads them; recomputing them costs the cell 18 ms a step, keeping
+# them 0.54 GB)
+DENSE_NAME = "draco_dense_experts"
+KEEP_DENSE = jax.checkpoint_policies.save_only_these_names(DENSE_NAME)
 # per-step counters of the expert layers (token-expert pairs that landed on
 # the experts held, over all expert layers; the fullest held expert over the
 # mean one; pairs that got no row; dispatch buffers run beyond each layer's
@@ -176,17 +205,33 @@ def rope_interleaved(x, positions, theta):
     return out.reshape(x.shape)
 
 
-def dense_causal_attention(q, k, v):
+def rope_half(x, positions, freqs, factor: float = 1.0):
+    """Rotate the pairs (x[i], x[i + n]) of the first 2n dims of the last
+    axis by positions·freqs[i], n = len(freqs), cos and sin times
+    ``factor``; the dims past 2n pass. x: (B, T, H, dim), positions: (T,)."""
+    half = len(freqs)
+    ang = positions.astype(jnp.float32)[:, None] * freqs  # (T, half)
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    a, b = x[..., :half], x[..., half:2 * half]
+    parts = [a * cos - b * sin, b * cos + a * sin]
+    if 2 * half < x.shape[-1]:
+        parts.append(x[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1)
+
+
+def dense_causal_attention(q, k, v, window=None):
     """(B, T, H, Dh) q, k and (B, T, H, Dv) v -> (B, T, H, Dv): the plain
-    lowering where no kernel is selected. k and v may have fewer heads
-    (grouped-query attention)."""
-    t = q.shape[1]
+    lowering where no kernel is selected (parallel/ring_attention.
+    dense_attention, the one the kernels fall back to). k and v may have
+    fewer heads (grouped-query attention). ``window``: a query sees itself
+    and the ``window - 1`` tokens before it; None sees every earlier
+    token."""
+    from draco_tpu.parallel.ring_attention import dense_attention
+
     k, v = spread_kv_heads(q.shape[2], k, v)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
-    mask = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    return dense_attention(q, k, v, window=window)
 
 
 def _dot(x, kernel):
@@ -258,7 +303,9 @@ def grouped_dot(xs, kernels, sizes, held: int):
 
 
 class MoeSpec(NamedTuple):
-    """What the shared expert layer is told about its model."""
+    """What the shared expert layer is told about its model. A model may
+    have no shared expert (``shared`` None): its layers then carry no
+    ``shared`` leaves and add the routed experts' part alone."""
 
     experts: int  # routed experts of the deployment: the router's width
     top_k: int  # experts a token takes, of all of them
@@ -270,7 +317,12 @@ class MoeSpec(NamedTuple):
     scoring: str
     norm_topk: bool  # weights renormalised over the chosen top_k
     scale: float  # the routed experts' weights times this
-    gated_shared: bool  # shared expert times sigmoid(h · w_sg)
+    # the shared expert every token takes: "plain" (added as it is),
+    # "gated" (times sigmoid(h · w_sg)), or None (the model has none)
+    shared: str | None
+    # the held experts run over every token (``_every_token``) instead of
+    # the sorted pairs' buffers: the model says so (``DENSE_SHARE``)
+    dense: bool = False
 
 
 class RoutedExpertLM:
@@ -330,14 +382,11 @@ class RoutedExpertLM:
         share = -(-DISPATCH_SHARE * pairs * m.held // m.experts)
         return min(pairs, -(-share // ROW_TILE) * ROW_TILE)
 
-    def _route(self, h, p):
-        """h (N, hidden) -> (N, k) combine weights (zero where the chosen
-        expert is not held); the dispatch, all of it scalars: the (token,
-        choice) pairs' sorted order (this chip's experts first), the
-        groups' sizes, the count of pairs that landed here; the count of
-        dispatch buffers that hold a landed pair; and the counters."""
+    def _choose(self, h, p):
+        """h (N, hidden) -> the (N, k) experts each row takes, of all of
+        them, and their (N, k) combine weights."""
         m = self.moe
-        k, first, held, n_exp = m.top_k, m.first, m.held, m.experts
+        k, n_exp = m.top_k, m.experts
         logits = jnp.matmul(h.astype(jnp.float32), p["kernel"],
                             precision=lax.Precision.HIGHEST)
         if m.scoring == "sigmoid":
@@ -354,7 +403,18 @@ class RoutedExpertLM:
                               scores[:, None, :], 0.0), axis=-1)
         if m.norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        w = w * m.scale
+        return w * m.scale, chosen
+
+    def _route(self, h, p):
+        """h (N, hidden) -> (N, k) combine weights (zero where the chosen
+        expert is not held); the dispatch, all of it scalars: the (token,
+        choice) pairs' sorted order (this chip's experts first), the
+        groups' sizes, the count of pairs that landed here; the count of
+        dispatch buffers that hold a landed pair; and the counters."""
+        m = self.moe
+        first, held, n_exp = m.first, m.held, m.experts
+        w, chosen = self._choose(h, p)
+        experts = jnp.arange(n_exp, dtype=chosen.dtype)
         # this chip's experts become groups 0..held-1, the others follow
         group = (chosen.reshape(-1) - first) % n_exp
         order = jnp.argsort(group)  # stable: arrival order within a group
@@ -408,9 +468,42 @@ class RoutedExpertLM:
                 weight[:, None].astype(h.dtype) * ys, token,
                 num_segments=h.shape[0])
 
+    def _every_token(self, h, chosen, w, e):
+        """What the held experts add to the routed output (N, hidden) when
+        each runs over every row: gate and up each ONE product against
+        the held experts' matrices side by side, the rows' combine weights
+        (zero where a row did not choose the expert) on the result, and
+        the down product summing over experts and width at once. Returns
+        it with the held experts' loads."""
+        m = self.moe
+        slots = m.first + jnp.arange(m.held, dtype=chosen.dtype)
+        with jax.named_scope("draco_route"):
+            took = chosen[..., None] == slots  # (N, k, held)
+            weight = jnp.sum(jnp.where(took, w[..., None], 0.0), axis=1)
+            load = jnp.sum(took, axis=(0, 1), dtype=jnp.int32)
+        with jax.named_scope("draco_experts"):
+            xs = _operand(h)
+
+            def product(kernel):
+                return checkpoint_name(jnp.einsum(
+                    "nd,edf->nef", xs, kernel.astype(xs.dtype),
+                    preferred_element_type=jnp.float32), DENSE_NAME)
+
+            # silu written out, so that what ``KEEP_DENSE`` keeps is the
+            # two named results and nothing a jitted function made of them
+            gate = product(e["gate"]["kernel"])
+            mid = (gate * lax.logistic(gate) * product(e["up"]["kernel"])
+                   * weight[..., None])
+            mid = _operand(mid.reshape(h.shape[0], -1).astype(h.dtype))
+            down = e["down"]["kernel"].astype(mid.dtype)
+            ys = jnp.dot(mid, down.reshape(-1, down.shape[-1]),
+                         preferred_element_type=jnp.float32)
+        return ys.astype(h.dtype), load
+
     def _experts(self, x, p):
-        """x (N, hidden) -> x + the routed (held) and shared experts of its
-        normalised rows; the norm counts as the experts' (it feeds them)."""
+        """x (N, hidden) -> x + the routed (held) and, where the model has
+        one, the shared expert of its normalised rows; the norm counts as
+        the experts' (it feeds them)."""
         with jax.named_scope("draco_experts"):
             h = self.norm(x, p["mlp_norm"])
             # the products' operands, once a layer: every buffer reads the
@@ -418,12 +511,21 @@ class RoutedExpertLM:
             # compiler hoists a second set out of it and keeps it alive
             # through the step: +0.2 GB of peak memory)
             e = jax.tree.map(_operand, p["experts"])
-        with jax.named_scope("draco_route"):
-            w, dispatch, needed, stats = self._route(h, p["router"])
-        routed = routed_experts(self._buffer, h, w, e, dispatch, needed)
+        if self.moe.dense:
+            with jax.named_scope("draco_route"):
+                w, chosen = self._choose(h, p["router"])
+            routed, load = self._every_token(h, chosen, w, e)
+            stats = {"load": load.astype(jnp.float32),
+                     "dropped": jnp.float32(0), "further": jnp.float32(0)}
+        else:
+            with jax.named_scope("draco_route"):
+                w, dispatch, needed, stats = self._route(h, p["router"])
+            routed = routed_experts(self._buffer, h, w, e, dispatch, needed)
         with jax.named_scope("draco_experts"):
+            if self.moe.shared is None:
+                return x + routed, stats
             shared = swiglu(h, p["shared"])
-            if self.moe.gated_shared:
+            if self.moe.shared == "gated":
                 shared = jax.nn.sigmoid(
                     _dot(h, p["shared_gate"]["kernel"])) * shared
             return x + (routed + shared), stats
@@ -467,7 +569,7 @@ class LatentMoeLM(RoutedExpertLM):
             top_k=spec["num_experts_per_tok"],
             first=spec["experts_held"][0], held=spec["experts_held"][1],
             scoring="sigmoid", norm_topk=spec["norm_topk_prob"],
-            scale=spec["routed_scaling_factor"], gated_shared=False),
+            scale=spec["routed_scaling_factor"], shared="plain"),
             attn_fn, dtype, remat)
 
     def norm(self, x, p):

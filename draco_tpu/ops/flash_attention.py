@@ -15,6 +15,18 @@ materialising (T, T).
 Block-causal skipping: grid steps with j > i (keys entirely in the future)
 compute nothing (`pl.when`), so causal attention does ~half the block work.
 
+Sliding window (``flash_attention(..., window=W)``): query t sees key s iff
+0 <= t - s < W — itself and the W - 1 tokens before it. A (query block,
+key block) pair is computed iff some pair of its positions satisfies BOTH
+inequalities: the block's earliest key is no later than its latest query
+(causality, as above) and its latest key is less than W before its earliest
+query. Every other block is skipped from both sides, in the forward kernel
+and in both backward kernels, and the residency maps clamp the block index
+from both sides so that a skipped block is not fetched either; the two
+inequalities are applied elementwise inside every computed block (only the
+blocks at the two edges hold masked entries). ``window=None`` is the causal
+program, unchanged.
+
 No reference counterpart (the reference is CNN-only, SURVEY.md §5.7); this
 is a hot-op kernel of the TPU build's long-context axis, complementing ring
 attention (which shards T across chips; this kernel serves each shard or the
@@ -37,6 +49,14 @@ from draco_tpu.ops.coded import use_pallas
 
 NEG_INF = -1e30
 _LANE = 128
+BLOCK_Q = 512  # the query block's default limit
+# under a window a query block computes the key blocks that its
+# window + block_q - 1 keys touch: at W = 1024 two key blocks of 1024
+# whether it holds 512 queries or 1024, so the taller block halves the grid
+# steps a query pays (a layer-lane of 32 heads at T = 8192 on the chip:
+# forward 3.31 -> 2.75 ms, forward + backward 12.79 -> 11.15; PERF.md
+# section 6, PR 35)
+WINDOW_BLOCK_Q = 1024
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -61,7 +81,19 @@ def _fit_block(limit: int, t: int, lane_rule: bool) -> int:
     return 0
 
 
-def _kv_residency_map(bq: int, bk: int, causal: bool):
+def _first_k_block(i, bq: int, bk: int, window: int):
+    """The first key block a windowed query block i computes: the one that
+    holds its earliest query's earliest key, i*bq - (window - 1)."""
+    return jnp.maximum(i * bq - (window - 1), 0) // bk
+
+
+def _last_q_block(j, bq: int, bk: int, window: int, nq: int):
+    """The last query block a windowed key block j computes: the one that
+    holds its latest key's latest query, j*bk + bk - 1 + (window - 1)."""
+    return jnp.minimum((j * bk + bk + window - 2) // bq, nq - 1)
+
+
+def _kv_residency_map(bq: int, bk: int, causal: bool, window=None):
     """Index map for K/V-row input blocks on a (g, <q-block>, <k-block>)
     grid. Causal: clamp at the diagonal — the kernels' pl.when already
     skips compute for j > (i*bq + bq - 1)//bk (the largest k-block with any
@@ -70,21 +102,31 @@ def _kv_residency_map(bq: int, bk: int, causal: bool):
     Repeating the boundary index instead makes consecutive skipped steps
     fetch nothing (Mosaic elides copies when the block index is unchanged).
     The clamp is the identity on every computed block, so outputs are
-    untouched; keep this formula in lockstep with the kernels' guards."""
+    untouched; keep this formula in lockstep with the kernels' guards.
+    ``window``: clamped from below too, at the first block the window
+    reaches (_first_k_block)."""
     if not causal:
         return lambda g, i, j: (g, j, 0)
-    return lambda g, i, j: (g, jnp.minimum(j, (i * bq + bq - 1) // bk), 0)
+    if window is None:
+        return lambda g, i, j: (g, jnp.minimum(j, (i * bq + bq - 1) // bk), 0)
+    return lambda g, i, j: (g, jnp.clip(
+        j, _first_k_block(i, bq, bk, window), (i * bq + bq - 1) // bk), 0)
 
 
-def _q_residency_map(bq: int, bk: int, causal: bool):
+def _q_residency_map(bq: int, bk: int, causal: bool, window=None, nq=0):
     """Index map for Q-row input blocks (q, do, per-row stats) on the dk/dv
     grid (g, <k-block>, <q-block>). Causal: the sweep only computes from the
     first diagonal-touching q block, i_min = (j*bk)//bq — which equals
     ceil((j*bk - bq + 1)/bq), the smallest i with i*bq + bq - 1 >= j*bk —
-    so clamp residency there (same elision mechanics as _kv_residency_map)."""
+    so clamp residency there (same elision mechanics as _kv_residency_map).
+    ``window``: clamped from above too, at the last query block that still
+    sees the key block (_last_q_block)."""
     if not causal:
         return lambda g, j, i: (g, i, 0)
-    return lambda g, j, i: (g, jnp.maximum(i, (j * bk) // bq), 0)
+    if window is None:
+        return lambda g, j, i: (g, jnp.maximum(i, (j * bk) // bq), 0)
+    return lambda g, j, i: (g, jnp.clip(
+        i, (j * bk) // bq, _last_q_block(j, bq, bk, window, nq)), 0)
 
 
 def _cols(stat, ncols):
@@ -101,12 +143,40 @@ def _cols(stat, ncols):
     return jnp.tile(stat, (1, ncols // _LANE))
 
 
+def _computed(i, j, bq: int, bk: int, causal: bool, window):
+    """Whether the (query block i, key block j) pair holds an entry the
+    mask lets through — comparing raw block indices (j <= i) is only
+    correct when bq == bk. Non-causal (the ring's fully-visible past-owner
+    hops) computes every pair."""
+    if not causal:
+        return j >= 0
+    seen = j * bk <= i * bq + bq - 1
+    if window is not None:
+        seen &= i * bq < j * bk + bk + window - 1
+    return seen
+
+
+def _masked(s, i, j, causal: bool, window):
+    """Scores ``s`` (bq, bk) of block pair (i, j) with the entries the mask
+    hides at NEG_INF: a key after its query, and under a window a key
+    ``window`` or more before it."""
+    if not causal:
+        return s
+    bq, bk = s.shape
+    q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, s, NEG_INF)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(scale, nk, bq, bk, causal, q_ref, k_ref, v_ref, o_ref,
-                lse_ref, acc_ref, m_ref, l_ref):
+def _fwd_kernel(scale, nk, bq, bk, causal, window, q_ref, k_ref, v_ref,
+                o_ref, lse_ref, acc_ref, m_ref, l_ref):
     i = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -116,11 +186,7 @@ def _fwd_kernel(scale, nk, bq, bk, causal, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
 
-    # a (i, j) block pair holds >= 1 causal (q_pos >= k_pos) entry iff the
-    # block's earliest key is no later than its latest query — comparing raw
-    # block indices (j <= i) is only correct when bq == bk. Non-causal
-    # (the ring's fully-visible past-owner hops) computes every pair.
-    @pl.when((j * bk <= i * bq + bq - 1) if causal else (j >= 0))
+    @pl.when(_computed(i, j, bq, bk, causal, window))
     def _compute():
         # matmuls take the input dtype (bf16 inputs ride the fast MXU pass)
         # and accumulate f32 via preferred_element_type — the flash standard;
@@ -132,10 +198,11 @@ def _fwd_kernel(scale, nk, bq, bk, causal, q_ref, k_ref, v_ref, o_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # (bq, bk) f32
-        if causal:
-            q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        # (a row whose window has not reached this block yet reads all
+        # NEG_INF here: p = 1 against m = NEG_INF, and the first block with
+        # a key it sees — its own diagonal at the latest — scales that away
+        # by corr = exp(NEG_INF - m) = 0)
+        s = _masked(s, i, j, causal, window)
         m_prev = m_ref[...]  # (bq, _LANE), lane-broadcast
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         p = jnp.exp(s - _cols(m_cur, bk))
@@ -154,9 +221,9 @@ def _fwd_kernel(scale, nk, bq, bk, causal, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "bq", "bk", "causal",
+                   static_argnames=("scale", "bq", "bk", "causal", "window",
                                     "interpret"))
-def _flash_fwd(q, k, v, scale, bq, bk, causal, interpret):
+def _flash_fwd(q, k, v, scale, bq, bk, causal, window, interpret):
     """q, k: (G, T, Dh_padded), v: (G, T, Dv_padded) (G = B·H folded; v's
     head size may differ from q/k's — latent attention scores at 192 and
     mixes values of 128). ``scale`` comes from the TRUE q/k head dim (the
@@ -168,8 +235,8 @@ def _flash_fwd(q, k, v, scale, bq, bk, causal, interpret):
     dv = v.shape[-1]
     nq, nk = t // bq, t // bk
     grid = (g, nq, nk)
-    kern = functools.partial(_fwd_kernel, scale, nk, bq, bk, causal)
-    kv_row = _kv_residency_map(bq, bk, causal)
+    kern = functools.partial(_fwd_kernel, scale, nk, bq, bk, causal, window)
+    kv_row = _kv_residency_map(bq, bk, causal, window)
     o, lse = pl.pallas_call(
         kern,
         grid=grid,
@@ -203,7 +270,7 @@ def _flash_fwd(q, k, v, scale, bq, bk, causal, interpret):
 # backward
 # ---------------------------------------------------------------------------
 
-def _p_block(q_ref, k_ref, lse_ref, scale, causal, i, j):
+def _p_block(q_ref, k_ref, lse_ref, scale, causal, window, i, j):
     """Recompute the masked probability block P = exp(S - lse). lse_ref
     holds the (bq, _LANE) lane-broadcast log-sum-exp."""
     q = q_ref[0]
@@ -211,15 +278,11 @@ def _p_block(q_ref, k_ref, lse_ref, scale, causal, i, j):
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
-    bq, bk = s.shape
-    if causal:
-        q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    return jnp.exp(s - _cols(lse_ref[0], bk))
+    s = _masked(s, i, j, causal, window)
+    return jnp.exp(s - _cols(lse_ref[0], s.shape[1]))
 
 
-def _dq_kernel(scale, nk, bq, bk, causal, has_dlse, *refs):
+def _dq_kernel(scale, nk, bq, bk, causal, window, has_dlse, *refs):
     if has_dlse:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, dlse_ref,
          dq_ref, dq_acc) = refs
@@ -234,9 +297,9 @@ def _dq_kernel(scale, nk, bq, bk, causal, has_dlse, *refs):
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when((j * bk <= i * bq + bq - 1) if causal else (j >= 0))
+    @pl.when(_computed(i, j, bq, bk, causal, window))
     def _compute():
-        p = _p_block(q_ref, k_ref, lse_ref, scale, causal, i, j)  # (bq,bk) f32
+        p = _p_block(q_ref, k_ref, lse_ref, scale, causal, window, i, j)
         do = do_ref[0]
         v = v_ref[0]
         dp = jax.lax.dot_general(
@@ -257,7 +320,7 @@ def _dq_kernel(scale, nk, bq, bk, causal, has_dlse, *refs):
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(scale, nq, bq, bk, causal, has_dlse, *refs):
+def _dkv_kernel(scale, nq, bq, bk, causal, window, has_dlse, *refs):
     if has_dlse:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, dlse_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -273,9 +336,9 @@ def _dkv_kernel(scale, nq, bq, bk, causal, has_dlse, *refs):
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when((i * bq + bq - 1 >= j * bk) if causal else (i >= 0))
+    @pl.when(_computed(i, j, bq, bk, causal, window))
     def _compute():
-        p = _p_block(q_ref, k_ref, lse_ref, scale, causal, i, j)  # (bq,bk)
+        p = _p_block(q_ref, k_ref, lse_ref, scale, causal, window, i, j)
         do = do_ref[0]
         v = v_ref[0]
         dv_acc[...] += jax.lax.dot_general(
@@ -301,9 +364,10 @@ def _dkv_kernel(scale, nq, bq, bk, causal, has_dlse, *refs):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "bq", "bk", "causal",
+                   static_argnames=("scale", "bq", "bk", "causal", "window",
                                     "interpret"))
-def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
+def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, window,
+               interpret):
     """dlse=None is the hot path (lse output unused): the kernels take one
     fewer input stream and skip the dead add."""
     g, t, dh = q.shape
@@ -322,11 +386,12 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
     def q_row(g, i, j):
         return (g, i, 0)
 
-    k_row = _kv_residency_map(bq, bk, causal)
+    k_row = _kv_residency_map(bq, bk, causal, window)
 
     stat_specs = [pl.BlockSpec((1, bq, _LANE), q_row)] * len(stats)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale, nk, bq, bk, causal, has_dlse),
+        functools.partial(_dq_kernel, scale, nk, bq, bk, causal, window,
+                          has_dlse),
         grid=(g, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, dh), q_row),
@@ -344,14 +409,15 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
         interpret=interpret,
     )(q, k, v, do, *stats)
 
-    q_row2 = _q_residency_map(bq, bk, causal)
+    q_row2 = _q_residency_map(bq, bk, causal, window, nq)
 
     def k_row2(g, j, i):
         return (g, j, 0)
 
     stat_specs2 = [pl.BlockSpec((1, bq, _LANE), q_row2)] * len(stats)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale, nq, bq, bk, causal, has_dlse),
+        functools.partial(_dkv_kernel, scale, nq, bq, bk, causal, window,
+                          has_dlse),
         grid=(g, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, dh), q_row2),
@@ -388,20 +454,20 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, interpret):
 # and merge per-hop outputs under grad.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, scale, bq, bk, causal, interpret):
-    return _flash_fwd(q, k, v, scale, bq, bk, causal, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, scale, bq, bk, causal, window, interpret):
+    return _flash_fwd(q, k, v, scale, bq, bk, causal, window, interpret)[0]
 
 
-def _flash_core_fwd(q, k, v, scale, bq, bk, causal, interpret):
-    o, lse = _flash_fwd(q, k, v, scale, bq, bk, causal, interpret)
+def _flash_core_fwd(q, k, v, scale, bq, bk, causal, window, interpret):
+    o, lse = _flash_fwd(q, k, v, scale, bq, bk, causal, window, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(scale, bq, bk, causal, interpret, res, do):
+def _flash_core_bwd(scale, bq, bk, causal, window, interpret, res, do):
     q, k, v, o, lse = res
     return _flash_bwd(q, k, v, o, lse, do, None, scale, bq, bk, causal,
-                      interpret)
+                      window, interpret)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -409,11 +475,11 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_core_lse(q, k, v, scale, bq, bk, causal, interpret):
-    return _flash_fwd(q, k, v, scale, bq, bk, causal, interpret)
+    return _flash_fwd(q, k, v, scale, bq, bk, causal, None, interpret)
 
 
 def _flash_core_lse_fwd(q, k, v, scale, bq, bk, causal, interpret):
-    o, lse = _flash_fwd(q, k, v, scale, bq, bk, causal, interpret)
+    o, lse = _flash_fwd(q, k, v, scale, bq, bk, causal, None, interpret)
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -421,7 +487,7 @@ def _flash_core_lse_bwd(scale, bq, bk, causal, interpret, res, cts):
     q, k, v, o, lse = res
     do, dlse = cts
     return _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal,
-                      interpret)
+                      None, interpret)
 
 
 _flash_core_lse.defvjp(_flash_core_lse_fwd, _flash_core_lse_bwd)
@@ -431,13 +497,17 @@ _flash_core_lse.defvjp(_flash_core_lse_fwd, _flash_core_lse_bwd)
 # public entry — AttnFn contract of models/transformer.Block
 # ---------------------------------------------------------------------------
 
-def flash_attention(q, k, v, *, block_q: int = 512, block_k: int = 1024,
-                    force=None, interpret: bool = False):
+def flash_attention(q, k, v, *, window=None, block_q: int | None = None,
+                    block_k: int = 1024, force=None,
+                    interpret: bool = False):
     """Causal self-attention. q, k: (B, T, H, Dh), v: (B, T, H, Dv) — the
     Block contract with Dv == Dh, latent attention with Dh 192 against Dv
     128 (attention math upstream is f32; the kernel accumulates f32
     regardless); k and v may have fewer heads than q (grouped-query
-    attention, ``spread_kv_heads``). Returns (B, T, H, Dv).
+    attention, ``spread_kv_heads``). Returns (B, T, H, Dv). ``window``: a
+    query sees itself and the ``window - 1`` tokens before it (module
+    docstring); None sees every earlier token. ``block_q`` None: ``BLOCK_Q``,
+    under a window ``WINDOW_BLOCK_Q``.
 
     The causal mask is offset-invariant for self-attention (q and k share
     positions), so no offset argument is needed. Off-TPU (and not
@@ -448,11 +518,16 @@ def flash_attention(q, k, v, *, block_q: int = 512, block_k: int = 1024,
 
     b, t, h, dh = q.shape
     k, v = spread_kv_heads(h, k, v)
+    if block_q is None:
+        block_q = BLOCK_Q if window is None else WINDOW_BLOCK_Q
     bq = _fit_block(block_q, t, lane_rule=False)
     bk = _fit_block(block_k, t, lane_rule=True)
+    if window is not None and window < 1:
+        raise ValueError(f"flash_attention: window={window} must be >= 1")
     if not _kernel_eligible(t, bq, bk, dh, force, interpret):
-        return dense_attention(q, k, v, causal=True)
-    return _run_folded(q, k, v, bq, bk, True, interpret, want_lse=False)
+        return dense_attention(q, k, v, causal=True, window=window)
+    return _run_folded(q, k, v, bq, bk, True, interpret, want_lse=False,
+                       window=window)
 
 
 def spread_kv_heads(heads: int, k, v):
@@ -467,6 +542,12 @@ def spread_kv_heads(heads: int, k, v):
     return jnp.repeat(k, r, axis=2), jnp.repeat(v, r, axis=2)
 
 
+def runs_in_kernels(force=None, interpret: bool = False) -> bool:
+    """Whether a call with these arguments takes the Pallas kernels (a
+    shape that does not tile then raises) or the dense lowering."""
+    return force if force is not None else (use_pallas() or interpret)
+
+
 def _kernel_eligible(t, bq, bk, dh, force, interpret) -> bool:
     """Shared kernel-vs-dense dispatch for both public wrappers. Blocks
     (including T itself when it becomes the single block) must honour the
@@ -477,8 +558,7 @@ def _kernel_eligible(t, bq, bk, dh, force, interpret) -> bool:
     O(T·Dh)-memory kernel must not silently get the O(T²) dense path.
     False (the dense path) only when the kernel is not selected at all:
     off-TPU, or ``force=False``."""
-    use = force if force is not None else (use_pallas() or interpret)
-    if not use:
+    if not runs_in_kernels(force, interpret):
         return False
     if (bq < 8 or bk < 8  # _fit_block found no legal block (t % 8 != 0)
             or t % 8 or bq % 8 or bk % 8 or t % bq or t % bk
@@ -491,7 +571,7 @@ def _kernel_eligible(t, bq, bk, dh, force, interpret) -> bool:
     return True
 
 
-def _run_folded(q, k, v, bq, bk, causal, interpret, want_lse):
+def _run_folded(q, k, v, bq, bk, causal, interpret, want_lse, window=None):
     """(B,T,H,Dh) q, k and (B,T,H,Dv) v -> folded kernel call -> o
     (B,T,H,Dv), or (o, lse (B,T,H)) with a differentiable lse when
     want_lse. Each head size is padded to whole lane tiles on its own."""
@@ -505,15 +585,14 @@ def _run_folded(q, k, v, bq, bk, causal, interpret, want_lse):
             x = jnp.pad(x, ((0, 0), (0, 0), (0, _ceil_to(d, _LANE) - d)))
         return x
 
-    args = (fold(q), fold(k), fold(v), 1.0 / (dh ** 0.5),
-            bq, bk, causal, interpret)
+    args = (fold(q), fold(k), fold(v), 1.0 / (dh ** 0.5), bq, bk, causal)
 
     def unfold(o):
         return jnp.moveaxis(o[..., :dv].reshape(b, h, t, dv), 1, 2)
 
     if not want_lse:
-        return unfold(_flash_core(*args))
-    o, lse = _flash_core_lse(*args)
+        return unfold(_flash_core(*args, window, interpret))
+    o, lse = _flash_core_lse(*args, interpret)
     return unfold(o), jnp.moveaxis(lse.reshape(b, h, t), 1, 2)  # (B, T, H)
 
 
@@ -538,5 +617,7 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True,
 
 def attn_impl_fn(cfg):
     """cfg.attn_impl -> AttnFn for the single-shard LM paths (None = Block's
-    dense default). One dispatch point shared by sp_step / pp_step."""
+    dense default). One dispatch point shared by sp_step / pp_step. A model
+    whose layers differ in their window hands each call its own
+    (``window=``)."""
     return flash_attention if cfg.attn_impl == "flash" else None

@@ -28,7 +28,7 @@ from jax import lax
 NEG_INF = -1e30
 
 
-def _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l):
+def _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l, window=None):
     """Fold one K/V block into the streaming-softmax accumulators.
 
     q: (B, Tq, H, Dh); k, v: (B, Tk, H, Dh); q_pos: (Tq,), k_pos: (Tk,)
@@ -37,6 +37,8 @@ def _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l):
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     if causal:
         mask = q_pos[:, None] >= k_pos[None, :]  # (Tq, Tk)
+        if window is not None:  # a key `window` or more before its query
+            mask &= q_pos[:, None] - k_pos[None, :] < window
         s = jnp.where(mask[None, None], s, NEG_INF)
     m_blk = jnp.max(s, axis=-1)  # (B, H, Tq)
     m_blk = jnp.moveaxis(m_blk, 1, 2)  # (B, Tq, H)
@@ -50,15 +52,20 @@ def _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l):
     return o_new, m_new, l_new
 
 
-def dense_attention(q, k, v, q_offset=0, k_offset=0, causal: bool = True):
+def dense_attention(q, k, v, q_offset=0, k_offset=0, causal: bool = True,
+                    window=None):
     """Single-shard exact attention with the same streaming accumulators.
+    ``window`` (causal only): a query sees itself and the ``window - 1``
+    positions before it.
 
     Used as the sp=1 fallback and as the oracle in tests.
     """
-    return dense_attention_lse(q, k, v, q_offset, k_offset, causal)[0]
+    return dense_attention_lse(q, k, v, q_offset, k_offset, causal,
+                               window)[0]
 
 
-def dense_attention_lse(q, k, v, q_offset=0, k_offset=0, causal: bool = True):
+def dense_attention_lse(q, k, v, q_offset=0, k_offset=0, causal: bool = True,
+                        window=None):
     """dense_attention that also returns the per-row log-sum-exp (B, T, H)
     f32 — the dense counterpart of ops/flash_attention.flash_attention_with_lse
     (its off-TPU / non-tiling fallback, and the small-shape oracle)."""
@@ -71,7 +78,10 @@ def dense_attention_lse(q, k, v, q_offset=0, k_offset=0, causal: bool = True):
     o = jnp.zeros((b, tq, h, v.shape[-1]), jnp.float32)
     m = jnp.full((b, tq, h), NEG_INF, jnp.float32)
     l = jnp.zeros((b, tq, h), jnp.float32)
-    o, m, l = _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l)
+    if window is not None and not causal:
+        raise ValueError("dense_attention: a window needs causal=True")
+    o, m, l = _block_attn(q, k, v, q_pos, k_pos, scale, causal, o, m, l,
+                          window)
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype), lse
 

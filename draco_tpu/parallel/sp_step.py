@@ -20,8 +20,9 @@ by name.)
 
 The token model comes from ``models.build_lm``: ``TransformerLM`` on every
 mesh this route builds; the published-config blocks ``LatentMoeLM`` (latent
-attention, routed and shared experts) and ``HybridMoeLM`` (Gated DeltaNet
-beside gated attention, over the same expert layer) at ``seq_shards == 1``. Where the
+attention, routed and shared experts), ``HybridMoeLM`` (Gated DeltaNet
+beside gated attention) and ``WindowedMoeLM`` (sliding-window beside full
+attention), all over the same expert layer, at ``seq_shards == 1``. Where the
 (lanes, d) stack of per-lane gradients computed side by side would not fit
 beside the rest of the step (``LANES_IN_TURN_BYTES``), the lanes are
 evaluated in turn (``lax.map``), each layer is rematerialised in the

@@ -140,6 +140,27 @@ def _flash_grouped_query():
     return fn, [(GQA_Q, jnp.bfloat16)] + [(GQA_KV, jnp.bfloat16)] * 2
 
 
+# mellum2.maj_vote_r3: 32 query heads of 128 on 4 key/value heads over
+# 8 192 tokens, a window of 1 024
+SWA_Q, SWA_KV, SWA_WINDOW = (1, 8192, 32, 128), (1, 8192, 4, 128), 1024
+
+
+def _flash_windowed():
+    """Forward and backward of a sliding layer's core as the model runs it:
+    the window's block skipping and two-sided residency maps, grouped-query
+    heads, under the model's scope."""
+    def fn(q, k, v):
+        def core(q, k, v):
+            with jax.named_scope("draco_window"):
+                return flash_attention(q, k, v, window=SWA_WINDOW,
+                                       force=True)
+
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(core(
+            q, k, v).astype(jnp.float32))), argnums=(0, 1, 2))(q, k, v)
+
+    return fn, [(SWA_Q, jnp.bfloat16)] + [(SWA_KV, jnp.bfloat16)] * 2
+
+
 def _grouped_dot():
     """models/latent_moe.grouped_dot's kernel (jax's megablox) at the
     cell's shapes, forward and backward, with only the held groups'
@@ -197,6 +218,7 @@ CASES = {
     "flash_grad": lambda: _flash(grad=True),
     "flash_grad_qk192_v128": _flash_latent,
     "flash_grad_16_heads_on_2_d256": _flash_grouped_query,
+    "flash_grad_window_1024_32_heads_on_4": _flash_windowed,
     "grouped_dot_8_of_128": _grouped_dot,
     "delta_rule_fwd": lambda: _delta_rule(grad=False),
     "delta_rule_grad": lambda: _delta_rule(grad=True),
@@ -237,6 +259,26 @@ def test_every_kernel_of_the_rule_carries_the_rules_scope(one_chip):
         assert sum(kernel in name for name in names) == 1, names
 
 
+def test_every_kernel_of_the_window_carries_the_windows_scope(one_chip):
+    """``window_kernel_ms`` reads each instruction's innermost ``draco_*``
+    segment: the forward kernel and both backward kernels of a sliding
+    layer's core carry ``draco_window``, and so do the copies of k and v to
+    the query heads' count."""
+    import re
+
+    fn, specs = _flash_windowed()
+    text = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in specs]).compile().as_text()
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 3, names
+    assert all("draco_window" in name for name in names), names
+    assert sum("transpose(jvp(draco_window))" in name
+               for name in names) == 2, names
+
+
 def _resnet18_step_text(chip) -> str:
     """The headline cell's step program (ResNet-18, cyclic s=1, n=8, r=3,
     batch 32 a worker) compiled for the described chip: the mesh is built
@@ -271,6 +313,51 @@ def _resnet18_step_text(chip) -> str:
         setup = build_train_setup(cfg, mesh, dataset_name=cfg.dataset)
         return setup.train_step.lower(
             setup.state, x, y, np.zeros((8,), bool)).compile().as_text()
+
+
+def test_the_dense_expert_layer_is_three_plain_products(one_chip):
+    """mellum2.maj_vote_r3's expert layer at the published widths (8 of 64
+    experts held, top-8, a row of 8 192 tokens), forward and backward, for
+    the described chip: ``MoeSpec.dense`` — no sort, no gather or
+    scatter-add of rows, no loop with the data's trip count and no grouped
+    kernel, so its work is the same at every routing; the products carry
+    ``draco_experts``, the weights' mask ``draco_route``."""
+    import json
+    import re
+    from unittest import mock
+
+    from draco_tpu.models import latent_moe
+    from draco_tpu.models.windowed_moe import WindowedMoeLM
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "mellum2-12b-a2.5b-ep8.json")) as fh:
+        spec = json.load(fh)["train_config"]["model_spec"]
+    lm = WindowedMoeLM(dict(spec, layers=1))
+    assert lm.moe.dense
+    shapes = lm.param_shapes()["layer0"]
+    layer = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=one_chip),
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    x = jax.ShapeDtypeStruct((8192, spec["hidden_size"]), jnp.float32,
+                             sharding=one_chip)
+
+    def fn(x, p):
+        with mock.patch.object(latent_moe, "use_pallas", lambda: True):
+            return jax.grad(lambda x, p: jnp.sum(
+                jnp.sin(lm._experts(x, p)[0])), argnums=(0, 1))(x, p)
+
+    text = jax.jit(fn).lower(x, layer).compile().as_text()
+    ops = re.findall(r" = \S+ ([a-z\-]+)\(", text)
+    assert "tpu_custom_call" not in text
+    for op in ("sort", "scatter", "while"):
+        assert op not in ops, op
+    products = [line for line in text.splitlines()
+                if " convolution(" in line and "draco_experts" in line]
+    # gate, up, down; each one's two transposes
+    assert len(products) == 9, len(products)
+    assert any("draco_route" in line for line in text.splitlines())
 
 
 def test_resnet18_step_scopes_on_the_described_chip(one_chip, monkeypatch):

@@ -26,10 +26,11 @@ from draco_tpu.parallel.token_loop import (  # noqa: E402
 TINY = os.path.join(ROOT, "benchmark", "testdata", "latent-moe-tiny.json")
 with open(TINY) as fh:
     SPEC = json.load(fh)["train_config"]["model_spec"]
-with open(os.path.join(ROOT, "benchmark", "testdata",
-                       "hybrid-moe-tiny.json")) as fh:
-    SPECS = {"LatentMoeLM": SPEC,
-             "HybridMoeLM": json.load(fh)["train_config"]["model_spec"]}
+SPECS = {"LatentMoeLM": SPEC}
+for _network, _file in (("HybridMoeLM", "hybrid-moe-tiny.json"),
+                        ("WindowedMoeLM", "windowed-moe-tiny.json")):
+    with open(os.path.join(ROOT, "benchmark", "testdata", _file)) as fh:
+        SPECS[_network] = json.load(fh)["train_config"]["model_spec"]
 STEPS = 4
 
 
@@ -70,7 +71,7 @@ def _run(cfg):
 
 
 @pytest.fixture(scope="module", params=["LatentMoeLM", "TransformerLM",
-                                        "HybridMoeLM"])
+                                        "HybridMoeLM", "WindowedMoeLM"])
 def runs(request):
     attacked = _run(_cfg(request.param))
     clean = _run(_cfg(request.param, adversary_count=0))
@@ -147,7 +148,7 @@ def test_the_chunked_loop_runs_the_same_steps():
 
 
 @pytest.mark.parametrize("network", ["LatentMoeLM", "TransformerLM",
-                                     "HybridMoeLM"])
+                                     "HybridMoeLM", "WindowedMoeLM"])
 def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     """The large side of ``sp_step.LANES_IN_TURN_BYTES`` at the tiny size:
     lanes in turn (``lax.map``), each layer rematerialised, the stack in
