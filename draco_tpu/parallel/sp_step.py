@@ -25,12 +25,14 @@ beside gated attention) and ``WindowedMoeLM`` (sliding-window beside full
 attention), all over the same expert layer, at ``seq_shards == 1``. Where the
 (lanes, d) stack of per-lane gradients computed side by side would not fit
 beside the rest of the step (``LANES_IN_TURN_BYTES``), the lanes are
-evaluated in turn (``lax.map``), each layer is rematerialised in the
+evaluated in turn (a loop), each layer is rematerialised in the
 backward pass and the vote's stack is kept in the chip's tiles
-(``STACK_LANES``): ONE size test, and the shape decides, no option does.
-The vote then reads that stack once — finite check, simulated attack and
-fingerprints in one sweep, nothing stack-sized stored — and the winner's row
-is copied once, the leaves cut from it where it lies
+(``STACK_LANES``) as the carry of the lanes' loop, each lane writing its
+leaves into its row of it in whole 128-wide lines: ONE size test, and the
+shape decides, no option does. The vote then reads that stack once — finite
+check, simulated attack and fingerprints in one sweep, nothing stack-sized
+stored — and the winner's row is copied once, the leaves cut from it where
+it lies
 (parallel/common.aggregate_flat_grads, coding/repetition.majority_vote).
 """
 
@@ -97,7 +99,70 @@ LANES_IN_TURN_BYTES = 2**30
 # (lanes, 8, d / 8) building a lane's row is eight such rewrites, 49 ms; as
 # (lanes, 1, d) the stack is linear but every pass over it uses one
 # sublane of eight (the fingerprints 80 ms for 10).
+# A lane's row is WRITTEN in whole lines too (``row_layout``,
+# ``_write_row``): the leaves are walked in ravel order and cut into pieces
+# wherever the running offset is a multiple of 128; a leaf that is whole
+# lines at a whole-line offset is a piece by itself, laid out (lines, 128)
+# by a reshape that moves nothing; what is not whole lines is the ravel's
+# tail, joined flat with the zeros that close the row into one small piece
+# of whole lines; the stack is the carry of the lanes' loop and each piece is
+# written once, into its range of the lane's lines — no row exists beside
+# the stack, flat or in lines. The measured cost of breaking it (ledger,
+# PR 35, qwen3next.maj_vote_r3): 192 floats of d = 424 340 544 in two
+# (3, 32) leaves made the flat row's length no multiple of 128; the
+# compiler, which otherwise turns the flat concatenate into one in-place
+# update-slice a leaf, kept ONE concatenate of the 66 leaves at 42 % of the
+# memory rate, 29.2 ms a step, ahead of the row's 15.5 ms copy into the
+# stack (that copy every large cell paid: PERF.md section 6, PR 36).
 STACK_LANES = 128
+
+
+class RowLayout(NamedTuple):
+    """The static layout of a lane's row in a tiled stack: what
+    ``_write_row`` does for this model's leaf table."""
+    lines: int  # 128-wide lines of a row, the closing zeros included
+    zeros: int  # zeros that close the row's last (8, 128) tile
+    # leaves that reach a line's end only together with their neighbours
+    # (or with the zeros), so are joined flat first, and their elements
+    joined_leaves: int
+    joined_size: int
+    # (first leaf, one past the last) of each piece in ravel order, the
+    # zeros counted as one more leaf after the last
+    pieces: tuple
+
+
+def row_layout(sizes) -> RowLayout:
+    """Cut the leaves' ravel (``sizes`` in ravel order) into pieces that
+    each start and end on a 128-wide line."""
+    sizes = [int(s) for s in sizes]
+    zeros = -sum(sizes) % (8 * STACK_LANES)
+    pieces, start, offset = [], 0, 0
+    for i, size in enumerate(sizes + [zeros] * bool(zeros)):
+        offset += size
+        if offset % STACK_LANES == 0:
+            pieces.append((start, i + 1))
+            start = i + 1
+    joined = [size for lo, hi in pieces if hi - lo > 1
+              for size in sizes[lo:hi]]  # the slice leaves the zeros out
+    return RowLayout(lines=offset // STACK_LANES, zeros=zeros,
+                     joined_leaves=len(joined), joined_size=sum(joined),
+                     pieces=tuple(pieces))
+
+
+def _write_row(stack, lane, tree, layout: RowLayout):
+    """A gradient tree written into row ``lane`` of the tiled
+    (lanes, lines, 128) stack, a whole-line piece at a time, each into its
+    range of the row's lines (``STACK_LANES``). Byte for byte the row is
+    ``jnp.pad(_flatten_tree(tree), (0, layout.zeros))`` in lines."""
+    leaves = jax.tree.leaves(tree)
+    if layout.zeros:
+        leaves.append(jnp.zeros((layout.zeros,), stack.dtype))
+    line = 0
+    for lo, hi in layout.pieces:
+        piece = _flatten_tree(leaves[lo:hi]).reshape(1, -1, STACK_LANES)
+        stack = lax.dynamic_update_slice(stack, piece, (lane, line, 0))
+        line += piece.shape[1]
+    return stack
 
 
 class SPTrainSetup(NamedTuple):
@@ -114,6 +179,9 @@ class SPTrainSetup(NamedTuple):
     #   -> (state, metrics (K, len(metric_names)) float32)
     train_token_many: Any = None
     metric_names: tuple = TOKEN_METRIC_NAMES
+    # how a lane's row is assembled where the stack is kept in tiles
+    # (STACK_LANES); None on the small side, whose rows are flat
+    row_layout: Optional[RowLayout] = None
 
 
 def synthetic_text(seed: int, step: int, n: int, batch: int,
@@ -224,10 +292,10 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
     lanes = n // mesh.shape[WORKER_AXIS]
     lanes_in_turn = 4 * lanes * dim > LANES_IN_TURN_BYTES
     tiled_stack = lanes_in_turn and cfg.approach == "maj_vote"
-    # zeros that close a row's last (8, 128) tile (none at kanana2's
-    # d = 415 001 tiles, 960 at qwen3next's d = 424 340 544); every lane
-    # writes the same, so the vote is unmoved
-    tile_pad = -dim % (8 * STACK_LANES) if tiled_stack else 0
+    # the row in whole lines; its zeros close the last (8, 128) tile (none
+    # at kanana2's d = 415 001 tiles, 960 at qwen3next's d = 424 340 544,
+    # 768 at mellum2's); every lane writes the same, so the vote is unmoved
+    layout = row_layout(np.diff(leaf_offsets)) if tiled_stack else None
     if lanes_in_turn and not cfg.remat:
         model = build_lm(dataclasses.replace(cfg, remat=True), attn,
                          kernel_fn=flash)
@@ -278,21 +346,36 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
     def device_grads(params, tokens):
         """tokens: (lanes, B, t_local) — this device's shard of its workers'
         batches (lanes = num_workers / mesh w-axis; 1 on a full mesh).
-        Returns (flat_grads (lanes, d), losses (lanes,), the model's
-        counters (lanes,) each) — each worker's FULL gradient,
+        Returns (flat_grads (lanes, d) — (lanes, lines, 128) where the
+        stack is tiled —, losses (lanes,), the model's counters (lanes,)
+        each) — each worker's FULL gradient,
         psum-assembled over sp and replicated along it."""
-        def one_lane(toks):
+        def lane_grad(toks):
             (loss, stats), g = jax.value_and_grad(
                 lambda p: _shard_objective(p, toks, train=True),
                 has_aux=True)(params)
-            g = _flatten_tree(g)
-            if tiled_stack:
-                if tile_pad:
-                    g = jnp.pad(g, (0, tile_pad))
-                g = g.reshape(-1, STACK_LANES)
             return g, loss, stats
 
-        g, loss, stats = over_lanes(one_lane, tokens)
+        if tiled_stack:
+            # the stack is the loop's carry and every lane writes its
+            # leaves into it where they belong: no row exists beside it
+            def into_stack(stack, lane_toks):
+                lane, toks = lane_toks
+                g, loss, stats = lane_grad(toks)
+                return _write_row(stack, lane, g, layout), (loss, stats)
+
+            g, (loss, stats) = lax.scan(
+                into_stack,
+                # every element is written: the pieces tile the row
+                lax.empty((lanes, layout.lines, STACK_LANES),
+                          jnp.result_type(*jax.tree.leaves(params))),
+                (jnp.arange(lanes), tokens))
+        else:
+            def one_lane(toks):
+                g, loss, stats = lane_grad(toks)
+                return _flatten_tree(g), loss, stats
+
+            g, loss, stats = over_lanes(one_lane, tokens)
         # exact per-worker grad: cotangents already routed through the ring's
         # transpose; psum folds the shard contributions
         g = lax.psum(g, SEQ_AXIS)
@@ -405,6 +488,7 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
         model=model, state=state, train_step=train_step, eval_step=eval_step,
         code=code, unravel=unravel, dim=dim,
         train_token_many=train_token_many, metric_names=metric_names,
+        row_layout=layout,
     )
 
 

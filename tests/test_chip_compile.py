@@ -514,3 +514,71 @@ def test_the_vote_reads_its_stack_once_and_copies_the_winner_once(one_chip):
                  and not i[1].lstrip().startswith("(")
                  and f32_sizes(i[1]) == [row]]
     assert 1 <= len(row_sized) <= 2, row_sized
+
+
+def test_a_lane_writes_its_leaves_straight_into_the_stack(one_chip):
+    """ISSUE 36: the lanes' loop as ``sp_step`` builds it where the stack is
+    tiled — the stack is the loop's carry and ``_write_row`` writes each
+    whole-line piece of a lane's gradient into its range of the lane's
+    lines — once the chip's compiler is done with it: ONE loop carries the
+    stack, every piece is an update-slice of the stack in place, and no
+    result of a row's size (flat or in lines) stands beside it. Leaves as
+    in the vote's test above (the third starts inside a tile) and two
+    (3, 32) leaves last, which only close a line together with the zeros.
+    At the parent the row was built flat — one ``concatenate`` of d
+    elements where a leaf is no whole lines, 29.2 ms a step at d = 424 M
+    (PERF.md section 6, PR 36) — and then copied into the stack."""
+    import math
+    import re
+
+    from jax import lax
+
+    from draco_tpu.obs import device_attr as da
+    from draco_tpu.parallel.sp_step import (STACK_LANES, _write_row,
+                                            row_layout)
+    from draco_tpu.training.step import _make_unravel
+    from tests.test_step_scopes import _executed_lines
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"a": arg((4096, 2048)), "b": arg((128,)),
+              "c": arg((2048, 1408)),
+              "heads": {"A_log": arg((3, 32)), "dt_bias": arg((3, 32))}}
+    _, dim, offsets = _make_unravel(params)
+    layout = row_layout(offsets[1:] - offsets[:-1])
+    assert (layout.joined_leaves, layout.joined_size, layout.zeros,
+            len(layout.pieces)) == (2, 192, 704, 4)
+
+    def lanes(params, xs):
+        def into_stack(stack, lane_x):
+            # a gradient made inside the loop, a leaf at a time
+            g = jax.tree.map(lambda p: jnp.sin(p * lane_x[1]), params)
+            return _write_row(stack, lane_x[0], g, layout), None
+
+        return lax.scan(
+            into_stack, lax.empty((3, layout.lines, STACK_LANES),
+                                  jnp.float32), (jnp.arange(3), xs))[0]
+
+    text = jax.jit(lanes).lower(params, arg((3,))).compile().as_text()
+    row = layout.lines * STACK_LANES
+
+    def f32_sizes(result):
+        return [math.prod(int(x) for x in dims.split(","))
+                for dims in re.findall(r"f32\[([\d,]+)\]", result)]
+
+    ins = []
+    for line in _executed_lines(text):
+        m = da._HLO_LINE_RE.match(line)
+        if m:
+            result = line.split("=", 1)[1].split(f" {m.group(2)}(", 1)[0]
+            ins.append((m.group(1), result, m.group(2)))
+    loops = [i for i in ins if i[2] == "while"
+             and 3 * row in f32_sizes(i[1])]
+    assert len(loops) == 1, loops
+    assert not [i for i in ins if {row, dim} & set(f32_sizes(i[1]))]
+    writes = [i for i in ins if f32_sizes(i[1]) == [3 * row]
+              and i[2] in ("fusion", "dynamic-update-slice")]
+    assert len(writes) == len(layout.pieces), writes
+    assert "concatenate" not in {i[2] for i in ins if max(
+        f32_sizes(i[1]), default=0) > layout.joined_size + layout.zeros}

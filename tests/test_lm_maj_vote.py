@@ -166,6 +166,9 @@ def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     monkeypatch.setattr(repetition, "FINGERPRINT_BLOCK", 2048)
     setup = build_sp_train_setup(cfg, make_mesh_2d(3, 1, jax.devices()[:1]))
     assert setup.dim % 1024  # the padded case
+    # ... whose rows the lanes write into the stack in whole-line pieces
+    assert setup.row_layout.zeros == -setup.dim % 1024
+    assert setup.row_layout.joined_leaves > 1
     in_turn, rows2 = _run(cfg)
     for a, b in zip(jax.tree.leaves(side_by_side), jax.tree.leaves(in_turn)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
@@ -173,6 +176,89 @@ def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
         assert r2["det_adv"] == r2["det_tp"] == r2["located_errors"] == 1.0
         assert r2["vote_agree"] == r1["vote_agree"] == pytest.approx(2 / 3)
         assert r2["loss"] == pytest.approx(r1["loss"], rel=1e-5)
+
+
+# the published leaf tables: configuration file -> the layout facts of a
+# lane's row (joined leaves, their elements, closing zeros, d)
+PUBLISHED = {
+    "kanana2": ("LatentMoeLM", "kanana-2-30b-a3b-ep16.json",
+                (0, 0, 0, 424_961_024)),
+    "mellum2": ("WindowedMoeLM", "mellum2-12b-a2.5b-ep8.json",
+                (0, 0, 768, 340_349_184)),
+    "qwen3next": ("HybridMoeLM", "qwen3-next-80b-a3b-ep32.json",
+                  (2, 192, 960, 424_340_544)),
+}
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("case", ["LatentMoeLM", "TransformerLM",
+                                  "HybridMoeLM", "WindowedMoeLM",
+                                  *PUBLISHED])
+def test_a_lanes_row_is_written_in_whole_lines(case):
+    """``sp_step._write_row``: the leaves cut into pieces that each start
+    and end on a 128-wide line, each written into its range of the lane's
+    row. At the tiny widths (leaves off the lines everywhere: long joined
+    runs) the row is the padded flat ravel bit for bit, the other lanes'
+    rows are not touched and ``unravel`` hands every leaf back; on the
+    published leaf tables (shapes only) the recorded layout reads what the
+    cells run: every leaf a piece of its own but qwen3next's two (3, 32)
+    leaves, which close the row together with its zeros."""
+    import jax.numpy as jnp
+
+    from draco_tpu.models import build_lm
+    from draco_tpu.parallel.sp_step import (
+        STACK_LANES, _write_row, row_layout,
+    )
+    from draco_tpu.training.step import _flatten_tree, _make_unravel
+
+    if case in PUBLISHED:
+        network, file, facts = PUBLISHED[case]
+        with open(os.path.join(ROOT, "benchmark", "configs", file)) as fh:
+            spec = json.load(fh)["train_config"]["model_spec"]
+        lm = build_lm(_cfg(network, model_spec=spec,
+                           vocab=spec["vocab_rows"]))
+        tree = jax.eval_shape(lm.init, jax.random.key(0))
+    else:
+        params = build_lm(_cfg(case)).init(jax.random.key(0))
+        leaves, treedef = jax.tree.flatten(params)
+        # a gradient's worth of bits in every leaf, a -0.0 among them
+        tree = jax.tree.unflatten(treedef, [
+            jax.random.normal(jax.random.key(i), x.shape).at[
+                (0,) * x.ndim].set(-0.0) for i, x in enumerate(leaves)])
+    unravel, dim, offsets = _make_unravel(tree)
+    sizes = np.diff(offsets)
+    layout = row_layout(sizes)
+    # the pieces tile the leaves (and the zeros, one more leaf after them)
+    # in order, and each ends on a line
+    assert layout.zeros == -dim % (8 * STACK_LANES)
+    assert layout.lines * STACK_LANES == dim + layout.zeros
+    ends = [hi for _, hi in layout.pieces]
+    assert [lo for lo, _ in layout.pieces] == [0] + ends[:-1]
+    assert ends[-1] == len(sizes) + bool(layout.zeros)
+    padded = np.cumsum(list(sizes) + [layout.zeros])
+    assert all(padded[hi - 1] % STACK_LANES == 0 for hi in ends)
+    stack = jax.ShapeDtypeStruct((2, layout.lines, STACK_LANES), jnp.float32)
+    if case in PUBLISHED:
+        assert (layout.joined_leaves, layout.joined_size, layout.zeros,
+                dim) == facts
+        out = jax.eval_shape(lambda s, t: _write_row(s, 1, t, layout),
+                             stack, tree)
+        assert (out.shape, out.dtype) == (stack.shape, stack.dtype)
+        return
+    assert layout.zeros and layout.joined_leaves > 1
+    assert layout.joined_size == sum(
+        sizes[lo:hi].sum() for lo, hi in layout.pieces if hi - lo > 1)
+    got = jax.jit(lambda s, t: _write_row(s, 1, t, layout))(
+        jnp.full(stack.shape, jnp.nan), tree)
+    want = jnp.pad(_flatten_tree(tree), (0, layout.zeros)).reshape(
+        -1, STACK_LANES)
+    assert np.array_equal(_bits(got[1]), _bits(want))
+    assert np.isnan(np.asarray(got[0])).all()
+    for a, b in zip(jax.tree.leaves(unravel(got[1])), jax.tree.leaves(tree)):
+        assert a.shape == b.shape and np.array_equal(_bits(a), _bits(b))
 
 
 @pytest.mark.parametrize("layout", [(1, -1), (8, -1), (-1, 8, 128),

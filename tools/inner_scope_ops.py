@@ -12,7 +12,9 @@ innermost-scope map, and writes ``chiprun_out/inner_scope_ops_<tag>.json``
 (every scope's total, the scope's instructions in ms per traced step with
 their result shapes, the run's metrics, and the largest and the smallest
 value any step's record held of each of the model's counters — the
-``ledger:`` lines print medians) and the compiled step program's text beside it
+``ledger:`` lines print medians —, how a lane's row is laid into a tiled
+stack (``sp_step.RowLayout``: lines, closing zeros, joined leaves), every
+step's loss as float hex) and the compiled step program's text beside it
 (``step_hlo_<tag>.txt.gz``: what shapes the program holds).
 Edits nothing of the benchmark; a TPU or exit 1, as the benchmark.
 """
@@ -52,6 +54,9 @@ def main(argv) -> int:
     def keep_records(self):
         kept["records"], kept["counters"] = self.records, getattr(
             self.setup.model, "stat_names", ())
+        layout = self.setup.row_layout
+        kept["row_layout"] = layout and dict(
+            layout._asdict(), pieces=len(layout.pieces))
         return close(self)
 
     close = token.Route.close
@@ -84,6 +89,9 @@ def main(argv) -> int:
            "counters_min": {k: min(r[k] for r in kept["records"].rows)
                             for k in kept["counters"]},
            "steps": len(kept["records"].rows),
+           "row_layout": kept["row_layout"],
+           "loss_hex": [float(r["loss"]).hex()
+                        for r in kept["records"].rows],
            "correct": result["correct"], "metrics": result["metrics"],
            "device": result["device"]}
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
@@ -95,7 +103,7 @@ def main(argv) -> int:
         fh.write(kept["hlo"])
     print(json.dumps({k: out[k] for k in (
         "correct", "metrics", "scope_ms_per_step", "counters_max",
-        "counters_min", "steps")}))
+        "counters_min", "steps", "row_layout")}))
     for scope in scopes:
         print(f"-- {scope}")
         for label, ms in out["ops_ms_per_step"][scope][:40]:
