@@ -7,10 +7,15 @@ through VMEM with the online-softmax accumulators (the same m/l/o algebra the
 ring uses *across chips*, here applied *within* a chip's sequence), so peak
 memory is O(T·Dh + block²) and the (T, T) matrix never exists.
 
-Forward saves only the per-row log-sum-exp; backward recomputes the
-probability blocks in two passes (dq sweeping K blocks, dk/dv sweeping Q
-blocks) — the standard flash-attention custom VJP, each pass again never
-materialising (T, T).
+Forward saves only the per-row log-sum-exp; backward is ONE kernel
+(_bwd_kernel) that rebuilds each probability block once and takes all three
+gradients from it: five products a block (s, dp, dv += pᵀ·do, dk += dsᵀ·q,
+dq += ds·k), one pass of mask / exp / ds. Its grid sweeps the query blocks
+under each key block, so dk / dv sum in block-sized scratch; dq, which that
+sweep crosses, keeps its float32 sum whole for the head in VMEM ((T, Dh):
+4 MB at T = 4096, Dh = 256) and no partial dq ever reaches main memory.
+``vmem_limit_bytes`` is set from the shapes (_bwd_vmem_bytes); a head too
+long for the chip's vector memory raises. Again (T, T) never exists.
 
 Block-causal skipping: grid steps with j > i (keys entirely in the future)
 compute nothing (`pl.when`), so causal attention does ~half the block work.
@@ -21,7 +26,7 @@ key block) pair is computed iff some pair of its positions satisfies BOTH
 inequalities: the block's earliest key is no later than its latest query
 (causality, as above) and its latest key is less than W before its earliest
 query. Every other block is skipped from both sides, in the forward kernel
-and in both backward kernels, and the residency maps clamp the block index
+and in the backward kernel, and the residency maps clamp the block index
 from both sides so that a skipped block is not fetched either; the two
 inequalities are applied elementwise inside every computed block (only the
 blocks at the two edges hold masked entries). ``window=None`` is the causal
@@ -114,9 +119,9 @@ def _kv_residency_map(bq: int, bk: int, causal: bool, window=None):
 
 
 def _q_residency_map(bq: int, bk: int, causal: bool, window=None, nq=0):
-    """Index map for Q-row input blocks (q, do, per-row stats) on the dk/dv
-    grid (g, <k-block>, <q-block>). Causal: the sweep only computes from the
-    first diagonal-touching q block, i_min = (j*bk)//bq — which equals
+    """Index map for Q-row input blocks (q, do, per-row stats) on the
+    backward grid (g, <k-block>, <q-block>). Causal: the sweep only computes
+    from the first diagonal-touching q block, i_min = (j*bk)//bq — which equals
     ceil((j*bk - bq + 1)/bq), the smallest i with i*bq + bq - 1 >= j*bk —
     so clamp residency there (same elision mechanics as _kv_residency_map).
     ``window``: clamped from above too, at the last query block that still
@@ -270,97 +275,89 @@ def _flash_fwd(q, k, v, scale, bq, bk, causal, window, interpret):
 # backward
 # ---------------------------------------------------------------------------
 
-def _p_block(q_ref, k_ref, lse_ref, scale, causal, window, i, j):
-    """Recompute the masked probability block P = exp(S - lse). lse_ref
-    holds the (bq, _LANE) lane-broadcast log-sum-exp."""
-    q = q_ref[0]
-    k = k_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    s = _masked(s, i, j, causal, window)
-    return jnp.exp(s - _cols(lse_ref[0], s.shape[1]))
-
-
-def _dq_kernel(scale, nk, bq, bk, causal, window, has_dlse, *refs):
+def _bwd_kernel(scale, nq, nk, bq, bk, causal, window, has_dlse, *refs):
+    """One (key block j, query block i) pair of the backward: the masked
+    probability block P = exp(S - lse) and dS are built once, and all three
+    gradients take their term from them. Grid (g, j, i), the query blocks
+    innermost: dk and dv of block j sum over i in block-sized scratch and
+    leave at the sweep's end; dq is crossed by the sweep, so its float32 sum
+    is held whole for the head (``dq_acc``, (T, Dh)) and each slab leaves at
+    the head's last key block. Each sum adds its blocks in ascending order
+    (dq over j, dk / dv over i), as a pass of its own would."""
     if has_dlse:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, dlse_ref,
-         dq_ref, dq_acc) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
     else:  # hot path (lse output unused): no dlse stream, no dead add
         (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-         dq_ref, dq_acc) = refs
-        dlse_ref = None
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    @pl.when(_computed(i, j, bq, bk, causal, window))
-    def _compute():
-        p = _p_block(q_ref, k_ref, lse_ref, scale, causal, window, i, j)
-        do = do_ref[0]
-        v = v_ref[0]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bk) f32
-        # d lse_i / d s_ij = p_ij, so an lse cotangent adds p * dlse_i
-        dsum = dp - _cols(dcap_ref[0], dp.shape[1])
-        if dlse_ref is not None:
-            dsum = dsum + _cols(dlse_ref[0], dp.shape[1])
-        ds = p * dsum
-        dq_acc[...] += jax.lax.dot(
-            ds.astype(k_ref.dtype), k_ref[0],
-            preferred_element_type=jnp.float32,
-        ) * scale
-
-    @pl.when(j == nk - 1)
-    def _flush():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(scale, nq, bq, bk, causal, window, has_dlse, *refs):
-    if has_dlse:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, dlse_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
         dlse_ref = None
     j = pl.program_id(1)
     i = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(i * bq, bq), bq)  # block i of the head's dq
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((bq, dq_acc.shape[1]), jnp.float32)
 
     @pl.when(i == 0)
-    def _init():
+    def _init_dkv():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     @pl.when(_computed(i, j, bq, bk, causal, window))
     def _compute():
-        p = _p_block(q_ref, k_ref, lse_ref, scale, causal, window, i, j)
-        do = do_ref[0]
+        q = q_ref[0]
+        k = k_ref[0]
         v = v_ref[0]
+        do = do_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        s = _masked(s, i, j, causal, window)
+        p = jnp.exp(s - _cols(lse_ref[0], bk))  # (bq, bk) f32
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
-        )  # pᵀ · do -> (bk, dh)
+        )  # pᵀ · do -> (bk, dv)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dsum = dp - _cols(dcap_ref[0], dp.shape[1])
+        )  # (bq, bk) f32
+        # d lse_i / d s_ij = p_ij, so an lse cotangent adds p * dlse_i
+        dsum = dp - _cols(dcap_ref[0], bk)
         if dlse_ref is not None:
-            dsum = dsum + _cols(dlse_ref[0], dp.shape[1])
-        ds = p * dsum
+            dsum = dsum + _cols(dlse_ref[0], bk)
+        ds = (p * dsum).astype(q.dtype)
         dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0],
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         ) * scale  # dsᵀ · q -> (bk, dh)
+        dq_acc[rows, :] += jax.lax.dot(
+            ds, k, preferred_element_type=jnp.float32) * scale
 
     @pl.when(i == nq - 1)
-    def _flush():
+    def _flush_dkv():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(j == nk - 1)
+    def _flush_dq():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+_VMEM_BYTES = 128 * 2 ** 20  # a v5e core's vector memory
+
+
+def _bwd_vmem_bytes(t, dh, dv, bq, bk, itemsize, n_stats):
+    """What one step of _bwd_kernel holds in VMEM: the head's dq (its
+    float32 sum and the output block, double-buffered by the pipeline), the
+    double-buffered blocks in (q, do and the row statistics; k, v) and out
+    (dk, dv), the dk / dv sums, and the (bq, bk) float32 intermediates (s,
+    p, dp, ds, the mask's two iotas, the operands' copies)."""
+    dq = t * dh * (4 + 2 * itemsize)
+    blocks = 2 * itemsize * (bq * (dh + dv) + 2 * bk * (dh + dv))
+    stats = 2 * 4 * n_stats * bq * _LANE
+    sums = 4 * bk * (dh + dv)
+    return dq + blocks + stats + sums + 8 * 4 * bq * bk
 
 
 @functools.partial(jax.jit,
@@ -368,8 +365,9 @@ def _dkv_kernel(scale, nq, bq, bk, causal, window, has_dlse, *refs):
                                     "interpret"))
 def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, window,
                interpret):
-    """dlse=None is the hot path (lse output unused): the kernels take one
-    fewer input stream and skip the dead add."""
+    """dq, dk, dv in one kernel (_bwd_kernel). dlse=None is the hot path
+    (lse output unused): the kernel takes one fewer input stream and skips
+    the dead add."""
     g, t, dh = q.shape
     dv = v.shape[-1]
     nq, nk = t // bq, t // bk
@@ -382,64 +380,51 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, bq, bk, causal, window,
     if has_dlse:
         stats.append(jnp.broadcast_to(dlse.astype(jnp.float32)[..., None],
                                       (g, t, _LANE)))
+    vmem = _bwd_vmem_bytes(t, dh, dv, bq, bk, q.dtype.itemsize, len(stats))
+    if vmem > _VMEM_BYTES:
+        raise ValueError(
+            f"flash_attention backward: a head's dq (t={t}, dh={dh}) and the "
+            f"blocks (bq={bq}, bk={bk}) want {vmem >> 20} MiB of the chip's "
+            f"{_VMEM_BYTES >> 20} MiB vector memory — shard the sequence "
+            f"(sp_attn=ring) or use attn_impl=dense for this shape")
 
-    def q_row(g, i, j):
-        return (g, i, 0)
+    q_row = _q_residency_map(bq, bk, causal, window, nq)
 
-    k_row = _kv_residency_map(bq, bk, causal, window)
+    def k_row(g, j, i):
+        return (g, j, 0)
 
-    stat_specs = [pl.BlockSpec((1, bq, _LANE), q_row)] * len(stats)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale, nk, bq, bk, causal, window,
+    def head(g, j, i):
+        return (g, 0, 0)
+
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale, nq, nk, bq, bk, causal, window,
                           has_dlse),
-        grid=(g, nq, nk),
+        grid=(g, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, dh), q_row),
             pl.BlockSpec((1, bk, dh), k_row),
             pl.BlockSpec((1, bk, dv), k_row),
             pl.BlockSpec((1, bq, dv), q_row),
-            *stat_specs,
-        ],
-        out_specs=pl.BlockSpec((1, bq, dh), q_row),
-        out_shape=jax.ShapeDtypeStruct((g, t, dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q, k, v, do, *stats)
-
-    q_row2 = _q_residency_map(bq, bk, causal, window, nq)
-
-    def k_row2(g, j, i):
-        return (g, j, 0)
-
-    stat_specs2 = [pl.BlockSpec((1, bq, _LANE), q_row2)] * len(stats)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale, nq, bq, bk, causal, window,
-                          has_dlse),
-        grid=(g, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), q_row2),
-            pl.BlockSpec((1, bk, dh), k_row2),
-            pl.BlockSpec((1, bk, dv), k_row2),
-            pl.BlockSpec((1, bq, dv), q_row2),
-            *stat_specs2,
+            *[pl.BlockSpec((1, bq, _LANE), q_row)] * len(stats),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, dh), k_row2),
-            pl.BlockSpec((1, bk, dv), k_row2),
+            pl.BlockSpec((1, t, dh), head),
+            pl.BlockSpec((1, bk, dh), k_row),
+            pl.BlockSpec((1, bk, dv), k_row),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((g, t, dh), q.dtype),
             jax.ShapeDtypeStruct((g, t, dh), k.dtype),
             jax.ShapeDtypeStruct((g, t, dv), v.dtype),
         ],
         scratch_shapes=[
+            pltpu.VMEM((t, dh), jnp.float32),
             pltpu.VMEM((bk, dh), jnp.float32),
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem,
         ),
         interpret=interpret,
     )(q, k, v, do, *stats)

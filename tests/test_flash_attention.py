@@ -3,8 +3,9 @@ exercised by tools/tpu_attn_check.py on hardware).
 
 Oracle: parallel/ring_attention.dense_attention — the streaming-softmax
 reference the ring path is tested against. Forward values AND input
-gradients must match: the backward pass is a hand-written two-kernel
-custom VJP, the most bug-prone part."""
+gradients must match: the backward pass is a hand-written custom VJP —
+one kernel that builds each probability block once and takes dq, dk and dv
+from it — the most bug-prone part."""
 
 import jax
 import jax.numpy as jnp
@@ -45,45 +46,162 @@ def test_forward_uneven_blocks(rng, bq, bk):
                                rtol=2e-5, atol=2e-5)
 
 
+def _grads(attn, loss_of, args):
+    return jax.grad(lambda *a: loss_of(attn(*a)), argnums=(0, 1, 2))(*args)
+
+
+def _assert_grads_close(got, want, atol):
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = max(np.abs(b).max(), 1e-8)
+        np.testing.assert_allclose(a / scale, b / scale, atol=atol,
+                                   err_msg=f"d{name} mismatch")
+
+
 def test_grads_uneven_blocks(rng):
-    """bq > bk through the custom VJP (both backward kernels' predicates)."""
+    """bq > bk through the custom VJP (the backward kernel's predicate)."""
     q, k, v = _qkv(rng, t=256, dh=64)
     tgt = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
 
-    def loss(attn):
-        return lambda q, k, v: jnp.sum((attn(q, k, v) - tgt) ** 2)
+    def loss_of(o):
+        return jnp.sum((o - tgt) ** 2)
 
     flash = lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=64,
                                             force=True, interpret=True)
     dense = lambda q, k, v: dense_attention(q, k, v, causal=True)
-    g_f = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_d = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip("qkv", g_f, g_d):
-        a, b = np.asarray(a), np.asarray(b)
-        scale = max(np.abs(b).max(), 1e-8)
-        np.testing.assert_allclose(a / scale, b / scale, atol=5e-5,
-                                   err_msg=f"d{name} mismatch")
+    _assert_grads_close(_grads(flash, loss_of, (q, k, v)),
+                        _grads(dense, loss_of, (q, k, v)), atol=5e-5)
 
 
-def test_grads_match_dense(rng):
-    q, k, v = _qkv(rng, t=256, dh=64)
-    tgt = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+# (T, query heads, key/value heads, q/k head size, v head size, bq, bk):
+# several query blocks cross every key block's sweep and several key blocks
+# add into every query block's dq
+GRAD_SHAPES = {
+    "square": (256, 2, 2, 64, 64, None, 1024),
+    "latent_qk192_v128": (256, 2, 2, 192, 128, 64, 128),  # kanana2's MLA
+    "grouped_query_32_on_4": (128, 32, 4, 16, 16, 32, 64),  # mellum2's heads
+    "grouped_query_d256": (256, 4, 1, 256, 256, 128, 64),  # qwen3next, bq > bk
+}
+
+
+@pytest.mark.parametrize("shape", sorted(GRAD_SHAPES))
+def test_grads_match_dense(rng, shape):
+    """dq, dk and dv of the fused backward against the dense reference at
+    the head layouts the cells run: padded q/k heads beside narrower v heads,
+    and key/value heads shared by several query heads."""
+    t, h, kv, dh, dv, bq, bk = GRAD_SHAPES[shape]
+    q, k, v = (jnp.asarray(rng.normal(size=s).astype(np.float32))
+               for s in [(1, t, h, dh), (1, t, kv, dh), (1, t, kv, dv)])
+    tgt = jnp.asarray(rng.normal(size=(1, t, h, dv)).astype(np.float32))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=bq, block_k=bk, force=True,
+                               interpret=True)
+
+    def dense(q, k, v):
+        return dense_attention(q, *fa.spread_kv_heads(h, k, v), causal=True)
+
+    def loss_of(o):
+        return jnp.sum((o - tgt) ** 2)
+
+    _assert_grads_close(_grads(flash, loss_of, (q, k, v)),
+                        _grads(dense, loss_of, (q, k, v)), atol=5e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_grads_with_a_live_lse_match_dense(rng, causal):
+    """``flash_attention_with_lse`` under a loss that reads BOTH outputs:
+    the ring's merge differentiates the log-sum-exp, so the fused kernel
+    takes the dlse stream (d lse / d s = softmax) — causal (the self hop)
+    and fully visible (a past owner's hop)."""
+    from draco_tpu.parallel.ring_attention import dense_attention_lse
+
+    q, k, v = _qkv(rng, b=1, t=256, dh=64)
 
     def loss(attn):
         def f(q, k, v):
-            o = attn(q, k, v)
-            return jnp.sum((o - tgt) ** 2)
+            o, lse = attn(q, k, v)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(lse))
         return f
 
-    flash = lambda q, k, v: flash_attention(q, k, v, force=True, interpret=True)
-    dense = lambda q, k, v: dense_attention(q, k, v, causal=True)
-    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_dense = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip("qkv", g_flash, g_dense):
-        a, b = np.asarray(a), np.asarray(b)
-        scale = max(np.abs(b).max(), 1e-8)
-        np.testing.assert_allclose(a / scale, b / scale, atol=5e-5,
-                                   err_msg=f"d{name} mismatch")
+    def flash(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, causal=causal, block_q=64,
+                                           block_k=128, interpret=True)
+
+    def dense(q, k, v):
+        return dense_attention_lse(q, k, v, causal=causal)
+
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    _assert_grads_close(got, want, atol=5e-5)
+
+
+def _pallas_calls(jaxpr) -> int:
+    """pallas_call equations of a jaxpr, those of nested jaxprs (pjit,
+    custom_vjp, cond) included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pallas_calls(sub)
+    return n
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_backward_is_one_kernel(rng, window):
+    """One ``flash_attention`` call under ``jax.grad`` holds the forward
+    kernel and exactly ONE backward kernel: the probability blocks are
+    rebuilt once, not once for dq and once more for dk / dv."""
+    q, k, v = _qkv(rng, b=1, t=128, dh=64)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, window=window, block_q=32,
+                               block_k=64, interpret=True)
+
+    forward = _pallas_calls(jax.make_jaxpr(attn)(q, k, v).jaxpr)
+    both = _pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(attn(*a) ** 2), argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert forward == 1
+    assert both - forward == 1
+
+
+def test_three_evaluations_give_the_same_bits(rng):
+    """The vote's premise: three lanes that compute the same row hold the
+    same gradient, bit for bit — also through the dq sum that stays in the
+    kernel's scratch across a head's key blocks."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(rng, b=1, t=256, dh=64))
+    grads = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, block_q=64, block_k=128, interpret=True
+        ).astype(jnp.float32))), argnums=(0, 1, 2)))
+    first = grads(q, k, v)
+    for _ in range(2):
+        for a, b in zip(first, grads(q, k, v)):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+    # three lanes of one program (the step's own form: a map over lanes)
+    lanes = jax.jit(jax.vmap(grads))(*(jnp.stack([x] * 3) for x in (q, k, v)))
+    for a in lanes:
+        np.testing.assert_array_equal(np.asarray(a[0], np.float32),
+                                      np.asarray(a[1], np.float32))
+        np.testing.assert_array_equal(np.asarray(a[0], np.float32),
+                                      np.asarray(a[2], np.float32))
+
+
+def test_backward_refuses_a_head_that_cannot_stay_in_vector_memory():
+    """A head's dq sum lives in VMEM whole: a row too long for that raises
+    at trace time, naming the way out, instead of failing in the chip's
+    compiler."""
+    g, t, d = 1, 2 ** 17, 256
+    x = jax.ShapeDtypeStruct((g, t, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((g, t), jnp.float32)
+    with pytest.raises(ValueError, match="vector memory"):
+        jax.eval_shape(lambda q, k, v, o, lse, do: fa._flash_bwd(
+            q, k, v, o, lse, do, None, 0.0625, 512, 1024, True, None, False),
+            x, x, x, x, lse, x)
+    assert fa._bwd_vmem_bytes(4096, 256, 128, 512, 1024, 2, 2) < 2 ** 25
+    assert fa._bwd_vmem_bytes(8192, 128, 128, 1024, 1024, 2, 2) < 2 ** 26
 
 
 def test_flash_through_model_matches_dense(rng, monkeypatch):

@@ -97,6 +97,11 @@ def test_the_windows_edge_is_exact(impl, t, window):
     (96, 40, 32, 16, 2, 1),   # across several key blocks
     (64, 100, 16, 16, 2, 2),  # a window longer than the row: plain causal
     (256, 100, 32, 128, 2, 2),  # a key block of a whole lane tile
+    # bq != bk, a window that is a multiple of neither, grouped-query: three
+    # to four query blocks cross a key block's sweep and two to three key
+    # blocks add into a query block's dq, in the one backward kernel
+    (256, 72, 32, 64, 4, 2),
+    (256, 72, 64, 32, 4, 1),
 ])
 def test_kernel_matches_dense_forward_and_all_three_gradients(
         t, window, bq, bk, heads, kv):

@@ -13,6 +13,13 @@ the same math; a mis-tiled config raises at lowering, not silently).
 
 Writes --out (default baselines_out/tpu_attn_tune.json) after every row,
 so a run cut short keeps finished rows (decode_study r3 precedent).
+
+``--point <name>`` (several, comma-separated) times instead one layer-lane
+of a benchmark cell's attention as the cell runs it — heads, T, Dh, Dv, key /
+value heads, window (POINTS), bfloat16, the kernel's own blocks: the forward,
+forward + backward through the public entry with all three gradients live,
+and ``_flash_bwd`` alone on the folded arrays. One row a point; PERF.md
+section 6 (PR 38) holds the readings.
 """
 
 from __future__ import annotations
@@ -23,6 +30,82 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+# one layer-lane of an LM cell's attention:
+# (heads, T, Dh, Dv, key/value heads, window)
+POINTS = {
+    "kanana2": (32, 4096, 192, 128, 32, None),
+    "qwen3next": (16, 4096, 256, 256, 2, None),
+    "mellum2_full": (32, 8192, 128, 128, 4, None),
+    "mellum2_sliding": (32, 8192, 128, 128, 4, 1024),
+}
+
+
+def time_point(name, reps, interpret=False):
+    """fwd_ms, fwdbwd_ms and bwd_ms (``_flash_bwd`` alone) of one named
+    point. Every output of the op under test reaches the carry: a gradient
+    no one reads would let XLA drop the kernel that makes it."""
+    import jax
+    import jax.numpy as jnp
+
+    from draco_tpu.ops import flash_attention as fa
+    from draco_tpu.utils.timing import timeit_chained
+
+    heads, t, dh, dv, kv, window = POINTS[name]
+    if interpret:
+        t = 256
+    key = jax.random.key(0)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, n), shape
+                                 ).astype(jnp.bfloat16)
+               for n, shape in enumerate([(1, t, heads, dh), (1, t, kv, dh),
+                                          (1, t, kv, dv)]))
+
+    def attn(q, k, v):
+        return fa.flash_attention(q, k, v, window=window, force=True,
+                                  interpret=interpret)
+
+    def touch(*xs):  # one element of each: a kernel's call is all or nothing
+        return sum(x[(0,) * x.ndim].astype(jnp.float32) for x in xs)
+
+    def fwd_step(qc, k, v):
+        return qc + (1e-30 * touch(attn(qc, k, v))).astype(qc.dtype)
+
+    grads = jax.grad(
+        lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v).astype(jnp.float32))),
+        argnums=(0, 1, 2))
+
+    def fb_step(qc, k, v):
+        return qc + (1e-30 * touch(*grads(qc, k, v))).astype(qc.dtype)
+
+    # the kernel's own operands: heads folded, head sizes padded to lanes
+    block_q = fa.BLOCK_Q if window is None else fa.WINDOW_BLOCK_Q
+    bq = fa._fit_block(block_q, t, lane_rule=False)
+    bk = fa._fit_block(1024, t, lane_rule=True)
+    scale = 1.0 / dh ** 0.5
+
+    def fold(x):
+        x = jnp.moveaxis(x, 2, 1).reshape(heads, t, x.shape[-1])
+        return jnp.pad(x, ((0, 0), (0, 0), (0, -x.shape[-1] % 128)))
+
+    fq, fk, fv = (fold(x) for x in (q, *fa.spread_kv_heads(heads, k, v)))
+    o, lse = fa._flash_fwd(fq, fk, fv, scale, bq, bk, True, window, interpret)
+
+    def bwd_step(do, q, k, v, o, lse):
+        return do + (1e-30 * touch(*fa._flash_bwd(
+            q, k, v, o, lse, do, None, scale, bq, bk, True, window,
+            interpret))).astype(do.dtype)
+
+    rec = {"point": name, "heads": heads, "seq_len": t, "head_dim": dh,
+           "v_head_dim": dv, "kv_heads": kv, "window": window,
+           "block_q": bq, "block_k": bk}
+    for label, step, carry, consts in [
+            ("fwd_ms", fwd_step, q, (k, v)),
+            ("fwdbwd_ms", fb_step, q, (k, v)),
+            ("bwd_ms", bwd_step, jnp.ones_like(o), (fq, fk, fv, o, lse))]:
+        rec[label] = round(
+            timeit_chained(step, carry, consts, reps=reps) * 1e3, 3)
+    return rec
 
 
 def main(argv=None) -> int:
@@ -38,6 +121,9 @@ def main(argv=None) -> int:
     ap.add_argument("--blocks-q", type=str, default="128,256,512")
     ap.add_argument("--blocks-k", type=str, default="128,256,512,1024")
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--point", type=str, default="",
+                    help=f"named layer-lanes to time instead of the sweep: "
+                         f"{','.join(POINTS)}")
     ap.add_argument("--cpu-interpret", action="store_true",
                     help="smoke: run tiny shapes in interpret mode on CPU")
     args = ap.parse_args(argv)
@@ -56,6 +142,21 @@ def main(argv=None) -> int:
     from draco_tpu.ops.flash_attention import flash_attention
     from draco_tpu.parallel.ring_attention import dense_attention
     from draco_tpu.utils.timing import timeit_chained
+
+    if args.point:
+        dev = jax.devices()[0]
+        report = {"platform": dev.platform,
+                  "device_kind": getattr(dev, "device_kind", dev.platform),
+                  "rows": []}
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        for name in args.point.split(","):
+            rec = time_point(name, args.reps, interpret=args.cpu_interpret)
+            print(f"[tune] {json.dumps(rec)}", file=sys.stderr, flush=True)
+            report["rows"].append(rec)
+            with open(args.out, "w") as fh:
+                json.dump(report, fh, indent=1)
+        print(json.dumps(report))
+        return 0
 
     t, b, h, dh = args.seq_len, args.batch, args.heads, args.head_dim
     r = np.random.RandomState(0)
