@@ -27,20 +27,21 @@ AGG_MODES = ("normal", "geometric_median", "krum", "coord_median",
 # (parallel/token_loop.py) and come from models.build_lm; everything else is
 # an image model on the CNN Trainer.
 TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM", "HybridMoeLM",
-                  "WindowedMoeLM")
+                  "WindowedMoeLM", "LoopedLM")
 # the token models stated by ONE mapping of a published config's keys
 # (TrainConfig.model_spec), each with the module that checks and builds it
 SPEC_NETWORKS = {"LatentMoeLM": "draco_tpu.models.latent_moe",
                  "HybridMoeLM": "draco_tpu.models.hybrid_moe",
-                 "WindowedMoeLM": "draco_tpu.models.windowed_moe"}
+                 "WindowedMoeLM": "draco_tpu.models.windowed_moe",
+                 "LoopedLM": "draco_tpu.models.looped"}
 
 
 @dataclasses.dataclass
 class TrainConfig:
     # --- model / data (reference: distributed_nn.py:27-37) ---
     # LeNet | FC | ResNet18/34/50/101/152 | VGG11/13/16/19[_bn] | the token
-    # models TransformerLM | LatentMoeLM | HybridMoeLM | WindowedMoeLM
-    # (TOKEN_NETWORKS)
+    # models TransformerLM | LatentMoeLM | HybridMoeLM | WindowedMoeLM |
+    # LoopedLM (TOKEN_NETWORKS)
     network: str = "LeNet"
     dataset: str = "MNIST"  # MNIST | Cifar10 | synthetic variants
     data_dir: str = "./data"
@@ -176,11 +177,12 @@ class TrainConfig:
     model_dim: int = 128
     model_heads: int = 4
     model_layers: int = 2
-    # network=LatentMoeLM | HybridMoeLM | WindowedMoeLM (SPEC_NETWORKS): the
-    # ONE mapping that states the model — a published config.json's keys
-    # verbatim plus ``layers``, ``experts_held`` ([first, count]) and
-    # ``vocab_rows``, the chip's share of a deployment (models/latent_moe.py,
-    # hybrid_moe.py, windowed_moe.py).
+    # network=LatentMoeLM | HybridMoeLM | WindowedMoeLM | LoopedLM
+    # (SPEC_NETWORKS): the ONE mapping that states the model — a published
+    # config.json's keys verbatim plus ``layers``, ``vocab_rows`` and, for
+    # the sparse-expert blocks, ``experts_held`` ([first, count]): the chip's
+    # share of a deployment (models/latent_moe.py, hybrid_moe.py,
+    # windowed_moe.py, looped.py).
     # The model_* fields above are TransformerLM's and are not read for it.
     # CLI: --model-spec <file.json>.
     model_spec: Optional[dict] = None
@@ -944,9 +946,9 @@ class TrainConfig:
                     raise ValueError(
                         f"{name}={getattr(self, name)} with network="
                         f"{self.network} is not implemented: the block runs on "
-                        "the single-shard token route (its experts are the "
-                        "chip's share of a deployment, stated in "
-                        "model_spec['experts_held'], not an ep mesh axis)"
+                        "the single-shard token route (the chip's share of "
+                        "a deployment is stated in model_spec — layers, "
+                        "experts_held, vocab_rows — not by a mesh axis)"
                     )
             if self.pp_microbatches > 0 or self.moe_experts > 0 \
                     or self.scan_layers:

@@ -58,8 +58,10 @@ import numpy as np
 from jax import lax
 
 from draco_tpu.models.latent_moe import (
-    EMBED_STD, STAT_NAMES, MoeSpec, RoutedExpertLM, _dot, _operand,
-    fold_stats, rms_norm, rope_half,
+    STAT_NAMES, MoeSpec, RoutedExpertLM, fold_stats,
+)
+from draco_tpu.models.spec_lm import (
+    EMBED_STD, _dot, _operand, rms_norm, rope_half,
 )
 from draco_tpu.ops.delta_rule import (
     CHUNK, SOLVE_NAME, chunked_gated_delta_rule, rule_runs_in_kernels,

@@ -44,10 +44,12 @@ runs over the next C sorted pairs, and the next, until it has passed
 in the likely case); the counter ``moe_full_dispatch`` says how many such
 further buffers the step's expert layers ran.
 
-The expert layer, the head and the loss are ``RoutedExpertLM``'s, the base
-every published-config model builds on (models/hybrid_moe.py and
-models/windowed_moe.py are the others): ONE ``_choose`` (scores, top-k,
-combine weights) and ONE ``_route`` / ``_buffer`` / ``grouped_dot`` path,
+Seeded init, head and loss are ``spec_lm.SpecLM``'s, the base every
+published-config model builds on (the dense models/looped.py too); the
+expert layer is ``RoutedExpertLM``'s, the sparse-expert models' part of it
+(models/hybrid_moe.py and models/windowed_moe.py are the others): ONE
+``_choose`` (scores, top-k, combine weights) and ONE ``_route`` /
+``_buffer`` / ``grouped_dot`` path,
 told by ``MoeSpec`` which score function ranks the experts (``sigmoid``
 with the selection bias and the routed scale, or ``softmax``), whether the
 model has a shared expert at all — one that has none carries no ``shared``
@@ -80,8 +82,11 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from draco_tpu.models.spec_lm import (  # noqa: F401  (helpers by this name)
+    EMBED_STD, SpecLM, _dot, _operand, dense_causal_attention, rms_norm,
+    rope_half, swiglu,
+)
 from draco_tpu.ops.coded import use_pallas
-from draco_tpu.ops.flash_attention import spread_kv_heads
 
 # the published config keys the block reads (model_spec must carry them)
 SPEC_KEYS = (
@@ -96,16 +101,6 @@ SPEC_KEYS = (
     # the chip's share
     "layers", "experts_held", "vocab_rows",
 )
-INIT_STD = 0.02  # initializer_range is not in the published config
-# The embedding alone is seeded at unit scale. Every block reads its input
-# through an RMS norm, so what a block adds does not shrink with its input:
-# beside rows of std 0.02 the stream after layer 0 is the attention's running
-# mean of v — the same vector at every position — and every token of a
-# sequence takes the same six experts (measured at the published widths:
-# single experts of the eight held got 0 to 2 982 of 4 096 tokens, the chip's
-# share 3 096 to 7 891 pairs by seed, and the step time followed it). A
-# trained model's stream is the token's own, as it is here at unit scale.
-EMBED_STD = 1.0
 BIAS_STD = 0.02  # e_score_correction_bias: moves the top-6, not the load
 # The dispatch buffer's rows, in units of what uniform routing sends this
 # chip (T·k·held/n_routed_experts pairs). A deployment's balancing keeps a
@@ -177,21 +172,6 @@ def check_spec(spec) -> None:
         raise ValueError("vocab_rows must be >= 2")
 
 
-def _operand(x):
-    """What a kernel's product is handed. On the TPU a float32 product at
-    default precision rounds its operands to bfloat16 and accumulates in
-    float32; the Pallas kernels take the type they are given, so they are
-    given what XLA's own products get. Elsewhere products are float32."""
-    return x.astype(jnp.bfloat16) if use_pallas() else x
-
-
-def rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-                        + eps)
-    return (y * scale).astype(x.dtype)
-
-
 def rope_interleaved(x, positions, theta):
     """Rotate the pairs (x[2i], x[2i+1]) of the last axis by
     positions·theta^(-2i/dim). x: (B, T, ..., dim), positions: (T,)."""
@@ -203,44 +183,6 @@ def rope_interleaved(x, positions, theta):
     a, b = x[..., 0::2], x[..., 1::2]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape)
-
-
-def rope_half(x, positions, freqs, factor: float = 1.0):
-    """Rotate the pairs (x[i], x[i + n]) of the first 2n dims of the last
-    axis by positions·freqs[i], n = len(freqs), cos and sin times
-    ``factor``; the dims past 2n pass. x: (B, T, H, dim), positions: (T,)."""
-    half = len(freqs)
-    ang = positions.astype(jnp.float32)[:, None] * freqs  # (T, half)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    if factor != 1.0:
-        cos, sin = cos * factor, sin * factor
-    a, b = x[..., :half], x[..., half:2 * half]
-    parts = [a * cos - b * sin, b * cos + a * sin]
-    if 2 * half < x.shape[-1]:
-        parts.append(x[..., 2 * half:])
-    return jnp.concatenate(parts, axis=-1)
-
-
-def dense_causal_attention(q, k, v, window=None):
-    """(B, T, H, Dh) q, k and (B, T, H, Dv) v -> (B, T, H, Dv): the plain
-    lowering where no kernel is selected (parallel/ring_attention.
-    dense_attention, the one the kernels fall back to). k and v may have
-    fewer heads (grouped-query attention). ``window``: a query sees itself
-    and the ``window - 1`` tokens before it; None sees every earlier
-    token."""
-    from draco_tpu.parallel.ring_attention import dense_attention
-
-    k, v = spread_kv_heads(q.shape[2], k, v)
-    return dense_attention(q, k, v, window=window)
-
-
-def _dot(x, kernel):
-    return x @ kernel.astype(x.dtype)
-
-
-def swiglu(h, p):
-    return _dot(jax.nn.silu(_dot(h, p["gate"]["kernel"]))
-                * _dot(h, p["up"]["kernel"]), p["down"]["kernel"])
 
 
 def _all_buffers(buffer, h, w, e, dispatch, needed):
@@ -325,52 +267,18 @@ class MoeSpec(NamedTuple):
     dense: bool = False
 
 
-class RoutedExpertLM:
-    """The part every published-config model here shares: seeded ``init``
-    over ``param_shapes()``, the expert layer over the experts held, the
-    head, the loss. A model adds ``param_shapes``, ``norm(x, p)`` (its RMS
-    norm over a norm's leaves ``p``), ``hidden`` and ``init_rules`` (leaf
-    name -> ``"ones"`` | ``"zeros"`` | a normal's std; ``INIT_STD``
-    otherwise).
-
-    ``init(key) -> params``; ``token_nll(params, tokens, targets,
-    pos_offset, train) -> (nll (B, T) float32, stats)``. ``attn_fn``: (q,
-    k, v) -> o with v's own head size (ops/flash_attention.flash_attention
-    on the TPU); None is the plain lowering. ``remat``: rematerialise each
-    layer in the backward pass. ``stat_names``: the counters' names, in the
-    order a step's metric row carries them."""
+class RoutedExpertLM(SpecLM):
+    """The sparse-expert models' part of ``spec_lm.SpecLM`` (seeded
+    ``init``, head and loss are the base's): the expert layer over the
+    experts held, told by ``moe`` what its model's router and shared expert
+    are."""
 
     stat_names = STAT_NAMES
-    init_rules: dict = {}
 
     def __init__(self, spec: dict, moe: MoeSpec, attn_fn=None,
                  dtype=jnp.float32, remat: bool = False):
-        self.spec = dict(spec)
+        super().__init__(spec, attn_fn, dtype, remat)
         self.moe = moe
-        self.attn_fn = attn_fn or dense_causal_attention
-        self.dtype = jnp.dtype(dtype)
-        self.remat = remat
-
-    # ---- parameters ---------------------------------------------------
-    def mlp_shapes(self, width: int, lead=()) -> dict:
-        d = self.spec["hidden_size"]
-        return {"gate": {"kernel": lead + (d, width)},
-                "up": {"kernel": lead + (d, width)},
-                "down": {"kernel": lead + (width, d)}}
-
-    def init(self, key):
-        shapes = self.param_shapes()
-        paths, treedef = jax.tree_util.tree_flatten_with_path(
-            shapes, is_leaf=lambda x: isinstance(x, tuple))
-        leaves = []
-        for i, (path, shape) in enumerate(paths):
-            rule = self.init_rules.get(path[-1].key, INIT_STD)
-            k = jax.random.fold_in(key, i)
-            if rule in ("ones", "zeros"):
-                leaves.append(getattr(jnp, rule)(shape, jnp.float32))
-            else:
-                leaves.append(rule * jax.random.normal(k, shape, jnp.float32))
-        return jax.tree_util.tree_unflatten(treedef, leaves)
 
     # ---- the expert layer ---------------------------------------------
     def dispatch_rows(self, tokens: int) -> int:
@@ -529,30 +437,6 @@ class RoutedExpertLM:
                 shared = jax.nn.sigmoid(
                     _dot(h, p["shared_gate"]["kernel"])) * shared
             return x + (routed + shared), stats
-
-    # ---- head and loss ------------------------------------------------
-    def _head(self, params, x):
-        x = self.norm(x, params["final_norm"])
-        return _dot(x, params["head"]["kernel"]).astype(jnp.float32)
-
-    def logits(self, params, tokens, pos_offset=0):
-        """tokens (B, T) -> (B, T, vocab_rows) float32."""
-        x, _ = self.hidden(params, tokens, pos_offset)
-        with jax.named_scope("draco_head"):
-            return self._head(params, x)
-
-    def token_nll(self, params, tokens, targets, pos_offset=0,
-                  train: bool = True):
-        """tokens, targets (B, T) -> (per-position negative log-likelihood
-        (B, T) float32 over the vocabulary slice, the ``stat_names``
-        counters)."""
-        del train  # no dropout in these blocks
-        x, stats = self.hidden(params, tokens, pos_offset)
-        with jax.named_scope("draco_head"):
-            logp = jax.nn.log_softmax(self._head(params, x))
-            nll = -jnp.take_along_axis(logp, targets[..., None],
-                                       axis=-1)[..., 0]
-        return nll, stats
 
 
 class LatentMoeLM(RoutedExpertLM):

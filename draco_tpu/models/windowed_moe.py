@@ -57,8 +57,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from draco_tpu.models.latent_moe import (
-    DENSE_SHARE, EMBED_STD, KEEP_DENSE, STAT_NAMES, MoeSpec, RoutedExpertLM,
-    _dot, _operand, dense_causal_attention, fold_stats, rms_norm, rope_half,
+    DENSE_SHARE, KEEP_DENSE, STAT_NAMES, MoeSpec, RoutedExpertLM, fold_stats,
+)
+from draco_tpu.models.spec_lm import (
+    EMBED_STD, _dot, _operand, dense_causal_attention, rms_norm, rope_half,
 )
 from draco_tpu.ops.flash_attention import runs_in_kernels
 

@@ -141,6 +141,21 @@ def _flash_latent():
     return fn, [(MLA_QK, jnp.bfloat16)] * 2 + [(MLA_V, jnp.bfloat16)]
 
 
+# ouro.maj_vote_r3: 16 heads of 128, as many key/value heads, 4 096 tokens
+MHA = (1, 4096, 16, 128)
+
+
+def _flash_equal_heads():
+    """Forward and backward at the looped model's shape: equal head sizes,
+    one key/value head a query head, bfloat16 as the model hands them."""
+    def fn(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, force=True).astype(jnp.float32))), argnums=(0, 1, 2))(
+                q, k, v)
+
+    return fn, [(MHA, jnp.bfloat16)] * 3
+
+
 # qwen3next.maj_vote_r3: 16 query heads of 256 on 2 key/value heads
 GQA_Q, GQA_KV = (1, 4096, 16, 256), (1, 4096, 2, 256)
 
@@ -183,10 +198,12 @@ def _grouped_dot():
     matrices."""
     from unittest import mock
 
-    from draco_tpu.models import latent_moe
+    from draco_tpu.models import latent_moe, spec_lm
 
     def fn(xs, kernels, sizes):
-        with mock.patch.object(latent_moe, "use_pallas", lambda: True):
+        # (the operand rule, ``_operand``, asks spec_lm's name)
+        with mock.patch.object(latent_moe, "use_pallas", lambda: True), \
+                mock.patch.object(spec_lm, "use_pallas", lambda: True):
             return jax.grad(lambda xs, kernels: jnp.sum(
                 latent_moe.grouped_dot(xs, kernels, sizes, GMM_HELD) ** 2),
                 argnums=(0, 1))(xs, kernels)
@@ -235,6 +252,7 @@ CASES = {
     "flash_grad_with_lse_fully_visible": _flash_lse,
     "flash_grad_qk192_v128": _flash_latent,
     "flash_grad_16_heads_on_2_d256": _flash_grouped_query,
+    "flash_grad_16_heads_d128": _flash_equal_heads,
     "flash_grad_window_1024_32_heads_on_4": _flash_windowed,
     "grouped_dot_8_of_128": _grouped_dot,
     "delta_rule_fwd": lambda: _delta_rule(grad=False),
@@ -343,7 +361,7 @@ def test_the_dense_expert_layer_is_three_plain_products(one_chip):
     import re
     from unittest import mock
 
-    from draco_tpu.models import latent_moe
+    from draco_tpu.models import latent_moe, spec_lm
     from draco_tpu.models.windowed_moe import WindowedMoeLM
 
     with open(os.path.join(os.path.dirname(os.path.dirname(
@@ -361,7 +379,8 @@ def test_the_dense_expert_layer_is_three_plain_products(one_chip):
                              sharding=one_chip)
 
     def fn(x, p):
-        with mock.patch.object(latent_moe, "use_pallas", lambda: True):
+        with mock.patch.object(latent_moe, "use_pallas", lambda: True), \
+                mock.patch.object(spec_lm, "use_pallas", lambda: True):
             return jax.grad(lambda x, p: jnp.sum(
                 jnp.sin(lm._experts(x, p)[0])), argnums=(0, 1))(x, p)
 
@@ -375,6 +394,36 @@ def test_the_dense_expert_layer_is_three_plain_products(one_chip):
     # gate, up, down; each one's two transposes
     assert len(products) == 9, len(products)
     assert any("draco_route" in line for line in text.splitlines())
+
+
+def test_four_exits_head_holds_one_blocks_logits_at_a_time(one_chip):
+    """ouro.maj_vote_r3's head and loss (models/spec_lm.blocked_nll), forward
+    and backward, at the cell's size — four exits' 16 384 rows against the
+    whole 49 152-row vocabulary: logits exist a block of 2 048 rows at a
+    time (0.4 GB), never all rows' (3.2 GB), and the program's temporaries
+    stay under the state's and the weight gradient's 0.4 GB each plus a
+    block's few arrays."""
+    from draco_tpu.models import spec_lm
+
+    rows, hidden, vocab = 4 * 4096, 2048, 49152
+    block = spec_lm.head_block_rows(vocab)
+    assert block == 2048
+
+    def fn(h, kernel, targets):
+        with jax.named_scope("draco_head"):
+            return jax.grad(lambda h, kernel: jnp.sum(spec_lm.blocked_nll(
+                h, kernel, targets)), argnums=(0, 1))(h, kernel)
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((rows, hidden), jnp.float32),
+                                 ((hidden, vocab), jnp.float32),
+                                 ((rows,), jnp.int32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"[{rows},{vocab}]" not in text
+    assert f"[{rows // block},{block},{vocab}]" not in text
+    assert f"f32[{block},{vocab}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 def test_resnet18_step_scopes_on_the_described_chip(one_chip, monkeypatch):

@@ -28,7 +28,8 @@ with open(TINY) as fh:
     SPEC = json.load(fh)["train_config"]["model_spec"]
 SPECS = {"LatentMoeLM": SPEC}
 for _network, _file in (("HybridMoeLM", "hybrid-moe-tiny.json"),
-                        ("WindowedMoeLM", "windowed-moe-tiny.json")):
+                        ("WindowedMoeLM", "windowed-moe-tiny.json"),
+                        ("LoopedLM", "looped-tiny.json")):
     with open(os.path.join(ROOT, "benchmark", "testdata", _file)) as fh:
         SPECS[_network] = json.load(fh)["train_config"]["model_spec"]
 STEPS = 4
@@ -71,7 +72,8 @@ def _run(cfg):
 
 
 @pytest.fixture(scope="module", params=["LatentMoeLM", "TransformerLM",
-                                        "HybridMoeLM", "WindowedMoeLM"])
+                                        "HybridMoeLM", "WindowedMoeLM",
+                                        "LoopedLM"])
 def runs(request):
     attacked = _run(_cfg(request.param))
     clean = _run(_cfg(request.param, adversary_count=0))
@@ -132,6 +134,18 @@ def test_the_new_network_reports_its_experts_counters():
         assert r["moe_load_max_over_mean"] >= 1.0
 
 
+def test_the_looped_network_reports_its_exits_counters():
+    _, rows = _run(_cfg("LoopedLM", num_workers=3, max_steps=3))
+    for r in rows:
+        assert r["loop_passes"] == SPECS["LoopedLM"]["total_ut_steps"] == 4
+        assert 1.0 < r["exit_pass_mean"] < 4.0
+        assert 0.0 < r["exit_entropy"] <= np.log(4) + 1e-6
+        assert np.isfinite(r["exit_ce_first"])
+        assert np.isfinite(r["exit_ce_last"])
+    # the gate starts at a bias of zero: 1/2, 1/4, 1/8, 1/8
+    assert rows[0]["exit_pass_mean"] == pytest.approx(1.875, abs=0.05)
+
+
 def test_the_chunked_loop_runs_the_same_steps():
     """K = 2 through ``train_token_many``: the metric block's columns are
     the eager record's, the vote's and the experts' included."""
@@ -148,7 +162,8 @@ def test_the_chunked_loop_runs_the_same_steps():
 
 
 @pytest.mark.parametrize("network", ["LatentMoeLM", "TransformerLM",
-                                     "HybridMoeLM", "WindowedMoeLM"])
+                                     "HybridMoeLM", "WindowedMoeLM",
+                                     "LoopedLM"])
 def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     """The large side of ``sp_step.LANES_IN_TURN_BYTES`` at the tiny size:
     lanes in turn (``lax.map``), each layer rematerialised, the stack in
@@ -187,6 +202,7 @@ PUBLISHED = {
                 (0, 0, 768, 340_349_184)),
     "qwen3next": ("HybridMoeLM", "qwen3-next-80b-a3b-ep32.json",
                   (2, 192, 960, 424_340_544)),
+    "ouro": ("LoopedLM", "ouro-2.6b-l4.json", (2, 2049, 1023, 406_884_353)),
 }
 
 
@@ -196,7 +212,7 @@ def _bits(x):
 
 @pytest.mark.parametrize("case", ["LatentMoeLM", "TransformerLM",
                                   "HybridMoeLM", "WindowedMoeLM",
-                                  *PUBLISHED])
+                                  "LoopedLM", *PUBLISHED])
 def test_a_lanes_row_is_written_in_whole_lines(case):
     """``sp_step._write_row``: the leaves cut into pieces that each start
     and end on a 128-wide line, each written into its range of the lane's
@@ -205,7 +221,8 @@ def test_a_lanes_row_is_written_in_whole_lines(case):
     rows are not touched and ``unravel`` hands every leaf back; on the
     published leaf tables (shapes only) the recorded layout reads what the
     cells run: every leaf a piece of its own but qwen3next's two (3, 32)
-    leaves, which close the row together with its zeros."""
+    leaves and ouro's gate (a one-element bias and 2 048 weights), which
+    close the row together with its zeros."""
     import jax.numpy as jnp
 
     from draco_tpu.models import build_lm
@@ -296,13 +313,14 @@ def test_a_stack_with_rows_of_several_axes_votes_the_same(layout):
     assert float(hg["vote_agree"]) == float(hw["vote_agree"])
 
 
-def test_cli_trains_the_new_network_coded_and_attacked(tmp_path):
+@pytest.mark.parametrize("network", ["LatentMoeLM", "LoopedLM"])
+def test_cli_trains_the_new_network_coded_and_attacked(network, tmp_path):
     from draco_tpu import cli
 
     spec = tmp_path / "spec.json"
-    spec.write_text(json.dumps(SPEC))
+    spec.write_text(json.dumps(SPECS[network]))
     last = cli.main([
-        "--network", "LatentMoeLM", "--model-spec", str(spec), "--dataset",
+        "--network", network, "--model-spec", str(spec), "--dataset",
         "synthetic-text", "--approach", "maj_vote", "--num-workers", "3",
         "--group-size", "3", "--worker-fail", "1", "--err-mode", "rev_grad",
         "--batch-size", "2", "--seq-len", "32", "--vocab", "64",

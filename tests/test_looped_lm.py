@@ -1,0 +1,380 @@
+"""models/looped.LoopedLM at a tiny size (hidden 64, 4 heads of 16, SwiGLU
+of 96, 2 layers kept, a whole vocabulary of 64) against the plain reference
+the benchmark compares it with on the chip (benchmark/reference/nets/
+ouro.py, which imports nothing of draco_tpu and loops in Python):
+
+* the objective, every exit's cross-entropy, the exit distribution and
+  every leaf's gradient on seeded weights, the norms' weights and the gate
+  moved off their initial values, at ``total_ut_steps`` 1, 2 and 4, each
+  layer application rematerialised and not;
+* the loop is over the SAME leaves: a shared leaf's gradient is the sum of
+  the passes' taken apart;
+* the exit distribution sums to one and the last pass takes the rest; the
+  entropy term's gradient reaches the gate;
+* the head and loss a block of rows at a time (spec_lm.blocked_nll) are the
+  whole-array form, values and gradients, for one exit and four and on all
+  four published-config models;
+* a one-element leaf goes through the vote stack's row layout, wherever it
+  lies, and the published leaf table keeps it last;
+* a mapping the block cannot state is refused by the key's name.
+
+Tolerances: program and reference are float32 sums of the same terms in
+another order (a scan against a Python loop, log-sigmoids against
+products): 2e-6 relative on the objective, 2e-5 absolute on cross-entropies
+of order one, 2e-4 of a leaf's largest gradient entry.
+"""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference.nets import ouro as ref  # noqa: E402
+from draco_tpu.config import SPEC_NETWORKS, TrainConfig  # noqa: E402
+from draco_tpu.models import build_lm, looped, spec_lm  # noqa: E402
+from draco_tpu.models.looped import LoopedLM, exit_log_probs  # noqa: E402
+
+TESTDATA = os.path.join(ROOT, "benchmark", "testdata")
+with open(os.path.join(TESTDATA, "looped-tiny.json")) as fh:
+    SPEC = json.load(fh)["train_config"]["model_spec"]
+T = 40
+
+
+def _tokens(seed=0, batch=2, t=T, vocab=SPEC["vocab_rows"]):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.integers(0, vocab, (batch, t)), jnp.int32)
+
+
+def _loss(lm, params, toks):
+    nll, stats = lm.token_nll(params, toks, jnp.roll(toks, -1, axis=1))
+    return jnp.mean(nll[:, :-1]), stats
+
+
+def _moved(params, seed=1):
+    """Every leaf off its initial value, so that a norm left out or applied
+    twice, a gate without its bias, shows."""
+    leaves, treedef = jax.tree.flatten(params)
+    return jax.tree.unflatten(treedef, [
+        x + 0.1 * jax.random.normal(jax.random.key(seed + i), x.shape)
+        for i, x in enumerate(leaves)])
+
+
+def _close(got, want, what):
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-12,
+            err_msg=f"{what}: {jax.tree_util.keystr(path)}")
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_objective_exits_and_gradients_are_the_references(steps, remat):
+    spec = dict(SPEC, total_ut_steps=steps)
+    lm = LoopedLM(spec, remat=remat)
+    params = _moved(lm.init(jax.random.key(0)))
+    toks = _tokens()
+    (loss, stats), grad = jax.jit(jax.value_and_grad(
+        lambda p: _loss(lm, p, toks), has_aux=True))(params)
+    want, want_grad = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss(p, toks, spec)))(params)
+    np.testing.assert_allclose(loss, want, rtol=2e-6)
+    _close(grad, want_grad, f"steps {steps}")
+    ce, logp = jax.jit(lm.exit_terms)(params, toks,
+                                      jnp.roll(toks, -1, axis=1))
+    assert ce.shape == logp.shape == (steps,) + toks.shape
+    for b in range(toks.shape[0]):
+        ref_ce, ref_p = jax.jit(
+            lambda p, seq: ref.exits(p, seq, spec))(params, toks[b])
+        np.testing.assert_allclose(ce[:, b, :-1], ref_ce, atol=2e-5)
+        np.testing.assert_allclose(jnp.exp(logp[:, b, :-1]), ref_p,
+                                   atol=2e-6)
+    assert set(stats) == set(lm.stat_names)
+    assert lm.stat_names == looped.STAT_NAMES
+    assert float(stats["loop_passes"]) == steps
+    # the counters are means over every position of the row
+    p = jnp.exp(logp)
+    np.testing.assert_allclose(stats["exit_ce_first"], jnp.mean(ce[0]),
+                               rtol=1e-6)
+    np.testing.assert_allclose(stats["exit_ce_last"], jnp.mean(ce[-1]),
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        stats["exit_pass_mean"],
+        jnp.mean(sum((t + 1) * p[t] for t in range(steps))), rtol=1e-6)
+    np.testing.assert_allclose(stats["exit_entropy"],
+                               jnp.mean(-jnp.sum(p * logp, axis=0)),
+                               rtol=1e-5, atol=1e-7)
+    # the last exit's logits are what ``logits`` hands back
+    np.testing.assert_allclose(
+        jax.jit(lm.logits)(params, toks)[0],
+        jax.jit(lambda p: ref.logits(p, toks[0], spec))(params), atol=2e-5)
+
+
+def test_a_shared_leafs_gradient_is_the_sum_of_the_four_passes():
+    lm = LoopedLM(SPEC)
+    params = _moved(lm.init(jax.random.key(3)))
+    toks = _tokens(3)
+    steps, layers = SPEC["total_ut_steps"], SPEC["layers"]
+    names = [f"layer{i}" for i in range(layers)]
+
+    def apart(copies):
+        """The same model, pass t reading its own copy of the layers."""
+        twin = LoopedLM(SPEC)
+
+        def passes(p, tokens, pos_offset=0):
+            positions = pos_offset + jnp.arange(tokens.shape[1])
+            x, out = p["embed"]["embedding"][tokens], []
+            for copy in copies:
+                for name in names:
+                    x = twin._layer(x, copy[name], positions)
+                x = twin.norm(x, p["final_norm"])
+                out.append(x)
+            return jnp.stack(out)
+
+        twin.passes = passes
+        return _loss(twin, params, toks)[0]
+
+    shared = jax.jit(jax.grad(lambda p: _loss(lm, p, toks)[0]))(params)
+    each = jax.jit(jax.grad(apart))([{n: params[n] for n in names}] * steps)
+    assert len(each) == steps
+    summed = jax.tree.map(lambda *g: sum(g), *each)
+    _close({n: shared[n] for n in names}, summed, "sum of the passes")
+    # and no pass is idle: each one's share of a leaf is not zero
+    for g in each:
+        assert float(jnp.max(jnp.abs(g["layer0"]["q"]["kernel"]))) > 0
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4, 7])
+def test_the_exits_share_one_and_the_last_pass_takes_the_rest(steps):
+    z = 3.0 * jax.random.normal(jax.random.key(steps), (steps, 5, 11))
+    logp = exit_log_probs(z)
+    p, lam = jnp.exp(logp), jax.nn.sigmoid(z)
+    np.testing.assert_allclose(jnp.sum(p, axis=0), 1.0, atol=1e-6)
+    left = jnp.ones(z.shape[1:])
+    for t in range(steps - 1):
+        np.testing.assert_allclose(p[t], lam[t] * left, atol=1e-6)
+        left = left * (1.0 - lam[t])
+    np.testing.assert_allclose(p[-1], left, atol=1e-6)
+    # the last gate is not read: the last pass takes what is left
+    moved = exit_log_probs(z.at[-1].add(5.0))
+    assert np.array_equal(np.asarray(moved), np.asarray(logp))
+
+
+def test_the_entropy_terms_gradient_reaches_the_gate(monkeypatch):
+    """With a head of zeros every exit's cross-entropy is log V whatever
+    the gate says, so Σ pᵗ·CEᵗ is a constant of the gate: what reaches
+    ``loop_exit`` is the entropy term alone, −β·∇H."""
+    lm = LoopedLM(SPEC)
+    params = _moved(lm.init(jax.random.key(5)))
+    params["head"]["kernel"] = jnp.zeros_like(params["head"]["kernel"])
+    toks = _tokens(5)
+    targets = jnp.roll(toks, -1, axis=1)
+    grad = jax.jit(jax.grad(lambda p: jnp.mean(
+        lm.token_nll(p, toks, targets)[0])))(params)["loop_exit"]
+    entropy = jax.jit(jax.grad(lambda p: lm.token_nll(p, toks, targets)[1][
+        "exit_entropy"]))(params)["loop_exit"]
+    assert float(jnp.max(jnp.abs(grad["kernel"]))) > 1e-6
+    _close(grad, jax.tree.map(lambda g: -looped.ENTROPY_WEIGHT * g, entropy),
+           "entropy term")
+    monkeypatch.setattr(looped, "ENTROPY_WEIGHT", 0.0)
+    none = jax.jit(jax.grad(lambda p: jnp.mean(
+        lm.token_nll(p, toks, targets)[0])))(params)["loop_exit"]
+    assert float(jnp.max(jnp.abs(none["kernel"]))) < 1e-7
+    assert float(jnp.abs(none["bias"][0])) < 1e-7
+
+
+# ---- the head and loss a block of rows at a time ----------------------
+
+@pytest.mark.parametrize("lead", [(1, 37), (4, 2, 37), (2, 64)])
+def test_the_blocked_head_is_the_whole_array_form(lead, monkeypatch):
+    """One exit's rows ((B, T)) and four exits' ((R, B, T)), a row count
+    that is and is not a multiple of the block: values and all three
+    gradients."""
+    hidden, vocab = 32, 50
+    h = jax.random.normal(jax.random.key(0), lead + (hidden,))
+    kernel = 0.3 * jax.random.normal(jax.random.key(1), (hidden, vocab))
+    targets = jax.random.randint(jax.random.key(2), lead, 0, vocab)
+    weight = jax.random.normal(jax.random.key(3), lead)
+
+    def total():
+        # (a function of its own a call: jax keeps a function's trace)
+        return lambda h, kernel: jnp.sum(
+            weight * spec_lm.blocked_nll(h, kernel, targets))
+
+    whole = spec_lm.blocked_nll(h, kernel, targets)
+    whole_grad = jax.grad(total(), argnums=(0, 1))(h, kernel)
+    assert "scan" not in str(jax.make_jaxpr(total())(h, kernel))
+    # 16 rows a block: 37 rows are three blocks, the last one padded
+    monkeypatch.setattr(spec_lm, "HEAD_BLOCK_BYTES", 16 * 4 * vocab)
+    assert spec_lm.head_block_rows(vocab) == 16
+    assert "scan" in str(jax.make_jaxpr(total())(h, kernel))
+    blocked = spec_lm.blocked_nll(h, kernel, targets)
+    assert blocked.shape == lead and blocked.dtype == jnp.float32
+    np.testing.assert_allclose(blocked, whole, rtol=1e-6, atol=1e-6)
+    for got, want in zip(jax.grad(total(), argnums=(0, 1))(h, kernel),
+                         whole_grad):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def test_a_blocks_rows_are_a_power_of_two_inside_the_budget():
+    # the cells: an eighth of a vocabulary is one block of every row a
+    # lane has (4 096, mellum2 8 192); four exits against the whole
+    # vocabulary are eight blocks of 2 048
+    assert spec_lm.head_block_rows(12288) == 8192
+    assert spec_lm.head_block_rows(12800) == 8192
+    assert spec_lm.head_block_rows(49152) == 2048
+    for vocab in (2, 64, 12288, 49152, 151936, 2**31):
+        rows = spec_lm.head_block_rows(vocab)
+        assert rows >= 1 and rows & (rows - 1) == 0
+        assert rows == 1 or 4 * vocab * rows <= spec_lm.HEAD_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("network,file,seq_len", [
+    ("LatentMoeLM", "latent-moe-tiny.json", 40),
+    ("HybridMoeLM", "hybrid-moe-tiny.json", 80),
+    ("WindowedMoeLM", "windowed-moe-tiny.json", 40),
+    ("LoopedLM", "looped-tiny.json", 40)])
+def test_every_spec_models_loss_is_the_same_a_block_at_a_time(
+        network, file, seq_len, monkeypatch):
+    with open(os.path.join(TESTDATA, file)) as fh:
+        spec = json.load(fh)["train_config"]["model_spec"]
+    lm = build_lm(TrainConfig(
+        network=network, dataset="synthetic-text", model_spec=spec,
+        vocab=spec["vocab_rows"], seq_len=seq_len, remat=True).validate())
+    assert isinstance(lm, spec_lm.SpecLM) and network in SPEC_NETWORKS
+    params = lm.init(jax.random.key(7))
+    toks = _tokens(7, t=seq_len, vocab=spec["vocab_rows"])
+
+    def run():
+        return jax.jit(jax.value_and_grad(
+            lambda p: _loss(lm, p, toks)[0]))(params)
+
+    whole, whole_grad = run()
+    monkeypatch.setattr(spec_lm, "HEAD_BLOCK_BYTES",
+                        32 * 4 * spec["vocab_rows"])
+    blocked, blocked_grad = run()
+    np.testing.assert_allclose(blocked, whole, rtol=1e-6)
+    _close(blocked_grad, whole_grad, network)
+
+
+# ---- the vote stack's rows --------------------------------------------
+
+@pytest.mark.parametrize("where", ["last", "middle", "first"])
+def test_a_one_element_leaf_goes_through_the_row_layout(where):
+    """``sp_step.row_layout`` / ``_write_row`` with a one-element leaf among
+    whole-line ones: last (where LoopedLM keeps its gate's bias: one small
+    joined piece with the closing zeros), and in the middle or first (every
+    later leaf then reaches a line's end only with the zeros: one long
+    joined piece — slower on the chip, the same bytes)."""
+    from draco_tpu.parallel.sp_step import (
+        STACK_LANES, _write_row, row_layout,
+    )
+    from draco_tpu.training.step import _flatten_tree, _make_unravel
+
+    shapes = [(4, 128), (256,), (2, 3, 128), (128,)]
+    shapes.insert({"last": 4, "middle": 2, "first": 0}[where], (1,))
+    tree = {f"leaf{i}": jax.random.normal(jax.random.key(i), shape)
+            for i, shape in enumerate(shapes)}
+    unravel, dim, offsets = _make_unravel(tree)
+    layout = row_layout(np.diff(offsets))
+    assert dim == 1665 and layout.zeros == 383
+    assert layout.lines * STACK_LANES == dim + layout.zeros
+    if where == "last":
+        assert (layout.joined_leaves, layout.joined_size) == (1, 1)
+        assert len(layout.pieces) == 5
+    else:
+        assert layout.joined_leaves == {"middle": 3, "first": 5}[where]
+    got = jax.jit(lambda s, t: _write_row(s, 1, t, layout))(
+        jnp.full((2, layout.lines, STACK_LANES), jnp.nan), tree)
+    want = jnp.pad(_flatten_tree(tree), (0, layout.zeros)).reshape(
+        -1, STACK_LANES)
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want))
+    assert np.isnan(np.asarray(got[0])).all()
+    for a, b in zip(jax.tree.leaves(unravel(got[1])), jax.tree.leaves(tree)):
+        assert a.shape == b.shape and np.array_equal(np.asarray(a),
+                                                     np.asarray(b))
+
+
+def test_the_published_leaf_table_keeps_the_gate_last():
+    """Every leaf of the configuration the benchmark runs lies on the
+    stack's 128-wide lines but the gate's (2 048 + 1 elements), which sort
+    last in ravel order and close the row with its zeros; d is the
+    configuration's count."""
+    from draco_tpu.parallel.sp_step import row_layout
+    from draco_tpu.training.step import _make_unravel
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "ouro-2.6b-l4.json")) as fh:
+        config = json.load(fh)
+    spec = config["train_config"]["model_spec"]
+    tree = jax.eval_shape(LoopedLM(spec).init, jax.random.key(0))
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(tree)[0]]
+    assert paths[-2:] == ["['loop_exit']['bias']", "['loop_exit']['kernel']"]
+    _, dim, offsets = _make_unravel(tree)
+    assert dim == 406_884_353 and "406 884 353" in config["size"]
+    layout = row_layout(np.diff(offsets))
+    assert (layout.joined_leaves, layout.joined_size, layout.zeros) == (
+        2, 2049, 1023)
+    assert all(off % 128 == 0 for off in offsets[:-2])
+    # the init the configuration states: the gate's bias zeros (all four
+    # exits start near 1/2, 1/4, 1/8, 1/8), norms ones
+    assert LoopedLM.init_rules == {"scale": "ones", "embedding": 1.0,
+                                   "bias": "zeros"}
+    assert config["weights"]["bias"] == "zeros"
+
+
+# ---- what the block refuses -------------------------------------------
+
+@pytest.mark.parametrize("change,names", [
+    ({"layer_types": ["full_attention", "sliding_attention"]},
+     "layer_types"),
+    ({"use_sliding_window": True}, "use_sliding_window"),
+    ({"rope_scaling": {"rope_type": "yarn", "factor": 4}}, "rope_scaling"),
+    ({"total_ut_steps": 0}, "total_ut_steps"),
+    ({"total_ut_steps": 2.5}, "total_ut_steps"),
+    ({"num_key_value_heads": 2}, "num_key_value_heads"),
+    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"layers": 0}, "layers"),
+    ({"layers": 7}, "layers"),
+    ({"head_dim": 15}, "head_dim"),
+    ({"vocab_rows": 1}, "vocab_rows"),
+])
+def test_a_mapping_the_block_cannot_state_is_refused_by_name(change, names):
+    with pytest.raises(ValueError, match=names):
+        looped.check_spec(dict(SPEC, **change))
+
+
+@pytest.mark.parametrize("key", looped.SPEC_KEYS)
+def test_a_missing_key_is_named(key):
+    spec = {k: v for k, v in SPEC.items() if k != key}
+    with pytest.raises(ValueError, match=key):
+        looped.check_spec(spec)
+    with pytest.raises(ValueError, match="model_spec"):
+        TrainConfig(network="LoopedLM", dataset="synthetic-text",
+                    model_spec=spec, vocab=SPEC["vocab_rows"]).validate()
+
+
+def test_the_config_validates_the_mapping_and_builds_the_block():
+    looped.check_spec(SPEC)
+    with pytest.raises(ValueError, match="mapping"):
+        looped.check_spec(None)
+    cfg = TrainConfig(network="LoopedLM", dataset="synthetic-text",
+                      model_spec=SPEC, vocab=SPEC["vocab_rows"],
+                      seq_len=T).validate()
+    assert isinstance(build_lm(cfg), LoopedLM)
+    with pytest.raises(ValueError, match="vocab_rows"):
+        TrainConfig(network="LoopedLM", dataset="synthetic-text",
+                    model_spec=SPEC, vocab=32).validate()
+    with pytest.raises(ValueError, match="seq_shards"):
+        TrainConfig(network="LoopedLM", dataset="synthetic-text",
+                    model_spec=SPEC, vocab=SPEC["vocab_rows"],
+                    seq_shards=2, num_workers=8).validate()
