@@ -119,12 +119,24 @@ class _FlaxTokenLM:
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return nll, {}
 
+    def weighted_nll(self, params, tokens, targets, weights, denom=1.0,
+                     pos_offset=0, train: bool = True):
+        import jax.numpy as jnp
+
+        nll, stats = self.token_nll(params, tokens, targets, pos_offset,
+                                    train)
+        return jnp.sum(nll * weights) / denom, stats
+
 
 def build_lm(cfg, attn_fn=None, kernel_fn=None):
     """The token model of ``cfg.network``: an object with ``init(key) ->
     params``, ``token_nll(params, tokens (B, T), targets (B, T),
     pos_offset, train) -> (per-position negative log-likelihood (B, T)
-    float32, per-step counters {name: scalar})`` and ``stat_names``, those
+    float32, per-step counters {name: scalar})``, ``weighted_nll(params,
+    tokens, targets, weights, denom, pos_offset, train) -> (Σ weights ·
+    token_nll / denom, a scalar, the counters)`` — the training objective's
+    surface: a head that knows the rows' weights can take its gradients in
+    the forward pass (models/spec_lm.py) — and ``stat_names``, those
     counters' names in the metric row's order (empty where a model has
     none). The route offers two attentions ((q, k, v) -> o) and each model
     takes the one it can use: ``attn_fn``, the route's own (sequence-

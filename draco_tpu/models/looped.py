@@ -31,19 +31,26 @@ every pass, the same positions; the normed state is both the pass's exit
 and the next pass's input. The passes are a ``lax.scan`` over the one
 layer stack (the leaves are the scan's constants, so autodiff sums the R
 uses into each leaf); with ``remat`` each of the R·L layer applications is
-its own ``jax.checkpoint``.
+its own ``jax.checkpoint``, and so is each pass's final norm (kept, its
+two float32 intermediates are two more (R, T, hidden) stacks alive where
+the head's state gradient is born: 268 MB of the cell's peak).
 
 The exits (``draco_head``: logitsᵗ = hᵗ·W_head, the one head R times, and
-CEᵗ its next-token cross-entropy — all R·T rows through ``spec_lm.
-blocked_nll``; ``draco_exit``: the rest): gate λᵗ = σ(hᵗ·w_g + b_g)
-(float32 at ``highest``), exit distribution pᵗ = λᵗ ∏_{j<t}(1 − λʲ) for
-t < R and p^R = ∏_{j<R}(1 − λʲ) — the last pass takes what is left, Σ pᵗ
-= 1 —, and per position
+CEᵗ its next-token cross-entropy; ``draco_exit``: the rest): gate λᵗ =
+σ(hᵗ·w_g + b_g) (float32 at ``highest``), exit distribution pᵗ = λᵗ
+∏_{j<t}(1 − λʲ) for t < R and p^R = ∏_{j<R}(1 − λʲ) — the last pass takes
+what is left, Σ pᵗ = 1 —, and per position
 
   ℓ = Σₜ pᵗ·CEᵗ − β·H(p),  H(p) = −Σ pᵗ log pᵗ  (``ENTROPY_WEIGHT``)
 
 which ``token_nll`` returns where the other blocks return a plain negative
-log-likelihood; the route's mean over positions is the loss. The gate's
+log-likelihood (all R·T rows through ``spec_lm.blocked_nll``); the route's
+mean over positions is the loss. The route trains through
+``weighted_nll``, which is handed the positions' weights w: Σ w·ℓ is the
+head's own weighted sum at the rows' weights w·pᵗ (``spec_lm.
+weighted_nll``: a block's forward pass takes the block's gradients, three
+products a block where a rematerialised block ran four) less β·Σ w·H(p),
+and the gate's gradient arrives as the weights' cotangent, CEᵗ. The gate's
 leaves (``loop_exit``: a one-element bias among them) sort last in ravel
 order, so every leaf before them lies on the vote stack's 128-wide lines
 (parallel/sp_step.row_layout).
@@ -63,8 +70,8 @@ import numpy as np
 from jax import lax
 
 from draco_tpu.models.spec_lm import (
-    EMBED_STD, SpecLM, _dot, _operand, blocked_nll, rms_norm, rope_half,
-    swiglu,
+    EMBED_STD, SpecLM, _dot, _operand, blocked_nll, head_blocks_fused,
+    rms_norm, rope_half, swiglu, weighted_nll,
 )
 
 # the published config keys the block reads (model_spec must carry them)
@@ -81,9 +88,11 @@ SPEC_KEYS = (
 ENTROPY_WEIGHT = 0.1
 # per-step counters: passes run; the mean over positions of Σ t·pᵗ, of H(p),
 # and of the first and the last exit's cross-entropy (the loop is doing
-# something when the last is below the first)
+# something when the last is below the first); the head blocks of a lane
+# whose gradients the forward pass took (spec_lm.weighted_nll: 0 where the
+# exits' rows are one block, and under ``token_nll``)
 STAT_NAMES = ("loop_passes", "exit_pass_mean", "exit_entropy",
-              "exit_ce_first", "exit_ce_last")
+              "exit_ce_first", "exit_ce_last", "head_blocks_fused")
 
 
 def check_spec(spec) -> None:
@@ -198,13 +207,14 @@ class LoopedLM(SpecLM):
         """tokens (B, T) -> every pass's normed state (R, B, T, hidden)."""
         positions = pos_offset + jnp.arange(tokens.shape[1])
         layer = functools.partial(self._layer, positions=positions)
+        norm = self.norm
         if self.remat:
-            layer = jax.checkpoint(layer)
+            layer, norm = jax.checkpoint(layer), jax.checkpoint(norm)
 
         def one_pass(x, _):
             for i in range(self.spec["layers"]):
                 x = layer(x, params[f"layer{i}"])
-            x = self.norm(x, params["final_norm"])
+            x = norm(x, params["final_norm"])
             return x, x
 
         x = params["embed"]["embedding"][tokens].astype(self.dtype)
@@ -216,6 +226,11 @@ class LoopedLM(SpecLM):
         exit's rows), no counters."""
         return self.passes(params, tokens, pos_offset)[-1], {}
 
+    def _exit_log_probs(self, params, h):
+        z = jnp.matmul(h.astype(jnp.float32), params["loop_exit"]["kernel"],
+                       precision=lax.Precision.HIGHEST)
+        return exit_log_probs(z + params["loop_exit"]["bias"])
+
     def exit_terms(self, params, tokens, targets, pos_offset=0):
         """tokens, targets (B, T) -> (CEᵗ, log pᵗ), each (R, B, T) float32:
         every exit's next-token cross-entropy and the exit distribution."""
@@ -224,10 +239,18 @@ class LoopedLM(SpecLM):
             ce = blocked_nll(h, params["head"]["kernel"],
                              jnp.broadcast_to(targets, h.shape[:-1]))
         with jax.named_scope("draco_exit"):
-            z = jnp.matmul(h.astype(jnp.float32),
-                           params["loop_exit"]["kernel"],
-                           precision=lax.Precision.HIGHEST)
-            return ce, exit_log_probs(z + params["loop_exit"]["bias"])
+            return ce, self._exit_log_probs(params, h)
+
+    def _exit_stats(self, ce, p, entropy, fused: int = 0) -> dict:
+        order = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
+        return {
+            "loop_passes": jnp.float32(p.shape[0]),
+            "exit_pass_mean": jnp.mean(jnp.tensordot(order, p, axes=1)),
+            "exit_entropy": jnp.mean(entropy),
+            "exit_ce_first": jnp.mean(ce[0]),
+            "exit_ce_last": jnp.mean(ce[-1]),
+            "head_blocks_fused": jnp.float32(fused),
+        }
 
     def token_nll(self, params, tokens, targets, pos_offset=0,
                   train: bool = True):
@@ -239,12 +262,26 @@ class LoopedLM(SpecLM):
             p = jnp.exp(logp)
             entropy = -jnp.sum(p * logp, axis=0)
             objective = jnp.sum(p * ce, axis=0) - ENTROPY_WEIGHT * entropy
-            order = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
-            stats = {
-                "loop_passes": jnp.float32(p.shape[0]),
-                "exit_pass_mean": jnp.mean(jnp.tensordot(order, p, axes=1)),
-                "exit_entropy": jnp.mean(entropy),
-                "exit_ce_first": jnp.mean(ce[0]),
-                "exit_ce_last": jnp.mean(ce[-1]),
-            }
-        return objective, stats
+            return objective, self._exit_stats(ce, p, entropy)
+
+    def weighted_nll(self, params, tokens, targets, weights, denom=1.0,
+                     pos_offset=0, train: bool = True):
+        """Σ ``weights`` · ℓ / denom, a scalar, and the counters: the
+        exits' term Σ w·pᵗ·CEᵗ is the head's own weighted sum (``spec_lm.
+        weighted_nll`` at weights w·pᵗ), so the gate's gradient arrives as
+        the weights' cotangent."""
+        del train  # no dropout in this block
+        h = self.passes(params, tokens, pos_offset)
+        with jax.named_scope("draco_exit"):
+            logp = self._exit_log_probs(params, h)
+            p = jnp.exp(logp)
+        with jax.named_scope("draco_head"):
+            exits, ce = weighted_nll(
+                h, params["head"]["kernel"],
+                jnp.broadcast_to(targets, h.shape[:-1]), weights * p, denom)
+        with jax.named_scope("draco_exit"):
+            entropy = -jnp.sum(p * logp, axis=0)
+            return (exits
+                    - ENTROPY_WEIGHT * jnp.sum(weights * entropy) / denom,
+                    self._exit_stats(ce, p, entropy, head_blocks_fused(
+                        ce.size, self.spec["vocab_rows"])))

@@ -6,13 +6,19 @@ and the head and loss — ``SpecLM``, the base of ``latent_moe.
 RoutedExpertLM`` (the three sparse-expert models) and of ``looped.LoopedLM``
 (dense, its depth a loop over the same leaves).
 
-The head and the loss (``blocked_nll``, under ``draco_head``): logits,
-log-softmax and the target's log-probability a block of rows at a time
-(``head_block_rows``: what ``HEAD_BLOCK_BYTES`` of float32 logits hold),
-each block rematerialised in the backward pass, so that no (rows, V) array
-outlives its block — one exit's rows or four exits' alike. Rows that fit
-one block are that block, with no loop and nothing to rematerialise: the
-whole-array form.
+The head and the loss (under ``draco_head``): logits, log-softmax and the
+target's log-probability a block of rows at a time (``head_block_rows``:
+what ``HEAD_BLOCK_BYTES`` of float32 logits hold), so that no (rows, V)
+array outlives its block — one exit's rows or four exits' alike. Rows that
+fit one block are that block, with no loop: the whole-array form, under
+plain autodiff. ``blocked_nll`` hands back each row's value (its loop
+rematerialises each block where something differentiates it: four products
+a block); ``weighted_nll``, which the training objective goes through, is
+given each row's weight and so knows a block's cotangent in the forward
+pass — softmax − one-hot, times the weight: the block's logits product, the
+state's gradient and the weight gradient (added into one float32
+accumulator the loop carries) are taken there, three products a block, and
+the backward pass scales them by the scalar cotangent and runs none.
 """
 
 from __future__ import annotations
@@ -109,6 +115,17 @@ def _block_nll(h, kernel, targets):
     return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
 
 
+def _in_blocks(rows, *arrays):
+    """Arrays of n leading elements -> a tuple of (blocks, rows, ...)
+    arrays, zeros closing the last block."""
+    n = arrays[0].shape[0]
+    blocks = -(-n // rows)
+    pad = blocks * rows - n
+    return tuple(
+        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            blocks, rows, *a.shape[1:]) for a in arrays)
+
+
 def blocked_nll(h, kernel, targets):
     """h (..., hidden) the head's input rows, ``kernel`` (hidden, V),
     ``targets`` (...) -> each row's negative log-probability of its target,
@@ -117,14 +134,85 @@ def blocked_nll(h, kernel, targets):
     rows = head_block_rows(kernel.shape[-1])
     if n <= rows:
         return _block_nll(h, kernel, targets)
-    blocks = -(-n // rows)
-    pad = blocks * rows - n
-    h = jnp.pad(h.reshape(n, -1), ((0, pad), (0, 0)))
-    targets = jnp.pad(targets.reshape(n), (0, pad))
     nll = lax.map(
         jax.checkpoint(lambda ht: _block_nll(ht[0], kernel, ht[1])),
-        (h.reshape(blocks, rows, -1), targets.reshape(blocks, rows)))
+        _in_blocks(rows, h.reshape(n, -1), targets.reshape(n)))
     return nll.reshape(-1)[:n].reshape(lead)
+
+
+def head_blocks_fused(n_rows: int, vocab_rows: int) -> int:
+    """Blocks of ``n_rows`` head rows whose gradients ``weighted_nll``'s
+    forward pass takes: every one where the rows outgrow one block, none
+    where they are that block."""
+    rows = head_block_rows(vocab_rows)
+    return 0 if n_rows <= rows else -(-n_rows // rows)
+
+
+@jax.custom_vjp
+def _fused_nll(h, kernel, targets, weights):
+    """h (n, hidden), targets and weights (n,) -> (Σ weights·nll, each
+    row's nll (n,)): the primal is the blocked form as it stands (what a
+    forward-only call runs)."""
+    nll = blocked_nll(h, kernel, targets)
+    return jnp.sum(nll * weights), nll
+
+
+def _fused_nll_fwd(h, kernel, targets, weights):
+    n = targets.shape[0]
+    w = kernel.astype(h.dtype)
+    hs, ts, ws = _in_blocks(head_block_rows(kernel.shape[-1]), h, targets,
+                            weights)
+
+    def block(carry, xs):
+        gw, gh = carry
+        i, hb, tb, wb = xs
+        logits = (hb @ w).astype(jnp.float32)
+        # log-softmax as jax.nn.log_softmax writes it
+        shifted = logits - jnp.max(logits, axis=-1, keepdims=True)
+        log_sum = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True))
+        nll = (log_sum - jnp.take_along_axis(shifted, tb[:, None], axis=-1)
+               )[:, 0]
+        hit = tb[:, None] == lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        d = (wb[:, None] * (jnp.exp(shifted - log_sum) - hit)).astype(h.dtype)
+        return (gw + (hb.T @ d).astype(jnp.float32),
+                gh.at[i].set(d @ w.T)), nll
+
+    # the state's gradient rides in the carry, zeros first: as a result
+    # stacked by the loop its buffer is allocated where the lane begins and
+    # held through the whole forward pass (134 MB of the cell's peak)
+    (gw, gh), nll = lax.scan(
+        block, (jnp.zeros(kernel.shape, jnp.float32), jnp.zeros_like(hs)),
+        (jnp.arange(hs.shape[0]), hs, ts, ws))
+    gh, nll = gh.reshape(-1, h.shape[-1])[:n], nll.reshape(-1)[:n]
+    return (jnp.sum(nll * weights), nll), (gh, gw.astype(kernel.dtype), nll)
+
+
+def _fused_nll_bwd(res, cotangents):
+    # exact for every cotangent of the sum, which is a scalar; the rows'
+    # nll carry none (``weighted_nll`` stops it)
+    c, _ = cotangents
+    gh, gw, nll = res
+    return c.astype(gh.dtype) * gh, c.astype(gw.dtype) * gw, None, c * nll
+
+
+_fused_nll.defvjp(_fused_nll_fwd, _fused_nll_bwd)
+
+
+def weighted_nll(h, kernel, targets, weights, denom=1.0):
+    """h (..., hidden), ``kernel`` (hidden, V), ``targets`` (...) and
+    ``weights`` (broadcastable against them) -> (Σ weights·nll / denom, a
+    scalar; each row's nll, float32, carrying no gradient). Rows that
+    outgrow one block take their gradients in the forward pass (module
+    docstring), at weights / denom: a loss that is this sum hands back the
+    cotangent 1, and the backward pass's scaling — a pass over the
+    (hidden, V) weight gradient and one over the state's — folds away."""
+    if not head_blocks_fused(targets.size, kernel.shape[-1]):
+        nll = _block_nll(h, kernel, targets)
+        return jnp.sum(nll * weights) / denom, lax.stop_gradient(nll)
+    total, nll = _fused_nll(
+        h.reshape(targets.size, -1), kernel, targets.reshape(-1),
+        jnp.broadcast_to(weights / denom, targets.shape).reshape(-1))
+    return total, lax.stop_gradient(nll).reshape(targets.shape)
 
 
 class SpecLM:
@@ -197,3 +285,15 @@ class SpecLM:
         h, stats = self.head_rows(params, tokens, pos_offset)
         with jax.named_scope("draco_head"):
             return blocked_nll(h, params["head"]["kernel"], targets), stats
+
+    def weighted_nll(self, params, tokens, targets, weights, denom=1.0,
+                     pos_offset=0, train: bool = True):
+        """tokens, targets (B, T), ``weights`` broadcastable against them
+        (the route's: one a position, (T,)) -> (Σ weights · ``token_nll`` /
+        denom, a scalar, the ``stat_names`` counters): the training
+        objective's surface (``weighted_nll``)."""
+        del train  # no dropout in these blocks
+        h, stats = self.head_rows(params, tokens, pos_offset)
+        with jax.named_scope("draco_head"):
+            return weighted_nll(h, params["head"]["kernel"], targets,
+                                weights, denom)[0], stats

@@ -345,9 +345,9 @@ def build_sp_train_setup(cfg: TrainConfig, mesh) -> SPTrainSetup:
                 jnp.ones((t_local,), jnp.float32),
             )
             denom = toks.shape[0] * (cfg.seq_len - 1)
-            nll, stats = model.token_nll(params, toks, targets, pos_offset=off,
-                                         train=train)
-            return jnp.sum(nll * pos_valid[None, :]) / denom, stats
+            return model.weighted_nll(
+                params, toks, targets, weights=pos_valid,
+                denom=denom, pos_offset=off, train=train)
 
         def over_lanes(fn, tokens):
             """``fn`` on every lane's tokens: side by side, or in turn where

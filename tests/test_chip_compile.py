@@ -426,6 +426,56 @@ def test_four_exits_head_holds_one_blocks_logits_at_a_time(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+@pytest.mark.parametrize("fused,products", [(False, 4), (True, 3)])
+def test_four_exits_head_runs_three_products_a_block_where_four_ran(
+        one_chip, fused, products):
+    """The same head at the same size, value and gradients: through
+    ``spec_lm.weighted_nll`` (the training objective's surface) a block's
+    forward pass takes the block's gradients, and the compiled program
+    holds THREE products against the vocabulary — logits, the state's
+    gradient, the weight gradient — in ONE loop; differentiating
+    ``blocked_nll`` (the blocks rematerialised) it holds four in two."""
+    import re
+
+    from draco_tpu.models import spec_lm
+
+    rows, hidden, vocab = 4 * 4096, 2048, 49152
+    block = spec_lm.head_block_rows(vocab)
+
+    def loss(h, kernel, targets, weights):
+        if fused:
+            return spec_lm.weighted_nll(h, kernel, targets, weights,
+                                        rows)[0]
+        return jnp.sum(spec_lm.blocked_nll(h, kernel, targets)
+                       * weights) / rows
+
+    def fn(h, kernel, targets, weights):
+        with jax.named_scope("draco_head"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 3))(
+                h, kernel, targets, weights)
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((rows, hidden), jnp.float32),
+                                 ((hidden, vocab), jnp.float32),
+                                 ((rows,), jnp.int32),
+                                 ((rows,), jnp.float32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    shapes = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text))
+    against_vocab = [
+        m.group(0) for m in re.finditer(
+            r"%\S+ = \w+\[([\d,]*)\]\S* convolution\(%(\S+), %(\S+)\)",
+            text)
+        if any(str(vocab) in dims.split(",") for dims in (
+            m.group(1), shapes[m.group(2)], shapes[m.group(3)]))]
+    assert len(against_vocab) == products, against_vocab
+    assert len(re.findall(r" while\(", text)) == (1 if fused else 2)
+    # logits a block at a time, on either path
+    assert f"[{rows},{vocab}]" not in text
+    assert f"f32[{block},{vocab}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 def test_resnet18_step_scopes_on_the_described_chip(one_chip, monkeypatch):
     """The TPU program's own labels (ISSUE 24): at ResNet-18 width every
     instruction the program wrote is under a ``draco_*`` scope, the Pallas

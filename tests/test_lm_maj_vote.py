@@ -146,6 +146,27 @@ def test_the_looped_network_reports_its_exits_counters():
     assert rows[0]["exit_pass_mean"] == pytest.approx(1.875, abs=0.05)
 
 
+def test_the_fused_head_trains_the_same_under_the_vote(monkeypatch):
+    """The exits' rows cut into blocks (``HEAD_BLOCK_BYTES`` patched
+    small), so the head takes its gradients in the forward pass (models/
+    spec_lm.weighted_nll) on every lane: the counter says so, the lanes
+    still agree bitwise, the vote names the adversary, and the losses are
+    the whole-array form's to float32 rounding."""
+    from draco_tpu.models import spec_lm
+
+    cfg = _cfg("LoopedLM", num_workers=3, max_steps=3)
+    _, whole = _run(cfg)
+    # 64 rows a block: four exits of 2 x 32 rows are four blocks
+    monkeypatch.setattr(spec_lm, "HEAD_BLOCK_BYTES", 64 * 4 * cfg.vocab)
+    _, fused = _run(cfg)
+    for a, b in zip(fused, whole):
+        assert b["head_blocks_fused"] == 0.0 and a["head_blocks_fused"] == 4.0
+        assert a["det_adv"] == a["det_tp"] == a["located_errors"] == 1.0
+        assert a["vote_agree"] == pytest.approx(2 / 3)
+        assert a["loss"] == pytest.approx(b["loss"], rel=1e-5)
+        assert a["exit_ce_last"] == pytest.approx(b["exit_ce_last"], rel=1e-5)
+
+
 def test_the_chunked_loop_runs_the_same_steps():
     """K = 2 through ``train_token_many``: the metric block's columns are
     the eager record's, the vote's and the experts' included."""

@@ -14,6 +14,14 @@ ouro.py, which imports nothing of draco_tpu and loops in Python):
 * the head and loss a block of rows at a time (spec_lm.blocked_nll) are the
   whole-array form, values and gradients, for one exit and four and on all
   four published-config models;
+* the head that takes its gradients in the forward pass (spec_lm.
+  weighted_nll, the training objective's surface): value and the gradients
+  for the state, the weight and the rows' weights against plain autodiff of
+  the whole-array form, for row counts that fill their blocks and do not,
+  rows of weight zero, an upstream cotangent and a denominator other than
+  one; four exits' rows through ``LoopedLM.weighted_nll`` against Σ w ·
+  ``token_nll``, every leaf's gradient and the counters; rows that fit one
+  block stay plain autodiff, bit for bit what the route computed before;
 * a one-element leaf goes through the vote stack's row layout, wherever it
   lies, and the published leaf table keeps it last;
 * a mapping the block cannot state is refused by the key's name.
@@ -262,6 +270,143 @@ def test_every_spec_models_loss_is_the_same_a_block_at_a_time(
     blocked, blocked_grad = run()
     np.testing.assert_allclose(blocked, whole, rtol=1e-6)
     _close(blocked_grad, whole_grad, network)
+
+
+# ---- the head takes its gradients in the forward pass -----------------
+
+def _plain_weighted(h, kernel, targets, weights, denom):
+    return jnp.sum(spec_lm._block_nll(h, kernel, targets) * weights) / denom
+
+
+@pytest.mark.parametrize("lead,masked,cotangent,denom", [
+    ((4, 1, 32), False, 1.0, 1.0),    # eight whole blocks
+    ((4, 2, 37), False, 1.0, 1.0),    # the last block padded
+    ((1, 37), True, 1.0, 1.0),        # rows of weight zero
+    ((2, 64), False, -2.5, 1.0),      # an upstream cotangent
+    ((4, 2, 37), True, 0.7, 73.0),    # all of it, and a denominator
+])
+def test_the_fused_head_is_plain_autodiff_of_the_whole_array_form(
+        lead, masked, cotangent, denom, monkeypatch):
+    hidden, vocab = 32, 50
+    h = jax.random.normal(jax.random.key(0), lead + (hidden,))
+    kernel = 0.3 * jax.random.normal(jax.random.key(1), (hidden, vocab))
+    targets = jax.random.randint(jax.random.key(2), lead, 0, vocab)
+    weights = jax.random.uniform(jax.random.key(3), lead, minval=0.2)
+    if masked:
+        weights = weights * (jax.random.uniform(jax.random.key(4), lead)
+                             < 0.6)
+
+    def fused():
+        # (a function of its own a call: jax keeps a function's trace)
+        return lambda h, kernel, weights: cotangent * spec_lm.weighted_nll(
+            h, kernel, targets, weights, denom)[0]
+
+    want, want_grad = jax.value_and_grad(
+        lambda *a: cotangent * _plain_weighted(a[0], a[1], targets, a[2],
+                                               denom),
+        argnums=(0, 1, 2))(h, kernel, weights)
+    assert "_fused_nll" not in str(jax.make_jaxpr(fused())(h, kernel,
+                                                           weights))
+    monkeypatch.setattr(spec_lm, "HEAD_BLOCK_BYTES", 16 * 4 * vocab)
+    rows = int(np.prod(lead))
+    assert spec_lm.head_blocks_fused(rows, vocab) == -(-rows // 16)
+    assert "_fused_nll" in str(jax.make_jaxpr(fused())(h, kernel, weights))
+    got, got_grad = jax.value_and_grad(fused(), argnums=(0, 1, 2))(
+        h, kernel, weights)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w, what in zip(got_grad, want_grad, ("h", "kernel", "weights")):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(w))),
+            err_msg=what)
+    # the rows' values come back too, and carry no gradient
+    total, nll = spec_lm.weighted_nll(h, kernel, targets, weights, denom)
+    np.testing.assert_allclose(
+        nll, spec_lm._block_nll(h, kernel, targets), rtol=1e-6, atol=1e-6)
+    assert nll.shape == lead and nll.dtype == jnp.float32
+    assert not np.any(jax.grad(lambda h: jnp.sum(spec_lm.weighted_nll(
+        h, kernel, targets, weights, denom)[1]))(h))
+    # what a forward-only call runs is the blocked form as it stands
+    np.testing.assert_allclose(
+        total, _plain_weighted(h, kernel, targets, weights, denom),
+        rtol=1e-5)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_the_exits_objective_through_the_weighted_surface(fused,
+                                                          monkeypatch):
+    """Σ w·ℓ / denom from ``LoopedLM.weighted_nll`` — the exits' rows at
+    weights w·pᵗ through the head, the gate's gradient arriving as the
+    weights' cotangent — against the same sum over ``token_nll``: value,
+    every leaf's gradient (the gate's among them), the counters."""
+    lm = LoopedLM(SPEC, remat=True)
+    params = _moved(lm.init(jax.random.key(3)))
+    toks = _tokens(3)
+    targets = jnp.roll(toks, -1, axis=1)
+    w = (jnp.arange(T) < T - 1).astype(jnp.float32)[None, :]
+    denom = toks.shape[0] * (T - 1)
+
+    def per_position(p):
+        nll, stats = lm.token_nll(p, toks, targets)
+        return jnp.sum(nll * w) / denom, stats
+
+    def weighted(p):
+        return lm.weighted_nll(p, toks, targets, weights=w, denom=denom)
+
+    (want, want_stats), want_grad = jax.jit(jax.value_and_grad(
+        per_position, has_aux=True))(params)
+    blocks = 0
+    if fused:
+        # 32 rows a block: four exits of 2 x 40 rows are ten blocks
+        monkeypatch.setattr(spec_lm, "HEAD_BLOCK_BYTES",
+                            32 * 4 * SPEC["vocab_rows"])
+        blocks = 10
+    assert ("_fused_nll" in str(jax.make_jaxpr(weighted)(params))) == fused
+    (got, stats), grad = jax.jit(jax.value_and_grad(
+        weighted, has_aux=True))(params)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    _close(grad, want_grad, "weighted_nll")
+    assert float(jnp.max(jnp.abs(grad["loop_exit"]["kernel"]))) > 0
+    assert set(stats) == set(lm.stat_names) == set(looped.STAT_NAMES)
+    assert float(stats["head_blocks_fused"]) == blocks
+    assert float(want_stats["head_blocks_fused"]) == 0
+    for name in looped.STAT_NAMES[:5]:
+        np.testing.assert_allclose(stats[name], want_stats[name], rtol=1e-5,
+                                   err_msg=name)
+
+
+def test_rows_that_fit_one_block_stay_plain_autodiff():
+    """The three sparse cells' bypass: their rows are one block, so the
+    weighted surface is the whole-array form under plain autodiff — the
+    head's ``custom_vjp`` not in the jaxpr, the gradient bit for bit that of
+    Σ w · ``token_nll`` / denom, which the route computed before."""
+    with open(os.path.join(TESTDATA, "latent-moe-tiny.json")) as fh:
+        spec = json.load(fh)["train_config"]["model_spec"]
+    lm = build_lm(TrainConfig(
+        network="LatentMoeLM", dataset="synthetic-text", model_spec=spec,
+        vocab=spec["vocab_rows"], seq_len=T, remat=True).validate())
+    params = lm.init(jax.random.key(11))
+    toks = _tokens(11, vocab=spec["vocab_rows"])
+    targets = jnp.roll(toks, -1, axis=1)
+    w = (jnp.arange(T) < T - 1).astype(jnp.float32)
+    denom = toks.shape[0] * (T - 1)
+
+    def before(p):
+        nll, stats = lm.token_nll(p, toks, targets)
+        return jnp.sum(nll * w[None, :]) / denom, stats
+
+    def now(p):
+        return lm.weighted_nll(p, toks, targets, weights=w[None, :],
+                               denom=denom)
+
+    assert "_fused_nll" not in str(jax.make_jaxpr(now)(params))
+    (want, _), want_grad = jax.jit(jax.value_and_grad(
+        before, has_aux=True))(params)
+    (got, stats), grad = jax.jit(jax.value_and_grad(
+        now, has_aux=True))(params)
+    assert set(stats) == set(lm.stat_names)
+    assert np.array_equal(got, want)
+    for a, b in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
+        assert np.array_equal(a, b)
 
 
 # ---- the vote stack's rows --------------------------------------------
