@@ -47,7 +47,7 @@ further buffers the step's expert layers ran.
 Seeded init, head and loss are ``spec_lm.SpecLM``'s, the base every
 published-config model builds on (the dense models/looped.py too); the
 expert layer is ``RoutedExpertLM``'s, the sparse-expert models' part of it
-(models/hybrid_moe.py and models/windowed_moe.py are the others): ONE
+(models/hybrid_moe.py, windowed_moe.py and conv_moe.py are the others): ONE
 ``_choose`` (scores, top-k, combine weights) and ONE ``_route`` /
 ``_buffer`` / ``grouped_dot`` path,
 told by ``MoeSpec`` which score function ranks the experts (``sigmoid``

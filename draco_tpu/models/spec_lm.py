@@ -3,7 +3,7 @@
 ``init``, the helpers the blocks are written in (RMS norm, half-rotation
 rotary, the products' operand rule, SwiGLU, the plain attention lowering),
 and the head and loss — ``SpecLM``, the base of ``latent_moe.
-RoutedExpertLM`` (the three sparse-expert models) and of ``looped.LoopedLM``
+RoutedExpertLM`` (the four sparse-expert models) and of ``looped.LoopedLM``
 (dense, its depth a loop over the same leaves).
 
 The head and the loss (under ``draco_head``): logits, log-softmax and the
@@ -274,7 +274,7 @@ class SpecLM:
         """tokens (B, T) -> (B, T, vocab_rows) float32."""
         h, _ = self.head_rows(params, tokens, pos_offset)
         with jax.named_scope("draco_head"):
-            return _dot(h, params["head"]["kernel"]).astype(jnp.float32)
+            return _dot(h, self.head_kernel(params)).astype(jnp.float32)
 
     def token_nll(self, params, tokens, targets, pos_offset=0,
                   train: bool = True):
@@ -284,7 +284,7 @@ class SpecLM:
         del train  # no dropout in these blocks
         h, stats = self.head_rows(params, tokens, pos_offset)
         with jax.named_scope("draco_head"):
-            return blocked_nll(h, params["head"]["kernel"], targets), stats
+            return blocked_nll(h, self.head_kernel(params), targets), stats
 
     def weighted_nll(self, params, tokens, targets, weights, denom=1.0,
                      pos_offset=0, train: bool = True):
@@ -295,5 +295,21 @@ class SpecLM:
         del train  # no dropout in these blocks
         h, stats = self.head_rows(params, tokens, pos_offset)
         with jax.named_scope("draco_head"):
-            return weighted_nll(h, params["head"]["kernel"], targets,
+            return weighted_nll(h, self.head_kernel(params), targets,
                                 weights, denom)[0], stats
+
+    # ---- a head tied to the embedding ---------------------------------
+    # (below every line the standing models' steps are traced from: their
+    # lowered programs name source lines, and the compile cache keys on
+    # them)
+    tied_head: bool = False
+
+    def head_kernel(self, params):
+        """The head's (hidden, V) matrix: the ``head`` leaf, or — where the
+        model says ``tied_head`` and so carries no such leaf — the
+        embedding's, transposed: ONE leaf read twice (rows gathered at the
+        bottom, this product at the top), its gradient the sum of both
+        uses."""
+        if self.tied_head:
+            return params["embed"]["embedding"].T
+        return params["head"]["kernel"]

@@ -13,6 +13,7 @@ chip.
 Skipped where the topology cannot be described (no TPU compiler installed).
 """
 
+import functools
 import os
 
 import jax
@@ -192,6 +193,22 @@ def _flash_windowed():
     return fn, [(SWA_Q, jnp.bfloat16)] + [(SWA_KV, jnp.bfloat16)] * 2
 
 
+# lfm2.maj_vote_r3: 32 query heads of 64 on 8 key/value heads, 4 096 tokens
+# (each head padded to a lane tile of 128 inside the kernels' folding)
+GQA64_Q, GQA64_KV = (1, 4096, 32, 64), (1, 4096, 8, 64)
+
+
+def _flash_grouped_query_d64():
+    """Forward and backward at heads of 64, half a lane tile, the key/value
+    heads spread over the four query heads each serves."""
+    def fn(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, force=True).astype(jnp.float32))), argnums=(0, 1, 2))(
+                q, k, v)
+
+    return fn, [(GQA64_Q, jnp.bfloat16)] + [(GQA64_KV, jnp.bfloat16)] * 2
+
+
 def _grouped_dot():
     """models/latent_moe.grouped_dot's kernel (jax's megablox) at the
     cell's shapes, forward and backward, with only the held groups'
@@ -254,20 +271,31 @@ CASES = {
     "flash_grad_16_heads_on_2_d256": _flash_grouped_query,
     "flash_grad_16_heads_d128": _flash_equal_heads,
     "flash_grad_window_1024_32_heads_on_4": _flash_windowed,
+    "flash_grad_32_heads_on_8_d64": _flash_grouped_query_d64,
     "grouped_dot_8_of_128": _grouped_dot,
     "delta_rule_fwd": lambda: _delta_rule(grad=False),
     "delta_rule_grad": lambda: _delta_rule(grad=True),
 }
 
 
+_TEXTS: dict = {}
+
+
+def _compiled_text(case, one_chip) -> str:
+    """The case's program compiled for the described chip, as text; compiled
+    once a process (two tests read the rule's and the window's)."""
+    if case not in _TEXTS:
+        fn, specs = CASES[case]()
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        _TEXTS[case] = jax.jit(fn).lower(*args).compile().as_text()
+    return _TEXTS[case]
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip):
-    fn, specs = CASES[case]()
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-            for shape, dtype in specs]
-    compiled = jax.jit(fn).lower(*args).compile()
     # a kernel that quietly became plain XLA would pass a compile
-    assert "tpu_custom_call" in compiled.as_text(), case
+    assert "tpu_custom_call" in _compiled_text(case, one_chip), case
 
 
 def test_every_kernel_of_the_rule_carries_the_rules_scope(one_chip):
@@ -278,10 +306,7 @@ def test_every_kernel_of_the_rule_carries_the_rules_scope(one_chip):
     ``deltarule_roofline`` nothing to divide by."""
     import re
 
-    fn, specs = _delta_rule(grad=True)
-    text = jax.jit(fn).lower(*[
-        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-        for shape, dtype in specs]).compile().as_text()
+    text = _compiled_text("delta_rule_grad", one_chip)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
@@ -301,10 +326,7 @@ def test_every_kernel_of_the_window_carries_the_windows_scope(one_chip):
     the query heads' count."""
     import re
 
-    fn, specs = _flash_windowed()
-    text = jax.jit(fn).lower(*[
-        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-        for shape, dtype in specs]).compile().as_text()
+    text = _compiled_text("flash_grad_window_1024_32_heads_on_4", one_chip)
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -394,6 +416,70 @@ def test_the_dense_expert_layer_is_three_plain_products(one_chip):
     # gate, up, down; each one's two transposes
     assert len(products) == 9, len(products)
     assert any("draco_route" in line for line in text.splitlines())
+
+
+def test_a_short_conv_layer_lowers_for_the_chip(one_chip):
+    """lfm2.maj_vote_r3's convolution layer at the published widths (a row
+    of 4 096 tokens; published layer 3: the operator, then 8 of 32 experts
+    over every token), forward and backward under the per-layer
+    rematerialisation, for the described chip (the attention layer's
+    kernels at heads of 64 are the case ``flash_grad_32_heads_on_8_d64``).
+    The operator's products carry ``draco_conv`` and run no kernel; the
+    expert layer is plain products under ``draco_experts`` with no sort
+    and no loop."""
+    held_index, kind = 3, "conv"
+    import json
+    import re
+    from unittest import mock
+
+    from draco_tpu.models import latent_moe, spec_lm
+    from draco_tpu.models.conv_moe import ShortConvMoeLM
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "lfm2-8b-a1b-ep4.json")) as fh:
+        spec = json.load(fh)["train_config"]["model_spec"]
+    lm = ShortConvMoeLM(dict(spec, layers=1, layers_held=[held_index]),
+                        attn_fn=functools.partial(flash_attention,
+                                                  force=True), remat=True)
+    assert lm.layer_types == [kind] and lm.moe.dense
+    t, d = 4096, spec["hidden_size"]
+
+    def struct(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    params = jax.tree.map(struct, lm.param_shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    params.pop("embed")
+    x = struct((1, t, d))
+
+    def fn(x, params):
+        def loss(x, params):
+            y, stats = lm.hidden(
+                dict(params, embed={"embedding": x[0]}),
+                jnp.arange(t)[None])
+            return jnp.sum(jnp.sin(y)) + stats["short_conv_absmax"]
+
+        with mock.patch.object(latent_moe, "use_pallas", lambda: True), \
+                mock.patch.object(spec_lm, "use_pallas", lambda: True):
+            return jax.grad(loss, argnums=(0, 1))(x, params)
+
+    text = jax.jit(fn).lower(x, params).compile().as_text()
+    ops = re.findall(r" = \S+ ([a-z\-]+)\(", text)
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    products = [line for line in text.splitlines()
+                if " convolution(" in line]
+    assert kernels == []
+    # W_in and W_out, each one's two transposes, and the recomputed forward
+    # products the backward pass reads
+    assert sum("draco_conv" in line for line in products) >= 6
+    assert any("draco_experts" in line for line in products)
+    assert any("draco_route" in line for line in text.splitlines())
+    # (the one scatter is this test's: the gradient of its embedding gather)
+    for op in ("sort", "while"):
+        assert op not in ops, op
 
 
 def test_four_exits_head_holds_one_blocks_logits_at_a_time(one_chip):

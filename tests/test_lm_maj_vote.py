@@ -29,7 +29,8 @@ with open(TINY) as fh:
 SPECS = {"LatentMoeLM": SPEC}
 for _network, _file in (("HybridMoeLM", "hybrid-moe-tiny.json"),
                         ("WindowedMoeLM", "windowed-moe-tiny.json"),
-                        ("LoopedLM", "looped-tiny.json")):
+                        ("LoopedLM", "looped-tiny.json"),
+                        ("ShortConvMoeLM", "conv-moe-tiny.json")):
     with open(os.path.join(ROOT, "benchmark", "testdata", _file)) as fh:
         SPECS[_network] = json.load(fh)["train_config"]["model_spec"]
 STEPS = 4
@@ -73,7 +74,7 @@ def _run(cfg):
 
 @pytest.fixture(scope="module", params=["LatentMoeLM", "TransformerLM",
                                         "HybridMoeLM", "WindowedMoeLM",
-                                        "LoopedLM"])
+                                        "LoopedLM", "ShortConvMoeLM"])
 def runs(request):
     attacked = _run(_cfg(request.param))
     clean = _run(_cfg(request.param, adversary_count=0))
@@ -146,6 +147,21 @@ def test_the_looped_network_reports_its_exits_counters():
     assert rows[0]["exit_pass_mean"] == pytest.approx(1.875, abs=0.05)
 
 
+def test_the_short_conv_networks_counters_ride_in_every_record(runs):
+    """Of the fixture's networks the one with the convolution operators:
+    its counters are in every record of the attacked run."""
+    (_, rows), _ = runs
+    if "short_conv_layers" not in rows[0]:
+        assert "tied_head" not in rows[0]
+        return
+    for r in rows:
+        assert r["short_conv_layers"] == 4.0 and r["tied_head"] == 1.0
+        assert 0.0 < r["short_conv_absmax"] < 100.0
+        assert r["moe_dropped"] == r["moe_full_dispatch"] == 0.0
+        # two groups of three lanes, 2 x 32 tokens, top-2, four layers
+        assert 0 < r["moe_assignments_held"] <= 2 * 32 * 2 * 4
+
+
 def test_the_fused_head_trains_the_same_under_the_vote(monkeypatch):
     """The exits' rows cut into blocks (``HEAD_BLOCK_BYTES`` patched
     small), so the head takes its gradients in the forward pass (models/
@@ -184,7 +200,7 @@ def test_the_chunked_loop_runs_the_same_steps():
 
 @pytest.mark.parametrize("network", ["LatentMoeLM", "TransformerLM",
                                      "HybridMoeLM", "WindowedMoeLM",
-                                     "LoopedLM"])
+                                     "LoopedLM", "ShortConvMoeLM"])
 def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     """The large side of ``sp_step.LANES_IN_TURN_BYTES`` at the tiny size:
     lanes in turn (``lax.map``), each layer rematerialised, the stack in
@@ -224,6 +240,8 @@ PUBLISHED = {
     "qwen3next": ("HybridMoeLM", "qwen3-next-80b-a3b-ep32.json",
                   (2, 192, 960, 424_340_544)),
     "ouro": ("LoopedLM", "ouro-2.6b-l4.json", (2, 2049, 1023, 406_884_353)),
+    "lfm2": ("ShortConvMoeLM", "lfm2-8b-a1b-ep4.json",
+             (2, 128, 768, 507_820_288)),
 }
 
 
@@ -233,7 +251,7 @@ def _bits(x):
 
 @pytest.mark.parametrize("case", ["LatentMoeLM", "TransformerLM",
                                   "HybridMoeLM", "WindowedMoeLM",
-                                  "LoopedLM", *PUBLISHED])
+                                  "LoopedLM", "ShortConvMoeLM", *PUBLISHED])
 def test_a_lanes_row_is_written_in_whole_lines(case):
     """``sp_step._write_row``: the leaves cut into pieces that each start
     and end on a 128-wide line, each written into its range of the lane's
@@ -243,7 +261,8 @@ def test_a_lanes_row_is_written_in_whole_lines(case):
     published leaf tables (shapes only) the recorded layout reads what the
     cells run: every leaf a piece of its own but qwen3next's two (3, 32)
     leaves and ouro's gate (a one-element bias and 2 048 weights), which
-    close the row together with its zeros."""
+    close the row together with its zeros, and lfm2's two (1, 64) q/k norm
+    weights, one line together."""
     import jax.numpy as jnp
 
     from draco_tpu.models import build_lm
