@@ -25,7 +25,9 @@ device fetches in steady state** and zero overhead when disabled.
                 ``loop.prologue`` / ``loop.epilogue``.
                 The eager loop's per-step record carries the same ledger
                 as seconds (utils/metrics.Segments): ``t_fetch``,
-                ``t_comp`` = ``t_dispatch + t_wait + t_drain``, ``t_book``.
+                ``t_comp`` = ``t_dispatch + t_wait + t_drain``, ``t_book``
+                — and ``ahead``, 1.0 where the Trainer's loop dispatched
+                the step before it waited for the one before.
   heartbeat.py  RunHeartbeat — ``train_dir/status.json`` rewritten
                 atomically at every flush boundary (step, steps/s, ETA,
                 last loss, decode health, prefetch queue depth, compile

@@ -14,6 +14,11 @@ everything a dashboard or a watchdog needs —
                                              adversary schedule, last decode
                                              residual / vote agreement
   prefetch_depth                             in-flight prefetch requests
+  ahead_share                                share of the run's steps that
+                                             the eager loop dispatched
+                                             before their predecessor was
+                                             waited for (the records'
+                                             ``ahead`` column)
   setup                                      what came before the first
                                              step, as of the first beat:
                                              the process's set-up ledger
@@ -107,6 +112,9 @@ STATUS_BLOCKS = {
     # the set-up ledger as of the run's first beat (``setup_block``):
     # ADDITIVE under schema 5
     "setup": 5,
+    # the eager loop's run-ahead share (the records' ``ahead`` column):
+    # ADDITIVE under schema 5
+    "ahead_share": 5,
 }
 KNOWN_STATUS_SCHEMAS = tuple(range(2, STATUS_SCHEMA + 1))
 
@@ -200,6 +208,7 @@ class RunHeartbeat:
         self._guard_trips = 0.0
         self._skipped_steps = 0.0
         self._guard_seen = False  # any record carried guard columns
+        self._ahead = self._ahead_n = 0.0  # the ``ahead`` column, summed
         self._last: dict = {}
         # numerics-observatory fold (ISSUE 10): the ``numerics`` status
         # block accumulated from the nx_*/shadow_* columns, plus the
@@ -272,6 +281,9 @@ class RunHeartbeat:
             # block carries the last residual/bound/coverage instead, and
             # the empty detection denominators read as the healthy 1.0
             self._last_health_rec = record
+        if "ahead" in record:
+            self._ahead += float(record["ahead"])
+            self._ahead_n += 1
         if "guard_trips" in record:
             self._guard_trips += float(record["guard_trips"])
             self._skipped_steps += float(record.get("skipped_steps", 0.0))
@@ -421,6 +433,8 @@ class RunHeartbeat:
         if self._guard_seen:
             payload["guard"] = {"trips": self._guard_trips,
                                 "skipped_steps": self._skipped_steps}
+        if self._ahead_n:
+            payload["ahead_share"] = round(self._ahead / self._ahead_n, 4)
         if self.ledger is not None and self.ledger.active:
             # per-worker forensics (obs/forensics.AccusationLedger):
             # top suspects, trust vector, episode counts

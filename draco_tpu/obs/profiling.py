@@ -28,6 +28,8 @@ Window semantics (unchanged from the per-site logic):
   ``step_end >= profile_steps[1] - 1``, draining ``drain`` (the state
   carry) through ``jax.block_until_ready`` first so the capture contains
   the full device execution, not the dispatch tail.
+* ``holds(step_end)`` before a loop dispatches the NEXT unit ahead of this
+  one's retirement — True at the window's two edges, where it must not.
 * ``stop(drain)`` in the loop's exit path — the safety stop when the run
   ends inside the window.
 
@@ -108,6 +110,9 @@ class NullProfilerWindow:
     def maybe_stop(self, step_end: int, drain=None) -> None:
         pass
 
+    def holds(self, step_end: int) -> bool:
+        return False
+
     def stop(self, drain=None) -> None:
         pass
 
@@ -183,6 +188,18 @@ class ProfilerWindow:
         self._last_end = int(step_end)  # newest unit fully inside the window
         if step_end >= self.steps[1] - 1:
             self.stop(drain)
+
+    def holds(self, step_end: int) -> bool:
+        """True at the window's two edges: the unit before the one
+        :meth:`maybe_start` starts the capture at, and the unit
+        :meth:`maybe_stop` stops it after. A loop that dispatches ahead asks
+        before it sends the unit after ``step_end``, and sends it only once
+        this one is retired — so the capture holds whole units."""
+        if self.profiled:
+            return False
+        if self.active:
+            return step_end >= self.steps[1] - 1
+        return step_end + 1 >= self.steps[0]
 
     def stop(self, drain=None) -> None:
         """Stop the capture (drain first — the PR 4 fix, now unconditional:

@@ -431,21 +431,25 @@ class HostFaultInjector:
     def active(self) -> bool:
         return self._plan is not None and bool(self._plan.events)
 
+    def _unfired(self, kinds, lo: int, hi: int):
+        """(event, occurrence step) of every occurrence of ``kinds`` within
+        [lo, hi] that has not fired."""
+        if self._plan is None:
+            return
+        for ev in self._plan.of_kind(*kinds):
+            for o in ev.occurrences(lo, hi):
+                if (ev.index, o) not in self._fired:
+                    yield ev, o
+
     def _fire(self, kinds, lo: int, hi: Optional[int] = None):
         """First unfired OCCURRENCE of an event of ``kinds`` within
         [lo, hi] (hi defaults to lo), marked fired. Keyed by (event index,
         occurrence step): recurring events fire once per occurrence, and
         two identical point events (e.g. ``sigterm@5,sigterm@5`` — the
         pinned escalation sequence) each fire."""
-        if self._plan is None:
-            return None
-        hi = lo if hi is None else hi
-        for ev in self._plan.of_kind(*kinds):
-            for o in ev.occurrences(lo, hi):
-                key = (ev.index, o)
-                if key not in self._fired:
-                    self._fired.add(key)
-                    return ev
+        for ev, o in self._unfired(kinds, lo, lo if hi is None else hi):
+            self._fired.add((ev.index, o))
+            return ev
         return None
 
     def wrap_step_fn(self, fn):
@@ -485,6 +489,18 @@ class HostFaultInjector:
         import time
 
         time.sleep(30.0 if ev.duration_s is None else ev.duration_s)
+
+    def holds(self, step: int) -> bool:
+        """True where the plan names ``step`` for a host event that the
+        eager loop has to meet with nothing in flight behind it: a sigterm
+        still due by ``step`` (the stop then lands on the step the plan
+        names) or a prefetch fault on ``step + 1``'s data (it is raised
+        with ``step`` booked). Consumes nothing."""
+        if self._plan is None:
+            return False
+        return any(self._unfired(("sigterm",), 1, step)) or any(
+            ev.occurs_at(step + 1) for ev in self._plan.of_kind(
+                "prefetch_crash", "prefetch_hang"))
 
     def sigterm_due(self, end_step: int) -> bool:
         """True once, when a sigterm event's step has been reached — the

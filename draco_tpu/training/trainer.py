@@ -8,10 +8,18 @@ segment names, checkpoint every eval_freq steps.
 
 Two execution regimes, selected by ``cfg.steps_per_call``:
 
-* K=1 (default): the eager per-step loop — one dispatch, one metrics fetch,
-  one ``block_until_ready`` per step. Honest on CPU (PERF_HISTORY.md §4: XLA:CPU
-  serializes conv thunks inside scan bodies) and the bitwise reference for
-  the chunked path.
+* K=1 (default): the eager per-step loop, one step ahead — one dispatch,
+  one wait, one metrics fetch per step, and the dispatch of step k+1 comes
+  BEFORE the wait for step k (``_run_eager``): JAX dispatch is asynchronous,
+  so the host's fetch, dispatch, drain and bookkeeping of one step all run
+  while the device runs the next, and the device goes from one program
+  straight into the other. Every step is still waited for, drained and
+  booked, in order, one record a step. The loop syncs — retires a step with
+  nothing queued behind it — where something reads the state AT that step:
+  a call's last step, an ``eval_freq`` boundary (evaluate, checkpoint), an
+  edge of the profiler's window, a step the fault plan names, a pending
+  stop. Honest on CPU (PERF_HISTORY.md §4: XLA:CPU serializes conv thunks
+  inside scan bodies) and the bitwise reference for the chunked path.
 * K>1: the scan-chunked loop — ``train_many`` fuses K full coded steps into
   one device program (training/step.py); the host runs a two-deep pipeline
   (assemble + device_put chunk i+1 while chunk i executes), metrics are
@@ -25,7 +33,7 @@ Two execution regimes, selected by ``cfg.steps_per_call``:
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +64,14 @@ from draco_tpu.runtime import WORKER_AXIS, make_mesh, put_global
 from draco_tpu.training.step import build_train_setup
 from draco_tpu.utils import checkpoint as ckpt
 from draco_tpu.utils.metrics import MetricWriter, Segments
+
+
+class _InFlight(NamedTuple):
+    """A dispatched step the eager loop has not waited for yet."""
+
+    metrics: dict  # the step's metric columns, still on the device
+    present: Optional[np.ndarray]  # its straggler row (None: no schedule)
+    ahead: float  # 1.0: dispatched before its predecessor was waited for
 
 
 class Trainer:
@@ -116,7 +132,7 @@ class Trainer:
         self._engine = None  # live ChunkedEngine while _run_chunked runs
         # (label, key, callable, argument shapes) of the newest dispatch
         self._dispatched = None
-        self._eager_step = None  # newest completed eager step (escalation)
+        self._eager_step = None  # the step self.state is at (eager loop)
         self._group_seeds = drng.group_seeds(cfg.seed, max(cfg.num_groups, 1))
         # both prefetchers are lazy: the chunked path never touches the
         # per-step one (and vice versa), so neither thread pool should
@@ -390,6 +406,37 @@ class Trainer:
 
     def _run_eager(self, n_steps: int, profile_dir, profile_steps,
                    edge) -> dict:
+        """The per-step loop, one step in flight. An iteration retires
+        ``step``; in steady state ``step`` is already on the device, and the
+        iteration
+
+        1. fetches the batch of ``step + 1`` and dispatches its
+           ``train_step`` on the state ``step`` returns (a future, donated
+           as ever) without touching ``step``'s outputs first;
+        2. waits for ``step``'s metrics (its state went into ``step + 1``
+           and may not be read), drains them and books ``step`` — while
+           the device runs ``step + 1``.
+
+        It does NOT send ``step + 1`` ahead where :meth:`_may_run_ahead`
+        says something reads the state at ``step``; the next iteration then
+        finds nothing in flight and sends its own step first. One loop, its
+        depth 0 or 1 by the step's position.
+
+        ``self.state`` and ``self._eager_step`` move together, at dispatch:
+        a checkpoint names the step its state is at. A stop requested while
+        ``step + 1`` is in flight is therefore honoured one iteration later,
+        at ``step + 1``, the newest dispatched step.
+
+        The record of ``step`` holds the parts of the iteration that retired
+        it: ``t_fetch`` and ``t_dispatch`` of the step sent in it, ``t_wait``
+        and ``t_drain`` of ``step``, ``t_book`` since the previous
+        iteration's last clock read; ``t_dispatch + t_wait + t_drain ==
+        t_comp``, and the records tile the loop as before. The odd halves:
+        an iteration that finds nothing in flight (a call's first) sends two
+        steps and books both fetches and both dispatches; one that sends
+        nothing (a call's last) has ``t_fetch`` and ``t_dispatch`` 0.
+        ``ahead`` is 1.0 on a step that was dispatched before its
+        predecessor was waited for."""
         cfg = self.cfg
         last = {}
         # the shared capture window (obs/profiling.py): start/stop/drain +
@@ -400,6 +447,7 @@ class Trainer:
                               self.tracer,
                               on_stop=self.heartbeat.observe_device)
         comp_end = None  # clock read that closed the previous step's t_comp
+        flight = None  # the step sent ahead by the previous iteration
         win.maybe_start(self._start_step)
         edge.close()  # loop.prologue ends where the first step begins
         for step in range(self._start_step, n_steps + 1):
@@ -409,58 +457,48 @@ class Trainer:
             # it the records tile the loop: sum(t_book + t_fetch + t_comp)
             # is the wall time from the first fetch to the last sync
             seg.begin("fetch", since=comp_end, gap="book")
-            with self.tracer.span("gather+upload", step=step):
-                x, y = self._device_batch(step)
-                # numpy (uncommitted) so multi-host jit treats it as
-                # replicated
-                mask = np.asarray(self._adv_schedule[step])
-                present = (
-                    np.asarray(~self._straggle_schedule[step])
-                    if self._straggle_schedule is not None
-                    else None
-                )
-            seg.end()
+            if flight is None:
+                flight = self._send(step, 0.0, seg, win)
+                seg.begin("fetch", lap="dispatch")
+            mine, flight = flight, None
+            if self._may_run_ahead(step, n_steps, win):
+                flight = self._send(step + 1, 1.0, seg, win)
+            else:
+                seg.begin("comp")
+            seg.lap("dispatch")  # where nothing was sent the part stays, at 0
 
-            # fwd+bwd+encode+gather+decode+update, one program; t_comp's
-            # parts: t_dispatch (the call until it returns), t_wait (the
-            # host waiting for the device: the loop's own per-step device
-            # time, a lower bound — t_dispatch + t_wait is the upper),
-            # t_drain (the metric columns, one device->host fetch each)
-            seg.begin("comp")
-            args = (self.state, x, y, mask) if present is None \
-                else (self.state, x, y, mask, present)
-            self._note_dispatch("train_step", self.setup.train_step, args)
-            win.note_program("train_step", self.setup.train_step, args)
-            with self.tracer.span("dispatch", step=step), \
-                    self.compile_watch.expect("train_step"):
-                self.state, metrics = self.setup.train_step(*args)
-            del args  # the donated state must not outlive its call
-            seg.lap("dispatch")
+            # t_comp's other parts: t_wait (the host waiting for the device
+            # to finish ``step`` — with a successor queued, what is left of
+            # it after this iteration's fetch and dispatch), t_drain (the
+            # metric columns, one device->host fetch each)
             with self.tracer.span("sync", step=step):
                 # wait FIRST, on the step's outputs, so the wait is not
-                # hidden inside the first column's fetch
+                # hidden inside the first column's fetch; the state only
+                # where it is this step's (else it was donated onward)
                 with self.tracer.span("device_wait", step=step):
-                    jax.block_until_ready((metrics, self.state.params))
+                    jax.block_until_ready(
+                        mine.metrics if flight is not None
+                        else (mine.metrics, self.state.params))
                 seg.lap("wait")
                 with self.tracer.span("drain", step=step,
-                                      columns=len(metrics)):
+                                      columns=len(mine.metrics)):
                     # record_value: forensics bitmask columns materialize
                     # as exact integer words, everything else as float
                     metrics = {k: record_value(k, v)
-                               for k, v in metrics.items()}
-                    if present is not None:
-                        metrics["present"] = float(present.sum())
+                               for k, v in mine.metrics.items()}
+                    if mine.present is not None:
+                        metrics["present"] = float(mine.present.sum())
             comp_end = seg.end(lap="drain")
 
             with self.tracer.span("book", step=step):
                 win.maybe_stop(step, self.state.params)
-                self._eager_step = step  # escalated-stop checkpoint cursor
-                record = {"step": step, **metrics, **seg.as_dict()}
+                record = {"step": step, **metrics, "ahead": mine.ahead,
+                          **seg.as_dict()}
                 last = record
                 self.heartbeat.observe(record)
                 if step % cfg.log_every == 0 or step == 1:
                     self.writer.write(record)
-                boundary = cfg.eval_freq and step % cfg.eval_freq == 0
+                boundary = self._boundary(step)
                 if boundary or step == n_steps:
                     with self.tracer.span("flush", at_step=step):
                         self.writer.flush()
@@ -476,10 +514,13 @@ class Trainer:
                             ckpt.save(cfg.train_dir, step, self.state,
                                       compress=cfg.compress_ckpt,
                                       keep=cfg.keep_checkpoints)
-                if self._check_stop(step):
+                # a stop seen with ``step + 1`` in flight waits for the
+                # next iteration, which sends nothing further, retires
+                # ``step + 1`` and lands here with the state at that step
+                if self._check_stop(step) and flight is None:
                     with self.tracer.span("flush", at_step=step):
                         self.writer.flush()
-                    self._snap_stop(step, already_saved=bool(boundary))
+                    self._snap_stop(step, already_saved=boundary)
                     break
                 if step < n_steps:
                     win.maybe_start(step + 1)
@@ -487,6 +528,54 @@ class Trainer:
         edge.enter_context(self.tracer.span_since("loop.epilogue", comp_end))
         win.stop(self.state.params)  # loop ended inside the window
         return last
+
+    def _boundary(self, step: int) -> bool:
+        """An ``eval_freq`` boundary: evaluate and checkpoint at ``step``."""
+        return bool(self.cfg.eval_freq) and step % self.cfg.eval_freq == 0
+
+    def _may_run_ahead(self, step: int, n_steps: int, win) -> bool:
+        """May ``step + 1`` be dispatched before ``step`` is waited for?
+        Not where something reads the state AT ``step`` or has to see whole
+        steps: the call's last step, an ``eval_freq`` boundary (evaluate,
+        checkpoint), an edge of the profiler's window, a step the fault plan
+        names, a stop already requested. Read from what the loop can
+        observe, step by step — there is no option for it."""
+        return not (
+            step >= n_steps
+            or self._boundary(step)
+            or win.holds(step)
+            or self._injector.holds(step)
+            or (self._stop is not None and self._stop.requested))
+
+    def _send(self, step: int, ahead: float, seg: Segments,
+              win) -> _InFlight:
+        """Fetch ``step``'s batch (the open ``t_fetch``) and dispatch its
+        ``train_step`` on the newest state (inside the ``t_comp`` this
+        leaves open: the caller's next clock read closes ``t_dispatch``).
+        The step's outputs are not waited for here."""
+        with self.tracer.span("gather+upload", step=step):
+            x, y = self._device_batch(step)
+            # numpy (uncommitted) so multi-host jit treats it as
+            # replicated
+            mask = np.asarray(self._adv_schedule[step])
+            present = (
+                np.asarray(~self._straggle_schedule[step])
+                if self._straggle_schedule is not None
+                else None
+            )
+        # fwd+bwd+encode+gather+decode+update, one program
+        seg.begin("comp")
+        args = (self.state, x, y, mask) if present is None \
+            else (self.state, x, y, mask, present)
+        self._note_dispatch("train_step", self.setup.train_step, args)
+        win.note_program("train_step", self.setup.train_step, args)
+        with self.tracer.span("dispatch", step=step, ahead=ahead), \
+                self.compile_watch.expect("train_step"):
+            # the state and the step it is at move together
+            self.state, metrics = self.setup.train_step(*args)
+            self._eager_step = step
+        del args  # the donated state must not outlive its call
+        return _InFlight(metrics, present, ahead)
 
     def _note_dispatch(self, label: str, fn, args, key=None) -> None:
         """Remember what is about to be dispatched — the callable and, as

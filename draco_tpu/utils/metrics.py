@@ -172,7 +172,9 @@ class Segments:
     tile the segment exactly (``t_dispatch + t_wait + t_drain == t_comp``).
     ``begin(name, since=..., gap="book")`` books the time since ``since`` —
     the previous step's ``end()`` — under ``gap``, so consecutive steps'
-    records tile the loop's wall time."""
+    records tile the loop's wall time. A segment still open when the next
+    begins ends on that same clock read (with its last part, ``lap``), and
+    a name begun twice adds up."""
 
     def __init__(self):
         self.t = {}
@@ -181,12 +183,20 @@ class Segments:
         self._name = None
 
     def begin(self, name: str, since: Optional[float] = None,
-              gap: Optional[str] = None):
+              gap: Optional[str] = None, lap: Optional[str] = None):
         now = time.perf_counter()
+        if self._name is not None:
+            self._close(now, lap)
         if gap is not None:
             # since=None: the run's first step, which follows nothing
             self.t[gap] = 0.0 if since is None else max(now - since, 0.0)
         self._name, self._start, self._lap = name, now, now
+
+    def _close(self, now: float, lap: Optional[str]):
+        if lap is not None:
+            self.lap(lap, now)
+        self.t[self._name] = self.t.get(self._name, 0.0) + now - self._start
+        self._name = None
 
     def lap(self, name: str, now=None):
         now = time.perf_counter() if now is None else now
@@ -199,10 +209,7 @@ class Segments:
         if self._name is None:
             return None
         now = time.perf_counter()
-        if lap is not None:
-            self.lap(lap, now)
-        self.t[self._name] = self.t.get(self._name, 0.0) + now - self._start
-        self._name = None
+        self._close(now, lap)
         return now
 
     def as_dict(self, prefix: str = "t_"):

@@ -82,9 +82,11 @@ def metric_stream(train_dir):
             if "loss" not in rec:
                 continue  # eval records
             # every t_* key is a host clock (the eager loop's records also
-            # carry t_comp's parts and t_book, utils/metrics.Segments)
+            # carry t_comp's parts and t_book, utils/metrics.Segments), and
+            # ``ahead`` says how that loop sent the step, not what it learnt
             vals = {k: v for k, v in rec.items()
-                    if k not in ("time", "step") and not k.startswith("t_")}
+                    if k not in ("time", "step", "ahead")
+                    and not k.startswith("t_")}
             out.append((rec["step"], vals))
     return out
 
