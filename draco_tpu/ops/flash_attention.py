@@ -17,8 +17,31 @@ sweep crosses, keeps its float32 sum whole for the head in VMEM ((T, Dh):
 ``vmem_limit_bytes`` is set from the shapes (_bwd_vmem_bytes); a head too
 long for the chip's vector memory raises. Again (T, T) never exists.
 
-Block-causal skipping: grid steps with j > i (keys entirely in the future)
-compute nothing (`pl.when`), so causal attention does ~half the block work.
+Block-causal skipping, at two granularities. Whole grid steps: a (query
+block, key block) pair whose keys all lie in the future computes nothing
+(`pl.when`) and fetches nothing (the residency maps), so causal attention
+does ~half the block work. Inside a computed grid step (PR 43): the pair's
+offset d = i*bq - j*bk is one of a few values known at trace time (causal
+1024 x 1024: 0, the diagonal pair, or "every entry seen"; 512 x 1024: 0,
+512 or that), and for each the kernels hold one body (`_bodies`, selected
+by `pl.when` on d) that walks the block in strips of ``SUB_TILE`` query
+rows and multiplies each strip only against
+the contiguous range of ``SUB_TILE``-wide key columns that holds a pair it
+sees (`_strips`) — static slices of the blocks the grid step already holds,
+so no more grid steps and no more fetches. The mask is applied only to a
+strip the diagonal (or the window's edge) cuts, from static offsets; a pair
+with every entry seen takes a body with no mask at all. What the rectangle
+rule multiplied and threw away — the upper triangle of every diagonal pair,
+half of each windowed pair — is not multiplied, down to the sub-tile:
+the terms left out are exact zeros (p = exp(NEG_INF - lse) = 0), the sums
+keep their order (dq over ascending keys, dk / dv over ascending queries),
+and only the grouping of a shorter contraction can move a float32 sum's
+last bit. `computed_pairs` counts the entries a head multiplies under the
+rule. Where the rule does not fit — ``causal=False`` (the ring's fully
+visible hops: nothing to mask), blocks or a window that are not whole
+sub-tiles (`_fit_block`'s odd sizes, the small blocks of the tests), more
+cut offsets than ``_MAX_CUT_BODIES`` — the kernels keep ONE body over the
+whole rectangle, masked elementwise from the block indices (`_masked`).
 
 Sliding window (``flash_attention(..., window=W)``): query t sees key s iff
 0 <= t - s < W — itself and the W - 1 tokens before it. A (query block,
@@ -27,10 +50,13 @@ inequalities: the block's earliest key is no later than its latest query
 (causality, as above) and its latest key is less than W before its earliest
 query. Every other block is skipped from both sides, in the forward kernel
 and in the backward kernel, and the residency maps clamp the block index
-from both sides so that a skipped block is not fetched either; the two
-inequalities are applied elementwise inside every computed block (only the
-blocks at the two edges hold masked entries). ``window=None`` is the causal
-program, unchanged.
+from both sides so that a skipped block is not fetched either. Inside a
+computed pair the strips above apply to both inequalities: at 1024 x 1024
+and W = 1024 the two pairs a query block computes (d = 0: the lower
+triangle, d = 1024: the strict upper one) multiply 5/8 of their entries at
+a sub-tile of 256 where the rectangle rule multiplied all (2 048 keys a
+query for the 1 024 it sees, past the row's start; now 1 280).
+``window=None`` is the causal program, unchanged.
 
 No reference counterpart (the reference is CNN-only, SURVEY.md §5.7); this
 is a hot-op kernel of the TPU build's long-context axis, complementing ring
@@ -54,7 +80,15 @@ from draco_tpu.ops.coded import use_pallas
 
 NEG_INF = -1e30
 _LANE = 128
-BLOCK_Q = 512  # the query block's default limit
+# the query block's default limit. 512 until PR 43: a taller block then
+# multiplied more of the future (the rectangle rule), and now multiplies the
+# same sub-tiles in half the grid steps — a layer-lane's forward / backward
+# on the chip at SUB_TILE = 256, 512 -> 1024: kanana2 (32, 4096, 256 | 128)
+# 2.21 / 3.71 -> 2.04 / 3.51 ms, qwen3next (16, 4096, 256) 1.00 / 2.25 ->
+# 0.98 / 2.18, mellum2's full layer (32, 8192, 128) 5.29 / 9.26 -> 4.71 /
+# 8.78 (the parent's rectangles: 2.40 / 4.14, 1.13 / 2.53, 5.55 / 9.71;
+# PERF.md section 6, PR 43)
+BLOCK_Q = 1024
 # under a window a query block computes the key blocks that its
 # window + block_q - 1 keys touch: at W = 1024 two key blocks of 1024
 # whether it holds 512 queries or 1024, so the taller block halves the grid
@@ -62,6 +96,19 @@ BLOCK_Q = 512  # the query block's default limit
 # forward 3.31 -> 2.75 ms, forward + backward 12.79 -> 11.15; PERF.md
 # section 6, PR 35)
 WINDOW_BLOCK_Q = 1024
+# the edge of the sub-tiles a computed block pair is walked in (module
+# docstring): whole sub-tiles with no seen pair are not multiplied. Smaller
+# skips more and feeds the matrix unit shorter products; from the same
+# readings, 512 / 256 / 128 at a 512-row causal block: kanana2 forward +
+# backward 7.42 / 7.22 / 7.26 ms (parent 7.81), mellum2's sliding layer
+# (W = 1024, 1024 x 1024) 7.67 / 7.44 / 7.70 (parent 9.05) — 256, where
+# that layer multiplies 1 280 keys a query for the 1 024 it sees (2 048
+# as rectangles). Strips of key columns in place of query rows lost in the
+# backward at every point (+ 2 to + 7 %).
+SUB_TILE = 256
+# cut offsets beyond this keep the one rectangle body: each is a body the
+# chip's compiler builds
+_MAX_CUT_BODIES = 4
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -176,6 +223,124 @@ def _masked(s, i, j, causal: bool, window):
     return jnp.where(seen, s, NEG_INF)
 
 
+def _strips(d: int, bq: int, bk: int, window, sub: int):
+    """The work of a computed block pair whose offset i*bq - j*bk is ``d``,
+    as strips of ``sub`` query rows: row r of the block sees column c iff
+    0 <= r + d - c (< window), so a strip sees ONE contiguous range of
+    columns, widened here to whole sub-tiles. ``(r0, rows, lo, hi,
+    causal_cut, window_cut)`` a strip; a strip that sees no column is left
+    out, and the two flags say which inequality can fail inside it (neither:
+    no mask is applied)."""
+    out = []
+    for r0 in range(0, bq, sub):
+        last = min(r0 + sub - 1 + d, bk - 1)  # the last row's last column
+        first = 0 if window is None else max(r0 + d - window + 1, 0)
+        if last < first:
+            continue
+        lo, hi = first // sub * sub, (last // sub + 1) * sub
+        out.append((r0, sub, lo, hi, r0 + d < hi - 1,
+                    window is not None and r0 + sub - 1 + d - lo >= window))
+    return out
+
+
+def _interior(d, bq: int, bk: int, window):
+    """Whether every entry of a block pair at offset ``d`` is seen."""
+    seen = d >= bk - 1
+    if window is not None:
+        seen &= d <= window - bq
+    return seen
+
+
+def _computed_offsets(t: int, bq: int, bk: int, causal: bool, window):
+    """The offset i*bq - j*bk of every computed block pair of a length-t
+    head, pair by pair."""
+    return [i * bq - j * bk
+            for i in range(t // bq) for j in range(t // bk)
+            if _computed(i, j, bq, bk, causal, window)]
+
+
+@functools.lru_cache(maxsize=None)
+def _bodies(t: int, bq: int, bk: int, causal: bool, window, sub: int):
+    """What the kernels' grid steps multiply, keyed by the pair's offset
+    d = i*bq - j*bk: ``{None: [whole block, unmasked]}`` for the pairs with
+    every entry seen, ``{d: _strips(d)}`` for each offset the mask cuts —
+    only those that occur among the (t // bq) x (t // bk) pairs, so no body
+    is compiled that no step takes. None where the rule does not apply and
+    the kernels keep ONE body over the whole rectangle, masked elementwise
+    from the block indices: no mask at all (``causal=False``), blocks or a
+    window that are not whole sub-tiles, or more cut offsets than
+    ``_MAX_CUT_BODIES`` (odd blocks out of ``_fit_block``)."""
+    if (not causal or bq % sub or bk % sub
+            or (window is not None and window % sub)):
+        return None
+    bodies = {}
+    for d in _computed_offsets(t, bq, bk, causal, window):
+        if _interior(d, bq, bk, window):
+            bodies[None] = [(0, bq, 0, bk, False, False)]
+        elif d not in bodies:
+            bodies[d] = _strips(d, bq, bk, window, sub)
+    if len(bodies) - (None in bodies) > _MAX_CUT_BODIES:
+        return None
+    return bodies
+
+
+def _strip_masked(s, strip, d: int, window):
+    """Scores ``s`` of a strip of a pair at the static offset ``d``, masked
+    by the inequalities that can fail in it: c - r <= e and
+    c - r > e - window with e = r0 + d - lo."""
+    r0, _, lo, _, causal_cut, window_cut = strip
+    if not (causal_cut or window_cut):
+        return s
+    e = r0 + d - lo
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+    seen = ahead <= e if causal_cut else ahead > e - window
+    if causal_cut and window_cut:
+        seen &= ahead > e - window
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _per_body(i, j, t, bq, bk, causal, window, compute):
+    """Run ``compute(strips, mask)`` for the grid step's pair (i, j), under
+    the ``pl.when`` of the body its offset selects (_bodies): ``strips`` as
+    _strips gives them, ``mask(s, strip)`` the strip's scores masked."""
+    bodies = _bodies(t, bq, bk, causal, window, SUB_TILE)
+    if bodies is None:
+        pl.when(_computed(i, j, bq, bk, causal, window))(functools.partial(
+            compute, [(0, bq, 0, bk, causal, window is not None)],
+            lambda s, strip: _masked(s, i, j, causal, window)))
+        return
+    d = i * bq - j * bk
+    for d0, strips in bodies.items():
+        pl.when(_interior(d, bq, bk, window) if d0 is None else d == d0)(
+            functools.partial(compute, strips, functools.partial(
+                _strip_masked, d=d0, window=window)))
+
+
+def computed_pairs(t: int, bq: int, bk: int, window=None,
+                   sub_tile: int | None = None) -> int:
+    """The (query, key) entries one head's causal kernel multiplies at
+    length ``t`` under blocks (bq, bk) — forward and backward alike —, from
+    the rule the bodies are generated from (_bodies). ``sub_tile`` None:
+    the kernels' own ``SUB_TILE``; 0: the rectangle rule, every computed
+    pair whole. Seen pairs (what the rooflines count) are fewer."""
+    sub = SUB_TILE if sub_tile is None else sub_tile
+    bodies = _bodies(t, bq, bk, True, window, sub) if sub else None
+    offsets = _computed_offsets(t, bq, bk, True, window)
+    if bodies is None:
+        return bq * bk * len(offsets)
+    return sum(rows * (hi - lo)
+               for d in offsets
+               for _, rows, lo, hi, _, _ in bodies[
+                   None if _interior(d, bq, bk, window) else d])
+
+
+def seen_pairs(t: int, window=None) -> int:
+    """The (query, key) pairs of one head with 0 <= t - s (< window)."""
+    w = t if window is None else min(window, t)
+    return w * (w + 1) // 2 + (t - w) * w
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -191,32 +356,36 @@ def _fwd_kernel(scale, nk, bq, bk, causal, window, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
 
-    @pl.when(_computed(i, j, bq, bk, causal, window))
-    def _compute():
+    def _compute(strips, mask):
         # matmuls take the input dtype (bf16 inputs ride the fast MXU pass)
         # and accumulate f32 via preferred_element_type — the flash standard;
         # all softmax/accumulator algebra stays f32
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (bq, bk) f32
-        # (a row whose window has not reached this block yet reads all
-        # NEG_INF here: p = 1 against m = NEG_INF, and the first block with
-        # a key it sees — its own diagonal at the latest — scales that away
-        # by corr = exp(NEG_INF - m) = 0)
-        s = _masked(s, i, j, causal, window)
-        m_prev = m_ref[...]  # (bq, _LANE), lane-broadcast
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
-        p = jnp.exp(s - _cols(m_cur, bk))
-        corr = jnp.exp(m_prev - m_cur)  # (bq, _LANE)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)[:, None]
-        acc_ref[...] = acc_ref[...] * _cols(corr, acc_ref.shape[1]) + \
-            jax.lax.dot(p.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
+        for strip in strips:
+            r0, nrows, lo, hi = strip[:4]
+            rows = slice(r0, r0 + nrows)
+            q = q_ref[0, rows]
+            k = k_ref[0, lo:hi]
+            v = v_ref[0, lo:hi]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (nrows, hi - lo) f32
+            # (a row whose window has not reached these columns yet reads
+            # all NEG_INF here: p = 1 against m = NEG_INF, and the first
+            # strip with a key it sees — its own diagonal at the latest —
+            # scales that away by corr = exp(NEG_INF - m) = 0)
+            s = mask(s, strip)
+            m_prev = m_ref[rows]  # (nrows, _LANE), lane-broadcast
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+            p = jnp.exp(s - _cols(m_cur, hi - lo))
+            corr = jnp.exp(m_prev - m_cur)  # (nrows, _LANE)
+            l_ref[rows] = l_ref[rows] * corr + jnp.sum(p, axis=1)[:, None]
+            acc_ref[rows] = acc_ref[rows] * _cols(corr, acc_ref.shape[1]) + \
+                jax.lax.dot(p.astype(v.dtype), v,
+                            preferred_element_type=jnp.float32)
+            m_ref[rows] = m_cur
+
+    _per_body(i, j, nk * bk, bq, bk, causal, window, _compute)
 
     @pl.when(j == nk - 1)
     def _flush():
@@ -304,35 +473,43 @@ def _bwd_kernel(scale, nq, nk, bq, bk, causal, window, has_dlse, *refs):
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_computed(i, j, bq, bk, causal, window))
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        s = _masked(s, i, j, causal, window)
-        p = jnp.exp(s - _cols(lse_ref[0], bk))  # (bq, bk) f32
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32
-        )  # pᵀ · do -> (bk, dv)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bk) f32
-        # d lse_i / d s_ij = p_ij, so an lse cotangent adds p * dlse_i
-        dsum = dp - _cols(dcap_ref[0], bk)
-        if dlse_ref is not None:
-            dsum = dsum + _cols(dlse_ref[0], bk)
-        ds = (p * dsum).astype(q.dtype)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # dsᵀ · q -> (bk, dh)
-        dq_acc[rows, :] += jax.lax.dot(
-            ds, k, preferred_element_type=jnp.float32) * scale
+    def _compute(strips, mask):
+        for strip in strips:
+            r0, nrows, lo, hi = strip[:4]
+            rows = slice(r0, r0 + nrows)
+            cols = slice(lo, hi)
+            q = q_ref[0, rows]
+            do = do_ref[0, rows]
+            k = k_ref[0, cols]
+            v = v_ref[0, cols]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32
+            ) * scale
+            s = mask(s, strip)
+            p = jnp.exp(s - _cols(lse_ref[0, rows], hi - lo))  # f32
+            dv_acc[cols] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32
+            )  # pᵀ · do -> (hi - lo, dv)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32
+            )  # (nrows, hi - lo) f32
+            # d lse_i / d s_ij = p_ij, so an lse cotangent adds p * dlse_i
+            dsum = dp - _cols(dcap_ref[0, rows], hi - lo)
+            if dlse_ref is not None:
+                dsum = dsum + _cols(dlse_ref[0, rows], hi - lo)
+            ds = (p * dsum).astype(q.dtype)
+            dk_acc[cols] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # dsᵀ · q -> (hi - lo, dh)
+            head_rows = pl.ds(pl.multiple_of(i * bq + r0, nrows), nrows)
+            dq_acc[head_rows, :] += jax.lax.dot(
+                ds, k, preferred_element_type=jnp.float32) * scale
+
+    _per_body(i, j, nk * bk, bq, bk, causal, window, _compute)
 
     @pl.when(i == nq - 1)
     def _flush_dkv():

@@ -108,14 +108,16 @@ def _flash(grad):
     return (bwd if grad else fwd), [(FLASH_SHAPE, jnp.float32)] * 3
 
 
-def _flash_lse():
-    """A ring hop's pair: fully visible, both outputs read, so the backward
-    kernel takes the log-sum-exp's cotangent as a third row statistic."""
+def _flash_lse(causal=False):
+    """A ring hop's pair: fully visible (a past owner's shard) or causal
+    (the own shard: the sub-tiled bodies), both outputs read, so the
+    backward kernel takes the log-sum-exp's cotangent as a third row
+    statistic."""
     from draco_tpu.ops.flash_attention import flash_attention_with_lse
 
     def fn(q, k, v):
         def loss(q, k, v):
-            o, lse = flash_attention_with_lse(q, k, v, causal=False,
+            o, lse = flash_attention_with_lse(q, k, v, causal=causal,
                                               force=True)
             return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(lse))
 
@@ -177,15 +179,15 @@ def _flash_grouped_query():
 SWA_Q, SWA_KV, SWA_WINDOW = (1, 8192, 32, 128), (1, 8192, 4, 128), 1024
 
 
-def _flash_windowed():
+def _flash_windowed(window=SWA_WINDOW):
     """Forward and backward of a sliding layer's core as the model runs it:
     the window's block skipping and two-sided residency maps, grouped-query
-    heads, under the model's scope."""
+    heads, under the model's scope (``window=None``: the model's full layer,
+    the longest head the backward keeps in vector memory)."""
     def fn(q, k, v):
         def core(q, k, v):
             with jax.named_scope("draco_window"):
-                return flash_attention(q, k, v, window=SWA_WINDOW,
-                                       force=True)
+                return flash_attention(q, k, v, window=window, force=True)
 
         return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(core(
             q, k, v).astype(jnp.float32))), argnums=(0, 1, 2))(q, k, v)
@@ -267,10 +269,12 @@ CASES = {
     "flash_fwd": lambda: _flash(grad=False),
     "flash_grad": lambda: _flash(grad=True),
     "flash_grad_with_lse_fully_visible": _flash_lse,
+    "flash_grad_with_lse_causal": lambda: _flash_lse(causal=True),
     "flash_grad_qk192_v128": _flash_latent,
     "flash_grad_16_heads_on_2_d256": _flash_grouped_query,
     "flash_grad_16_heads_d128": _flash_equal_heads,
     "flash_grad_window_1024_32_heads_on_4": _flash_windowed,
+    "flash_grad_t8192_32_heads_on_4": lambda: _flash_windowed(window=None),
     "flash_grad_32_heads_on_8_d64": _flash_grouped_query_d64,
     "grouped_dot_8_of_128": _grouped_dot,
     "delta_rule_fwd": lambda: _delta_rule(grad=False),

@@ -447,3 +447,270 @@ def test_tpu_lowering_clean_and_control():
     with pytest.raises(ValueError, match="Pallas TPU lowering"):
         jax.export.export(jax.jit(bad), platforms=["tpu"])(
             jnp.zeros((16, 48), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# the sub-tiles (PR 43): a computed block pair multiplies only the strips of
+# sub-tiles that hold a seen pair. Oracle: the parent's kernels, whose
+# bodies multiply the whole (bq, bk) rectangle and mask it elementwise.
+# ---------------------------------------------------------------------------
+
+def _parent_fwd_kernel(scale, nk, bq, bk, causal, window, q_ref, k_ref, v_ref,
+                       o_ref, lse_ref, acc_ref, m_ref, l_ref):
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, fa.NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    @pl.when(fa._computed(i, j, bq, bk, causal, window))
+    def _compute():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        s = fa._masked(s, i, j, causal, window)
+        m_prev = m_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - fa._cols(m_cur, bk))
+        corr = jnp.exp(m_prev - m_cur)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)[:, None]
+        acc_ref[...] = acc_ref[...] * fa._cols(corr, acc_ref.shape[1]) + \
+            jax.lax.dot(p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32)
+        m_ref[...] = m_cur
+
+    @pl.when(j == nk - 1)
+    def _flush():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / fa._cols(l, o_ref.shape[2])).astype(
+            o_ref.dtype)
+        lse_ref[0] = m_ref[...] + jnp.log(l)
+
+
+def _parent_bwd_kernel(scale, nq, nk, bq, bk, causal, window, has_dlse,
+                       *refs):
+    from jax.experimental import pallas as pl
+
+    if has_dlse:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, dlse_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
+        dlse_ref = None
+    j = pl.program_id(1)
+    i = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((bq, dq_acc.shape[1]), jnp.float32)
+
+    @pl.when(i == 0)
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(fa._computed(i, j, bq, bk, causal, window))
+    def _compute():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        s = fa._masked(s, i, j, causal, window)
+        p = jnp.exp(s - fa._cols(lse_ref[0], bk))
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dsum = dp - fa._cols(dcap_ref[0], bk)
+        if dlse_ref is not None:
+            dsum = dsum + fa._cols(dlse_ref[0], bk)
+        ds = (p * dsum).astype(q.dtype)
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        dq_acc[rows, :] += jax.lax.dot(
+            ds, k, preferred_element_type=jnp.float32) * scale
+
+    @pl.when(i == nq - 1)
+    def _flush_dkv():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(j == nk - 1)
+    def _flush_dq():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+def _folded_operands(case, dtype):
+    """q, k, v, do (and dlse where the case reads the log-sum-exp) as the
+    kernels take them: heads folded, head sizes whole lane tiles."""
+    t, dh, dv = case["t"], case["dh"], case["dv"]
+    key = jax.random.key(t + dh)
+    q, k, v, do = (
+        jax.random.normal(jax.random.fold_in(key, n), (1, t, d)).astype(dtype)
+        for n, d in enumerate([dh, dh, dv, dv]))
+    dlse = (jax.random.normal(jax.random.fold_in(key, 9), (1, t))
+            if case.get("dlse") else None)
+    return q, k, v, do, dlse
+
+
+def _o_lse_and_gradients(case, q, k, v, do, dlse):
+    """o, lse, dq, dk, dv of the module's kernels as they stand (un-jitted,
+    so a patched kernel body is the one traced)."""
+    bq, bk, window = case["bq"], case["bk"], case.get("window")
+    causal = case.get("causal", True)
+    scale = q.shape[-1] ** -0.5
+    o, lse = fa._flash_fwd.__wrapped__(q, k, v, scale, bq, bk, causal,
+                                       window, True)
+    return (o, lse) + tuple(fa._flash_bwd.__wrapped__(
+        q, k, v, o, lse, do, dlse, scale, bq, bk, causal, window, True))
+
+
+# blocks as the cells run them (1024 x 1024, causal and windowed at
+# W = 1024) and as the ring's hops and the parent's causal kernel do
+# (512 x 1024); ``bit_equal``: the rectangle rule's one body is kept (a
+# window of no whole sub-tiles, no mask at all, blocks below a sub-tile), so
+# the parent's bits come back
+PARENT_CASES = {
+    "causal_t1024_d128": dict(t=1024, bq=512, bk=1024, dh=128, dv=128),
+    "causal_t2048_d256": dict(t=2048, bq=512, bk=1024, dh=256, dv=256),
+    "causal_t4096_qk256_v128": dict(t=4096, bq=1024, bk=1024, dh=256, dv=128),
+    "causal_t2048_bq1024": dict(t=2048, bq=1024, bk=1024, dh=128, dv=128),
+    "causal_t1024_dlse": dict(t=1024, bq=512, bk=1024, dh=128, dv=128,
+                              dlse=True),
+    "window_1024_t3072": dict(t=3072, bq=1024, bk=1024, dh=128, dv=128,
+                              window=1024),
+    "window_512_t2048": dict(t=2048, bq=1024, bk=1024, dh=128, dv=128,
+                             window=512),
+    "window_1000_t2048": dict(t=2048, bq=1024, bk=1024, dh=128, dv=128,
+                              window=1000, bit_equal=True),
+    "fully_visible_t1024_dlse": dict(t=1024, bq=512, bk=1024, dh=128, dv=128,
+                                     causal=False, dlse=True, bit_equal=True),
+    "small_blocks_t256": dict(t=256, bq=64, bk=128, dh=128, dv=128,
+                              bit_equal=True),
+}
+
+
+# bfloat16 as the models hand the operands over, at one case of each kind
+BFLOAT16_CASES = ["causal_t1024_d128", "causal_t2048_bq1024",
+                  "window_1024_t3072", "fully_visible_t1024_dlse",
+                  "small_blocks_t256"]
+
+
+@pytest.mark.parametrize("name,dtype", [
+    *[(name, "float32") for name in sorted(PARENT_CASES)],
+    *[(name, "bfloat16") for name in BFLOAT16_CASES]])
+def test_sub_tiled_bodies_stay_within_ulps_of_the_rectangle_bodies(
+        monkeypatch, name, dtype):
+    """o, lse, dq, dk, dv of the sub-tiled kernels against the parent's
+    bodies on the same operands: the terms left out are exact zeros, so only
+    the grouping of a shorter contraction or row sum can differ — a few
+    float32 ulps of the array's scale, one bfloat16 ulp on bfloat16
+    results; and a second evaluation gives the first one's bits."""
+    case = PARENT_CASES[name]
+    dtype = jnp.dtype(dtype)
+    ops = _folded_operands(case, dtype)
+    got = _o_lse_and_gradients(case, *ops)
+    again = _o_lse_and_gradients(case, *ops)
+    sub_tiled = fa._bodies(case["t"], case["bq"], case["bk"],
+                           case.get("causal", True), case.get("window"),
+                           fa.SUB_TILE) is not None
+    assert sub_tiled == (not case.get("bit_equal", False))
+    monkeypatch.setattr(fa, "_fwd_kernel", _parent_fwd_kernel)
+    monkeypatch.setattr(fa, "_bwd_kernel", _parent_bwd_kernel)
+    want = _o_lse_and_gradients(case, *ops)
+    for label, a, b, c in zip(["o", "lse", "dq", "dk", "dv"], got, want,
+                              again):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(c, np.float32), label)
+        eps = float(jnp.finfo(a.dtype).eps)
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if case.get("bit_equal"):
+            np.testing.assert_array_equal(a, b, label)
+            continue
+        ulps = 1 if eps > 1e-3 else 8
+        np.testing.assert_allclose(a, b, rtol=0, err_msg=label,
+                                   atol=ulps * eps * np.abs(b).max())
+
+
+# (T, query heads, key/value heads, q/k head size, v head size, window)
+# through the public entry at the blocks it chooses itself
+SUB_TILED_SHAPES = {
+    "causal_t1024_d64": (1024, 1, 1, 64, 64, None),
+    "causal_t2048_d128_grouped": (2048, 2, 1, 128, 128, None),
+    "causal_t4096_qk192_v128": (4096, 1, 1, 192, 128, None),
+    "window_1024_t2048_grouped": (2048, 2, 1, 128, 128, 1024),
+    "window_1000_t2048": (2048, 1, 1, 64, 64, 1000),  # the rectangle rule
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SUB_TILED_SHAPES))
+def test_sub_tiled_kernels_match_dense(shape):
+    """Output and all three gradients against the dense reference at blocks
+    of whole sub-tiles (the defaults: 1024 x 1024, causal and windowed), at
+    the standing tolerances."""
+    t, h, kv, dh, dv, window = SUB_TILED_SHAPES[shape]
+    key = jax.random.key(t + dh)
+    q, k, v, tgt = (jax.random.normal(jax.random.fold_in(key, n), s)
+                    for n, s in enumerate([(1, t, h, dh), (1, t, kv, dh),
+                                           (1, t, kv, dv), (1, t, h, dv)]))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, window=window, interpret=True)
+
+    def dense(q, k, v):
+        return dense_attention(q, *fa.spread_kv_heads(h, k, v), causal=True,
+                               window=window)
+
+    def loss_of(o):
+        return jnp.sum((o - tgt) ** 2)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    _assert_grads_close(_grads(flash, loss_of, (q, k, v)),
+                        _grads(dense, loss_of, (q, k, v)), atol=5e-5)
+
+
+def test_sub_tiled_grads_with_a_live_lse_match_dense():
+    """The ring's own-shard hop (``causal=True``, both outputs read) at
+    blocks of whole sub-tiles: the dlse stream through the strips."""
+    from draco_tpu.parallel.ring_attention import dense_attention_lse
+
+    key = jax.random.key(7)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, n), (1, 1024, 2, 64))
+               for n in range(3))
+
+    def loss(attn):
+        def f(q, k, v):
+            o, lse = attn(q, k, v)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(lse))
+        return f
+
+    def flash(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, causal=True,
+                                           interpret=True)
+
+    def dense(q, k, v):
+        return dense_attention_lse(q, k, v, causal=True)
+
+    assert fa._bodies(1024, 512, 1024, True, None, fa.SUB_TILE) is not None
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    _assert_grads_close(got, want, atol=5e-5)

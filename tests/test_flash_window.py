@@ -159,6 +159,95 @@ def test_blocks_computed_and_fetched_are_those_with_a_visible_pair(
         assert want.sum() / causal.sum() < 0.45
 
 
+# (T, bq, bk, window) of the cells' kernels: causal at 4 096 (kanana2, ouro,
+# lfm2, qwen3next) and at 8 192 (mellum2's full layer), mellum2's window
+CELL_GEOMETRIES = {
+    "causal_t4096": (4096, fa.BLOCK_Q, 1024, None),
+    "causal_t8192": (8192, fa.BLOCK_Q, 1024, None),
+    "window_1024_t8192": (8192, fa.WINDOW_BLOCK_Q, 1024, 1024),
+}
+# the rectangle rule's entries a head (every computed block pair whole) and
+# the pairs the mask lets through
+RECTANGLES = {"causal_t4096": (10_485_760, 8_390_656),
+              "causal_t8192": (37_748_736, 33_558_528),
+              "window_1024_t8192": (15_728_640, 7_864_832)}
+
+
+@pytest.mark.parametrize("name", sorted(CELL_GEOMETRIES))
+def test_computed_pairs_fall_below_the_rectangles_and_not_below_the_seen(
+        name):
+    t, bq, bk, window = CELL_GEOMETRIES[name]
+    rectangle, seen = RECTANGLES[name]
+    # (causal 1 024 x 1 024 and 512 x 1 024 rectangles cover the same area)
+    assert fa.computed_pairs(t, bq, bk, window, sub_tile=0) == rectangle
+    assert fa.computed_pairs(t, 512, bk, window, sub_tile=0) == rectangle
+    assert fa.seen_pairs(t, window) == seen
+    back = np.arange(t)[:, None] - np.arange(t)[None, :]
+    assert seen == ((0 <= back) & (back < (window or t))).sum()
+    assert seen <= fa.computed_pairs(t, bq, bk, window) < rectangle
+    # a finer sub-tile never computes more
+    counts = [fa.computed_pairs(t, bq, bk, window, sub_tile=s)
+              for s in (0, 512, 256, 128)]
+    assert counts == sorted(counts, reverse=True) and counts[-1] >= seen
+
+
+@pytest.mark.parametrize("t,bq,bk,window", [
+    *CELL_GEOMETRIES.values(),
+    (4096, 512, 1024, None),  # the ring's own-shard hop, the blocks to PR 42
+    (2048, 512, 1024, 512), (1024, 256, 512, 256),
+    (2048, 512, 1024, 1536),  # interior pairs under a window
+])
+def test_no_seen_pair_lies_outside_a_computed_sub_tile(t, bq, bk, window):
+    """The generated bodies' strips, laid over the (T, T) plane by the
+    offset that selects each, against the dense mask: every seen pair is in
+    a computed strip; a strip without a mask holds seen pairs only; a strip
+    holds a seen pair in its first and in its last sub-tile column (the
+    range is not wider than the mask makes it); and the entries counted are
+    ``computed_pairs``."""
+    sub = fa.SUB_TILE
+    bodies = fa._bodies(t, bq, bk, True, window, sub)
+    assert bodies is not None and len(bodies) <= fa._MAX_CUT_BODIES + 1
+    back = np.arange(t)[:, None] - np.arange(t)[None, :]
+    seen = (0 <= back) & (back < (window or t))
+    computed = np.zeros((t, t), bool)
+    taken = set()
+    for i, j in itertools.product(range(t // bq), range(t // bk)):
+        if not fa._computed(i, j, bq, bk, True, window):
+            continue
+        d = i * bq - j * bk
+        key = None if fa._interior(d, bq, bk, window) else d
+        taken.add(key)
+        for r0, rows, lo, hi, causal_cut, window_cut in bodies[key]:
+            assert lo % sub == 0 and hi % sub == 0 and lo < hi <= bk
+            tile = (slice(i * bq + r0, i * bq + r0 + rows),
+                    slice(j * bk + lo, j * bk + hi))
+            assert not computed[tile].any()  # no entry multiplied twice
+            computed[tile] = True
+            if not (causal_cut or window_cut):
+                assert seen[tile].all()
+            assert seen[tile][:, :sub].any() and seen[tile][:, -sub:].any()
+            # each inequality is applied where it can fail, and only there
+            assert causal_cut == (back[tile] < 0).any()
+            assert window_cut == (back[tile] >= (window or t + 1)).any()
+    assert not (seen & ~computed).any()
+    assert taken == set(bodies)  # no body that no grid step takes
+    assert computed.sum() == fa.computed_pairs(t, bq, bk, window)
+
+
+@pytest.mark.parametrize("t,bq,bk,causal,window", [
+    (2048, 1024, 1024, True, 1000),  # a window of no whole sub-tiles
+    (1024, 512, 1024, False, None),  # the ring's fully visible hops
+    (256, 64, 128, True, None), (768, 384, 768, True, None),  # odd blocks
+    (4096, 256, 4096, True, None),  # more cut offsets than bodies allowed
+])
+def test_the_rectangle_rule_stays_where_sub_tiles_do_not_fit(
+        t, bq, bk, causal, window):
+    assert fa._bodies(t, bq, bk, causal, window, fa.SUB_TILE) is None
+    if causal:
+        assert fa.computed_pairs(t, bq, bk, window) == fa.computed_pairs(
+            t, bq, bk, window, sub_tile=0)
+
+
 def test_window_none_is_the_causal_program_bit_for_bit():
     """No window: the jaxpr of today's call, kernel bodies included, and
     the same bits as a window too long to hide a key."""

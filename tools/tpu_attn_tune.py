@@ -18,8 +18,10 @@ so a run cut short keeps finished rows (decode_study r3 precedent).
 of a benchmark cell's attention as the cell runs it — heads, T, Dh, Dv, key /
 value heads, window (POINTS), bfloat16, the kernel's own blocks: the forward,
 forward + backward through the public entry with all three gradients live,
-and ``_flash_bwd`` alone on the folded arrays. One row a point; PERF.md
-section 6 (PR 38) holds the readings.
+and ``_flash_bwd`` alone on the folded arrays — beside the (query, key)
+entries a head's kernel multiplies under its sub-tile rule
+(``computed_pairs``), the pairs the mask lets through and their ratio. One
+row a point; PERF.md section 6 (PR 38, PR 43) holds the readings.
 """
 
 from __future__ import annotations
@@ -96,9 +98,13 @@ def time_point(name, reps, interpret=False):
             q, k, v, o, lse, do, None, scale, bq, bk, True, window,
             interpret))).astype(do.dtype)
 
+    computed, seen = fa.computed_pairs(t, bq, bk, window), fa.seen_pairs(
+        t, window)
     rec = {"point": name, "heads": heads, "seq_len": t, "head_dim": dh,
            "v_head_dim": dv, "kv_heads": kv, "window": window,
-           "block_q": bq, "block_k": bk}
+           "block_q": bq, "block_k": bk, "sub_tile": fa.SUB_TILE,
+           "computed_pairs": computed, "seen_pairs": seen,
+           "computed_over_seen": round(computed / seen, 4)}
     for label, step, carry, consts in [
             ("fwd_ms", fwd_step, q, (k, v)),
             ("fwdbwd_ms", fb_step, q, (k, v)),
