@@ -352,8 +352,7 @@ def check_recombine_kernels(ph: Phase, n: int, d: int,
 
 
 def check_flash_attention(ph: Phase, shape, interpret: bool) -> None:
-    """Flash forward and gradient (of Σ sin(o), tools/tpu_attn_check.py's
-    loss) against dense attention. Gates from the last hardware study of the
+    """Flash forward and gradient (of Σ sin(o)) against dense attention. Gates from the last hardware study of the
     same comparison (2026-08-02: 4.7e-4 forward, 1.7e-2 gradient, absolute,
     on O(1) values), with headroom."""
     import jax

@@ -147,7 +147,7 @@ def _memory_columns(compiled) -> Optional[dict]:
 
 
 def _cost_flops(compiled) -> Optional[float]:
-    """Analytic FLOPs of the optimized program (same source bench.py's MFU
+    """Analytic FLOPs of the optimized program (same source the MFU column
     uses; a scan body is counted once regardless of trip count)."""
     cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0)) if cost else 0.0

@@ -364,7 +364,7 @@ def maybe_force_cpu_mesh(args: argparse.Namespace) -> None:
     (runtime.enable_compile_cache — one policy, every backend), then apply
     --cpu-mesh N (an N-device virtual CPU mesh instead of accelerators).
     Must run before any jax computation; safe to call twice. Every tool and
-    bench.py routes through here so cache policy lives in one place."""
+    the benchmark route through here: cache policy lives in one place."""
     from draco_tpu.runtime import enable_compile_cache
 
     enable_compile_cache()

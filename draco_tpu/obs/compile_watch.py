@@ -64,7 +64,7 @@ jax's own figure for the read inside it.
 jax's listener registry has no per-listener removal, so ONE module-level
 dispatcher is installed forever. It assembles the builds, keeps
 process-wide totals (:func:`global_stats`, which jax-free consumers like
-``tools/host_loop_overhead.py`` diff around a run to split compile from
+the study tools diff around a run to split compile from
 steady-state wall-clock), adds every build's seconds to the set-up
 ledger's totals (obs/tracer.py) and fans out to the currently-active
 watches (a watch's lifetime is ``start()``/``stop()``, tied to its loop).
@@ -237,7 +237,7 @@ def global_stats() -> dict:
     ``compile_or_get_cached``, hit or miss), ``retrieval_s`` (of those, the
     persistent cache's reads), ``cache_hits``, ``cache_misses`` (entries
     written). Diff two snapshots around a run to split its compile cost
-    from steady-state wall-clock (tools/host_loop_overhead.py)."""
+    from steady-state wall-clock."""
     with _LOCK:
         return dict(_GLOBAL)
 

@@ -55,7 +55,7 @@ poisoned comparison lands at −1.0, never NaN, so the block stays finite.
 **Wire ledger** (:func:`wire_ledger`, jax-free) — logical wire bytes per
 worker per step from the program's registered shapes (cyclic ships re+im,
 everything else one row of d f32s), with the bf16/int8 candidate sizes, for
-``status.json``'s ``wire`` block, ``bench.py``'s ``extra.wire_bytes``, and
+``status.json``'s ``wire`` block, callers that report wire bytes, and
 ``tools/wire_study.py``.
 
 The int8 shadow stores its levels in f32 (every int8 value is exact in

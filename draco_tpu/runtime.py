@@ -36,7 +36,7 @@ def enable_compile_cache() -> str:
     one command is found by the next only if nobody moves it. Where the
     variable is not set, the cache lives at the fixed
     ``<checkout>/.jax_cache`` (gitignored). Every entry point — the CLI, the
-    tools' bootstrap (cli.maybe_force_cpu_mesh), bench.py, chip_smoke.py —
+    tools' bootstrap (cli.maybe_force_cpu_mesh), benchmark/, chip_smoke.py —
     goes through here, on every backend: a coded ResNet-18 step costs about
     a minute to compile cold and seconds warm. Safe to call repeatedly.
     """

@@ -117,7 +117,7 @@ class DeferredMetricWriter:
     def sync(self) -> None:
         """Execution barrier: device→host fetch of one element of the
         NEWEST pending block. ``jax.block_until_ready`` only awaits dispatch
-        on remote-dispatch backends (utils/timing.py, PERF_HISTORY.md §0); an actual
+        on remote-dispatch backends (tools/_timing.py, PERF_HISTORY.md §0); an actual
         transfer is the one portable way to await execution, and chunks run
         in program order, so the newest block landing means every pending
         chunk has executed. No-op when nothing is pending."""

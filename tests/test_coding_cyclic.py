@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu.coding import cyclic
 
 
@@ -73,7 +74,8 @@ def test_exact_recovery_no_adversary(n, s, rng):
     g = batch_grads[code.batch_ids]  # (n, hat_s, d)
     enc_re, enc_im = cyclic.encode(code, jnp.asarray(g))
     rf = np.ones(d, dtype=np.float32)
-    dec, honest = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf))
+    dec, honest = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=2e-4, atol=2e-4)
     # mask reports the n-2s rows used for recombination
@@ -94,7 +96,8 @@ def test_exact_recovery_under_attack(n, s, attack, rng):
     adv[rng.choice(n, size=s, replace=False)] = True
     enc_re, enc_im = inject_cyclic(enc_re, enc_im, jnp.asarray(adv), attack)
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
-    dec, honest = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf))
+    dec, honest = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=5e-3, atol=5e-3)
     # located honest set must exclude every adversary
@@ -112,8 +115,9 @@ def test_matches_numpy_oracle(rng):
     R[adv] += -100.0 * R[adv]
     rf = rng.normal(loc=1.0, size=d)
     want, honest_np = numpy_oracle_decode(code, R, rf)
-    dec, honest = cyclic.decode(
-        code, jnp.asarray(R.real.astype(np.float32)), jnp.asarray(R.imag.astype(np.float32)),
+    dec, honest = parity.run_jitted(
+        cyclic.decode, code, jnp.asarray(R.real.astype(np.float32)),
+        jnp.asarray(R.imag.astype(np.float32)),
         jnp.asarray(rf.astype(np.float32)),
     )
     np.testing.assert_allclose(np.asarray(dec), want, rtol=5e-3, atol=5e-3)
@@ -159,9 +163,11 @@ def test_decode_layers_matches_global(n, s, rng):
     enc_re, enc_im = inject_cyclic(enc_re, enc_im, jnp.asarray(adv), "rev_grad")
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
     offsets = [0, 17, 40, d]  # three unequal "layers"
-    dec_g, honest_g = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf))
-    dec_l, honest_l = cyclic.decode_layers(code, enc_re, enc_im, jnp.asarray(rf),
-                                           offsets)
+    dec_g, honest_g = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf))
+    dec_l, honest_l = parity.run_jitted(
+        cyclic.decode_layers, code, enc_re, enc_im, jnp.asarray(rf),
+        offsets=offsets)
     np.testing.assert_allclose(np.asarray(dec_l), np.asarray(dec_g),
                                rtol=5e-3, atol=5e-3)
     # every layer locates the same honest set, and none admits an adversary
@@ -181,8 +187,9 @@ def test_decode_layers_erasures(rng):
     enc_re = jnp.asarray(np.asarray(enc_re) * present[:, None])
     enc_im = jnp.asarray(np.asarray(enc_im) * present[:, None])
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
-    dec, honest_l = cyclic.decode_layers(code, enc_re, enc_im, jnp.asarray(rf),
-                                         [0, 20, d], present=jnp.asarray(present))
+    dec, honest_l = parity.run_jitted(cyclic.decode_layers, code, enc_re, enc_im,
+                              jnp.asarray(rf), offsets=[0, 20, d],
+                              present=jnp.asarray(present))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=2e-3, atol=2e-3)
     assert not np.asarray(honest_l)[:, [2, 6]].any()
@@ -225,7 +232,8 @@ def test_exact_recovery_under_attack_at_scale(n, s, attack, rng):
     adv[rng.choice(n, size=s, replace=False)] = True
     enc_re, enc_im = inject_cyclic(enc_re, enc_im, jnp.asarray(adv), attack)
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
-    dec, honest = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf))
+    dec, honest = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=1e-2, atol=1e-2)
     assert not np.asarray(honest)[adv].any()
@@ -252,8 +260,9 @@ def test_joint_adversary_and_erasure_at_scale(n, s, t, e, rng):
     enc_re = jnp.asarray(np.asarray(enc_re) * present[:, None])
     enc_im = jnp.asarray(np.asarray(enc_im) * present[:, None])
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
-    dec, used = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf),
-                              present=jnp.asarray(present))
+    dec, used = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf),
+        present=jnp.asarray(present))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=1e-2, atol=1e-2)
     used = np.asarray(used)

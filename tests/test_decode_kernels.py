@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu.attacks import inject_cyclic
 from draco_tpu.coding import approx as approx_mod
 from draco_tpu.coding import cyclic as cyclic_mod
@@ -117,7 +118,7 @@ def _locate_on_clean_columns(code, cols, pres=None):
     n = code.n
     pres_f = (np.ones((n, 1), np.float32) if pres is None
               else pres.astype(np.float32)[:, None])
-    return cyclic_mod.locator_core(
+    return jax.jit(cyclic_mod.locator_core, static_argnums=9)(
         jnp.asarray(cols.real.astype(np.float32)),
         jnp.asarray(cols.imag.astype(np.float32)),
         *(jnp.asarray(getattr(code, k)) for k in (
@@ -229,10 +230,13 @@ def test_cyclic_fused_matches_xla(n, s, t, e, tol, rng):
     code = cyclic_mod.build_cyclic_code(n, s)
     d = 192
     bg, er, ei, rf, adv, pres = _attacked_wire(code, rng, d, t, e)
-    dx, hx, hlx = cyclic_mod.decode(code, er, ei, rf, present=pres,
-                                    with_health=True, impl="xla")
-    df, hf, hlf = cyclic_mod.decode(code, er, ei, rf, present=pres,
-                                    with_health=True, impl="fused")
+    def decode(impl):  # each path ONE compiled program (tests/parity.py)
+        return jax.jit(lambda er, ei, rf, pres: cyclic_mod.decode(
+            code, er, ei, rf, present=pres, with_health=True, impl=impl))(
+                er, ei, rf, pres)
+
+    dx, hx, hlx = decode("xla")
+    df, hf, hlf = decode("fused")
     np.testing.assert_array_equal(np.asarray(hx), np.asarray(hf))
     np.testing.assert_array_equal(np.asarray(hlx["flagged"]),
                                   np.asarray(hlf["flagged"]))
@@ -252,10 +256,12 @@ def test_cyclic_fused_layer_matches_xla(n, s, rng):
     d = 192
     bg, er, ei, rf, adv, _ = _attacked_wire(code, rng, d, s, 0)
     offs = [0, 40, 100, d]
-    dx, hx, hlx = cyclic_mod.decode_layers(code, er, ei, rf, offs,
-                                           with_health=True, impl="xla")
-    df, hf, hlf = cyclic_mod.decode_layers(code, er, ei, rf, offs,
-                                           with_health=True, impl="fused")
+    def decode(impl):
+        return jax.jit(lambda er, ei, rf: cyclic_mod.decode_layers(
+            code, er, ei, rf, offs, with_health=True, impl=impl))(er, ei, rf)
+
+    dx, hx, hlx = decode("xla")
+    df, hf, hlf = decode("fused")
     np.testing.assert_array_equal(np.asarray(hx), np.asarray(hf))
     np.testing.assert_array_equal(np.asarray(hlx["flagged"]),
                                   np.asarray(hlf["flagged"]))
@@ -282,8 +288,8 @@ def test_cyclic_fused_beyond_budget_keeps_fault_signals(rng):
     rf = jnp.asarray(rng.normal(loc=1.0, size=d).astype(np.float32))
     flags = {}
     for impl in ("xla", "fused"):
-        _, _, hl = cyclic_mod.decode(code, er, ei, rf, with_health=True,
-                                     impl=impl)
+        _, _, hl = parity.run_jitted(
+            cyclic_mod.decode, code, er, ei, rf, with_health=True, impl=impl)
         assert int(np.asarray(hl["flagged"]).sum()) > code.s, impl
         # the loud forensic mask still names the magnitude outliers
         assert np.asarray(hl["loud"])[adv].all(), impl
@@ -301,8 +307,8 @@ def test_cyclic_fused_nan_wire_accuses_nobody(rng):
     ei = jnp.zeros((8, d), jnp.float32)
     rf = jnp.ones((d,), jnp.float32)
     for impl in ("xla", "fused"):
-        dec, _, hl = cyclic_mod.decode(code, er, ei, rf, with_health=True,
-                                       impl=impl)
+        dec, _, hl = parity.run_jitted(
+            cyclic_mod.decode, code, er, ei, rf, with_health=True, impl=impl)
         assert not np.isfinite(np.asarray(dec)).all(), impl
         assert not np.asarray(hl["flagged"]).any(), impl
         assert not np.asarray(hl["loud"]).any(), impl

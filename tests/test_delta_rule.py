@@ -13,19 +13,16 @@ largest entry forward, 1e-4 for a gradient (at strongly negative g the
 gradient of g is what is left of terms that cancel: its largest entry is
 1e-3 of the other gradients' and carries their rounding)."""
 
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from benchmark.reference.nets import qwen3_next as ref  # noqa: E402
-from draco_tpu.ops.delta_rule import (  # noqa: E402
+import parity
+from benchmark.reference.nets import qwen3_next as ref
+from draco_tpu.ops.delta_rule import (
     SOLVE_NAME, _solve_by_squaring, _unit_lower_inverse,
     chunked_gated_delta_rule, rule_runs_in_kernels,
 )
@@ -60,6 +57,28 @@ def _chunked(q, k, v, g, beta, chunk):
     return o[0], state[0]
 
 
+ALL_FIVE = (0, 1, 2, 3, 4)
+
+
+def _probed(out):
+    """Σ output · a seeded probe, over every output."""
+    return sum(jnp.sum(o * jax.random.normal(jax.random.key(9 + i), o.shape))
+               for i, o in enumerate(jax.tree.leaves(out)))
+
+
+# (outputs, the five inputs' gradients) of the recurrence token by token and
+# of the chunked form: one compiled program a side and a shape, whatever the
+# decay's scale
+_token_by_token_with_gradients = parity.with_gradients(
+    _token_by_token, _probed, ALL_FIVE)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunked_with_gradients(chunk):
+    return parity.with_gradients(
+        lambda *a: _chunked(*a, chunk)[0], _probed, ALL_FIVE)
+
+
 def _close(got, want, what, rel=2e-5):
     scale = float(jnp.max(jnp.abs(want))) + 1e-12
     assert np.all(np.isfinite(np.asarray(got))), what
@@ -75,15 +94,9 @@ def _close(got, want, what, rel=2e-5):
 ])
 def test_chunked_rule_is_the_recurrence(t, chunk, hk, hv, g_scale):
     args = _inputs(t, hk, hv, g_scale)
-    want = _token_by_token(*args)
-    got, _ = _chunked(*args, chunk)
+    want, g_want = _token_by_token_with_gradients(*args)
+    got, g_got = _chunked_with_gradients(chunk)(*args)
     _close(got, want, "outputs")
-
-    probe = jax.random.normal(jax.random.key(9), want.shape)
-    g_want = jax.grad(lambda *a: jnp.sum(_token_by_token(*a) * probe),
-                      argnums=(0, 1, 2, 3, 4))(*args)
-    g_got = jax.grad(lambda *a: jnp.sum(_chunked(*a, chunk)[0] * probe),
-                     argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("q k v g beta".split(), g_got, g_want):
         _close(a, b, f"gradient of {name}", rel=1e-4)
 
@@ -92,12 +105,18 @@ def test_the_state_handed_back_is_the_rows_last():
     """The state after the last REAL token: the closing tokens of a padded
     chunk neither decay it nor write to it."""
     q, k, v, g, beta = _inputs(21, 1, 1, 0.3, seed=3)
-    _, state = _chunked(q, k, v, g, beta, 8)
-    s = jnp.zeros((DK, DV))
-    for t in range(21):
-        s = jnp.exp(g[t, 0]) * s
-        s = s + jnp.outer(k[t, 0], beta[t, 0] * (v[t, 0] - s.T @ k[t, 0]))
-    _close(state[0], s, "state")
+    _, state = jax.jit(functools.partial(_chunked, chunk=8))(q, k, v, g, beta)
+
+    @jax.jit
+    def written_out(k, v, g, beta):
+        s = jnp.zeros((DK, DV))
+        for t in range(21):
+            s = jnp.exp(g[t, 0]) * s
+            s = s + jnp.outer(k[t, 0],
+                              beta[t, 0] * (v[t, 0] - s.T @ k[t, 0]))
+        return s
+
+    _close(state[0], written_out(k, v, g, beta), "state")
 
 
 def test_the_solves_stated_cotangent_is_autodiffs():
@@ -105,9 +124,10 @@ def test_the_solves_stated_cotangent_is_autodiffs():
     low = jnp.tril(0.2 * jax.random.normal(jax.random.key(1), (3, 16, 16)),
                    -1)
     probe = jax.random.normal(jax.random.key(2), low.shape)
-    got = jax.grad(lambda x: jnp.sum(_unit_lower_inverse(x) * probe))(low)
-    want = jax.grad(lambda x: jnp.sum(
-        _solve_by_squaring(x) * probe))(low)
+    got = jax.jit(jax.grad(
+        lambda x: jnp.sum(_unit_lower_inverse(x) * probe)))(low)
+    want = jax.jit(jax.grad(lambda x: jnp.sum(
+        _solve_by_squaring(x) * probe)))(low)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
@@ -152,18 +172,13 @@ def _lane_inputs(t, hk, hv, g_scale, d=128):
 
 def _both_paths(args, **kernel_kw):
     """(o, state, five gradients) of the ``jax.numpy`` path and of the call
-    with ``kernel_kw``; both outputs take a cotangent."""
-    o, state = chunked_gated_delta_rule(*args)
-    probes = (jax.random.normal(jax.random.key(9), o.shape),
-              jax.random.normal(jax.random.key(10), state.shape))
-
+    with ``kernel_kw``, each ONE compiled program (the forward pass run
+    once); both outputs take a cotangent."""
     def run(**kw):
-        def loss(*a):
-            o, state = chunked_gated_delta_rule(*a, **kw)
-            return jnp.sum(o * probes[0]) + jnp.sum(state * probes[1])
-
-        return (chunked_gated_delta_rule(*args, **kw)
-                + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args))
+        (o, state), grads = parity.with_gradients(
+            functools.partial(chunked_gated_delta_rule, **kw), _probed,
+            ALL_FIVE)(*args)
+        return (o, state) + grads
 
     return run(), run(**kernel_kw)
 

@@ -1,5 +1,5 @@
 """Flash-attention kernel parity (interpret mode in CI; real lowering is
-exercised by tools/tpu_attn_check.py on hardware).
+exercised by chip_smoke.py's ``kernels`` phase on hardware).
 
 Oracle: parallel/ring_attention.dense_attention — the streaming-softmax
 reference the ring path is tested against. Forward values AND input
@@ -7,12 +7,15 @@ gradients must match: the backward pass is a hand-written custom VJP —
 one kernel that builds each probability block once and takes dq, dk and dv
 from it — the most bug-prone part."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import draco_tpu.ops.flash_attention as fa
+import parity
 from draco_tpu.ops.flash_attention import flash_attention
 from draco_tpu.parallel.ring_attention import dense_attention
 
@@ -47,7 +50,8 @@ def test_forward_uneven_blocks(rng, bq, bk):
 
 
 def _grads(attn, loss_of, args):
-    return jax.grad(lambda *a: loss_of(attn(*a)), argnums=(0, 1, 2))(*args)
+    return jax.jit(jax.grad(lambda *a: loss_of(attn(*a)),
+                            argnums=(0, 1, 2)))(*args)
 
 
 def _assert_grads_close(got, want, atol):
@@ -132,8 +136,8 @@ def test_grads_with_a_live_lse_match_dense(rng, causal):
     def dense(q, k, v):
         return dense_attention_lse(q, k, v, causal=causal)
 
-    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(q, k, v)
     _assert_grads_close(got, want, atol=5e-5)
 
 
@@ -399,15 +403,15 @@ def test_bf16_inputs_match_dense_f32(rng):
     def loss(attn, *xs):
         return jnp.sum(jnp.sin(attn(*xs).astype(jnp.float32)))
 
-    g_f = jax.grad(
+    g_f = jax.jit(jax.grad(
         lambda q, k, v: loss(
             lambda *a: flash_attention(*a, force=True, interpret=True),
             q, k, v),
-        argnums=(0, 1, 2))(qb, kb, vb)
-    g_d = jax.grad(
+        argnums=(0, 1, 2)))(qb, kb, vb)
+    g_d = jax.jit(jax.grad(
         lambda q, k, v: loss(
             lambda *a: dense_attention(*a, causal=True), q, k, v),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for name, a, b in zip("qkv", g_f, g_d):
         a = np.asarray(a, np.float32)
         b = np.asarray(b)
@@ -418,8 +422,8 @@ def test_bf16_inputs_match_dense_f32(rng):
 
 def test_tpu_lowering_clean_and_control():
     """The kernel must pass the Pallas TPU *lowering* — the stage every
-    recorded hardware failure came from (tpu_attn.json (8,128)-tiling
-    errors) — via cross-platform export on the CPU host, and a
+    recorded hardware failure came from ((8,128)-tiling errors,
+    PERF_HISTORY.md) — via cross-platform export on the CPU host, and a
     deliberately mis-tiled pallas_call must still raise there (negative
     control: proves the check is exercised, not skipped). Full shape
     matrix: tools/tpu_attn_lowering_check.py."""
@@ -571,8 +575,9 @@ def _folded_operands(case, dtype):
 
 
 def _o_lse_and_gradients(case, q, k, v, do, dlse):
-    """o, lse, dq, dk, dv of the module's kernels as they stand (un-jitted,
-    so a patched kernel body is the one traced)."""
+    """o, lse, dq, dk, dv of the module's kernels as they stand (their
+    un-jitted forms, so a patched kernel body is the one traced when the
+    caller compiles this anew)."""
     bq, bk, window = case["bq"], case["bk"], case.get("window")
     causal = case.get("causal", True)
     scale = q.shape[-1] ** -0.5
@@ -626,15 +631,16 @@ def test_sub_tiled_bodies_stay_within_ulps_of_the_rectangle_bodies(
     case = PARENT_CASES[name]
     dtype = jnp.dtype(dtype)
     ops = _folded_operands(case, dtype)
-    got = _o_lse_and_gradients(case, *ops)
-    again = _o_lse_and_gradients(case, *ops)
+    sub_tiled_program = jax.jit(functools.partial(_o_lse_and_gradients, case))
+    got = sub_tiled_program(*ops)
+    again = sub_tiled_program(*ops)
     sub_tiled = fa._bodies(case["t"], case["bq"], case["bk"],
                            case.get("causal", True), case.get("window"),
                            fa.SUB_TILE) is not None
     assert sub_tiled == (not case.get("bit_equal", False))
     monkeypatch.setattr(fa, "_fwd_kernel", _parent_fwd_kernel)
     monkeypatch.setattr(fa, "_bwd_kernel", _parent_bwd_kernel)
-    want = _o_lse_and_gradients(case, *ops)
+    want = jax.jit(functools.partial(_o_lse_and_gradients, case))(*ops)
     for label, a, b, c in zip(["o", "lse", "dq", "dk", "dv"], got, want,
                               again):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
@@ -681,11 +687,11 @@ def test_sub_tiled_kernels_match_dense(shape):
     def loss_of(o):
         return jnp.sum((o - tgt) ** 2)
 
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(dense(q, k, v)),
+    out, got = parity.with_gradients(flash, loss_of, (0, 1, 2))(q, k, v)
+    want_out, want = parity.with_gradients(dense, loss_of, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
                                rtol=2e-5, atol=2e-5)
-    _assert_grads_close(_grads(flash, loss_of, (q, k, v)),
-                        _grads(dense, loss_of, (q, k, v)), atol=5e-5)
+    _assert_grads_close(got, want, atol=5e-5)
 
 
 def test_sub_tiled_grads_with_a_live_lse_match_dense():
@@ -711,6 +717,6 @@ def test_sub_tiled_grads_with_a_live_lse_match_dense():
         return dense_attention_lse(q, k, v, causal=True)
 
     assert fa._bodies(1024, 512, 1024, True, None, fa.SUB_TILE) is not None
-    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(q, k, v)
     _assert_grads_close(got, want, atol=5e-5)

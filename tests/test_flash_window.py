@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu.models.latent_moe import dense_causal_attention
 from draco_tpu.ops import flash_attention as fa
 from draco_tpu.ops.flash_attention import flash_attention
@@ -73,6 +74,8 @@ def test_the_windows_edge_is_exact(impl, t, window):
     does); it is the two inequalities' softmax everywhere."""
     q, k, v = _qkv(t)
     fn = IMPLS[impl]
+    if impl == "kernel":  # one compiled program, four calls
+        fn = jax.jit(fn, static_argnums=3)
     out = fn(q, k, v, window)
     np.testing.assert_allclose(
         out, _by_the_two_inequalities(q, k, v, window), atol=2e-6)
@@ -115,11 +118,12 @@ def test_kernel_matches_dense_forward_and_all_three_gradients(
         return dense_attention(q, *fa.spread_kv_heads(heads, k, v),
                                window=window)
 
-    np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) ** 2), argnums=(0, 1, 2))(
-        q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), argnums=(0, 1, 2))(
-        q, k, v)
+    def square(out):
+        return jnp.sum(out ** 2)
+
+    out, got = parity.with_gradients(kernel, square, (0, 1, 2))(q, k, v)
+    want_out, want = parity.with_gradients(dense, square, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(out, want_out, atol=2e-6)
     for a, b in zip(got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=2e-5)

@@ -1,12 +1,11 @@
 """models/latent_moe.LatentMoeLM at a tiny size (hidden 64, 4 heads, 8 routed
 experts of which 2 are held) against the plain reference the benchmark
 compares it with on the chip (benchmark/reference/nets/latent_moe.py, which
-imports nothing of draco_tpu):
+imports nothing of draco_tpu). What every published-config block is held
+to alike — loss, logits, every leaf's gradient, the shares adding up, the
+refusals — is tests/test_spec_lm_parity.py's; here is what is this
+block's own:
 
-* loss, logits and every leaf's gradient on seeded weights;
-* the shares add up: what every chip's share of the routed experts gives,
-  with the shared experts and attention counted once, is the uncut
-  reference layer;
 * the top-k never drops a token, at any imbalance (all tokens to one held
   expert);
 * the dispatch buffer holds C rows, fewer than T·k where the chip holds a
@@ -14,44 +13,30 @@ imports nothing of draco_tpu):
   T·min(k, held) pairs here gives the reference's output and gradients, with
   the further buffers it took counted;
 * the flash kernel at q/k and v of different head sizes (interpret mode);
-* a mapping the block cannot state is refused by the key's name, and so is
-  every TrainConfig the new network or the LM vote do not support.
+* the network validates with the vote.
+
+Every compared value is one compiled program (tests/parity.py).
 """
 
 import copy
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import parity
+from benchmark.reference.nets import latent_moe as ref
+from draco_tpu.config import TrainConfig
+from draco_tpu.models import latent_moe
+from draco_tpu.models.latent_moe import LatentMoeLM
 
-from benchmark.reference.nets import latent_moe as ref  # noqa: E402
-from draco_tpu.config import TrainConfig  # noqa: E402
-from draco_tpu.models import latent_moe  # noqa: E402
-from draco_tpu.models.latent_moe import LatentMoeLM  # noqa: E402
-
-with open(os.path.join(ROOT, "benchmark", "testdata",
-                       "latent-moe-tiny.json")) as fh:
-    TINY = json.load(fh)
-SPEC = TINY["train_config"]["model_spec"]
+SPEC = parity.tiny("latent-moe-tiny")
 T = 32
 
 
 def _tokens(seed=0, batch=2):
-    rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.integers(0, SPEC["vocab_rows"], (batch, T)),
-                       jnp.int32)
-
-
-def _loss(lm, params, toks):
-    nll, stats = lm.token_nll(params, toks, jnp.roll(toks, -1, axis=1))
-    return jnp.mean(nll[:, :-1]), stats
+    return parity.tokens(SPEC["vocab_rows"], batch, T, seed)
 
 
 @pytest.fixture(scope="module")
@@ -73,74 +58,6 @@ def test_parameter_count_is_the_shapes(model):
     assert "router" not in params["layer0"]
 
 
-def test_loss_and_logits_match_the_reference(model):
-    lm, params = model
-    toks = _tokens()
-    loss, _ = _loss(lm, params, toks)
-    assert float(loss) == pytest.approx(
-        float(ref.loss(params, toks, SPEC)), rel=2e-6)
-    got = lm.logits(params, toks)
-    for b in range(toks.shape[0]):
-        np.testing.assert_allclose(got[b], ref.logits(params, toks[b], SPEC),
-                                   atol=2e-5)
-
-
-def test_every_leafs_gradient_matches_the_reference(model):
-    lm, params = model
-    toks = _tokens(1)
-    got = jax.grad(lambda p: _loss(lm, p, toks)[0])(params)
-    want = jax.grad(ref.loss)(params, toks, SPEC)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, g), w in zip(flat_got, flat_want):
-        name = jax.tree_util.keystr(path)
-        scale = float(jnp.max(jnp.abs(w))) + 1e-12
-        assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * scale + 1e-9, name
-        if name.endswith("['e_score_correction_bias']"):
-            assert not np.any(np.asarray(g)), "the bias takes no gradient"
-
-
-def test_rematerialised_block_gives_the_same_gradient(model):
-    lm, params = model
-    toks = _tokens(2)
-    plain = jax.grad(lambda p: _loss(lm, p, toks)[0])(params)
-    remat = jax.grad(lambda p: _loss(LatentMoeLM(SPEC, remat=True), p,
-                                     toks)[0])(params)
-    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(remat)):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-8)
-
-
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Four chips hold two experts each. Every share's routed part, plus
-    the shared experts and attention ONCE, is what the reference gives for
-    the whole layer with all eight experts held."""
-    n_exp = SPEC["n_routed_experts"]
-    whole = dict(SPEC, experts_held=[0, n_exp])
-    p = LatentMoeLM(whole).init(jax.random.key(5))["layer1"]
-    x = jax.random.normal(jax.random.key(6), (T, SPEC["hidden_size"]))
-    want = ref.layer(x, p, whole, lambda t: t, dense=False)
-
-    eps = SPEC["rms_norm_eps"]
-    once = x + ref.attention(ref.rms(x, p["attn_norm"]["scale"], eps), p,
-                             whole, lambda t: t)
-    h = ref.rms(once, p["mlp_norm"]["scale"], eps)
-    shared = latent_moe.swiglu(h, p["shared"])
-    total = once + shared
-    landed = 0.0
-    for first in range(0, n_exp, 2):
-        lm = LatentMoeLM(dict(SPEC, experts_held=[first, 2]))
-        part = dict(p, experts=jax.tree.map(lambda a: a[first:first + 2],
-                                            p["experts"]))
-        after, stats = lm._experts(once, part)  # once + shared + routed
-        total = total + (after - once - shared)
-        landed += float(jnp.sum(stats["load"]))
-        assert float(stats["dropped"]) == 0.0
-    np.testing.assert_allclose(total, want, atol=2e-5)
-    # every (token, choice) pair landed on exactly one share
-    assert landed == T * SPEC["num_experts_per_tok"]
-
-
 def test_no_token_is_dropped_when_all_choose_one_held_expert(model):
     lm, params = model
     first, held = SPEC["experts_held"]
@@ -153,15 +70,15 @@ def test_no_token_is_dropped_when_all_choose_one_held_expert(model):
     for i in range(SPEC["first_k_dense_replace"], SPEC["layers"]):
         skew[f"layer{i}"]["router"]["e_score_correction_bias"] = bias
     toks = _tokens(4)
-    loss, stats = _loss(lm, skew, toks)
+    loss, stats = jax.jit(lambda p: parity.mean_nll(lm, p, toks))(skew)
     moe_layers = SPEC["layers"] - SPEC["first_k_dense_replace"]
     assert k >= held
     assert float(stats["moe_dropped"]) == 0.0
     assert float(stats["moe_assignments_held"]) == \
         toks.size * held * moe_layers
     assert float(stats["moe_load_max_over_mean"]) == pytest.approx(1.0)
-    assert float(loss) == pytest.approx(float(ref.loss(skew, toks, SPEC)),
-                                        rel=2e-6)
+    want = jax.jit(lambda p: ref.loss(p, toks, SPEC))(skew)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
 
 
 # ---- the dispatch buffer: C rows, and every routing still exact ---------
@@ -199,28 +116,50 @@ def _routing_that_lands(m: int):
     return jnp.asarray(x), jax.tree.map(jnp.asarray, p)
 
 
-def _assert_experts_match_the_reference(spec, x, p, landed, further):
-    """``_experts`` of (x, p): output and every leaf's gradient against the
-    dense reference, and the counters."""
+def _experts_both_ways(spec):
+    """(x, p, cotangent) -> (y, counters, the gradients for x and p) of
+    ``_experts``, and (y, the gradients) of the dense reference: two
+    compiled programs that serve every routing of the same shapes."""
     lm = LatentMoeLM(spec)
-    cot = jax.random.normal(jax.random.key(landed), x.shape)
-
-    def reference(x, p):
-        h = ref.rms(x, p["mlp_norm"]["scale"], spec["rms_norm_eps"])
-        return x + ref.experts(h, p, spec, lambda t: t)
 
     def ours(x, p):
         y, stats = lm._experts(x, p)
-        return jnp.sum(y * cot), (y, latent_moe.fold_stats([stats]))
+        return y, latent_moe.fold_stats([stats])
 
-    (_, (y, stats)), got = jax.value_and_grad(ours, argnums=(0, 1),
-                                              has_aux=True)(x, p)
+    def reference(x, p):
+        h = ref.rms(x, p["mlp_norm"]["scale"], spec["rms_norm_eps"])
+        return x + ref.experts(h, p, spec, lambda t: t), {}
+
+    def probed(fn):
+        def run(x, p, cot):
+            def loss(x, p):
+                y, stats = fn(x, p)
+                return jnp.sum(y * cot), (y, stats)
+
+            grads, out = jax.grad(loss, argnums=(0, 1), has_aux=True)(x, p)
+            return out, grads
+
+        return jax.jit(run)
+
+    return probed(ours), probed(reference)
+
+
+@pytest.fixture(scope="module")
+def small_share():
+    return _experts_both_ways(SMALL_SHARE)
+
+
+def _assert_experts_match_the_reference(both_ways, x, p, landed, further):
+    """``_experts`` of (x, p): output and every leaf's gradient against the
+    dense reference, and the counters."""
+    ours, reference = both_ways
+    cot = jax.random.normal(jax.random.key(landed), x.shape)
+    (y, stats), got = ours(x, p, cot)
     assert float(stats["moe_assignments_held"]) == landed
     assert float(stats["moe_dropped"]) == 0.0
     assert float(stats["moe_full_dispatch"]) == further
-    np.testing.assert_allclose(y, reference(x, p), atol=2e-5)
-    want = jax.grad(lambda x, p: jnp.sum(reference(x, p) * cot),
-                    argnums=(0, 1))(x, p)
+    (want_y, _), want = reference(x, p, cot)
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
     assert len(flat_got) == len(flat_want)
@@ -247,12 +186,12 @@ def test_dispatch_buffer_is_sized_by_the_chips_share():
 @pytest.mark.parametrize("landed,further", [
     (0, 0), (1, 0), (C_ROWS - 1, 0), (C_ROWS, 0), (C_ROWS + 1, 1),
     (2 * N_TOK, 3)])
-def test_any_routing_is_computed_exactly(landed, further):
+def test_any_routing_is_computed_exactly(small_share, landed, further):
     """Output and every leaf's gradient against the dense reference, for
     routings that fill the buffer to its last row, pass it by one, and
     send every token to both held experts."""
     x, p = _routing_that_lands(landed)
-    _assert_experts_match_the_reference(SMALL_SHARE, x, p, landed, further)
+    _assert_experts_match_the_reference(small_share, x, p, landed, further)
 
 
 def test_a_last_buffer_that_reaches_past_the_pairs_is_exact():
@@ -267,7 +206,8 @@ def test_a_last_buffer_that_reaches_past_the_pairs_is_exact():
     bias[[9, 12, 15]] = 50.0
     p["router"]["e_score_correction_bias"] = jnp.asarray(bias)
     x = jax.random.normal(jax.random.key(14), (tokens, spec["hidden_size"]))
-    _assert_experts_match_the_reference(spec, x, p, tokens * k, 1)
+    _assert_experts_match_the_reference(_experts_both_ways(spec), x, p,
+                                        tokens * k, 1)
 
 
 def test_lanes_side_by_side_each_take_the_buffers_they_need():
@@ -281,11 +221,10 @@ def test_lanes_side_by_side_each_take_the_buffers_they_need():
         y, stats = lm._experts(x, p)
         return jnp.sum(y ** 2), stats["further"]
 
-    each = [jax.value_and_grad(loss, argnums=1, has_aux=True)(x, p)
-            for x in (x0, x1)]
-    (_, further), grads = jax.vmap(
-        jax.value_and_grad(loss, argnums=1, has_aux=True),
-        in_axes=(0, None))(jnp.stack([x0, x1]), p)
+    alone = jax.jit(jax.value_and_grad(loss, argnums=1, has_aux=True))
+    each = [alone(x, p) for x in (x0, x1)]
+    (_, further), grads = jax.jit(jax.vmap(alone, in_axes=(0, None)))(
+        jnp.stack([x0, x1]), p)
     assert further.tolist() == [0.0, 1.0]
     for lane, ((_, f), g) in enumerate(each):
         assert float(f) == float(further[lane])
@@ -304,33 +243,17 @@ def test_flash_kernel_takes_q_k_and_v_of_different_head_sizes():
         return flash_attention(q, k, v, block_q=16, block_k=16,
                                interpret=True)
 
-    out = kernel(q, k, v)
+    def square(out):
+        return jnp.sum(out ** 2)
+
+    out, got = parity.with_gradients(kernel, square, (0, 1, 2))(q, k, v)
+    want_out, want = parity.with_gradients(
+        latent_moe.dense_causal_attention, square, (0, 1, 2))(q, k, v)
     assert out.shape == (1, 32, 2, 16)
-    np.testing.assert_allclose(out, latent_moe.dense_causal_attention(
-        q, k, v), atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) ** 2), argnums=(0, 1, 2))(
-        q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(
-        latent_moe.dense_causal_attention(*a) ** 2), argnums=(0, 1, 2))(
-            q, k, v)
+    np.testing.assert_allclose(out, want_out, atol=2e-6)
     for a, b in zip(got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=1e-5)
-
-
-@pytest.mark.parametrize("edit,names", [
-    (lambda s: s.pop("kv_lora_rank"), "kv_lora_rank"),
-    (lambda s: s.update(q_lora_rank=1536), "q_lora_rank"),
-    (lambda s: s.update(scoring_func="softmax"), "scoring_func"),
-    (lambda s: s.update(experts_held=[7, 2]), "experts_held"),
-    (lambda s: s.update(rope_scaling={"type": "yarn"}), "rope_scaling"),
-    (lambda s: s.update(tie_word_embeddings=True), "tie_word_embeddings"),
-])
-def test_a_mapping_the_block_cannot_state_is_refused_by_name(edit, names):
-    spec = dict(SPEC)
-    edit(spec)
-    with pytest.raises(ValueError, match=names):
-        LatentMoeLM(spec)
 
 
 def _cfg(**kw):
@@ -348,22 +271,3 @@ def test_the_new_network_validates_with_the_vote():
                 approach="maj_vote").validate().approach == "maj_vote"
 
 
-@pytest.mark.parametrize("kw,names", [
-    (dict(tensor_shards=2), "tensor_shards"),
-    (dict(seq_shards=2), "seq_shards"),
-    (dict(expert_shards=2), "expert_shards"),
-    (dict(pipeline_shards=2), "pipeline_shards"),
-    (dict(vocab=SPEC["vocab_rows"] + 1), "vocab_rows"),
-    (dict(moe_experts=4), "moe_experts"),
-    (dict(model_spec=None), "model_spec"),
-    (dict(network="LeNet", dataset="synthetic-mnist"), "model_spec"),
-    (dict(wire_dtype="bf16"), "wire_dtype"),
-    (dict(numerics_watch="on"), "numerics_watch"),
-    (dict(network="TransformerLM", model_spec=None, vocab=64, seq_shards=2),
-     "seq_shards"),
-    (dict(network="TransformerLM", model_spec=None, vocab=64,
-          tensor_shards=2), "tensor_shards"),
-])
-def test_what_stays_unsupported_is_refused_by_name(kw, names):
-    with pytest.raises(ValueError, match=names):
-        _cfg(**kw).validate()

@@ -22,23 +22,27 @@ class TestModels:
     def test_forward_shapes(self, name, shape):
         model = models.build_model(name)
         x = jnp.zeros((2,) + shape)
-        variables = model.init(
-            {"params": jax.random.key(0), "dropout": jax.random.key(1)}, x, train=False
-        )
-        out = model.apply(variables, x, train=False)
+        # init and forward pass ONE compiled program each: called eagerly
+        # they are a dispatch and a tiny compile a primitive
+        variables = jax.jit(lambda p, d: model.init(
+            {"params": p, "dropout": d}, x, train=False
+        ))(jax.random.key(0), jax.random.key(1))
+        out = jax.jit(lambda v: model.apply(v, x, train=False))(variables)
         assert out.shape == (2, 10)
 
     def test_resnet18_param_count(self):
         # CIFAR ResNet-18 has ~11.17M parameters — sanity against the standard
         model = models.build_model("ResNet18")
-        v = model.init(jax.random.key(0), jnp.zeros((1, 32, 32, 3)), train=False)
+        v = jax.eval_shape(lambda k: model.init(
+            k, jnp.zeros((1, 32, 32, 3)), train=False), jax.random.key(0))
         n = sum(np.prod(p.shape) for p in jax.tree.leaves(v["params"]))
         assert 11_000_000 < n < 11_400_000
 
     def test_lenet_param_count(self):
         # 20*25+20 + 50*20*25+50 + 800*500+500 + 500*10+10 = 431080
         model = models.build_model("LeNet")
-        v = model.init(jax.random.key(0), jnp.zeros((1, 28, 28, 1)), train=False)
+        v = jax.eval_shape(lambda k: model.init(
+            k, jnp.zeros((1, 28, 28, 1)), train=False), jax.random.key(0))
         n = sum(np.prod(p.shape) for p in jax.tree.leaves(v["params"]))
         assert n == 431080
 
@@ -277,7 +281,8 @@ class TestMixedPrecision:
 
         model = build_model("ResNet18", dtype="bfloat16")
         x = jnp.ones((2, 32, 32, 3), jnp.float32)
-        vs = model.init(jax.random.key(0), x, train=False)
+        vs = jax.jit(lambda k: model.init(k, x, train=False))(
+            jax.random.key(0))
         assert all(p.dtype == jnp.float32 for p in jax.tree.leaves(vs["params"]))
 
         def loss_fn(params):
@@ -288,7 +293,7 @@ class TestMixedPrecision:
             assert logits.dtype == jnp.float32
             return jnp.mean(logits ** 2)
 
-        g = jax.grad(loss_fn)(vs["params"])
+        g = jax.jit(jax.grad(loss_fn))(vs["params"])
         leaves = jax.tree.leaves(g)
         assert all(p.dtype == jnp.float32 for p in leaves)
         assert all(bool(jnp.all(jnp.isfinite(p))) for p in leaves)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu.obs import (
     NULL_TRACER,
     CompileWatch,
@@ -410,8 +411,8 @@ def test_forensics_straggler_never_accused_both_codes():
     pres = jnp.asarray(np.arange(8) != 2)
     er_d = er * pres[:, None]
     ei_d = ei * pres[:, None]
-    _, _, h = cyclic.decode(code, er_d, ei_d, rf, present=pres,
-                            with_health=True)
+    _, _, h = parity.run_jitted(
+        cyclic.decode, code, er_d, ei_d, rf, present=pres, with_health=True)
     h["bad_rows"] = fx.nonfinite_rows(jnp.asarray(g))
     accused = np.asarray(accusation_mask(h, pres))
     assert not accused[2]  # absent != accused
@@ -448,21 +449,22 @@ def test_cyclic_loud_rows_attribute_beyond_budget():
     g = rng.randn(8, 64).astype(np.float32)
     rf = jnp.asarray(1.0 + rng.randn(64).astype(np.float32))
     er, ei = cyclic.encode_shared(code, jnp.asarray(g))
+    decode = parity.jitted(cyclic.decode, code, with_health=True)
     for rows in ([2, 5], [0, 4], [1, 6], [3, 7]):
         er2, ei2 = er, ei
         for r in rows:
             er2, ei2 = er2.at[r].mul(-100.0), ei2.at[r].mul(-100.0)
-        _, _, h = cyclic.decode(code, er2, ei2, rf, with_health=True)
+        _, _, h = decode(er2, ei2, rf)
         accused = np.asarray(accusation_mask(h))
         assert set(rows) <= set(np.nonzero(accused)[0].tolist()), (
             rows, np.nonzero(accused)[0])
     # in budget: accusation == the exact flag set (no honest loud rows)
     er1, ei1 = er.at[3].mul(-100.0), ei.at[3].mul(-100.0)
-    _, _, h1 = cyclic.decode(code, er1, ei1, rf, with_health=True)
+    _, _, h1 = decode(er1, ei1, rf)
     np.testing.assert_array_equal(np.asarray(accusation_mask(h1)),
                                   np.arange(8) == 3)
     # clean: nobody accused
-    _, _, h0 = cyclic.decode(code, er, ei, rf, with_health=True)
+    _, _, h0 = decode(er, ei, rf)
     assert np.asarray(accusation_mask(h0)).sum() == 0
 
 
@@ -482,7 +484,8 @@ def test_nonfinite_rows_attribute_through_shared_encode():
     rf = jnp.asarray(1.0 + rng.randn(64).astype(np.float32))
     er, ei = cyclic.encode_shared(code, jnp.asarray(g))
     assert not np.isfinite(np.asarray(er)).all(axis=1).any()  # all smeared
-    _, _, h = cyclic.decode(code, er, ei, rf, with_health=True)
+    _, _, h = parity.run_jitted(
+        cyclic.decode, code, er, ei, rf, with_health=True)
     h["bad_rows"] = fx.nonfinite_rows(jnp.asarray(g))
     np.testing.assert_array_equal(np.asarray(accusation_mask(h)),
                                   np.arange(8) == 3)
@@ -554,12 +557,14 @@ def test_cyclic_decode_health_flags_exactly_the_corrupt_rows():
     rf = jnp.asarray(1.0 + rng.randn(64).astype(np.float32))
     er, ei = cyclic.encode_shared(code, jnp.asarray(g))
     # clean: nothing flagged, residual is float noise
-    _, _, h = cyclic.decode(code, er, ei, rf, with_health=True)
+    _, _, h = parity.run_jitted(
+        cyclic.decode, code, er, ei, rf, with_health=True)
     assert float(h["residual"]) < 1e-4
     assert np.asarray(h["flagged"]).sum() == 0
     # one corrupt row (rev_grad magnitude): flagged exactly, residual ~ 0
     er1, ei1 = er.at[3].mul(-99.0), ei.at[3].mul(-99.0)
-    _, honest, h1 = cyclic.decode(code, er1, ei1, rf, with_health=True)
+    _, honest, h1 = parity.run_jitted(
+        cyclic.decode, code, er1, ei1, rf, with_health=True)
     np.testing.assert_array_equal(
         np.asarray(h1["flagged"]),
         np.arange(8) == 3)
@@ -567,8 +572,9 @@ def test_cyclic_decode_health_flags_exactly_the_corrupt_rows():
     assert not bool(np.asarray(honest)[3])
     # erasure-only: stragglers are known-missing, never "detected"
     pres = np.arange(8) != 5
-    _, _, h2 = cyclic.decode(code, er * pres[:, None], ei * pres[:, None],
-                             rf, present=jnp.asarray(pres), with_health=True)
+    _, _, h2 = parity.run_jitted(
+        cyclic.decode, code, er * pres[:, None], ei * pres[:, None], rf,
+        present=jnp.asarray(pres), with_health=True)
     assert np.asarray(h2["flagged"]).sum() == 0
     assert float(h2["residual"]) < 1e-4
 
@@ -585,11 +591,12 @@ def test_cyclic_decode_health_raises_fault_beyond_budget():
     g = rng.randn(8, 64).astype(np.float32)
     rf = jnp.asarray(1.0 + rng.randn(64).astype(np.float32))
     er, ei = cyclic.encode_shared(code, jnp.asarray(g))
+    decode = parity.jitted(cyclic.decode, code, with_health=True)
     for rows in ([2, 5], [0, 4], [1, 6]):
         er2, ei2 = er, ei
         for r in rows:
             er2, ei2 = er2.at[r].mul(-99.0), ei2.at[r].mul(-99.0)
-        _, _, h = cyclic.decode(code, er2, ei2, rf, with_health=True)
+        _, _, h = decode(er2, ei2, rf)
         flagged = int(np.asarray(h["flagged"]).sum())
         assert flagged > code.s or float(h["residual"]) > 1e-4, (
             rows, flagged, float(h["residual"]))
@@ -606,8 +613,8 @@ def test_cyclic_decode_layers_health_unions_layers():
     er, ei = cyclic.encode_shared(code, jnp.asarray(g))
     # corrupt row 4 only inside the second layer's coordinates [10, 24)
     er = er.at[4, 10:].add(100.0)
-    _, _, h = cyclic.decode_layers(code, er, ei, rf, [0, 10, 24],
-                                   with_health=True)
+    _, _, h = parity.run_jitted(cyclic.decode_layers, code, er, ei, rf,
+                              offsets=[0, 10, 24], with_health=True)
     np.testing.assert_array_equal(np.asarray(h["flagged"]),
                                   np.arange(8) == 4)
     assert float(h["residual"]) < 1e-4

@@ -51,7 +51,7 @@ def test_ring_attention_matches_dense(rng, sp, causal):
         out_specs=P(None, "sp"),
         check_vma=False,
     )
-    out = ring(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    out = jax.jit(ring)(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
     np.testing.assert_allclose(np.asarray(out), _softmax_attn(q, k, v, causal),
                                rtol=1e-4, atol=1e-5)
 
@@ -76,8 +76,10 @@ def test_ring_attention_gradient_matches_dense(rng):
     def dense_scalar(q, k, v):
         return jnp.sum(jnp.sin(dense_attention(q, k, v, causal=True)))
 
-    g_ring = jax.grad(ring_scalar, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
-    g_dense = jax.grad(dense_scalar, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
+    g_ring = jax.jit(jax.grad(ring_scalar, argnums=(0, 1, 2)))(
+        *map(jnp.asarray, (q, k, v)))
+    g_dense = jax.jit(jax.grad(dense_scalar, argnums=(0, 1, 2)))(
+        *map(jnp.asarray, (q, k, v)))
     for gr, gd in zip(g_ring, g_dense):
         np.testing.assert_allclose(np.asarray(gr), np.asarray(gd), rtol=1e-4, atol=1e-5)
 
@@ -223,7 +225,7 @@ def test_a2a_attention_matches_dense(rng, sp, causal):
         out_specs=P(None, "sp"),
         check_vma=False,
     )
-    out = a2a(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    out = jax.jit(a2a)(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
     np.testing.assert_allclose(np.asarray(out), _softmax_attn(q, k, v, causal),
                                rtol=1e-4, atol=1e-5)
 
@@ -250,8 +252,10 @@ def test_a2a_attention_gradient_matches_dense(rng):
     def dense_scalar(q, k, v):
         return jnp.sum(jnp.sin(dense_attention(q, k, v, causal=True)))
 
-    g_a2a = jax.grad(a2a_scalar, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
-    g_dense = jax.grad(dense_scalar, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
+    g_a2a = jax.jit(jax.grad(a2a_scalar, argnums=(0, 1, 2)))(
+        *map(jnp.asarray, (q, k, v)))
+    g_dense = jax.jit(jax.grad(dense_scalar, argnums=(0, 1, 2)))(
+        *map(jnp.asarray, (q, k, v)))
     for ga, gd in zip(g_a2a, g_dense):
         np.testing.assert_allclose(np.asarray(ga), np.asarray(gd), rtol=1e-4, atol=1e-5)
 
@@ -344,7 +348,7 @@ def test_ring_flash_matches_dense(rng, sp, causal):
         out_specs=P(None, "sp"),
         check_vma=False,
     )
-    out = ring(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    out = jax.jit(ring)(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
     np.testing.assert_allclose(np.asarray(out), _softmax_attn(q, k, v, causal),
                                rtol=1e-4, atol=1e-5)
 
@@ -372,8 +376,10 @@ def test_ring_flash_gradient_matches_dense(rng):
     def dense_scalar(q, k, v):
         return jnp.sum(jnp.sin(dense_attention(q, k, v, causal=True)))
 
-    g_ring = jax.grad(ring_scalar, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
-    g_dense = jax.grad(dense_scalar, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
+    g_ring = jax.jit(jax.grad(ring_scalar, argnums=(0, 1, 2)))(
+        *map(jnp.asarray, (q, k, v)))
+    g_dense = jax.jit(jax.grad(dense_scalar, argnums=(0, 1, 2)))(
+        *map(jnp.asarray, (q, k, v)))
     for name, gr, gd in zip("qkv", g_ring, g_dense):
         np.testing.assert_allclose(np.asarray(gr), np.asarray(gd), rtol=1e-4,
                                    atol=1e-5, err_msg=f"d{name}")

@@ -628,6 +628,7 @@ class TestColludingAttacks:
         from draco_tpu.training.trainer import Trainer
 
         losses = {}
+        ds = load_dataset("synthetic-mnist")
         for mode in ("normal", "coord_median"):
             cfg = TrainConfig(
                 network="FC", dataset="synthetic-mnist", batch_size=16,
@@ -635,7 +636,6 @@ class TestColludingAttacks:
                 worker_fail=2, err_mode="ipm", adversarial=-800.0,
                 max_steps=30, eval_freq=0, train_dir="", log_every=1000,
             )
-            ds = load_dataset("synthetic-mnist")
             tr = Trainer(cfg, mesh=make_mesh(8), dataset=ds, quiet=True)
             last = tr.run()
             losses[mode] = float(last["loss"])

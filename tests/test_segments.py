@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu.config import TrainConfig
 from draco_tpu.obs import numerics as nx
 
@@ -152,11 +153,11 @@ def test_cyclic_single_segment_is_the_unsegmented_decode():
     from draco_tpu.coding import cyclic
 
     code, _, r_re, r_im, rf = _cyclic_fixture()
-    dec, honest, health = cyclic.decode(code, r_re, r_im, rf,
+    dec, honest, health = parity.run_jitted(cyclic.decode, code, r_re, r_im, rf,
                                         with_health=True)
-    d1, h1, he1 = cyclic.decode_segments(code, r_re, r_im, rf,
-                                         (0, r_re.shape[1]),
-                                         with_health=True)
+    d1, h1, he1 = parity.run_jitted(cyclic.decode_segments, code, r_re, r_im,
+                                  rf, bounds=(0, r_re.shape[1]),
+                                  with_health=True)
     np.testing.assert_allclose(np.asarray(dec), np.asarray(d1),
                                rtol=1e-5, atol=1e-7)
     assert h1.shape == (1, code.n)
@@ -180,9 +181,10 @@ def test_cyclic_segmented_fold(segs):
     d = r_re.shape[1]
     bounds = nx.wire_segment_bounds(d, segs)
     assert len(bounds) == segs + 1
-    dec, _, health = cyclic.decode(code, r_re, r_im, rf, with_health=True)
-    dS, hS, heS = cyclic.decode_segments(code, r_re, r_im, rf, bounds,
-                                         with_health=True)
+    dec, _, health = parity.run_jitted(
+        cyclic.decode, code, r_re, r_im, rf, with_health=True)
+    dS, hS, heS = parity.run_jitted(cyclic.decode_segments, code, r_re, r_im,
+                                  rf, bounds=bounds, with_health=True)
     truth = np.asarray(jnp.sum(grads, axis=0)) / code.n
     np.testing.assert_allclose(np.asarray(dS), truth, rtol=2e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dS), np.asarray(dec),
@@ -509,9 +511,9 @@ def test_segment_pipeline_rails():
 
 def test_perf_watch_segment_gates_flipped_rows(tmp_path):
     """The ISSUE 16 fold (tools/perf_watch.fold_segment_study): the
-    pipeline-win and overlap acceptance bools gate at tolerance 0; the
     per-cell segment counts and per-segment physical bytes are PINNED in
-    BOTH directions; the S=1 row's overlap is pinned at exactly 0."""
+    BOTH directions; the ms/step win and the overlap fractions are a CPU
+    run's wall clock and gate nothing."""
     from tools import perf_watch
 
     root = tmp_path
@@ -541,14 +543,18 @@ def test_perf_watch_segment_gates_flipped_rows(tmp_path):
     assert perf_watch.main(["--root", str(root), "--snapshot"]) == 0
     snap = json.loads(
         (root / "baselines_out" / "perf_watch.json").read_text())
-    for key in ("segment.all_ok", "segment.win.positive",
-                "segment.win.overlap_positive",
-                "segment.f32.s1.overlap_frac",
-                "segment.f32.s2.ms_per_step",
-                "segment.f32.s2.segments_count",
-                "segment.f32.s2.seg0_bytes_per_worker"):
-        assert key in snap["metrics"], key
+    assert set(snap["metrics"]) == {
+        "segment.f32.s1.segments_count",
+        "segment.f32.s1.seg0_bytes_per_worker",
+        "segment.f32.s2.segments_count",
+        "segment.f32.s2.seg0_bytes_per_worker",
+        "segment.f32.s2.seg1_bytes_per_worker"}
     assert perf_watch.main(["--root", str(root)]) == 0  # clean
+    # a win that turns into a loss, overlap that vanishes or appears at
+    # S=1: wall-clock measures of a CPU run, not folded
+    path.write_text(json.dumps(artifact(win_ms=-5.0, win_overlap=0.0,
+                                        s1_overlap=0.1)))
+    assert perf_watch.main(["--root", str(root)]) == 0
 
     def gated(art, *metrics):
         path.write_text(json.dumps(art))
@@ -559,12 +565,6 @@ def test_perf_watch_segment_gates_flipped_rows(tmp_path):
         for m in metrics:
             assert m in regs, (m, regs)
 
-    # the pipeline win going non-positive gates (the acceptance bool)
-    gated(artifact(win_ms=-5.0), "segment.win.positive")
-    # the overlap evidence vanishing gates
-    gated(artifact(win_overlap=0.0), "segment.win.overlap_positive")
-    # the S=1 row measuring ANY overlap means the metric broke: pinned
-    gated(artifact(s1_overlap=0.1), "segment.f32.s1.overlap_frac")
     # per-segment bytes pinned in BOTH directions
     gated(artifact(seg_bytes=(401, 400)),
           "segment.f32.s2.seg0_bytes_per_worker")

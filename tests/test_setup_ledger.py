@@ -56,6 +56,20 @@ def _build(kind):
     return cfg, setup, tuples
 
 
+@pytest.fixture(scope="module", autouse=True)
+def no_watch_left_open():
+    """A Trainer that an earlier file of this xdist worker built and never
+    closed (``single_machine.main``'s; one whose constructor raised after
+    its watch had started) keeps its compile watch receiving events, and
+    while any watch is active a build goes to that watch's tracer and not
+    onto the ledger's list (``compile_watch._dispatch``): the cases here
+    that list a build then find none, by the order ``--dist loadfile`` gave
+    the files. Watches left open are stopped before this module's first
+    builder."""
+    for watch in list(compile_watch._ACTIVE):
+        watch.stop()
+
+
 @pytest.fixture(scope="module", params=["cnn", "lm"])
 def built(request):
     return _build(request.param)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu import aggregation
 from draco_tpu.coding import cyclic, repetition
 from draco_tpu.config import TrainConfig
@@ -42,8 +43,9 @@ def test_erasure_only_exact(n, s, missing, rng):
     enc_re = jnp.asarray(np.asarray(enc_re) * present[:, None])
     enc_im = jnp.asarray(np.asarray(enc_im) * present[:, None])
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
-    dec, used = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf),
-                              present=jnp.asarray(present))
+    dec, used = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf),
+        present=jnp.asarray(present))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=2e-3, atol=2e-3)
     used = np.asarray(used)
@@ -68,8 +70,9 @@ def test_joint_adversary_and_erasure(n, s, adv, missing, rng):
     enc_re = jnp.asarray(np.asarray(enc_re) * present[:, None])
     enc_im = jnp.asarray(np.asarray(enc_im) * present[:, None])
     rf = rng.normal(loc=1.0, size=d).astype(np.float32)
-    dec, used = cyclic.decode(code, enc_re, enc_im, jnp.asarray(rf),
-                              present=jnp.asarray(present))
+    dec, used = parity.run_jitted(
+        cyclic.decode, code, enc_re, enc_im, jnp.asarray(rf),
+        present=jnp.asarray(present))
     want = batch_grads.sum(axis=0) / n
     np.testing.assert_allclose(np.asarray(dec), want, rtol=5e-3, atol=5e-3)
     used = np.asarray(used)

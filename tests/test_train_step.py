@@ -49,6 +49,9 @@ def run_steps(cfg, ds, mesh, n):
         tr.state, m = tr.setup.train_step(tr.state, x, y, mask)
         if first is None:
             first = {k: float(v) for k, v in m.items()}
+    # (its state stays readable; an open Trainer keeps its compile watch
+    # receiving events into every later file of the worker)
+    tr.close()
     return tr, first, {k: float(v) for k, v in m.items()}
 
 
@@ -202,6 +205,8 @@ class TestEvalAndCheckpoint:
         tr2 = Trainer(cfg2, mesh=mesh, dataset=ds, quiet=True)
         assert tr2._start_step == 31
         assert int(tr2.state.step) == 31
+        tr.close()
+        tr2.close()
 
 
 def _write_idx(path, arr, magic):
@@ -366,15 +371,20 @@ def test_step_update_is_the_seams_aggregate(case, ds, mesh):
         return jnp.concatenate(
             [g.reshape(-1) for g in jax.tree.leaves(jax.grad(loss)(params))])
 
-    stack = jax.vmap(row_grad)(x, y)  # (n, d): one row a batch
+    # (the rows and the seam each ONE compiled program: called eagerly
+    # they are a dispatch and a tiny compile a primitive)
+    stack = jax.jit(jax.vmap(row_grad))(x, y)  # (n, d): one row a batch
     if cfg.redundancy == "simulate" and cfg.approach == "cyclic":
         stack = stack[np.asarray(setup.code.batch_ids)]  # (n, hat_s, d)
-    rand_factor = (drng.random_projection_factors_in_graph(cfg.seed,
-                                                           setup.dim)
-                   if cfg.approach == "cyclic" else None)
-    want, health = aggregate_flat_grads(
-        stack, mask, cfg, setup.code, rand_factor, step=tr.state.step,
-        mesh=mesh)
+
+    @jax.jit
+    def seam(stack, mask, step):
+        rand_factor = (drng.random_projection_factors_in_graph(
+            cfg.seed, setup.dim) if cfg.approach == "cyclic" else None)
+        return aggregate_flat_grads(stack, mask, cfg, setup.code,
+                                    rand_factor, step=step, mesh=mesh)
+
+    want, health = seam(stack, mask, tr.state.step)
 
     state, metrics = setup.train_step(tr.state, x, y, mask)
     shapes = [p.shape for p in jax.tree.leaves(params)]
@@ -398,3 +408,4 @@ def test_step_update_is_the_seams_aggregate(case, ds, mesh):
         assert float(metrics["vote_agree"]) == float(health["vote_agree"])
     else:
         assert health is None and "located_errors" not in metrics
+    tr.close()

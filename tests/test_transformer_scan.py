@@ -33,6 +33,13 @@ def _toks(b=2, t=16, vocab=64):
                        jnp.int32)
 
 
+def _init(model, key, toks):
+    """The model's parameters, the init ONE compiled program (called
+    eagerly it is a dispatch and a tiny compile a primitive)."""
+    return jax.jit(lambda k: model.init({"params": k}, toks, train=True))(
+        jax.random.key(key))["params"]
+
+
 def _restack(p_unroll, p_scan, layers):
     """Unrolled block0..N-1 params stacked into the scan layout."""
     stacked = jtu.tree_map(lambda *xs: jnp.stack(xs),
@@ -50,12 +57,11 @@ def test_scan_layers_output_parity():
     toks = _toks()
     m_u = TransformerLM(**kw)
     m_s = TransformerLM(**kw, scan_layers=True)
-    p_u = m_u.init({"params": jax.random.key(0)}, toks, train=True)["params"]
-    p_s = m_s.init({"params": jax.random.key(0)}, toks, train=True)["params"]
+    p_u, p_s = _init(m_u, 0, toks), _init(m_s, 0, toks)
     assert p_s["blocks"]["qkv"]["kernel"].shape == (3, 32, 96)
     p_mix = _restack(p_u, p_s, 3)
-    o_u = m_u.apply({"params": p_u}, toks, train=True)
-    o_s = m_s.apply({"params": p_mix}, toks, train=True)
+    o_u = jax.jit(lambda p: m_u.apply({"params": p}, toks, train=True))(p_u)
+    o_s = jax.jit(lambda p: m_s.apply({"params": p}, toks, train=True))(p_mix)
     np.testing.assert_allclose(np.asarray(o_u), np.asarray(o_s),
                                rtol=0, atol=1e-5)
 
@@ -67,8 +73,7 @@ def test_scan_layers_remat_grad_parity():
     toks = _toks()
     m_u = TransformerLM(**kw, remat=True)
     m_s = TransformerLM(**kw, scan_layers=True, remat=True)
-    p_u = m_u.init({"params": jax.random.key(1)}, toks, train=True)["params"]
-    p_s = m_s.init({"params": jax.random.key(1)}, toks, train=True)["params"]
+    p_u, p_s = _init(m_u, 1, toks), _init(m_s, 1, toks)
     p_mix = _restack(p_u, p_s, 2)
 
     def loss_u(p):
@@ -77,8 +82,8 @@ def test_scan_layers_remat_grad_parity():
     def loss_s(p):
         return jnp.mean(m_s.apply({"params": p}, toks, train=True) ** 2)
 
-    g_u = jax.grad(loss_u)(p_u)
-    g_s = jax.grad(loss_s)(p_mix)
+    g_u = jax.jit(jax.grad(loss_u))(p_u)
+    g_s = jax.jit(jax.grad(loss_s))(p_mix)
     g_u_stacked = jtu.tree_map(lambda *xs: jnp.stack(xs),
                                *[g_u[f"block{i}"] for i in range(2)])
     flat_u = jnp.concatenate([x.ravel() for x in jtu.tree_leaves(g_u_stacked)])
@@ -94,7 +99,7 @@ def test_tp_train_step_scan_layers_matches_unrolled():
     from draco_tpu.config import TrainConfig
     from draco_tpu.parallel.mesh import make_folded_wtp_mesh
     from draco_tpu.parallel.tp_step import build_tp_train_setup
-    from tools.tpu_lm_perf import make_scan_loop, stage_scan_inputs
+    from tools._lowering_common import make_scan_loop, stage_scan_inputs
 
     common = dict(
         network="TransformerLM", dataset="synthetic-text",

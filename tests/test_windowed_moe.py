@@ -4,94 +4,59 @@ four and YaRN over 32 original positions in the fourth, 8 routed experts of
 which 2 are held, no shared expert) against the plain reference the
 benchmark compares it with on the chip (benchmark/reference/nets/mellum.py,
 which imports nothing of draco_tpu and masks every key by the two
-inequalities):
+inequalities). What every published-config block is held to alike — loss,
+logits, every leaf's gradient, the 8 shares adding up, the shared expert
+layer, the refusals — is tests/test_spec_lm_parity.py's; here is what is
+this block's own:
 
-* loss, logits and every leaf's gradient on seeded weights, the norms'
-  weights moved off their initial ones (the rematerialised block, as the
-  cell runs it: tests/test_lm_maj_vote.py's lanes in turn);
 * the window is really there: a longer window is another loss, and the
   sliding layers through the block-skipping kernel (interpret mode) are the
   reference's too, with the counter at 3;
 * YaRN's pins at the PUBLISHED numbers: low 18, high 35, f_i = e_i below
   18, e_i / 16 from 35 on, between them strictly between; the factor
   1.2772588722239782 on cos and sin, so the logits carry its square;
-* the shares add up: over all 8 shares of a layer of 16 experts the routed
-  parts summed, plus the attention ONCE, are the uncut reference layer —
-  nothing else is counted once, the model has no shared expert;
-* the expert layer is latent_moe's, not a copy, told ``shared=None``: no
-  ``shared`` leaf in the tree;
-* a mapping the block cannot state is refused by the key's name.
+* the held experts over every token equal the sorted buffers, run no
+  sort and no scatter, and keep their two products through a
+  rematerialised layer; no ``shared`` leaf in the tree.
 
 Tolerances: program and reference are float32 sums of the same terms in
 another order (a dispatch buffer against a dense mask, the flash-style
 softmax against the plain one): 2e-6 relative on the loss, 2e-5 absolute on
-logits of order one, 2e-4 of a leaf's largest gradient entry.
+logits of order one, 2e-4 of a leaf's largest gradient entry. Every
+compared value is one compiled program (tests/parity.py).
 """
 
 import functools
-import json
 import math
-import os
-import sys
-import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import parity
+from benchmark.reference.nets import mellum as ref
+from draco_tpu.models import latent_moe, windowed_moe
+from draco_tpu.models.windowed_moe import WindowedMoeLM, rope_frequencies
+from draco_tpu.ops.flash_attention import flash_attention
 
-from benchmark.reference.nets import mellum as ref  # noqa: E402
-from draco_tpu.config import TrainConfig  # noqa: E402
-from draco_tpu.models import build_lm, latent_moe, windowed_moe  # noqa: E402
-from draco_tpu.models.windowed_moe import (  # noqa: E402
-    WindowedMoeLM, rope_frequencies,
-)
-from draco_tpu.ops.flash_attention import flash_attention  # noqa: E402
-
-with open(os.path.join(ROOT, "benchmark", "testdata",
-                       "windowed-moe-tiny.json")) as fh:
-    TINY = json.load(fh)
-SPEC = TINY["train_config"]["model_spec"]
+SPEC = parity.tiny("windowed-moe-tiny")
 T = 80  # three windows of 24 and a rest: no multiple of the window
 
 
 def _published():
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "mellum2-12b-a2.5b-ep8.json")) as fh:
-        return json.load(fh)["train_config"]["model_spec"]
+    return parity.published("mellum2-12b-a2.5b-ep8")
 
 
 def _tokens(seed=0, batch=2, t=T):
-    rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.integers(0, SPEC["vocab_rows"], (batch, t)),
-                       jnp.int32)
-
-
-def _loss(lm, params, toks):
-    nll, stats = lm.token_nll(params, toks, jnp.roll(toks, -1, axis=1))
-    return jnp.mean(nll[:, :-1]), stats
-
-
-def _moved(params, key):
-    """Norm weights off their initial ones, so that a norm left out or
-    applied twice shows."""
-    def move(path, x):
-        if path[-1].key == "scale":
-            k = jax.random.fold_in(key, zlib.crc32(
-                jax.tree_util.keystr(path).encode()) % 2**31)
-            return x + 0.1 * jax.random.normal(k, x.shape)
-        return x
-
-    return jax.tree_util.tree_map_with_path(move, params)
+    return parity.tokens(SPEC["vocab_rows"], batch, t, seed)
 
 
 @pytest.fixture(scope="module")
 def model():
     lm = WindowedMoeLM(SPEC)
-    return lm, _moved(lm.init(jax.random.key(3)), jax.random.key(4))
+    return lm, parity.moved(lm.init(jax.random.key(3)), jax.random.key(4),
+                            ("scale",))
 
 
 def test_layer_kinds_are_read_verbatim_and_there_is_no_shared_leaf(model):
@@ -116,38 +81,6 @@ def test_layer_kinds_are_read_verbatim_and_there_is_no_shared_leaf(model):
         SPEC["hidden_size"], SPEC["num_key_value_heads"] * SPEC["head_dim"])
 
 
-def test_loss_and_logits_match_the_reference(model):
-    lm, params = model
-    toks = _tokens()
-    loss, stats = _loss(lm, params, toks)
-    assert float(loss) == pytest.approx(
-        float(ref.loss(params, toks, SPEC)), rel=2e-6)
-    got = lm.logits(params, toks)
-    for b in range(toks.shape[0]):
-        np.testing.assert_allclose(got[b], ref.logits(params, toks[b], SPEC),
-                                   atol=2e-5)
-    assert tuple(stats) == lm.stat_names == latent_moe.STAT_NAMES + (
-        "window_kernel_layers",)
-    assert float(stats["moe_dropped"]) == 0.0
-    # off the chip every sliding layer takes the plain lowering
-    assert float(stats["window_kernel_layers"]) == 0.0
-
-
-def test_every_leafs_gradient_matches_the_reference(model):
-    lm, params = model
-    toks = _tokens(1)
-    got = jax.grad(lambda p: _loss(lm, p, toks)[0])(params)
-    want = jax.grad(ref.loss)(params, toks, SPEC)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, g), w in zip(flat_got, flat_want):
-        name = jax.tree_util.keystr(path)
-        scale = float(jnp.max(jnp.abs(w)))
-        assert scale > 0.0, f"{name} takes no gradient"
-        assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * scale + 1e-9, name
-
-
 def test_the_window_and_the_full_layers_rotary_are_really_there(model):
     """A window that covers the row, and the full layer read with the
     sliding layers' rotary entry, are each another loss — in the program
@@ -159,17 +92,21 @@ def test_the_window_and_the_full_layers_rotary_are_really_there(model):
                      if name.startswith("layer") else leaves)
               for name, leaves in params.items()}
     toks = _tokens(2, batch=1)
-    base = float(_loss(lm, params, toks)[0])
+    def loss_of(net):
+        return float(jax.jit(lambda p: parity.mean_nll(net, p, toks)[0])(
+            params))
+
+    base = loss_of(lm)
     wide = dict(SPEC, sliding_window=T)
     rope = dict(SPEC["rope_parameters"])
     rope["full_attention"] = rope["sliding_attention"]
     plain = dict(SPEC, rope_parameters=rope)
     for other in (wide, plain):
-        moved = float(_loss(WindowedMoeLM(other), params, toks)[0])
+        moved = loss_of(WindowedMoeLM(other))
         # ten times what program and reference may differ by
         assert abs(moved - base) > 2e-5 * base
-        assert moved == pytest.approx(
-            float(ref.loss(params, toks, other)), rel=2e-6)
+        assert moved == pytest.approx(float(jax.jit(
+            lambda p: ref.loss(p, toks, other))(params)), rel=2e-6)
 
 
 # ---- the sliding layers through the kernel ------------------------------
@@ -191,9 +128,10 @@ def test_sliding_layers_in_the_kernel_match_the_reference(model, in_kernels):
     _, params = model
     lm = WindowedMoeLM(SPEC, attn_fn=in_kernels)
     toks = _tokens(3, batch=1, t=64)
-    (loss, stats), got = jax.value_and_grad(
-        lambda p: _loss(lm, p, toks), has_aux=True)(params)
-    want_loss, want = jax.value_and_grad(ref.loss)(params, toks, SPEC)
+    (loss, stats), got = jax.jit(jax.value_and_grad(
+        lambda p: parity.mean_nll(lm, p, toks), has_aux=True))(params)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss(p, toks, SPEC)))(params)
     assert float(loss) == pytest.approx(float(want_loss), rel=2e-6)
     assert float(stats["window_kernel_layers"]) == \
         lm.layer_types.count("sliding_attention") == 3
@@ -201,9 +139,7 @@ def test_sliding_layers_in_the_kernel_match_the_reference(model, in_kernels):
                  ("layer2", "v", "kernel"), ("layer3", "q", "kernel"),
                  ("layer0", "attn_norm", "scale"),
                  ("embed", "embedding")):
-        g, w = got, want
-        for key in path:
-            g, w = g[key], w[key]
+        g, w = parity.leaf(got, path), parity.leaf(want, path)
         assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * float(
             jnp.max(jnp.abs(w))) + 1e-9, path
 
@@ -211,7 +147,7 @@ def test_sliding_layers_in_the_kernel_match_the_reference(model, in_kernels):
 def test_the_counter_is_zero_on_the_plain_lowering(model, in_kernels):
     """The kernel path is selected, the model was handed no kernel: 0."""
     lm, params = model
-    _, stats = lm.hidden(params, _tokens(4, batch=1, t=64))
+    _, stats = jax.jit(lm.hidden)(params, _tokens(4, batch=1, t=64))
     assert float(stats["window_kernel_layers"]) == 0.0
 
 
@@ -277,50 +213,6 @@ def test_the_factor_is_on_cos_and_sin_so_the_logits_carry_its_square():
         rtol=1e-5)
 
 
-# ---- the chip's share ---------------------------------------------------
-
-def test_the_8_shares_add_up_to_the_uncut_layer():
-    """8 chips hold two of 16 experts each. Every share's routed part, plus
-    the attention ONCE, is what the reference gives for the whole layer
-    with all 16 experts held. Nothing else is counted once: there is no
-    shared expert."""
-    n_exp = 16
-    small = dict(SPEC, num_experts=n_exp, num_experts_per_tok=5)
-    whole = dict(small, experts_held=[0, n_exp])
-    lm_whole = WindowedMoeLM(whole)
-    params = _moved(lm_whole.init(jax.random.key(5)), jax.random.key(6))
-    p = params["layer1"]
-    x = jax.random.normal(jax.random.key(7), (T, SPEC["hidden_size"]))
-    same = lambda t: t  # noqa: E731
-    want = ref.layer(x, p, whole, same, "sliding_attention")
-
-    once = x + ref.attention(
-        ref.rms(x, p["attn_norm"]["scale"], SPEC["rms_norm_eps"]), p, whole,
-        same, "sliding_attention")
-    total, landed = once, 0.0
-    for first in range(0, n_exp, 2):
-        lm = WindowedMoeLM(dict(small, experts_held=[first, 2]))
-        part = dict(p, experts=jax.tree.map(lambda a: a[first:first + 2],
-                                            p["experts"]))
-        after, stats = lm._experts(once, part)  # once + routed
-        total = total + (after - once)
-        landed += float(jnp.sum(stats["load"]))
-        assert float(stats["dropped"]) == 0.0
-    np.testing.assert_allclose(total, want, atol=2e-5)
-    # every (token, choice) pair landed on exactly one share
-    assert landed == T * small["num_experts_per_tok"]
-
-
-def test_the_expert_layer_is_shared_not_copied():
-    for name in ("_route", "_buffer", "_experts", "dispatch_rows",
-                 "token_nll", "init"):
-        assert getattr(WindowedMoeLM, name) is getattr(
-            latent_moe.LatentMoeLM, name), name
-    assert windowed_moe.fold_stats is latent_moe.fold_stats
-    # uniform routing sends the chip T·k·held/experts pairs; C is 4 x that
-    assert WindowedMoeLM(_published()).dispatch_rows(8192) == 32768
-
-
 # ---- the held experts over every token ----------------------------------
 
 def _sorted(lm):
@@ -337,16 +229,16 @@ def test_which_models_run_their_held_experts_over_every_token():
     from draco_tpu.models.hybrid_moe import HybridMoeLM
 
     assert WindowedMoeLM(_published()).moe.dense
+    # uniform routing sends the chip T·k·held/experts pairs; C is 4 x that
+    assert WindowedMoeLM(_published()).dispatch_rows(8192) == 32768
     assert WindowedMoeLM(SPEC).moe.dense  # top-3 of 8
     assert not WindowedMoeLM(dict(_published(), num_experts=128)).moe.dense
     assert not WindowedMoeLM(
         dict(SPEC, experts_held=[0, SPEC["num_experts"]])).moe.dense
     for name, cls in (("kanana-2-30b-a3b-ep16", latent_moe.LatentMoeLM),
                       ("qwen3-next-80b-a3b-ep32", HybridMoeLM)):
-        with open(os.path.join(ROOT, "benchmark", "configs",
-                               name + ".json")) as fh:
-            spec = json.load(fh)["train_config"]["model_spec"]
-        assert not cls(dict(spec, layers=1)).moe.dense, name
+        assert not cls(dict(parity.published(name), layers=1)).moe.dense, \
+            name
 
 
 @pytest.mark.parametrize("first", [0, 3, 6])
@@ -367,11 +259,11 @@ def test_every_token_equals_the_sorted_buffers(model, first):
         return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), (
             y, stats)
 
-    (_, (y_d, s_d)), g_d = jax.value_and_grad(
-        functools.partial(run, dense), argnums=(0, 1), has_aux=True)(x, p)
-    (_, (y_s, s_s)), g_s = jax.value_and_grad(
+    (_, (y_d, s_d)), g_d = jax.jit(jax.value_and_grad(
+        functools.partial(run, dense), argnums=(0, 1), has_aux=True))(x, p)
+    (_, (y_s, s_s)), g_s = jax.jit(jax.value_and_grad(
         functools.partial(run, _sorted(dense)), argnums=(0, 1),
-        has_aux=True)(x, p)
+        has_aux=True))(x, p)
     np.testing.assert_allclose(y_d, y_s, atol=1e-5 * float(
         jnp.max(jnp.abs(y_s))))
     np.testing.assert_array_equal(s_d["load"], s_s["load"])
@@ -407,83 +299,9 @@ def test_a_rematerialised_layer_keeps_the_two_products(model, capsys):
     lm, params = model
     net = WindowedMoeLM(SPEC, remat=True)
     toks = _tokens(3)
-    print_saved_residuals(lambda p: _loss(net, p, toks)[0], params)
+    print_saved_residuals(lambda p: parity.mean_nll(net, p, toks)[0], params)
     kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
             if "_every_token" in line]  # the named values, nothing else
     rows = toks.shape[0] * toks.shape[1]
     held, width = SPEC["experts_held"][1], SPEC["moe_intermediate_size"]
     assert kept == [f"f32[{rows},{held},{width}]"] * (2 * SPEC["layers"])
-
-
-# ---- the mapping and the configuration ----------------------------------
-
-def _rope(kind, **kw):
-    def edit(s):
-        rope = {k: dict(v) for k, v in s["rope_parameters"].items()}
-        rope[kind].update(kw)
-        s["rope_parameters"] = rope
-    return edit
-
-
-@pytest.mark.parametrize("edit,names", [
-    (lambda s: s.pop("sliding_window"), "sliding_window"),
-    (lambda s: s.update(experts_held=[7, 2]), "experts_held"),
-    (lambda s: s.update(attention_bias=True), "attention_bias"),
-    (lambda s: s.update(use_sliding_window=False), "use_sliding_window"),
-    (lambda s: s.update(tie_word_embeddings=True), "tie_word_embeddings"),
-    (lambda s: s.update(norm_topk_prob=False), "norm_topk_prob"),
-    (lambda s: s.update(hidden_act="gelu"), "hidden_act"),
-    (lambda s: s.update(mlp_layer_types=["sparse", "dense", "sparse",
-                                         "sparse"]),
-     r"mlp_layer_types'\]\[1\]"),
-    (lambda s: s.update(layer_types=["sliding_attention", "chunked_attention",
-                                     "sliding_attention", "full_attention"]),
-     r"layer_types'\]\[1\]"),
-    (lambda s: s.update(layers=5), "layers"),
-    (lambda s: s.update(num_key_value_heads=3), "num_key_value_heads"),
-    (lambda s: s.update(sliding_window=0), "sliding_window"),
-    (_rope("full_attention", rope_type="llama3"), "rope_type"),
-    (_rope("full_attention", mscale=0.7), "mscale"),
-    (_rope("sliding_attention", factor=2.0), "factor"),
-    (lambda s: s["rope_parameters"].pop("full_attention"),
-     "full_attention"),
-])
-def test_a_mapping_the_block_cannot_state_is_refused_by_name(edit, names):
-    spec = json.loads(json.dumps(SPEC))
-    edit(spec)
-    with pytest.raises(ValueError, match=names):
-        WindowedMoeLM(spec)
-
-
-def _cfg(**kw):
-    base = dict(network="WindowedMoeLM", dataset="synthetic-text",
-                model_spec=SPEC, vocab=SPEC["vocab_rows"], seq_len=T,
-                batch_size=2, num_workers=3, approach="maj_vote",
-                group_size=3, worker_fail=1, train_dir="")
-    base.update(kw)
-    return TrainConfig(**base)
-
-
-def test_the_network_is_built_on_the_normal_path():
-    cfg = _cfg().validate()
-    lm = build_lm(cfg)
-    assert isinstance(lm, WindowedMoeLM) and lm.remat == cfg.remat
-    assert lm.stat_names[-1] == "window_kernel_layers"
-    # the route's bare kernel reaches the model, and takes a window
-    lm = build_lm(cfg, kernel_fn=flash_attention)
-    assert lm.attn_fn is flash_attention
-
-
-@pytest.mark.parametrize("kw,names", [
-    (dict(tensor_shards=2), "tensor_shards"),
-    (dict(seq_shards=2), "seq_shards"),
-    (dict(vocab=SPEC["vocab_rows"] + 1), "vocab_rows"),
-    (dict(model_spec=None), "model_spec"),
-    # another family's mapping under this network's name, and back
-    (dict(model_spec={"hidden_size": 64}), "model_spec lacks"),
-    (dict(network="HybridMoeLM"), "full_attention_interval"),
-    (dict(network="LeNet"), "WindowedMoeLM"),
-])
-def test_what_stays_unsupported_is_refused_by_name(kw, names):
-    with pytest.raises(ValueError, match=names):
-        _cfg(**kw).validate()

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from draco_tpu import rng as drng, runtime
 from draco_tpu.coding import approx as approx_mod
 from draco_tpu.coding import cyclic as cyclic_mod
@@ -227,15 +228,15 @@ def test_regularized_locator_solves_n32_s3_blocker(dtype):
     # subset-conditioning dependent, so the blocker is a worst-case over
     # trials (exactly how the study measures it)
     hmax0 = hmax1 = 0.0
+    blocked = parity.jitted(cyclic_mod.decode, code, with_health=True,
+                            rel_tol=1e9, lam=0.0)
+    regularized = parity.jitted(cyclic_mod.decode, code, with_health=True,
+                                rel_tol=tol, lam=lam)
     for seed in range(100, 108):
         enc_re, enc_im, _, f = _encode_quantized(code, dtype, 0,
                                                  seed=seed)
-        _, _, h0 = cyclic_mod.decode(code, enc_re, enc_im, f,
-                                     with_health=True, rel_tol=1e9,
-                                     lam=0.0)
-        _, _, h1 = cyclic_mod.decode(code, enc_re, enc_im, f,
-                                     with_health=True, rel_tol=tol,
-                                     lam=lam)
+        _, _, h0 = blocked(enc_re, enc_im, f)
+        _, _, h1 = regularized(enc_re, enc_im, f)
         hmax0 = max(hmax0, float(jnp.max(h0["dev_rel"])))
         hmax1 = max(hmax1, float(jnp.max(h1["dev_rel"])))
         # regularized: nothing flagged on any clean trial
@@ -246,9 +247,7 @@ def test_regularized_locator_solves_n32_s3_blocker(dtype):
 
     # s live adversaries: located exactly, flagged above the threshold
     enc_re, enc_im, adv, f = _encode_quantized(code, dtype, 3)
-    _, honest, h2 = cyclic_mod.decode(code, enc_re, enc_im, f,
-                                      with_health=True, rel_tol=tol,
-                                      lam=lam)
+    _, honest, h2 = regularized(enc_re, enc_im, f)
     honest = np.asarray(honest)
     assert not np.any(honest & adv)  # no adversary in the honest subset
     flagged = np.asarray(h2["flagged"])

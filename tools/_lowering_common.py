@@ -79,3 +79,77 @@ def lint_row(program, extra_row=None, only=None):
     if extra_row:
         row.update(extra_row)
     return row
+
+
+def stage_scan_inputs(cfg, steps):
+    """Pre-staged (xs tokens, adversary masks) for `steps` scanned steps —
+    the one source of truth for the LM lowering audits' input protocol."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from draco_tpu import rng as drng
+    from draco_tpu.parallel.sp_step import synthetic_text
+
+    adv = drng.adversary_schedule(cfg.seed, steps + 1, cfg.num_workers,
+                                  cfg.num_adversaries)
+    xs = jnp.asarray(np.stack([
+        synthetic_text(cfg.seed, s, cfg.num_workers, cfg.batch_size,
+                       cfg.seq_len, cfg.vocab)
+        for s in range(1, steps + 1)
+    ]))
+    ms = jnp.asarray(np.stack([np.asarray(adv[s]) for s in range(1, steps + 1)]))
+    return xs, ms
+
+
+def make_scan_loop(setup):
+    """The scanned multi-step train loop the LM lowering audits export and
+    compile."""
+    import jax
+
+    def loop(state, xs, ms):
+        def body(st, batch):
+            toks, mask = batch
+            st, metrics = setup.train_step(st, toks, mask)
+            return st, metrics["loss"]
+        return jax.lax.scan(body, state, (xs, ms))
+
+    return loop
+
+
+def build_lm_variants(*, batch_size, num_workers, seq_len, vocab, model_dim,
+                      model_heads, model_layers, remat, max_steps,
+                      scan_layers=False):
+    """The LM variant configs the lowering audits lower (one source of
+    truth for tools/tpu_lm_lowering_check.py and
+    tools/tpu_lm_scan_lowering_check.py)."""
+    common = dict(
+        network="TransformerLM", dataset="synthetic-text",
+        batch_size=batch_size, lr=0.01, momentum=0.9,
+        num_workers=num_workers, worker_fail=1, err_mode="rev_grad",
+        seq_len=seq_len, vocab=vocab, model_dim=model_dim,
+        model_heads=model_heads, model_layers=model_layers,
+        compute_dtype="bfloat16", remat=remat, scan_layers=scan_layers,
+        max_steps=max_steps, eval_freq=0,
+        train_dir="", log_every=10**9,
+    )
+    return {
+        # redundancy must be EXPLICIT here: the LM paths honour it now
+        # (parallel/tp_step.py simulate lanes); the shared variant would
+        # otherwise silently inherit the config default "simulate"
+        "lm_cyclic_s1_shared_bf16": dict(common, approach="cyclic",
+                                         redundancy="shared"),
+        # reference-parity r=2s+1 redundant compute at LM scale
+        # (cyclic_worker.py:122-146) — the r-cost VERDICT r2 item 6 asks for
+        "lm_cyclic_s1_simulate_bf16": dict(common, approach="cyclic",
+                                           redundancy="simulate"),
+        # the same coded step with the Pallas flash kernel in place of
+        # dense attention — the long-context hot-op on the training path
+        "lm_cyclic_s1_shared_bf16_flash": dict(common, approach="cyclic",
+                                               redundancy="shared",
+                                               attn_impl="flash"),
+        "lm_geomedian_bf16": dict(common, approach="baseline",
+                                  mode="geometric_median"),
+        "lm_krum_bf16": dict(common, approach="baseline", mode="krum"),
+        "lm_mean_no_attack_bf16": dict(common, approach="baseline",
+                                       mode="normal", worker_fail=0),
+    }

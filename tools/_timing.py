@@ -8,8 +8,10 @@ predates measuring on one and uses the fetch, and the round trip it
 subtracts is microseconds there (it was tens of milliseconds on the backend
 these helpers were first written against). Replacing the protocol with a
 plain ``block_until_ready`` around the timed region belongs to the benchmark
-PR (ROADMAP S0), which owns every timed number; until then bench.py and ten
-tools share this one implementation so their numbers stay comparable.
+PR (ROADMAP S0), which owns every timed number; until then the study tools
+(tpu_attn_tune, tpu_kernel_check, tree_study, decode_study, time_to_acc,
+lm_time_to_loss) share this one implementation so their numbers stay
+comparable. It lives beside them: no module of the package imports it.
 
 Protocol:
 
@@ -59,7 +61,7 @@ def timeit_chained(step, carry, consts=(), reps: int = 20,
     jitted fori_loop, synchronised by a device→host fetch minus RTT.
 
     The protocol for sub-ms ops (per-call Python dispatch stays off the
-    timed path); shared by tools/tpu_kernel_check.py and tools/tpu_perf.py.
+    timed path); shared by tools/tpu_kernel_check.py and tools/decode_study.py.
     Requirements on ``step`` (violations produce fantasy numbers):
 
       * big operands enter via ``consts`` (jit arguments) — a closed-over
@@ -96,28 +98,6 @@ def timeit_chained(step, carry, consts=(), reps: int = 20,
         fetch_scalar(out)
         return max(time.perf_counter() - t0 - rtt, 0.0) / (reps * scale)
     return max(total, 0.0) / reps
-
-
-def time_scanned_steps(compiled_loop, init_state, operands, *, steps: int,
-                       warmup: int = 1, reps: int = 2):
-    """Per-step seconds of a compiled ``lax.scan``-of-train-steps loop under
-    the fetch-sync protocol (items 1-4 above), plus the final per-step loss
-    array. ``compiled_loop(state, *operands) -> (state, losses)`` must fold
-    ``steps`` steps into one device program; warmup executions settle
-    compile/donation, timed reps chain through the state. Shared by bench.py
-    and tools/tpu_lm_perf.py so the protocol cannot drift between them."""
-    rtt = measure_rtt()
-    st = init_state
-    losses = None
-    for _ in range(max(warmup, 1)):
-        st, losses = compiled_loop(st, *operands)
-    fetch_scalar(losses)
-    t0 = time.perf_counter()
-    for _ in range(max(reps, 1)):
-        st, losses = compiled_loop(st, *operands)
-    fetch_scalar(losses)
-    dt = max(time.perf_counter() - t0 - rtt, 0.0) / (max(reps, 1) * steps)
-    return dt, losses
 
 
 def timeit_device(fn, *args, reps: int = 30, rtt: float | None = None) -> float:

@@ -173,10 +173,17 @@ def _base_cfg_kw():
 
 def _loops():
     """loop name -> (make_cfg(**kw), run(cfg, steps=None) -> params_vec)."""
+    import functools
+
     import jax
     import numpy as np
 
     from draco_tpu.config import TrainConfig
+    from draco_tpu.data.datasets import load_dataset
+
+    # a cell is up to three fresh Trainers and a loop a dozen: the (seeded,
+    # read-only) dataset is made once a loop, not once a Trainer
+    dataset = functools.lru_cache(maxsize=None)(load_dataset)
 
     def pv(state):
         return np.concatenate([
@@ -194,7 +201,8 @@ def _loops():
         # the restored cursor
         from draco_tpu.training.trainer import Trainer
 
-        t = Trainer(cfg, quiet=True)
+        t = Trainer(cfg, quiet=True,
+                    dataset=dataset(cfg.dataset, cfg.data_dir))
         try:
             t.run(max_steps=None if steps is None
                   else t._start_step - 1 + steps)

@@ -16,18 +16,14 @@ exits nonzero NAMING THE FIRST FAILURE:
                       the committed shadow-wire matrix, plus (ISSUE 15)
                       the real-wire rows' P/R + physical-bytes pins and
                       the n=32 s=3 regularized-locator certificate
-  decode_kernel_bench --check: ratio arithmetic + gated-rung
-                      kernel-not-slower pins of the committed fused-decode
-                      microbench (ISSUE 12)
   segment_study       --check: per-segment bytes sums + bounds algebra and
                       the overlap/ms-per-step-win acceptance pins of the
                       committed streaming-wire evidence (ISSUE 16)
   tree_study          --check: plan algebra + per-level byte sums +
                       detection-parity pins + crossover honesty of the
                       committed tree-aggregation evidence (ISSUE 17)
-  decode_study        --check: no stale error rows, numeric granularity
-                      cells, tree crossover columns self-consistent
-                      (ISSUE 17)
+  decode_study        --check: no stale error rows, tree crossover
+                      columns self-consistent (ISSUE 17)
   program_lint        committed all_ok roll-up
   sharding audit      every non-control lint row carries ok verdicts for
                       sharding_contract / collective_axes /
@@ -113,15 +109,6 @@ def _check_segment_study(root):
     artifact = os.path.join(root, "baselines_out", "segment_study.json")
     rc = segment_study.main(["--check", "--artifact", artifact])
     return None if rc == 0 else f"segment_study --check exited {rc}"
-
-
-def _check_decode_bench(root):
-    from tools import decode_kernel_bench
-
-    artifact = os.path.join(root, "baselines_out",
-                            "decode_kernel_bench.json")
-    rc = decode_kernel_bench.main(["--check", "--artifact", artifact])
-    return None if rc == 0 else f"decode_kernel_bench --check exited {rc}"
 
 
 def _check_trace_report(root):
@@ -396,7 +383,6 @@ CHECKS = (
     ("perf_watch", _check_perf_watch),
     ("device_profile --check", _check_device_profile),
     ("wire_study --check", _check_wire_study),
-    ("decode_kernel_bench --check", _check_decode_bench),
     ("segment_study --check", _check_segment_study),
     ("tree_study --check", _check_tree_study),
     ("decode_study --check", _check_decode_study),
@@ -420,7 +406,7 @@ CHECKS = (
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--root", type=str, default=".",
-                    help="repo root holding baselines_out/ + BENCH_r*.json")
+                    help="repo root holding baselines_out/")
     ap.add_argument("--verbose", action="store_true",
                     help="show the sub-verifiers' own output")
     args = ap.parse_args(argv)

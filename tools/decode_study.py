@@ -1,35 +1,36 @@
 #!/usr/bin/env python
-"""Decode-granularity and s-scaling study (VERDICT r2 item 7).
+"""Decode s-scaling study (VERDICT r2 item 7).
 
-Two questions the round-2 evidence left at two data points:
-
-1. How do the isolated encode / decode costs scale with the Byzantine
-   budget s ∈ {1, 2, 3} and the worker count n ∈ {8, 16, 32} at the
-   flagship gradient dimension — against the Weiszfeld geometric-median
-   cost at the same (n, d)? (The "decode stays flat while Weiszfeld
-   scales" claim.)
-2. What does reference-parity per-layer decode granularity
-   (cyclic_master.py:125-129, one locator per parameter tensor) cost vs
-   the global one-locator decode, as a full train step?
+How do the isolated encode / decode costs scale with the Byzantine budget
+s ∈ {1, 2, 3} and the worker count n ∈ {8, 16, 32} at the flagship gradient
+dimension — against the Weiszfeld geometric-median cost at the same (n, d)?
+(The "decode stays flat while Weiszfeld scales" claim.)
 
 Writes after every point; a run cut short keeps completed points.
+
+The study's second question — per-layer decode granularity against the
+global one-locator decode, as a full train step — was timed by the
+pre-ledger benchmark's scanned-steps harness, which PR 44 deleted with that
+benchmark. The committed artifact keeps the cells it measured
+(``granularity``: 98.8 ms global, 101.7 ms layer, ResNet-18 b32 on a v5e);
+this tool no longer writes them. A full step's time is a benchmark cell's
+to measure (benchmark/run.py).
 
 ISSUE 17 additions:
 
   * ``--merge PATCH`` folds a partial re-run (e.g. the regenerated n=32
     rows measured after the PR 15 regularized locator landed) into the
     committed artifact: every (n, s) scaling row the patch carries
-    WITHOUT an error replaces the main artifact's row, numeric
-    granularity cells replace errored ones, and the merge provenance is
-    recorded in the artifact ("merged_from");
+    WITHOUT an error replaces the main artifact's row, and the merge
+    provenance is recorded in the artifact ("merged_from");
   * ``--tree-fanout G`` measures, next to every flat (n, s) scaling row,
     the tree topology's per-node critical path at the same d (leaf
     decode at the (G, s_g) group code + per-level combine,
     coding/topology.py) and records the tree-vs-flat crossover column —
     the light companion of tools/tree_study.py;
   * ``--check`` re-verifies a committed artifact jax-free: NO scaling
-    row may carry an error, granularity cells must be numeric, and every
-    present tree column must agree with its own timings — wired into
+    row may carry an error, and every present tree column must agree
+    with its own timings — wired into
     tools/check_artifacts.py.
 
 Usage: python tools/decode_study.py [--out baselines_out/decode_study.json]
@@ -51,12 +52,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def geomedian_ms(n, d, iters=80, reps=10):
     """Isolated Weiszfeld cost at (n, d) under the chained-feedback timing
-    protocol (utils/timing.py) — the PS-phase cost cyclic decode replaces."""
+    protocol (tools/_timing.py) — the PS-phase cost cyclic decode replaces."""
     import jax.numpy as jnp
     import numpy as np
 
     from draco_tpu import aggregation
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     r = np.random.RandomState(0)
     g = jnp.asarray(r.randn(n, d).astype(np.float32))
@@ -79,7 +80,7 @@ def tree_phase_times(n, d, s, fanout, reps=10):
 
     from draco_tpu.coding import cyclic as cyc
     from draco_tpu.coding import topology as topo
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     if n % fanout != 0 or n // fanout < 2:
         return None
@@ -112,8 +113,8 @@ def tree_phase_times(n, d, s, fanout, reps=10):
 def merge_artifact(out_path: str, patch_path: str) -> int:
     """Fold a partial re-run into the committed artifact: error-free
     (n, s) scaling rows from the patch replace the main artifact's rows
-    (stale errors included), numeric granularity cells replace errored
-    ones. Jax-free; records provenance under ``merged_from``."""
+    (stale errors included). Jax-free; records provenance under
+    ``merged_from``."""
     try:
         with open(out_path) as fh:
             main_doc = json.load(fh)
@@ -136,12 +137,6 @@ def merge_artifact(out_path: str, patch_path: str) -> int:
     rows.extend(by_key.values())  # patch rows the main artifact lacked
     replaced.extend(by_key)
     main_doc["scaling"] = sorted(rows, key=lambda r: (r["n"], r["s"]))
-    for gran, val in (patch.get("granularity") or {}).items():
-        if isinstance(val, (int, float)):
-            main_doc.setdefault("granularity", {})[gran] = val
-    for meta in ("granularity_network", "granularity_batch_size"):
-        if meta in patch:
-            main_doc[meta] = patch[meta]
     main_doc["merged_from"] = {
         "patch": os.path.basename(patch_path),
         "replaced": sorted(f"n{n}s{s}" for n, s in replaced),
@@ -156,8 +151,8 @@ def merge_artifact(out_path: str, patch_path: str) -> int:
 def check_artifact(path: str) -> int:
     """Re-verify a committed decode_study.json jax-free: no error rows
     anywhere (ISSUE 17 satellite — the stale n=32 failure rows must
-    stay purged), numeric granularity cells, and any tree crossover
-    columns consistent with their own timings."""
+    stay purged), and any tree crossover columns consistent with their
+    own timings."""
     try:
         with open(path) as fh:
             data = json.load(fh)
@@ -186,13 +181,41 @@ def check_artifact(path: str) -> int:
                 print(f"decode_study --check: {cell}: tree_win disagrees "
                       f"with its own timings")
                 return 1
-    for gran, val in (data.get("granularity") or {}).items():
-        if not isinstance(val, (int, float)):
-            print(f"decode_study --check: granularity[{gran}] is not a "
-                  f"number: {str(val)[:80]}")
-            return 1
     print(f"decode_study --check: {len(rows)} scaling rows clean ({path})")
     return 0
+
+
+def phase_times(n, d, s, reps=20):
+    """Isolated encode / decode costs at gradient dimension d.
+
+    Timing and feedback discipline per tools/_timing.timeit_chained
+    (non-linear full-output feedback, operands via consts)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from draco_tpu.coding import cyclic as cyc
+    from tools._timing import timeit_chained
+
+    code = cyc.build_cyclic_code(n, s)
+    r = np.random.RandomState(0)
+    g = jnp.asarray(r.randn(n, d).astype(np.float32))
+    rf = jnp.asarray(r.randn(d).astype(np.float32))
+
+    def enc_step(gc):
+        e_re, e_im = cyc.encode_shared(code, gc)
+        return gc.at[0, 0].add(1e-30 * (jnp.sum(e_re**2) + jnp.sum(e_im**2)))
+
+    enc_ms = timeit_chained(enc_step, g, reps=reps) * 1e3
+
+    e_re, e_im = cyc.encode_shared(code, g)
+
+    def dec_step(carry, rf):
+        er, ei = carry
+        dec, honest = cyc.decode(code, er, ei, rf)
+        return (er.at[0, 0].add(1e-30 * jnp.sum(dec**2)), ei)
+
+    dec_ms = timeit_chained(dec_step, (e_re, e_im), (rf,), reps=reps) * 1e3
+    return enc_ms, dec_ms
 
 
 def main(argv=None) -> int:
@@ -211,13 +234,7 @@ def main(argv=None) -> int:
                     help="gradient dimension (0 = flagship ResNet-18 dim)")
     ap.add_argument("--ns", type=str, default="8,16,32")
     ap.add_argument("--ss", type=str, default="1,2,3")
-    ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--skip-granularity", action="store_true")
-    ap.add_argument("--gran-network", type=str, default="ResNet18",
-                    help="model for the granularity full-step rows (smoke: "
-                         "LeNet)")
-    ap.add_argument("--gran-batch-size", type=int, default=32)
     ap.add_argument("--cpu-mesh", type=int, default=0)
     args = ap.parse_args(argv)
     if args.merge:
@@ -230,10 +247,6 @@ def main(argv=None) -> int:
     maybe_force_cpu_mesh(args)
 
     import jax
-
-    # resolves in both contexts: as tools.decode_study (tests) and as a
-    # script (the sys.path.insert above puts the repo root first either way)
-    from tools.tpu_perf import phase_times
 
     dev = jax.devices()[0]
     d = args.d
@@ -248,11 +261,6 @@ def main(argv=None) -> int:
         "grad_dim": d,
         "geomedian_iters": 80,
         "scaling": [],
-        # provenance for the full-step rows: a LeNet smoke must never be
-        # mistakable for the flagship ResNet18/b32 evidence
-        "granularity_network": args.gran_network,
-        "granularity_batch_size": args.gran_batch_size,
-        "granularity": {},
     }
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
 
@@ -304,34 +312,6 @@ def main(argv=None) -> int:
             print(f"[decode_study] n={n} s={s}: enc {row['encode_ms']} ms, "
                   f"dec {row['decode_ms']} ms, geomed {row['geomedian_ms_same_n']} ms",
                   file=sys.stderr, flush=True)
-            flush()
-
-    # ---- decode granularity: global vs per-layer, full train step ---------
-    if not args.skip_granularity:
-        import bench
-        from draco_tpu.data.datasets import load_dataset
-        from draco_tpu.runtime import make_mesh
-
-        ds = load_dataset("Cifar10", data_dir="./data")
-        mesh = make_mesh(8)
-        for gran in ("global", "layer"):
-            kw = dict(
-                network=args.gran_network, dataset="Cifar10",
-                batch_size=args.gran_batch_size,
-                lr=0.01, momentum=0.9, num_workers=8, worker_fail=1,
-                err_mode="rev_grad", approach="cyclic",
-                redundancy="simulate", decode_granularity=gran,
-                max_steps=args.steps + 1, eval_freq=0, train_dir="",
-                log_every=10**9,
-            )
-            print(f"[decode_study] granularity={gran} full step ...",
-                  file=sys.stderr, flush=True)
-            try:
-                dt, _loss, _f, _c = bench.run(kw, ds, mesh, args.steps,
-                                              warmup=1, reps=2)
-                report["granularity"][gran] = round(dt * 1e3, 3)
-            except Exception as e:
-                report["granularity"][gran] = f"{type(e).__name__}: {e}"[:300]
             flush()
 
     print(json.dumps(report))

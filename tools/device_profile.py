@@ -134,8 +134,7 @@ CELLS = {
 # move ±3% with XLA:CPU fusion-attribution noise (eager k1 even inverts —
 # the true-mean matvec cannot fuse into the grads producer the way the
 # xla path's axis-0 reduction does), so those pallas cells are committed
-# as same-shape evidence WITHOUT the claim (PERF_HISTORY.md §14; the robust CPU
-# evidence for the decode itself is decode_kernel_bench.json).
+# as same-shape evidence WITHOUT the claim (PERF_HISTORY.md §14).
 PALLAS_CLAIMS = {
     "lm_sp_approx_pallas_k4": "lm_sp_approx_k4",
     "lm_tp_approx_pallas_k4": "lm_tp_approx_k4",

@@ -12,7 +12,7 @@ token set (disjoint seed namespace), until eval loss <= --target or
 separate evaluator process (src/distributed_evaluator.py:92-110); here the
 oracle is the same held-out principle at transformer scale.
 
-Wall-clock: train blocks are ONE jitted lax.scan each (utils/timing.py
+Wall-clock: train blocks are ONE jitted lax.scan each (tools/_timing.py
 fetch-sync protocol), synced by a device->host loss fetch, RTT subtracted;
 eval time is excluded from the train clock. Mean-under-attack is expected
 NOT to reach the target — its curve records the damage an undefended
@@ -45,7 +45,7 @@ def run_variant(cfg_kwargs, mesh, args, rtt):
     from draco_tpu.config import TrainConfig
     from draco_tpu.parallel.sp_step import synthetic_text
     from draco_tpu.parallel.tp_step import build_tp_train_setup
-    from draco_tpu.utils.timing import fetch_scalar
+    from tools._timing import fetch_scalar
 
     cfg = TrainConfig(**cfg_kwargs)
     setup = build_tp_train_setup(cfg, mesh)
@@ -94,7 +94,7 @@ def run_variant(cfg_kwargs, mesh, args, rtt):
         jax.block_until_ready((xs, ms))  # stage off the timed path
         t0 = time.perf_counter()
         state, losses = compiled(state, xs, ms)
-        fetch_scalar(losses)  # completion barrier (utils/timing.py)
+        fetch_scalar(losses)  # completion barrier (tools/_timing.py)
         wall += max(time.perf_counter() - t0 - rtt, 0.0)
         hi = step + block - 1
         eloss = float(setup.eval_step(state.params, eval_toks))
@@ -137,7 +137,7 @@ def main(argv=None) -> int:
     import jax
 
     from draco_tpu.parallel.mesh import make_folded_wtp_mesh
-    from draco_tpu.utils.timing import measure_rtt
+    from tools._timing import measure_rtt
 
     mesh = make_folded_wtp_mesh(args.num_workers)
     dev = jax.devices()[0]

@@ -1,13 +1,9 @@
 #!/usr/bin/env python
-"""Perf-regression watch: fold the committed round artifacts into one flat
-metric set and diff it against the committed baseline snapshot.
+"""Artifact-regression watch: fold the committed study artifacts into one
+flat metric set and diff it against the committed baseline snapshot.
 
-VERDICT r4's core complaint is that evidence does not accumulate across
-rounds: every BENCH_r*.json is a point measurement and nothing notices when
-a round's ms/step, module bytes, peak-memory estimate, or compile time
-quietly drifts from the last committed state. This tool is the accumulation
-point — jax-free (pure artifact folding, runs on a laptop against scp'd
-files), so it can gate a round without touching a backend:
+The accumulation point for what a CPU can COUNT and what is EXACT — jax-free
+(pure artifact folding), so it gates without touching a backend:
 
   python tools/perf_watch.py --snapshot        # (re)write the baseline
                                                #  baselines_out/perf_watch.json
@@ -16,18 +12,12 @@ files), so it can gate a round without touching a backend:
                                                #   out-of-tolerance regression
   python tools/perf_watch.py --json report.json
 
-Folded sources (all optional — a missing artifact folds nothing):
+It gates NO time: a millisecond, a compile time, a phase's share of a CPU
+trace, or a boolean derived from one (a "win", a crossover) follows the
+host's load, and every number a PR is judged by comes from the chip through
+benchmark/run.py and the driver's ledger (PERF.md §2). What is folded (all
+optional — a missing artifact folds nothing):
 
-  BENCH_r*.json                 driver bench records (the tail's last JSON
-                                line per metric, highest round wins):
-                                ms/step, vs_baseline ratio, flops/step, and
-                                the compile_ms field bench.py now records
-  MULTICHIP_r*.json             the multichip dry-run verdict (ok flag +
-                                device count)
-  baselines_out/host_loop_overhead*.json
-                                the K-sweep: eager & per-K steady-state
-                                ms/step, plus the compile-vs-steady split
-                                (compile_ms / timed-run builds per K)
   baselines_out/program_lint.json
                                 per-program module bytes (constant_bloat
                                 rule), the memory/cost ledger columns
@@ -52,15 +42,19 @@ Folded sources (all optional — a missing artifact folds nothing):
                                 directions (kind "pinned" — a budget-
                                 infeasible cell silently becoming
                                 feasible is a semantic change, not an
-                                improvement), wall ms/step at the time
-                                tolerance
+                                improvement)
   baselines_out/autopilot_study.json
                                 the adaptive-autopilot-vs-fixed scenario
                                 study (tools/autopilot_study.py, ISSUE
-                                14): beats-fixed / remediation-
-                                attribution / quarantine-clean
-                                certificates at tolerance 0, cell
-                                feasibility pinned both directions
+                                14): beats-fixed (a count of worker-
+                                steps) / remediation-attribution /
+                                quarantine-clean certificates at
+                                tolerance 0, cell feasibility pinned both
+                                directions
+  baselines_out/fleet_slo.json  the fleet observatory's SLO verdicts
+                                (tools/fleet_study.py, ISSUE 19) at
+                                tolerance 0, the error-budget burn and
+                                the detection P/R pinned
   baselines_out/wire_study.json
                                 the shadow-quantized wire matrix
                                 (tools/wire_study.py, ISSUE 10): shadow
@@ -71,51 +65,32 @@ Folded sources (all optional — a missing artifact folds nothing):
                                 0-tolerance ok flags, logical wire bytes
                                 at the bytes tolerance
   baselines_out/segment_study.json
-                                the streaming segmented wire's pipeline
-                                evidence (tools/segment_study.py, ISSUE
-                                16): the winning S>1 cell's positive
-                                overlap fraction and ms/step win as
-                                0-tolerance ok flags, the measured
-                                fractions at the ratio tolerance, segment
-                                counts + per-segment physical bytes
-                                pinned tolerance-0 in both directions
+                                the streaming segmented wire
+                                (tools/segment_study.py, ISSUE 16):
+                                segment counts + per-segment physical
+                                bytes pinned tolerance-0 in both
+                                directions
   baselines_out/tree_study.json
-                                the hierarchical tree-aggregation
-                                evidence (tools/tree_study.py, ISSUE 17):
-                                the per-cell win / bytes_ok / detection-
-                                parity bools at tolerance 0, the
-                                crossover n pinned in both directions,
-                                per-LEVEL ingest bytes pinned tolerance-0
-                                both ways (the leaf level must keep
-                                summing exactly to the flat per-step
-                                bytes), decode/critical-path ms at the
-                                time tolerance
-  baselines_out/decode_kernel_bench.json
-                                the fused-decode microbench
-                                (tools/decode_kernel_bench.py, ISSUE 12):
-                                per-rung xla/pallas decode ms and their
-                                ratio at the time tolerance, plus the
-                                gated rungs' kernel_not_slower flag at
-                                tolerance 0 — the fused path regressing
-                                slower than the XLA path at a committed
-                                rung fails the round
+                                the hierarchical tree aggregation
+                                (tools/tree_study.py, ISSUE 17): the
+                                per-cell bytes_ok / detection-parity
+                                bools at tolerance 0, per-LEVEL ingest
+                                bytes pinned tolerance-0 both ways (the
+                                leaf level must keep summing exactly to
+                                the flat per-step bytes)
   baselines_out/device_profile.json
-                                the device-time attribution ledger
+                                the device-trace attribution ledger
                                 (tools/device_profile.py, ISSUE 9):
-                                per-cell draco phase shares at the time
-                                tolerance (decode-share regressions gate),
                                 explicit-collective instruction/byte
                                 counts pinned at tolerance 0 both ways,
                                 manifest cross-check + seeded mismatch
                                 control as 0-tolerance ok flags
 
-Tolerances are per metric KIND (relative change vs baseline): time metrics
-default 10% (ms/step, a 20% regression trips loudly), bytes 10%, flops 2%
-(analytic flops should not drift at all without an algorithm change),
-ratios (higher-better) 10%, compile time 50% (host-load noisy), booleans 0
-(a multichip ok that goes false is always a regression). Improvements and
-new metrics are reported, never fatal; metrics that disappear are reported
-as missing (fatal only under --strict-missing, so artifact sets can evolve).
+Tolerances are per metric KIND (relative change vs baseline): bytes 10%,
+flops 2% (analytic flops should not drift at all without an algorithm
+change), booleans and pinned values 0. Improvements and new metrics are
+reported, never fatal; metrics that disappear are reported as missing
+(fatal only under --strict-missing, so artifact sets can evolve).
 
 Exit codes: 0 clean / snapshot written; 1 regression(s); 2 no baseline
 (run --snapshot first and commit it).
@@ -124,22 +99,16 @@ Exit codes: 0 clean / snapshot written; 1 regression(s); 2 no baseline
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
-import re
 import sys
 
 SNAPSHOT_REL = os.path.join("baselines_out", "perf_watch.json")
 
 # metric kinds: comparison direction + default relative tolerance
 KINDS = {
-    "time_ms": {"dir": "lower_better", "tol": 0.10},
-    "compile_ms": {"dir": "lower_better", "tol": 0.50},
     "bytes": {"dir": "lower_better", "tol": 0.10},
     "flops": {"dir": "lower_better", "tol": 0.02},
-    "count": {"dir": "lower_better", "tol": 0.0},  # e.g. steady-state builds
-    "ratio": {"dir": "higher_better", "tol": 0.10},
     "ok": {"dir": "higher_better", "tol": 0.0},
     # semantic flags with no good direction: ANY flip is a regression
     # (e.g. a budget-infeasible straggler cell silently becoming feasible)
@@ -153,112 +122,6 @@ def _read_json(path):
             return json.load(fh)
     except Exception:
         return None
-
-
-def _tail_records(tail: str) -> list:
-    """The structured JSON lines a bench emitted into the driver tail."""
-    out = []
-    for line in (tail or "").splitlines():
-        line = line.strip()
-        if not line.startswith("{"):
-            continue
-        try:
-            rec = json.loads(line)
-        except Exception:
-            continue
-        if isinstance(rec, dict) and "metric" in rec:
-            out.append(rec)
-    return out
-
-
-def _round_of(path: str):
-    m = re.search(r"_r(\d+)\.json$", os.path.basename(path))
-    return int(m.group(1)) if m else -1
-
-
-def fold_bench(root: str, metrics: dict) -> None:
-    """Latest round's record per bench metric name (the driver keeps the
-    tail line, so the LAST record in a tail is the most complete one)."""
-    latest: dict = {}  # metric name -> (round, record)
-    for path in sorted(glob.glob(os.path.join(root, "BENCH_r*.json"))):
-        data = _read_json(path)
-        if not isinstance(data, dict):
-            continue
-        rnd = _round_of(path)
-        for rec in _tail_records(data.get("tail", "")):
-            name = rec["metric"]
-            if name not in latest or rnd >= latest[name][0]:
-                latest[name] = (rnd, rec)
-    for name, (rnd, rec) in sorted(latest.items()):
-        src = f"BENCH_r{rnd:02d}"
-        extra = rec.get("extra") or {}
-        if isinstance(rec.get("value"), (int, float)):
-            metrics[f"bench.{name}.ms_per_step"] = {
-                "value": float(rec["value"]), "kind": "time_ms",
-                "source": src}
-        if isinstance(rec.get("vs_baseline"), (int, float)):
-            metrics[f"bench.{name}.vs_baseline"] = {
-                "value": float(rec["vs_baseline"]), "kind": "ratio",
-                "source": src}
-        if isinstance(extra.get("flops_per_step"), (int, float)):
-            metrics[f"bench.{name}.flops_per_step"] = {
-                "value": float(extra["flops_per_step"]), "kind": "flops",
-                "source": src}
-        if isinstance(extra.get("compile_ms"), (int, float)):
-            metrics[f"bench.{name}.compile_ms"] = {
-                "value": float(extra["compile_ms"]), "kind": "compile_ms",
-                "source": src}
-        if isinstance(extra.get("wire_bytes"), (int, float)):
-            # logical codeword bytes per step (obs/numerics.wire_ledger,
-            # ISSUE 10) — the series that will show the item-4 win when
-            # the real narrow wire lands
-            metrics[f"bench.{name}.wire_bytes"] = {
-                "value": float(extra["wire_bytes"]), "kind": "bytes",
-                "source": src}
-
-
-def fold_multichip(root: str, metrics: dict) -> None:
-    paths = sorted(glob.glob(os.path.join(root, "MULTICHIP_r*.json")),
-                   key=_round_of)
-    if not paths:
-        return
-    data = _read_json(paths[-1])
-    if not isinstance(data, dict):
-        return
-    src = os.path.basename(paths[-1]).rsplit(".", 1)[0]
-    if "ok" in data:
-        metrics["multichip.ok"] = {"value": float(bool(data["ok"])),
-                                   "kind": "ok", "source": src}
-    if isinstance(data.get("n_devices"), (int, float)):
-        metrics["multichip.n_devices"] = {
-            "value": float(data["n_devices"]), "kind": "ratio", "source": src}
-
-
-def fold_host_loop(root: str, metrics: dict) -> None:
-    for fname, mode in (("host_loop_overhead.json", "cnn"),
-                        ("host_loop_overhead_lm.json", "lm")):
-        path = os.path.join(root, "baselines_out", fname)
-        data = _read_json(path)
-        if not isinstance(data, dict):
-            continue
-        src = f"baselines_out/{fname}"
-        rows = data.get("ms_per_step_by_steps_per_call") or {}
-        for k, ms in sorted(rows.items(), key=lambda kv: int(kv[0])):
-            if isinstance(ms, (int, float)):
-                metrics[f"host_loop.{mode}.k{k}_ms_per_step"] = {
-                    "value": float(ms), "kind": "time_ms", "source": src}
-        for k, ms in sorted((data.get("compile_ms_by_steps_per_call")
-                             or {}).items(), key=lambda kv: int(kv[0])):
-            if isinstance(ms, (int, float)):
-                metrics[f"host_loop.{mode}.k{k}_compile_ms"] = {
-                    "value": float(ms), "kind": "compile_ms", "source": src}
-        for k, n in sorted((data.get("timed_builds_by_steps_per_call")
-                            or {}).items(), key=lambda kv: int(kv[0])):
-            if isinstance(n, (int, float)):
-                # steady-state executable builds during the timed window —
-                # must stay 0; any growth is a retrace regression
-                metrics[f"host_loop.{mode}.k{k}_timed_builds"] = {
-                    "value": float(n), "kind": "count", "source": src}
 
 
 def fold_program_lint(root: str, metrics: dict) -> None:
@@ -352,7 +215,7 @@ def fold_straggler(root: str, metrics: dict) -> None:
     certificate bools gate at tolerance 0 — a cell whose measured residual
     creeps past its analytic bound, stops reaching the target loss, or
     loses full batch recovery is a correctness regression, never noise.
-    The wall column rides at the ordinary time tolerance. Infeasible cells
+    Infeasible cells
     (exact-code budget exceeded) fold only their feasibility flag — a
     budget-exceeded scenario silently becoming "feasible" (or vice versa)
     is a semantic change worth tripping on too."""
@@ -385,10 +248,6 @@ def fold_straggler(root: str, metrics: dict) -> None:
             metrics[f"{key}.recovered_fraction_min"] = {
                 "value": float(row["recovered_fraction_min"]),
                 "kind": "ok", "source": src}
-        if isinstance(row.get("ms_per_step"), (int, float)):
-            metrics[f"{key}.ms_per_step"] = {
-                "value": float(row["ms_per_step"]), "kind": "time_ms",
-                "source": src}
 
 
 def fold_autopilot(root: str, metrics: dict) -> None:
@@ -525,55 +384,22 @@ def fold_wire_study(root: str, metrics: dict) -> None:
 
 def fold_segment_study(root: str, metrics: dict) -> None:
     """Segment-study artifact (tools/segment_study.py, ISSUE 16): the
-    streaming segmented wire's pipeline evidence. The ACCEPTANCE bools
-    gate at tolerance 0 — the winning pipelined S>1 cell must keep a
-    strictly positive wire/decode overlap fraction and a strictly
-    positive ms/step win over the S=1 base (the flipped-row control in
-    tests/test_segments.py proves both gates live). The measured overlap
-    and win fractions ride as ratio-kind (wall-clock noisy, 10%); the
-    per-cell segment COUNTS and per-segment physical bytes are PINNED at
+    per-cell segment COUNTS and per-segment physical bytes, PINNED at
     tolerance 0 in BOTH directions — a segment silently appearing,
-    vanishing, or changing size is a wire-format change, never noise.
-    S=1 rows pin overlap at exactly 0: the no-pipeline base measuring
-    overlap would mean the overlap metric itself broke."""
+    vanishing, or changing size is a wire-format change, never noise (the
+    flipped-row control in tests/test_segments.py proves the gate live).
+    The study's overlap fractions and ms/step win are wall-clock measures
+    of a CPU run and are not folded."""
     path = os.path.join(root, "baselines_out", "segment_study.json")
     data = _read_json(path)
     if not isinstance(data, dict):
         return
     src = "baselines_out/segment_study.json"
-    if "all_ok" in data:
-        metrics["segment.all_ok"] = {"value": float(bool(data["all_ok"])),
-                                     "kind": "ok", "source": src}
-    win = data.get("win") or {}
-    if win:
-        metrics["segment.win.positive"] = {
-            "value": float(float(win.get("ms_per_step_win", 0.0)) > 0.0),
-            "kind": "ok", "source": src}
-        metrics["segment.win.overlap_positive"] = {
-            "value": float(float(win.get("overlap_frac", 0.0)) > 0.0),
-            "kind": "ok", "source": src}
-        for col in ("win_frac", "overlap_frac"):
-            if isinstance(win.get(col), (int, float)):
-                metrics[f"segment.win.{col}"] = {
-                    "value": float(win[col]), "kind": "ratio",
-                    "source": src}
     for row in data.get("rows", []):
         dtype, s = row.get("dtype"), row.get("segments")
         if dtype is None or s is None:
             continue
         key = f"segment.{dtype}.s{s}"
-        if isinstance(row.get("ms_per_step"), (int, float)):
-            metrics[f"{key}.ms_per_step"] = {
-                "value": float(row["ms_per_step"]), "kind": "time_ms",
-                "source": src}
-        if s == 1:
-            metrics[f"{key}.overlap_frac"] = {
-                "value": float(row.get("overlap_frac", 0.0)),
-                "kind": "pinned", "source": src}
-        elif isinstance(row.get("overlap_frac"), (int, float)):
-            metrics[f"{key}.overlap_frac"] = {
-                "value": float(row["overlap_frac"]), "kind": "ratio",
-                "source": src}
         seg = (row.get("wire") or {}).get("segments") or {}
         if isinstance(seg.get("count"), (int, float)):
             metrics[f"{key}.segments_count"] = {
@@ -587,49 +413,25 @@ def fold_segment_study(root: str, metrics: dict) -> None:
 
 def fold_tree_study(root: str, metrics: dict) -> None:
     """Tree-study artifact (tools/tree_study.py, ISSUE 17): the
-    hierarchical CodedReduce evidence. The per-cell ACCEPTANCE bools gate
-    at tolerance 0 — win (critical path beats flat decode), bytes_ok
-    (leaf-level ingest sums exactly to the flat per-step bytes), and the
-    detection-parity pin on every s_g >= 1 cell (tree flags == flat
-    flags under the same live adversary; the flipped-row control in
-    tests/test_tree.py proves the gate live). The crossover n and the
-    per-LEVEL byte columns are PINNED in both directions — the tree
-    silently winning earlier/later or a level's bytes moving at all is a
-    topology/wire-format change, never noise. Decode and critical-path
-    ms ride at the time tolerance."""
+    hierarchical CodedReduce evidence a CPU can state exactly. The per-cell
+    bools gate at tolerance 0 — bytes_ok (leaf-level ingest sums exactly to
+    the flat per-step bytes) and the detection-parity pin on every
+    s_g >= 1 cell (tree flags == flat flags under the same live adversary;
+    the flipped-row control in tests/test_tree.py proves the gate live).
+    The per-LEVEL byte columns are PINNED in both directions — a level's
+    bytes moving at all is a topology/wire-format change, never noise. The
+    study's decode and critical-path ms, the win they decide and the
+    crossover n are wall-clock measures of a CPU run and are not folded."""
     path = os.path.join(root, "baselines_out", "tree_study.json")
     data = _read_json(path)
     if not isinstance(data, dict):
         return
     src = "baselines_out/tree_study.json"
-    if "all_ok" in data:
-        metrics["tree.all_ok"] = {"value": float(bool(data["all_ok"])),
-                                  "kind": "ok", "source": src}
-    cx = data.get("crossover") or {}
-    for col in ("critical_path_n", "sequential_n"):
-        if isinstance(cx.get(col), (int, float)):
-            metrics[f"tree.crossover.{col}"] = {
-                "value": float(cx[col]), "kind": "pinned", "source": src}
     for row in data.get("rows", []):
-        n = row.get("n")
-        if row.get("kind") == "flat":
-            if isinstance(row.get("decode_ms"), (int, float)):
-                metrics[f"tree.flat.n{n}.decode_ms"] = {
-                    "value": float(row["decode_ms"]), "kind": "time_ms",
-                    "source": src}
-            continue
-        g = row.get("fanout")
-        if n is None or g is None:
+        n, g = row.get("n"), row.get("fanout")
+        if row.get("kind") == "flat" or n is None or g is None:
             continue
         key = f"tree.n{n}.g{g}"
-        for col, kind in (("critical_path_ms", "time_ms"),
-                          ("leaf_decode_ms", "time_ms"),
-                          ("sequential_total_ms", "time_ms")):
-            if isinstance(row.get(col), (int, float)):
-                metrics[f"{key}.{col}"] = {
-                    "value": float(row[col]), "kind": kind, "source": src}
-        metrics[f"{key}.win"] = {"value": float(bool(row.get("win"))),
-                                 "kind": "ok", "source": src}
         metrics[f"{key}.bytes_ok"] = {
             "value": float(bool(row.get("bytes_ok"))), "kind": "ok",
             "source": src}
@@ -650,46 +452,14 @@ def fold_tree_study(root: str, metrics: dict) -> None:
                     "value": float(b), "kind": "pinned", "source": src}
 
 
-def fold_decode_bench(root: str, metrics: dict) -> None:
-    """Fused-decode microbench (tools/decode_kernel_bench.py, ISSUE 12):
-    absolute per-impl decode times and the pallas/xla ratio ride at the
-    time tolerance; gated rungs additionally pin ``kernel_not_slower``
-    (ratio ≤ 1) as a 0-tolerance ok flag — the flipped-row test in
-    tests/test_cli_tools.py proves that gate live."""
-    path = os.path.join(root, "baselines_out", "decode_kernel_bench.json")
-    data = _read_json(path)
-    if not isinstance(data, dict):
-        return
-    src = "baselines_out/decode_kernel_bench.json"
-    if "all_ok" in data:
-        metrics["decode_bench.all_ok"] = {
-            "value": float(bool(data["all_ok"])), "kind": "ok",
-            "source": src}
-    for row in data.get("rows", []):
-        rung = row.get("rung")
-        if not rung:
-            continue
-        key = f"decode_bench.{rung}"
-        for col in ("xla_ms", "pallas_ms", "pallas_over_xla"):
-            if isinstance(row.get(col), (int, float)):
-                metrics[f"{key}.{col}"] = {
-                    "value": float(row[col]), "kind": "time_ms",
-                    "source": src}
-        if "kernel_not_slower" in row:
-            metrics[f"{key}.kernel_not_slower"] = {
-                "value": float(bool(row["kernel_not_slower"])),
-                "kind": "ok", "source": src}
-
-
 def fold_device_profile(root: str, metrics: dict) -> None:
-    """Device-time attribution artifact (tools/device_profile.py, ISSUE 9):
-    per-cell phase SHARES at the ordinary time tolerance — a decode-share
-    creep past 10% relative is exactly the regression ROADMAP items 1-2
-    must develop under — and the explicit-collective instruction/byte
-    ledger pinned at tolerance 0 in BOTH directions (the runtime trace and
-    the static Manifest must agree; a collective appearing OR vanishing is
-    a semantic change, never noise). Cross-check flags and the seeded
-    mismatch control gate as 0-tolerance ok-kind."""
+    """Device-trace attribution artifact (tools/device_profile.py, ISSUE
+    9): the explicit-collective instruction/byte ledger pinned at tolerance
+    0 in BOTH directions (the runtime trace and the static Manifest must
+    agree; a collective appearing OR vanishing is a semantic change, never
+    noise). Cross-check flags and the seeded mismatch control gate as
+    0-tolerance ok-kind. The phases' shares of a CPU trace's time are not
+    folded: the chip's are the benchmark's per-layer metrics."""
     path = os.path.join(root, "baselines_out", "device_profile.json")
     data = _read_json(path)
     if not isinstance(data, dict):
@@ -714,13 +484,6 @@ def fold_device_profile(root: str, metrics: dict) -> None:
             # program can never silently overwrite the first's gate rows
             base = f"device.{cell}" if len(programs) == 1 else \
                 f"device.{cell}.{prog.get('module') or pi}"
-            for phase in ("draco_comp", "draco_encode", "draco_decode",
-                          "draco_update"):
-                frac = (prog.get("phases", {}).get(phase) or {}).get("frac")
-                if isinstance(frac, (int, float)):
-                    metrics[f"{base}.{phase}_share"] = {
-                        "value": float(frac), "kind": "time_ms",
-                        "source": src}
             check = prog.get("cross_check") or {}
             metrics[f"{base}.cross_check_ok"] = {
                 "value": float(bool(check.get("ok"))), "kind": "ok",
@@ -746,7 +509,8 @@ def fold_fleet(root: str, metrics: dict) -> None:
     burning cell silently going quiet is a contract change that must
     re-baseline consciously), and the detection SLO's P/R pinned at
     the certificate 1.0 on the adversary cells. The remediated cells'
-    MTTR gates at the time tolerance (wall-clock measure)."""
+    MTTR is a wall-clock measure and is not folded; that every repair
+    names its incident (``mttr_attributed``) is."""
     path = os.path.join(root, "baselines_out", "fleet_slo.json")
     data = _read_json(path)
     if not isinstance(data, dict):
@@ -793,9 +557,6 @@ def fold_fleet(root: str, metrics: dict) -> None:
                         "source": src}
         mttr = slo.get("incident_mttr") or {}
         if mttr.get("mttr_s") is not None:
-            metrics[f"{key}.mttr_s"] = {
-                "value": float(mttr["mttr_s"]), "kind": "time_ms",
-                "source": src}
             metrics[f"{key}.mttr_attributed"] = {
                 "value": float(mttr.get("unattributed", 0) == 0
                                and bool(mttr.get("attributed"))),
@@ -804,9 +565,6 @@ def fold_fleet(root: str, metrics: dict) -> None:
 
 def fold_all(root: str) -> dict:
     metrics: dict = {}
-    fold_bench(root, metrics)
-    fold_multichip(root, metrics)
-    fold_host_loop(root, metrics)
     fold_program_lint(root, metrics)
     fold_chaos(root, metrics)
     fold_straggler(root, metrics)
@@ -815,7 +573,6 @@ def fold_all(root: str) -> dict:
     fold_wire_study(root, metrics)
     fold_segment_study(root, metrics)
     fold_tree_study(root, metrics)
-    fold_decode_bench(root, metrics)
     fold_device_profile(root, metrics)
     return metrics
 
@@ -832,8 +589,8 @@ def compare(baseline: dict, current: dict, tols: dict) -> dict:
             missing.append({"metric": name, **baseline[name]})
             continue
         base, cur = baseline[name], current[name]
-        kind = cur.get("kind", base.get("kind", "time_ms"))
-        spec = KINDS.get(kind, KINDS["time_ms"])
+        kind = cur["kind"]
+        spec = KINDS[kind]
         tol = tols.get(kind, spec["tol"])
         b, c = float(base["value"]), float(cur["value"])
         if b == 0.0:
@@ -862,8 +619,8 @@ def _print_report(cmp_report: dict, out=None) -> None:
     def show(rows, tag):
         for r in rows:
             rel = r["rel_change"]
-            # rel is None when the baseline was 0 (e.g. timed_builds going
-            # 0 -> 1): an infinite relative change, not a no-op
+            # rel is None when the baseline was 0 (a count going 0 -> 1): an
+            # infinite relative change, not a no-op
             pct = ("inf%" if rel is None
                    else f"{'+' if rel >= 0 else ''}{rel * 100:.1f}%")
             print(f"  [{tag}] {r['metric']} ({r['kind']}): "
@@ -885,7 +642,7 @@ def _print_report(cmp_report: dict, out=None) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--root", type=str, default=".",
-                    help="repo root holding BENCH_r*.json / baselines_out/")
+                    help="repo root holding baselines_out/")
     ap.add_argument("--baseline", type=str, default="",
                     help=f"baseline snapshot (default <root>/{SNAPSHOT_REL})")
     ap.add_argument("--snapshot", action="store_true",
@@ -893,12 +650,8 @@ def main(argv=None) -> int:
                          "snapshot instead of comparing")
     ap.add_argument("--json", type=str, default="",
                     help="also write the comparison report as JSON here")
-    ap.add_argument("--tol-time", type=float, default=KINDS["time_ms"]["tol"])
     ap.add_argument("--tol-bytes", type=float, default=KINDS["bytes"]["tol"])
     ap.add_argument("--tol-flops", type=float, default=KINDS["flops"]["tol"])
-    ap.add_argument("--tol-compile", type=float,
-                    default=KINDS["compile_ms"]["tol"])
-    ap.add_argument("--tol-ratio", type=float, default=KINDS["ratio"]["tol"])
     ap.add_argument("--strict-missing", action="store_true",
                     help="treat metrics that disappeared from the artifacts "
                          "as regressions")
@@ -927,9 +680,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
 
-    tols = {"time_ms": args.tol_time, "bytes": args.tol_bytes,
-            "flops": args.tol_flops, "compile_ms": args.tol_compile,
-            "ratio": args.tol_ratio}
+    tols = {"bytes": args.tol_bytes, "flops": args.tol_flops}
     report = compare(snap["metrics"], current, tols)
     if args.strict_missing and report["missing"]:
         report["ok"] = False

@@ -28,10 +28,7 @@ constant-bloat guard, which builds ~3.3M params); the fast subset runs in
 roughly a minute on the CI host and is what the ``core``-tier test
 exercises (tests/test_program_lint.py, PERF_HISTORY.md §6).
 
-The report is rewritten after every row (incremental-artifact discipline);
-bench.py refuses to record a chip run while this artifact reports a
-constant_bloat or host_traffic violation for the program family being
-timed.
+The report is rewritten after every row (incremental-artifact discipline).
 """
 
 from __future__ import annotations

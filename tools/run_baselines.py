@@ -4,18 +4,12 @@
   python tools/run_baselines.py --smoke            # short runs, any hardware
   python tools/run_baselines.py --max-steps 2000   # real grid
 
-Writes one JSON line per config to stdout and baselines_out/results.jsonl.
-Eager rows record per-step wall-clock + final loss/accuracy; scan rows
-(accelerators) record per-step wall-clock + loss + analytic FLOPs — the
-timed scan has no eval loop, so the accuracy axis comes from the eager
-grid / tools/time_to_acc.py instead. --smoke shrinks steps and swaps in
-synthetic data so the grid runs anywhere in minutes.
-
-Timing protocol: on accelerators the per-step number comes from bench.run's
-scanned-steps protocol (utils/timing.py — per-step Python dispatch stays off
-the timed path); on CPU the eager Trainer
-loop is both honest and much faster than a scanned conv step
-(PERF_HISTORY.md §4). --protocol overrides the auto choice.
+Writes one JSON line per config to stdout and baselines_out/results.jsonl:
+per-step wall-clock of the eager Trainer loop (its first step's compile
+included, so give a real grid enough steps to carry it) + final
+loss/accuracy. --smoke shrinks steps and swaps in synthetic data so the
+grid runs anywhere in minutes. A step time to quote comes from a cell of
+benchmark/run.py, not from here.
 """
 
 from __future__ import annotations
@@ -39,12 +33,6 @@ def main(argv=None) -> int:
     ap.add_argument("--fresh", action="store_true",
                     help="truncate results.jsonl first (default appends), so "
                          "stale rows from older code can't shadow a re-run")
-    ap.add_argument("--protocol", choices=["auto", "eager", "scan"],
-                    default="auto",
-                    help="per-step timing: eager Trainer loop or scanned "
-                         "steps (auto: scan on accelerators, eager on CPU)")
-    ap.add_argument("--scan-steps", type=int, default=10,
-                    help="steps folded into each timed scan (scan protocol)")
     ap.add_argument("--only", type=str, default="",
                     help="comma-separated subset of preset names to run "
                          "(default: all five)")
@@ -96,48 +84,22 @@ def main(argv=None) -> int:
             ds = load_dataset(cfg.dataset, cfg.data_dir,
                               synthetic_train=1024, synthetic_test=128)
             try:
-                import jax
-
-                protocol = args.protocol
-                if protocol == "auto":
-                    protocol = (
-                        "eager" if jax.devices()[0].platform == "cpu" else "scan"
-                    )
-                if protocol == "scan":
-                    import bench as bench_mod
-
-                    steps = min(args.scan_steps, cfg.max_steps)
-                    dt, loss, flops, _compile_s = bench_mod.run(
-                        dataclasses.asdict(cfg), ds, make_mesh(cfg.num_workers),
-                        steps, warmup=1, reps=2, want_flops=True,
-                    )
-                    rec = {
-                        "preset": name,
-                        "steps": steps,
-                        "ms_per_step": round(1000 * dt, 2),
-                        "final_loss": round(loss, 4),
-                        "flops_per_step": flops,
-                        "protocol": "scan",
-                        "dataset": ds.name,
-                        "config": dataclasses.asdict(cfg),
-                    }
-                else:
-                    tr = Trainer(cfg, mesh=make_mesh(cfg.num_workers),
-                                 dataset=ds, quiet=True)
-                    t0 = time.perf_counter()
-                    last = tr.run()
-                    wall = time.perf_counter() - t0
-                    rec = {
-                        "preset": name,
-                        "steps": cfg.max_steps,
-                        "ms_per_step": round(1000 * wall / cfg.max_steps, 2),
-                        "final_loss": round(last.get("loss", float("nan")), 4),
-                        "final_prec1": round(last.get("prec1", float("nan")), 4),
-                        "protocol": "eager",
-                        "dataset": ds.name,
-                        "config": dataclasses.asdict(cfg),
-                    }
-                    tr.close()
+                tr = Trainer(cfg, mesh=make_mesh(cfg.num_workers),
+                             dataset=ds, quiet=True)
+                t0 = time.perf_counter()
+                last = tr.run()
+                wall = time.perf_counter() - t0
+                rec = {
+                    "preset": name,
+                    "steps": cfg.max_steps,
+                    "ms_per_step": round(1000 * wall / cfg.max_steps, 2),
+                    "final_loss": round(last.get("loss", float("nan")), 4),
+                    "final_prec1": round(last.get("prec1", float("nan")), 4),
+                    "protocol": "eager",
+                    "dataset": ds.name,
+                    "config": dataclasses.asdict(cfg),
+                }
+                tr.close()
             except Exception as e:  # record the failure, keep the grid going
                 rec = {"preset": name, "error": repr(e)}
                 rc = 1

@@ -66,7 +66,7 @@ def main(argv=None) -> int:
     from draco_tpu.data.datasets import load_dataset
     from draco_tpu.runtime import make_mesh
     from draco_tpu.training.trainer import Trainer
-    from draco_tpu.utils.timing import fetch_scalar, measure_rtt
+    from tools._timing import fetch_scalar, measure_rtt
 
     cfg = TrainConfig(
         network=args.network, dataset=args.dataset, approach=args.approach,

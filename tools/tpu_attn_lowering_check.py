@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Offline TPU-lowering audit of the flash-attention kernel (round 5).
 
-Every recorded hardware failure of the kernel (baselines_out/tpu_attn.json,
-rows all `ValueError: The Pallas TPU lowering currently requires that the
-last two dimensions of your block shape are divisible by 8 and 128 ...`)
+Every recorded hardware failure of the kernel (PERF_HISTORY.md's long-T
+table: rows all `ValueError: The Pallas TPU lowering currently requires that
+the last two dimensions of your block shape are divisible by 8 and 128 ...`)
 was raised by the *Python-side Pallas TPU lowering*, not by the Mosaic
 machine-code compiler. That stage runs during cross-platform export
 (`jax.export.export(..., platforms=["tpu"])`) on a CPU-only host, so the

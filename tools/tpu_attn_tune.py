@@ -52,7 +52,7 @@ def time_point(name, reps, interpret=False):
     import jax.numpy as jnp
 
     from draco_tpu.ops import flash_attention as fa
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     heads, t, dh, dv, kv, window = POINTS[name]
     if interpret:
@@ -147,7 +147,7 @@ def main(argv=None) -> int:
 
     from draco_tpu.ops.flash_attention import flash_attention
     from draco_tpu.parallel.ring_attention import dense_attention
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     if args.point:
         dev = jax.devices()[0]

@@ -28,10 +28,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def _loop_time(step, carry, consts=(), reps=20):
     """Chained in-jit per-iteration timing — see
-    draco_tpu.utils.timing.timeit_chained for the protocol and its
+    tools/_timing.timeit_chained for the protocol and its
     feedback-discipline requirements (non-linear full-output feedback,
     operands via consts, adaptive trip count)."""
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     return timeit_chained(step, carry, consts, reps=reps)
 

@@ -1,26 +1,23 @@
 #!/usr/bin/env python
 """Offline TPU-lowering audit of the d≈159M LM chip programs (round 5).
 
-The `lm_big` measurement (LM_BIG_RUNG below: two tools/tpu_lm_perf.py
-commands) stakes a large slice of chip time on the largest programs in the
-repo: TransformerLM dim=1024/heads=16/layers=12 (d ≈ 159M params), T=2048,
-bf16, remat, on the folded w×tp GSPMD mesh — cyclic shared + Pallas flash,
-cyclic shared, geomedian, and cyclic simulate (r=3 redundant lanes). A
-Python-side lowering bug there (Pallas tiling, sharding rule, remat/scan
-interaction) would burn that time for nothing.
+The `lm_big` shapes (LM_BIG below) are the largest TransformerLM programs
+in the repo: dim=1024/heads=16/layers=12 (d ≈ 159M params), T=2048, bf16,
+remat, on the folded w×tp GSPMD mesh — cyclic shared + Pallas flash, cyclic
+shared, geomedian, and cyclic simulate (r=3 redundant lanes). A Python-side
+lowering bug there (Pallas tiling, sharding rule, remat/scan interaction)
+would burn a chip call for nothing.
 
 This tool cross-platform exports the full scanned train-step programs for
 `platforms=["tpu"]` on the CPU host (`jax.export`), which runs the whole
 StableHLO + Pallas TPU lowering stack without a chip (methodology +
-negative control: tools/tpu_attn_lowering_check.py). Drift-proofing: the
-variant configs, input staging, and scan loop are IMPORTED from
-tools/tpu_lm_perf.py (build_lm_variants / stage_scan_inputs /
-make_scan_loop) — the audit lowers the same program the chip rung times,
-by construction. The host runs with ONE virtual device, so
-make_folded_wtp_mesh folds all 8 logical workers onto a single device —
-the exact layout the single-chip run uses (every on-chip artifact records
-devices_used: 1); an 8-device layout would exercise different GSPMD
-shardings than the chip will.
+negative control: tools/tpu_attn_lowering_check.py). The variant configs,
+input staging, and scan loop are the shared ones of
+tools/_lowering_common.py (build_lm_variants / stage_scan_inputs /
+make_scan_loop), which the scan audit lowers too. The host runs with ONE
+virtual device, so make_folded_wtp_mesh folds all 8 logical workers onto a
+single device — the exact layout the single-chip run uses; an 8-device
+layout would exercise different GSPMD shardings than the chip will.
 
 What it cannot prove: Mosaic machine-code compilation and HBM fit — a
 compile for the described chip (tests/test_chip_compile.py's method) or the
@@ -68,7 +65,7 @@ def lm_big_program(name, cfg_kw, steps=2):
         from draco_tpu.config import TrainConfig
         from draco_tpu.parallel.mesh import make_folded_wtp_mesh
         from draco_tpu.parallel.tp_step import build_tp_train_setup
-        from tools.tpu_lm_perf import make_scan_loop, stage_scan_inputs
+        from tools._lowering_common import make_scan_loop, stage_scan_inputs
 
         cfg = TrainConfig(**cfg_kw)
         mesh = make_folded_wtp_mesh(cfg.num_workers)
@@ -97,20 +94,7 @@ def lm_big_program(name, cfg_kw, steps=2):
     return LintProgram(name=name, build=build, route="lm_big", fast=False)
 
 
-# The lm_big measurement as it is run on the chip — the b=2 leg and the b=1
-# simulate leg, each a tools/tpu_lm_perf.py command line — and the shapes the
-# audit lowers. tests/test_cli_tools.py::test_lm_lowering_audit_matches_r5_rung
-# holds the two in step: change one and it points at the other.
-LM_BIG_RUNG = (
-    "--steps 4 --reps 2 --model-dim 1024 --model-heads 16 --model-layers 12 "
-    "--seq-len 2048 --batch-size 2 --remat --variants "
-    "lm_cyclic_s1_shared_bf16_flash,lm_cyclic_s1_shared_bf16,"
-    "lm_geomedian_bf16 --out baselines_out/tpu_lm_perf_big.json",
-    "--steps 4 --reps 2 --model-dim 1024 --model-heads 16 --model-layers 12 "
-    "--seq-len 2048 --batch-size 1 --remat --variants "
-    "lm_cyclic_s1_simulate_bf16 "
-    "--out baselines_out/tpu_lm_perf_big_simulate.json",
-)
+# The shapes the audit lowers: the b=2 variants and the b=1 simulate variant.
 LM_BIG = dict(num_workers=8, seq_len=2048, vocab=8192, model_dim=1024,
               model_heads=16, model_layers=12, remat=True, max_steps=5)
 LM_BIG_VARIANTS_B2 = ("lm_cyclic_s1_shared_bf16_flash",
@@ -126,11 +110,10 @@ def main(argv=None) -> int:
 
     # ONE virtual device: the chip folds all logical workers onto a single
     # device and the audit must lower that exact layout (docstring)
-    from tools._lowering_common import lint_row, run_rows, setup_cpu_host
+    from tools._lowering_common import (build_lm_variants, lint_row,
+                                        run_rows, setup_cpu_host)
 
     setup_cpu_host(1)
-
-    from tools.tpu_lm_perf import build_lm_variants
 
     v_b2 = build_lm_variants(batch_size=2, **LM_BIG)
     v_b1 = build_lm_variants(batch_size=1, **LM_BIG)
@@ -141,8 +124,8 @@ def main(argv=None) -> int:
         args.out,
         "jax.export cross-platform lowering, platforms=['tpu'], CPU host "
         "with ONE virtual device (the chip's folded layout), full scanned "
-        "train-step programs at the exact lm_big shapes (LM_BIG_RUNG), "
-        "configs imported from tools/tpu_lm_perf.py; each row "
+        "train-step programs at the lm_big shapes (LM_BIG), "
+        "configs from tools/_lowering_common.py; each row "
         "carries the six-rule program-lint verdict (draco_tpu/analysis)",
         named,
     )

@@ -22,9 +22,9 @@ This tool proves, without a chip:
      ARGUMENTS, so the 638 MB closed-over-constant regression (PERF_HISTORY.md §4)
      cannot reappear through them.
 
-Configs are IMPORTED from tools/tpu_lm_perf.py (build_lm_variants with
+Configs come from tools/_lowering_common.py (build_lm_variants with
 scan_layers=True) and the shapes from tools/tpu_lm_lowering_check.py
-(LM_BIG), so the audit lowers the same programs chain r5f times on chip.
+(LM_BIG), so both audits lower the same programs.
 
   python tools/tpu_lm_scan_lowering_check.py \
       [--out baselines_out/tpu_lm_scan_lowering.json]
@@ -49,7 +49,7 @@ def lower_variant(name, cfg_kw, steps=2):
     from draco_tpu.config import TrainConfig
     from draco_tpu.parallel.mesh import make_folded_wtp_mesh
     from draco_tpu.parallel.tp_step import build_tp_train_setup
-    from tools.tpu_lm_perf import make_scan_loop, stage_scan_inputs
+    from tools._lowering_common import make_scan_loop, stage_scan_inputs
 
     cfg = TrainConfig(**cfg_kw)
     mesh = make_folded_wtp_mesh(cfg.num_workers)
@@ -142,7 +142,7 @@ def main(argv=None) -> int:
     from tools.tpu_lm_lowering_check import (
         LM_BIG, LM_BIG_VARIANTS_B1, LM_BIG_VARIANTS_B2,
     )
-    from tools.tpu_lm_perf import build_lm_variants
+    from tools._lowering_common import build_lm_variants
 
     rows = []
     for scan in (True, False):
@@ -168,7 +168,7 @@ def main(argv=None) -> int:
         "the production chunked token-loop program (train_token_many, K=4) "
         "vs its eager single step; module_bytes = serialized StableHLO size "
         "(the compile-service pressure metric). Configs from "
-        "tools/tpu_lm_perf.py.",
+        "tools/_lowering_common.py.",
         rows,
     )
     # headline ratio: shared-flash variant, scan vs unroll

@@ -13,7 +13,7 @@ This study measures that trade at the study d for every valid
 
   * **flat decode ms** — the small-code decode at (n, d), the per-step
     cost of today's star aggregation point (chained-feedback timing,
-    utils/timing.py protocol);
+    tools/_timing.py protocol);
   * **per-node critical path** — what ONE tree node pays per step: the
     leaf decode at (g, d) plus each combine level's fan-in-f partial sum.
     This is the deployment quantity CodedReduce optimises (every level
@@ -91,7 +91,7 @@ def _decode_ms(code, d: int, reps: int) -> float:
     import numpy as np
 
     from draco_tpu.coding import cyclic as cyc
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     r = np.random.RandomState(SEED)
     g = jnp.asarray(r.randn(code.n, d).astype(np.float32) * 0.05)
@@ -111,7 +111,7 @@ def _combine_node_ms(fan_in: int, d: int, reps: int) -> float:
     import jax.numpy as jnp
     import numpy as np
 
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     r = np.random.RandomState(SEED)
     parts = jnp.asarray(r.randn(fan_in, d).astype(np.float32))
@@ -130,7 +130,7 @@ def _combine_full_ms(plan, d: int, reps: int) -> float:
     import numpy as np
 
     from draco_tpu.coding import topology as topo
-    from draco_tpu.utils.timing import timeit_chained
+    from tools._timing import timeit_chained
 
     r = np.random.RandomState(SEED)
     parts = jnp.asarray(r.randn(plan.num_groups, d).astype(np.float32))
