@@ -154,12 +154,13 @@ def add_fit_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     p.add_argument("--model-layers", type=int, default=2)
     p.add_argument("--model-spec", type=str, default="",
                    help="network=LatentMoeLM | HybridMoeLM | WindowedMoeLM | "
-                        "LoopedLM | ShortConvMoeLM: a JSON file holding the "
-                        "one mapping that states the model (a published "
-                        "config.json's keys plus layers / experts_held / "
-                        "vocab_rows; models/latent_moe.py, "
+                        "LoopedLM | ShortConvMoeLM | KdaMoeLM: a JSON file "
+                        "holding the one mapping that states the model (a "
+                        "published config.json's keys plus layers / "
+                        "experts_held / vocab_rows; models/latent_moe.py, "
                         "models/hybrid_moe.py, models/windowed_moe.py, "
-                        "models/looped.py, models/conv_moe.py)")
+                        "models/looped.py, models/conv_moe.py, "
+                        "models/kda_moe.py)")
     p.add_argument("--cpu-mesh", type=int, default=0, metavar="N",
                    help="force an N-device virtual CPU mesh (testing without TPUs)")
     p.add_argument("--steps-per-call", type=int, default=1,

@@ -27,14 +27,15 @@ AGG_MODES = ("normal", "geometric_median", "krum", "coord_median",
 # (parallel/token_loop.py) and come from models.build_lm; everything else is
 # an image model on the CNN Trainer.
 TOKEN_NETWORKS = ("TransformerLM", "LatentMoeLM", "HybridMoeLM",
-                  "WindowedMoeLM", "LoopedLM", "ShortConvMoeLM")
+                  "WindowedMoeLM", "LoopedLM", "ShortConvMoeLM", "KdaMoeLM")
 # the token models stated by ONE mapping of a published config's keys
 # (TrainConfig.model_spec), each with the module that checks and builds it
 SPEC_NETWORKS = {"LatentMoeLM": "draco_tpu.models.latent_moe",
                  "HybridMoeLM": "draco_tpu.models.hybrid_moe",
                  "WindowedMoeLM": "draco_tpu.models.windowed_moe",
                  "LoopedLM": "draco_tpu.models.looped",
-                 "ShortConvMoeLM": "draco_tpu.models.conv_moe"}
+                 "ShortConvMoeLM": "draco_tpu.models.conv_moe",
+                 "KdaMoeLM": "draco_tpu.models.kda_moe"}
 
 
 @dataclasses.dataclass
@@ -42,7 +43,7 @@ class TrainConfig:
     # --- model / data (reference: distributed_nn.py:27-37) ---
     # LeNet | FC | ResNet18/34/50/101/152 | VGG11/13/16/19[_bn] | the token
     # models TransformerLM | LatentMoeLM | HybridMoeLM | WindowedMoeLM |
-    # LoopedLM | ShortConvMoeLM (TOKEN_NETWORKS)
+    # LoopedLM | ShortConvMoeLM | KdaMoeLM (TOKEN_NETWORKS)
     network: str = "LeNet"
     dataset: str = "MNIST"  # MNIST | Cifar10 | synthetic variants
     data_dir: str = "./data"
@@ -179,12 +180,12 @@ class TrainConfig:
     model_heads: int = 4
     model_layers: int = 2
     # network=LatentMoeLM | HybridMoeLM | WindowedMoeLM | LoopedLM |
-    # ShortConvMoeLM (SPEC_NETWORKS): the ONE mapping that states the model
-    # — a published config.json's keys verbatim plus ``layers``,
+    # ShortConvMoeLM | KdaMoeLM (SPEC_NETWORKS): the ONE mapping that states
+    # the model — a published config.json's keys verbatim plus ``layers``,
     # ``vocab_rows`` and, for the sparse-expert blocks, ``experts_held``
-    # ([first, count]): the chip's share of a deployment
-    # (models/latent_moe.py, hybrid_moe.py, windowed_moe.py, looped.py,
-    # conv_moe.py).
+    # ([first, count]; kda_moe.py's ``heads_held`` likewise): the chip's
+    # share of a deployment (models/latent_moe.py, hybrid_moe.py,
+    # windowed_moe.py, looped.py, conv_moe.py, kda_moe.py).
     # The model_* fields above are TransformerLM's and are not read for it.
     # CLI: --model-spec <file.json>.
     model_spec: Optional[dict] = None

@@ -9,7 +9,7 @@ collectives + latency hiding), so there is exactly one copy of each model here.
 Token models (``TOKEN_NETWORKS``, config.py) come from :func:`build_lm`, the
 one factory the LM step builders call: ``TransformerLM`` (the repo's own
 pre-LN / GELU / tied-head block, the only one the tp / ep / pp / sequence-
-sharded routes build) and the five blocks that state a published config on
+sharded routes build) and the six blocks that state a published config on
 the single-shard route of parallel/sp_step.py (``config.SPEC_NETWORKS``;
 seeded init, head and loss are one base's, models/spec_lm.py):
 ``LatentMoeLM`` (models/latent_moe.py: RMS norm, SwiGLU, latent key/value
@@ -24,11 +24,11 @@ softmax routing and no shared expert — over the same expert layer), and
 ``LoopedLM`` (models/looped.py: dense — one stack of four-norm layers run
 ``total_ut_steps`` times over the same weights, an exit gate and the whole
 head after each pass, the loss an expectation over the exits), and
-``ShortConvMoeLM`` (models/conv_moe.py: double-gated short convolutions
-three layers in four and grouped-query attention with per-head q/k norms
-the fourth, leading dense SwiGLU layers, then bias-selected sigmoid routing
-and no shared expert — over the same expert layer —, the head tied to the
-embedding: one leaf read twice, ``spec_lm.SpecLM.head_kernel``).
+``ShortConvMoeLM`` (models/conv_moe.py: double-gated short convolutions 3:1
+with GQA under per-head q/k norms, leading dense layers, bias-selected
+sigmoid routing, no shared expert, the head tied to the embedding), and
+``KdaMoeLM`` (models/kda_moe.py: a delta rule decaying per key channel 3:1
+with position-free latent attention, both mixers told the heads they hold).
 """
 
 from draco_tpu.config import SPEC_NETWORKS, TOKEN_NETWORKS
@@ -149,8 +149,8 @@ def build_lm(cfg, attn_fn=None, kernel_fn=None):
     bare single-device kernel, which the published-config blocks take
     (``LatentMoeLM``'s q/k and v differ in head size, ``HybridMoeLM``'s
     key/value heads are fewer than its query heads, ``WindowedMoeLM`` hands
-    each layer's call its own ``window=``, ``LoopedLM`` and
-    ``ShortConvMoeLM`` run on the same single-shard route). None is each
+    each layer's call its own ``window=``, ``LoopedLM``, ``ShortConvMoeLM``
+    and ``KdaMoeLM`` run on the same single-shard route). None is each
     model's plain lowering."""
     import importlib
 

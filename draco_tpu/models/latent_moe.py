@@ -173,8 +173,8 @@ def check_spec(spec) -> None:
 
 
 def rope_interleaved(x, positions, theta):
-    """Rotate the pairs (x[2i], x[2i+1]) of the last axis by
-    positions·theta^(-2i/dim). x: (B, T, ..., dim), positions: (T,)."""
+    """Rotate the last axis' pairs (x[2i], x[2i+1]) by pos·theta^(-2i/dim)."""
+    if theta is None: return x  # noqa: E701 — no positions (models/kda_moe.py)
     dim = x.shape[-1]
     freqs = theta ** (-np.arange(0, dim, 2, dtype=np.float32) / dim)
     ang = positions.astype(jnp.float32)[:, None] * freqs

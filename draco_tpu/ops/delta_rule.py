@@ -152,14 +152,14 @@ def _pass_scan(u, w, a, q, k, keep):
 
 def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
                              force=None, interpret: bool = False):
-    """q, k (B, T, Hk, Dk), already normalised and scaled as the layer
-    wants them; v (B, T, Hv, Dv) with Hv a multiple of Hk (key head h
-    serves value heads h·r .. h·r + r − 1); g, beta (B, T, Hv), g <= 0.
-    Returns (o (B, T, Hv, Dv), the state after the last token (B, Hv, Dk,
-    Dv) float32). Any T: a last chunk is closed with tokens that neither
-    decay nor write (g = 0, β = 0, k = 0). ``force`` / ``interpret`` as
-    ops/flash_attention has them: the tests' way to the kernels off the
-    chip (``rule_runs_in_kernels`` says which path a call takes)."""
+    """q, k (B, T, Hk, Dk) normalised and scaled as the layer wants them; v
+    (B, T, Hv, Dv), key head h serving value heads h·r .. h·r + r − 1; g, beta
+    (B, T, Hv), g <= 0 (g per key channel: the file's end) -> (o (B, T, Hv,
+    Dv), the last state (B, Hv, Dk, Dv) float32). Any T: a last chunk is
+    closed with tokens that neither decay nor write. ``force`` / ``interpret``:
+    the tests' way to the kernels (``rule_runs_in_kernels``: which path)."""
+    if g.ndim == 4:
+        return _per_channel(q, k, v, g, beta, chunk)
     if rule_runs_in_kernels(q.shape, v.shape, chunk, force=force,
                             interpret=interpret):
         n = q.shape[1] // chunk
@@ -658,3 +658,22 @@ def rule_runs_in_kernels(q_shape, v_shape, chunk: int = CHUNK, *,
                 and dk % _LANE == 0 and dv % _LANE == 0 and hv % hk == 0
                 # G and β of a grid step's value heads share a lane tile
                 and 2 * (hv // hk) * _blocking(hk, 2)[0] <= _LANE)
+
+
+# ---- a decay per key channel ------------------------------------------------
+# (below every line the standing model's step is traced from: its lowered
+# program names source lines, and the compile cache keys on them)
+
+def _per_channel(q, k, v, g, beta, chunk):
+    """``chunked_gated_delta_rule`` for g (B, T, H, Dk) — every key channel
+    of a head decays by its own factor (Kimi Delta Attention), Hk = Hv: the
+    decay then sits inside the k·k and q·k products and the state's decay
+    between chunks is a row scale (ops/kda_rule.py, which builds on this
+    file's solve)."""
+    from draco_tpu.ops.kda_rule import chunked_kda_rule
+
+    if q.shape != k.shape or q.shape != g.shape or v.shape[2] != q.shape[2]:
+        raise ValueError(
+            f"a per-channel decay g {g.shape} wants q, k of its shape and as "
+            f"many value heads: q {q.shape}, k {k.shape}, v {v.shape}")
+    return chunked_kda_rule(q, k, v, g, beta, chunk)

@@ -486,6 +486,143 @@ def test_a_short_conv_layer_lowers_for_the_chip(one_chip):
         assert op not in ops, op
 
 
+def _kimilinear_spec():
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "kimi-linear-48b-a3b-ep32-tp2.json")) as fh:
+        return json.load(fh)["train_config"]["model_spec"]
+
+
+def test_a_kda_layer_lowers_for_the_chip(one_chip):
+    """kimilinear.maj_vote_r3's Kimi Delta Attention layer at the published
+    widths (a row of 4 096 tokens, 16 of 32 heads held; published layer 2:
+    the mixer, then 8 of 256 experts over the sorted pairs and the shared
+    expert), forward and backward under the block's two checkpoints a
+    layer, for the described chip. The rule is ``jax.numpy`` (no kernel
+    carries its scope; ``kda_kernel_layers`` reads 0): its products carry
+    ``draco_kdarule``, the pass over the chunks is a loop, no array of a
+    sub-block's pairwise decays (tokens x 16 x Dk a head: 537 MB a
+    layer-lane) is ever an instruction's result, and the layer's backward
+    pass fits 1.5 GB beside its own gradients. The expert layer is the
+    shared one: grouped-product kernels under ``draco_experts``."""
+    import re
+    from unittest import mock
+
+    from draco_tpu.models.kda_moe import KdaMoeLM
+    from tests.test_step_scopes import _executed_lines
+
+    spec = _kimilinear_spec()
+    lm = KdaMoeLM(dict(spec, layers=1, layers_held=[2]),
+                  attn_fn=functools.partial(flash_attention, force=True),
+                  remat=True)
+    assert lm.kept == [("kda", False)] and not lm.moe.dense
+    t, d = 4096, spec["hidden_size"]
+    heads, dk = lm.heads, spec["linear_attn_config"]["head_dim"]
+
+    def struct(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    params = jax.tree.map(struct, lm.param_shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    params.pop("embed")
+    x = struct((1, t, d))
+
+    def fn(x, params):
+        def loss(x, params):
+            y, stats = lm.hidden(
+                dict(params, embed={"embedding": x[0]}),
+                jnp.arange(t)[None])
+            return jnp.sum(jnp.sin(y)) + stats["kda_state_absmax"]
+
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            return jax.grad(loss, argnums=(0, 1))(x, params)
+
+    compiled = jax.jit(fn).lower(x, params).compile()
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and not any("draco_kda" in name for name in kernels)
+    assert any("draco_experts" in name for name in kernels)
+    lines = list(_executed_lines(text))
+    products = [line for line in lines if " convolution(" in line
+                or " fusion(" in line and "dot_general" in line]
+    assert sum("draco_kdarule" in line for line in products) >= 8
+    assert any(" while(" in line and "draco_kdarule" in line
+               for line in lines)
+    # a sub-block's pairwise decays live inside fusions only
+    pairwise = f"[{heads},{t // 64},4,16,16,{dk}]"
+    assert pairwise in text
+    assert not [line for line in lines
+                if pairwise in line.split(" = ", 1)[-1].split("(", 1)[0]]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# the instrument's own scale: compiled for a chip that is described, not
+# attached, by THIS file's recipe a token cell's whole step reads 3.4 GB above
+# what the chip reserves for it (lfm2.maj_vote_r3: 14 211 114 496 B of
+# ``preallocated-temp`` here, 10 748 805 120 on the chip; this cell
+# 14 175 764 480 here, 10 620 272 640 on the chip: PERF.md section 4) — so
+# the bound a new cell's step is held to is the standing cell's reading on
+# the same instrument, temp + donated arguments, with the 2 % that cell has
+# to spare on the chip (14.81 of 15.75 GB)
+LFM2_STEP_BYTES_DESCRIBED = 14_211_114_496 + 4_062_683_136
+
+
+@pytest.mark.slow  # one whole step program of five layers: three minutes
+def test_the_kimilinear_step_holds_no_more_than_the_cell_that_fits(one_chip):
+    """kimilinear.maj_vote_r3's whole step (n = r = 3 lanes in turn, 4 096
+    tokens, d = 510 692 160) compiled for the described v5e: its
+    ``preallocated-temp`` and donated bytes together stay within 2 % of
+    what lfm2.maj_vote_r3's step reads on the same instrument. ISSUE 45
+    asked for the sum under 15.75 GB; this recipe reads both cells over
+    it, and the chip settled the fallbacks (neither taken: 10.62 GB
+    reserved, 14.71 with the donated weights and momentum)."""
+    import json
+
+    import numpy as np
+    from unittest import mock
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from draco_tpu.config import TrainConfig
+    from draco_tpu.parallel.sp_step import build_sp_train_setup
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-linear-48b-a3b-ep32-tp2.json")) as fh:
+        fields = json.load(fh)["train_config"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "lm_maj_vote_r3.json")) as fh:
+        fields = dict(fields, **json.load(fh)["train_config"])
+    cfg = TrainConfig(**dict(fields, max_steps=8, eval_freq=0,
+                             train_dir="")).validate()
+    mesh = Mesh(np.asarray([one_chip._device_assignment[0]]).reshape(1, 1),
+                ("w", "sp"))
+
+    def shapes_only(tree, sharding=None, **kw):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                           sharding=sharding), tree)
+
+    whole = NamedSharding(mesh, P())
+    with mock.patch.object(jax, "device_put", shapes_only), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        setup = build_sp_train_setup(cfg, mesh)
+        compiled = setup.train_step.lower(
+            setup.state,
+            jax.ShapeDtypeStruct((3, 1, cfg.seq_len), jnp.int32,
+                                 sharding=whole),
+            jax.ShapeDtypeStruct((3,), bool, sharding=whole)).compile()
+    memory = compiled.memory_analysis()
+    assert int(setup.dim) == 510_692_160
+    assert memory.alias_size_in_bytes >= 8 * int(setup.dim)  # donated
+    assert (memory.temp_size_in_bytes + memory.argument_size_in_bytes
+            < 1.02 * LFM2_STEP_BYTES_DESCRIBED)
+
+
 def test_four_exits_head_holds_one_blocks_logits_at_a_time(one_chip):
     """ouro.maj_vote_r3's head and loss (models/spec_lm.blocked_nll), forward
     and backward, at the cell's size — four exits' 16 384 rows against the

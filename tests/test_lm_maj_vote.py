@@ -30,7 +30,8 @@ SPECS = {"LatentMoeLM": SPEC}
 for _network, _file in (("HybridMoeLM", "hybrid-moe-tiny.json"),
                         ("WindowedMoeLM", "windowed-moe-tiny.json"),
                         ("LoopedLM", "looped-tiny.json"),
-                        ("ShortConvMoeLM", "conv-moe-tiny.json")):
+                        ("ShortConvMoeLM", "conv-moe-tiny.json"),
+                        ("KdaMoeLM", "kda-moe-tiny.json")):
     with open(os.path.join(ROOT, "benchmark", "testdata", _file)) as fh:
         SPECS[_network] = json.load(fh)["train_config"]["model_spec"]
 STEPS = 4
@@ -74,7 +75,8 @@ def _run(cfg):
 
 @pytest.fixture(scope="module", params=["LatentMoeLM", "TransformerLM",
                                         "HybridMoeLM", "WindowedMoeLM",
-                                        "LoopedLM", "ShortConvMoeLM"])
+                                        "LoopedLM", "ShortConvMoeLM",
+                                        "KdaMoeLM"])
 def runs(request):
     attacked = _run(_cfg(request.param))
     clean = _run(_cfg(request.param, adversary_count=0))
@@ -162,6 +164,25 @@ def test_the_short_conv_networks_counters_ride_in_every_record(runs):
         assert 0 < r["moe_assignments_held"] <= 2 * 32 * 2 * 4
 
 
+def test_the_kda_networks_counters_ride_in_every_record(runs):
+    """Of the fixture's networks the one with the per-channel rule: its
+    counters are in every record of the attacked run, as stated."""
+    (_, rows), _ = runs
+    if "kda_layers" not in rows[0]:
+        assert "heads_held" not in rows[0]
+        return
+    for r in rows:
+        assert r["kda_layers"] == 4.0 and r["heads_held"] == 2.0
+        # ops/kda_rule.py is jax.numpy on every backend
+        assert r["kda_kernel_layers"] == 0.0
+        assert 0.0 < r["kda_state_absmax"] < 100.0
+        # 32-token rows: one chunk, closed with tokens that do not decay
+        assert -1000.0 < r["kda_decay_min"] < 0.0
+        assert r["moe_dropped"] == r["moe_full_dispatch"] == 0.0
+        # two groups of three lanes, 2 x 32 tokens, top-3, four layers
+        assert 0 < r["moe_assignments_held"] <= 2 * 32 * 3 * 4
+
+
 def test_the_fused_head_trains_the_same_under_the_vote(monkeypatch):
     """The exits' rows cut into blocks (``HEAD_BLOCK_BYTES`` patched
     small), so the head takes its gradients in the forward pass (models/
@@ -200,7 +221,8 @@ def test_the_chunked_loop_runs_the_same_steps():
 
 @pytest.mark.parametrize("network", ["LatentMoeLM", "TransformerLM",
                                      "HybridMoeLM", "WindowedMoeLM",
-                                     "LoopedLM", "ShortConvMoeLM"])
+                                     "LoopedLM", "ShortConvMoeLM",
+                                     "KdaMoeLM"])
 def test_lanes_in_turn_train_the_same_as_side_by_side(network, monkeypatch):
     """The large side of ``sp_step.LANES_IN_TURN_BYTES`` at the tiny size:
     lanes in turn (``lax.map``), each layer rematerialised, the stack in
@@ -242,6 +264,8 @@ PUBLISHED = {
     "ouro": ("LoopedLM", "ouro-2.6b-l4.json", (2, 2049, 1023, 406_884_353)),
     "lfm2": ("ShortConvMoeLM", "lfm2-8b-a1b-ep4.json",
              (2, 128, 768, 507_820_288)),
+    "kimilinear": ("KdaMoeLM", "kimi-linear-48b-a3b-ep32-tp2.json",
+                   (1, 64, 192, 510_692_160)),
 }
 
 
@@ -251,7 +275,8 @@ def _bits(x):
 
 @pytest.mark.parametrize("case", ["LatentMoeLM", "TransformerLM",
                                   "HybridMoeLM", "WindowedMoeLM",
-                                  "LoopedLM", "ShortConvMoeLM", *PUBLISHED])
+                                  "LoopedLM", "ShortConvMoeLM", "KdaMoeLM",
+                                  *PUBLISHED])
 def test_a_lanes_row_is_written_in_whole_lines(case):
     """``sp_step._write_row``: the leaves cut into pieces that each start
     and end on a 128-wide line, each written into its range of the lane's
@@ -262,7 +287,8 @@ def test_a_lanes_row_is_written_in_whole_lines(case):
     cells run: every leaf a piece of its own but qwen3next's two (3, 32)
     leaves and ouro's gate (a one-element bias and 2 048 weights), which
     close the row together with its zeros, and lfm2's two (1, 64) q/k norm
-    weights, one line together."""
+    weights, one line together; kimilinear's (4, 16) ``A_log`` closes its
+    row with 192 zeros."""
     import jax.numpy as jnp
 
     from draco_tpu.models import build_lm

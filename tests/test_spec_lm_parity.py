@@ -42,12 +42,14 @@ import pytest
 
 import parity
 from benchmark.reference.nets import latent_moe as ref_latent
-from benchmark.reference.nets import lfm2, mellum, ouro, qwen3_next
+from benchmark.reference.nets import kimi_linear, lfm2, mellum, ouro
+from benchmark.reference.nets import qwen3_next
 from draco_tpu.config import SPEC_NETWORKS, TrainConfig
-from draco_tpu.models import build_lm, conv_moe, hybrid_moe, latent_moe
-from draco_tpu.models import looped, windowed_moe
+from draco_tpu.models import build_lm, conv_moe, hybrid_moe, kda_moe
+from draco_tpu.models import latent_moe, looped, windowed_moe
 from draco_tpu.models.conv_moe import ShortConvMoeLM
 from draco_tpu.models.hybrid_moe import HybridMoeLM
+from draco_tpu.models.kda_moe import KdaMoeLM
 from draco_tpu.models.latent_moe import LatentMoeLM
 from draco_tpu.models.looped import LoopedLM
 from draco_tpu.models.windowed_moe import WindowedMoeLM
@@ -82,6 +84,21 @@ def _short_conv_counters(lm, stats):
     assert float(stats["tied_head"]) == 1.0
     assert (float(stats["short_conv_absmax"]) > 0.0) == (
         "conv" in lm.layer_types)
+
+
+KDA_TAIL = ("kda_layers", "kda_kernel_layers", "kda_state_absmax",
+            "kda_decay_min", "heads_held")
+
+
+def _kda_counters(lm, stats):
+    assert lm.stat_names == latent_moe.STAT_NAMES + KDA_TAIL
+    kda = sum(kind == "kda" for kind, _ in lm.kept)
+    assert float(stats["kda_layers"]) == kda
+    # ops/kda_rule.py is jax.numpy on every backend
+    assert float(stats["kda_kernel_layers"]) == 0.0
+    assert float(stats["heads_held"]) == lm.spec["heads_held"][1]
+    assert (float(stats["kda_state_absmax"]) > 0.0) == bool(kda)
+    assert (float(stats["kda_decay_min"]) < 0.0) == bool(kda)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,9 +148,32 @@ BLOCKS = {
         layer_types=["full_attention"] * 6)),
     "LoopedLM": Block(LoopedLM, "looped-tiny", ouro, 40, moves=None,
                       stat_tail=looped.STAT_NAMES),
+    # T = 80: a chunk of 64 and a closing chunk of 16, the state crosses;
+    # heads 2-3 of 4 held in both mixers (rematerialised, as the cell runs)
+    "KdaMoeLM": Block(
+        KdaMoeLM, "kda-moe-tiny", kimi_linear, 80,
+        moves=("scale", "dt_bias"), remat=True,
+        rel_by_suffix={"['A_log']": 1e-3, "['dt_bias']": 1e-3},
+        zero=("e_score_correction_bias",), counters=_kda_counters,
+        stat_tail=KDA_TAIL),
+    # the dense KDA layer and a sparse one: no latent attention at all
+    "kda_only": Block(
+        KdaMoeLM, "kda-moe-tiny", kimi_linear, 80,
+        moves=("scale", "dt_bias"), remat=True,
+        rel_by_suffix={"['A_log']": 1e-3, "['dt_bias']": 1e-3},
+        zero=("e_score_correction_bias",), counters=_kda_counters,
+        stat_tail=KDA_TAIL, edit=dict(layers=2, layers_held=[1, 6])),
+    # latent attention alone, the first of the two made dense
+    "nope_latent_only": Block(
+        KdaMoeLM, "kda-moe-tiny", kimi_linear, 80, remat=True,
+        zero=("e_score_correction_bias",), counters=_kda_counters,
+        stat_tail=KDA_TAIL, edit=dict(
+            layers=2, layers_held=[1, 2], linear_attn_config=dict(
+                parity.tiny("kda-moe-tiny")["linear_attn_config"],
+                kda_layers=[], full_attn_layers=[1, 2, 3, 4, 5, 6]))),
 }
 NETWORKS = ["LatentMoeLM", "HybridMoeLM", "WindowedMoeLM", "kept_five",
-            "LoopedLM"]  # one row a network
+            "LoopedLM", "KdaMoeLM"]  # one row a network
 SPARSE = [name for name in BLOCKS if name != "LoopedLM"]
 
 
@@ -226,7 +266,7 @@ def test_three_steps_parameters_match_the_reference(name):
 
 
 @pytest.mark.parametrize("name", ["LatentMoeLM", "HybridMoeLM",
-                                  "WindowedMoeLM", "kept_five"])
+                                  "WindowedMoeLM", "kept_five", "KdaMoeLM"])
 def test_rematerialised_block_gives_the_same_gradient(name):
     c = block_programs(name)
     toks = _tokens(name, 2)
@@ -239,7 +279,7 @@ def test_rematerialised_block_gives_the_same_gradient(name):
 
 
 @pytest.mark.parametrize("name", ["LatentMoeLM", "HybridMoeLM",
-                                  "WindowedMoeLM", "kept_five"])
+                                  "WindowedMoeLM", "kept_five", "KdaMoeLM"])
 def test_the_weighted_surface_is_the_weighted_sum_of_token_nll(name):
     """``weighted_nll(params, tokens, targets, weights, denom)``, which the
     route trains through, against Σ weights · ``token_nll`` / denom: value,
@@ -438,6 +478,9 @@ def test_the_shares_add_up_to_the_uncut_layer(name):
     ("kept_five", conv_moe, ("_choose", "_route", "_buffer", "_every_token",
                              "_experts", "dispatch_rows", "token_nll",
                              "weighted_nll", "init")),
+    ("KdaMoeLM", kda_moe, ("_choose", "_route", "_buffer", "_experts",
+                           "dispatch_rows", "token_nll", "weighted_nll",
+                           "init")),
 ])
 def test_the_expert_layer_is_shared_not_copied(name, module, names):
     for method in names:
@@ -483,6 +526,10 @@ def _set(**kw):
 
 def _pop(key):
     return lambda s: s.pop(key)
+
+
+def _linear(**kw):
+    return lambda s: s["linear_attn_config"].update(kw)
 
 
 REFUSED_MAPPINGS = {
@@ -543,6 +590,33 @@ REFUSED_MAPPINGS = {
         (_set(num_key_value_heads=3), "num_key_value_heads"),
         (_set(num_attention_heads=64), "hidden_size"),
         (_set(conv_L_cache=0), "conv_L_cache"),
+        (_set(vocab_rows=1), "vocab_rows"),
+    ],
+    "KdaMoeLM": [
+        (_pop("kv_lora_rank"), "kv_lora_rank"),
+        (_set(q_lora_rank=1536), "q_lora_rank"),
+        (_set(mla_use_nope=False), "mla_use_nope"),
+        (_set(rope_scaling={"type": "yarn"}), "rope_scaling"),
+        (_set(num_expert_group=2), "num_expert_group"),
+        (_set(topk_group=2), "topk_group"),
+        (_set(moe_layer_freq=2), "moe_layer_freq"),
+        (_set(num_nextn_predict_layers=1), "num_nextn_predict_layers"),
+        (_set(tie_word_embeddings=True), "tie_word_embeddings"),
+        (_set(hidden_act="gelu"), "hidden_act"),
+        (_set(moe_router_activation_func="softmax"),
+         "moe_router_activation_func"),
+        (_set(moe_renormalize=False), "moe_renormalize"),
+        (_linear(short_conv_kernel_size=3), "short_conv_kernel_size"),
+        (_linear(num_heads=8), r"linear_attn_config'\]\['num_heads"),
+        (lambda s: s["linear_attn_config"].pop("kda_layers"), "kda_layers"),
+        # a kept layer in both of the config's lists, and in neither
+        (_linear(full_attn_layers=[3, 4]), "layer 3"),
+        (_linear(kda_layers=[1, 2, 3, 6]), "layer 5"),
+        (_set(layers_held=[1, 3, 2, 4, 5]), "layers_held"),
+        (_set(layers=4), "layers_held"),
+        (_set(heads_held=[3, 2]), "heads_held"),
+        (_set(heads_held=[0, 0]), "heads_held"),
+        (_set(experts_held=[7, 2]), "experts_held"),
         (_set(vocab_rows=1), "vocab_rows"),
     ],
     "LoopedLM": [
@@ -647,6 +721,15 @@ UNSUPPORTED = {
         (dict(model_spec={"hidden_size": 64}), "model_spec lacks"),
         (dict(network="WindowedMoeLM"), "model_spec lacks"),
         (dict(network="LeNet"), "ShortConvMoeLM"),
+    ],
+    "KdaMoeLM": [
+        (dict(tensor_shards=2), "tensor_shards"),
+        (dict(seq_shards=2), "seq_shards"),
+        (dict(vocab=VOCAB_ROWS + 1), "vocab_rows"),
+        (dict(model_spec=None), "model_spec"),
+        (dict(model_spec={"hidden_size": 64}), "model_spec lacks"),
+        (dict(network="LatentMoeLM"), "model_spec lacks"),
+        (dict(network="LeNet"), "KdaMoeLM"),
     ],
 }
 
