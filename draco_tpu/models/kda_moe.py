@@ -82,7 +82,7 @@ from draco_tpu.models.latent_moe import (
 )
 from draco_tpu.models.spec_lm import EMBED_STD, _dot, rms_norm, swiglu
 from draco_tpu.ops.delta_rule import CHUNK, chunked_gated_delta_rule
-from draco_tpu.ops.kda_rule import chunk_decay_min
+from draco_tpu.ops.kda_rule import chunk_decay_min, kda_runs_in_kernels
 
 # the published config keys the block reads (model_spec must carry them)
 SPEC_KEYS = (
@@ -278,7 +278,8 @@ class KdaMoeLM(RoutedExpertLM):
     def _kda(self, x, p, a_log):
         """-> (the layer's output (B, T, hidden): the held heads' partial
         sum; (max |S| over heads of the state the row leaves behind, the
-        least of a chunk's summed g))."""
+        least of a chunk's summed g, 1 where the rule took the Pallas
+        kernels))."""
         s = self.spec
         b, t, _ = x.shape
         h, dk = self.heads, s["linear_attn_config"]["head_dim"]
@@ -304,7 +305,9 @@ class KdaMoeLM(RoutedExpertLM):
         with jax.named_scope("draco_kdarule"):
             o, state = chunked_gated_delta_rule(q, k, v, g, beta, CHUNK)
             marks = (jnp.max(jnp.abs(lax.stop_gradient(state))),
-                     chunk_decay_min(lax.stop_gradient(g), CHUNK))
+                     chunk_decay_min(lax.stop_gradient(g), CHUNK),
+                     jnp.float32(kda_runs_in_kernels(q.shape, v.shape,
+                                                     CHUNK)))
         gate = _dot(_dot(x, p["g_a"]["kernel"]), p["g_b"]["kernel"])
         o = (rms_norm(o, p["o_norm"]["scale"], s["rms_norm_eps"])
              * jax.nn.sigmoid(gate).reshape(b, t, h, dk))
@@ -360,12 +363,13 @@ class KdaMoeLM(RoutedExpertLM):
                 rules.append(marks)
         out = fold_stats(per_layer) or dict.fromkeys(STAT_NAMES,
                                                      jnp.float32(0))
-        absmax, least = (map(jnp.stack, zip(*rules)) if rules
-                         else (jnp.zeros((1,), jnp.float32),) * 2)
+        absmax, least, took = (map(jnp.stack, zip(*rules)) if rules
+                               else (jnp.zeros((1,), jnp.float32),) * 3)
         out["kda_layers"] = jnp.float32(len(rules))
-        # the KDA layers whose rule ran in Pallas kernels: ops/kda_rule.py
-        # is jax.numpy on every backend
-        out["kda_kernel_layers"] = jnp.float32(0)
+        # the KDA layers whose rule took the Pallas kernels
+        # (``kda_rule.kda_runs_in_kernels``: a TPU and the cell's shapes —
+        # every layer there, none on a CPU)
+        out["kda_kernel_layers"] = jnp.sum(took)
         out["kda_state_absmax"] = jnp.max(absmax)
         out["kda_decay_min"] = jnp.min(least)
         out["heads_held"] = jnp.float32(self.heads)

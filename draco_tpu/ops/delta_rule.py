@@ -159,7 +159,7 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
     closed with tokens that neither decay nor write. ``force`` / ``interpret``:
     the tests' way to the kernels (``rule_runs_in_kernels``: which path)."""
     if g.ndim == 4:
-        return _per_channel(q, k, v, g, beta, chunk)
+        return _per_channel(q, k, v, g, beta, chunk, force, interpret)
     if rule_runs_in_kernels(q.shape, v.shape, chunk, force=force,
                             interpret=interpret):
         n = q.shape[1] // chunk
@@ -664,16 +664,17 @@ def rule_runs_in_kernels(q_shape, v_shape, chunk: int = CHUNK, *,
 # (below every line the standing model's step is traced from: its lowered
 # program names source lines, and the compile cache keys on them)
 
-def _per_channel(q, k, v, g, beta, chunk):
+def _per_channel(q, k, v, g, beta, chunk, force=None, interpret=False):
     """``chunked_gated_delta_rule`` for g (B, T, H, Dk) — every key channel
     of a head decays by its own factor (Kimi Delta Attention), Hk = Hv: the
     decay then sits inside the k·k and q·k products and the state's decay
     between chunks is a row scale (ops/kda_rule.py, which builds on this
-    file's solve)."""
+    file's solve and has kernels of its own: ``kda_runs_in_kernels``)."""
     from draco_tpu.ops.kda_rule import chunked_kda_rule
 
     if q.shape != k.shape or q.shape != g.shape or v.shape[2] != q.shape[2]:
         raise ValueError(
             f"a per-channel decay g {g.shape} wants q, k of its shape and as "
             f"many value heads: q {q.shape}, k {k.shape}, v {v.shape}")
-    return chunked_kda_rule(q, k, v, g, beta, chunk)
+    return chunked_kda_rule(q, k, v, g, beta, chunk, force=force,
+                            interpret=interpret)

@@ -14,6 +14,7 @@ Skipped where the topology cannot be described (no TPU compiler installed).
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -331,9 +332,7 @@ def test_every_kernel_of_the_window_carries_the_windows_scope(one_chip):
     import re
 
     text = _compiled_text("flash_grad_window_1024_32_heads_on_4", one_chip)
-    names = [re.search(r'op_name="([^"]*)"', line).group(1)
-             for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    names = _kernel_names(text)
     assert len(names) == 2, names
     assert all("draco_window" in name for name in names), names
     assert sum("transpose(jvp(draco_window))" in name
@@ -470,9 +469,7 @@ def test_a_short_conv_layer_lowers_for_the_chip(one_chip):
 
     text = jax.jit(fn).lower(x, params).compile().as_text()
     ops = re.findall(r" = \S+ ([a-z\-]+)\(", text)
-    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
-               for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernel_names(text)
     products = [line for line in text.splitlines()
                 if " convolution(" in line]
     assert kernels == []
@@ -495,19 +492,40 @@ def _kimilinear_spec():
         return json.load(fh)["train_config"]["model_spec"]
 
 
+def _result_shapes(line):
+    """The dimensions of an HLO instruction's result arrays (a tuple's
+    members each), as strings: "1,8,4096,128"."""
+    import re
+
+    result = line.split(" = ", 1)[1]
+    result = (result[:result.index(") ") + 1] if result.startswith("(")
+              else result.split(" ", 1)[0])
+    return re.findall(r"[a-z]+[0-9]+\[([0-9,]*)\]", result)
+
+
+def _kernel_names(text):
+    import re
+
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def test_a_kda_layer_lowers_for_the_chip(one_chip):
     """kimilinear.maj_vote_r3's Kimi Delta Attention layer at the published
     widths (a row of 4 096 tokens, 16 of 32 heads held; published layer 2:
     the mixer, then 8 of 256 experts over the sorted pairs and the shared
     expert), forward and backward under the block's two checkpoints a
-    layer, for the described chip. The rule is ``jax.numpy`` (no kernel
-    carries its scope; ``kda_kernel_layers`` reads 0): its products carry
-    ``draco_kdarule``, the pass over the chunks is a loop, no array of a
-    sub-block's pairwise decays (tokens x 16 x Dk a head: 537 MB a
-    layer-lane) is ever an instruction's result, and the layer's backward
-    pass fits 1.5 GB beside its own gradients. The expert layer is the
-    shared one: grouped-product kernels under ``draco_experts``."""
-    import re
+    layer, for the described chip. Mosaic builds the rule's three kernels
+    (``ops/kda_rule.py``; ``kda_kernel_layers`` reads 1 a layer): the solve
+    once, the pass twice — forward and rematerialised forward: the mixer's
+    checkpoint keeps T —, the backward once, each under ``draco_kdarule``.
+    Nothing under that scope is a loop, no array of a sub-block's pairwise
+    decays (tokens x 16 x Dk a head: 537 MB a layer-lane) exists at all, no
+    (heads, chunks, C, C) array but T (two heads side by side: (heads / 2,
+    tokens, 2 C)) is an instruction's result under it, and the layer's
+    backward pass fits 1.5 GB beside its own gradients. The expert layer is
+    the shared one: grouped-product kernels under ``draco_experts``."""
     from unittest import mock
 
     from draco_tpu.models.kda_moe import KdaMoeLM
@@ -541,23 +559,46 @@ def test_a_kda_layer_lowers_for_the_chip(one_chip):
 
     compiled = jax.jit(fn).lower(x, params).compile()
     text = compiled.as_text()
-    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
-               for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    assert kernels and not any("draco_kda" in name for name in kernels)
+    kernels = _kernel_names(text)
+    rule = [name for name in kernels if "_kernel/pallas_call" in name]
+    assert sorted(name.split("/")[-2] for name in rule) == [
+        "backward_kernel", "pass_kernel", "pass_kernel", "solve_kernel"]
+    assert all("draco_kdarule" in name for name in rule)
     assert any("draco_experts" in name for name in kernels)
-    lines = list(_executed_lines(text))
-    products = [line for line in lines if " convolution(" in line
-                or " fusion(" in line and "dot_general" in line]
-    assert sum("draco_kdarule" in line for line in products) >= 8
-    assert any(" while(" in line and "draco_kdarule" in line
-               for line in lines)
-    # a sub-block's pairwise decays live inside fusions only
-    pairwise = f"[{heads},{t // 64},4,16,16,{dk}]"
-    assert pairwise in text
-    assert not [line for line in lines
-                if pairwise in line.split(" = ", 1)[-1].split("(", 1)[0]]
+    under = [line for line in _executed_lines(text)
+             if "draco_kdarule" in line]
+    assert not [line for line in under if " while(" in line]
+    assert f"[{heads},{t // 64},4,16,16,{dk}]" not in text
+    solves = {dims for line in under for dims in _result_shapes(line)
+              if math.prod(map(int, dims.split(","))) == heads * t * 64}
+    assert solves == {f"1,{heads // 2},{t},128"}
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_the_kda_rule_alone_builds_for_the_chip(one_chip):
+    """The per-channel rule by itself at the cell's shapes, (1, 4 096, 16,
+    128) float32, forward and backward: three kernels — ``solve_kernel``,
+    ``pass_kernel``, ``backward_kernel`` — and beside the operands and the
+    five gradients only T (16.8 MB), the chunk-start states (67 MB) and G
+    in main memory."""
+    from draco_tpu.ops import kda_rule
+
+    shape = (1, 4096, 16, 128)
+    assert kda_rule.kda_runs_in_kernels(shape, shape, force=True)
+    q = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct(shape[:3], jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        o, state = kda_rule.chunked_kda_rule(q, k, v, g, beta, force=True)
+        return jnp.sum(jnp.sin(o)) + jnp.sum(state)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, q, q, beta).compile()
+    assert [name.split("/")[-2] for name in _kernel_names(
+        compiled.as_text())] == ["solve_kernel", "pass_kernel",
+                                 "backward_kernel"]
+    # T + the chunk-start states + G and its cotangent's pass: 151 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
 
 
 # the instrument's own scale: compiled for a chip that is described, not

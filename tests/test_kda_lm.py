@@ -9,6 +9,9 @@ configurations, the counters: tests/test_spec_lm_parity.py's rows
   so strong that a chunk's summed g passes −80 (and −500): a form that
   formed e^{−G} would overflow there, one that dropped the small terms
   would miss the gradients;
+* the three Pallas kernels (interpret mode) against the ``jax.numpy`` form at
+  the same three strengths — o, the last state, the five gradients —, the
+  shapes they do not take, the checkpoint that keeps the solve;
 * g of three dimensions never enters the new code, still matches the
   scalar-decay recurrence, and is what the per-channel rule gives when every
   channel of a head is handed the same decay (bit-equality with the parent
@@ -43,15 +46,15 @@ from draco_tpu.ops import delta_rule, kda_rule  # noqa: E402
 T, H, D = 150, 2, 16  # two whole chunks and a closing one of 22 tokens
 
 
-def _inputs(strength, seed=0):
+def _inputs(strength, seed=0, t=T, h=H, d=D):
     ks = jax.random.split(jax.random.key(seed), 5)
-    q = jax.random.normal(ks[0], (1, T, H, D))
-    k = jax.random.normal(ks[1], (1, T, H, D))
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    q = jax.random.normal(ks[0], (1, t, h, d))
+    k = jax.random.normal(ks[1], (1, t, h, d))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (1, T, H, D))
-    g = -strength * jax.nn.softplus(jax.random.normal(ks[3], (1, T, H, D)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, H)))
+    v = jax.random.normal(ks[2], (1, t, h, d))
+    g = -strength * jax.nn.softplus(jax.random.normal(ks[3], (1, t, h, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h)))
     return q, k, v, g, beta
 
 
@@ -146,6 +149,133 @@ def test_shapes_the_per_channel_rule_cannot_take_are_refused(which):
         v = jnp.concatenate([v, v], axis=2)
     with pytest.raises(ValueError, match="per-channel decay"):
         delta_rule.chunked_gated_delta_rule(q, k, v, g, beta)
+
+
+# ---- the kernels (interpret mode) against the jax.numpy form ----------------
+
+LANES = 128  # the kernels take head sizes of whole lane tiles
+
+
+def _lane_inputs(t, heads, strength, d=LANES, seed=0):
+    """(``_inputs`` at T = ``t``, ``heads`` heads of size ``d``; what the
+    two outputs are weighed by)."""
+    ks = jax.random.split(jax.random.key(seed + 100), 2)
+    return _inputs(strength, seed, t, heads, d), (
+        jax.random.normal(ks[0], (1, t, heads, d)),
+        jax.random.normal(ks[1], (1, heads, d, d)))
+
+
+def _both_forms(args, probes, **kernel_kw):
+    """(o, state, five gradients) of the ``jax.numpy`` form and of the call
+    with ``kernel_kw``, each ONE compiled program; both outputs take a
+    cotangent."""
+    def run(**kw):
+        (o, state), grads = parity.with_gradients(
+            functools.partial(delta_rule.chunked_gated_delta_rule, **kw),
+            lambda out: (jnp.sum(out[0] * probes[0])
+                         + jnp.sum(out[1] * probes[1])),
+            argnums=(0, 1, 2, 3, 4))(*args)
+        return (o, state) + tuple(grads)
+
+    return run(), run(**kernel_kw)
+
+
+def _assert_same(got, want, rel=2e-5):
+    for name, a, b in zip("o state dq dk dv dg dbeta".split(), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        scale = float(jnp.max(jnp.abs(b)))
+        assert scale > 0.0, name  # no term lost
+        assert float(jnp.max(jnp.abs(a - b))) <= rel * scale, name
+
+
+@pytest.mark.parametrize("strength,least", [(0.05, -5.0), (1.5, -80.0),
+                                            (8.0, -500.0)])
+def test_kernels_are_the_jnp_form(strength, least):
+    """Two heads side by side in the solve, T of two grid steps: the state
+    and its cotangent cross a grid step in VMEM."""
+    args, probes = _lane_inputs(128, 2, strength)
+    reach = float(kda_rule.chunk_decay_min(args[3]))
+    assert reach < least if least < -5.0 else reach > least
+    assert kda_rule.kda_runs_in_kernels(args[0].shape, args[2].shape,
+                                        interpret=True)
+    want, got = _both_forms(args, probes, interpret=True)
+    _assert_same(got, want)
+
+
+def test_kernels_work_through_a_step_s_heads_in_groups(monkeypatch):
+    """Four heads a grid step in two groups of two (sixteen in two of eight
+    on the chip): the loop over the groups, a head's index the loop's."""
+    monkeypatch.setattr(kda_rule, "_HEADS_A_STEP", 4)
+    monkeypatch.setattr(kda_rule, "_HEADS_A_GROUP", 2)
+    kda_rule._shapes.cache_clear()
+    try:
+        args, probes = _lane_inputs(128, 4, 1.5, seed=2)
+        shapes = kda_rule._shapes(args[0].shape, args[2].shape, True)
+        assert (shapes.hb, shapes.hg, shapes.pr) == (4, 2, 2)
+        want, got = _both_forms(args, probes, interpret=True)
+        _assert_same(got, want)
+    finally:
+        kda_rule._shapes.cache_clear()
+
+
+@pytest.mark.parametrize("t,d,chunk", [
+    (100, LANES, 64),  # T not whole chunks
+    (128, 64, 64),     # head size under a lane tile
+    (128, LANES, 32),  # not the family's chunk
+])
+def test_shapes_the_kernels_do_not_take_are_the_jnp_form(t, d, chunk,
+                                                         monkeypatch):
+    """The kernels are not chosen — a call to them would raise here — and
+    the result is today's, bit for bit, whatever ``interpret`` says."""
+    args, _ = _lane_inputs(t, 1, 0.2, d=d)
+    assert not kda_rule.kda_runs_in_kernels(args[0].shape, args[2].shape,
+                                            chunk, force=True)
+
+    def refuse(*a, **kw):
+        raise AssertionError("the kernels were chosen")
+
+    monkeypatch.setattr(kda_rule, "_rule", refuse)
+    rule = functools.partial(delta_rule.chunked_gated_delta_rule, chunk=chunk)
+    want = jax.jit(rule)(*args)
+    got = jax.jit(functools.partial(rule, interpret=True))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_off_the_chip_the_kernels_are_not_chosen():
+    shape = (1, 128, 2, LANES)
+    assert not kda_rule.kda_runs_in_kernels(shape, shape)
+    assert not kda_rule.kda_runs_in_kernels(shape, shape, interpret=True,
+                                            force=False)
+    assert kda_rule.kda_runs_in_kernels(shape, shape, force=True)
+    # as many value heads as key heads, or the jax.numpy form
+    assert not kda_rule.kda_runs_in_kernels(shape, (1, 128, 4, LANES),
+                                            force=True)
+
+
+def test_a_checkpoint_that_keeps_the_solve_runs_the_pass_again_only():
+    """The solve is its own kernel and T carries ``SOLVE_NAME``: the
+    gradient's program is three kernels (solve, pass, backward); under a
+    checkpoint five (solve and pass again); with the name saved — the
+    mixer's policy, ``KEEP_SOLVE`` — four: the rematerialised forward is the
+    pass alone."""
+    from draco_tpu.models.hybrid_moe import KEEP_SOLVE
+
+    args, _ = _lane_inputs(64, 1, 0.2)
+
+    def rule(*a):
+        return delta_rule.chunked_gated_delta_rule(*a, interpret=True)[0]
+
+    def kernels(fn):
+        grad = jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2, 3, 4))
+        text = str(jax.make_jaxpr(grad)(*args))
+        return [text.count(f"name={name}_kernel")
+                for name in ("solve", "pass", "backward")]
+
+    assert kernels(rule) == [1, 1, 1]
+    assert kernels(jax.checkpoint(rule)) == [2, 2, 1]
+    assert kernels(jax.checkpoint(rule, policy=KEEP_SOLVE)) == [1, 2, 1]
 
 
 # ---- the share tied to the model ---------------------------------------

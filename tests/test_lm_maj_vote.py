@@ -17,6 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from draco_tpu.config import TrainConfig  # noqa: E402
+from draco_tpu.ops.kda_rule import kda_runs_in_kernels  # noqa: E402
 from draco_tpu.parallel import make_mesh_2d  # noqa: E402
 from draco_tpu.parallel.sp_step import build_sp_train_setup  # noqa: E402
 from draco_tpu.parallel.token_loop import (  # noqa: E402
@@ -173,8 +174,10 @@ def test_the_kda_networks_counters_ride_in_every_record(runs):
         return
     for r in rows:
         assert r["kda_layers"] == 4.0 and r["heads_held"] == 2.0
-        # ops/kda_rule.py is jax.numpy on every backend
-        assert r["kda_kernel_layers"] == 0.0
+        # the KDA layers whose rule took the Pallas kernels
+        # (ops/kda_rule.kda_runs_in_kernels): none off the chip
+        assert r["kda_kernel_layers"] == 4.0 * kda_runs_in_kernels(
+            (1, 64, 2, 128), (1, 64, 2, 128)) == 0.0
         assert 0.0 < r["kda_state_absmax"] < 100.0
         # 32-token rows: one chunk, closed with tokens that do not decay
         assert -1000.0 < r["kda_decay_min"] < 0.0

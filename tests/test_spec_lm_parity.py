@@ -54,6 +54,7 @@ from draco_tpu.models.latent_moe import LatentMoeLM
 from draco_tpu.models.looped import LoopedLM
 from draco_tpu.models.windowed_moe import WindowedMoeLM
 from draco_tpu.ops.flash_attention import flash_attention
+from draco_tpu.ops.kda_rule import kda_runs_in_kernels
 
 
 def _hybrid_counters(lm, stats):
@@ -94,8 +95,13 @@ def _kda_counters(lm, stats):
     assert lm.stat_names == latent_moe.STAT_NAMES + KDA_TAIL
     kda = sum(kind == "kda" for kind, _ in lm.kept)
     assert float(stats["kda_layers"]) == kda
-    # ops/kda_rule.py is jax.numpy on every backend
-    assert float(stats["kda_kernel_layers"]) == 0.0
+    # the layers whose rule took the Pallas kernels: the backend and the
+    # shapes decide (a whole chunk here: the row's length is the case's) —
+    # none off the chip, none at a head size that is no lane tile
+    shape = (1, 64, lm.heads, lm.spec["linear_attn_config"]["head_dim"])
+    assert float(stats["kda_kernel_layers"]) == kda * kda_runs_in_kernels(
+        shape, shape)
+    assert not kda_runs_in_kernels((1, 64, 16, 128), (1, 64, 16, 128))
     assert float(stats["heads_held"]) == lm.spec["heads_held"][1]
     assert (float(stats["kda_state_absmax"]) > 0.0) == bool(kda)
     assert (float(stats["kda_decay_min"]) < 0.0) == bool(kda)
